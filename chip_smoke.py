@@ -1,0 +1,648 @@
+#!/usr/bin/env python3
+"""Prove that the trainer and the server start, run and are right on a TPU.
+
+    python chip_smoke.py             # one chip: trainer, server, int8 server,
+                                     # and the Pallas kernels in each step
+    python chip_smoke.py --chips 4   # four chips: ZeRO-3 sharded training and
+                                     # the one-device loss it is compared with
+
+The model is the ``mistral-7b`` preset at its published widths (hidden 4096,
+MLP 14336, 32 query / 8 KV heads, head_dim 128, vocab 32000, window 4096).
+Depth is cut to what the memory of the chips holds, and every phase prints
+the depth it used and why.  Weights, batches and prompts come from ``--seed``.
+
+One process throughout: a chip belongs to one process at a time, so nothing
+here starts a child.  Every phase ends in a device-to-host fetch.  A phase
+that fails raises, and the exit code is then non-zero; there is no fallback
+to the CPU.  The last line of standard output is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import http.client
+import json
+import os
+import sys
+import threading
+import time
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from deepspeed_tpu.models import transformer as tfm  # noqa: E402
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Everything a run is sized by.  The defaults fill one 16 GB v5e chip;
+    a rehearsal on the CPU hands in small ones (see the verify skill)."""
+    preset: str = "mistral-7b"
+    # -- trainer: bf16 params (2 B) + f32 Adam moments (8 B) + f32 grads
+    # (4 B) + the caller's own copy (2 B) is 16 B a parameter; two layers
+    # with embedding and head are 0.70 B parameters = 11.2 GB of the 15.75
+    train_layers: int = 2
+    train_micro: int = 4
+    train_seq: int = 2048
+    train_warmup: int = 2
+    train_steps: int = 6
+    loss_tile: int = 512
+    lr: float = 2e-4
+    # -- server: 0.44 GB a layer in bf16 + 0.52 GB embedding and head, and a
+    # KV cache of 4 KiB a token a layer: 24 layers are 11.0 GB of weights
+    # and 1.6 GB of cache for 16k tokens
+    serve_layers: int = 24
+    # int8 codes are 0.22 GB a layer: all 32 layers are 7.5 GB
+    quant_layers: int = 32
+    block_size: int = 64
+    num_blocks: int = 256
+    max_blocks_per_seq: int = 16
+    max_seqs: int = 16
+    max_tokens_per_step: int = 512
+    # (prompt tokens, new tokens); one prompt is longer than a step's token
+    # budget, so its prefill is chunked across steps
+    requests: Tuple[Tuple[int, int], ...] = (
+        (40, 24), (96, 32), (160, 16), (300, 40), (64, 28), (200, 20),
+        (600, 24), (24, 36))
+    quant_requests: Tuple[Tuple[int, int], ...] = (
+        (48, 16), (130, 24), (20, 20), (260, 12))
+    # a served token's reference logit may lie this far under the reference
+    # maximum: random-init logits have a standard deviation near 1 and a
+    # maximum near 4, the top two lie 0.2 apart on average, and bf16 noise
+    # through the stack is under 0.1; a wrong token lies about 4 under
+    margin: float = 0.5
+    # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
+    # the caller's unsharded copy on chip 0
+    zero3_layers: int = 8
+    zero3_steps: int = 3
+    compare_layers: int = 2
+    loss_rel_tol: float = 5e-3
+
+
+def log(phase: str, **kv: Any) -> None:
+    print(f"[{phase}] " + " ".join(f"{k}={v}" for k, v in kv.items()),
+          flush=True)
+
+
+def memory_line(phase: str, device) -> None:
+    stats = device.memory_stats()
+    if not stats:
+        log(phase, memory_stats="not reported by this backend")
+        return
+    log(phase, peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+        bytes_in_use=stats.get("bytes_in_use"),
+        bytes_limit=stats.get("bytes_limit"))
+
+
+def require_kernel(phase: str, name: str, hlo_text: str) -> None:
+    """Phase 4: the compiled step must hold a Mosaic kernel.  One that gave
+    way to its XLA reference compiles without any ``tpu_custom_call``."""
+    n = hlo_text.count("tpu_custom_call")
+    log(phase, program=name, tpu_custom_calls=n)
+    if n == 0:
+        raise AssertionError(
+            f"{name}: no tpu_custom_call in the compiled program — a Pallas "
+            f"kernel gave way to its reference")
+
+
+class Recorded:
+    """Stand-in for one of the engine's jitted steps: counts the calls,
+    keeps the argument shapes of the first, so the very program that ran can
+    be lowered and compiled again for inspection, and the largest number of
+    live rows (``context_lens > 0``, argument ``rows_arg``) in any call."""
+
+    def __init__(self, jitted, rows_arg: int):
+        self.jitted = jitted
+        self.rows_arg = rows_arg
+        self.calls = 0
+        self.max_rows = 0
+        self.specs = None
+
+    def __call__(self, *args):
+        if self.specs is None:
+            self.specs = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        self.calls += 1
+        self.max_rows = max(self.max_rows, int(np.count_nonzero(
+            np.asarray(args[self.rows_arg]))))
+        return self.jitted(*args)
+
+    def compiled_text(self) -> str:
+        return self.jitted.lower(*self.specs).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# phase 1: the trainer
+# ---------------------------------------------------------------------------
+
+
+def build_trainer(sz: Sizes, seed: int, num_layers: int,
+                  ds_extra: Dict[str, Any]):
+    """``deepspeed_tpu.initialize`` as ``examples/train.py`` drives it."""
+    import deepspeed_tpu
+    from deepspeed_tpu.parallel import topology
+    from deepspeed_tpu.runtime.engine import ModelSpec
+    from deepspeed_tpu.sequence.tiled_compute import tiled_loss_fn
+
+    topology.reset_topology()
+    cfg = tfm.get_config(sz.preset, num_layers=num_layers,
+                         param_dtype="bfloat16")
+    params = jax.jit(lambda k: tfm.init_params(k, cfg))(
+        jax.random.PRNGKey(seed))
+
+    def loss_fn(p, b, r):
+        return tiled_loss_fn(p, b, cfg, tile_size=sz.loss_tile)
+
+    spec = ModelSpec(loss_fn=loss_fn, params=params,
+                     param_axes=tfm.param_axes(cfg),
+                     flops_per_token=cfg.flops_per_token())
+    config = {
+        "train_micro_batch_size_per_gpu": sz.train_micro,
+        "optimizer": {"type": "AdamW", "params": {"lr": sz.lr}},
+        "zero_optimization": {"stage": 0},
+        "bf16": {"enabled": True},
+        "steps_per_print": 1_000_000,
+    }
+    config.update(ds_extra)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=spec, config=config)
+    batch = {"input_ids": np.random.default_rng(seed).integers(
+        0, cfg.vocab_size,
+        size=(engine.train_batch_size, sz.train_seq)).astype(np.int32)}
+    return cfg, params, engine, batch
+
+
+def timed_steps(phase: str, engine, placed, warmup: int, steps: int
+                ) -> Tuple[List[float], float]:
+    """``warmup + steps`` steps on one repeated batch, each waited for and
+    timed on its own, so that a step that compiles again shows.  Returns
+    every loss and the median seconds of the timed steps.  Each wait is a
+    barrier and then a fetch; the fetch is timed apart, since it would be
+    long if the barrier returned early."""
+    losses, seconds, fetch = [], [], 0.0
+    for _ in range(warmup + steps):
+        t0 = time.perf_counter()
+        out = engine.train_batch(placed)
+        jax.block_until_ready(engine.state.step)
+        t1 = time.perf_counter()
+        losses.append(float(out["loss"]))  # device-to-host
+        seconds.append(time.perf_counter() - t0)
+        fetch = max(fetch, time.perf_counter() - t1)
+    step_s = float(np.median(seconds[warmup:]))
+    log(phase, compile_seconds=round(seconds[0] - step_s, 2),
+        step_seconds=round(step_s, 4),
+        each_step_seconds=[round(x, 3) for x in seconds],
+        programs_compiled=engine._train_step._cache_size(),
+        longest_fetch_after_barrier_seconds=round(fetch, 5))
+    log(phase, losses=[round(x, 4) for x in losses])
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"{phase}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(
+            f"{phase}: loss on the repeated batch did not fall: {losses}")
+    return losses, step_s
+
+
+def phase_trainer(sz: Sizes, seed: int, check_kernels: bool = True) -> None:
+    phase = "trainer"
+    log(phase, preset=sz.preset, layers=sz.train_layers,
+        why="bf16 params + f32 Adam moments + f32 grads + the caller's copy "
+            "are 16 B a parameter; 2 layers with embedding and head are "
+            "0.70 B parameters = 11.2 GB of one 16 GB chip")
+    cfg, params, engine, batch = build_trainer(sz, seed, sz.train_layers, {})
+    log(phase, params_m=round(cfg.num_params() / 1e6, 1), dtype="bfloat16",
+        optimizer="AdamW", zero_stage=0, attn=cfg.attn_impl,
+        window=cfg.sliding_window, loss_tile=sz.loss_tile,
+        micro_batch=sz.train_micro, seq=sz.train_seq)
+    placed = engine.place_batch(batch)
+    _, step_s = timed_steps(phase, engine, placed, sz.train_warmup,
+                            sz.train_steps)
+    log(phase, tokens_per_second=round(
+        engine.train_batch_size * sz.train_seq / step_s, 1))
+    memory_line(phase, jax.devices()[0])
+    if check_kernels:
+        require_kernel("kernels", "train_step", engine._train_step.lower(
+            engine.state, placed.placed).compile().as_text())
+
+
+# ---------------------------------------------------------------------------
+# phases 2 and 3: the server
+# ---------------------------------------------------------------------------
+
+
+def _post(port: int, path: str, obj: dict) -> Tuple[Any, Any]:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=900)
+    conn.request("POST", path, json.dumps(obj),
+                 {"Content-Type": "application/json"})
+    return conn, conn.getresponse()
+
+
+def _complete(port: int, prompt: List[int], n: int, stream: bool
+              ) -> Tuple[List[int], str]:
+    """One ``/v1/completions`` request → (tokens, finish_reason)."""
+    conn, resp = _post(port, "/v1/completions",
+                       {"prompt": prompt, "max_tokens": n, "stream": stream})
+    try:
+        if resp.status != 200:
+            raise AssertionError(f"HTTP {resp.status}: {resp.read()[:300]}")
+        if not stream:
+            choice = json.loads(resp.read())["choices"][0]
+            return choice["tokens"], choice["finish_reason"]
+        tokens, finish = [], None
+        for raw in resp:
+            raw = raw.strip()
+            if not raw.startswith(b"data: "):
+                continue
+            if raw[6:] == b"[DONE]":
+                break
+            choice = json.loads(raw[6:])["choices"][0]
+            if choice.get("token") is not None:
+                tokens.append(choice["token"])
+            else:
+                finish = choice["finish_reason"]
+        return tokens, finish
+    finally:
+        conn.close()
+
+
+def host_params(cfg, seed: int, chunk: int = 8):
+    """bf16 weights of the whole depth on the host, as a checkpoint would
+    be, without the chip ever holding them whole: made on the chip ``chunk``
+    layers at a time (the host's generator is slow, and its f32
+    intermediates of a whole stack do not fit the host), moved over, and
+    joined leaf by leaf so that the host holds one copy and one leaf more."""
+    cpu = jax.local_devices(backend="cpu")[0]
+    part_cfg = dataclasses.replace(cfg, num_layers=min(chunk, cfg.num_layers))
+    init = jax.jit(lambda k: tfm.init_params(k, part_cfg))
+    key = jax.random.PRNGKey(seed)
+    params, parts = None, []
+    for i in range(0, cfg.num_layers, part_cfg.num_layers):
+        part = jax.device_put(init(jax.random.fold_in(key, i)), cpu)
+        leaves, treedef = jax.tree_util.tree_flatten(part.pop("layers"))
+        parts.append(leaves)
+        params = params or part  # embedding, head and final norm: the first
+    joined = []
+    for j in range(len(parts[0])):
+        joined.append(jnp.concatenate([p[j] for p in parts], axis=0))
+        for p in parts:
+            p[j] = None
+    params["layers"] = jax.tree_util.tree_unflatten(treedef, joined)
+    return params
+
+
+def reference_margins(params, cfg, sequences: List[List[int]]):
+    """Plain reference: ``tfm.forward`` on the XLA attention path over each
+    whole sequence (prompt + served tokens), same parameters.  Returns, for
+    every position p, how far the reference logit of the token at p+1 lies
+    under the reference maximum at p, how many tokens the reference ranks
+    above it, and the spread of the logits over the vocabulary.  XLA
+    attention is plain causal: right while every sequence is shorter than
+    the model's window, which is checked."""
+    s_max = -(-max(len(s) for s in sequences) // 64) * 64
+    if cfg.sliding_window and s_max > cfg.sliding_window:
+        raise AssertionError("the reference has no window: keep sequences "
+                             f"under {cfg.sliding_window} tokens")
+    tokens = np.zeros((len(sequences), s_max), np.int32)
+    for i, s in enumerate(sequences):
+        tokens[i, :len(s)] = s
+
+    @jax.jit
+    def margins(p, t):
+        logits = tfm.forward(p, t, cfg, attn_fn=tfm.xla_attention)
+        # one materialised f32 copy, so that the maximum, the served logit
+        # and the rank all read the same values
+        logits = jax.lax.optimization_barrier(
+            logits[:, :-1].astype(jnp.float32))
+        served = jnp.take_along_axis(logits, t[:, 1:, None], axis=-1)
+        rank = (logits > served).sum(-1)
+        return logits.max(-1) - served[..., 0], rank, logits.std(-1).mean()
+
+    m, rank, std = margins(params, jnp.asarray(tokens))
+    return np.asarray(m), np.asarray(rank), float(std)
+
+
+def phase_server(sz: Sizes, seed: int, quantize_bits: int,
+                 check_kernels: bool = True) -> None:
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.serving.balancer import ReplicaPool
+    from deepspeed_tpu.serving.config import ServingConfig
+    from deepspeed_tpu.serving.metrics import ServingMetrics
+    from deepspeed_tpu.serving.server import create_server
+
+    phase = f"server-int{quantize_bits}" if quantize_bits else "server"
+    device = jax.devices()[0]
+    if quantize_bits:
+        layers, jobs = sz.quant_layers, sz.quant_requests
+        why = ("int8 codes are 0.22 GB a layer, so the full depth is 7.5 GB; "
+               "the bf16 weights are made on the host and only the codes "
+               "reach the chip")
+    else:
+        layers, jobs = sz.serve_layers, sz.requests
+        why = ("bf16 weights are 0.44 GB a layer + 0.52 GB embedding and "
+               "head, the KV cache 4 KiB a token a layer: 24 layers are "
+               "11.0 GB + 1.6 GB for 16k tokens, of one 16 GB chip")
+    log(phase, preset=sz.preset, layers=layers, why=why)
+    memory_line(phase + "/start", device)
+
+    # the engine as serving/server.py:main builds it (build_engine_factory →
+    # ReplicaPool.build → create_server), over a config with the depth cut
+    # and bf16 parameters, which the server's flags cannot say
+    cfg = tfm.get_config(sz.preset, num_layers=layers, dtype="bfloat16",
+                         param_dtype="bfloat16")
+    t0 = time.perf_counter()
+    if quantize_bits:
+        params = host_params(cfg, seed)
+    else:
+        params = jax.jit(lambda k: tfm.init_params(k, cfg))(
+            jax.random.PRNGKey(seed))
+    jax.block_until_ready(params)
+    log(phase, init_seconds=round(time.perf_counter() - t0, 1),
+        params_m=round(cfg.num_params() / 1e6, 1))
+    v2 = V2Config(max_tokens_per_step=sz.max_tokens_per_step,
+                  max_seqs=sz.max_seqs, block_size=sz.block_size,
+                  num_blocks=sz.num_blocks,
+                  max_blocks_per_seq=sz.max_blocks_per_seq, dtype="bfloat16",
+                  quantize_bits=quantize_bits)
+    log(phase, **{k: getattr(v2, k) for k in (
+        "max_tokens_per_step", "max_seqs", "block_size", "num_blocks",
+        "max_blocks_per_seq", "quantize_bits")})
+    t0 = time.perf_counter()
+    scfg = ServingConfig(num_replicas=1, max_queue=64,
+                         default_max_tokens=16, drain_timeout_s=120.0)
+    metrics = ServingMetrics()
+    pool = ReplicaPool.build(lambda: InferenceEngineV2(cfg, params, v2),
+                             scfg, metrics=metrics)
+    engine = pool.replicas[0].engine
+    if quantize_bits:
+        params = engine.params  # the codes, on the chip; drop the host bf16
+        log(phase, quantize_seconds=round(time.perf_counter() - t0, 1))
+    # (params, caches, tokens, positions, seq_index | block_tables, ...)
+    prefill = engine._fwd = Recorded(engine._fwd, rows_arg=6)
+    decode = engine._decode_fwd = Recorded(engine._decode_fwd, rows_arg=5)
+    pool.start()
+    pool.wait_ready(timeout=scfg.spawn_timeout_s)
+    server = create_server(pool, metrics, scfg, port=0,
+                           model_name=sz.preset)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    port = server.server_port
+    try:
+        rng = np.random.default_rng(seed + 1)
+        prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+                   for n, _ in jobs]
+
+        # one request alone first: it compiles the prefill and decode steps
+        t0 = time.perf_counter()
+        warm, _ = _complete(port, prompts[0][:32], 4, stream=False)
+        log(phase, warmup_request_seconds=round(time.perf_counter() - t0, 2),
+            note="compiles the prefill and the decode step")
+        if len(warm) != 4:
+            raise AssertionError(f"{phase}: warm-up gave {len(warm)} tokens")
+
+        # then the rest: half at once, half staggered, so that prompts
+        # arrive while earlier requests decode (mixed steps), several rows
+        # decode together, and the tail is pure decode
+        results: Dict[int, Tuple[List[int], str]] = {}
+        errors: List[str] = []
+
+        def run(i: int) -> None:
+            try:
+                results[i] = _complete(port, prompts[i], jobs[i][1],
+                                       stream=i % 2 == 0)
+            except Exception as e:  # re-raised below, on the main thread
+                errors.append(f"request {i}: {e!r}")
+
+        calls0 = (prefill.calls, decode.calls)
+        t0 = time.perf_counter()
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(len(jobs))]
+        for i, t in enumerate(threads):
+            t.start()
+            if i >= len(threads) // 2:
+                time.sleep(0.15)
+        for t in threads:
+            t.join(timeout=600)
+            if t.is_alive():
+                raise AssertionError(f"{phase}: a request hung")
+        wall = time.perf_counter() - t0
+        if errors:
+            raise AssertionError(f"{phase}: {errors}")
+        for i, (_, n) in enumerate(jobs):
+            toks, finish = results[i]
+            if len(toks) != n or finish != "length":
+                raise AssertionError(
+                    f"{phase}: request {i} asked {n} tokens, got "
+                    f"{len(toks)} (finish_reason {finish})")
+        mixed, pure = prefill.calls - calls0[0], decode.calls - calls0[1]
+        n_tok = sum(n for _, n in jobs)
+        log(phase, requests=len(jobs), streamed=(len(jobs) + 1) // 2,
+            unary=len(jobs) // 2, tokens_served=n_tok,
+            wall_seconds=round(wall, 2), prefill_or_mixed_steps=mixed,
+            pure_decode_steps=pure, most_rows_in_a_mixed_step=prefill.max_rows,
+            most_rows_in_a_decode_step=decode.max_rows)
+        if mixed == 0 or pure == 0 or decode.max_rows < 2:
+            raise AssertionError(
+                f"{phase}: wanted mixed steps and pure-decode steps over "
+                f"several rows; ran {mixed} and {pure}, at most "
+                f"{decode.max_rows} rows")
+
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+        conn.request("GET", "/healthz")
+        health = json.loads(conn.getresponse().read())
+        conn.close()
+        log(phase, healthz=health["status"])
+        if health["status"] != "ok":
+            raise AssertionError(f"{phase}: /healthz said {health}")
+    finally:
+        pool.drain(scfg.drain_timeout_s)
+        server.shutdown()
+        server.server_close()
+    free, total = engine.free_blocks, engine.total_blocks
+    log(phase, drained=True, free_blocks=free, total_blocks=total)
+    if free != total:
+        raise AssertionError(
+            f"{phase}: KV blocks leaked: {free} free of {total}")
+    del engine, pool, server  # the KV cache goes; the reference needs room
+    gc.collect()
+
+    # the served tokens against the plain reference, same parameters.  For
+    # the quantized server the reference reads the same int8 codes through
+    # the mixed GEMM, so that kernel is checked against its dequantize
+    # oracle first, at the projections' own shapes.
+    if quantize_bits:
+        check_mixed_gemm(phase, params, cfg)
+    sequences = [prompts[i] + results[i][0] for i in range(len(jobs))]
+    m, rank, std = reference_margins(params, cfg, sequences)
+    picks = np.concatenate([
+        np.stack([m[i, n_prompt - 1:n_prompt - 1 + n_new],
+                  rank[i, n_prompt - 1:n_prompt - 1 + n_new]])
+        for i, (n_prompt, n_new) in enumerate(jobs)], axis=1)
+    worst = float(picks[0].max())
+    log(phase, reference="tfm.forward, XLA attention, same parameters",
+        logit_std_over_vocab=round(std, 3), worst_margin=round(worst, 4),
+        mean_margin=round(float(picks[0].mean()), 5),
+        allowed_margin=sz.margin,
+        tokens_equal_to_reference_argmax=int((picks[1] == 0).sum()),
+        worst_rank=int(picks[1].max()), tokens_checked=picks.shape[1])
+    if not np.isfinite(m).all() or worst > sz.margin:
+        raise AssertionError(
+            f"{phase}: a served token lies {worst} under the reference "
+            f"maximum (allowed {sz.margin})")
+    memory_line(phase, device)
+    if check_kernels:
+        name = f"int{quantize_bits}" if quantize_bits else "bf16"
+        require_kernel("kernels", f"prefill_step@{name}",
+                       prefill.compiled_text())
+        require_kernel("kernels", f"decode_step@{name}",
+                       decode.compiled_text())
+
+
+def check_mixed_gemm(phase: str, params, cfg) -> None:
+    """The int8 mixed GEMM against dequantize-then-matmul on layer 0's
+    projections, at a decode-sized and a prefill-sized M."""
+    from deepspeed_tpu.ops.pallas.mixed_gemm import (QuantizedWeight,
+                                                     dequantize_gemm_weight,
+                                                     mixed_gemm)
+
+    worst = 0.0
+    layer = params["layers"]
+    for name, qw in (("wq", layer["attn"]["wq"]),
+                     ("w_in", layer["mlp"]["w_in"]),
+                     ("w_out", layer["mlp"]["w_out"])):
+        qw0 = QuantizedWeight(qw.codes[0], qw.scales[0], qw.bits, qw.group,
+                              qw.k)
+        for m_rows in (16, 512):
+            x = jax.random.normal(jax.random.PRNGKey(m_rows),
+                                  (m_rows, qw0.k_features), jnp.bfloat16)
+            got = jax.jit(mixed_gemm)(x, qw0).astype(jnp.float32)
+            want = jnp.dot(x, dequantize_gemm_weight(qw0).astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+            err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+            worst = max(worst, err)
+            if not err < 2e-2:
+                raise AssertionError(
+                    f"{phase}: mixed_gemm {name} M={m_rows} is {err} off "
+                    f"its dequantize oracle")
+    log(phase, mixed_gemm_vs_oracle_worst_rel_err=round(worst, 5))
+
+
+# ---------------------------------------------------------------------------
+# four chips: ZeRO-3 sharded training, and what it is compared with
+# ---------------------------------------------------------------------------
+
+
+def phase_zero3(sz: Sizes, seed: int) -> None:
+    phase = "zero3x4"
+    n = len(jax.devices())
+    zero3 = {"zero_optimization": {"stage": 3}, "mesh": {"fsdp_size": n},
+             "train_micro_batch_size_per_gpu": 1}
+
+    # (a) the comparison, at a depth one chip holds: the sharded first-step
+    # loss against the one-device dense loss, same parameters and batch
+    log(phase, part="compare", layers=sz.compare_layers,
+        why="a depth one chip holds beside the sharded engine")
+    cfg, params, engine, batch = build_trainer(sz, seed, sz.compare_layers,
+                                               zero3)
+    loss = float(engine.train_batch(batch)["loss"])
+    # XLA attention is plain causal: the window (4096) is wider than seq
+    ref = float(jax.jit(lambda p, b: tfm.loss_fn(
+        p, b, cfg, attn_fn=tfm.xla_attention)[0])(params, batch))
+    rel = abs(loss - ref) / abs(ref)
+    log(phase, part="compare", sharded_first_step_loss=round(loss, 5),
+        one_device_loss=round(ref, 5), rel_diff=f"{rel:.2e}",
+        allowed=sz.loss_rel_tol)
+    if not (np.isfinite(loss) and rel < sz.loss_rel_tol):
+        raise AssertionError(
+            f"{phase}: sharded loss {loss} != one-device loss {ref}")
+    del params, engine
+    gc.collect()
+
+    # (b) the sharded run, at a depth fitted to the four chips
+    log(phase, part="train", layers=sz.zero3_layers,
+        why="ZeRO-3 shards 14 B a parameter (bf16 params, f32 moments and "
+            "grads) over 4 chips: 8 layers are 2.0 B parameters = 7 GB a "
+            "chip, beside the caller's 4 GB unsharded copy on chip 0")
+    cfg, params, engine, batch = build_trainer(sz, seed, sz.zero3_layers,
+                                               zero3)
+    log(phase, params_m=round(cfg.num_params() / 1e6, 1), topo=engine.topo,
+        train_batch_size=engine.train_batch_size, seq=sz.train_seq)
+    leaf = engine.state.params["layers"]["mlp"]["w_in"]
+    shard = leaf.addressable_shards[0].data
+    devices = {s.device for s in leaf.addressable_shards}
+    log(phase, leaf="layers/mlp/w_in", leaf_shape=leaf.shape,
+        shard_shape=shard.shape, shard_devices=len(devices))
+    if shard.size * n != leaf.size or len(devices) != n:
+        raise AssertionError(
+            f"{phase}: parameters are not sharded {n} ways: leaf "
+            f"{leaf.shape}, shard {shard.shape} on {len(devices)} devices")
+    placed = engine.place_batch(batch)
+    _, step_s = timed_steps(phase, engine, placed, sz.train_warmup,
+                            sz.zero3_steps)
+    log(phase, tokens_per_second=round(
+        engine.train_batch_size * sz.train_seq / step_s, 1))
+    for d in jax.devices():
+        memory_line(f"{phase}/chip{d.id}", d)
+    text = engine._train_step.lower(engine.state,
+                                    placed.placed).compile().as_text()
+    # the TPU compiler writes a reduce-scatter as a fusion named
+    # ``all-reduce-scatter``, so that one is counted by name
+    counts = {op: text.count(f" {op}(") + text.count(f" {op}-start(")
+              for op in ("all-gather", "all-reduce", "all-to-all")}
+    counts["reduce-scatter"] = text.count("reduce-scatter")
+    log(phase, compiled_collectives=counts)
+    if not (counts["all-gather"] and counts["reduce-scatter"]):
+        raise AssertionError(
+            f"{phase}: the compiled step lacks all-gather or "
+            f"reduce-scatter: {counts}")
+    require_kernel(phase, "train_step@zero3", text)
+
+
+# ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache()
+    devices = jax.devices()  # raises when the backend cannot start
+    dev = devices[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found {dev.platform!r} "
+              f"({dev.device_kind}); there is no CPU fallback",
+              file=sys.stderr)
+        return 1
+    if len(devices) != args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX found "
+              f"{len(devices)} devices", file=sys.stderr)
+        return 1
+    log("setup", device_kind=dev.device_kind, devices=len(devices),
+        jax=jax.__version__, compile_cache=cache, seed=args.seed)
+    t0 = time.perf_counter()
+    sz = Sizes()
+    if args.chips == 4:
+        phase_zero3(sz, args.seed)
+    else:
+        phase_trainer(sz, args.seed)
+        gc.collect()
+        phase_server(sz, args.seed, quantize_bits=0)
+        gc.collect()
+        phase_server(sz, args.seed, quantize_bits=8)
+    log("done", total_seconds=round(time.perf_counter() - t0, 1))
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -28,12 +28,12 @@ class StrictTomlError(ValueError):
 
 def load_toml(path: str) -> Dict[str, Any]:
     """Parse ``path`` as TOML; parse failures carry the file name."""
-    import tomli
+    import tomllib
 
     try:
         with open(path, "rb") as f:
-            return tomli.load(f)
-    except tomli.TOMLDecodeError as e:
+            return tomllib.load(f)
+    except tomllib.TOMLDecodeError as e:
         raise StrictTomlError(f"{path}: invalid TOML: {e}") from e
 
 

@@ -252,7 +252,7 @@ def axis_index(axis_name: AxisName):
 def axis_size(axis_name: AxisName) -> int:
     import math
 
-    from ..compat import axis_size as _axis_size
+    from jax.lax import axis_size as _axis_size
 
     if isinstance(axis_name, str):
         return _axis_size(axis_name)

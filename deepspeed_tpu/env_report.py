@@ -28,7 +28,8 @@ def main() -> int:
     print(f"local devices .......... {accel.device_count()}")
     print(f"global devices ......... {accel.global_device_count()}")
     print(f"process count .......... {jax.process_count()}")
-    print(f"peak bf16 TFLOPS/chip .. {accel.peak_tflops():.0f}")
+    if accel.platform() == "tpu":
+        print(f"peak bf16 TFLOPS/chip .. {accel.peak_tflops():.0f}")
     mem = accel.total_memory()
     if mem:
         print(f"HBM per chip ........... {mem / 2**30:.1f} GiB")

@@ -13,6 +13,7 @@ gain nothing from int codes), matching the reference's exclude list.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -26,7 +27,8 @@ _QUANT_PARENTS = frozenset({"attn", "mlp"})
 
 
 def quantize_model_params(params: Dict[str, Any], bits: int = 8,
-                          group: int = 256) -> Dict[str, Any]:
+                          group: int = 256,
+                          quantize=quantize_gemm_weight) -> Dict[str, Any]:
     """Replace layer projection weights with QuantizedWeight nodes."""
     saw_moe = False
 
@@ -35,7 +37,7 @@ def quantize_model_params(params: Dict[str, Any], bits: int = 8,
         if isinstance(tree, dict):
             if "moe" in tree:
                 saw_moe = True
-            return {k: (quantize_gemm_weight(v, bits=bits, group=group)
+            return {k: (quantize(v, bits=bits, group=group)
                         if (parent in _QUANT_PARENTS and k in _QUANT_KEYS
                             and getattr(v, "ndim", 0) >= 2)
                         else walk(v, k))
@@ -95,16 +97,26 @@ def quantize_on_host(params: Dict[str, Any], bits: int,
                      group: int) -> Dict[str, Any]:
     """Quantize on the host CPU backend so the accelerator never holds the
     full-precision weights (the whole point of weight-only quantization)."""
-    try:
-        cpus = jax.local_devices(backend="cpu")
-    except RuntimeError:  # platform-restricted build: quantize in place
-        return quantize_model_params(params, bits=bits, group=group)
+    cpu = jax.local_devices(backend="cpu")[0]
     # device_put (not default_device + asarray): already-committed accelerator
     # arrays are actually MOVED to host, keeping the no-fp-weights-on-chip
     # guarantee even when params arrive as device arrays
-    host = jax.tree.map(lambda x: jax.device_put(x, cpus[0]), params)
-    with jax.default_device(cpus[0]):
-        return quantize_model_params(host, bits=bits, group=group)
+    host = jax.device_put(params, cpu)
+    return quantize_model_params(host, bits=bits, group=group,
+                                 quantize=_quantize_layerwise)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "group"))
+def _quantize_layerwise(w: jax.Array, bits: int, group: int
+                        ) -> QuantizedWeight:
+    """One leaf, jitted (it runs where its committed input is, on the
+    host), and a stacked (L, K, N) leaf a layer at a time: the f32
+    intermediates are then one layer's.  Op by op, a 32-layer stack of a
+    7B model's MLP weight holds three 7.5 GB f32 copies of itself."""
+    if w.ndim != 3:
+        return quantize_gemm_weight(w, bits=bits, group=group)
+    return jax.lax.map(
+        functools.partial(quantize_gemm_weight, bits=bits, group=group), w)
 
 
 def quantized_bytes(params: Dict[str, Any]) -> Dict[str, int]:

@@ -619,12 +619,7 @@ class InferenceEngineV2:
                 "(deepspeed_tpu.init_inference), which supports alibi")
         self.cfg = config or V2Config()
         self.model_cfg = dataclasses.replace(model_config, dtype=self.cfg.dtype)
-        if self.cfg.quantize_bits:
-            from ..quantization import quantize_on_host
-
-            params = quantize_on_host(params, self.cfg.quantize_bits,
-                                      self.cfg.quantize_group)
-        self.params = params
+        self.params = self._quantized(params)
         # device adapter stack for multi-tenant LoRA routing (slot 0 is the
         # reserved all-zero null adapter; serving/adapters.py owns 1..N-1)
         self.adapter_stack = None
@@ -745,6 +740,20 @@ class InferenceEngineV2:
             self._spec_fwd = build_draft_spec_step(
                 self.model_cfg, self.draft_cfg, self.cfg)
 
+    def _quantized(self, raw_params: Any) -> Any:
+        """``raw_params`` as this engine serves them: untouched without
+        ``quantize_bits``; else quantized on the host, and the codes moved
+        beside the KV cache, on the default device, where the jitted steps
+        want them."""
+        if not self.cfg.quantize_bits:
+            return raw_params
+        from ..quantization import quantize_on_host
+
+        return jax.device_put(
+            quantize_on_host(raw_params, self.cfg.quantize_bits,
+                             self.cfg.quantize_group),
+            jax.local_devices()[0])
+
     # -- rolling weight swaps (serving/rollout.py) ----------------------
 
     def swap_params(self, raw_params: Any) -> None:
@@ -756,11 +765,7 @@ class InferenceEngineV2:
         engine: the jitted forwards take params as call arguments, so
         the swap is a pointer move, but swapping mid-request would mix
         weight generations within one stream."""
-        if self.cfg.quantize_bits:
-            from ..quantization import quantize_on_host
-
-            raw_params = quantize_on_host(raw_params, self.cfg.quantize_bits,
-                                          self.cfg.quantize_group)
+        raw_params = self._quantized(raw_params)
         if (jax.tree_util.tree_structure(raw_params)
                 != jax.tree_util.tree_structure(self.params)):
             raise ValueError("swap_params: incoming pytree structure does "

@@ -91,9 +91,6 @@ def build_env(coordinator: str, port: int, num_processes: int, process_id: int,
         "NUM_PROCESSES": str(num_processes),
         "PROCESS_ID": str(process_id),
         "DSTPU_MULTIPROCESS": "1",
-        # multi-host jobs must fail fast on accelerator-init failure: one
-        # worker silently degrading to CPU deadlocks the first collective
-        "DSTPU_REQUIRE_ACCELERATOR": "1",
     }
     if extra_env:
         env.update(extra_env)

@@ -154,7 +154,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                          num_experts=8, moe_top_k=2),
     "mistral-7b": dict(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
                        num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=32768,
-                       sliding_window=4096, attn_impl="flash"),
+                       sliding_window=4096, attn_impl="flash",
+                       tie_embeddings=False),  # as published: 7.24 B
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
     "tiny-moe": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,

@@ -19,7 +19,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import get_topology

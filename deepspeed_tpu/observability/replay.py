@@ -781,11 +781,11 @@ def default_slo_path() -> str:
 
 def load_slos(path: Optional[str] = None) -> Dict[str, Dict[str, Any]]:
     """Load and validate ``slo.toml``; returns {workload: slo table}."""
-    import tomli
+    import tomllib
 
     path = path or default_slo_path()
     with open(path, "rb") as f:
-        data = tomli.load(f)
+        data = tomllib.load(f)
     workloads = data.get("workloads")
     if not isinstance(workloads, dict) or not workloads:
         raise SLOError(f"{path}: missing [workloads.\"<name>\"] tables")

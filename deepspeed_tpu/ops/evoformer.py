@@ -38,8 +38,9 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .pallas import backend
 from .pallas.flash_attention import (NUM_LANES, NUM_SUBLANES, _flash_fwd,
-                                     _interpret, aligned_divisor)
+                                     aligned_divisor)
 
 
 def _chunk_size(n: int, b: int, h: int, l_q: int, l_k: int,
@@ -65,7 +66,7 @@ def _fwd_impl(q, k, v, b1, b2, has_b1: bool, has_b2: bool):
     bq = aligned_divisor(Lq, 512)
     # the bias tiles put block_k in the minor (lane) dim, so on TPU it must
     # be lane-aligned (a full-dim block, n ≤ cap, is always legal)
-    k_align = NUM_LANES if (has_b1 or has_b2) and not _interpret() \
+    k_align = NUM_LANES if (has_b1 or has_b2) and not backend.interpret() \
         else NUM_SUBLANES
     bk = aligned_divisor(Lk, 512, k_align)
     if bq is not None and bk is not None and Lq >= 8 and Lk >= 8:

@@ -23,25 +23,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+from .pallas import backend
 
 
-def _adam_kernel(p_ref, g_ref, m_ref, v_ref, step_ref,
+def _adam_kernel(p_ref, g_ref, m_ref, v_ref, bc_ref,
                  p_out, m_out, v_out,
                  *, lr, b1, b2, eps, wd):
-    step = step_ref[0]
     p = p_ref[:].astype(jnp.float32)
     g = g_ref[:].astype(jnp.float32)
     m = m_ref[:]
     v = v_ref[:]
     m_new = b1 * m + (1.0 - b1) * g
     v_new = b2 * v + (1.0 - b2) * g * g
-    bc1 = 1.0 - b1 ** step.astype(jnp.float32)
-    bc2 = 1.0 - b2 ** step.astype(jnp.float32)
-    m_hat = m_new / bc1
-    v_hat = v_new / bc2
+    m_hat = m_new / bc_ref[0]
+    v_hat = v_new / bc_ref[1]
     update = m_hat / (jnp.sqrt(v_hat) + eps) + wd * p
     p_out[:] = (p - lr * update).astype(p_out.dtype)
     m_out[:] = m_new
@@ -64,25 +59,33 @@ def fused_adamw_flat(params: jax.Array, grads: jax.Array, m: jax.Array,
             return jnp.pad(x.reshape(-1), (0, pad))
 
         params, grads, m, v = map(padf, (params, grads, m, v))
-    shape2d = (padded // block, block)
+    # one grid step covers ``block`` elements as an (8, block/8) tile: the
+    # chip's compiler wants the last two block dims divisible by (8, 128)
+    rows, cols = 8, block // 8
+    shape2d = (padded // cols, cols)
     args = [params.reshape(shape2d), grads.reshape(shape2d),
             m.reshape(shape2d), v.reshape(shape2d)]
+
+    # the bias corrections are two scalars: computed here, since the chip's
+    # compiler has no scalar ``pow`` inside a kernel
+    t = jnp.asarray(step, jnp.float32)
+    bias_correction = jnp.stack([1.0 - b1 ** t, 1.0 - b2 ** t])
 
     grid = (padded // block,)
     out = pl.pallas_call(
         functools.partial(_adam_kernel, lr=lr, b1=b1, b2=b2, eps=eps,
                           wd=weight_decay),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 4 +
+        in_specs=[pl.BlockSpec((rows, cols), lambda i: (i, 0))] * 4 +
                  [pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 3,
+        out_specs=[pl.BlockSpec((rows, cols), lambda i: (i, 0))] * 3,
         out_shape=[
             jax.ShapeDtypeStruct(shape2d, params.dtype),
             jax.ShapeDtypeStruct(shape2d, jnp.float32),
             jax.ShapeDtypeStruct(shape2d, jnp.float32),
         ],
-        interpret=_interpret(),
-    )(*args, jnp.asarray([step], jnp.int32))
+        interpret=backend.interpret(),
+    )(*args, bias_correction)
     p_new, m_new, v_new = (o.reshape(-1)[:n] for o in out)
     return p_new, m_new, v_new
 

@@ -37,15 +37,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
+from . import backend
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 NUM_LANES = 128
 NUM_SUBLANES = 8
-
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def aligned_divisor(n: int, cap: int, align: int = NUM_SUBLANES):
@@ -218,7 +214,7 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
 def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
                  mask_tab, inputs):
     """Dispatch with or without the scalar-prefetched block-mask table."""
-    params = tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     if mask_tab is not None:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -226,12 +222,12 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
             out_specs=out_specs, scratch_shapes=scratch_shapes)
         return pl.pallas_call(kernel, grid_spec=grid_spec,
                               out_shape=out_shape, compiler_params=params,
-                              interpret=_interpret())(mask_tab, *inputs)
+                              interpret=backend.interpret())(mask_tab, *inputs)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch_shapes,
         compiler_params=params,
-        interpret=_interpret())(*inputs)
+        interpret=backend.interpret())(*inputs)
 
 
 def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
@@ -530,6 +526,28 @@ _flash_attention_bhsd.defvjp(
         sm_scale, causal, block_q, block_k, window, res, g))
 
 
+def _mesh_specs(B: int, H: int, KV: int):
+    """How a (B, S, H, D) attention call splits over the engine's mesh:
+    batch rows over (dp, fsdp), heads over tp.  None when there is no mesh
+    of several devices, or the call is already inside a ``shard_map``.  An
+    axis that does not divide its dimension stays whole (replicated work,
+    never a wrong answer); the sequence is never split here."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel import topology
+
+    if (not topology.topology_initialized()
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    topo = topology.get_topology()
+    if topo.world_size == 1:
+        return None
+    batch = ("dp", "fsdp") if B % topo.dp_world_size == 0 else None
+    tp = topo.size("tp")
+    heads = "tp" if tp > 1 and H % tp == 0 and KV % tp == 0 else None
+    return (topo.mesh, P(batch, None, heads, None), P(batch, None))
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
@@ -550,6 +568,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     KV = k.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
+    if not backend.interpret():
+        specs = _mesh_specs(B, H, KV)
+        if specs is not None:
+            # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+            # shard_map"): on a mesh each device runs the kernel on its own
+            # batch rows and heads; inside, the manual axes stop a second wrap
+            mesh, qspec, bspec = specs
+            local = functools.partial(
+                flash_attention, causal=causal, sm_scale=sm_scale,
+                block_q=block_q, block_k=block_k, window=window,
+                block_mask=block_mask)
+            if segment_ids is None:
+                return jax.shard_map(
+                    local, mesh=mesh, in_specs=(qspec, qspec, qspec),
+                    out_specs=qspec, check_vma=False)(q, k, v)
+            return jax.shard_map(
+                lambda q_, k_, v_, seg: local(q_, k_, v_, segment_ids=seg),
+                mesh=mesh, in_specs=(qspec, qspec, qspec, bspec),
+                out_specs=qspec, check_vma=False)(q, k, v, segment_ids)
 
     def pick_block(n: int, cap: int) -> int:
         # small windows waste MXU work in huge tiles: shrink the cap toward
@@ -570,7 +607,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     usable = (S % block_q == 0 and k.shape[1] % block_k == 0 and H % KV == 0)
     if segment_ids is not None:
         # the in-kernel lane-tiling needs 128-aligned kv blocks on TPU
-        usable = usable and (block_k % NUM_LANES == 0 or _interpret())
+        usable = usable and (block_k % NUM_LANES == 0
+                             or backend.interpret())
     if block_mask is not None:
         nq, nk = pl.cdiv(S, block_q), pl.cdiv(k.shape[1], block_k)
         if block_mask.shape != (nq, nk):
@@ -578,6 +616,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"block_mask shape {block_mask.shape} != grid ({nq}, {nk}) "
                 f"for S={S}, block_q={block_q}, block_k={block_k}")
     if not usable:
+        backend.warn_fallback(
+            "flash_attention",
+            f"S={S}, Skv={k.shape[1]}, H={H}, KV={KV} do not tile into "
+            f"block_q={block_q}, block_k={block_k}"
+            + (" (segment ids need 128-aligned kv blocks)"
+               if segment_ids is not None else ""))
         return _reference_attention(q, k, v, causal=causal, window=window,
                                     segment_ids=segment_ids,
                                     block_mask=block_mask, block_q=block_q,

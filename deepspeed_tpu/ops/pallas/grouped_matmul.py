@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 
 def _pick_tile_k(K: int) -> int:
     for cand in (1024, 512, 256, 128):
@@ -37,11 +38,16 @@ def _pick_tile_k(K: int) -> int:
 
 
 def _use_pallas(M: int, K: int, N: int, tile_m: int, tile_n: int) -> bool:
-    if jax.default_backend() != "tpu":
+    if backend.interpret():  # the host CPU runs ragged_dot, by design
         return False
     # Mosaic lane tiling: keep every matmul dim 128-aligned
-    return (M % tile_m == 0 and _pick_tile_k(K) > 0 and N % tile_n == 0
-            and tile_m % 128 == 0 and tile_n % 128 == 0)
+    ok = (M % tile_m == 0 and _pick_tile_k(K) > 0 and N % tile_n == 0
+          and tile_m % 128 == 0 and tile_n % 128 == 0)
+    if not ok:
+        backend.warn_fallback(
+            "grouped_matmul", f"M={M}, K={K}, N={N} do not tile into "
+            f"tile_m={tile_m}, tile_n={tile_n} with 128-aligned dims")
+    return ok
 
 
 def _gmm_kernel(tile_group_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,

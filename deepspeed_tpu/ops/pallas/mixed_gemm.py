@@ -32,10 +32,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
 from ..quantizer import (minifloat_decode, minifloat_encode, minifloat_max,
                          pack_fp6, pack_int4, unpack_fp6, unpack_int4)
-from .flash_attention import _interpret, aligned_divisor
+from . import backend
+from .flash_attention import aligned_divisor
 
 
 @jax.tree_util.register_pytree_node_class
@@ -162,7 +162,10 @@ def _apply_tile_override(mp: int, N: int, K: int, bits: int,
 
 
 def _unpack_int4(c):
-    lo, hi = unpack_int4(c)  # byte row r holds K-rows 2r (lo), 2r+1 (hi)
+    # byte row r holds K-rows 2r (lo), 2r+1 (hi).  Widen first: the chip's
+    # compiler has no shifts on int8 vectors.
+    c = c.astype(jnp.int32)
+    lo, hi = (c << 28) >> 28, c >> 4  # arithmetic shifts sign-extend
     tk2, tn = c.shape
     return jnp.stack([lo, hi], axis=1).reshape(tk2 * 2, tn)
 
@@ -171,8 +174,10 @@ def _unpack_decode_fp6(c):
     """(3k, tn) packed bytes → (4k, tn) decoded fp6 values (in-kernel:
     shifts + masks + an exact power-of-two bitcast, no table gather)."""
     rows, tn = c.shape
-    b = c.astype(jnp.int32)
-    b0, b1, b2 = b[0::3], b[1::3], b[2::3]
+    # a strided slice of a value (``b[0::3]``) lowers to a gather, which
+    # the chip's compiler refuses; a reshape and an index do not
+    b = c.astype(jnp.int32).reshape(rows // 3, 3, tn)
+    b0, b1, b2 = b[:, 0], b[:, 1], b[:, 2]
     c0 = b0 & 63
     c1 = ((b0 >> 6) & 3) | ((b1 & 15) << 2)
     c2 = ((b1 >> 4) & 15) | ((b2 & 3) << 4)
@@ -225,9 +230,9 @@ def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, tm: int, tn: int):
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x2.dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(x2, qw.codes, qw.scales[:, None, :])
 
 
@@ -326,6 +331,9 @@ def int8_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     usable = (tm is not None and tn is not None and K % qw.group == 0
               and qw.group % 128 == 0)
     if not usable:
+        backend.warn_fallback(
+            "int8_gemm", f"M={M}, K={K}, N={N}, group={qw.group} do not tile "
+            f"(tm={tm}, tn={tn}; the k-tile must be a multiple of 128)")
         out = (x2 @ dequantize_gemm_weight(qw).astype(x2.dtype))
         return out.reshape(*lead, N)
     xp = jnp.pad(x2, ((0, pad_m), (0, 0))) if pad_m else x2
@@ -348,9 +356,9 @@ def int8_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M + pad_m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(codes, scales.T[:, :, None], qw.codes, qw.scales[:, None, :])
     if pad_m:
         out = out[:M]
@@ -387,6 +395,9 @@ def mixed_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
         if pad_m:
             out = out[:M]
     else:
+        backend.warn_fallback(
+            "mixed_gemm", f"bits={qw.bits}, M={M}, K={K}, N={N}, "
+            f"group={qw.group} do not tile (tm={tm}, tn={tn})")
         out = x2 @ dequantize_gemm_weight(qw).astype(x2.dtype)
     return out.reshape(*lead, N)
 

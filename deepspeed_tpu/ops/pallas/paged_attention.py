@@ -22,11 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ... import compat
-
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+from . import backend
 
 
 def _decode_attention_xla(q, k_cache, v_cache, block_tables, context_lens):
@@ -169,7 +165,11 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     # Mosaic DMA slices need the lane dim 128-aligned and sublanes 8-aligned;
     # small-model shapes fall back to the (correct, slower) XLA gather path.
-    if not _interpret() and (D % 128 != 0 or BS % 8 != 0):
+    if not backend.interpret() and (D % 128 != 0 or BS % 8 != 0):
+        backend.warn_fallback(
+            "paged_decode_attention",
+            f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
+            f"a multiple of 8 (Mosaic DMA slice alignment)")
         return _decode_attention_xla(q, k_cache, v_cache, block_tables,
                                      context_lens)
 
@@ -178,8 +178,8 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         grid=(S,),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
-            pl.BlockSpec(memory_space=compat.pallas_any_memory_space()),
-            pl.BlockSpec(memory_space=compat.pallas_any_memory_space()),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
@@ -193,7 +193,7 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                           group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(block_tables, context_lens, q, k_cache, v_cache)
 
 
@@ -364,7 +364,11 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     NB, BS, KV, _ = k_cache.shape
     group = H // KV
 
-    if not _interpret() and (D % 128 != 0 or BS % 8 != 0):
+    if not backend.interpret() and (D % 128 != 0 or BS % 8 != 0):
+        backend.warn_fallback(
+            "paged_prefill_attention",
+            f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
+            f"a multiple of 8 (Mosaic DMA slice alignment)")
         return _prefill_attention_xla(q, k_cache, v_cache, block_tables,
                                       chunk_start, chunk_len)
     tq = min(tq, Qp)
@@ -376,8 +380,8 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
         grid=(S, Qp // tq),
         in_specs=[
             pl.BlockSpec((1, tq, H, D), lambda s, t, *_: (s, t, 0, 0)),
-            pl.BlockSpec(memory_space=compat.pallas_any_memory_space()),
-            pl.BlockSpec(memory_space=compat.pallas_any_memory_space()),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((1, tq, H, D), lambda s, t, *_: (s, t, 0, 0)),
         scratch_shapes=[
@@ -390,5 +394,5 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
         functools.partial(_prefill_kernel, block_size=BS, group=group, tq=tq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Qp, H, D), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(block_tables, chunk_start, chunk_len, q, k_cache, v_cache)

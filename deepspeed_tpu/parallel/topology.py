@@ -69,13 +69,12 @@ class MeshTopology:
         On real TPU slices defer to ``mesh_utils.create_device_mesh`` which
         understands the physical torus; on CPU/virtual devices a plain reshape.
         """
-        try:
+        if devices and devices[0].platform != "cpu":
             from jax.experimental import mesh_utils
 
-            if devices and getattr(devices[0], "platform", "cpu") not in ("cpu",):
-                return mesh_utils.create_device_mesh(shape, devices=list(devices))
-        except Exception:
-            pass
+            # an assignment the torus cannot give raises: it is not reshaped
+            # into one with slower links in silence
+            return mesh_utils.create_device_mesh(shape, devices=list(devices))
         return np.asarray(devices, dtype=object).reshape(shape)
 
     # -- factory --------------------------------------------------------

@@ -14,7 +14,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..comm import comm as dcomm

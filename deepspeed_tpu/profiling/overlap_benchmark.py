@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import get_topology

@@ -558,7 +558,7 @@ class TrainingEngine:
         ``all_gather`` per dtype bucket (``_gather_plan``).  ``tree`` is the
         updated (trainable) param tree; scatter-bucket leaves enter in their
         optimizer-state sharding, everything exits replicated."""
-        from ..compat import shard_map
+        from jax import shard_map
         from .coalesce import unflatten_bucket_shard_major
 
         plan = self._gather_plan
@@ -640,13 +640,20 @@ class TrainingEngine:
             )
         else:
             ls = init_loss_scale(static_scale=1.0)
+        # the step hands its scalars back committed to the mesh, replicated:
+        # start them there too, or step 2 meets new input shardings and the
+        # whole program compiles a second time
+        scalars = jax.device_put(
+            (jnp.zeros((), jnp.int32), ls,
+             jax.random.PRNGKey(self.config.seed), jnp.zeros((), jnp.int32)),
+            NamedSharding(self.topo.mesh, P()))
         return EngineState(
-            step=jnp.zeros((), jnp.int32),
+            step=scalars[0],
             params=params,
             opt_state=opt_state,
-            loss_scale=ls,
-            rng=jax.random.PRNGKey(self.config.seed),
-            skipped_steps=jnp.zeros((), jnp.int32),
+            loss_scale=scalars[1],
+            rng=scalars[2],
+            skipped_steps=scalars[3],
         )
 
     def _cast_opt_to_steady_state(self, opt_state, init_params, opt_shardings):
@@ -861,7 +868,7 @@ class TrainingEngine:
                 (residuals) ride sharded over the dp axes.  ``norm_out``
                 adds a replicated scalar (the gradient sum-of-squares,
                 psummed inside with the metrics) after the metrics."""
-                from ..compat import shard_map
+                from jax import shard_map
 
                 batch_specs = jax.tree.map(lambda _: P(None, dp_axes), batch)
                 rep = jax.tree.map(lambda _: P(), state.params)
@@ -1149,7 +1156,13 @@ class TrainingEngine:
             # positional-compat wrapper: existing callers pass lr_scale third
             return step_fn(state, batch, None, lr_scale)
 
-        return jax.jit(step_compat, donate_argnums=(0,))
+        # the new state leaves with the shardings the old one came in with.
+        # Left to the compiler, a replicated ``P(None, None)`` comes back as
+        # ``P()``; the jit cache takes that for a new input sharding, and
+        # step 2 compiles the whole program a second time
+        state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
+        return jax.jit(step_compat, donate_argnums=(0,),
+                       out_shardings=(state_shardings, None))
 
     def _build_grad_step(self):
         """Device half of the offloaded step: fwd+bwd+accumulate only.

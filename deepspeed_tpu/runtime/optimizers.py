@@ -5,9 +5,9 @@ Capability analogue of the reference's optimizer zoo: FusedAdam/CPUAdam
 plus the engine's ``_configure_basic_optimizer`` dispatch
 (``runtime/engine.py:1960``).  On TPU, "fused" is what XLA does to any
 jitted elementwise update over the parameter pytree — the multi-tensor-apply
-machinery is unnecessary; for the HBM-bound sharded update there is a Pallas
-fused kernel in ``ops/fused_optimizers.py`` selectable via
-``optimizer.params["fused"]``.
+machinery is unnecessary.  The Pallas fused AdamW in
+``ops/fused_optimizers.py`` is a standalone op (``fused_adamw_tree``, also in
+the op registry): no config key selects it and the engine never calls it.
 
 All optimizers are optax ``GradientTransformation``s so they compose with
 clipping, loss scaling, and schedule injection.

@@ -45,7 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.topology import MeshTopology, get_topology

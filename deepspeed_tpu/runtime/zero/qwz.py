@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...ops.quantizer import dequantize_blockwise, quantize_blockwise

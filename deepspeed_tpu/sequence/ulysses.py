@@ -21,7 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import axis_size as _axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import get_topology

@@ -801,6 +801,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="emit serving metrics to a CSVMonitor at this path")
     args = p.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     pool, metrics, cfg = _build_pool_from_args(args)
     pool.start()
     pool.wait_ready(timeout=cfg.spawn_timeout_s)

@@ -536,6 +536,9 @@ def main(argv: Optional[list] = None) -> int:
         if root:
             setattr(args, attr, replica_state_subdir(root, args.name))
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     engine = build_engine_factory(args)()
     rehydrated = engine.rehydrate_coldstore()
     if rehydrated.get("adopted") or rehydrated.get("skipped"):

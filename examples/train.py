@@ -39,7 +39,9 @@ def main() -> int:
     import deepspeed_tpu
     from deepspeed_tpu.models import transformer as tfm
     from deepspeed_tpu.runtime.engine import ModelSpec
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     with open(args.config) as f:
         raw = json.load(f)
     model_cfg_dict = raw.pop("model")

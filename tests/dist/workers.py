@@ -88,7 +88,7 @@ def comm_facade(args: Dict[str, Any]) -> Dict[str, Any]:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from deepspeed_tpu.compat import shard_map
+    from jax import shard_map
 
     from deepspeed_tpu import comm
 

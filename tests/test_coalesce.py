@@ -11,7 +11,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu
-from deepspeed_tpu.compat import shard_map
+from jax import shard_map
 from deepspeed_tpu.runtime.coalesce import (
     DEFAULT_BUCKET_NUMEL, flatten_bucket, flatten_bucket_shard_major,
     plan_buckets, psum_scalars, reduce_bucketed, resolve_bucket_numel,

@@ -50,15 +50,34 @@ def test_stage2_single_fused_reduce_scatter(devices):
     assert c.get("all-reduce", 0) <= 4, census
 
 
+def _emitted_all_reduces(engine) -> int:
+    """All-reduces the step itself emits: counted in the lowered module,
+    before XLA partitions, combines or schedules anything."""
+    batch = {"input_ids": np.zeros((engine.train_batch_size, 32), np.int32)}
+    text = engine._train_step.lower(
+        engine.state, engine._place_batch(batch)).as_text()
+    return text.count("stablehlo.all_reduce")
+
+
 def test_per_leaf_baseline_is_worse(devices):
-    """The lever is real: disabling coalescing multiplies the all-reduce
-    count (one per leaf) — the delta this PR removes."""
-    _, bucketed = _census(dict(BASE, zero_optimization={"stage": 0}))
-    _, per_leaf = _census(dict(BASE, zero_optimization={
+    """The lever is real: with coalescing the step emits one reduction per
+    BUCKET (plus the coalesced metrics psum); without it the step emits
+    none and every gradient leaf is left for the partitioner to reduce on
+    its own — one per leaf, the delta this lever removes.  Asserted on what
+    the program emits and on the bucket plan: the compiled count no longer
+    tells them apart, since XLA's own combiner now merges the per-leaf
+    all-reduces too."""
+    bucketed, _ = _census(dict(BASE, zero_optimization={"stage": 0}))
+    per_leaf, _ = _census(dict(BASE, zero_optimization={
         "stage": 0, "reduce_bucket_size": 0}))
-    n_b = bucketed["collectives"].get("all-reduce", 0)
-    n_p = per_leaf["collectives"].get("all-reduce", 0)
-    assert n_p >= 2 * max(n_b, 1), (bucketed, per_leaf)
+    assert per_leaf._bucket_plan is None
+    stats = bucketed._bucket_plan.stats()
+    n_b = _emitted_all_reduces(bucketed)
+    assert n_b == stats["num_buckets"] + 1, stats
+    assert _emitted_all_reduces(per_leaf) == 0  # all left to the partitioner
+    n_p = stats["num_leaves"]  # one reduction per gradient leaf
+    assert stats["bucketed_leaves"] == n_p
+    assert n_p >= 2 * n_b, (stats, n_b)
 
 
 def test_stage1_coalesced_param_allgather(devices):

@@ -34,6 +34,9 @@ def test_zero_stages_train(devices, stage):
     engine, losses = _train(cfg)
     assert losses[-1] < losses[0] * 0.7, f"stage {stage} loss did not drop: {losses}"
     assert engine.get_global_step() == 12
+    # the state leaves a step with the shardings it came in with, so twelve
+    # steps are one program (step 2 used to compile a second one)
+    assert engine._train_step._cache_size() == 1
 
 
 def test_zero_stage3_params_actually_sharded(devices):

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from deepspeed_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.fused_optimizers import (FusedAdamState, fused_adamw_tree,
