@@ -1,0 +1,5 @@
+"""Kernels: the reader of ``serve_pallas_busy_pct`` under the training cells' name.  A
+metric moves one end-to-end metric and only ``setup_s`` is in every cell, so
+what is read in all four cells exists once a kind of cell."""
+
+from benchmark.layer_metrics.serve_pallas_busy_pct import read  # noqa: F401

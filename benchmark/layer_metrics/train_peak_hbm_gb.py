@@ -1,0 +1,5 @@
+"""Device: the reader of ``serve_peak_hbm_gb`` under the training cells' name.  A
+metric moves one end-to-end metric and only ``setup_s`` is in every cell, so
+what is read in all four cells exists once a kind of cell."""
+
+from benchmark.layer_metrics.serve_peak_hbm_gb import read  # noqa: F401
