@@ -48,6 +48,20 @@ def _memo(key, build):
     return _BUILD_CACHE[key]
 
 
+# What a step's body hands back to ``step``: the tokens by uid, the tokens in
+# its batch, and ``device_ms`` (None where it dispatched nothing).
+_StepResult = Tuple[Dict[int, List[int]], int, Optional[float]]
+
+
+def _device_ms(sp_dispatch, sp_wait) -> Optional[float]:
+    """Milliseconds from the start of ``engine/dispatch`` to the end of
+    ``engine/wait``: host clocks round the first enqueue and the token fetch,
+    between which the device is presumed busy.  None with tracing off."""
+    if sp_wait is None:
+        return None
+    return (sp_wait.t_end - sp_dispatch.t_start) * 1e3
+
+
 class AdmissionError(ValueError):
     """A request cannot be admitted: the prompt+budget exceeds the maximum
     context, or (``put(strict=True)``) no sequence slot / KV block budget is
@@ -132,11 +146,13 @@ def sample_rows(logits, temps, rng, seeds):
     ``categorical(logits / temp)`` under their own fold_in key.  Both lanes
     are computed and selected with ``jnp.where`` — no host sync, no
     per-row control flow."""
-    greedy = logits.argmax(-1).astype(jnp.int32)
-    keys = _row_keys(rng, seeds)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope("sampler"):
+        greedy = logits.argmax(-1).astype(jnp.int32)
+        keys = _row_keys(rng, seeds)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.vmap(jax.random.categorical)(
+            keys, scaled).astype(jnp.int32)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 # ---------------------------------------------------------------------------
@@ -325,21 +341,27 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 # apply_rope expects (B,S,H,D); use batch dim 1
                 q = tfm.apply_rope(q[None], cos, sin)[0]
                 k = tfm.apply_rope(k[None], cos, sin)[0]
-            k_cache = k_cache.at[blk_ids, offsets].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[blk_ids, offsets].set(v.astype(v_cache.dtype))
+            with jax.named_scope("cache_write"):
+                k_cache = k_cache.at[blk_ids, offsets].set(
+                    k.astype(k_cache.dtype))
+                v_cache = v_cache.at[blk_ids, offsets].set(
+                    v.astype(v_cache.dtype))
             # chunked-prefill attention over paged KV: reorganize the ragged
             # (T, H, D) q into per-sequence chunks and run the paged Pallas
             # prefill kernel — never materializes the old (T, S_max, KV, D)
             # per-token gather
             from ...ops.pallas.paged_attention import paged_prefill_attention
 
-            q_seq = jnp.zeros((block_tables.shape[0], Qp, nh, hd), q.dtype)
-            q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
-            o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
-                                            block_tables, chunk_start,
-                                            chunk_len)
-            # padding rows read in-range garbage (clamped col), dropped later
-            o = o_seq[gath_row, gath_col]  # (T, H, D)
+            with jax.named_scope("prefill_attention"):
+                q_seq = jnp.zeros((block_tables.shape[0], Qp, nh, hd),
+                                  q.dtype)
+                q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
+                o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
+                                                block_tables, chunk_start,
+                                                chunk_len)
+                # padding rows read in-range garbage (clamped col), dropped
+                # later
+                o = o_seq[gath_row, gath_col]  # (T, H, D)
             o_flat = o.reshape(T, nh * hd)
             attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
             if "wo" in ad:
@@ -374,23 +396,23 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 {"k": new_k, "v": new_v})
 
     if v2.adapter_slots:
-        def fwd(params, caches, token_ids, position_ids, seq_index,
-                block_tables, context_lens, logits_rows, chunk_start,
-                chunk_len, adapters, row_adapter):
+        def mixed_step(params, caches, token_ids, position_ids, seq_index,
+                       block_tables, context_lens, logits_rows, chunk_start,
+                       chunk_len, adapters, row_adapter):
             return fwd_body(params, caches, token_ids, position_ids,
                             seq_index, block_tables, context_lens,
                             logits_rows, chunk_start, chunk_len,
                             adapters=adapters, row_adapter=row_adapter)
     else:
-        def fwd(params, caches, token_ids, position_ids, seq_index,
-                block_tables, context_lens, logits_rows, chunk_start,
-                chunk_len):
+        def mixed_step(params, caches, token_ids, position_ids, seq_index,
+                       block_tables, context_lens, logits_rows, chunk_start,
+                       chunk_len):
             return fwd_body(params, caches, token_ids, position_ids,
                             seq_index, block_tables, context_lens,
                             logits_rows, chunk_start, chunk_len)
 
     return _memo(("ragged_fwd", model_cfg, dataclasses.astuple(v2)),
-                 lambda: jax.jit(fwd, donate_argnums=(1,)))
+                 lambda: jax.jit(mixed_step, donate_argnums=(1,)))
 
 
 def build_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
@@ -404,23 +426,24 @@ def build_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
     program (the ``decode_step@v2`` budget proves it)."""
 
     if v2.adapter_slots:
-        def fwd(params, caches, token_ids, position_ids, block_tables,
-                context_lens, temps, rng, seeds, adapters, row_adapter):
+        def decode_step(params, caches, token_ids, position_ids,
+                        block_tables, context_lens, temps, rng, seeds,
+                        adapters, row_adapter):
             logits, caches = _decode_body(
                 params, caches, token_ids, position_ids, block_tables,
                 context_lens, model_cfg, v2, adapters=adapters,
                 row_adapter=row_adapter)
             return sample_rows(logits, temps, rng, seeds), caches
     else:
-        def fwd(params, caches, token_ids, position_ids, block_tables,
-                context_lens, temps, rng, seeds):
+        def decode_step(params, caches, token_ids, position_ids,
+                        block_tables, context_lens, temps, rng, seeds):
             logits, caches = _decode_body(params, caches, token_ids,
                                           position_ids, block_tables,
                                           context_lens, model_cfg, v2)
             return sample_rows(logits, temps, rng, seeds), caches
 
     return _memo(("decode_fwd", model_cfg, dataclasses.astuple(v2)),
-                 lambda: jax.jit(fwd, donate_argnums=(1,)))
+                 lambda: jax.jit(decode_step, donate_argnums=(1,)))
 
 
 def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config,
@@ -460,20 +483,21 @@ def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config,
         return toks, caches
 
     if v2.adapter_slots:
-        def fwd(params, caches, token_ids, position_ids, block_tables,
-                context_lens, rng, temps, seeds, adapters, row_adapter):
+        def multi_decode_step(params, caches, token_ids, position_ids,
+                              block_tables, context_lens, rng, temps, seeds,
+                              adapters, row_adapter):
             return fwd_body(params, caches, token_ids, position_ids,
                             block_tables, context_lens, rng, temps, seeds,
                             adapters=adapters, row_adapter=row_adapter)
     else:
-        def fwd(params, caches, token_ids, position_ids, block_tables,
-                context_lens, rng, temps, seeds):
+        def multi_decode_step(params, caches, token_ids, position_ids,
+                              block_tables, context_lens, rng, temps, seeds):
             return fwd_body(params, caches, token_ids, position_ids,
                             block_tables, context_lens, rng, temps, seeds)
 
     return _memo(("multi_decode", model_cfg, dataclasses.astuple(v2),
                   num_steps),
-                 lambda: jax.jit(fwd, donate_argnums=(1,)))
+                 lambda: jax.jit(multi_decode_step, donate_argnums=(1,)))
 
 
 def build_cow_copy():
@@ -484,13 +508,13 @@ def build_cow_copy():
     (prefill overwrites the chunk before attention, and keys beyond
     ``context_lens`` are masked)."""
 
-    def copy_block(caches, src, dst):
+    def cow_copy(caches, src, dst):
         k, v = caches["k"], caches["v"]
         return {"k": k.at[:, dst].set(k[:, src]),
                 "v": v.at[:, dst].set(v[:, src])}
 
     return _memo(("cow_copy",),
-                 lambda: jax.jit(copy_block, donate_argnums=(0,)))
+                 lambda: jax.jit(cow_copy, donate_argnums=(0,)))
 
 
 def _decode_body(params, caches, token_ids, position_ids, block_tables,
@@ -559,10 +583,14 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
                 return jnp.concatenate([out, t[..., rd:]], axis=-1)
 
             q, k = rot(q), rot(k)
-        k_cache = k_cache.at[blk_ids, offsets].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[blk_ids, offsets].set(v.astype(v_cache.dtype))
-        o = paged_decode_attention(q, k_cache, v_cache, block_tables,
-                                   context_lens)
+        with jax.named_scope("cache_write"):
+            k_cache = k_cache.at[blk_ids, offsets].set(
+                k.astype(k_cache.dtype))
+            v_cache = v_cache.at[blk_ids, offsets].set(
+                v.astype(v_cache.dtype))
+        with jax.named_scope("decode_attention"):
+            o = paged_decode_attention(q, k_cache, v_cache, block_tables,
+                                       context_lens)
         o_flat = o.reshape(S, nh * hd)
         attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
         if "wo" in ad:
@@ -686,6 +714,7 @@ class InferenceEngineV2:
             self.cfg.max_seqs, self.cfg.max_blocks_per_seq,
             self.cfg.max_blocks_per_seq * self.cfg.block_size)
         self._prefilling = 0  # running seqs still before their first token
+        self.steps = 0  # step() calls so far: the spans' ``step``
         self.fast_steps = 0  # telemetry: SoA decode steps taken
         self.burst_steps = 0  # telemetry: multi-token burst programs run
         self._uid = 0
@@ -1434,25 +1463,33 @@ class InferenceEngineV2:
             self._finish(t.seq_at[int(r)])
         return rows
 
-    def _decode_step_fast(self, temperature: float,
-                          rng: Optional[jax.Array]) -> Dict[int, List[int]]:
+    def _decode_step_fast(self, temperature: float, rng: Optional[jax.Array],
+                          sub: Dict[str, Any]) -> _StepResult:
         """Steady-state decode: inputs ARE the table arrays; bookkeeping is
         vectorized; Python touches only sequences that just completed."""
         self.fast_steps += 1
         t = self.table
-        toks, self.caches = self._decode_fwd(
-            self.params, self.caches, *self._table_inputs(),
-            self._row_temps(temperature), self._step_rng(rng),
-            jnp.asarray(t.seed), *self._adapter_args())
+        sp = tracer.begin("engine/h2d", **sub)
+        args = (*self._table_inputs(), self._row_temps(temperature),
+                self._step_rng(rng), jnp.asarray(t.seed),
+                *self._adapter_args())
+        tracer.end(sp)
+        sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        toks, self.caches = self._decode_fwd(self.params, self.caches, *args)
+        tracer.end(sp_dispatch)
+        sp_wait = tracer.begin("engine/wait", **sub)
         sampled = np.asarray(toks)
+        tracer.end(sp_wait)
+        sp = tracer.begin("engine/finish", **sub)
         rows = np.nonzero(t.active)[0]
         sel = sampled[rows].astype(np.int32)[None, :]  # (1, ns)
         out = {t.seq_at[int(r)].uid: [int(s)] for r, s in zip(rows, sel[0])}
         self._advance_rows(sel)
-        return out
+        tracer.end(sp)
+        return out, len(rows), _device_ms(sp_dispatch, sp_wait)
 
-    def _spec_decode_step(self, temperature: float,
-                          rng: Optional[jax.Array]) -> Dict[int, List[int]]:
+    def _spec_decode_step(self, temperature: float, rng: Optional[jax.Array],
+                          sub: Dict[str, Any]) -> _StepResult:
         """Steady-state SPECULATIVE decode: one jitted propose→verify→accept
         program emits 1..k+1 tokens per sequence.  The host reads back only
         the emitted tokens + accept lengths; rejected-suffix KV needs no
@@ -1461,31 +1498,42 @@ class InferenceEngineV2:
         self.fast_steps += 1
         self.spec_steps += 1
         t = self.table
+        self_draft = self.cfg.spec_mode == "self_draft"
+        sp = tracer.begin("engine/h2d", **sub)
         rng = self._step_rng(rng)
         next_tok, ctx, block_tables, _ = self._table_inputs()
         limit = jnp.asarray(t.limit)
         temps = self._row_temps(temperature)
         seeds = jnp.asarray(t.seed)
+        hidden = jnp.asarray(self._spec_hidden) if self_draft else None
+        tracer.end(sp)
         hidden_np = None
-        if self.cfg.spec_mode == "self_draft":
+        sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        if self_draft:
             emitted, alen, new_hidden, self.caches = self._spec_fwd(
                 self.params, self.spec_heads, self.caches, next_tok, ctx,
-                block_tables, limit, jnp.asarray(self._spec_hidden), rng,
+                block_tables, limit, hidden, rng,
                 temps, seeds, *self._adapter_args())
-            hidden_np = np.asarray(new_hidden)
         else:
             emitted, alen, self.caches, self._draft_caches = self._spec_fwd(
                 self.params, self.draft_params, self.caches,
                 self._draft_caches, next_tok, ctx, block_tables, limit, rng,
                 temps, seeds)
+        tracer.end(sp_dispatch)
+        sp_wait = tracer.begin("engine/wait", **sub)
+        if self_draft:
+            hidden_np = np.asarray(new_hidden)
         emitted = np.asarray(emitted)  # (max_seqs, k+1)
         alen = np.asarray(alen)
+        tracer.end(sp_wait)
+        sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
         k = self.cfg.spec_k
+        active = np.nonzero(t.active)[0]
         # per-row Python loop: rows advance by DIFFERENT amounts (accept
         # length), so the vectorized _advance_rows contract doesn't apply;
         # the loop body is a handful of scalar ops per ACTIVE row only
-        for r in np.nonzero(t.active)[0]:
+        for r in active:
             r = int(r)
             seq = t.seq_at[r]
             # never emit past the request budget: the verify forward parks
@@ -1506,7 +1554,8 @@ class InferenceEngineV2:
             self.spec_emitted += take
             if t.gen[r] >= t.budget[r]:
                 self._finish(seq)
-        return out
+        tracer.end(sp)
+        return out, len(active), _device_ms(sp_dispatch, sp_wait)
 
     def step(self, temperature: float = 0.0, rng: Optional[jax.Array] = None
              ) -> Dict[int, List[int]]:
@@ -1515,25 +1564,35 @@ class InferenceEngineV2:
         paths emit exactly one token per sequence; speculative steady-state
         steps emit 1..spec_k+1.
 
-        Instrumentation is host-side only (a span + flight-recorder append
+        Instrumentation is host-side only (spans + a flight-recorder append
         around the untouched step body), so tracing provably changes no
-        compiled program."""
+        compiled program.  ``engine/step`` has a child span per phase
+        (``engine/schedule``, ``build``, ``h2d``, ``dispatch``, ``sample``,
+        ``wait``, ``finish``), each carrying ``kind`` and ``step``, and
+        itself ends with ``device_ms`` (first enqueue to fetch: host clocks,
+        the device *presumed* busy between them), ``tokens`` and ``budget``,
+        so a reader needs no join."""
         steady = (not self.waiting and self.running
                   and self._prefilling == 0)
         kind = (("spec" if self._spec_fwd is not None else "decode")
                 if steady else "mixed")
         running, waiting = self.num_running, len(self.waiting)
         prop0, acc0 = self.spec_proposed, self.spec_accepted
+        self.steps += 1
+        sub = {"kind": kind, "step": self.steps}  # on the step and its children
         t0 = time.monotonic()
-        sp = tracer.begin("engine/step", kind=kind, running=running,
-                          waiting=waiting, prefilling=self._prefilling)
+        sp = tracer.begin("engine/step", running=running, waiting=waiting,
+                          prefilling=self._prefilling, **sub)
         try:
-            out = self._step_impl(temperature=temperature, rng=rng)
+            out, tokens, device_ms = self._step_impl(temperature, rng, sub)
         except Exception:
             tracer.end(sp, error=True)
             raise
         emitted = sum(len(v) for v in out.values())
-        attrs = {"emitted": emitted}
+        attrs = {"emitted": emitted, "tokens": tokens,
+                 "budget": self.cfg.max_tokens_per_step}
+        if device_ms is not None:  # the step reached the device, tracing on
+            attrs["device_ms"] = device_ms
         if kind == "spec":
             attrs["proposed"] = self.spec_proposed - prop0
             attrs["accepted"] = self.spec_accepted - acc0
@@ -1546,24 +1605,31 @@ class InferenceEngineV2:
                 if kind == "spec" else {})})
         return out
 
-    def _step_impl(self, temperature: float = 0.0,
-                   rng: Optional[jax.Array] = None) -> Dict[int, List[int]]:
+    def _step_impl(self, temperature: float, rng: Optional[jax.Array],
+                   sub: Dict[str, Any]) -> _StepResult:
+        """The step body.  ``sub`` is what each of its spans carries."""
         if not self.waiting and self.running and self._prefilling == 0:
             # steady state: every running sequence is decoding — SoA path
             if self._spec_fwd is not None:
-                return self._spec_decode_step(temperature, rng)
-            return self._decode_step_fast(temperature, rng)
+                return self._spec_decode_step(temperature, rng, sub)
+            return self._decode_step_fast(temperature, rng, sub)
+        sp = tracer.begin("engine/schedule", **sub)
         self._flush_table()
         picks = self._schedule()
+        tokens = sum(n for _, n in picks)
+        tracer.end(sp)
         if not picks:
             if self.running:
                 raise RuntimeError(
                     "scheduler made no progress with running sequences — "
                     "KV reservation invariant violated (bug)")
-            return {}
+            return {}, 0, None
         if self._spec_fwd is not None:
             self.spec_fallback += 1  # prefill/mixed step: no speculation
+        sp = tracer.begin("engine/build", **sub)
         batch = self.builder.build(picks)
+        tracer.end(sp)
+        sp = tracer.begin("engine/h2d", **sub)
         batch_args = (
             jnp.asarray(batch.token_ids), jnp.asarray(batch.position_ids),
             jnp.asarray(batch.seq_index), jnp.asarray(batch.block_tables),
@@ -1577,6 +1643,8 @@ class InferenceEngineV2:
             for row, (seq, _) in enumerate(picks):
                 row_ad[row] = seq.adapter_slot
             ad_args = (self.adapter_stack, jnp.asarray(row_ad))
+        tracer.end(sp)
+        sp_dispatch = tracer.begin("engine/dispatch", **sub)
         logits, hidden, self.caches = self._fwd(
             self.params, self.caches, *batch_args, *ad_args)
         if self.cfg.spec_mode == "draft":
@@ -1585,20 +1653,27 @@ class InferenceEngineV2:
             # position ctx without ever re-prefilling
             _, _, self._draft_caches = self._draft_fwd(
                 self.draft_params, self._draft_caches, *batch_args)
+        tracer.end(sp_dispatch)
         # per-row selection mirrors the jitted decode path: pick rows carry
         # their request's pinned temperature/seed, padding rows stay greedy
+        # (its inputs are made after the dispatch, while the device works)
+        sp = tracer.begin("engine/sample", **sub)
         temps = np.zeros(self.cfg.max_seqs, np.float32)
         seeds = np.zeros(self.cfg.max_seqs, np.int32)
         for row, (seq, _) in enumerate(picks):
             temps[row] = (temperature if seq.temperature is None
                           else seq.temperature)
             seeds[row] = np.int32(np.uint32(seq.seed & 0xFFFFFFFF))
-        sampled = np.asarray(sample_rows(logits, jnp.asarray(temps),
-                                         self._step_rng(rng),
-                                         jnp.asarray(seeds)))
+        sampled = sample_rows(logits, jnp.asarray(temps),
+                              self._step_rng(rng), jnp.asarray(seeds))
+        tracer.end(sp)
+        sp_wait = tracer.begin("engine/wait", **sub)
+        sampled = np.asarray(sampled)
         hidden_np = (np.asarray(hidden)
                      if self.cfg.spec_mode == "self_draft" else None)
+        tracer.end(sp_wait)
 
+        sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
         for row, (seq, n) in enumerate(picks):
             seq.seen_tokens += n
@@ -1619,7 +1694,8 @@ class InferenceEngineV2:
                         hidden_np[row]
             if seq.uid in self.table.row_of:
                 self.table.sync(seq)
-        return out
+        tracer.end(sp)
+        return out, tokens, _device_ms(sp_dispatch, sp_wait)
 
     def _burst_decode(self, k: int, temperature: float = 0.0,
                       rng: Optional[jax.Array] = None) -> None:

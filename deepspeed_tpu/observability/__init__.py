@@ -4,11 +4,17 @@ recorder, and first-class Prometheus exposition.
 Exceeds the reference DeepSpeed, which ships a monitor fan-out
 (``deepspeed/monitor``) and a comms logger but nothing request-scoped:
 
-* :mod:`.trace` — always-on span tracer (thread-safe ring buffer, host-side
-  only, Chrome/Perfetto export) threaded through the whole request
-  lifecycle: broker submit→queue→admit→prefill→decode/spec→finish, engine
-  steps with batch-composition attrs, checkpoint save/load, elastic-agent
-  relaunches, comm-collective timings;
+* :mod:`.trace` — always-on span tracer (thread-safe ring buffer,
+  Chrome/Perfetto export; live spans are also ``jax.profiler``
+  annotations, so a profile shows them beside the device's operations)
+  threaded through the whole request lifecycle: broker
+  submit→queue→admit→prefill→decode/spec→finish→first SSE write, the
+  broker loop's turns (emit, admit) and idle waits, engine steps with
+  batch-composition attrs and their children (schedule, build, h2d,
+  dispatch, sample, wait, finish), KV paging and adapter moves,
+  checkpoint save/load, elastic-agent relaunches, replica lifecycle
+  events, comm-collective timings (``PERF.md`` lists every name with its
+  reader);
 * :mod:`.recorder` — flight recorder: bounded rings of the last N request
   timelines / M engine steps / K infra events, dumped to
   ``$DSTPU_FLIGHT_DIR`` on crash or injected fault;
@@ -21,7 +27,8 @@ Exceeds the reference DeepSpeed, which ships a monitor fan-out
 
 Server surfaces (``serving/server.py``): ``GET /debug/requests`` (recent
 timelines), ``GET /debug/trace`` (Perfetto JSON), ``GET /debug/profile``
-(on-demand ``jax.profiler`` capture).  CLI:
+(on-demand ``jax.profiler`` capture: device operations and the program's
+live spans on one clock).  CLI:
 ``python -m deepspeed_tpu.observability <flight-dump.json>``.
 
 Tracing never enters a jitted computation, so the analysis budgets
@@ -35,13 +42,13 @@ from .recorder import FlightRecorder, load_dump, recorder
 from .replay import (SLOError, SLOViolation, WorkloadCapture, WorkloadError,
                      WorkloadRequest, check_slo, load_slos, load_workload,
                      replay_workload, save_workload, synthesize_workload)
-from .trace import Span, Tracer, add_event, add_span, span, tracer
+from .trace import Span, Tracer, tracer
 
 __all__ = [
     "DEFAULT_MS_BUCKETS", "ExpositionBuilder", "ExpositionError",
     "FlightRecorder", "Histogram", "SLOError", "SLOViolation", "Span",
     "Tracer", "WorkloadCapture", "WorkloadError", "WorkloadRequest",
-    "add_event", "add_span", "check_slo", "load_dump", "load_slos",
+    "check_slo", "load_dump", "load_slos",
     "load_workload", "parse_exposition", "recorder", "replay_workload",
-    "save_workload", "span", "synthesize_workload", "tracer",
+    "save_workload", "synthesize_workload", "tracer",
 ]

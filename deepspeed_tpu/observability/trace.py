@@ -1,4 +1,5 @@
-"""Span-based request tracer: a thread-safe ring buffer of host-side spans.
+"""Span-based request tracer: a thread-safe ring buffer of host-side spans,
+mirrored onto the JAX profiler's clock.
 
 The serving stack's aggregate gauges (``serving/metrics.py``) say *how much*;
 this module says *where the time went* for one request or one engine step.
@@ -9,12 +10,23 @@ request's ``rid``), ``parent_id`` nests them.
 Design constraints (ISSUE 9):
 
 * **always-on and cheap** — recording a span is two ``time.monotonic()``
-  calls, one small dict, and one deque append under a lock.  No sampling
-  daemon, no network, no allocation spikes.  ``DSTPU_TRACE=0`` disables it
-  entirely (context managers become no-ops).
-* **host-side only** — nothing here is ever called from inside a jitted
-  computation, so enabling tracing provably changes no compiled program:
-  the analysis budgets (zero host syncs, HLO identity) hold with tracing on.
+  calls, one small dict, one deque append under a lock, and (for a span
+  opened and closed live, see below) one ``jax.profiler.TraceAnnotation``,
+  which costs a flag test while no profile is being taken.  No sampling
+  daemon, no network, no allocation spikes.  ``DSTPU_TRACE=0`` disables
+  all of it (context managers become no-ops).
+* **one mechanism, two clocks** — a span opened with :meth:`Tracer.span`
+  or a :meth:`Tracer.begin` / :meth:`Tracer.end` pair also enters and
+  leaves a ``TraceAnnotation`` of the same name carrying its small scalar
+  attributes, so in any ``jax.profiler`` capture (``GET /debug/profile``)
+  it lies on the ``/host:CPU`` plane beside the device's operations, on the
+  profiler's clock.  Retroactive :meth:`Tracer.add_span` /
+  :meth:`Tracer.add_event` have no live interval to annotate and stay
+  ring-only.  ``jax.profiler`` is imported at the first live span, so
+  importing this module starts no backend.
+* **never inside a jitted computation** — spans are recorded by host code
+  only, so enabling tracing provably changes no compiled program: the
+  analysis budgets (zero host syncs, HLO identity) hold with tracing on.
 * **bounded** — the ring keeps the most recent ``capacity`` spans; old spans
   fall off the back.  Postmortem durability is the flight recorder's job
   (``observability/recorder.py``), not the ring's.
@@ -30,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import json
 import os
 import threading
 import time
@@ -41,6 +52,23 @@ from typing import Any, Deque, Dict, Iterator, List, Optional
 from ..utils.locks import named_lock
 
 _ENV = "DSTPU_TRACE"
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, at the first live span
+
+
+def _annotate(name: str, attrs: Dict[str, Any]):
+    """Enter a ``jax.profiler.TraceAnnotation`` named like the span, with
+    those of its attributes that are small scalars."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    ann = _TraceAnnotation(name, **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float))
+        or (isinstance(v, str) and len(v) <= 64)})
+    ann.__enter__()
+    return ann
 
 
 @dataclasses.dataclass
@@ -61,6 +89,9 @@ class Span:
     pid: Optional[int] = None
     process: str = ""
     seq: int = 0
+    # the live span's jax.profiler.TraceAnnotation, entered at begin()
+    annotation: Any = dataclasses.field(default=None, repr=False,
+                                        compare=False)
 
     @property
     def duration_s(self) -> float:
@@ -116,26 +147,36 @@ class Tracer:
                   span_id=next(self._ids), parent_id=parent_id,
                   t_start=time.monotonic(), t_end=None, attrs=attrs,
                   thread=threading.current_thread().name)
+        sp.annotation = _annotate(name, attrs)
         stack.append(sp)
         return sp
 
     def end(self, sp: Optional[Span], **attrs: Any) -> None:
+        """Close a span opened by :meth:`begin`, adding ``attrs`` (which the
+        ring keeps; the profiler's annotation has only those of ``begin``).
+        Children that an exception left open on this thread are closed with
+        it, innermost first, marked ``error``: the phase that failed stays
+        in the ring and no annotation stays open."""
         if sp is None:
             return
-        sp.t_end = time.monotonic()
+        now = time.monotonic()
         if attrs:
             sp.attrs.update(attrs)
         stack = self._stack()
-        if stack and stack[-1] is sp:
-            stack.pop()
-        else:  # out-of-order end (cross-thread misuse): drop if present
-            try:
-                stack.remove(sp)
-            except ValueError:
-                pass
+        closing = [sp]
+        if sp in stack:
+            while (child := stack.pop()) is not sp:
+                child.attrs["error"] = True
+                closing.insert(-1, child)
+        for s in closing:
+            s.t_end = now
+            ann, s.annotation = s.annotation, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
         with self._lock:
-            sp.seq = next(self._seq)
-            self._ring.append(sp)
+            for s in closing:
+                s.seq = next(self._seq)
+                self._ring.append(s)
 
     @contextmanager
     def span(self, name: str, trace_id: Optional[str] = None,
@@ -291,26 +332,7 @@ class Tracer:
                 "otherData": {"wall_zero": self.wall_zero,
                               "mono_zero": self.mono_zero}}
 
-    def to_chrome_json(self) -> str:
-        return json.dumps(self.to_chrome_trace())
-
 
 #: process-wide tracer every subsystem records into
 tracer = Tracer()
 
-
-@contextmanager
-def span(name: str, trace_id: Optional[str] = None,
-         **attrs: Any) -> Iterator[Optional[Span]]:
-    """Module-level shorthand for ``tracer.span(...)``."""
-    with tracer.span(name, trace_id=trace_id, **attrs) as sp:
-        yield sp
-
-
-def add_span(name: str, t_start: float, t_end: float, **kw) -> Optional[Span]:
-    return tracer.add_span(name, t_start, t_end, **kw)
-
-
-def add_event(name: str, trace_id: Optional[str] = None,
-              attrs: Optional[Dict[str, Any]] = None) -> Optional[Span]:
-    return tracer.add_event(name, trace_id=trace_id, attrs=attrs)

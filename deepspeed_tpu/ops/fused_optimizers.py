@@ -85,6 +85,7 @@ def fused_adamw_flat(params: jax.Array, grads: jax.Array, m: jax.Array,
             jax.ShapeDtypeStruct(shape2d, jnp.float32),
         ],
         interpret=backend.interpret(),
+        name="fused_adamw",
     )(*args, bias_correction)
     p_new, m_new, v_new = (o.reshape(-1)[:n] for o in out)
     return p_new, m_new, v_new

@@ -211,9 +211,10 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         lse_ref[0, 0] = jnp.where(l == 0.0, -jnp.inf, lse)
 
 
-def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
-                 mask_tab, inputs):
-    """Dispatch with or without the scalar-prefetched block-mask table."""
+def _pallas_call(name, kernel, grid, in_specs, out_specs, out_shape,
+                 scratch_shapes, mask_tab, inputs):
+    """Dispatch with or without the scalar-prefetched block-mask table;
+    ``name`` is the kernel's name in the compiled program and the trace."""
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     if mask_tab is not None:
@@ -222,12 +223,13 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
             out_specs=out_specs, scratch_shapes=scratch_shapes)
         return pl.pallas_call(kernel, grid_spec=grid_spec,
                               out_shape=out_shape, compiler_params=params,
-                              interpret=backend.interpret())(mask_tab, *inputs)
+                              interpret=backend.interpret(),
+                              name=name)(mask_tab, *inputs)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch_shapes,
         compiler_params=params,
-        interpret=backend.interpret())(*inputs)
+        interpret=backend.interpret(), name=name)(*inputs)
 
 
 def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
@@ -273,6 +275,7 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
     ]
     inputs += [q, k, v]
     out, lse = _pallas_call(
+        "flash_attention_fwd",
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window,
                           has_mask=mask_tab is not None, has_seg=has_seg,
@@ -444,6 +447,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     ]
     inputs += [q, k, v, g, lse, delta]
     dk, dv = _pallas_call(
+        "flash_attention_bwd_dkv",
         functools.partial(_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq,
                           window=window, has_mask=mask_tab is not None,
@@ -487,6 +491,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     ]
     inputs += [q, k, v, g, lse, delta]
     dq = _pallas_call(
+        "flash_attention_bwd_dq",
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window,
                           has_mask=mask_tab is not None, has_seg=has_seg),

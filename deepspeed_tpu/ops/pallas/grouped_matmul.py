@@ -87,6 +87,7 @@ def _gmm_pallas(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
             scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        name="grouped_matmul",
     )(tile_group, lhs, rhs)
 
 

@@ -233,6 +233,7 @@ def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, tm: int, tn: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret(),
+        name="mixed_gemm",
     )(x2, qw.codes, qw.scales[:, None, :])
 
 
@@ -359,6 +360,7 @@ def int8_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend.interpret(),
+        name="int8_gemm",
     )(codes, scales.T[:, :, None], qw.codes, qw.scales[:, None, :])
     if pad_m:
         out = out[:M]

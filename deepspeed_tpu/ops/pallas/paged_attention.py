@@ -194,6 +194,7 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=backend.interpret(),
+        name="paged_attention_decode",
     )(block_tables, context_lens, q, k_cache, v_cache)
 
 
@@ -395,4 +396,5 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Qp, H, D), q.dtype),
         interpret=backend.interpret(),
+        name="paged_attention_prefill",
     )(block_tables, chunk_start, chunk_len, q, k_cache, v_cache)

@@ -59,6 +59,7 @@ def tiled_mlp(x: jax.Array, p: Dict[str, Any], cfg, tile_size: int) -> jax.Array
     return tiled_map(lambda t: _mlp_block(t, p, cfg), x, tile_size, axis=1)
 
 
+@jax.named_scope("tiled_loss")  # the trace's op_name says whose these are
 def tiled_logits_loss(x: jax.Array, embed_or_head: jax.Array,
                       labels: jax.Array, tile_size: int,
                       mask: Optional[jax.Array] = None,

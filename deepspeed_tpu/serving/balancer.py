@@ -89,6 +89,16 @@ class BalancedHandle:
     def prompt(self) -> List[int]:
         return self._handle.prompt
 
+    @property
+    def trace_id(self) -> str:
+        return getattr(self._handle, "trace_id", self._handle.rid)
+
+    @property
+    def first_token_ts(self) -> Optional[float]:
+        """The broker's first-token time on this process's monotonic clock;
+        None before it, and for a replica in another process."""
+        return getattr(self._handle, "first_token_ts", None)
+
     def cancel(self) -> None:
         self._cancelled = True
         self._handle.cancel()

@@ -143,6 +143,17 @@ class RequestHandle:
     def prompt(self) -> List[int]:
         return self._req.prompt
 
+    @property
+    def trace_id(self) -> str:
+        return self._req.trace_id or self._req.rid
+
+    @property
+    def first_token_ts(self) -> Optional[float]:
+        """When the engine thread handed over the first token
+        (``time.monotonic()``): the HTTP thread measures its first write
+        from here (``request/first_write``)."""
+        return self._req.first_token_ts
+
     def cancel(self) -> None:
         self._broker.cancel(self._req.rid)
 
@@ -644,6 +655,12 @@ class RequestBroker:
                         self._finalize_locked(req, "length")
 
     def _run(self) -> None:
+        # ``broker/turn``: the loop's host work between two engine steps,
+        # from one step's return to the next one's call (``next="step"``)
+        # or to where nothing is left to run (``next="idle"``).
+        # ``broker/idle``: from there until there is work again, one span
+        # however often the wait wakes.
+        turn = idle = None
         try:
             while True:
                 with self._wake:
@@ -651,11 +668,16 @@ class RequestBroker:
                         self._fail_all_locked(self._dead)
                         return
                     now = time.monotonic()
+                    # an idle loop with an empty queue admits nothing and
+                    # records nothing (it turns every ``idle_wait_s``)
+                    sp = (tracer.begin("broker/admit")
+                          if turn is not None or self._queue else None)
                     self._apply_cancels_locked()
                     self._shed_deadlines_locked(now)
                     if not (self._stop and not self._drain):
                         self._admit_locked(now)
                     self._reap_terminal_locked()
+                    tracer.end(sp)
                     has_work = bool(self.engine.running or
                                     self.engine.waiting or self._queue)
                     self.last_progress_ts = now
@@ -675,12 +697,22 @@ class RequestBroker:
                             if self.adapters is not None:
                                 self.metrics.set_adapter_stats(
                                     self.adapters.stats())
+                        if idle is None:
+                            tracer.end(turn, next="idle")
+                            turn = None
+                            idle = tracer.begin("broker/idle")
                         self._wake.wait(self.cfg.idle_wait_s)
                         continue
                 # JAX outside the lock: submit/cancel stay non-blocking
                 faults.maybe_fail("serving.step")
+                tracer.end(idle)
+                tracer.end(turn, next="step")
+                turn = idle = None
                 out = self.engine.step(temperature=self.cfg.temperature)
+                turn = tracer.begin("broker/turn")
+                sp = tracer.begin("broker/emit")
                 self._dispatch(out, time.monotonic())
+                tracer.end(sp)
                 if self._own_gauges:
                     self.metrics.set_gauges(
                         len(self._queue), self.engine.num_running,
@@ -698,6 +730,10 @@ class RequestBroker:
                 self._dead = f"engine_error: {e!r}"
                 self._fail_all_locked("engine_error")
         finally:
+            # a loop that stops or dies closes what it had open (and with it
+            # a child the fault left open, marked ``error``)
+            tracer.end(idle)
+            tracer.end(turn)
             # release paging-tier resources (promote-ahead thread, spill
             # writer) with the engine thread — nobody else owns the engine
             close = getattr(self.engine, "close", None)

@@ -22,7 +22,8 @@ Endpoints:
   timelines, engine steps, and infra events.
 * ``GET /debug/trace`` — tracer ring as Chrome/Perfetto trace-event JSON
   (load at https://ui.perfetto.dev).
-* ``GET /debug/profile?seconds=N`` — on-demand ``jax.profiler`` capture;
+* ``GET /debug/profile?seconds=N`` — on-demand ``jax.profiler`` capture
+  (device operations, and the program's live spans on the host plane);
   responds with the directory holding the profile.
 
 Backpressure: when every healthy replica's bounded admission queue is full,
@@ -214,9 +215,16 @@ class _Handler(BaseHTTPRequestHandler):
             return
         try:
             out_dir = tempfile.mkdtemp(prefix="dstpu_profile_")
+            # The profiler's Python tracer is off: the program's own spans
+            # (engine/step and its children, broker/turn) name the host's
+            # work, and with it on a mixed step took 5 % longer and its
+            # host spans 1.5 to 2.5 times as long (PERF.md, PR 24).
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             try:
                 with tracer.span("debug/profile", seconds=seconds):
-                    with jax.profiler.trace(out_dir):
+                    with jax.profiler.trace(out_dir,
+                                            profiler_options=options):
                         time.sleep(seconds)
             except Exception as e:  # profiler unavailable on this backend
                 self._error(503, f"profiler failed: {e!r}", "profiler_error")
@@ -357,12 +365,16 @@ class _Handler(BaseHTTPRequestHandler):
         def sse(obj) -> bytes:
             return b"data: " + json.dumps(obj).encode() + b"\n\n"
 
+        first = True
         try:
             try:
                 for tok in handle.tokens():
                     self._chunk(sse(self._completion_obj(
                         handle, self.server.decode([tok]), None,
                         chunk=True, token=tok)))
+                    if first:
+                        first = False
+                        self._record_first_write(handle)
                 final = self._completion_obj(handle, "",
                                              handle.finish_reason or "length",
                                              chunk=True)
@@ -377,6 +389,18 @@ class _Handler(BaseHTTPRequestHandler):
             # client went away mid-stream: the disconnect IS the cancel
             handle.cancel()
             self.close_connection = True
+
+
+    @staticmethod
+    def _record_first_write(handle: BalancedHandle) -> None:
+        """``request/first_write``: from the broker's first token to the
+        first SSE chunk flushed: the hand-off to this thread, the JSON and
+        the socket write.  Once a request; an in-process replica only (a
+        worker process's clock is not this one's)."""
+        t_first = handle.first_token_ts
+        if t_first is not None:
+            tracer.add_span("request/first_write", t_first, time.monotonic(),
+                            trace_id=handle.trace_id)
 
 
 def create_server(pool: ReplicaPool, metrics: ServingMetrics,

@@ -1,6 +1,8 @@
 """Inference-v2 (continuous batching / paged KV) tests
 (reference: tests/unit/inference/v2/)."""
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -424,3 +426,101 @@ def test_serving_scale_fallback_memory_bounded(devices):
     old_decode = 2 * S * MB * BS * H * D * 4
     assert mad.temp_size_in_bytes < old_decode / 8, (
         f"decode fallback temp {mad.temp_size_in_bytes/2**20:.0f} MiB")
+
+
+# ---------------------------------------------------------------------------
+# the step seen from inside: engine/step's children (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+_DECODE_PHASES = ["engine/h2d", "engine/dispatch", "engine/wait",
+                  "engine/finish"]
+_CHILDREN = {
+    "decode": _DECODE_PHASES,
+    "spec": _DECODE_PHASES,
+    "mixed": ["engine/schedule", "engine/build", "engine/h2d",
+              "engine/dispatch", "engine/sample", "engine/wait",
+              "engine/finish"],
+}
+
+
+@pytest.mark.parametrize("kind,over", [
+    ("decode", {}), ("mixed", {}), ("spec", {"spec_mode": "self_draft",
+                                             "spec_k": 2})])
+def test_step_children_nest_in_order(devices, tiny_model, kind, over):
+    """Every ``engine/step`` of the kind has one child per phase, inside its
+    interval and in order, each with the step's ``kind`` and ``step``; the
+    step itself says how long the device was (presumed) busy, and how full
+    its batch was."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg, params = tiny_model
+    eng = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=16, max_seqs=4, block_size=8, num_blocks=64,
+        max_blocks_per_seq=8, dtype="float32", **over))
+    eng.put(list(range(1, 41)), max_new_tokens=4)  # 40 tokens: 3 chunks
+    eng.put([7, 8, 9], max_new_tokens=6)
+    tracer.clear()
+    thread = threading.current_thread().name
+    while eng.running or eng.waiting:
+        eng.step()
+    spans = [s for s in tracer.spans() if s.thread == thread]
+    steps = [s for s in spans
+             if s.name == "engine/step" and s.attrs["kind"] == kind]
+    assert steps, f"no {kind} step ran"
+    numbers = [s.attrs["step"] for s in spans if s.name == "engine/step"]
+    assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    for st in steps:
+        kids = [s for s in spans if s.parent_id == st.span_id]
+        assert [k.name for k in kids] == _CHILDREN[kind]
+        edges = [st.t_start]
+        for k in kids:
+            assert k.attrs["kind"] == kind
+            assert k.attrs["step"] == st.attrs["step"]
+            edges += [k.t_start, k.t_end]
+        edges.append(st.t_end)
+        assert edges == sorted(edges)  # inside the step, one after another
+        assert 0.0 <= st.attrs["device_ms"] <= st.duration_s * 1e3
+        assert st.attrs["budget"] == 16
+        assert 0 < st.attrs["tokens"] <= 16
+        assert st.attrs["device_ms"] == pytest.approx(
+            (kids[-2].t_end - kids[-4 if kind == "mixed" else -3].t_start)
+            * 1e3)  # start of engine/dispatch to end of engine/wait
+        for k in kids:  # a child carries what a reader joins on, no more
+            assert set(k.attrs) == {"kind", "step"}
+    if kind == "mixed":  # the long prompt fills whole chunks of the budget
+        assert max(s.attrs["tokens"] for s in steps) == 16
+
+
+def test_a_failing_phase_stays_in_the_ring(devices, tiny_model):
+    """When the forward raises, ``engine/dispatch`` is closed with its step,
+    both marked ``error``, and the next step's spans nest cleanly."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg, params = tiny_model
+    eng = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=16, max_seqs=4, block_size=8, num_blocks=64,
+        max_blocks_per_seq=8, dtype="float32"))
+    eng.put([7, 8, 9], max_new_tokens=2)
+    fwd = eng._fwd
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("boom")
+
+    eng._fwd = boom
+    tracer.clear()
+    thread = threading.current_thread().name
+    with pytest.raises(RuntimeError, match="boom"):
+        eng.step()
+    eng._fwd = fwd
+    eng.step()
+    spans = [s for s in tracer.spans() if s.thread == thread]
+    first = [s for s in spans if s.attrs["step"] == spans[0].attrs["step"]]
+    assert [(s.name, s.attrs.get("error")) for s in first] == [
+        ("engine/schedule", None), ("engine/build", None),
+        ("engine/h2d", None), ("engine/dispatch", True),
+        ("engine/step", True)]
+    assert first[3].parent_id == first[4].span_id
+    assert first[3].t_end == first[4].t_end and first[3].annotation is None
+    again = spans[-1]
+    assert again.name == "engine/step" and again.parent_id is None
+    assert "error" not in again.attrs and again.attrs["device_ms"] >= 0.0

@@ -11,6 +11,7 @@ The load-bearing guarantees:
 * an injected hard-kill (``DSTPU_FAULTS``) leaves a flight-recorder dump.
 """
 
+import glob
 import http.client
 import json
 import logging
@@ -131,12 +132,92 @@ def test_disabled_tracer_is_noop():
     assert tr.spans() == []
 
 
+def test_env_switch_turns_off_ring_and_annotation(monkeypatch):
+    """``DSTPU_TRACE=0`` is a no-op for both clocks: no span, and no
+    profiler annotation is made."""
+    from deepspeed_tpu.observability import trace as trace_mod
+
+    made = []
+    monkeypatch.setattr(trace_mod, "_annotate",
+                        lambda name, attrs: made.append(name))
+    monkeypatch.setenv("DSTPU_TRACE", "0")
+    off = Tracer()
+    assert not off.enabled
+    off.end(off.begin("x", n=1), more=2)
+    with off.span("y"):
+        pass
+    assert made == [] and off.spans() == []
+    monkeypatch.setenv("DSTPU_TRACE", "1")
+    on = Tracer()
+    with on.span("y", n=1):
+        pass
+    on.add_span("retro", 0.0, 1.0)  # no live interval: ring only
+    assert made == ["y"] and [s.name for s in on.spans()] == ["y", "retro"]
+
+
+def test_end_closes_children_an_exception_left_open():
+    """The phase that raised stays in the ring, closed with its parent and
+    marked ``error``, its profiler annotation is left, and the stack is
+    clean for the next span."""
+    tr = Tracer(enabled=True)
+    outer = tr.begin("outer")
+    child = tr.begin("child-left-open")
+    inner = tr.begin("grandchild-left-open")
+    tr.end(outer, error=True)
+    with tr.span("next") as nxt:
+        assert nxt.parent_id is None  # the stack is clean again
+    spans = tr.spans()
+    assert [s.name for s in spans] == [
+        "grandchild-left-open", "child-left-open", "outer", "next"]
+    for sp in (inner, child):
+        assert sp.attrs == {"error": True}
+        assert sp.t_end == outer.t_end and sp.annotation is None
+    assert child.parent_id == outer.span_id
+    assert inner.parent_id == child.span_id
+
+
+def test_live_span_lies_on_the_profilers_host_plane(devices, tmp_path):
+    """A span opened and closed live is also a ``TraceAnnotation``: in a
+    ``jax.profiler`` capture it is on the ``/host:CPU`` plane under its
+    name, with its small scalar attributes, on the profiler's clock."""
+    import jax.numpy as jnp
+
+    tr = Tracer(enabled=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("engine/step", kind="decode", step=7, blob="x" * 100):
+            sp = tr.begin("engine/wait", kind="decode", step=7)
+            jnp.ones(8).block_until_ready()
+            tr.end(sp, late=1)
+        tr.add_span("request/first_write", 0.0, 1.0)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in profile.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("engine/step", "engine/wait",
+                               "request/first_write"):
+                    assert plane.name == "/host:CPU"
+                    found[ev.name] = (ev.start_ns, ev.duration_ns,
+                                      dict(ev.stats))
+    assert set(found) == {"engine/step", "engine/wait"}  # not the retroactive
+    (s0, sd, sattrs), (w0, wd, wattrs) = (found["engine/step"],
+                                          found["engine/wait"])
+    assert s0 <= w0 and w0 + wd <= s0 + sd  # nested on the profiler's clock
+    assert sattrs == {"kind": "decode", "step": 7}  # small scalars only
+    assert wattrs == {"kind": "decode", "step": 7}  # begin()'s, not end()'s
+    # the ring has the same spans on time.monotonic, with every attribute
+    ring = {s.name: s for s in tr.spans()}
+    assert ring["engine/wait"].attrs["late"] == 1
+    assert len(ring["engine/step"].attrs["blob"]) == 100
+
+
 def test_chrome_trace_format():
     tr = Tracer(enabled=True)
     with tr.span("work", trace_id="r1", items=3):
         pass
     tr.add_event("instant")
-    doc = json.loads(tr.to_chrome_json())  # must be valid JSON
+    doc = json.loads(json.dumps(tr.to_chrome_trace()))  # must be valid JSON
     events = doc["traceEvents"]
     assert events[0]["ph"] == "M"  # process_name metadata
     complete = [e for e in events if e["ph"] == "X"]
@@ -419,21 +500,35 @@ def test_broker_logs_carry_rid(devices, tiny_model):
 # ---------------------------------------------------------------------------
 
 
-def test_tracing_on_vs_off_token_identical(devices, tiny_model, ref_fn):
-    """Tracing must change no compiled program: greedy serving outputs are
-    token-identical with the tracer enabled and disabled."""
-    prompts = [([5, 6, 7], 6), ([1, 2, 3, 4], 5), ([11, 12], 8)]
+@pytest.mark.parametrize("over", [
+    {}, {"max_tokens_per_step": 8},
+    {"spec_mode": "self_draft", "spec_k": 2}],
+    ids=["decode-mixed", "chunked-prefill", "spec"])
+def test_tracing_on_vs_off_token_identical(devices, tiny_model, ref_fn, over):
+    """Tracing must change no compiled program and no order of host work
+    that a token depends on: greedy serving outputs are token-identical
+    with the tracer enabled (``DSTPU_TRACE`` unset or 1) and disabled
+    (``DSTPU_TRACE=0``), through the decode, the mixed (one chunk and
+    several) and the speculative step, each with its sub-spans."""
+    prompts = [([5, 6, 7], 6), ([1, 2, 3, 4], 5), ([11, 12], 8),
+               (list(range(1, 20)), 4)]
     outs = {}
     was_enabled = global_tracer.enabled
     try:
         for enabled in (True, False):
             global_tracer.enabled = enabled
-            broker = RequestBroker(_engine(tiny_model),
+            global_tracer.clear()
+            broker = RequestBroker(_engine(tiny_model, **over),
                                    ServingConfig()).start()
             handles = [broker.submit(p, max_new_tokens=n)
                        for p, n in prompts]
             outs[enabled] = [h.result(timeout=120) for h in handles]
             broker.stop(drain=True, timeout=90)
+            kinds = {s.attrs["kind"]
+                     for s in global_tracer.spans(name="engine/wait")}
+            assert kinds == (set() if not enabled else
+                             {"mixed", "spec" if over.get("spec_mode")
+                              else "decode"})
     finally:
         global_tracer.enabled = was_enabled
     assert outs[True] == outs[False]
@@ -555,12 +650,111 @@ def test_debug_endpoints_and_metrics_e2e(http_stack):
 
     resp, body = _get(port, "/debug/profile?seconds=nope")
     assert resp.status == 400
-    resp, body = _get(port, "/debug/profile?seconds=0.2")
+    # a request served while the capture runs: its steps are in the profile
+    late = threading.Timer(0.4, lambda: pool.submit(
+        [3, 1, 4, 1], max_new_tokens=8).result(timeout=120))
+    late.start()
+    resp, body = _get(port, "/debug/profile?seconds=1.5")
+    late.join()
     if resp.status == 200:  # profiler may be unavailable on some backends
         prof = json.loads(body)
         assert os.path.isdir(prof["profile_dir"])
+        (path,) = glob.glob(os.path.join(prof["profile_dir"], "**",
+                                         "*.xplane.pb"), recursive=True)
+        names = {ev.name for plane in
+                 jax.profiler.ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for ev in line.events}
+        # the program's live spans, and no Python-function events (the
+        # profiler's Python tracer is off: it slows what it shows)
+        assert {"engine/step", "engine/dispatch", "engine/wait",
+                "broker/turn", "broker/emit"} <= names
+        assert not any(n.startswith("$") for n in names)
     else:
         assert resp.status == 503
+
+
+def test_streamed_request_records_turns_and_first_write(http_stack):
+    """The broker loop's turns (token hand-off, then admission) and, once a
+    streamed request, the time from the broker's first token to the
+    first SSE chunk written."""
+    srv, pool, port = http_stack
+    global_tracer.clear()
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    conn.request("POST", "/v1/completions", body=json.dumps(
+        {"prompt": [2, 7, 1, 8], "max_tokens": 5, "stream": True}),
+        headers={"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    assert resp.status == 200
+    chunks = [line for line in resp.read().split(b"\n\n")
+              if line.startswith(b"data: {")]
+    conn.close()
+    rid = json.loads(chunks[0][6:])["id"].removeprefix("cmpl-")
+    assert len(chunks) == 6  # five tokens and the closing chunk
+
+    (fw,) = global_tracer.spans(name="request/first_write")  # one a request
+    assert fw.trace_id == rid
+    tl = next(r for r in global_recorder.snapshot()["requests"]
+              if r["rid"] == rid)
+    assert fw.t_start == tl["first_token_ts"] and fw.t_end >= fw.t_start
+    (prefill,) = global_tracer.spans(trace_id=rid, name="request/prefill")
+    assert prefill.t_end == fw.t_start  # the TTFT sum's parts meet
+
+    deadline = time.monotonic() + 30  # the loop goes idle after the reply
+    while time.monotonic() < deadline:
+        turns = global_tracer.spans(name="broker/turn")
+        if turns and turns[-1].attrs["next"] == "idle":
+            break
+        time.sleep(0.01)
+    to_step = [t for t in turns if t.attrs["next"] == "step"]
+    assert len(to_step) >= 4  # five tokens: a step each, a turn between
+    assert turns[-1].attrs["next"] == "idle"  # then nothing is left to run
+    spans = global_tracer.spans()
+    for t in to_step:
+        kids = [s for s in spans if s.parent_id == t.span_id]
+        assert [k.name for k in kids] == ["broker/emit", "broker/admit"]
+        assert t.t_start <= kids[0].t_start and kids[-1].t_end <= t.t_end
+        # no step runs inside a turn: it ends where the next step starts
+        assert not any(s.name == "engine/step" and s.thread == t.thread
+                       and s.t_start < t.t_end and s.t_end > t.t_start
+                       for s in spans)
+    admits = global_tracer.spans(name="broker/admit")
+    # one idle span a quiet period, however often the wait woke: the one
+    # this request ended (the other replica's is still open)
+    (idle,) = global_tracer.spans(name="broker/idle")
+    assert idle.t_end <= to_step[0].t_start
+    assert [a.parent_id for a in admits][0] == idle.span_id
+
+
+def test_broker_loop_closes_its_spans_when_it_stops_or_dies(devices,
+                                                            tiny_model):
+    """A loop that stops closes its open ``broker/idle``; one that dies in
+    the token hand-off leaves ``broker/emit`` (marked ``error``) and its
+    ``broker/turn`` in the ring: the flight dump shows the failing phase."""
+    global_tracer.clear()
+    broker = RequestBroker(_engine(tiny_model), ServingConfig()).start()
+    assert len(broker.submit([1, 2, 3], max_new_tokens=3)
+               .result(timeout=90)) == 3
+    broker.stop(drain=True, timeout=60)
+    idles = global_tracer.spans(name="broker/idle")
+    assert idles and idles[-1].t_end is not None
+    assert idles[-1].annotation is None
+
+    global_tracer.clear()
+    broker = RequestBroker(_engine(tiny_model), ServingConfig())
+
+    def boom(out, now):
+        raise RuntimeError("boom")
+
+    broker._dispatch = boom
+    handle = broker.start().submit([1, 2, 3], max_new_tokens=3)
+    with pytest.raises(Exception):
+        handle.result(timeout=90)
+    broker.stop(drain=False, timeout=60)
+    (emit,) = global_tracer.spans(name="broker/emit")
+    (turn,) = global_tracer.spans(name="broker/turn")
+    assert emit.attrs == {"error": True} and emit.parent_id == turn.span_id
+    assert emit.t_end == turn.t_end and emit.annotation is None
 
 
 def test_profile_endpoint_409_when_capture_in_flight(http_stack):

@@ -16,6 +16,7 @@ compilation cache is off around them (a described chip cannot read it back).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +67,21 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args) -> str:
-    """Compile ``fn`` for the described chip; the kernel must be in it."""
+def _compile(fn, *args, kernels) -> str:
+    """Compile ``fn`` for the described chip; each of ``kernels`` must be in
+    it as a ``tpu_custom_call`` whose instruction carries the kernel's own
+    name (``name=`` of its ``pallas_call``): that name is what the trace of
+    a chip run and the benchmark's breakdown show, so it must not be the
+    name of whatever scope happens to enclose the call.  Under ``jax.grad``
+    JAX puts the transform round it (``jvp_<kernel>_``,
+    ``transpose_jvp_<kernel>__``); the kernel's name stays whole."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "the kernel gave way to a reference"
+    for kernel in kernels:
+        assert re.search(
+            rf'%(\w+_)?{kernel}_*(\.\d+)? = [^\n]*'
+            r'custom_call_target="tpu_custom_call"',
+            text), f"no tpu_custom_call instruction is named {kernel}"
     return text
 
 
@@ -84,11 +96,12 @@ def test_paged_attention_compiles(one_chip, mosaic, kernel):
     tables, lens = sds((seqs, max_blocks), jnp.int32), sds((seqs,), jnp.int32)
     if kernel == "decode":
         _compile(paged_decode_attention, sds((seqs, H, D), jnp.bfloat16),
-                 cache, cache, tables, lens)
+                 cache, cache, tables, lens,
+                 kernels=["paged_attention_decode"])
     else:
         _compile(paged_prefill_attention,
                  sds((seqs, 512, H, D), jnp.bfloat16), cache, cache, tables,
-                 lens, lens)
+                 lens, lens, kernels=["paged_attention_prefill"])
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
@@ -102,7 +115,9 @@ def test_flash_attention_window_compiles(one_chip, mosaic, grad):
         fn = jax.grad(lambda q_, k_, v_: flash_attention(
             q_, k_, v_, causal=True, window=4096).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))
-    _compile(fn, q, kv, kv)
+    _compile(fn, q, kv, kv, kernels=[
+        "flash_attention_fwd", "flash_attention_bwd_dkv",
+        "flash_attention_bwd_dq"] if grad else ["flash_attention_fwd"])
 
 
 def test_flash_attention_on_a_mesh_compiles(topo, mosaic):
@@ -117,7 +132,8 @@ def test_flash_attention_on_a_mesh_compiles(topo, mosaic):
     q = _sds((4, 2048, H, D), jnp.bfloat16, rows)
     kv = _sds((4, 2048, KV, D), jnp.bfloat16, rows)
     text = _compile(functools.partial(flash_attention, causal=True,
-                                      window=4096), q, kv, kv)
+                                      window=4096), q, kv, kv,
+                    kernels=["flash_attention_fwd"])
     assert f"bf16[1,{H},2048,{D}]" in text  # one batch row a chip
 
 
@@ -136,7 +152,43 @@ def test_mixed_gemm_compiles(one_chip, mosaic, bits, m):
             lambda x, c, s: mixed_gemm(x, QuantizedWeight(c, s, bits, 256, k)),
             _sds((m, k), jnp.bfloat16, one_chip),
             _sds(qw.codes.shape, qw.codes.dtype, one_chip),
-            _sds(qw.scales.shape, qw.scales.dtype, one_chip))
+            _sds(qw.scales.shape, qw.scales.dtype, one_chip),
+            kernels=["mixed_gemm"])
+
+
+@pytest.mark.parametrize("m", [32, 512], ids=["decode", "prefill"])
+def test_int8_gemm_compiles(one_chip, mosaic, m):
+    """W8A8: activations quantized a row and group, int8 x int8 on the MXU."""
+    from deepspeed_tpu.ops.pallas.mixed_gemm import (QuantizedWeight,
+                                                     int8_gemm,
+                                                     quantize_gemm_weight)
+
+    k, n = HIDDEN, MLP
+    qw = jax.eval_shape(
+        functools.partial(quantize_gemm_weight, bits=8, group=256),
+        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+    _compile(
+        lambda x, c, s: int8_gemm(x, QuantizedWeight(c, s, 8, 256, k)),
+        _sds((m, k), jnp.bfloat16, one_chip),
+        _sds(qw.codes.shape, qw.codes.dtype, one_chip),
+        _sds(qw.scales.shape, qw.scales.dtype, one_chip),
+        kernels=["int8_gemm"])
+
+
+def test_grouped_matmul_compiles(one_chip, mosaic):
+    """The dropless MoE's grouped GEMM: 8 experts at Mistral's MLP widths
+    (Mixtral-8x7B's), 4096 tile-aligned rows."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    rows, experts, tile_m = 4096, 8, 512
+    _compile(
+        lambda lhs, rhs, tg, sizes: grouped_matmul(lhs, rhs, tg, sizes,
+                                                   tile_m=tile_m),
+        _sds((rows, HIDDEN), jnp.bfloat16, one_chip),
+        _sds((experts, HIDDEN, MLP), jnp.bfloat16, one_chip),
+        _sds((rows // tile_m,), jnp.int32, one_chip),
+        _sds((experts,), jnp.int32, one_chip),
+        kernels=["grouped_matmul"])
 
 
 @pytest.mark.parametrize("n", [HIDDEN * MLP, 1_000_003],
@@ -148,7 +200,58 @@ def test_fused_adamw_compiles(one_chip, mosaic, n):
     m = _sds((n,), jnp.float32, one_chip)
     step = _sds((), jnp.int32, one_chip)
     _compile(lambda p_, g_, m_, v_, s_: fused_adamw_flat(
-        p_, g_, m_, v_, s_, lr=1e-3, weight_decay=0.1), p, p, m, m, step)
+        p_, g_, m_, v_, s_, lr=1e-3, weight_decay=0.1), p, p, m, m, step,
+        kernels=["fused_adamw"])
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_step_programs_carry_their_names(one_chip, mosaic, program):
+    """The server's two step programs lower, for the described chip at
+    Mistral-7B widths (two layers, W8A16), to modules ``jit_decode_step``
+    and ``jit_mixed_step``: the trace's ``XLA Modules`` line and every
+    operation name of the benchmark's breakdown start with them.  Their
+    kernels and the forward's scopes are in the lowered text by name."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.quantization import quantize_model_params
+    from deepspeed_tpu.inference.v2 import engine as v2e
+    from deepspeed_tpu.models import transformer as tfm
+
+    cfg = dataclasses.replace(tfm.get_config("mistral-7b"), num_layers=2)
+    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
+                      num_blocks=64, max_blocks_per_seq=64)
+    sds = functools.partial(_sds, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda key: quantize_model_params(
+            tfm.init_params(key, cfg), bits=8, group=256),
+            jax.random.PRNGKey(0)))
+    cache = sds((cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
+                 cfg.head_dim), jnp.bfloat16)
+    caches = {"k": cache, "v": cache}
+    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
+    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
+    if program == "decode_step":
+        lowered = v2e.build_decode_forward(cfg, v2).lower(
+            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
+            rows(jnp.int32), rows(jnp.float32),
+            sds((2,), jnp.uint32), rows(jnp.int32))
+        inside = ["paged_attention_decode", "mixed_gemm", "decode_attention",
+                  "cache_write", "sampler"]
+    else:
+        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
+        lowered = v2e.build_ragged_forward(cfg, v2).lower(
+            params, caches, tokens(), tokens(), tokens(), tables,
+            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.int32))
+        inside = ["paged_attention_prefill", "mixed_gemm",
+                  "prefill_attention", "cache_write"]
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{program} " in text
+    assert "tpu_custom_call" in text
+    for name in inside:
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
 
 
 def test_mesh_follows_the_torus(topo):
