@@ -243,6 +243,15 @@ def _post(port: int, path: str, obj: dict) -> Tuple[Any, Any]:
     return conn, conn.getresponse()
 
 
+def _get_json(port: int, path: str) -> Any:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    conn.request("GET", path)
+    try:
+        return json.loads(conn.getresponse().read())
+    finally:
+        conn.close()
+
+
 def _complete(port: int, prompt: List[int], n: int, stream: bool
               ) -> Tuple[List[int], str]:
     """One ``/v1/completions`` request → (tokens, finish_reason)."""
@@ -451,13 +460,12 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
                 f"several rows; ran {mixed} and {pure}, at most "
                 f"{decode.max_rows} rows")
 
-        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
-        conn.request("GET", "/healthz")
-        health = json.loads(conn.getresponse().read())
-        conn.close()
+        health = _get_json(port, "/healthz")
         log(phase, healthz=health["status"])
         if health["status"] != "ok":
             raise AssertionError(f"{phase}: /healthz said {health}")
+        if quantize_bits:
+            check_gemm_tiles(phase, port)
     finally:
         pool.drain(scfg.drain_timeout_s)
         server.shutdown()
@@ -502,9 +510,27 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
                        decode.compiled_text())
 
 
+def check_gemm_tiles(phase: str, port: int) -> None:
+    """``GET /debug/trace`` of the warmed server: every mixed GEMM the step
+    programs traced left a ``kernel/mixed_gemm_tiles`` event with its tile,
+    and none gave way to dequantize-then-matmul."""
+    events = [e["args"] for e in _get_json(port, "/debug/trace")["traceEvents"]
+              if e["name"] == "kernel/mixed_gemm_tiles"]
+    fallen = [a for a in events if "fallback" in a]
+    tiles = sorted({(a["m"], a["k"], a["n"], a["tm"], a["tn"], a["tk"],
+                     a["grid_steps"]) for a in events if "fallback" not in a})
+    log(phase, mixed_gemm_tile_events=len(events),
+        m_k_n_tm_tn_tk_steps=tiles)
+    if not events or fallen:
+        raise AssertionError(
+            f"{phase}: /debug/trace shows {len(events)} "
+            f"kernel/mixed_gemm_tiles events, fallen back: {fallen}")
+
+
 def check_mixed_gemm(phase: str, params, cfg) -> None:
     """The int8 mixed GEMM against dequantize-then-matmul on layer 0's
-    projections, at a decode-sized and a prefill-sized M."""
+    projections, at the rows the benchmark's serving cells run: 32 decode
+    rows and a chunk of 512 tokens."""
     from deepspeed_tpu.ops.pallas.mixed_gemm import (QuantizedWeight,
                                                      dequantize_gemm_weight,
                                                      mixed_gemm)
@@ -512,11 +538,13 @@ def check_mixed_gemm(phase: str, params, cfg) -> None:
     worst = 0.0
     layer = params["layers"]
     for name, qw in (("wq", layer["attn"]["wq"]),
+                     ("wk", layer["attn"]["wk"]),
+                     ("wo", layer["attn"]["wo"]),
                      ("w_in", layer["mlp"]["w_in"]),
                      ("w_out", layer["mlp"]["w_out"])):
         qw0 = QuantizedWeight(qw.codes[0], qw.scales[0], qw.bits, qw.group,
                               qw.k)
-        for m_rows in (16, 512):
+        for m_rows in (32, 512):
             x = jax.random.normal(jax.random.PRNGKey(m_rows),
                                   (m_rows, qw0.k_features), jnp.bfloat16)
             got = jax.jit(mixed_gemm)(x, qw0).astype(jnp.float32)

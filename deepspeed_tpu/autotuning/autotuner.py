@@ -402,85 +402,3 @@ class SubprocessAutotuner(Autotuner):
             logger.warning(f"autotune candidate {overrides} failed: "
                            f"{exp.error}")
         return exp
-
-
-# ---------------------------------------------------------------------------
-# mixed-GEMM tile tuning (serving-side analogue of the training sweep)
-# ---------------------------------------------------------------------------
-
-
-def tune_gemm_tiles(m: int, n: int, k: int, bits: int = 8,
-                    group: int = 256, dtype: Any = None,
-                    warmup: int = 2, iters: int = 5,
-                    install: bool = True, seed: int = 0,
-                    ) -> Dict[str, Any]:
-    """Measured (tm, tn) tile search for one Pallas mixed-GEMM shape.
-
-    Times every legal tile pair from ``gemm_tile_candidates`` on a random
-    W(bits)A16 problem of the given shape and — when ``install`` — pins the
-    winner with ``set_gemm_tiles`` so every later ``mixed_gemm`` /
-    ``mixed_gemm_frozen`` call on the same (padded-M, N, K, bits) problem
-    uses it.  The heuristic pick is always among the candidates, so the
-    tuned result can only match or beat the default.
-
-    Returns ``{"key": (m_padded, n, k, bits), "best": (tm, tn),
-    "best_s": float, "heuristic": (tm, tn) | None,
-    "timings": [{"tm", "tn", "seconds"}, ...], "installed": bool}``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops.pallas import mixed_gemm as mg
-
-    dtype = dtype or jnp.bfloat16
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((m, k)), dtype)
-    qw = mg.quantize_gemm_weight(
-        jnp.asarray(rng.standard_normal((k, n)), jnp.float32),
-        bits=bits, group=group)
-    pad_m = (-m) % 8
-    key = (m + pad_m, n, k, bits)
-    prior = mg._TILE_OVERRIDES.get(key)
-    # the heuristic pick the override competes against
-    _, _, _, _, h_tm, h_tn = mg._flatten_pad_tiles(x, n)
-    heuristic = (h_tm, h_tn) if h_tm is not None and h_tn is not None \
-        else None
-
-    timings: List[Dict[str, Any]] = []
-    best: Optional[Tuple[int, int]] = None
-    best_s = float("inf")
-    for tm, tn in mg.gemm_tile_candidates(m, n, pad_m):
-        mg.set_gemm_tiles(*key, tm, tn)
-        try:
-            # fresh lambda per candidate: each override needs its own
-            # compile-cache entry, or every pair times the first program
-            fn = jax.jit(lambda xx, _qw=qw: mg.mixed_gemm(xx, _qw))
-            fn(x).block_until_ready()
-            for _ in range(max(0, warmup - 1)):
-                fn(x).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(max(1, iters)):
-                fn(x).block_until_ready()
-            dt = (time.perf_counter() - t0) / max(1, iters)
-        except Exception as e:  # Mosaic reject / OOM: data, not a crash
-            logger.warning(f"gemm tile ({tm}, {tn}) failed for "
-                           f"{key}: {type(e).__name__}: {e}")
-            continue
-        finally:
-            if prior is None:
-                mg._TILE_OVERRIDES.pop(key, None)
-            else:
-                mg._TILE_OVERRIDES[key] = prior
-        timings.append({"tm": tm, "tn": tn, "seconds": dt})
-        if dt < best_s:
-            best, best_s = (tm, tn), dt
-    if best is None:
-        raise RuntimeError(
-            f"gemm tile tuning: every candidate failed for {key}")
-    if install:
-        mg.set_gemm_tiles(*key, *best)
-    log_dist(f"gemm tiles {key}: best {best} ({best_s * 1e6:.0f} us, "
-             f"{len(timings)} candidates, heuristic {heuristic})")
-    return {"key": key, "best": best, "best_s": best_s,
-            "heuristic": heuristic, "timings": timings,
-            "installed": bool(install)}

@@ -5,15 +5,22 @@ Reference: the CUTLASS mixed GEMM family backing weight-quantized inference
 ``deepspeed/inference/quantization`` W8A16/W4A16 paths). There the weight
 stays int8/int4 in HBM and dequantizes in registers inside the GEMM.
 
-TPU-native design: a Pallas kernel with grid (M/tm, N/tn, K/tk) whose inner
-step streams an int8 code tile + its per-group scale row out of HBM,
-dequantizes in VMEM, and feeds the MXU in bfloat16 with an f32 accumulator.
-The quantization group size along K equals the k-tile, so each grid step
-reads exactly one (1, tn) scale row — no gather, no unaligned broadcast.
-int4 packs two K-rows per byte (codes shape (K/2, N)) and unpacks with two
-arithmetic shifts in-kernel. HBM traffic for the weight is K·N bytes (int8)
-or K·N/2 (int4) instead of 2·K·N (bf16) — the same bandwidth win the
-reference gets, which is what matters for memory-bound decode.
+TPU-native design: a Pallas kernel with grid (M/tm, N/tn, K/tk).  One grid
+step streams a ``(tk, tn)`` tile of codes out of HBM together with the
+``tk / group`` scale rows that belong to it, and walks the tile one
+quantization group and one column chunk at a time: dequantize in VMEM with
+that group's scale row, feed the MXU in bfloat16, accumulate in f32.  A tile
+is therefore several groups deep and thousands of columns wide: a grid step
+costs about a third of a microsecond whatever it moves, so it has to move
+megabytes.  ``pick_gemm_tiles`` is the one place that chooses ``(tm, tn,
+tk)``, from the shapes the call can see: the whole padded M up to 512 rows
+(the weights are read and dequantized once), the widest ``tn`` a VMEM budget
+holds, and a ``tk`` of as many whole groups as make a step about 2 MB of
+int8 codes.  int4 packs two K-rows per byte (codes shape (K/2, N)) and
+unpacks with two arithmetic shifts in-kernel; fp6 packs four K-rows in three
+byte-rows.  HBM traffic for the weight is K·N bytes (int8) or K·N/2 (int4)
+instead of 2·K·N (bf16) — the same bandwidth win the reference gets, which
+is what matters for memory-bound decode.
 
 ``QuantizedWeight`` is a pytree node (static bits/group), so stacked
 per-layer weights slice transparently under ``lax.scan`` and shard under
@@ -24,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...observability.trace import tracer
 from ..quantizer import (minifloat_decode, minifloat_encode, minifloat_max,
                          pack_fp6, pack_int4, unpack_fp6, unpack_int4)
 from . import backend
@@ -114,51 +122,92 @@ def quantize_gemm_weight(w: jax.Array, bits: int = 8,
 
 
 # ---------------------------------------------------------------------------
-# tile selection: heuristic default + autotuner override
+# tile selection: one function, from the shapes a call can see
 # ---------------------------------------------------------------------------
 
-#: (M_padded, N, K, bits) → (tm, tn), installed by the autotuner
-#: (``autotuning.autotuner.tune_gemm_tiles``).  The heuristic in
-#: ``_flatten_pad_tiles`` stays the default; an override only applies when it
-#: tiles the problem legally, so a stale entry can never break a call.
-_TILE_OVERRIDES: Dict[Tuple[int, int, int, int], Tuple[int, int]] = {}
+#: Scoped VMEM a ``mixed_gemm`` call asks the compiler for, and what the
+#: picker may plan inside it.  The default scoped limit (16 MiB) is what
+#: binds a tile of megabytes; a v5e core has 128 MiB.  The picker counts its
+#: pipelined buffers, the accumulator and one chunk's temporaries, and leaves
+#: the other half of the limit to what the compiler adds (int4 / fp6 unpack).
+_VMEM_LIMIT = 64 << 20
+_VMEM_BUDGET = _VMEM_LIMIT // 2
+#: Rows and columns of the widest output tile.  Up to 512 rows a call reads
+#: its weights once; more rows take another pass per M tile.
+_MAX_TM = 512
+_MAX_TN = 4096
+#: Weights a grid step dequantizes (2 MB of int8 codes, 2.4 us of HBM time
+#: against a third of a microsecond a step), and the fewest grid steps a
+#: call is cut into: the first tile's fetch overlaps nothing.
+_TILE_WEIGHTS = 2 << 20
+_MIN_STEPS = 4
+#: Columns dequantized at a time inside a grid step: bounds the temporaries.
+_CHUNK_N = 512
 
 
-def set_gemm_tiles(m: int, n: int, k: int, bits: int,
-                   tm: int, tn: int) -> None:
-    """Pin the (tm, tn) tiles for one (padded-M, N, K, bits) GEMM shape."""
-    _TILE_OVERRIDES[(m, n, k, bits)] = (int(tm), int(tn))
+def _code_rows(k_rows: int, bits: int) -> int:
+    """Byte rows of codes that hold ``k_rows`` rows of K: int8 1:1, int4 two
+    codes a byte, fp6 four codes in three bytes."""
+    return {8: k_rows, 4: k_rows // 2, 6: k_rows // 4 * 3}[bits]
 
 
-def clear_gemm_tiles() -> None:
-    _TILE_OVERRIDES.clear()
+@dataclasses.dataclass(frozen=True)
+class GemmTiles:
+    """One ``mixed_gemm`` call's tiling, as :func:`pick_gemm_tiles` chose it."""
+    tm: int
+    tn: int
+    tk: int
+    grid_steps: int
+    code_bytes_per_step: int
 
 
-def _tile_legal(m: int, n: int, tm: int, tn: int) -> bool:
-    return (tm > 0 and tn > 0 and m % tm == 0 and n % tn == 0
-            and (tm % 8 == 0 or tm == m) and (tn % 128 == 0 or tn == n))
+@functools.lru_cache(maxsize=None)
+def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
+                    x_itemsize: int = 2) -> Optional[GemmTiles]:
+    """The ``(tm, tn, tk)`` tile of an ``(m, k) @ (k, n)`` mixed GEMM (``m``
+    already padded to the sublane multiple), or None when the shapes do not
+    tile (→ the dequantize-then-matmul fallback).
 
-
-def gemm_tile_candidates(m: int, n: int, pad_m: int = 0
-                         ) -> List[Tuple[int, int]]:
-    """Legal (tm, tn) tile pairs for an (m+pad_m, K) × (K, n) problem —
-    the autotuner's search space.  Every pair divides the padded M and N
-    with Mosaic-legal alignment; the heuristic pick is always a member."""
-    mp = m + pad_m
-    tms = [d for d in (8, 16, 32, 64, 128, 256, 512) if mp % d == 0]
-    if not tms:
-        tms = [mp]
-    tns = [d for d in (128, 256, 512) if n % d == 0] or [n]
-    return [(tm, tn) for tm in tms for tn in tns]
-
-
-def _apply_tile_override(mp: int, N: int, K: int, bits: int,
-                         tm: Optional[int], tn: Optional[int]
-                         ) -> Tuple[Optional[int], Optional[int]]:
-    ov = _TILE_OVERRIDES.get((mp, N, K, bits))
-    if ov is not None and _tile_legal(mp, N, ov[0], ov[1]):
-        return ov
-    return tm, tn
+    ``tm`` is all of ``m`` up to 512 rows: one pass over the weights, no tile
+    of them dequantized twice.  ``tn`` is the widest lane-aligned divisor of
+    ``n`` (or a small ``n`` whole) whose buffers fit the VMEM budget: a tile's
+    rows are then long contiguous runs of HBM (tiles of equal bytes measured
+    faster wide than deep, PERF.md).  ``tk`` is as many whole quantization
+    groups, dividing ``k``, as keep a step at ``_TILE_WEIGHTS`` weights and
+    the call at ``_MIN_STEPS`` steps or more."""
+    # int4 packs two codes per byte (group must be even); fp6 packs 4 K-rows
+    # per 3 byte-rows (group must divide by 4, and the byte-row tile must be
+    # sublane-aligned); int8 has no pack constraint
+    if (k % group
+            or (bits == 4 and group % 2)
+            or (bits == 6 and (group % 4 or _code_rows(group, 6) % 8))
+            or (group % 128 and group != k)):
+        return None
+    tm = m if m <= _MAX_TM else aligned_divisor(m, _MAX_TM)
+    tns = [d for d in range(min(n, _MAX_TN) // 128 * 128, 0, -128)
+           if n % d == 0]
+    if n <= 256 and n % 128:
+        tns.insert(0, n)  # a full-dim block is legal whatever its width
+    if tm is None or not tns:
+        return None
+    groups = k // group
+    for tn in tns:  # widest first
+        g = max([g for g in range(1, groups + 1)
+                 if groups % g == 0 and g * group * tn <= _TILE_WEIGHTS
+                 and (m // tm) * (n // tn) * (groups // g) >= _MIN_STEPS],
+                default=1)
+        tk = g * group
+        codes = _code_rows(tk, bits) * tn
+        chunk = min(tn, _CHUNK_N)
+        vmem = (2 * codes + 2 * g * 8 * tn * 4  # a scale row pads to 8
+                + 2 * tm * tk * x_itemsize + 2 * tm * tn * x_itemsize
+                + tm * tn * 4
+                # a group's chunk: widened codes, f32 product, bf16 MXU
+                # operand, the partial product
+                + group * chunk * 10 + tm * chunk * 4)
+        if vmem <= _VMEM_BUDGET or tn == tns[-1]:
+            return GemmTiles(tm, tn, tk, (m // tm) * (n // tn) * (k // tk),
+                             codes)
 
 
 def _unpack_int4(c):
@@ -186,7 +235,11 @@ def _unpack_decode_fp6(c):
     return minifloat_decode(codes, 3, 2)
 
 
-def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int):
+def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int,
+                       group: int):
+    """One (tm, tn) output tile's step over a k-tile of ``g`` quantization
+    groups: codes (rows of g groups, tn), scales (g, 1, tn), each group
+    dequantized with its own scale row into the same f32 accumulator."""
     kk = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -194,44 +247,54 @@ def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int):
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    c = c_ref[:]
-    if bits == 4:
-        c = _unpack_int4(c)
-    if bits == 6:
-        c = _unpack_decode_fp6(c)
-    w = (c.astype(jnp.float32) * s_ref[0]).astype(jnp.bfloat16)
-    x = x_ref[:].astype(jnp.bfloat16)
-    acc_ref[:] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    tn = o_ref.shape[1]
+    rows = _code_rows(group, bits)
+    # static loops: every slice is a static, tile-aligned window
+    for gi in range(s_ref.shape[0]):
+        x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
+        for c0 in range(0, tn, _CHUNK_N):
+            cols = slice(c0, min(c0 + _CHUNK_N, tn))
+            c = c_ref[gi * rows:(gi + 1) * rows, cols]
+            if bits == 4:
+                c = _unpack_int4(c)
+            if bits == 6:
+                c = _unpack_decode_fp6(c)
+            w = (c.astype(jnp.float32) * s_ref[gi, :, cols]
+                 ).astype(jnp.bfloat16)
+            acc_ref[:, cols] += jax.lax.dot_general(
+                x, w, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(kk == nk - 1)
     def _flush():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
-def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, tm: int, tn: int):
+def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, tiles: GemmTiles):
     M, K = x2.shape
     N = qw.out_features
-    tk = qw.group
+    tm, tn, tk = tiles.tm, tiles.tn, tiles.tk
+    g = tk // qw.group
     grid = (M // tm, N // tn, K // tk)
-    kernel = functools.partial(_mixed_gemm_kernel, bits=qw.bits)
+    kernel = functools.partial(_mixed_gemm_kernel, bits=qw.bits,
+                               group=qw.group)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
-            # code rows per k-tile: int8 1:1, int4 2 codes/byte, fp6 4:3
-            pl.BlockSpec(({8: tk, 4: tk // 2, 6: tk // 4 * 3}[qw.bits], tn),
+            pl.BlockSpec((_code_rows(tk, qw.bits), tn),
                          lambda i, j, kk: (kk, j)),
             # scales get a unit middle axis so every block dim is either
             # lane-aligned or covers the full array dim (Mosaic legality)
-            pl.BlockSpec((1, 1, tn), lambda i, j, kk: (kk, 0, j)),
+            pl.BlockSpec((g, 1, tn), lambda i, j, kk: (kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x2.dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=backend.interpret(),
         name="mixed_gemm",
     )(x2, qw.codes, qw.scales[:, None, :])
@@ -278,19 +341,14 @@ def _int8_gemm_kernel(xc_ref, xs_ref, c_ref, s_ref, o_ref, acc_ref):
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
-def _flatten_pad_tiles(x: jax.Array, N: int):
-    """Shared GEMM prologue: collapse lead dims, pad M to the sublane
-    multiple, pick (tm, tn) tiles.  Returns (x2, lead, M, pad_m, tm, tn);
-    tm/tn are None when no aligned tiling exists (→ oracle fallback)."""
+def _flatten_pad(x: jax.Array):
+    """Shared GEMM prologue: collapse lead dims; the rows that pad M to the
+    sublane multiple.  Returns (x2, lead, M, pad_m)."""
     *lead, K = x.shape
     M = 1
     for d in lead:
         M *= d
-    x2 = x.reshape(M, K)
-    pad_m = (-M) % 8
-    tm = aligned_divisor(M + pad_m, 256)
-    tn = aligned_divisor(N, 256, 128)
-    return x2, lead, M, pad_m, tm, tn
+    return x.reshape(M, K), lead, M, (-M) % 8
 
 
 def quantize_activations_rowwise(x2: jax.Array, group: int
@@ -325,8 +383,9 @@ def int8_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
             f"x K={K} != weight K={qw.k_features} — a partial product "
             f"would be silently wrong")
     N = qw.out_features
-    x2, lead, M, pad_m, tm, tn = _flatten_pad_tiles(x, N)
-    tm, tn = _apply_tile_override(M + pad_m, N, K, qw.bits, tm, tn)
+    x2, lead, M, pad_m = _flatten_pad(x)
+    tm = aligned_divisor(M + pad_m, 256)
+    tn = aligned_divisor(N, 256, 128)
     # int8 MXU tiles want lane-aligned k-tiles; no group==K escape here —
     # a misaligned single tile would pass interpret mode and fail Mosaic
     usable = (tm is not None and tn is not None and K % qw.group == 0
@@ -381,25 +440,23 @@ def mixed_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     # ragged M (e.g. prefill with an odd token count) pads up to the sublane
     # multiple so the kernel path — the whole bandwidth win — is never lost
     # to an unlucky batch·seq product
-    x2, lead, M, pad_m, tm, tn = _flatten_pad_tiles(x, N)
-    tm, tn = _apply_tile_override(M + pad_m, N, K, qw.bits, tm, tn)
-    # int4 packs two codes per byte (group must be even); fp6 packs 4 K-rows
-    # per 3 byte-rows (group must divide by 4, and the byte-row tile must be
-    # sublane-aligned); int8 has no pack constraint
-    usable = (tm is not None and tn is not None and K % qw.group == 0
-              and (qw.bits != 4 or qw.group % 2 == 0)
-              and (qw.bits != 6 or (qw.group % 4 == 0
-                                    and (qw.group // 4 * 3) % 8 == 0))
-              and (qw.group % 128 == 0 or qw.group == K))
-    if usable:
+    x2, lead, M, pad_m = _flatten_pad(x)
+    tiles = pick_gemm_tiles(M + pad_m, K, N, qw.bits, qw.group,
+                            x2.dtype.itemsize)
+    # chosen once per shape, while the caller's program is traced: the ring
+    # (``/debug/trace``) shows which of a server's GEMMs run on the kernel
+    tracer.add_event("kernel/mixed_gemm_tiles", attrs={
+        "m": M, "k": K, "n": N, "bits": qw.bits, "group": qw.group,
+        **(dataclasses.asdict(tiles) if tiles else {"fallback": 1})})
+    if tiles is not None:
         xp = jnp.pad(x2, ((0, pad_m), (0, 0))) if pad_m else x2
-        out = _gemm_pallas(xp, qw, tm, tn)
+        out = _gemm_pallas(xp, qw, tiles)
         if pad_m:
             out = out[:M]
     else:
         backend.warn_fallback(
             "mixed_gemm", f"bits={qw.bits}, M={M}, K={K}, N={N}, "
-            f"group={qw.group} do not tile (tm={tm}, tn={tn})")
+            f"group={qw.group} do not tile")
         out = x2 @ dequantize_gemm_weight(qw).astype(x2.dtype)
     return out.reshape(*lead, N)
 

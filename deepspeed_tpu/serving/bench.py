@@ -1304,7 +1304,7 @@ def _time_fn(fn, args, warmup: int, iters: int) -> float:
 def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
                    shapes=((256, 256), (256, 704), (704, 256)),
                    bits_list=(8, 4, 6), groups=(0, 128),
-                   warmup=1, iters=3, tune_tiles=False, seed=0) -> dict:
+                   warmup=1, iters=3, seed=0) -> dict:
     """Kernel-vs-fallback microbench for the Pallas mixed GEMM.
 
     Sweeps bits × group × (M, N, K) — decode-shaped M=1..8 plus a prefill
@@ -1314,9 +1314,7 @@ def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
     Parity columns record kernel-vs-fallback max abs/rel error — the
     portable signal; on ``JAX_PLATFORMS=cpu`` the kernel runs in Pallas
     interpret mode, so CPU *timings* only sanity-check plumbing, never
-    perf.  ``tune_tiles`` additionally runs the measured tile search
-    (``autotuning.autotuner.tune_gemm_tiles``) per cell and records the
-    tuned tiles + tuned kernel time.
+    perf.
 
     The (N, K) defaults are the flagship subject's projections: attention
     256×256, MLP up 256→704, MLP down 704→256.
@@ -1325,7 +1323,6 @@ def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
     import jax.numpy as jnp
     import numpy as np
 
-    from ..autotuning.autotuner import tune_gemm_tiles as _tune
     from ..ops.pallas import mixed_gemm as mg
 
     rng = np.random.default_rng(seed)
@@ -1341,9 +1338,8 @@ def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
                 for m in ms:
                     x = jnp.asarray(rng.standard_normal((m, k)),
                                     jnp.bfloat16)
-                    # fresh jits per cell: tile overrides bind at trace
-                    # time, and qw rides as an ARGUMENT so XLA cannot
-                    # constant-fold the fallback's dequant away
+                    # qw rides as an ARGUMENT so XLA cannot constant-fold
+                    # the fallback's dequant away
                     kern = jax.jit(lambda xx, q: mg.mixed_gemm(xx, q))
                     orac = jax.jit(
                         lambda xx, q:
@@ -1365,15 +1361,6 @@ def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
                     cell["kernel_speedup"] = round(
                         cell["dequant_dot_s"] / cell["kernel_s"], 3) \
                         if cell["kernel_s"] else 0.0
-                    if tune_tiles:
-                        tuned = _tune(m, n, k, bits=bits, group=group,
-                                      warmup=warmup, iters=iters, seed=seed)
-                        tkern = jax.jit(
-                            lambda xx, q: mg.mixed_gemm(xx, q))
-                        cell["tuned_tiles"] = list(tuned["best"])
-                        cell["tuned_kernel_s"] = round(
-                            _time_fn(tkern, (x, qw), warmup, iters), 6)
-                        mg.clear_gemm_tiles()
                     cells.append(cell)
     return {
         "subject": "random W{bits}A16 problems at the flagship subject's "
@@ -1382,7 +1369,7 @@ def run_gemm_sweep(ms=(1, 2, 4, 8, 64),
                 "mode — CPU timings check plumbing only; the parity "
                 "columns (kernel vs full-matrix dequant+dot) are the "
                 "portable signal, speedups are only meaningful on TPUs",
-        "warmup": warmup, "iters": iters, "tile_tuning": bool(tune_tiles),
+        "warmup": warmup, "iters": iters,
         "cells": cells,
     }
 
@@ -1409,8 +1396,6 @@ def main(argv=None) -> int:
                    help="comma-separated M values for --mode gemm")
     p.add_argument("--gemm_bits", default="8,4,6")
     p.add_argument("--gemm_iters", type=int, default=3)
-    p.add_argument("--tune_tiles", action="store_true",
-                   help="run the measured tile search per gemm cell")
     p.add_argument("--workload_trace", default=None,
                    help="replay: recorded workload JSONL (default: seeded "
                         "synthesis)")
@@ -1546,7 +1531,7 @@ def main(argv=None) -> int:
         result = run_gemm_sweep(
             ms=tuple(int(m) for m in args.gemm_ms.split(",")),
             bits_list=tuple(int(b) for b in args.gemm_bits.split(",")),
-            iters=args.gemm_iters, tune_tiles=args.tune_tiles)
+            iters=args.gemm_iters)
         key = "mixed_gemm"
     elif args.mode == "spec":
         result = run_spec_sweep(

@@ -236,3 +236,107 @@ def test_int8_gemm_rejects_non8bit_and_falls_back():
     out = int8_gemm(x, qw)
     ref = x @ dequantize_gemm_weight(qw).astype(x.dtype)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# a k-tile of several quantization groups, tiles picked from the shapes
+# ---------------------------------------------------------------------------
+
+
+def _oracle_check(x, qw, out):
+    ref = x @ dequantize_gemm_weight(qw).astype(jnp.float32)
+    tol = 2e-2 * float(jnp.max(jnp.abs(ref))) + 1e-3
+    assert out.shape == ref.shape
+    assert float(jnp.max(jnp.abs(out - ref))) < tol
+
+
+@pytest.mark.parametrize("bits", [8, 4, 6])
+@pytest.mark.parametrize("m", [32, 37], ids=["m32", "ragged"])
+@pytest.mark.parametrize("groups,g", [(4, 2), (4, 4), (7, 7), (7, 1)],
+                         ids=["4x2", "4xK", "7xK", "7x1"])
+def test_k_tile_of_several_groups(monkeypatch, bits, m, groups, g):
+    """``tk = g * group``: each group of the k-tile is dequantized with its
+    own scale row.  N = 640 makes the in-kernel column chunks ragged (512
+    and 128).  The tile is forced where the picker would choose another."""
+    from deepspeed_tpu.ops.pallas import mixed_gemm as mg
+
+    group, N = 128, 640
+    K = groups * group
+    kx, kw = jax.random.split(jax.random.PRNGKey(groups * 10 + g))
+    x = jax.random.normal(kx, (m, K), jnp.float32)
+    # a different magnitude for every group, so a scale row applied to the
+    # wrong group cannot pass
+    w = jax.random.normal(kw, (K, N), jnp.float32) * jnp.repeat(
+        2.0 ** jnp.arange(groups), group)[:, None]
+    qw = quantize_gemm_weight(w, bits=bits, group=group)
+    assert qw.group == group and qw.scales.shape == (groups, N)
+    mp = m + (-m) % 8
+    picked = mg.pick_gemm_tiles(mp, K, N, bits, group, 4)
+    forced = mg.GemmTiles(mp, N, g * group, groups // g, 0)
+    monkeypatch.setattr(mg, "pick_gemm_tiles", lambda *a: forced)
+    _oracle_check(x, qw, mixed_gemm(x, qw))
+    assert picked.tm == mp and K % picked.tk == 0 and picked.tk % group == 0
+
+
+@pytest.mark.parametrize("m,k,n,bits,group", [
+    (32, 4096, 4096, 8, 256), (32, 4096, 1024, 8, 256),
+    (32, 4096, 14336, 8, 256), (32, 14336, 4096, 8, 256),
+    (512, 4096, 14336, 8, 256), (512, 14336, 4096, 8, 256),
+    (512, 4096, 14336, 4, 256), (32, 14336, 4096, 6, 256),
+    (1024, 4096, 14336, 8, 128), (8, 512, 384, 8, 256),
+    (304, 256, 256, 8, 256), (8, 99, 33, 8, 99), (8, 100, 33, 4, 100),
+])
+def test_picked_tiles_are_legal(m, k, n, bits, group):
+    from deepspeed_tpu.ops.pallas import mixed_gemm as mg
+
+    t = mg.pick_gemm_tiles(m, k, n, bits, group)
+    assert m % t.tm == 0 and (t.tm == m or t.tm % 8 == 0) and t.tm <= 512
+    assert n % t.tn == 0 and (t.tn % 128 == 0 or t.tn == n)
+    assert k % t.tk == 0 and t.tk % group == 0
+    assert t.grid_steps == (m // t.tm) * (n // t.tn) * (k // t.tk)
+    assert t.code_bytes_per_step == t.tk * t.tn * bits // 8
+    if m <= 512:
+        assert t.tm == m  # one pass over the weights
+    if bits == 8 and k * n >= 4 << 20:
+        assert t.code_bytes_per_step >= 1 << 20
+
+
+@pytest.mark.parametrize("k,n,bits,group", [
+    (98, 33, 8, 49),  # group neither lane-aligned nor all of K
+    (130, 128, 6, 130),  # fp6 packs 4 rows of K in 3 bytes
+    (256, 300, 8, 256),  # N wider than a small whole block, not lane-aligned
+    (99, 128, 4, 99),  # int4 packs two rows of K a byte
+])
+def test_shapes_off_the_envelope_pick_nothing(k, n, bits, group):
+    from deepspeed_tpu.ops.pallas import mixed_gemm as mg
+
+    assert mg.pick_gemm_tiles(8, k, n, bits, group) is None
+
+
+def test_tile_choice_is_recorded_at_trace_time():
+    """One ring event a traced call, with the tile the picker returns, or
+    ``fallback`` where the call gave way to dequantize-then-matmul."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import mixed_gemm as mg
+
+    def last():
+        return tracer.spans(name="kernel/mixed_gemm_tiles")[-1].attrs
+
+    M, K, N = 24, 768, 640
+    x = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)
+    qw = quantize_gemm_weight(w, bits=8, group=128)
+    before = len(tracer.spans(name="kernel/mixed_gemm_tiles"))
+    fn = jax.jit(mixed_gemm)
+    fn(x, qw)
+    fn(x, qw)  # the second call is not traced again: no second event
+    assert len(tracer.spans(name="kernel/mixed_gemm_tiles")) == before + 1
+    t = mg.pick_gemm_tiles(M, K, N, 8, 128, 4)
+    assert last() == {"m": M, "k": K, "n": N, "bits": 8, "group": 128,
+                      "tm": t.tm, "tn": t.tn, "tk": t.tk,
+                      "grid_steps": t.grid_steps,
+                      "code_bytes_per_step": t.code_bytes_per_step}
+    qw49 = quantize_gemm_weight(w[:98, :33], bits=8, group=49)
+    mixed_gemm(x[:, :98], qw49)
+    assert last() == {"m": M, "k": 98, "n": 33, "bits": 8, "group": 49,
+                      "fallback": 1}

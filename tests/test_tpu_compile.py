@@ -138,22 +138,35 @@ def test_flash_attention_on_a_mesh_compiles(topo, mosaic):
 
 
 @pytest.mark.parametrize("bits", [8, 4, 6])
-@pytest.mark.parametrize("m", [16, 256], ids=["decode", "prefill"])
-def test_mixed_gemm_compiles(one_chip, mosaic, bits, m):
+@pytest.mark.parametrize("k,n", [(HIDDEN, H * D), (HIDDEN, KV * D),
+                                 (HIDDEN, MLP), (MLP, HIDDEN)],
+                         ids=["wq-wo", "wk-wv", "w_in-w_gate", "w_out"])
+@pytest.mark.parametrize("m", [32, 512], ids=["decode", "prefill"])
+def test_mixed_gemm_compiles(one_chip, mosaic, bits, k, n, m):
+    """The seven projections of a layer at the rows the serving cells run
+    (32 decode rows, a chunk of 512 tokens), at the tile the picker gives
+    them: the only place a machine without the chip sees a tile that
+    overflows VMEM or a block Mosaic refuses.  An int8 call reads its
+    weights once (one M tile) in steps of at least 1 MB of codes."""
     from deepspeed_tpu.ops.pallas.mixed_gemm import (QuantizedWeight,
                                                      mixed_gemm,
+                                                     pick_gemm_tiles,
                                                      quantize_gemm_weight)
 
-    for k, n in ((HIDDEN, MLP), (MLP, HIDDEN), (HIDDEN, (H + 2 * KV) * D)):
-        qw = jax.eval_shape(
-            functools.partial(quantize_gemm_weight, bits=bits, group=256),
-            jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
-        _compile(
-            lambda x, c, s: mixed_gemm(x, QuantizedWeight(c, s, bits, 256, k)),
-            _sds((m, k), jnp.bfloat16, one_chip),
-            _sds(qw.codes.shape, qw.codes.dtype, one_chip),
-            _sds(qw.scales.shape, qw.scales.dtype, one_chip),
-            kernels=["mixed_gemm"])
+    tiles = pick_gemm_tiles(m, k, n, bits, 256)
+    assert tiles.tm == m and tiles.tk % 256 == 0 and k % tiles.tk == 0
+    assert tiles.grid_steps == (n // tiles.tn) * (k // tiles.tk)
+    if bits == 8:
+        assert tiles.code_bytes_per_step == tiles.tk * tiles.tn >= 1 << 20
+    qw = jax.eval_shape(
+        functools.partial(quantize_gemm_weight, bits=bits, group=256),
+        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+    _compile(
+        lambda x, c, s: mixed_gemm(x, QuantizedWeight(c, s, bits, 256, k)),
+        _sds((m, k), jnp.bfloat16, one_chip),
+        _sds(qw.codes.shape, qw.codes.dtype, one_chip),
+        _sds(qw.scales.shape, qw.scales.dtype, one_chip),
+        kernels=["mixed_gemm"])
 
 
 @pytest.mark.parametrize("m", [32, 512], ids=["decode", "prefill"])
