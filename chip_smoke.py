@@ -78,6 +78,13 @@ class Sizes:
     # maximum near 4, the top two lie 0.2 apart on average, and bf16 noise
     # through the stack is under 0.1; a wrong token lies about 4 under
     margin: float = 0.5
+    # -- sparse experts: two layers at OLMoE-1B-7B's widths (64 experts of
+    # 2048 x 1024, top 8) are 0.81 GB of int8 codes; the engine quantizes
+    # them on the host, a layer's experts at a time
+    moe_preset: str = "olmoe-1b-7b"
+    moe_layers: int = 2
+    moe_requests: Tuple[Tuple[int, int], ...] = (
+        (48, 16), (600, 20), (20, 24), (130, 12))
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -510,6 +517,75 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
                        decode.compiled_text())
 
 
+def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
+                     ) -> None:
+    """An int8 sparse-expert model through ``InferenceEngineV2`` (which
+    quantizes it, experts included, on the host): chunked prefill and decode
+    over the routed experts' grouped W8A16 GEMM; every served token within
+    ``margin`` of the maximum of ``tfm.forward`` over the same codes; no
+    grouped or mixed GEMM fallen back; no KV block leaked."""
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-moe-int8"
+    cfg = tfm.get_config(sz.moe_preset, num_layers=sz.moe_layers,
+                         dtype="bfloat16", param_dtype="bfloat16",
+                         attn_impl="xla")
+    log(phase, preset=sz.moe_preset, layers=cfg.num_layers,
+        experts=cfg.num_experts, top_k=cfg.moe_top_k,
+        params_m=round(cfg.num_params() / 1e6, 1))
+    params = jax.jit(lambda k: tfm.init_params(k, cfg))(
+        jax.random.PRNGKey(seed))
+    tracer.clear()
+    t0 = time.perf_counter()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.num_blocks,
+        max_blocks_per_seq=sz.max_blocks_per_seq, quantize_bits=8,
+        quantize_group=min(256, cfg.hidden_size)))
+    log(phase, quantize_seconds=round(time.perf_counter() - t0, 1))
+    rng = np.random.default_rng([seed, 27])
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n, _ in sz.moe_requests]
+    uids = [engine.put(p, max_new_tokens=n)
+            for p, (_, n) in zip(prompts, sz.moe_requests)]
+    whole = engine.generate_all(burst=1)  # step by step, as a server does
+    out = {u: whole[u][len(p):] for p, u in zip(prompts, uids)}
+    for uid, (_, n) in zip(uids, sz.moe_requests):
+        if len(out[uid]) != n:
+            raise AssertionError(f"{phase}: asked {n} tokens, got "
+                                 f"{len(out[uid])}")
+    if engine.free_blocks != engine.total_blocks:
+        raise AssertionError(f"{phase}: KV blocks leaked")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
+    events = [s.attrs for s in tracer.spans()
+              if s.name.startswith("kernel/") and s.name.endswith("_tiles")]
+    grouped = sorted({(a["k"], a["n"], a["rows"], a["tile_m"], a["tn"])
+                      for a in events if "rows" in a and "fallback" not in a})
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        experts_hit=[round(a["moe_experts_hit"], 1) for a in steps[-3:]],
+        gemm_tile_events=len(events), grouped_k_n_rows_tilem_tn=grouped)
+    fallen = [a for a in events if "fallback" in a]
+    if check_kernels and (fallen or not grouped):
+        raise AssertionError(f"{phase}: of {len(events)} GEMM tile events, "
+                             f"grouped {grouped}, fallen back: {fallen}")
+    sequences = [p + out[u] for p, u in zip(prompts, uids)]
+    m, rank, std = reference_margins(engine.params, cfg, sequences)
+    worst, exact, checked = 0.0, 0, 0
+    for i, (p, u) in enumerate(zip(prompts, uids)):
+        rows = slice(len(p) - 1, len(p) - 1 + len(out[u]))
+        worst = max(worst, float(m[i, rows].max()))
+        exact += int((rank[i, rows] == 0).sum())
+        checked += len(out[u])
+    log(phase, served_tokens=checked, reference_argmax=exact,
+        worst_margin=round(worst, 4), allowed=sz.margin,
+        logit_std=round(std, 3))
+    if not worst <= sz.margin:
+        raise AssertionError(f"{phase}: a served token lies {worst:.3f} "
+                             f"under the reference's maximum")
+    memory_line(phase, jax.local_devices()[0])
+
+
 def check_gemm_tiles(phase: str, port: int) -> None:
     """``GET /debug/trace`` of the warmed server: every mixed GEMM the step
     programs traced left a ``kernel/mixed_gemm_tiles`` event with its tile,
@@ -665,6 +741,8 @@ def main() -> int:
         phase_server(sz, args.seed, quantize_bits=0)
         gc.collect()
         phase_server(sz, args.seed, quantize_bits=8)
+        gc.collect()
+        phase_moe_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

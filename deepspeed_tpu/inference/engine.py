@@ -67,8 +67,10 @@ def forward_cached(params, tokens, cache, start_pos, cfg: tfm.TransformerConfig)
         a_in = tfm._norm(h, layer_params["ln1"], cfg.norm, cfg.norm_eps)
         nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         ap = layer_params["attn"]
-        q = tfm._lin(a_in, ap, "wq", "bq").reshape(B, T, nh, hd)
-        k = tfm._lin(a_in, ap, "wk", "bk").reshape(B, T, nkv, hd)
+        q = tfm.qk_norm(tfm._lin(a_in, ap, "wq", "bq"), ap, "q_norm", cfg
+                        ).reshape(B, T, nh, hd)
+        k = tfm.qk_norm(tfm._lin(a_in, ap, "wk", "bk"), ap, "k_norm", cfg
+                        ).reshape(B, T, nkv, hd)
         v = tfm._lin(a_in, ap, "wv", "bv").reshape(B, T, nkv, hd)
         if cfg.position == "rope":
             cos = jax.lax.dynamic_slice_in_dim(cos_full, start_pos, T)
@@ -106,9 +108,9 @@ def forward_cached(params, tokens, cache, start_pos, cfg: tfm.TransformerConfig)
         m_src = h if cfg.parallel_residual else h + attn_out
         m_in = tfm._norm(m_src, layer_params["ln2"], cfg.norm, cfg.norm_eps)
         if cfg.num_experts > 0:
-            from ..moe.layer import dense_moe_block
+            from ..moe.dropless import serving_moe_block
 
-            mlp_out = dense_moe_block(m_in, layer_params["moe"], cfg)
+            mlp_out, _ = serving_moe_block(m_in, layer_params["moe"], cfg)
         else:
             mlp_out = tfm._mlp_block(m_in, layer_params["mlp"], cfg)
         h = (h + attn_out + mlp_out) if cfg.parallel_residual \
@@ -154,8 +156,8 @@ class InferenceEngine:
             raise ValueError(
                 "expert_choice routing is non-causal (experts pick top-C "
                 "tokens over the whole sequence) — autoregressive decode "
-                "with it is incoherent; serve with moe_routing='capacity' "
-                "or 'dropless' (dataclasses.replace(cfg, moe_routing=...))")
+                "with it is incoherent; serve experts trained with top-k "
+                "routing (dataclasses.replace(cfg, moe_routing='dropless'))")
         self.model_config = dataclasses.replace(model_config, dtype=icfg.dtype)
         # a training engine in the same process may have pinned the tp×sp
         # gather anchors — they name mesh axes this engine's mesh lacks

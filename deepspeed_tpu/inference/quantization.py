@@ -5,9 +5,12 @@ quantization``, matmul_4bit/8bit paths) — weights live in HBM as int8/int4
 and dequantize inside the GEMM. Here the projection weights of every
 transformer layer become ``QuantizedWeight`` pytree nodes that
 ``models/transformer._lin`` routes through the Pallas mixed GEMM; stacked
-(L, K, N) layers slice transparently under the layer scan.
+(L, K, N) layers slice transparently under the layer scan.  The routed
+experts of an MoE layer (``moe.w_in`` / ``w_gate`` / ``w_out``, stacked
+(L, E, K, N)) are quantized per expert in the same format and served by the
+grouped mixed GEMM (``ops/pallas/grouped_mixed_gemm``).
 
-Embeddings / lm_head / norms stay high-precision (gather and tiny tensors
+Embeddings / lm_head / norms / the MoE router stay high-precision (gather and tiny tensors
 gain nothing from int codes), matching the reference's exclude list.
 """
 
@@ -21,22 +24,18 @@ import jax
 from ..ops.pallas.mixed_gemm import QuantizedWeight, quantize_gemm_weight
 from ..utils.logging import logger
 
-# projection weights inside each layer's attn/mlp dicts
+# projection weights inside each layer's attn/mlp/moe dicts (the router, the
+# PR-MoE shared expert and its coefficient are not among the keys)
 _QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate"})
-_QUANT_PARENTS = frozenset({"attn", "mlp"})
+_QUANT_PARENTS = frozenset({"attn", "mlp", "moe"})
 
 
 def quantize_model_params(params: Dict[str, Any], bits: int = 8,
                           group: int = 256,
                           quantize=quantize_gemm_weight) -> Dict[str, Any]:
     """Replace layer projection weights with QuantizedWeight nodes."""
-    saw_moe = False
-
     def walk(tree, parent=None):
-        nonlocal saw_moe
         if isinstance(tree, dict):
-            if "moe" in tree:
-                saw_moe = True
             return {k: (quantize(v, bits=bits, group=group)
                         if (parent in _QUANT_PARENTS and k in _QUANT_KEYS
                             and getattr(v, "ndim", 0) >= 2)
@@ -44,14 +43,7 @@ def quantize_model_params(params: Dict[str, Any], bits: int = 8,
                     for k, v in tree.items()}
         return tree
 
-    out = walk(params)
-    if saw_moe:
-        logger.warning(
-            "quantize_model_params: expert (MoE) weights stay "
-            "high-precision — the einsum dispatch path does not take "
-            "QuantizedWeight; only attention/MLP projections were quantized. "
-            "Check quantized_bytes() for the actual savings.")
-    return out
+    return walk(params)
 
 
 def shardings_for_quantized(params: Dict[str, Any],
@@ -110,10 +102,11 @@ def quantize_on_host(params: Dict[str, Any], bits: int,
 def _quantize_layerwise(w: jax.Array, bits: int, group: int
                         ) -> QuantizedWeight:
     """One leaf, jitted (it runs where its committed input is, on the
-    host), and a stacked (L, K, N) leaf a layer at a time: the f32
-    intermediates are then one layer's.  Op by op, a 32-layer stack of a
-    7B model's MLP weight holds three 7.5 GB f32 copies of itself."""
-    if w.ndim != 3:
+    host), and a stacked (L, K, N) or (L, E, K, N) leaf a layer at a time:
+    the f32 intermediates are then one layer's (one layer's experts).  Op by
+    op, a 32-layer stack of a 7B model's MLP weight holds three 7.5 GB f32
+    copies of itself."""
+    if w.ndim < 3:
         return quantize_gemm_weight(w, bits=bits, group=group)
     return jax.lax.map(
         functools.partial(quantize_gemm_weight, bits=bits, group=group), w)

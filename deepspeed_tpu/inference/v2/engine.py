@@ -268,6 +268,43 @@ def prefill_scatter_coords(seq_index, position_ids, chunk_start, max_seqs: int,
     return scat_row, scat_col, row, jnp.clip(qp_col, 0, Qp - 1)
 
 
+def _ffn(m_in, lp, model_cfg: tfm.TransformerConfig, experts=None,
+         valid=None):
+    """The feed-forward half of a serving layer, the one place the three step
+    bodies (mixed, decode, verify) get it: ``m_in (..., H)`` → ``(out, moe
+    stats or None)``.  An MoE model runs ``moe/dropless.serving_moe_block``:
+    every top-k assignment is computed, so a row's output does not depend on
+    the rows beside it.  ``experts`` is what ``hoist_expert_codes`` kept out
+    of the layer scan; ``valid`` marks the rows the stats count."""
+    if model_cfg.num_experts > 0:
+        from ...moe.dropless import serving_moe_block
+
+        return serving_moe_block(m_in, lp["moe"], model_cfg, stacked=experts,
+                                 valid=valid)
+    if m_in.ndim == 2:  # as the dense programs were always traced: one
+        # batch of all rows (a dense model's step programs stay bit for bit
+        # the parent's, and hit its entries in the compile cache)
+        return tfm._mlp_block(m_in[None], lp["mlp"], model_cfg)[0], None
+    return tfm._mlp_block(m_in, lp["mlp"], model_cfg), None
+
+
+def _scan_layers(params):
+    """→ (the stacked layers as the layer scan slices them, the quantized
+    expert codes the scan must leave whole, or None)."""
+    from ...moe.dropless import hoist_expert_codes
+
+    return hoist_expert_codes(params["layers"])
+
+
+def _moe_step_stats(per_layer):
+    """Per-layer ``(L, 2)`` stats of ``_ffn`` → int32 ``(2,)`` for the step:
+    experts hit summed over layers (the host divides by L), and the largest
+    rows-per-expert of any layer.  None for a dense model."""
+    if per_layer is None:
+        return None
+    return jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max()])
+
+
 def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
     dt = jnp.dtype(v2.dtype)
     bs = v2.block_size
@@ -313,7 +350,8 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 row_adapter[jnp.clip(seq_index, 0, row_adapter.shape[0] - 1)],
                 0)
 
-        xs = (params["layers"], caches["k"], caches["v"])
+        layers, experts = _scan_layers(params)
+        xs = (layers, caches["k"], caches["v"])
         if adapters is not None:
             xs = xs + (adapters,)
 
@@ -332,8 +370,10 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 k = k + _adapter_proj_delta(a_in, ad["wk"], tok_slot)
             if "wv" in ad:
                 v = v + _adapter_proj_delta(a_in, ad["wv"], tok_slot)
-            q = q.reshape(T, nh, hd)
-            k = k.reshape(T, nkv, hd)
+            q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
+                            ).reshape(T, nh, hd)
+            k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
+                            ).reshape(T, nkv, hd)
             v = v.reshape(T, nkv, hd)
             if model_cfg.position == "rope":
                 cos = cos_full[position_ids]
@@ -370,18 +410,13 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
             m_src = x if model_cfg.parallel_residual else x + attn_out
             m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm,
                              model_cfg.norm_eps)
-            if model_cfg.num_experts > 0:
-                from ...moe.layer import dense_moe_block
-
-                mlp_out = dense_moe_block(m_in[None], lp["moe"], model_cfg)[0]
-            else:
-                mlp_out = tfm._mlp_block(m_in[None], lp["mlp"], model_cfg)[0]
+            mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts,
+                                      write_mask)
             x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
                 else (m_src + mlp_out)
-            return x, (k_cache, v_cache)
+            return x, (k_cache, v_cache, moe_stats)
 
-        x, scan_out = jax.lax.scan(layer_body, x, xs)
-        new_k, new_v = scan_out[0], scan_out[1]
+        x, (new_k, new_v, moe_stats) = jax.lax.scan(layer_body, x, xs)
         x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
         last_hidden = x[logits_rows]  # (max_seqs, H)
         if model_cfg.tie_embeddings:
@@ -391,9 +426,13 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
             if "b" in params["lm_head"]:
                 logits = logits + params["lm_head"]["b"].astype(dt)
         # last_hidden rides along for the self-draft speculation heads (the
-        # carried state their next proposals are computed from)
-        return (logits.astype(jnp.float32), last_hidden.astype(jnp.float32),
-                {"k": new_k, "v": new_v})
+        # carried state their next proposals are computed from); an MoE
+        # model's step stats ride fourth (a dense model returns three)
+        out = (logits.astype(jnp.float32), last_hidden.astype(jnp.float32),
+               {"k": new_k, "v": new_v})
+        if moe_stats is not None:
+            out += (_moe_step_stats(moe_stats),)
+        return out
 
     if v2.adapter_slots:
         def mixed_step(params, caches, token_ids, position_ids, seq_index,
@@ -415,6 +454,15 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                  lambda: jax.jit(mixed_step, donate_argnums=(1,)))
 
 
+def _with_stats(tokens, moe_stats):
+    """An MoE model's step stats behind the sampled tokens, in the one int32
+    array the step fetches anyway: ``(max_seqs,)`` for a dense model,
+    ``(max_seqs + 2,)`` for an MoE model."""
+    if moe_stats is None:
+        return tokens
+    return jnp.concatenate([tokens, moe_stats])
+
+
 def build_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
     """Pure-decode step: one token per sequence, attention through the paged
     Pallas kernel (ops/pallas/paged_attention.py) — the FastGen decode hot
@@ -429,18 +477,20 @@ def build_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
         def decode_step(params, caches, token_ids, position_ids,
                         block_tables, context_lens, temps, rng, seeds,
                         adapters, row_adapter):
-            logits, caches = _decode_body(
+            logits, caches, moe_stats = _decode_body(
                 params, caches, token_ids, position_ids, block_tables,
                 context_lens, model_cfg, v2, adapters=adapters,
                 row_adapter=row_adapter)
-            return sample_rows(logits, temps, rng, seeds), caches
+            return _with_stats(sample_rows(logits, temps, rng, seeds),
+                               moe_stats), caches
     else:
         def decode_step(params, caches, token_ids, position_ids,
                         block_tables, context_lens, temps, rng, seeds):
-            logits, caches = _decode_body(params, caches, token_ids,
-                                          position_ids, block_tables,
-                                          context_lens, model_cfg, v2)
-            return sample_rows(logits, temps, rng, seeds), caches
+            logits, caches, moe_stats = _decode_body(
+                params, caches, token_ids, position_ids, block_tables,
+                context_lens, model_cfg, v2)
+            return _with_stats(sample_rows(logits, temps, rng, seeds),
+                               moe_stats), caches
 
     return _memo(("decode_fwd", model_cfg, dataclasses.astuple(v2)),
                  lambda: jax.jit(decode_step, donate_argnums=(1,)))
@@ -469,10 +519,10 @@ def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config,
 
         def step(carry, _):
             caches, tok, pos, ctx, rng = carry
-            logits, caches = _decode_body(params, caches, tok, pos,
-                                          block_tables, ctx, model_cfg, v2,
-                                          adapters=adapters,
-                                          row_adapter=row_adapter)
+            logits, caches, _ = _decode_body(params, caches, tok, pos,
+                                             block_tables, ctx, model_cfg, v2,
+                                             adapters=adapters,
+                                             row_adapter=row_adapter)
             rng, step_rng = jax.random.split(rng)
             nxt = sample_rows(logits, temps, step_rng, seeds)
             return (caches, nxt, pos + alive, ctx + alive, rng), nxt
@@ -521,7 +571,8 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
                  context_lens, model_cfg, v2, adapters=None,
                  row_adapter=None):
     """Single-token decode shared by build_decode_forward and the multi-step
-    scan (context_lens INCLUDE the current token).  With ``adapters`` (the
+    scan (context_lens INCLUDE the current token); → (logits, caches, an MoE
+    model's step stats or None).  With ``adapters`` (the
     stacked per-slot LoRA factors) and ``row_adapter`` (per-row slot
     vector), each row's attention projections add its adapter's gathered
     low-rank delta on top of the unchanged base path."""
@@ -545,7 +596,8 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
     offsets = position_ids % bs
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
 
-    xs = (params["layers"], caches["k"], caches["v"])
+    layers, experts = _scan_layers(params)
+    xs = (layers, caches["k"], caches["v"])
     if adapters is not None:
         xs = xs + (adapters,)
 
@@ -564,8 +616,10 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
             k = k + _adapter_proj_delta(a_in, ad["wk"], row_adapter)
         if "wv" in ad:
             v = v + _adapter_proj_delta(a_in, ad["wv"], row_adapter)
-        q = q.reshape(S, nh, hd)
-        k = k.reshape(S, nkv, hd)
+        q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
+                        ).reshape(S, nh, hd)
+        k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
+                        ).reshape(S, nkv, hd)
         v = v.reshape(S, nkv, hd)
         if model_cfg.position == "rope":
             cos = cos_full[position_ids][:, None, :].astype(dt)
@@ -598,18 +652,12 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
                 o_flat, ad["wo"], row_adapter)
         m_src = x if model_cfg.parallel_residual else x + attn_out
         m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
-        if model_cfg.num_experts > 0:
-            from ...moe.layer import dense_moe_block
-
-            mlp_out = dense_moe_block(m_in[None], lp["moe"], model_cfg)[0]
-        else:
-            mlp_out = tfm._mlp_block(m_in[None], lp["mlp"], model_cfg)[0]
+        mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts, active)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
-        return x, (k_cache, v_cache)
+        return x, (k_cache, v_cache, moe_stats)
 
-    x, scan_out = jax.lax.scan(layer_body, x, xs)
-    new_k, new_v = scan_out[0], scan_out[1]
+    x, (new_k, new_v, moe_stats) = jax.lax.scan(layer_body, x, xs)
     x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
     if model_cfg.tie_embeddings:
         logits = x @ params["embed"]["tokens"].astype(dt).T
@@ -617,7 +665,8 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
         logits = x @ params["lm_head"]["w"].astype(dt)
         if "b" in params["lm_head"]:
             logits = logits + params["lm_head"]["b"].astype(dt)
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return (logits.astype(jnp.float32), {"k": new_k, "v": new_v},
+            _moe_step_stats(moe_stats))
 
 
 # ---------------------------------------------------------------------------
@@ -639,7 +688,7 @@ class InferenceEngineV2:
             raise ValueError(
                 "expert_choice routing is non-causal — continuous-batching "
                 "decode with it would route across unrelated requests; "
-                "serve with moe_routing='capacity' or 'dropless'")
+                "serve experts trained with top-k routing")
         if getattr(model_config, "position", "rope") == "alibi":
             raise NotImplementedError(
                 "v2's paged Pallas attention takes no additive logit bias "
@@ -704,6 +753,17 @@ class InferenceEngineV2:
         self.caches = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         self._fwd = build_ragged_forward(self.model_cfg, self.cfg)
         self._decode_fwd = build_decode_forward(self.model_cfg, self.cfg)
+        # an MoE model's step programs: assignments (rows x top-k) and the
+        # rows of the grouped layout they are computed on, by step kind
+        self._moe_rows: Dict[str, Tuple[int, int]] = {}
+        if self.model_cfg.num_experts > 0:
+            from ...moe.dropless import padded_rows
+
+            E, k = self.model_cfg.num_experts, self.model_cfg.moe_top_k
+            for kind, rows in (("decode", self.cfg.max_seqs),
+                               ("mixed", self.cfg.max_tokens_per_step)):
+                self._moe_rows[kind] = (rows * k, padded_rows(rows * k, E))
+        self._moe_stats = None  # (experts hit, rows max) of the last step
         self._multi_decode = {}  # num_steps -> jitted burst decoder
         self.running: Dict[int, SequenceDescriptor] = {}
         self.waiting: Deque[SequenceDescriptor] = deque()
@@ -1478,7 +1538,7 @@ class InferenceEngineV2:
         toks, self.caches = self._decode_fwd(self.params, self.caches, *args)
         tracer.end(sp_dispatch)
         sp_wait = tracer.begin("engine/wait", **sub)
-        sampled = np.asarray(toks)
+        sampled = self._split_stats(np.asarray(toks))
         tracer.end(sp_wait)
         sp = tracer.begin("engine/finish", **sub)
         rows = np.nonzero(t.active)[0]
@@ -1487,6 +1547,16 @@ class InferenceEngineV2:
         self._advance_rows(sel)
         tracer.end(sp)
         return out, len(rows), _device_ms(sp_dispatch, sp_wait)
+
+    def _split_stats(self, fetched: "np.ndarray") -> "np.ndarray":
+        """The tokens of a step's one fetch; an MoE model's two stats behind
+        them are kept for the step's span."""
+        if not self._moe_rows:
+            return fetched
+        n = self.cfg.max_seqs
+        self._moe_stats = (float(fetched[n]) / self.model_cfg.num_layers,
+                           int(fetched[n + 1]))
+        return fetched[:n]
 
     def _spec_decode_step(self, temperature: float, rng: Optional[jax.Array],
                           sub: Dict[str, Any]) -> _StepResult:
@@ -1580,6 +1650,7 @@ class InferenceEngineV2:
         prop0, acc0 = self.spec_proposed, self.spec_accepted
         self.steps += 1
         sub = {"kind": kind, "step": self.steps}  # on the step and its children
+        self._moe_stats = None
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
@@ -1596,6 +1667,9 @@ class InferenceEngineV2:
         if kind == "spec":
             attrs["proposed"] = self.spec_proposed - prop0
             attrs["accepted"] = self.spec_accepted - acc0
+        if self._moe_stats is not None:  # an MoE model's step ran the device
+            attrs["moe_rows"], attrs["moe_rows_padded"] = self._moe_rows[kind]
+            attrs["moe_experts_hit"], attrs["moe_rows_max"] = self._moe_stats
         tracer.end(sp, **attrs)
         recorder.record_step({
             "kind": kind, "t_start": t0, "t_end": time.monotonic(),
@@ -1645,13 +1719,14 @@ class InferenceEngineV2:
             ad_args = (self.adapter_stack, jnp.asarray(row_ad))
         tracer.end(sp)
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
-        logits, hidden, self.caches = self._fwd(
+        logits, hidden, self.caches, *rest = self._fwd(
             self.params, self.caches, *batch_args, *ad_args)
+        moe_stats = rest[0] if rest else None  # an MoE model's fourth
         if self.cfg.spec_mode == "draft":
             # mirror every target KV write into the draft cache (same block
             # tables, its own pool array) so the draft scan can decode from
             # position ctx without ever re-prefilling
-            _, _, self._draft_caches = self._draft_fwd(
+            _, _, self._draft_caches, *_ = self._draft_fwd(
                 self.draft_params, self._draft_caches, *batch_args)
         tracer.end(sp_dispatch)
         # per-row selection mirrors the jitted decode path: pick rows carry
@@ -1664,11 +1739,12 @@ class InferenceEngineV2:
             temps[row] = (temperature if seq.temperature is None
                           else seq.temperature)
             seeds[row] = np.int32(np.uint32(seq.seed & 0xFFFFFFFF))
-        sampled = sample_rows(logits, jnp.asarray(temps),
-                              self._step_rng(rng), jnp.asarray(seeds))
+        sampled = _with_stats(
+            sample_rows(logits, jnp.asarray(temps), self._step_rng(rng),
+                        jnp.asarray(seeds)), moe_stats)
         tracer.end(sp)
         sp_wait = tracer.begin("engine/wait", **sub)
-        sampled = np.asarray(sampled)
+        sampled = self._split_stats(np.asarray(sampled))
         hidden_np = (np.asarray(hidden)
                      if self.cfg.spec_mode == "self_draft" else None)
         tracer.end(sp_wait)

@@ -105,7 +105,7 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
             lp, k_cache, v_cache, ad = inp
         else:
             (lp, k_cache, v_cache), ad = inp, {}
-        from .engine import _adapter_proj_delta
+        from .engine import _adapter_proj_delta, _ffn
 
         a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
         q = tfm._lin(a_in, lp["attn"], "wq", "bq")
@@ -117,8 +117,10 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
             k = k + _adapter_proj_delta(a_in, ad["wk"], row_adapter)
         if "wv" in ad:
             v = v + _adapter_proj_delta(a_in, ad["wv"], row_adapter)
-        q = q.reshape(S, Q, nh, hd)
-        k = k.reshape(S, Q, nkv, hd)
+        q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
+                        ).reshape(S, Q, nh, hd)
+        k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
+                        ).reshape(S, Q, nkv, hd)
         v = v.reshape(S, Q, nkv, hd)
         if model_cfg.position == "rope":
             cos = cos_full[pos][:, :, None, :].astype(dt)
@@ -147,17 +149,15 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
                 o_flat, ad["wo"], row_adapter)
         m_src = x if model_cfg.parallel_residual else x + attn_out
         m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
-        if model_cfg.num_experts > 0:
-            from ...moe.layer import dense_moe_block
-
-            mlp_out = dense_moe_block(m_in, lp["moe"], model_cfg)
-        else:
-            mlp_out = tfm._mlp_block(m_in, lp["mlp"], model_cfg)
+        mlp_out, _ = _ffn(m_in, lp, model_cfg, experts)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
         return x, (k_cache, v_cache)
 
-    xs = (params["layers"], caches["k"], caches["v"])
+    from .engine import _scan_layers
+
+    layers, experts = _scan_layers(params)
+    xs = (layers, caches["k"], caches["v"])
     if adapters is not None:
         xs = xs + (adapters,)
     x, (new_k, new_v) = jax.lax.scan(layer_body, x, xs)
@@ -325,7 +325,7 @@ def build_draft_spec_step(model_cfg: tfm.TransformerConfig,
             dcaches, tok, it_rng = carry
             pos = ctx + i
             ok = active & (pos < pos_limit)
-            dlogits, dcaches = _decode_body(
+            dlogits, dcaches, _ = _decode_body(
                 draft_params, dcaches, tok, pos, block_tables,
                 (pos + 1) * ok, draft_cfg, v2)
             it_rng, s_rng = jax.random.split(it_rng)

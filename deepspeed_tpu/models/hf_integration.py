@@ -174,6 +174,26 @@ def config_from_hf(hf_config) -> tfm.TransformerConfig:
             rope_theta=get("rope_theta", 10000.0),
             norm_eps=get("rms_norm_eps", 1e-5),
             tie_embeddings=bool(get("tie_word_embeddings", False)))
+    if model_type == "olmoe":
+        # llama attention with an RMSNorm of q and k over the whole
+        # projection; 64 routed SwiGLU experts of width intermediate_size,
+        # dropless, gates the raw top-k probabilities unless norm_topk_prob
+        if get("clip_qkv") is not None or get("attention_bias", False):
+            raise ValueError("olmoe: clip_qkv / attention_bias not supported")
+        return tfm.TransformerConfig(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_layers=get("num_hidden_layers"),
+            num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            max_seq_len=get("max_position_embeddings", 4096),
+            rope_theta=get("rope_theta", 10000.0),
+            norm_eps=get("rms_norm_eps", 1e-5),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            num_experts=get("num_experts"),
+            moe_top_k=get("num_experts_per_tok", 8),
+            moe_norm_topk=bool(get("norm_topk_prob", False)),
+            moe_routing="dropless", qk_norm=True)
     # llama / mistral / qwen2 / mixtral share the llama schema
     num_experts = get("num_local_experts", 0) or 0
     sliding = get("sliding_window") or 0
@@ -404,6 +424,54 @@ def params_from_hf_mixtral(state_dict: Dict[str, Any],
                 "w_gate": experts("w1"),
                 "w_out": experts("w2"),
                 "w_in": experts("w3"),
+            },
+        },
+        "final_norm": {"scale": sd["model.norm.weight"]},
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"w": sd["lm_head.weight"].T}
+    return params
+
+
+def params_from_hf_olmoe(state_dict: Dict[str, Any],
+                         cfg: tfm.TransformerConfig) -> Dict[str, Any]:
+    """OLMoE: llama attention plus ``q_norm`` / ``k_norm`` (their entries
+    follow the q/k columns through the rope un-permutation: an RMS over the
+    whole projection does not see the order, the scale does), and
+    ``mlp.gate`` routing over ``mlp.experts.{e}.{gate,up,down}_proj``."""
+    sd = {k: np.asarray(v) for k, v in state_dict.items()}
+    L, E = cfg.num_layers, cfg.num_experts
+    attn = "model.layers.{}.self_attn."
+
+    def experts(w_name):
+        return _stack([
+            np.stack([sd[f"model.layers.{i}.mlp.experts.{e}.{w_name}.weight"
+                         ].T for e in range(E)]) for i in range(L)])
+
+    params: Dict[str, Any] = {
+        "embed": {"tokens": sd["model.embed_tokens.weight"]},
+        "layers": {
+            "attn": {
+                "wq": _lw_rope(sd, attn + "q_proj.weight", L, cfg.num_heads,
+                               cfg.head_dim),
+                "wk": _lw_rope(sd, attn + "k_proj.weight", L, cfg.kv_heads,
+                               cfg.head_dim),
+                "wv": _lw(sd, attn + "v_proj.weight", L),
+                "wo": _lw(sd, attn + "o_proj.weight", L),
+                "q_norm": {"scale": _lb_rope(sd, attn + "q_norm.weight", L,
+                                             cfg.num_heads, cfg.head_dim)},
+                "k_norm": {"scale": _lb_rope(sd, attn + "k_norm.weight", L,
+                                             cfg.kv_heads, cfg.head_dim)},
+            },
+            "ln1": {"scale": _lnorm(
+                sd, "model.layers.{}.input_layernorm.weight", L)},
+            "ln2": {"scale": _lnorm(
+                sd, "model.layers.{}.post_attention_layernorm.weight", L)},
+            "moe": {
+                "router": _lw(sd, "model.layers.{}.mlp.gate.weight", L),
+                "w_gate": experts("gate_proj"),
+                "w_out": experts("down_proj"),
+                "w_in": experts("up_proj"),
             },
         },
         "final_norm": {"scale": sd["model.norm.weight"]},
@@ -1069,6 +1137,41 @@ def params_to_hf_mixtral(params: Dict[str, Any], cfg: tfm.TransformerConfig
     return out
 
 
+def params_to_hf_olmoe(params: Dict[str, Any], cfg: tfm.TransformerConfig
+                       ) -> Dict[str, np.ndarray]:
+    """OLMoE export: llama attention + q_norm / k_norm (back through the rope
+    permutation with their columns) + per-expert gate/up/down + router."""
+    lp = params["layers"]
+    out: Dict[str, np.ndarray] = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["tokens"]),
+        "model.norm.weight": np.asarray(params["final_norm"]["scale"]),
+    }
+    attn, moe = lp["attn"], lp["moe"]
+    for i in range(cfg.num_layers):
+        pre = f"model.layers.{i}"
+        for hf, ours, heads in (("q", "wq", cfg.num_heads),
+                                ("k", "wk", cfg.kv_heads)):
+            out[f"{pre}.self_attn.{hf}_proj.weight"] = _rope_permute(
+                np.asarray(attn[ours][i]), heads, cfg.head_dim).T
+            out[f"{pre}.self_attn.{hf}_norm.weight"] = _rope_permute_bias(
+                np.asarray(attn[f"{hf}_norm"]["scale"][i]), heads,
+                cfg.head_dim)
+        out[f"{pre}.self_attn.v_proj.weight"] = np.asarray(attn["wv"][i]).T
+        out[f"{pre}.self_attn.o_proj.weight"] = np.asarray(attn["wo"][i]).T
+        out[f"{pre}.input_layernorm.weight"] = np.asarray(lp["ln1"]["scale"][i])
+        out[f"{pre}.post_attention_layernorm.weight"] = \
+            np.asarray(lp["ln2"]["scale"][i])
+        out[f"{pre}.mlp.gate.weight"] = np.asarray(moe["router"][i]).T
+        for e in range(cfg.num_experts):
+            epre = f"{pre}.mlp.experts.{e}"
+            out[f"{epre}.gate_proj.weight"] = np.asarray(moe["w_gate"][i, e]).T
+            out[f"{epre}.down_proj.weight"] = np.asarray(moe["w_out"][i, e]).T
+            out[f"{epre}.up_proj.weight"] = np.asarray(moe["w_in"][i, e]).T
+    if not cfg.tie_embeddings and "lm_head" in params:
+        out["lm_head.weight"] = np.asarray(params["lm_head"]["w"]).T
+    return out
+
+
 def params_to_hf_phi3(params: Dict[str, Any], cfg: tfm.TransformerConfig
                       ) -> Dict[str, np.ndarray]:
     """Phi-3 export: re-fuse qkv_proj and gate_up_proj."""
@@ -1255,6 +1358,7 @@ ARCH_CONVERTERS: Dict[str, Callable] = {
     "mistral": params_from_hf_llama,  # llama schema (+ sliding window cfg)
     "qwen2": params_from_hf_qwen2,
     "mixtral": params_from_hf_mixtral,
+    "olmoe": params_from_hf_olmoe,
     "phi3": params_from_hf_phi3,
     "falcon": params_from_hf_falcon,
     "gpt_neox": params_from_hf_gpt_neox,
@@ -1275,6 +1379,7 @@ ARCH_EXPORTERS: Dict[str, Callable] = {
     "mistral": params_to_hf_llama,
     "qwen2": params_to_hf_qwen2,
     "mixtral": params_to_hf_mixtral,
+    "olmoe": params_to_hf_olmoe,
     "phi3": params_to_hf_phi3,
     "falcon": params_to_hf_falcon,
     "gpt_neox": params_to_hf_gpt_neox,

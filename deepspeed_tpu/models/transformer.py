@@ -72,10 +72,20 @@ class TransformerConfig:
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
-    # 'capacity' (GShard buckets; the ep all-to-all path) | 'dropless'
-    # (grouped-GEMM, no token dropping — moe/dropless.py) | 'expert_choice'
-    # (experts pick top-C tokens; balanced by construction)
+    # how the TRAINING forward routes: 'capacity' (GShard buckets; the ep
+    # all-to-all path) | 'dropless' (grouped-GEMM, no token dropping —
+    # moe/dropless.py) | 'expert_choice' (experts pick top-C tokens; balanced
+    # by construction).  The inference engines do not read it: they compute
+    # every top-k assignment (moe/dropless.serving_moe_block), and refuse
+    # experts trained with 'expert_choice'
     moe_routing: str = "capacity"
+    # whether the top-k gate weights are renormalised to sum 1 (Mixtral,
+    # GShard) or stay the raw softmax probabilities (OLMoE's
+    # ``norm_topk_prob: false``)
+    moe_norm_topk: bool = True
+    # RMSNorm of q and k over the whole projection, before the split into
+    # heads and before RoPE (OLMoE); v has none
+    qk_norm: bool = False
     # PR-MoE (reference deepspeed/moe/layer.py:17 use_residual): a dense
     # "shared expert" MLP runs beside the MoE and a learned 2-way softmax
     # coefficient mixes the two outputs per token
@@ -123,6 +133,8 @@ class TransformerConfig:
         kvh = self.kv_heads * self.head_dim
         qh = self.num_heads * self.head_dim  # != h with head_dim_override
         per_layer = h * qh + 2 * h * kvh + qh * h  # q, k, v, o
+        if self.qk_norm:
+            per_layer += qh + kvh
         n_mlp = 3 * h * f if self.is_gated_mlp else 2 * h * f
         if self.num_experts > 0:
             n_mlp = n_mlp * self.num_experts + h * self.num_experts  # experts + router
@@ -156,10 +168,23 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                        num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=32768,
                        sliding_window=4096, attn_impl="flash",
                        tie_embeddings=False),  # as published: 7.24 B
+    # allenai/OLMoE-1B-7B-0125-Instruct as published: 6.92 B, 1.3 B active;
+    # intermediate_size is the width of ONE expert; no token is dropped
+    "olmoe-1b-7b": dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                        num_layers=16, num_heads=16, num_kv_heads=16, max_seq_len=4096,
+                        rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
+                        num_experts=64, moe_top_k=8, moe_norm_topk=False,
+                        moe_routing="dropless", qk_norm=True, attn_impl="flash"),
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
     "tiny-moe": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                      num_heads=4, max_seq_len=128, num_experts=4, moe_top_k=2),
+    # OLMoE's block at toy widths (tests, the benchmark's rehearsal)
+    "tiny-olmoe": dict(vocab_size=256, hidden_size=128, intermediate_size=128,
+                       num_layers=2, num_heads=4, max_seq_len=128,
+                       tie_embeddings=False, num_experts=8, moe_top_k=2,
+                       moe_norm_topk=False, moe_routing="dropless",
+                       qk_norm=True),
     "tiny-prmoe": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
                        num_layers=2, num_heads=4, max_seq_len=128,
                        num_experts=4, moe_top_k=2, moe_use_residual=True),
@@ -207,6 +232,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.norm == "layernorm":
         layer["ln1"]["bias"] = jnp.zeros((L, h), pd)
         layer["ln2"]["bias"] = jnp.zeros((L, h), pd)
+    if cfg.qk_norm:
+        layer["attn"]["q_norm"] = {"scale": jnp.ones((L, nh * hd), pd)}
+        layer["attn"]["k_norm"] = {"scale": jnp.ones((L, nkv * hd), pd)}
 
     if cfg.num_experts > 0:
         E = cfg.num_experts
@@ -271,6 +299,9 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
         "ln1": dict(ln),
         "ln2": dict(ln),
     }
+    if cfg.qk_norm:
+        layer["attn"]["q_norm"] = {"scale": ("layers", "heads")}
+        layer["attn"]["k_norm"] = {"scale": ("layers", "kv_heads")}
     if cfg.num_experts > 0:
         moe = {
             "router": ("layers", "embed", None),
@@ -517,14 +548,29 @@ def _lin(x, p, w_key, b_key):
     return y
 
 
+def qk_norm(x, p, which: str, cfg: TransformerConfig):
+    """The q/k norm of ``cfg.qk_norm`` models, the one place it is written:
+    RMSNorm of a q or k projection (``which``: ``"q_norm"`` / ``"k_norm"``)
+    over the whole ``(..., heads * head_dim)`` width, before the split into
+    heads and before RoPE.  Every layer body (training forward, v1 engine,
+    the v2 engine's mixed, decode and verify steps) calls it on q and on k;
+    for a model without the norm it is the identity and traces nothing."""
+    if not cfg.qk_norm:
+        return x
+    with jax.named_scope("qk_norm"):
+        return _norm(x, p[which], "rmsnorm", cfg.norm_eps)
+
+
 def _attention_block(x, p, cfg: TransformerConfig, cos, sin, attn_fn: AttentionFn):
     # named scopes feed the flops profiler's per-module census
     with jax.named_scope("attn"):
         B, S, h = x.shape
         nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         dt = x.dtype
-        q = _lin(x, p, "wq", "bq").reshape(B, S, nh, hd)
-        k = _lin(x, p, "wk", "bk").reshape(B, S, nkv, hd)
+        q = qk_norm(_lin(x, p, "wq", "bq"), p, "q_norm", cfg
+                    ).reshape(B, S, nh, hd)
+        k = qk_norm(_lin(x, p, "wk", "bk"), p, "k_norm", cfg
+                    ).reshape(B, S, nkv, hd)
         v = _lin(x, p, "wv", "bv").reshape(B, S, nkv, hd)
         if cfg.position == "rope":
             q = apply_rope(q, cos, sin)

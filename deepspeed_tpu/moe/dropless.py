@@ -1,73 +1,206 @@
-"""Dropless MoE dispatch via grouped GEMM.
+"""Dropless MoE: every top-k assignment is computed, as a grouped GEMM.
 
 Capability analogue of the reference's modern MoE inference/training path
 (``inference/v2/kernels/cutlass_ops/moe_gemm`` + dropless routing): no
-capacity buckets, no token dropping — every top-k assignment is computed.
-Tokens are scattered once into the tile-aligned grouped layout (see
-``ops/pallas/grouped_matmul``), the expert FFN runs as three grouped GEMMs,
-and a scatter-add combines weighted expert outputs back per token.
+capacity buckets, no token dropping, so a token's output never depends on
+the tokens batched beside it.  That is why this is THE serving path: the
+inference engines call :func:`routed_ffn` for every MoE model, whatever
+``moe_routing`` the model was trained with.
 
-Compared with the capacity-einsum path (``moe/layer.py``) this removes the
-(B,S,E,C)-onehot dispatch/combine contractions entirely and computes exactly
-T = B·S·k token-rows of FFN (plus ≤ E·tile rows of alignment padding) instead
-of E·C capacity rows.
+Four stages, each under a named scope a device trace can find:
 
-Select with ``TransformerConfig.moe_routing = 'dropless'`` (default
-'capacity' keeps the GShard-style path, which is also the expert-parallel
-all-to-all path — dropless currently targets replicated/dp expert weights).
+``moe_route``     float32 softmax of the router logits, top-k, the weights by
+                  the config's rule (``moe_norm_topk``: renormalised to sum 1,
+                  or the raw probabilities);
+``moe_dispatch``  tokens scattered once into the tile-aligned grouped layout
+                  (``ops/pallas/grouped_matmul.tile_aligned_layout``), whose
+                  ``tile_m`` follows the step's assignments (:func:`moe_tile_m`);
+``moe_experts``   the expert FFN as three grouped GEMMs: bf16
+                  (``grouped_matmul``) or int8 codes dequantized in the kernel
+                  (``grouped_mixed_gemm``), by the weight's type;
+``moe_combine``   weighted expert outputs gathered back and summed per token.
+
+Training (``moe/layer.py`` with ``moe_routing='dropless'``) calls
+:func:`dropless_moe_block_with_losses`, which is the same four stages plus
+the router's auxiliary losses; serving pays for no loss.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.grouped_matmul import grouped_matmul, tile_aligned_layout
+from ..ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
+from ..ops.pallas.mixed_gemm import QuantizedWeight
+
+#: expert projections of a layer's ``moe`` dict
+EXPERT_KEYS = ("w_in", "w_gate", "w_out")
+_MIN_TILE_M, _MAX_TILE_M = 16, 512
 
 
-def dropless_moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg,
-                                   tile_m: int = 512,
+def moe_tile_m(assignments: int, num_experts: int) -> int:
+    """Rows of one M tile of the grouped layout, from the two static sizes of
+    the step program: T assignments (tokens x top-k) over E experts.
+
+    The smallest power of two that holds twice the mean rows an expert gets,
+    between 16 (one packed bf16 sublane tile) and 512 (``mixed_gemm``'s
+    widest M tile).  Twice the mean, so that under near-uniform routing an
+    expert is one tile (its weights are fetched once and no tile is mostly
+    another expert's padding) and a hot expert just takes more tiles.  The
+    padded layout is ``(ceil(T / tile_m) + E) * tile_m`` rows, at most about
+    three times T: a decode step of 32 rows x top-8 over 64 experts
+    (T = 256) gets 16, a mixed step of 512 tokens (T = 4,096) gets 128."""
+    want = -(-2 * assignments // num_experts)
+    tile = _MIN_TILE_M
+    while tile < min(want, _MAX_TILE_M):
+        tile *= 2
+    return tile
+
+
+def padded_rows(assignments: int, num_experts: int) -> int:
+    """Rows of the grouped layout a step program of T assignments computes
+    on (static; what ``tile_aligned_layout`` returns as ``M_pad``)."""
+    tile = moe_tile_m(assignments, num_experts)
+    return (-(-assignments // tile) + num_experts) * tile
+
+
+class Routing(NamedTuple):
+    weights: jax.Array  # (N, k) float32: the gate weight of each assignment
+    experts: jax.Array  # (N, k) int32
+    probs: jax.Array  # (N, E) float32 softmax, for the training losses
+    logits: jax.Array  # (N, E) float32
+
+
+def route(x2: jax.Array, router: jax.Array, cfg) -> Routing:
+    """``x2 (N, H)`` → top-k experts and their weights, in float32."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.moe_top_k)
+        if getattr(cfg, "moe_norm_topk", True):
+            weights = weights / jnp.maximum(
+                weights.sum(-1, keepdims=True), 1e-9)
+        return Routing(weights, experts.astype(jnp.int32), probs, logits)
+
+
+def _expert_gemm(a, w, tile_group, pad_sizes, used_tiles, tile_m, layer):
+    if isinstance(w, QuantizedWeight):
+        return grouped_mixed_gemm(a, w, tile_group, pad_sizes, used_tiles,
+                                  tile_m=tile_m, layer=layer)
+    return grouped_matmul(a, w.astype(a.dtype), tile_group, pad_sizes,
+                          tile_m=tile_m)
+
+
+def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
+               routing: Optional[Routing] = None,
+               layer: Optional[jax.Array] = None,
+               valid: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The routed expert FFN on ``x2 (N, H)``: route → layout → experts →
+    combine.  → ``(y (N, H), stats)`` with ``stats`` int32 ``(2,)``: experts
+    that got at least one row, and the largest rows-per-expert, counted over
+    the rows ``valid (N,)`` marks (all rows when None).
+
+    ``p`` is a layer's ``moe`` dict.  Its expert weights are ``(E, K, N)``
+    arrays or ``QuantizedWeight`` nodes; with ``layer`` (an int32 scalar) the
+    nodes are the whole stack ``(L, E, K, N)`` and the kernel reads that
+    layer's experts in place (a scan that sliced them would copy one layer's
+    codes before every call)."""
+    N, H = x2.shape
+    E, k = cfg.num_experts, cfg.moe_top_k
+    dt = x2.dtype
+    T = N * k
+    tile_m = moe_tile_m(T, E)
+    r = routing if routing is not None else route(x2, p["router"], cfg)
+
+    with jax.named_scope("moe_dispatch"):
+        expert_flat = r.experts.reshape(T)
+        positions, tile_group, pad_sizes, M_pad = tile_aligned_layout(
+            expert_flat, E, T, tile_m)
+        counts = jnp.bincount(expert_flat, length=E)
+        used_tiles = jnp.sum(-(-counts // tile_m)).astype(jnp.int32)
+        xs = jnp.zeros((M_pad, H), dt).at[positions].set(
+            jnp.repeat(x2, k, axis=0))
+        if valid is not None:
+            counts = jnp.bincount(
+                expert_flat, weights=jnp.repeat(valid, k).astype(jnp.int32),
+                length=E)
+        stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]
+                          ).astype(jnp.int32)
+
+    with jax.named_scope("moe_experts"):
+        def gmm(a, key):
+            return _expert_gemm(a, p[key], tile_group, pad_sizes, used_tiles,
+                                tile_m, layer)
+
+        if "w_gate" in p:
+            hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
+        else:
+            hmid = jax.nn.gelu(gmm(xs, "w_in"), approximate=True)
+        ys = gmm(hmid, "w_out")  # (M_pad, H)
+
+    with jax.named_scope("moe_combine"):
+        # a token's k expert outputs, weighted and summed in float32: the sum
+        # of a token does not depend on where its rows lie in the layout
+        picked = ys[positions].reshape(N, k, H).astype(jnp.float32)
+        y = jnp.sum(picked * r.weights[..., None], axis=1).astype(dt)
+    return y, stats
+
+
+def hoist_expert_codes(layers: Dict[str, Any]
+                       ) -> Tuple[Dict[str, Any], Optional[Dict[str, Any]]]:
+    """Split a stacked ``layers`` tree for a layer scan: → (what the scan
+    slices, the quantized expert nodes it must not slice, or None).  The
+    scanned ``moe`` dict gets a ``layer`` index in their place, which
+    :func:`serving_moe_block` hands to the kernel."""
+    moe = layers.get("moe") or {}
+    stacked = {k: moe[k] for k in EXPERT_KEYS
+               if isinstance(moe.get(k), QuantizedWeight)}
+    if not stacked:
+        return layers, None
+    n_layers = next(iter(stacked.values())).codes.shape[0]
+    rest = {k: v for k, v in moe.items() if k not in stacked}
+    rest["layer"] = jnp.arange(n_layers, dtype=jnp.int32)
+    return {**layers, "moe": rest}, stacked
+
+
+def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
+                      stacked: Optional[Dict[str, Any]] = None,
+                      valid: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """The MoE FFN of every inference engine: ``x (..., H)`` → ``(y, stats)``
+    (``stats`` as :func:`routed_ffn` gives them).  ``p`` is the layer's
+    ``moe`` dict as the scan sliced it; ``stacked`` what
+    :func:`hoist_expert_codes` kept out of the scan."""
+    layer = None
+    if stacked is not None:
+        p, layer = {**p, **stacked}, p["layer"]
+    x2 = x.reshape(-1, x.shape[-1])
+    y, stats = routed_ffn(x2, p, cfg, layer=layer,
+                          valid=None if valid is None else valid.reshape(-1))
+    y = y.reshape(x.shape)
+    if getattr(cfg, "moe_use_residual", False):
+        from .layer import _prmoe_combine
+
+        y = _prmoe_combine(x, y, p, cfg)
+    return y, stats
+
+
+def dropless_moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg
                                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, H) → (y, aux_loss, z_loss); router losses as in
     ``moe/layer.py`` (Switch aux loss + St-MoE z-loss)."""
     B, S, H = x.shape
-    E, k = cfg.num_experts, cfg.moe_top_k
-    dt = x.dtype
-
-    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (B,S,E)
-    z = jax.nn.logsumexp(logits, axis=-1)
-    z_loss = jnp.mean(z ** 2)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (B,S,k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    me = jnp.mean(probs, axis=(0, 1))
-    ce = jnp.mean(jax.nn.one_hot(gate_idx[..., 0], E), axis=(0, 1))
+    E = cfg.num_experts
+    x2 = x.reshape(B * S, H)
+    r = route(x2, p["router"], cfg)
+    z_loss = jnp.mean(jax.nn.logsumexp(r.logits, axis=-1) ** 2)
+    me = jnp.mean(r.probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(r.experts[:, 0], E), axis=0)
     aux_loss = E * jnp.sum(me * ce)
-
-    T = B * S * k
-    expert_flat = gate_idx.reshape(T)
-    token_flat = jnp.repeat(jnp.arange(B * S), k)
-    gates_flat = gate_vals.reshape(T)
-
-    positions, tile_group, pad_sizes, M_pad = tile_aligned_layout(
-        expert_flat, E, T, tile_m)
-
-    xs = jnp.zeros((M_pad, H), dt).at[positions].set(
-        x.reshape(B * S, H)[token_flat])
-
-    def gmm(a, w_key):
-        return grouped_matmul(a, p[w_key].astype(dt), tile_group, pad_sizes,
-                              tile_m=tile_m)
-
-    if "w_gate" in p:
-        hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
-    else:
-        hmid = jax.nn.gelu(gmm(xs, "w_in"), approximate=True)
-    ys = gmm(hmid, "w_out")  # (M_pad, H)
-
-    weighted = ys[positions] * gates_flat[:, None].astype(dt)  # (T, H)
-    y = jnp.zeros((B * S, H), dt).at[token_flat].add(weighted)
+    y, _ = routed_ffn(x2, p, cfg, routing=r)
     return y.reshape(B, S, H), aux_loss, z_loss

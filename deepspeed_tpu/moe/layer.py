@@ -36,7 +36,8 @@ class GateOutput(NamedTuple):
 def top_k_gating(logits: jax.Array, num_experts: int, top_k: int,
                  capacity_factor: float, min_capacity: int = 4,
                  rng: Optional[jax.Array] = None,
-                 noise_std: float = 0.0) -> GateOutput:
+                 noise_std: float = 0.0,
+                 norm_topk: bool = True) -> GateOutput:
     """logits: (B, S, E). Returns capacity-bucketed dispatch/combine tensors.
 
     Reference: ``sharded_moe.py`` topkgating — same capacity math
@@ -55,8 +56,9 @@ def top_k_gating(logits: jax.Array, num_experts: int, top_k: int,
 
     # top-k selection
     gate_vals, gate_idx = jax.lax.top_k(raw_probs, top_k)  # (B,S,k)
-    # renormalize the selected gates
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if norm_topk:  # renormalize the selected gates (``cfg.moe_norm_topk``)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
     # load-balancing aux loss (Switch eq.4): E * sum_e f_e * P_e
     me = jnp.mean(raw_probs, axis=(0, 1))  # (E,) mean router prob
@@ -101,8 +103,8 @@ def expert_choice_gating(logits: jax.Array, num_experts: int,
     selection sees the whole sequence, so token t's routing depends on
     later tokens.  This is a TRAINING-TIME router (encoders, prefix-LM,
     distillation targets); autoregressive DECODE with it is incoherent —
-    the inference engines refuse it (serve the trained experts with
-    ``moe_routing='capacity'`` or ``'dropless'`` instead)."""
+    the inference engines refuse it (they serve every other MoE model
+    through ``moe/dropless.routed_ffn``, whatever it was trained with)."""
     B, S, E = logits.shape
     capacity = max(int(S * capacity_factor / num_experts), min_capacity)
     capacity = min(capacity, S)
@@ -151,7 +153,8 @@ def moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg
         gate = expert_choice_gating(logits, E, cfg.moe_capacity_factor)
     else:
         gate = top_k_gating(logits, E, cfg.moe_top_k,
-                            cfg.moe_capacity_factor)
+                            cfg.moe_capacity_factor,
+                            norm_topk=getattr(cfg, "moe_norm_topk", True))
     disp = gate.dispatch_mask.astype(dt)
     comb = gate.combine_weights.astype(dt)
     xe = jnp.einsum("bsec,bsh->ebch", disp, x)

@@ -40,13 +40,14 @@ def _pick_tile_k(K: int) -> int:
 def _use_pallas(M: int, K: int, N: int, tile_m: int, tile_n: int) -> bool:
     if backend.interpret():  # the host CPU runs ragged_dot, by design
         return False
-    # Mosaic lane tiling: keep every matmul dim 128-aligned
+    # Mosaic tiling: K and N 128-aligned lanes, the M tile whole packed bf16
+    # sublane tiles (16 rows: a decode step's few rows an expert)
     ok = (M % tile_m == 0 and _pick_tile_k(K) > 0 and N % tile_n == 0
-          and tile_m % 128 == 0 and tile_n % 128 == 0)
+          and tile_m % 16 == 0 and tile_n % 128 == 0)
     if not ok:
         backend.warn_fallback(
             "grouped_matmul", f"M={M}, K={K}, N={N} do not tile into "
-            f"tile_m={tile_m}, tile_n={tile_n} with 128-aligned dims")
+            f"tile_m={tile_m}, tile_n={tile_n} (16-row, 128-lane tiles)")
     return ok
 
 

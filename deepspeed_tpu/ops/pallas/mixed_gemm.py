@@ -163,7 +163,8 @@ class GemmTiles:
 
 @functools.lru_cache(maxsize=None)
 def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
-                    x_itemsize: int = 2) -> Optional[GemmTiles]:
+                    x_itemsize: int = 2, tm: Optional[int] = None
+                    ) -> Optional[GemmTiles]:
     """The ``(tm, tn, tk)`` tile of an ``(m, k) @ (k, n)`` mixed GEMM (``m``
     already padded to the sublane multiple), or None when the shapes do not
     tile (→ the dequantize-then-matmul fallback).
@@ -174,7 +175,10 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
     rows are then long contiguous runs of HBM (tiles of equal bytes measured
     faster wide than deep, PERF.md).  ``tk`` is as many whole quantization
     groups, dividing ``k``, as keep a step at ``_TILE_WEIGHTS`` weights and
-    the call at ``_MIN_STEPS`` steps or more."""
+    the call at ``_MIN_STEPS`` steps or more.
+
+    A caller whose rows already lie in M tiles (the grouped GEMM of MoE
+    experts) passes its own ``tm``, a divisor of ``m``; the rest follows."""
     # int4 packs two codes per byte (group must be even); fp6 packs 4 K-rows
     # per 3 byte-rows (group must divide by 4, and the byte-row tile must be
     # sublane-aligned); int8 has no pack constraint
@@ -183,7 +187,10 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
             or (bits == 6 and (group % 4 or _code_rows(group, 6) % 8))
             or (group % 128 and group != k)):
         return None
-    tm = m if m <= _MAX_TM else aligned_divisor(m, _MAX_TM)
+    if tm is None:
+        tm = m if m <= _MAX_TM else aligned_divisor(m, _MAX_TM)
+    elif m % tm:
+        return None
     tns = [d for d in range(min(n, _MAX_TN) // 128 * 128, 0, -128)
            if n % d == 0]
     if n <= 256 and n % 128:

@@ -70,6 +70,35 @@ def test_mixtral_golden(devices):
         cfg_overrides={"moe_capacity_factor": 4.0})
 
 
+def test_olmoe_golden(devices):
+    """q/k norm over the whole projection (its scales moved off 1 and
+    carried through the rope un-permutation), 8 routed experts, top 2, gates
+    the raw probabilities: transformers' own forward, to float32 precision."""
+    from transformers import AutoModelForCausalLM, OlmoeConfig
+
+    hf_cfg = OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+        max_position_embeddings=64, tie_word_embeddings=False)
+    torch.manual_seed(0)
+    hf = AutoModelForCausalLM.from_config(
+        hf_cfg, attn_implementation="eager").eval()
+    with torch.no_grad():
+        for layer in hf.model.layers:
+            for norm in (layer.self_attn.q_norm, layer.self_attn.k_norm):
+                norm.weight.add_(0.3 * torch.randn_like(norm.weight))
+    cfg, params = load_hf_model(hf)
+    assert cfg.qk_norm and not cfg.moe_norm_topk and cfg.num_experts == 8
+    cfg = tfm.TransformerConfig(**{**cfg.__dict__, "dtype": "float32",
+                                   "param_dtype": "float32"})
+    toks = np.random.default_rng(0).integers(0, 128, (2, 16)).astype(np.int32)
+    with torch.no_grad():
+        ref = hf(torch.tensor(toks.astype(np.int64))).logits.numpy()
+    np.testing.assert_allclose(np.asarray(tfm.forward(params, toks, cfg)),
+                               ref, atol=3e-4, rtol=3e-3)
+
+
 def test_phi3_golden(devices):
     Phi3Config = pytest.importorskip("transformers").Phi3Config
 

@@ -110,6 +110,22 @@ def test_mixtral_export_roundtrip():
     _roundtrip(m)
 
 
+def test_olmoe_export_roundtrip():
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+
+    torch.manual_seed(0)
+    m = OlmoeForCausalLM(OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+        max_position_embeddings=64, tie_word_embeddings=False)).eval()
+    with torch.no_grad():  # scales off 1, so that their order is checked
+        for layer in m.model.layers:
+            layer.self_attn.q_norm.weight.add_(
+                0.3 * torch.randn_like(layer.self_attn.q_norm.weight))
+    _roundtrip(m)
+
+
 def test_phi3_export_roundtrip():
     tr = pytest.importorskip("transformers")
 

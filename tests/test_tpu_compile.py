@@ -188,17 +188,21 @@ def test_int8_gemm_compiles(one_chip, mosaic, m):
         kernels=["int8_gemm"])
 
 
-def test_grouped_matmul_compiles(one_chip, mosaic):
-    """The dropless MoE's grouped GEMM: 8 experts at Mistral's MLP widths
-    (Mixtral-8x7B's), 4096 tile-aligned rows."""
+@pytest.mark.parametrize("rows,experts,tile_m,k,n", [
+    (4096, 8, 512, HIDDEN, MLP), (1280, 64, 16, 2048, 1024)],
+    ids=["mixtral-train", "olmoe-decode"])
+def test_grouped_matmul_compiles(one_chip, mosaic, rows, experts, tile_m, k,
+                                 n):
+    """The dropless MoE's bf16 grouped GEMM: 8 experts at Mistral's MLP
+    widths (Mixtral-8x7B's) on 4096 tile-aligned rows, and OLMoE's 64
+    experts on a decode step's 16-row tiles."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
-    rows, experts, tile_m = 4096, 8, 512
     _compile(
         lambda lhs, rhs, tg, sizes: grouped_matmul(lhs, rhs, tg, sizes,
                                                    tile_m=tile_m),
-        _sds((rows, HIDDEN), jnp.bfloat16, one_chip),
-        _sds((experts, HIDDEN, MLP), jnp.bfloat16, one_chip),
+        _sds((rows, k), jnp.bfloat16, one_chip),
+        _sds((experts, k, n), jnp.bfloat16, one_chip),
         _sds((rows // tile_m,), jnp.int32, one_chip),
         _sds((experts,), jnp.int32, one_chip),
         kernels=["grouped_matmul"])
@@ -265,6 +269,107 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
     for name in inside:
         assert re.search(rf'[/"]{name}/', text), \
             f"{name} is not in the lowered program's operation names"
+
+
+# olmoe-1b-7b: 64 experts of width 1024 on hidden 2048, top 8; the decode
+# program routes 32 rows (256 assignments), the mixed step 512 tokens (4,096)
+OLMOE_E, OLMOE_H, OLMOE_F, OLMOE_K = 64, 2048, 1024, 8
+
+
+@pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "mixed"])
+@pytest.mark.parametrize("k,n", [(OLMOE_H, OLMOE_F), (OLMOE_H, OLMOE_F),
+                                 (OLMOE_F, OLMOE_H)],
+                         ids=["w_gate", "w_in", "w_out"])
+def test_grouped_mixed_gemm_compiles(one_chip, mosaic, k, n, tokens):
+    """The grouped W8A16 GEMM of the routed experts at OLMoE's three expert
+    shapes, on the layer-stacked codes (16 x 64 experts, the layer an index),
+    at the ``tile_m`` the decode and the mixed step program get: the
+    picker's tile holds one expert's whole matrix (2 MB of codes a grid
+    step), and nothing falls back."""
+    from deepspeed_tpu.moe.dropless import moe_tile_m, padded_rows
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
+    from deepspeed_tpu.ops.pallas.mixed_gemm import QuantizedWeight
+
+    layers, group = 16, 256
+    assignments = tokens * OLMOE_K
+    tile_m = moe_tile_m(assignments, OLMOE_E)
+    rows = padded_rows(assignments, OLMOE_E)
+    assert (tile_m, rows) == {32: (16, 1280), 512: (128, 12288)}[tokens]
+    tracer.clear()
+    _compile(
+        lambda x, c, s, tg, sizes, used, layer: grouped_mixed_gemm(
+            x, QuantizedWeight(c, s, 8, group, k), tg, sizes, used,
+            tile_m=tile_m, layer=layer),
+        _sds((rows, k), jnp.bfloat16, one_chip),
+        _sds((layers, OLMOE_E, k, n), jnp.int8, one_chip),
+        _sds((layers, OLMOE_E, k // group, n), jnp.float32, one_chip),
+        _sds((rows // tile_m,), jnp.int32, one_chip),
+        _sds((OLMOE_E,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        kernels=["grouped_mixed_gemm"])
+    (event,) = [s.attrs for s in tracer.spans()
+                if s.name == "kernel/grouped_mixed_gemm_tiles"]
+    assert "fallback" not in event
+    assert (event["tile_m"], event["tn"], event["tk"]) == (tile_m, n, k)
+    assert event["code_bytes_per_step"] == k * n == 2 << 20
+    assert event["grid_steps"] == rows // tile_m
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_olmoe_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of a 2-layer model at OLMoE-1B-7B's widths,
+    W8A16 experts and all, compile for the described chip; the grouped kernel
+    is in them by name once a projection (the layer loop holds three calls,
+    the expert codes go in whole: no slice of them), and the routed FFN's
+    four scopes and the q/k norm are in the lowered operation names."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.quantization import quantize_model_params
+    from deepspeed_tpu.inference.v2 import engine as v2e
+    from deepspeed_tpu.models import transformer as tfm
+
+    cfg = dataclasses.replace(tfm.get_config("olmoe-1b-7b"), num_layers=2,
+                              dtype="bfloat16", param_dtype="bfloat16")
+    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
+                      num_blocks=64, max_blocks_per_seq=64)
+    sds = functools.partial(_sds, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda key: quantize_model_params(
+            tfm.init_params(key, cfg), bits=8, group=256),
+            jax.random.PRNGKey(0)))
+    assert params["layers"]["moe"]["w_in"].codes.shape == (2, 64, 2048, 1024)
+    assert params["layers"]["moe"]["router"].dtype == jnp.bfloat16
+    cache = sds((cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
+                 cfg.head_dim), jnp.bfloat16)
+    caches = {"k": cache, "v": cache}
+    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
+    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
+    if program == "decode_step":
+        lowered = v2e.build_decode_forward(cfg, v2).lower(
+            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
+            rows(jnp.int32), rows(jnp.float32),
+            sds((2,), jnp.uint32), rows(jnp.int32))
+    else:
+        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
+        lowered = v2e.build_ragged_forward(cfg, v2).lower(
+            params, caches, tokens(), tokens(), tokens(), tables,
+            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.int32))
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{program} " in text
+    for name in ("grouped_mixed_gemm", "mixed_gemm", "moe_route",
+                 "moe_dispatch", "moe_experts", "moe_combine", "qk_norm"):
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile().as_text()
+    calls = re.findall(r"%(grouped_mixed_gemm[.\d]*) = [^\n]*custom-call\(",
+                       compiled)
+    assert len(calls) == 3, calls
+    assert not re.search(r"dynamic-slice[^\n]*s8\[\d+,64,", compiled)
+    assert not re.search(r"s8\[64,\d+,\d+\][^\n]* dynamic-slice\(",
+                         compiled)
 
 
 def test_mesh_follows_the_torus(topo):
