@@ -296,6 +296,20 @@ def _scan_layers(params):
     return hoist_expert_codes(params["layers"])
 
 
+def _layer_xs(layers, adapters=None):
+    """What the layer scan of a step body steps over: ``(the layer's
+    parameters, its index, its adapter factors or {})``.  The K/V pools are
+    NOT here: they ride the scan's carry whole, each layer scatters the
+    step's rows into them at ``[layer, block, offset]`` and the paged kernels
+    read them at ``(layer, block)``, so a step program holds a pool in no form
+    but the one donated buffer (a pool handed to the scan as ``xs`` is
+    sliced a layer at a time, re-stacked into ``ys`` and copied: six passes
+    over 1.7 GB a step at the serving cells' sizes)."""
+    num_layers = jax.tree.leaves(layers)[0].shape[0]
+    return (layers, jnp.arange(num_layers, dtype=jnp.int32),
+            {} if adapters is None else adapters)
+
+
 def _moe_step_stats(per_layer):
     """Per-layer ``(L, 2)`` stats of ``_ffn`` → int32 ``(2,)`` for the step:
     experts hit summed over layers (the host divides by L), and the largest
@@ -351,15 +365,11 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 0)
 
         layers, experts = _scan_layers(params)
-        xs = (layers, caches["k"], caches["v"])
-        if adapters is not None:
-            xs = xs + (adapters,)
+        xs = _layer_xs(layers, adapters)
 
-        def layer_body(x, inp):
-            if adapters is not None:
-                lp, k_cache, v_cache, ad = inp
-            else:
-                (lp, k_cache, v_cache), ad = inp, {}
+        def layer_body(carry, inp):
+            x, k_cache, v_cache = carry
+            lp, layer, ad = inp
             a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
             q = tfm._lin(a_in, lp["attn"], "wq", "bq")
             k = tfm._lin(a_in, lp["attn"], "wk", "bk")
@@ -382,9 +392,9 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                 q = tfm.apply_rope(q[None], cos, sin)[0]
                 k = tfm.apply_rope(k[None], cos, sin)[0]
             with jax.named_scope("cache_write"):
-                k_cache = k_cache.at[blk_ids, offsets].set(
+                k_cache = k_cache.at[layer, blk_ids, offsets].set(
                     k.astype(k_cache.dtype))
-                v_cache = v_cache.at[blk_ids, offsets].set(
+                v_cache = v_cache.at[layer, blk_ids, offsets].set(
                     v.astype(v_cache.dtype))
             # chunked-prefill attention over paged KV: reorganize the ragged
             # (T, H, D) q into per-sequence chunks and run the paged Pallas
@@ -397,8 +407,8 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                                   q.dtype)
                 q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
                 o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
-                                                block_tables, chunk_start,
-                                                chunk_len)
+                                                layer, block_tables,
+                                                chunk_start, chunk_len)
                 # padding rows read in-range garbage (clamped col), dropped
                 # later
                 o = o_seq[gath_row, gath_col]  # (T, H, D)
@@ -414,9 +424,10 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
                                       write_mask)
             x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
                 else (m_src + mlp_out)
-            return x, (k_cache, v_cache, moe_stats)
+            return (x, k_cache, v_cache), moe_stats
 
-        x, (new_k, new_v, moe_stats) = jax.lax.scan(layer_body, x, xs)
+        (x, new_k, new_v), moe_stats = jax.lax.scan(
+            layer_body, (x, caches["k"], caches["v"]), xs)
         x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
         last_hidden = x[logits_rows]  # (max_seqs, H)
         if model_cfg.tie_embeddings:
@@ -597,15 +608,11 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
 
     layers, experts = _scan_layers(params)
-    xs = (layers, caches["k"], caches["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
+    xs = _layer_xs(layers, adapters)
 
-    def layer_body(x, inp):
-        if adapters is not None:
-            lp, k_cache, v_cache, ad = inp
-        else:
-            (lp, k_cache, v_cache), ad = inp, {}
+    def layer_body(carry, inp):
+        x, k_cache, v_cache = carry
+        lp, layer, ad = inp
         a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
         q = tfm._lin(a_in, lp["attn"], "wq", "bq")
         k = tfm._lin(a_in, lp["attn"], "wk", "bk")
@@ -638,13 +645,13 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
 
             q, k = rot(q), rot(k)
         with jax.named_scope("cache_write"):
-            k_cache = k_cache.at[blk_ids, offsets].set(
+            k_cache = k_cache.at[layer, blk_ids, offsets].set(
                 k.astype(k_cache.dtype))
-            v_cache = v_cache.at[blk_ids, offsets].set(
+            v_cache = v_cache.at[layer, blk_ids, offsets].set(
                 v.astype(v_cache.dtype))
         with jax.named_scope("decode_attention"):
-            o = paged_decode_attention(q, k_cache, v_cache, block_tables,
-                                       context_lens)
+            o = paged_decode_attention(q, k_cache, v_cache, layer,
+                                       block_tables, context_lens)
         o_flat = o.reshape(S, nh * hd)
         attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
         if "wo" in ad:
@@ -655,9 +662,10 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
         mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts, active)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
-        return x, (k_cache, v_cache, moe_stats)
+        return (x, k_cache, v_cache), moe_stats
 
-    x, (new_k, new_v, moe_stats) = jax.lax.scan(layer_body, x, xs)
+    (x, new_k, new_v), moe_stats = jax.lax.scan(
+        layer_body, (x, caches["k"], caches["v"]), xs)
     x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
     if model_cfg.tie_embeddings:
         logits = x @ params["embed"]["tokens"].astype(dt).T

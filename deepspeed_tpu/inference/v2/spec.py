@@ -100,11 +100,9 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
                                             model_cfg.rope_theta)
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
 
-    def layer_body(x, inp):
-        if adapters is not None:
-            lp, k_cache, v_cache, ad = inp
-        else:
-            (lp, k_cache, v_cache), ad = inp, {}
+    def layer_body(carry, inp):
+        x, k_cache, v_cache = carry
+        lp, layer, ad = inp
         from .engine import _adapter_proj_delta, _ffn
 
         a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
@@ -138,9 +136,11 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
                 return jnp.concatenate([out, t[..., rd:]], axis=-1)
 
             q, k = rot(q), rot(k)
-        k_cache = k_cache.at[blk_ids, offsets].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[blk_ids, offsets].set(v.astype(v_cache.dtype))
-        o = paged_prefill_attention(q, k_cache, v_cache, block_tables,
+        k_cache = k_cache.at[layer, blk_ids, offsets].set(
+            k.astype(k_cache.dtype))
+        v_cache = v_cache.at[layer, blk_ids, offsets].set(
+            v.astype(v_cache.dtype))
+        o = paged_prefill_attention(q, k_cache, v_cache, layer, block_tables,
                                     ctx * active, chunk_len)
         o_flat = o.reshape(S, Q, nh * hd)
         attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
@@ -152,15 +152,14 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
         mlp_out, _ = _ffn(m_in, lp, model_cfg, experts)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
-        return x, (k_cache, v_cache)
+        return (x, k_cache, v_cache), None
 
-    from .engine import _scan_layers
+    from .engine import _layer_xs, _scan_layers
 
     layers, experts = _scan_layers(params)
-    xs = (layers, caches["k"], caches["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
-    x, (new_k, new_v) = jax.lax.scan(layer_body, x, xs)
+    (x, new_k, new_v), _ = jax.lax.scan(
+        layer_body, (x, caches["k"], caches["v"]),
+        _layer_xs(layers, adapters))
     x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
     if model_cfg.tie_embeddings:
         logits = x @ params["embed"]["tokens"].astype(dt).T

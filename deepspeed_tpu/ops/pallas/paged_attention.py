@@ -9,6 +9,11 @@ decode hot loop.
 Kernel shape: grid over sequences; the block table arrives via scalar
 prefetch (SMEM) so each step can DMA the right KV block HBM→VMEM with double
 buffering while computing the previous one; online softmax across blocks.
+
+Both kernels take the K/V pools whole, ``(L, num_blocks, block_size, KV, D)``,
+and the layer as one more scalar-prefetch operand: a block is fetched as
+``k_hbm.at[layer, blk]``, so a step program's layer scan never slices a layer
+out of the pool for them (a Pallas call cannot fuse its operand's slice).
 """
 
 from __future__ import annotations
@@ -25,14 +30,20 @@ from jax.experimental.pallas import tpu as pltpu
 from . import backend
 
 
-def _decode_attention_xla(q, k_cache, v_cache, block_tables, context_lens):
+def _layer_operand(layer) -> jax.Array:
+    """The layer as the kernels prefetch it: int32 ``(1,)`` in SMEM."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _decode_attention_xla(q, k_cache, v_cache, layer, block_tables,
+                          context_lens):
     """Blockwise decode fallback for kernel-unfriendly shapes: a lax.scan
     over the block-table columns with online softmax.  Peak temp memory is
     O(S·KV·block_size), NOT O(S·S_max) — the r3 verdict's "gather path
     memory" bound: the old version materialized every sequence's whole
     gathered cache at once, punishing at serving scale."""
     S, H, D = q.shape
-    NB, BS, KV, _ = k_cache.shape
+    _, NB, BS, KV, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
     rep = H // KV
     # grouped-head layout: contracting per KV head keeps the per-step
@@ -44,8 +55,8 @@ def _decode_attention_xla(q, k_cache, v_cache, block_tables, context_lens):
     def block_step(carry, j):
         acc, m, l = carry
         blk = block_tables[:, j]                      # (S,)
-        k = k_cache[blk].astype(jnp.float32)          # (S, BS, KV, D)
-        v = v_cache[blk].astype(jnp.float32)
+        k = k_cache[layer, blk].astype(jnp.float32)   # (S, BS, KV, D)
+        v = v_cache[layer, blk].astype(jnp.float32)
         scores = jnp.einsum("skrd,stkd->skrt", qf, k)  # (S, KV, rep, BS)
         scores = scores.reshape(S, H, BS)
         pos = j * BS + jnp.arange(BS)[None, None, :]
@@ -70,12 +81,13 @@ def _decode_attention_xla(q, k_cache, v_cache, block_tables, context_lens):
     return out.astype(q.dtype)
 
 
-def _decode_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
+def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
                    q_ref, k_hbm, v_hbm,  # inputs
                    o_ref,  # output
                    k_buf, v_buf, copy_sems,  # scratch
                    *, block_size: int, max_blocks: int, group: int):
     s = pl.program_id(0)
+    layer = layer_ref[0]
     ctx = context_lens_ref[s]
     nblocks = pl.cdiv(ctx, block_size)
 
@@ -93,9 +105,9 @@ def _decode_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
 
     def get_dma(slot, j):
         blk = block_tables_ref[s, j]
-        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot],
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk], k_buf.at[slot],
                                       copy_sems.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, blk], v_buf.at[slot],
                                       copy_sems.at[slot, 1]))
 
     @pl.when(nblocks > 0)
@@ -152,14 +164,16 @@ def _decode_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
 
 
 def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                           block_tables: jax.Array, context_lens: jax.Array
-                           ) -> jax.Array:
+                           layer: jax.Array, block_tables: jax.Array,
+                           context_lens: jax.Array) -> jax.Array:
     """q: (max_seqs, H, D) — one decode token per sequence.
-    k/v_cache: (num_blocks, block_size, KV, D); block_tables:
-    (max_seqs, max_blocks) int32; context_lens: (max_seqs,) int32.
-    Context length INCLUDES the current token (its KV already written)."""
+    k/v_cache: the whole pools, (L, num_blocks, block_size, KV, D); layer:
+    int32 scalar (traced in a layer scan), the pool's layer to read;
+    block_tables: (max_seqs, max_blocks) int32; context_lens: (max_seqs,)
+    int32.  Context length INCLUDES the current token (its KV already
+    written)."""
     S, H, D = q.shape
-    NB, BS, KV, _ = k_cache.shape
+    _, NB, BS, KV, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
     group = H // KV
 
@@ -170,11 +184,11 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             "paged_decode_attention",
             f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
             f"a multiple of 8 (Mosaic DMA slice alignment)")
-        return _decode_attention_xla(q, k_cache, v_cache, block_tables,
-                                     context_lens)
+        return _decode_attention_xla(q, k_cache, v_cache, layer,
+                                     block_tables, context_lens)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S,),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
@@ -195,7 +209,7 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=backend.interpret(),
         name="paged_attention_decode",
-    )(block_tables, context_lens, q, k_cache, v_cache)
+    )(_layer_operand(layer), block_tables, context_lens, q, k_cache, v_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -203,14 +217,14 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _prefill_attention_xla(q, k_cache, v_cache, block_tables, chunk_start,
-                           chunk_len):
+def _prefill_attention_xla(q, k_cache, v_cache, layer, block_tables,
+                           chunk_start, chunk_len):
     """Blockwise prefill fallback.  q: (S, Qp, H, D) — each sequence's
     prefill chunk, rows ≥ chunk_len invalid.  A lax.scan over block-table
     columns with online softmax: peak temp memory is O(S·Qp·block_size),
     never O(S·S_max) (the r3 "bound the gather path" item)."""
     S, Qp, H, D = q.shape
-    NB, BS, KV, _ = k_cache.shape
+    _, NB, BS, KV, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
     rep = H // KV
     # grouped heads: contract per KV head (see _decode_attention_xla)
@@ -223,8 +237,8 @@ def _prefill_attention_xla(q, k_cache, v_cache, block_tables, chunk_start,
     def block_step(carry, j):
         acc, m, l = carry
         blk = block_tables[:, j]
-        k = k_cache[blk].astype(jnp.float32)          # (S, BS, KV, D)
-        v = v_cache[blk].astype(jnp.float32)
+        k = k_cache[layer, blk].astype(jnp.float32)   # (S, BS, KV, D)
+        v = v_cache[layer, blk].astype(jnp.float32)
         scores = jnp.einsum("sqkrd,stkd->skrqt", qf, k)
         scores = scores.reshape(S, H, Qp, BS)
         t_pos = j * BS + jnp.arange(BS)[None, None, None, :]
@@ -253,13 +267,15 @@ def _prefill_attention_xla(q, k_cache, v_cache, block_tables, chunk_start,
     return jnp.where(q_valid[:, :, None, None], out, 0.0).astype(q.dtype)
 
 
-def _prefill_kernel(block_tables_ref, chunk_start_ref, chunk_len_ref,  # SMEM
+def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
+                    chunk_len_ref,  # scalar prefetch (SMEM)
                     q_ref, k_hbm, v_hbm,  # inputs
                     o_ref,  # output
                     k_buf, v_buf, copy_sems,  # scratch
                     *, block_size: int, group: int, tq: int):
     s = pl.program_id(0)
     t = pl.program_id(1)
+    layer = layer_ref[0]
     start = chunk_start_ref[s]
     qlen = chunk_len_ref[s]
     tile_lo = t * tq  # chunk-relative index of this q tile's first row
@@ -286,9 +302,9 @@ def _prefill_kernel(block_tables_ref, chunk_start_ref, chunk_len_ref,  # SMEM
 
     def get_dma(slot, j):
         blk = block_tables_ref[s, j]
-        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot],
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk], k_buf.at[slot],
                                       copy_sems.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, blk], v_buf.at[slot],
                                       copy_sems.at[slot, 1]))
 
     @pl.when(nblocks > 0)
@@ -344,14 +360,16 @@ def _prefill_kernel(block_tables_ref, chunk_start_ref, chunk_len_ref,  # SMEM
 
 
 def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
-                            v_cache: jax.Array, block_tables: jax.Array,
-                            chunk_start: jax.Array, chunk_len: jax.Array,
-                            tq: int = 16) -> jax.Array:
+                            v_cache: jax.Array, layer: jax.Array,
+                            block_tables: jax.Array, chunk_start: jax.Array,
+                            chunk_len: jax.Array, tq: int = 16) -> jax.Array:
     """Chunked-prefill attention over paged KV (the reference's ragged-batch
     ``blocked_flash`` prefill kernel, ``inference/v2/kernels/ragged_ops/``).
 
     q: (max_seqs, Qp, H, D) — each sequence's prefill chunk this step, padded
     to the static token budget Qp; rows ≥ ``chunk_len[s]`` are padding.
+    ``k_cache``/``v_cache``: the whole pools (L, num_blocks, block_size, KV,
+    D), read at ``layer`` (int32 scalar, traced in a layer scan).
     ``chunk_start``: absolute position of chunk row 0 (tokens already in
     cache); the chunk's own KV must already be written to the cache.
     Returns (max_seqs, Qp, H, D).
@@ -362,7 +380,7 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     DMA like the decode kernel.
     """
     S, Qp, H, D = q.shape
-    NB, BS, KV, _ = k_cache.shape
+    _, NB, BS, KV, _ = k_cache.shape
     group = H // KV
 
     if not backend.interpret() and (D % 128 != 0 or BS % 8 != 0):
@@ -370,14 +388,14 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
             "paged_prefill_attention",
             f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
             f"a multiple of 8 (Mosaic DMA slice alignment)")
-        return _prefill_attention_xla(q, k_cache, v_cache, block_tables,
-                                      chunk_start, chunk_len)
+        return _prefill_attention_xla(q, k_cache, v_cache, layer,
+                                      block_tables, chunk_start, chunk_len)
     tq = min(tq, Qp)
     while Qp % tq != 0:  # static divisor for the tile grid
         tq -= 1
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(S, Qp // tq),
         in_specs=[
             pl.BlockSpec((1, tq, H, D), lambda s, t, *_: (s, t, 0, 0)),
@@ -397,4 +415,5 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, Qp, H, D), q.dtype),
         interpret=backend.interpret(),
         name="paged_attention_prefill",
-    )(block_tables, chunk_start, chunk_len, q, k_cache, v_cache)
+    )(_layer_operand(layer), block_tables, chunk_start, chunk_len, q, k_cache,
+      v_cache)

@@ -72,13 +72,14 @@ def test_kernel_that_gives_way_on_a_tpu_warns_once(monkeypatch, caplog):
     _warning_once_impl.cache_clear()
     monkeypatch.setattr(logging.getLogger("deepspeed_tpu"), "propagate", True)
     q = jax.ShapeDtypeStruct((4, 8, 64), jnp.bfloat16)  # head_dim 64
-    cache = jax.ShapeDtypeStruct((16, 16, 2, 64), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((2, 16, 16, 2, 64), jnp.bfloat16)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
     tables = jax.ShapeDtypeStruct((4, 4), jnp.int32)
     lens = jax.ShapeDtypeStruct((4,), jnp.int32)
     with caplog.at_level(logging.WARNING, logger="deepspeed_tpu"):
         for _ in range(2):
             out = jax.eval_shape(paged_decode_attention, q, cache, cache,
-                                 tables, lens)
+                                 layer, tables, lens)
     assert out.shape == q.shape
     warned = [r for r in caplog.records if "paged_decode_attention" in
               r.getMessage()]

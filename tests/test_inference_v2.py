@@ -1,6 +1,7 @@
 """Inference-v2 (continuous batching / paged KV) tests
 (reference: tests/unit/inference/v2/)."""
 
+import functools
 import threading
 
 import jax
@@ -55,6 +56,15 @@ def tiny_model():
     return cfg, params
 
 
+def _greedy_reference(cfg, params, prompt, n):
+    """``n`` greedy tokens after ``prompt`` by the plain uncached forward."""
+    seq = np.array([prompt], np.int32)
+    for _ in range(n):
+        nxt = np.asarray(tfm.forward(params, seq, cfg)[:, -1].argmax(-1))
+        seq = np.concatenate([seq, nxt.astype(np.int32)[:, None]], axis=1)
+    return seq[0, len(prompt):].tolist()
+
+
 def test_v2_matches_v1_greedy(devices, tiny_model):
     """Continuous-batching decode must produce exactly the tokens the plain
     uncached forward produces — the canonical paged-KV correctness check."""
@@ -65,14 +75,8 @@ def test_v2_matches_v1_greedy(devices, tiny_model):
     prompt = [5, 6, 7, 8]
     uid = eng.put(prompt, max_new_tokens=6)
     results = eng.generate_all()
-    got = results[uid]
-
-    seq = np.array([prompt], np.int32)
-    for _ in range(6):
-        logits = tfm.forward(params, seq, cfg)
-        nxt = np.asarray(logits[:, -1].argmax(-1)).astype(np.int32)
-        seq = np.concatenate([seq, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(got, seq[0].tolist())
+    np.testing.assert_array_equal(
+        results[uid], prompt + _greedy_reference(cfg, params, prompt, 6))
 
 
 def test_v2_concurrent_requests(devices, tiny_model):
@@ -86,13 +90,9 @@ def test_v2_concurrent_requests(devices, tiny_model):
     uids = [eng.put(p, max_new_tokens=4) for p in prompts]
     results = eng.generate_all()
     for p, uid in zip(prompts, uids):
-        seq = np.array([p], np.int32)
-        for _ in range(4):
-            logits = tfm.forward(params, seq, cfg)
-            nxt = np.asarray(logits[:, -1].argmax(-1)).astype(np.int32)
-            seq = np.concatenate([seq, nxt[:, None]], axis=1)
-        np.testing.assert_array_equal(results[uid], seq[0].tolist(),
-                                      err_msg=f"uid {uid} prompt {p}")
+        np.testing.assert_array_equal(
+            results[uid], p + _greedy_reference(cfg, params, p, 4),
+            err_msg=f"uid {uid} prompt {p}")
 
 
 def test_prefill_scatter_drops_padding():
@@ -151,13 +151,9 @@ def test_v2_full_batch_padding_exact(devices, tiny_model):
     uids = [eng.put(p, max_new_tokens=4) for p in prompts]
     results = eng.generate_all()
     for p, uid in zip(prompts, uids):
-        seq = np.array([p], np.int32)
-        for _ in range(4):
-            logits = tfm.forward(params, seq, cfg)
-            nxt = np.asarray(logits[:, -1].argmax(-1)).astype(np.int32)
-            seq = np.concatenate([seq, nxt[:, None]], axis=1)
-        np.testing.assert_array_equal(results[uid], seq[0].tolist(),
-                                      err_msg=f"uid {uid} prompt {p}")
+        np.testing.assert_array_equal(
+            results[uid], p + _greedy_reference(cfg, params, p, 4),
+            err_msg=f"uid {uid} prompt {p}")
 
 
 def test_v2_blocks_recycled(devices, tiny_model):
@@ -188,8 +184,9 @@ def test_paged_decode_kernel_matches_xla(devices):
         rng.permutation(NB)[: S * MB].reshape(S, MB).astype(np.int32))
     context_lens = jnp.asarray([5, 17, 32, 1], jnp.int32)
 
-    out_k = paged_decode_attention(q, k_cache, v_cache, block_tables,
-                                   context_lens)
+    # the kernel takes the pools whole: here a pool of one layer
+    out_k = paged_decode_attention(q, k_cache[None], v_cache[None], 0,
+                                   block_tables, context_lens)
     # XLA path: one token per seq at position ctx-1
     positions = context_lens - 1
     out_x = ragged_attention_xla(
@@ -368,7 +365,8 @@ def test_blockwise_prefill_fallback_matches_full_gather(devices):
                      .reshape(S, MB).astype(np.int32))
     cs = jnp.asarray([0, 5, 11], jnp.int32)
     cl = jnp.asarray([8, 3, 6], jnp.int32)
-    got = _prefill_attention_xla(q, k_cache, v_cache, bt, cs, cl)
+    got = _prefill_attention_xla(q, k_cache[None], v_cache[None], 0, bt, cs,
+                                 cl)
     ref = _naive_paged_prefill(q, k_cache, v_cache, bt, cs, cl)
     # compare only valid rows (padding rows emit zeros vs garbage)
     for s in range(S):
@@ -391,7 +389,7 @@ def test_blockwise_decode_fallback_matches_reference(devices):
     ctx = jnp.asarray([5, 17, 32, 1], jnp.int32)
     from deepspeed_tpu.inference.v2.engine import ragged_attention_xla
 
-    got = _decode_attention_xla(q, k_cache, v_cache, bt, ctx)
+    got = _decode_attention_xla(q, k_cache[None], v_cache[None], 0, bt, ctx)
     ref = ragged_attention_xla(q, k_cache, v_cache, bt, ctx,
                                jnp.arange(S, dtype=jnp.int32), ctx - 1,
                                None, BS)
@@ -410,11 +408,12 @@ def test_serving_scale_fallback_memory_bounded(devices):
     # rep-x jnp.repeat of K/V inflating the per-step working set
     S, Qp, H, KV, D, BS, MB, NB = 16, 256, 8, 2, 64, 32, 128, 2048
     q = jnp.zeros((S, Qp, H, D), jnp.float32)
-    kc = jnp.zeros((NB, BS, KV, D), jnp.float32)
+    kc = jnp.zeros((1, NB, BS, KV, D), jnp.float32)  # a pool of one layer
     bt = jnp.zeros((S, MB), jnp.int32)
     z = jnp.zeros((S,), jnp.int32)
+    layer = jnp.int32(0)
     ma = jax.jit(_prefill_attention_xla).lower(
-        q, kc, kc, bt, z, z).compile().memory_analysis()
+        q, kc, kc, layer, bt, z, z).compile().memory_analysis()
     old_working_set = 2 * S * MB * BS * H * D * 4 + S * H * Qp * MB * BS * 4
     assert ma.temp_size_in_bytes < old_working_set / 8, (
         f"prefill fallback temp {ma.temp_size_in_bytes/2**20:.0f} MiB — "
@@ -422,10 +421,145 @@ def test_serving_scale_fallback_memory_bounded(devices):
 
     qd = jnp.zeros((S, H, D), jnp.float32)
     mad = jax.jit(_decode_attention_xla).lower(
-        qd, kc, kc, bt, z).compile().memory_analysis()
+        qd, kc, kc, layer, bt, z).compile().memory_analysis()
     old_decode = 2 * S * MB * BS * H * D * 4
     assert mad.temp_size_in_bytes < old_decode / 8, (
         f"decode fallback temp {mad.temp_size_in_bytes/2**20:.0f} MiB")
+
+
+# ---------------------------------------------------------------------------
+# the pools stay where they lie (ISSUE 28): kernels and fallbacks read the
+# whole pool at (layer, block); the step bodies carry it through the scan
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_paged(kernel: str, impl: str):
+    """One of the four attention entry points, jitted once: the layer is a
+    traced scalar, as in the layer scan, so three layers are one program."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    return jax.jit({
+        ("decode", "pallas"): pa.paged_decode_attention,
+        ("decode", "xla"): pa._decode_attention_xla,
+        ("prefill", "pallas"): pa.paged_prefill_attention,
+        ("prefill", "xla"): pa._prefill_attention_xla}[kernel, impl])
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_attention_reads_its_layer_of_the_pool(devices, kernel, impl,
+                                                     layer):
+    """Both kernels (interpret mode here) and both blockwise fallbacks, given
+    a 3-layer pool whole and a traced layer index, equal the full-gather
+    reference on that layer's slice alone."""
+    from deepspeed_tpu.inference.v2.engine import ragged_attention_xla
+
+    L, S, H, KV, D, BS, NB, MB = 3, 3, 4, 2, 16, 8, 32, 4
+    k_pool = jax.random.normal(jax.random.PRNGKey(1), (L, NB, BS, KV, D))
+    v_pool = jax.random.normal(jax.random.PRNGKey(2), (L, NB, BS, KV, D))
+    bt = jnp.asarray(np.random.default_rng(0).permutation(NB)[:S * MB]
+                     .reshape(S, MB).astype(np.int32))
+    fn = _jitted_paged(kernel, impl)
+    if kernel == "decode":
+        q = jax.random.normal(jax.random.PRNGKey(0), (S, H, D), jnp.float32)
+        ctx = jnp.asarray([5, 17, 32], jnp.int32)
+        got = fn(q, k_pool, v_pool, jnp.int32(layer), bt, ctx)
+        ref = ragged_attention_xla(q, k_pool[layer], v_pool[layer], bt, ctx,
+                                   jnp.arange(S, dtype=jnp.int32), ctx - 1,
+                                   None, BS)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        return
+    Qp = 8
+    q = jax.random.normal(jax.random.PRNGKey(0), (S, Qp, H, D), jnp.float32)
+    cs = jnp.asarray([0, 5, 11], jnp.int32)
+    cl = jnp.asarray([8, 3, 6], jnp.int32)
+    got = fn(q, k_pool, v_pool, jnp.int32(layer), bt, cs, cl)
+    ref = _naive_paged_prefill(q, k_pool[layer], v_pool[layer], bt, cs, cl)
+    for s in range(S):  # padding rows emit zeros, the reference garbage
+        n = int(cl[s])
+        np.testing.assert_allclose(np.asarray(got[s, :n]),
+                                   np.asarray(ref[s, :n]),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.fixture(scope="module")
+def three_layer_model():
+    """Three layers, grouped KV heads, weights scaled so that greedy decoding
+    wanders instead of settling on one token."""
+    import dataclasses
+
+    cfg = dataclasses.replace(tfm.get_config("tiny", dtype="float32"),
+                              num_layers=3, num_kv_heads=2)
+    params = tfm.init_params(jax.random.PRNGKey(1), cfg)
+    return cfg, jax.tree.map(lambda a: a * 3.0 if a.ndim >= 2 else a, params)
+
+
+_POOL_V2 = dict(max_tokens_per_step=8, max_seqs=4, block_size=8,
+                num_blocks=64, max_blocks_per_seq=8, dtype="float32")
+
+
+def _shared_prefix_prompts():
+    rng = np.random.default_rng(1)
+    a = rng.integers(1, 256, 20).tolist()
+    return a, a[:12] + rng.integers(1, 256, 4).tolist()
+
+
+def test_carried_pools_give_the_parents_tokens(devices, three_layer_model):
+    """Chunked prefill (20-token prompts, 8 tokens a step), then decode step
+    by step, with a second sequence that shares one whole block and forks
+    the next through ``cow_copy`` and a third that re-reads the first's
+    blocks: greedy tokens are those the parent commit (aee5449: the pools as
+    the scan's ``xs``/``ys``) gave on this model, which are the plain
+    uncached forward's."""
+    cfg, params = three_layer_model
+    eng = InferenceEngineV2(cfg, params, V2Config(
+        **_POOL_V2, enable_prefix_cache=True))
+    assert eng.caches["k"].shape == (3, 64, 8, 2, 16)
+    p_a, p_b = _shared_prefix_prompts()
+    u_a = eng.put(list(p_a), max_new_tokens=10)
+    out_a = eng.generate_all(burst=1)[u_a][len(p_a):]
+    u_b = eng.put(list(p_b), max_new_tokens=10)
+    u_c = eng.put(list(p_a), max_new_tokens=10)
+    res = eng.generate_all(burst=1)
+    assert eng.prefix_stats()["cow_copies"] == 2
+    parent_a = [81, 14, 197, 199, 75, 77, 46, 66, 108, 27]
+    parent_b = [119, 87, 178, 137, 118, 237, 90, 209, 191, 152]
+    assert out_a == parent_a == _greedy_reference(cfg, params, p_a, 10)
+    assert res[u_b][len(p_b):] == parent_b == \
+        _greedy_reference(cfg, params, p_b, 10)
+    assert res[u_c][len(p_a):] == parent_a
+    assert eng.caches["k"].shape == (3, 64, 8, 2, 16)
+
+
+@pytest.mark.parametrize("path", ["burst", "self_draft", "draft"])
+def test_other_step_bodies_agree_with_single_steps(devices,
+                                                   three_layer_model, path):
+    """``multi_decode_step`` (an outer scan that carries the caches round
+    the layer scan's carry) and the speculative verify body (``spec.py``)
+    give exactly the tokens of single decode steps on three layers."""
+    cfg, params = three_layer_model
+    prompts = list(_shared_prefix_prompts()) + [[42]]
+
+    def run(burst=1, **over):
+        kw = dict(draft_params=params, draft_config=cfg) \
+            if over.get("spec_mode") == "draft" else {}
+        eng = InferenceEngineV2(cfg, params, V2Config(
+            **{**_POOL_V2, "max_tokens_per_step": 32, **over}), **kw)
+        uids = [eng.put(list(p), max_new_tokens=12) for p in prompts]
+        res = eng.generate_all(burst=burst)
+        return [res[u] for u in uids], eng
+
+    single, _ = run()
+    if path == "burst":
+        got, eng = run(burst=4)
+        assert eng.burst_steps > 0
+    else:
+        got, eng = run(spec_mode=path, spec_k=3)
+        assert eng.spec_steps > 0
+    assert got == single
 
 
 # ---------------------------------------------------------------------------

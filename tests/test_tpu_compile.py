@@ -85,23 +85,94 @@ def _compile(fn, *args, kernels) -> str:
     return text
 
 
+def _pool_passes(compiled_text: str, pool) -> list:
+    """The instructions of a compiled program that copy, slice or update a
+    slice with a result the shape of a K/V pool ``(L, blocks, block, KV, D)``
+    or of one layer of it, by (name, opcode): ``copy``, ``copy-start`` to the
+    fast memory, ``slice-start``, ``dynamic-slice`` / ``dynamic-update-slice``
+    and the fusions XLA names after them.  Each is a pass over 0.1-1.7 GB at
+    the cells' sizes; a step program is to hold none (the in-place ``scatter``
+    of the step's rows is not one)."""
+    shapes = [f"bf16[{','.join(map(str, s))}]" for s in (pool, pool[1:])]
+    return [(name, opcode) for name, result, opcode in re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", compiled_text,
+        re.M) if any(s in result for s in shapes)
+        and re.search("copy|slice", f"{name} {opcode}")]
+
+
+def _lower_step_program(program: str, cfg, sds):
+    """``decode_step`` or ``mixed_step`` of a W8A16 model of ``cfg``, lowered
+    on shapes alone at the serving cells' sizes (32 rows, 512 tokens a step,
+    block 64, 416 blocks: ``benchmark/configs/*-w8.json``) → (lowered, the
+    pool's shape, the quantized parameters' shapes)."""
+    from deepspeed_tpu.inference.quantization import quantize_model_params
+    from deepspeed_tpu.inference.v2 import engine as v2e
+    from deepspeed_tpu.models import transformer as tfm
+
+    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
+                      num_blocks=416, max_blocks_per_seq=64)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda key: quantize_model_params(
+            tfm.init_params(key, cfg), bits=8, group=256),
+            jax.random.PRNGKey(0)))
+    pool = (cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
+            cfg.head_dim)
+    caches = {"k": sds(pool, jnp.bfloat16), "v": sds(pool, jnp.bfloat16)}
+    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
+    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
+    if program == "decode_step":
+        lowered = v2e.build_decode_forward(cfg, v2).lower(
+            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
+            rows(jnp.int32), rows(jnp.float32),
+            sds((2,), jnp.uint32), rows(jnp.int32))
+    else:
+        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
+        lowered = v2e.build_ragged_forward(cfg, v2).lower(
+            params, caches, tokens(), tokens(), tokens(), tables,
+            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.int32))
+    return lowered, pool, params
+
+
+def _assert_pools_stay_in_place(compiled, pool):
+    """The compiled step program holds a K/V pool in no form but the donated
+    buffer: no pass over a pool or a layer of one, a temp smaller than one
+    pool (the parent's held a second cache: 3.9-4.2 GB), and the output
+    aliasing both donated pools.  At the cells' 416 blocks, where neither a
+    pool nor a layer fits the fast memory XLA would otherwise prefetch it
+    to."""
+    pool_bytes = 2 * int(np.prod(pool))
+    assert _pool_passes(compiled.as_text(), pool) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes, (
+        f"temp {mem.temp_size_in_bytes / 1e9:.2f} GB holds a pool "
+        f"({pool_bytes / 1e9:.2f} GB)")
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+
+
 @pytest.mark.parametrize("kernel", ["decode", "prefill"])
 def test_paged_attention_compiles(one_chip, mosaic, kernel):
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_prefill_attention)
 
-    seqs, blocks, bs, max_blocks = 16, 256, 64, 16
+    """Each kernel alone on the whole pool (layers, blocks, ...) with the
+    layer a traced scalar: Mosaic takes ``k_hbm.at[layer, blk]``."""
+    seqs, layers, blocks, bs, max_blocks = 16, 4, 256, 64, 16
     sds = functools.partial(_sds, sharding=one_chip)
-    cache = sds((blocks, bs, KV, D), jnp.bfloat16)
+    pool = sds((layers, blocks, bs, KV, D), jnp.bfloat16)
+    layer = sds((), jnp.int32)
     tables, lens = sds((seqs, max_blocks), jnp.int32), sds((seqs,), jnp.int32)
     if kernel == "decode":
-        _compile(paged_decode_attention, sds((seqs, H, D), jnp.bfloat16),
-                 cache, cache, tables, lens,
-                 kernels=["paged_attention_decode"])
+        text = _compile(paged_decode_attention,
+                        sds((seqs, H, D), jnp.bfloat16), pool, pool, layer,
+                        tables, lens, kernels=["paged_attention_decode"])
     else:
-        _compile(paged_prefill_attention,
-                 sds((seqs, 512, H, D), jnp.bfloat16), cache, cache, tables,
-                 lens, lens, kernels=["paged_attention_prefill"])
+        text = _compile(paged_prefill_attention,
+                        sds((seqs, 512, H, D), jnp.bfloat16), pool, pool,
+                        layer, tables, lens, lens,
+                        kernels=["paged_attention_prefill"])
+    assert _pool_passes(text, (layers, blocks, bs, KV, D)) == []
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
@@ -224,51 +295,32 @@ def test_fused_adamw_compiles(one_chip, mosaic, n):
 @pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
 def test_step_programs_carry_their_names(one_chip, mosaic, program):
     """The server's two step programs lower, for the described chip at
-    Mistral-7B widths (two layers, W8A16), to modules ``jit_decode_step``
-    and ``jit_mixed_step``: the trace's ``XLA Modules`` line and every
-    operation name of the benchmark's breakdown start with them.  Their
-    kernels and the forward's scopes are in the lowered text by name."""
+    Mistral-7B's widths and depth (W8A16, the serving cells' sizes), to
+    modules ``jit_decode_step`` and ``jit_mixed_step``: the trace's ``XLA
+    Modules`` line and every operation name of the benchmark's breakdown
+    start with them.  Their kernels and the forward's scopes are in the
+    lowered text by name, and the compiled program leaves the K/V pools
+    where they lie."""
     import dataclasses
 
-    from deepspeed_tpu.inference.quantization import quantize_model_params
-    from deepspeed_tpu.inference.v2 import engine as v2e
     from deepspeed_tpu.models import transformer as tfm
 
-    cfg = dataclasses.replace(tfm.get_config("mistral-7b"), num_layers=2)
-    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
-                      num_blocks=64, max_blocks_per_seq=64)
-    sds = functools.partial(_sds, sharding=one_chip)
-    params = jax.tree.map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda key: quantize_model_params(
-            tfm.init_params(key, cfg), bits=8, group=256),
-            jax.random.PRNGKey(0)))
-    cache = sds((cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
-                 cfg.head_dim), jnp.bfloat16)
-    caches = {"k": cache, "v": cache}
-    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
-    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
-    if program == "decode_step":
-        lowered = v2e.build_decode_forward(cfg, v2).lower(
-            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
-            rows(jnp.int32), rows(jnp.float32),
-            sds((2,), jnp.uint32), rows(jnp.int32))
-        inside = ["paged_attention_decode", "mixed_gemm", "decode_attention",
-                  "cache_write", "sampler"]
-    else:
-        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
-        lowered = v2e.build_ragged_forward(cfg, v2).lower(
-            params, caches, tokens(), tokens(), tokens(), tables,
-            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
-            rows(jnp.int32))
-        inside = ["paged_attention_prefill", "mixed_gemm",
-                  "prefill_attention", "cache_write"]
+    cfg = dataclasses.replace(tfm.get_config("mistral-7b"), dtype="bfloat16",
+                              param_dtype="bfloat16")
+    lowered, pool, _ = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip))
+    assert pool == (32, 416, 64, KV, D)
+    inside = {"decode_step": ["paged_attention_decode", "mixed_gemm",
+                              "decode_attention", "cache_write", "sampler"],
+              "mixed_step": ["paged_attention_prefill", "mixed_gemm",
+                             "prefill_attention", "cache_write"]}[program]
     text = lowered.as_text(debug_info=True)
     assert f"module @jit_{program} " in text
     assert "tpu_custom_call" in text
     for name in inside:
         assert re.search(rf'[/"]{name}/', text), \
             f"{name} is not in the lowered program's operation names"
+    _assert_pools_stay_in_place(lowered.compile(), pool)
 
 
 # olmoe-1b-7b: 64 experts of width 1024 on hidden 2048, top 8; the decode
@@ -318,58 +370,39 @@ def test_grouped_mixed_gemm_compiles(one_chip, mosaic, k, n, tokens):
 
 @pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
 def test_olmoe_step_programs_compile(one_chip, mosaic, program):
-    """The two step programs of a 2-layer model at OLMoE-1B-7B's widths,
-    W8A16 experts and all, compile for the described chip; the grouped kernel
-    is in them by name once a projection (the layer loop holds three calls,
-    the expert codes go in whole: no slice of them), and the routed FFN's
-    four scopes and the q/k norm are in the lowered operation names."""
+    """The two step programs of OLMoE-1B-7B (all 16 layers, W8A16 experts and
+    all, the serving cell's sizes) compile for the described chip; the
+    grouped kernel is in them by name once a projection (the layer loop holds
+    three calls, the expert codes go in whole: no slice of them), the routed
+    FFN's four scopes and the q/k norm are in the lowered operation names,
+    and the K/V pools stay where they lie (at MHA widths a layer of one is
+    109 MB: every pass the parent made over it cost 4.6 ms)."""
     import dataclasses
 
-    from deepspeed_tpu.inference.quantization import quantize_model_params
-    from deepspeed_tpu.inference.v2 import engine as v2e
     from deepspeed_tpu.models import transformer as tfm
 
-    cfg = dataclasses.replace(tfm.get_config("olmoe-1b-7b"), num_layers=2,
+    cfg = dataclasses.replace(tfm.get_config("olmoe-1b-7b"),
                               dtype="bfloat16", param_dtype="bfloat16")
-    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
-                      num_blocks=64, max_blocks_per_seq=64)
-    sds = functools.partial(_sds, sharding=one_chip)
-    params = jax.tree.map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda key: quantize_model_params(
-            tfm.init_params(key, cfg), bits=8, group=256),
-            jax.random.PRNGKey(0)))
-    assert params["layers"]["moe"]["w_in"].codes.shape == (2, 64, 2048, 1024)
+    lowered, pool, params = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip))
+    assert pool == (16, 416, 64, 16, 128)
+    assert params["layers"]["moe"]["w_in"].codes.shape == (16, 64, 2048, 1024)
     assert params["layers"]["moe"]["router"].dtype == jnp.bfloat16
-    cache = sds((cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
-                 cfg.head_dim), jnp.bfloat16)
-    caches = {"k": cache, "v": cache}
-    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
-    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
-    if program == "decode_step":
-        lowered = v2e.build_decode_forward(cfg, v2).lower(
-            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
-            rows(jnp.int32), rows(jnp.float32),
-            sds((2,), jnp.uint32), rows(jnp.int32))
-    else:
-        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
-        lowered = v2e.build_ragged_forward(cfg, v2).lower(
-            params, caches, tokens(), tokens(), tokens(), tables,
-            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
-            rows(jnp.int32))
     text = lowered.as_text(debug_info=True)
     assert f"module @jit_{program} " in text
     for name in ("grouped_mixed_gemm", "mixed_gemm", "moe_route",
                  "moe_dispatch", "moe_experts", "moe_combine", "qk_norm"):
         assert re.search(rf'[/"]{name}/', text), \
             f"{name} is not in the lowered program's operation names"
-    compiled = lowered.compile().as_text()
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
     calls = re.findall(r"%(grouped_mixed_gemm[.\d]*) = [^\n]*custom-call\(",
-                       compiled)
+                       compiled_text)
     assert len(calls) == 3, calls
-    assert not re.search(r"dynamic-slice[^\n]*s8\[\d+,64,", compiled)
+    assert not re.search(r"dynamic-slice[^\n]*s8\[\d+,64,", compiled_text)
     assert not re.search(r"s8\[64,\d+,\d+\][^\n]* dynamic-slice\(",
-                         compiled)
+                         compiled_text)
+    _assert_pools_stay_in_place(compiled, pool)
 
 
 def test_mesh_follows_the_torus(topo):
