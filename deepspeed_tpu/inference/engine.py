@@ -121,12 +121,7 @@ def forward_cached(params, tokens, cache, start_pos, cfg: tfm.TransformerConfig)
         layer_body, (x,), (params["layers"], cache["k"], cache["v"]))
 
     x = tfm._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x[:, -1] @ params["embed"]["tokens"].astype(dt).T
-    else:
-        logits = x[:, -1] @ params["lm_head"]["w"].astype(dt)
-        if "b" in params["lm_head"]:
-            logits = logits + params["lm_head"]["b"].astype(dt)
+    logits = tfm.lm_logits(params, x[:, -1], cfg)
     new_cache = {"k": new_ks, "v": new_vs,
                  "length": cache["length"] + T}
     return logits.astype(jnp.float32), new_cache

@@ -19,7 +19,6 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from functools import partial
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -29,23 +28,16 @@ import numpy as np
 from ...models import transformer as tfm
 from ...observability.recorder import recorder
 from ...observability.trace import tracer
+# ``_decode_body`` and ``_memo`` are not used here: ``benchmark/logit_tap.py``
+# imports them from this module
+from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
+                       build_cow_copy, build_decode_forward,
+                       build_multi_decode_forward, build_ragged_forward,
+                       sample_rows)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder,
                      SequenceDescriptor)
-
-
-# Built forward functions are memoized per (builder, configs): every engine
-# over the same shapes — serving replicas, test fixtures — shares ONE jitted
-# callable, so XLA compiles each program once per process instead of once
-# per engine.  Params/caches are call arguments, never closed over, so
-# sharing is safe (donation is per-call).
-_BUILD_CACHE: dict = {}
-
-
-def _memo(key, build):
-    if key not in _BUILD_CACHE:
-        _BUILD_CACHE[key] = build()
-    return _BUILD_CACHE[key]
+from .spec import build_draft_spec_step, build_self_draft_step
 
 
 # What a step's body hands back to ``step``: the tokens by uid, the tokens in
@@ -124,38 +116,6 @@ class V2Config:
 
 
 # ---------------------------------------------------------------------------
-# per-row sampling (in-graph: the decode programs emit token ids, not logits)
-# ---------------------------------------------------------------------------
-
-
-def _row_keys(rng, seeds):
-    """One PRNG key per row: fold the request seed AND the row index into
-    the step key.  Folding the row index means two requests that picked the
-    same seed still draw independently within a batch; folding the request
-    seed means a request's sample stream survives row reassignment."""
-    rows = jnp.arange(seeds.shape[0])
-    return jax.vmap(
-        lambda s, r: jax.random.fold_in(jax.random.fold_in(rng, s), r)
-    )(seeds, rows)
-
-
-def sample_rows(logits, temps, rng, seeds):
-    """Per-row next-token selection: rows with ``temps <= 0`` take the
-    argmax (bit-identical to the pre-vectorization greedy path — the same
-    f32 logits through the same argmax); rows with ``temps > 0`` draw from
-    ``categorical(logits / temp)`` under their own fold_in key.  Both lanes
-    are computed and selected with ``jnp.where`` — no host sync, no
-    per-row control flow."""
-    with jax.named_scope("sampler"):
-        greedy = logits.argmax(-1).astype(jnp.int32)
-        keys = _row_keys(rng, seeds)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(
-            keys, scaled).astype(jnp.int32)
-        return jnp.where(temps > 0.0, sampled, greedy)
-
-
-# ---------------------------------------------------------------------------
 # batched heterogeneous-adapter LoRA (S-LoRA / Punica shape)
 # ---------------------------------------------------------------------------
 
@@ -186,495 +146,6 @@ def init_adapter_stack(model_cfg: tfm.TransformerConfig, v2: V2Config):
     return {name: {"a": jnp.zeros((L, S, K, r), dt),
                    "b": jnp.zeros((L, S, r, N), dt)}
             for name, (K, N) in adapter_target_shapes(model_cfg).items()}
-
-
-def _adapter_proj_delta(x, ab, slots):
-    """Per-row gathered low-rank delta for one projection: row ``s`` adds
-    ``(x_s @ A[slots_s]) @ B[slots_s]`` (scaling folded into B at load).
-
-    ``x``: (S, K) or (S, Q, K) activations; ``ab``: this layer's stacked
-    factors {"a": (slots, K, r), "b": (slots, r, N)}; ``slots``: (S,)
-    int32.  Gather + two thin batched matmuls — in-graph, no host sync;
-    rows on the all-zero null slot add an exact zero."""
-    a_sel = ab["a"][slots]  # (S, K, r)
-    b_sel = ab["b"][slots]  # (S, r, N)
-    if x.ndim == 2:
-        return jnp.einsum("sr,srn->sn",
-                          jnp.einsum("sk,skr->sr", x, a_sel), b_sel)
-    return jnp.einsum("sqr,srn->sqn",
-                      jnp.einsum("sqk,skr->sqr", x, a_sel), b_sel)
-
-
-# ---------------------------------------------------------------------------
-# ragged forward (jitted once; static shapes from V2Config)
-# ---------------------------------------------------------------------------
-
-
-def ragged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
-                         seq_index, position_ids, cfg: tfm.TransformerConfig,
-                         block_size: int):
-    """Correct-for-everything gather path. q: (T, H, D); caches
-    (num_blocks, bs, KV, D); returns (T, H, D)."""
-    import math
-
-    T, H, D = q.shape
-    KV = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
-    S_max = max_blocks * block_size
-
-    # gather each sequence's cache: (max_seqs, S_max, KV, D)
-    k_seq = k_cache[block_tables].reshape(block_tables.shape[0], S_max, KV, D)
-    v_seq = v_cache[block_tables].reshape(block_tables.shape[0], S_max, KV, D)
-    # per-token views (T, S_max, KV, D)
-    row = jnp.clip(seq_index, 0, block_tables.shape[0] - 1)
-    k_t = k_seq[row]
-    v_t = v_seq[row]
-    if KV != H:
-        rep = H // KV
-        k_t = jnp.repeat(k_t, rep, axis=2)
-        v_t = jnp.repeat(v_t, rep, axis=2)
-    scores = jnp.einsum("thd,tshd->ths", q.astype(jnp.float32),
-                        k_t.astype(jnp.float32)) / math.sqrt(D)
-    key_pos = jnp.arange(S_max)[None, None, :]
-    valid = key_pos <= position_ids[:, None, None]  # causal within sequence
-    valid &= key_pos < context_lens[row][:, None, None]
-    valid &= (seq_index >= 0)[:, None, None]
-    scores = jnp.where(valid, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("ths,tshd->thd", probs, v_t.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def prefill_scatter_coords(seq_index, position_ids, chunk_start, max_seqs: int,
-                           Qp: int):
-    """Coordinates for scattering the ragged (T, H, D) q into the per-sequence
-    (max_seqs, Qp, H, D) chunk layout, plus the gather coordinates to read the
-    attention output back.
-
-    Padding tokens (seq_index == -1) MUST get POSITIVE out-of-range sentinels
-    (row == max_seqs, col == Qp): JAX normalizes negative scatter indices
-    (idx + size) *before* the ``mode="drop"`` check, so a -1 sentinel would
-    wrap onto row max_seqs-1 and collide with a real sequence's write —
-    duplicate-index ``.set`` order is nondeterministic on TPU (r3 advisor,
-    high).  Only idx >= size is genuinely dropped.
-
-    Returns (scat_row, scat_col, gather_row, gather_col); gather coords are
-    clamped in-range (padding rows read garbage that callers drop)."""
-    row = jnp.clip(seq_index, 0, max_seqs - 1)
-    qp_col = position_ids - chunk_start[row]
-    valid = seq_index >= 0
-    scat_row = jnp.where(valid, row, max_seqs)
-    scat_col = jnp.where(valid, qp_col, Qp)
-    return scat_row, scat_col, row, jnp.clip(qp_col, 0, Qp - 1)
-
-
-def _ffn(m_in, lp, model_cfg: tfm.TransformerConfig, experts=None,
-         valid=None):
-    """The feed-forward half of a serving layer, the one place the three step
-    bodies (mixed, decode, verify) get it: ``m_in (..., H)`` → ``(out, moe
-    stats or None)``.  An MoE model runs ``moe/dropless.serving_moe_block``:
-    every top-k assignment is computed, so a row's output does not depend on
-    the rows beside it.  ``experts`` is what ``hoist_expert_codes`` kept out
-    of the layer scan; ``valid`` marks the rows the stats count."""
-    if model_cfg.num_experts > 0:
-        from ...moe.dropless import serving_moe_block
-
-        return serving_moe_block(m_in, lp["moe"], model_cfg, stacked=experts,
-                                 valid=valid)
-    if m_in.ndim == 2:  # as the dense programs were always traced: one
-        # batch of all rows (a dense model's step programs stay bit for bit
-        # the parent's, and hit its entries in the compile cache)
-        return tfm._mlp_block(m_in[None], lp["mlp"], model_cfg)[0], None
-    return tfm._mlp_block(m_in, lp["mlp"], model_cfg), None
-
-
-def _scan_layers(params):
-    """→ (the stacked layers as the layer scan slices them, the quantized
-    expert codes the scan must leave whole, or None)."""
-    from ...moe.dropless import hoist_expert_codes
-
-    return hoist_expert_codes(params["layers"])
-
-
-def _layer_xs(layers, adapters=None):
-    """What the layer scan of a step body steps over: ``(the layer's
-    parameters, its index, its adapter factors or {})``.  The K/V pools are
-    NOT here: they ride the scan's carry whole, each layer scatters the
-    step's rows into them at ``[layer, block, offset]`` and the paged kernels
-    read them at ``(layer, block)``, so a step program holds a pool in no form
-    but the one donated buffer (a pool handed to the scan as ``xs`` is
-    sliced a layer at a time, re-stacked into ``ys`` and copied: six passes
-    over 1.7 GB a step at the serving cells' sizes)."""
-    num_layers = jax.tree.leaves(layers)[0].shape[0]
-    return (layers, jnp.arange(num_layers, dtype=jnp.int32),
-            {} if adapters is None else adapters)
-
-
-def _moe_step_stats(per_layer):
-    """Per-layer ``(L, 2)`` stats of ``_ffn`` → int32 ``(2,)`` for the step:
-    experts hit summed over layers (the host divides by L), and the largest
-    rows-per-expert of any layer.  None for a dense model."""
-    if per_layer is None:
-        return None
-    return jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max()])
-
-
-def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
-    dt = jnp.dtype(v2.dtype)
-    bs = v2.block_size
-
-    def fwd_body(params, caches, token_ids, position_ids, seq_index,
-                 block_tables, context_lens, logits_rows, chunk_start,
-                 chunk_len, adapters=None, row_adapter=None):
-        T = token_ids.shape[0]
-        x = tfm.embed_tokens(params, token_ids, model_cfg,
-                             position_ids=position_ids)  # (T, H)
-        cos_full, sin_full = (None, None)
-        if model_cfg.position == "rope":
-            max_len = v2.max_blocks_per_seq * bs
-            cos_full, sin_full = tfm.rope_table(max_len, model_cfg.rot_dim,
-                                                model_cfg.rope_theta)
-
-        # KV write positions: token t → (block_tables[seq, pos//bs], pos%bs)
-        blk_col = position_ids // bs
-        row = jnp.clip(seq_index, 0, block_tables.shape[0] - 1)
-        blk_ids = block_tables[row, blk_col]  # (T,)
-        offsets = position_ids % bs
-        write_mask = (seq_index >= 0)
-        # park invalid tokens' writes in a scratch block (last block id is
-        # reserved by the engine for this)
-        scratch_block = caches["k"].shape[1] - 1
-        blk_ids = jnp.where(write_mask, blk_ids, scratch_block)
-
-        nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
-        # per-token scatter coordinates into the per-sequence chunk layout
-        # (max_seqs, Qp): row = sequence, col = offset within this step's
-        # chunk (padding handled by positive OOB sentinels — see helper)
-        Qp = v2.max_tokens_per_step
-        scat_row, scat_col, gath_row, gath_col = prefill_scatter_coords(
-            seq_index, position_ids, chunk_start, block_tables.shape[0], Qp)
-
-        # per-token adapter slot: each ragged token reads its row's slot
-        # (padding tokens pin to the null slot — their outputs are dropped
-        # and their KV writes park in scratch, but exact-zero is cheapest)
-        tok_slot = None
-        if adapters is not None:
-            tok_slot = jnp.where(
-                seq_index >= 0,
-                row_adapter[jnp.clip(seq_index, 0, row_adapter.shape[0] - 1)],
-                0)
-
-        layers, experts = _scan_layers(params)
-        xs = _layer_xs(layers, adapters)
-
-        def layer_body(carry, inp):
-            x, k_cache, v_cache = carry
-            lp, layer, ad = inp
-            a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
-            q = tfm._lin(a_in, lp["attn"], "wq", "bq")
-            k = tfm._lin(a_in, lp["attn"], "wk", "bk")
-            v = tfm._lin(a_in, lp["attn"], "wv", "bv")
-            if "wq" in ad:
-                q = q + _adapter_proj_delta(a_in, ad["wq"], tok_slot)
-            if "wk" in ad:
-                k = k + _adapter_proj_delta(a_in, ad["wk"], tok_slot)
-            if "wv" in ad:
-                v = v + _adapter_proj_delta(a_in, ad["wv"], tok_slot)
-            q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
-                            ).reshape(T, nh, hd)
-            k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
-                            ).reshape(T, nkv, hd)
-            v = v.reshape(T, nkv, hd)
-            if model_cfg.position == "rope":
-                cos = cos_full[position_ids]
-                sin = sin_full[position_ids]
-                # apply_rope expects (B,S,H,D); use batch dim 1
-                q = tfm.apply_rope(q[None], cos, sin)[0]
-                k = tfm.apply_rope(k[None], cos, sin)[0]
-            with jax.named_scope("cache_write"):
-                k_cache = k_cache.at[layer, blk_ids, offsets].set(
-                    k.astype(k_cache.dtype))
-                v_cache = v_cache.at[layer, blk_ids, offsets].set(
-                    v.astype(v_cache.dtype))
-            # chunked-prefill attention over paged KV: reorganize the ragged
-            # (T, H, D) q into per-sequence chunks and run the paged Pallas
-            # prefill kernel — never materializes the old (T, S_max, KV, D)
-            # per-token gather
-            from ...ops.pallas.paged_attention import paged_prefill_attention
-
-            with jax.named_scope("prefill_attention"):
-                q_seq = jnp.zeros((block_tables.shape[0], Qp, nh, hd),
-                                  q.dtype)
-                q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
-                o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
-                                                layer, block_tables,
-                                                chunk_start, chunk_len)
-                # padding rows read in-range garbage (clamped col), dropped
-                # later
-                o = o_seq[gath_row, gath_col]  # (T, H, D)
-            o_flat = o.reshape(T, nh * hd)
-            attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
-            if "wo" in ad:
-                attn_out = attn_out + _adapter_proj_delta(
-                    o_flat, ad["wo"], tok_slot)
-            m_src = x if model_cfg.parallel_residual else x + attn_out
-            m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm,
-                             model_cfg.norm_eps)
-            mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts,
-                                      write_mask)
-            x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
-                else (m_src + mlp_out)
-            return (x, k_cache, v_cache), moe_stats
-
-        (x, new_k, new_v), moe_stats = jax.lax.scan(
-            layer_body, (x, caches["k"], caches["v"]), xs)
-        x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
-        last_hidden = x[logits_rows]  # (max_seqs, H)
-        if model_cfg.tie_embeddings:
-            logits = last_hidden @ params["embed"]["tokens"].astype(dt).T
-        else:
-            logits = last_hidden @ params["lm_head"]["w"].astype(dt)
-            if "b" in params["lm_head"]:
-                logits = logits + params["lm_head"]["b"].astype(dt)
-        # last_hidden rides along for the self-draft speculation heads (the
-        # carried state their next proposals are computed from); an MoE
-        # model's step stats ride fourth (a dense model returns three)
-        out = (logits.astype(jnp.float32), last_hidden.astype(jnp.float32),
-               {"k": new_k, "v": new_v})
-        if moe_stats is not None:
-            out += (_moe_step_stats(moe_stats),)
-        return out
-
-    if v2.adapter_slots:
-        def mixed_step(params, caches, token_ids, position_ids, seq_index,
-                       block_tables, context_lens, logits_rows, chunk_start,
-                       chunk_len, adapters, row_adapter):
-            return fwd_body(params, caches, token_ids, position_ids,
-                            seq_index, block_tables, context_lens,
-                            logits_rows, chunk_start, chunk_len,
-                            adapters=adapters, row_adapter=row_adapter)
-    else:
-        def mixed_step(params, caches, token_ids, position_ids, seq_index,
-                       block_tables, context_lens, logits_rows, chunk_start,
-                       chunk_len):
-            return fwd_body(params, caches, token_ids, position_ids,
-                            seq_index, block_tables, context_lens,
-                            logits_rows, chunk_start, chunk_len)
-
-    return _memo(("ragged_fwd", model_cfg, dataclasses.astuple(v2)),
-                 lambda: jax.jit(mixed_step, donate_argnums=(1,)))
-
-
-def _with_stats(tokens, moe_stats):
-    """An MoE model's step stats behind the sampled tokens, in the one int32
-    array the step fetches anyway: ``(max_seqs,)`` for a dense model,
-    ``(max_seqs + 2,)`` for an MoE model."""
-    if moe_stats is None:
-        return tokens
-    return jnp.concatenate([tokens, moe_stats])
-
-
-def build_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config):
-    """Pure-decode step: one token per sequence, attention through the paged
-    Pallas kernel (ops/pallas/paged_attention.py) — the FastGen decode hot
-    loop.  tokens/positions: (max_seqs,); context_lens INCLUDE the new token.
-
-    Sampling happens IN-GRAPH per row (``sample_rows``): the program takes a
-    (max_seqs,) temperature vector + step rng + per-row seeds and returns the
-    selected token ids, so a mixed greedy/sampled batch is one host-sync-free
-    program (the ``decode_step@v2`` budget proves it)."""
-
-    if v2.adapter_slots:
-        def decode_step(params, caches, token_ids, position_ids,
-                        block_tables, context_lens, temps, rng, seeds,
-                        adapters, row_adapter):
-            logits, caches, moe_stats = _decode_body(
-                params, caches, token_ids, position_ids, block_tables,
-                context_lens, model_cfg, v2, adapters=adapters,
-                row_adapter=row_adapter)
-            return _with_stats(sample_rows(logits, temps, rng, seeds),
-                               moe_stats), caches
-    else:
-        def decode_step(params, caches, token_ids, position_ids,
-                        block_tables, context_lens, temps, rng, seeds):
-            logits, caches, moe_stats = _decode_body(
-                params, caches, token_ids, position_ids, block_tables,
-                context_lens, model_cfg, v2)
-            return _with_stats(sample_rows(logits, temps, rng, seeds),
-                               moe_stats), caches
-
-    return _memo(("decode_fwd", model_cfg, dataclasses.astuple(v2)),
-                 lambda: jax.jit(decode_step, donate_argnums=(1,)))
-
-
-def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2: V2Config,
-                               num_steps: int):
-    """Decode ``num_steps`` tokens per sequence inside ONE jitted program (an
-    outer ``lax.scan`` over single-token decodes) — eliminates the per-token
-    host roundtrip that dominates small-model decode.  Safe because admission
-    reserves each sequence's whole block budget up front.
-
-    Per-row sampling (``temps``/``seeds`` vectors, see ``sample_rows``) with
-    a per-step split of ``rng`` carried through the scan; rows with
-    ``temps <= 0`` stay greedy-argmax.
-
-    Returns (tokens_out (num_steps, max_seqs), caches)."""
-
-    def fwd_body(params, caches, token_ids, position_ids, block_tables,
-                 context_lens, rng, temps, seeds, adapters=None,
-                 row_adapter=None):
-        # rows inactive at entry must STAY inactive: advancing their ctx/pos
-        # would flip them "active" with a zeroed block table and corrupt
-        # block 0 of a real sequence
-        alive = (context_lens > 0).astype(jnp.int32)
-
-        def step(carry, _):
-            caches, tok, pos, ctx, rng = carry
-            logits, caches, _ = _decode_body(params, caches, tok, pos,
-                                             block_tables, ctx, model_cfg, v2,
-                                             adapters=adapters,
-                                             row_adapter=row_adapter)
-            rng, step_rng = jax.random.split(rng)
-            nxt = sample_rows(logits, temps, step_rng, seeds)
-            return (caches, nxt, pos + alive, ctx + alive, rng), nxt
-
-        (caches, _, _, _, _), toks = jax.lax.scan(
-            step, (caches, token_ids, position_ids, context_lens, rng), None,
-            length=num_steps)
-        return toks, caches
-
-    if v2.adapter_slots:
-        def multi_decode_step(params, caches, token_ids, position_ids,
-                              block_tables, context_lens, rng, temps, seeds,
-                              adapters, row_adapter):
-            return fwd_body(params, caches, token_ids, position_ids,
-                            block_tables, context_lens, rng, temps, seeds,
-                            adapters=adapters, row_adapter=row_adapter)
-    else:
-        def multi_decode_step(params, caches, token_ids, position_ids,
-                              block_tables, context_lens, rng, temps, seeds):
-            return fwd_body(params, caches, token_ids, position_ids,
-                            block_tables, context_lens, rng, temps, seeds)
-
-    return _memo(("multi_decode", model_cfg, dataclasses.astuple(v2),
-                  num_steps),
-                 lambda: jax.jit(multi_decode_step, donate_argnums=(1,)))
-
-
-def build_cow_copy():
-    """Copy one KV block to another across every layer — the copy-on-write
-    fork for partial-block prefix sharing.  ``src``/``dst`` are traced int32
-    scalars so every (src, dst) pair reuses one compiled program; positions
-    past the shared prefix carry stale KV that the paged kernels never read
-    (prefill overwrites the chunk before attention, and keys beyond
-    ``context_lens`` are masked)."""
-
-    def cow_copy(caches, src, dst):
-        k, v = caches["k"], caches["v"]
-        return {"k": k.at[:, dst].set(k[:, src]),
-                "v": v.at[:, dst].set(v[:, src])}
-
-    return _memo(("cow_copy",),
-                 lambda: jax.jit(cow_copy, donate_argnums=(0,)))
-
-
-def _decode_body(params, caches, token_ids, position_ids, block_tables,
-                 context_lens, model_cfg, v2, adapters=None,
-                 row_adapter=None):
-    """Single-token decode shared by build_decode_forward and the multi-step
-    scan (context_lens INCLUDE the current token); → (logits, caches, an MoE
-    model's step stats or None).  With ``adapters`` (the
-    stacked per-slot LoRA factors) and ``row_adapter`` (per-row slot
-    vector), each row's attention projections add its adapter's gathered
-    low-rank delta on top of the unchanged base path."""
-    from ...ops.pallas.paged_attention import paged_decode_attention
-
-    dt = jnp.dtype(v2.dtype)
-    bs = v2.block_size
-    S = token_ids.shape[0]
-    x = tfm.embed_tokens(params, token_ids, model_cfg,
-                         position_ids=position_ids)
-    cos_full, sin_full = (None, None)
-    if model_cfg.position == "rope":
-        max_len = v2.max_blocks_per_seq * bs
-        cos_full, sin_full = tfm.rope_table(max_len, model_cfg.rot_dim,
-                                            model_cfg.rope_theta)
-    active = context_lens > 0
-    blk_ids = jnp.where(
-        active,
-        block_tables[jnp.arange(S), position_ids // bs],
-        caches["k"].shape[1] - 1)
-    offsets = position_ids % bs
-    nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
-
-    layers, experts = _scan_layers(params)
-    xs = _layer_xs(layers, adapters)
-
-    def layer_body(carry, inp):
-        x, k_cache, v_cache = carry
-        lp, layer, ad = inp
-        a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
-        q = tfm._lin(a_in, lp["attn"], "wq", "bq")
-        k = tfm._lin(a_in, lp["attn"], "wk", "bk")
-        v = tfm._lin(a_in, lp["attn"], "wv", "bv")
-        if "wq" in ad:
-            q = q + _adapter_proj_delta(a_in, ad["wq"], row_adapter)
-        if "wk" in ad:
-            k = k + _adapter_proj_delta(a_in, ad["wk"], row_adapter)
-        if "wv" in ad:
-            v = v + _adapter_proj_delta(a_in, ad["wv"], row_adapter)
-        q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
-                        ).reshape(S, nh, hd)
-        k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
-                        ).reshape(S, nkv, hd)
-        v = v.reshape(S, nkv, hd)
-        if model_cfg.position == "rope":
-            cos = cos_full[position_ids][:, None, :].astype(dt)
-            sin = sin_full[position_ids][:, None, :].astype(dt)
-            rd = model_cfg.rot_dim
-
-            def rot(t):
-                tr = t[..., :rd]
-                t1, t2 = tr[..., ::2], tr[..., 1::2]
-                o1 = t1 * cos - t2 * sin
-                o2 = t2 * cos + t1 * sin
-                out = jnp.stack([o1, o2], axis=-1).reshape(tr.shape)
-                if rd == t.shape[-1]:
-                    return out
-                return jnp.concatenate([out, t[..., rd:]], axis=-1)
-
-            q, k = rot(q), rot(k)
-        with jax.named_scope("cache_write"):
-            k_cache = k_cache.at[layer, blk_ids, offsets].set(
-                k.astype(k_cache.dtype))
-            v_cache = v_cache.at[layer, blk_ids, offsets].set(
-                v.astype(v_cache.dtype))
-        with jax.named_scope("decode_attention"):
-            o = paged_decode_attention(q, k_cache, v_cache, layer,
-                                       block_tables, context_lens)
-        o_flat = o.reshape(S, nh * hd)
-        attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
-        if "wo" in ad:
-            attn_out = attn_out + _adapter_proj_delta(
-                o_flat, ad["wo"], row_adapter)
-        m_src = x if model_cfg.parallel_residual else x + attn_out
-        m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
-        mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts, active)
-        x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
-            else (m_src + mlp_out)
-        return (x, k_cache, v_cache), moe_stats
-
-    (x, new_k, new_v), moe_stats = jax.lax.scan(
-        layer_body, (x, caches["k"], caches["v"]), xs)
-    x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
-    if model_cfg.tie_embeddings:
-        logits = x @ params["embed"]["tokens"].astype(dt).T
-    else:
-        logits = x @ params["lm_head"]["w"].astype(dt)
-        if "b" in params["lm_head"]:
-            logits = logits + params["lm_head"]["b"].astype(dt)
-    return (logits.astype(jnp.float32), {"k": new_k, "v": new_v},
-            _moe_step_stats(moe_stats))
 
 
 # ---------------------------------------------------------------------------
@@ -809,8 +280,6 @@ class InferenceEngineV2:
         self.spec_emitted = 0  # total tokens emitted by spec steps
         self.spec_fallback = 0  # mixed steps taken while speculation enabled
         if mode == "self_draft":
-            from .spec import build_self_draft_step
-
             if self.spec_heads is None:
                 from ...linear.spec_heads import init_spec_heads
 
@@ -821,8 +290,6 @@ class InferenceEngineV2:
                     base_params=self.params)
             self._spec_fwd = build_self_draft_step(self.model_cfg, self.cfg)
         elif mode == "draft":
-            from .spec import build_draft_spec_step
-
             if draft_params is None or draft_config is None:
                 raise ValueError(
                     "spec_mode='draft' needs draft_params and draft_config")

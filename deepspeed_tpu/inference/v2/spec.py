@@ -38,7 +38,10 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ...linear.spec_heads import apply_spec_heads
 from ...models import transformer as tfm
+from ...ops.pallas.paged_attention import paged_prefill_attention
+from .programs import _decode_body, _memo, _row_keys, serving_layers
 
 
 def _leading_accepts(accept: jax.Array) -> jax.Array:
@@ -73,101 +76,31 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
 
     Returns (logits (S, Q, V) f32, hidden (S, Q, H), caches).
     """
-    from ...ops.pallas.paged_attention import paged_prefill_attention
-
-    dt = jnp.dtype(v2.dtype)
     bs = v2.block_size
-    S, Q = tokens.shape
+    Q = tokens.shape[1]
     pos = ctx[:, None] + jnp.arange(Q)[None, :]  # (S, Q)
     active = ctx > 0
     write_ok = active[:, None] & (pos < pos_limit[:, None])
-    scratch_block = caches["k"].shape[1] - 1
     blk_col = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
     blk_ids = jnp.where(write_ok,
                         jnp.take_along_axis(block_tables, blk_col, axis=1),
-                        scratch_block)
-    offsets = pos % bs
+                        caches["k"].shape[1] - 1)
     # attention window per row: chunk [ctx, ctx+chunk_len) — clipped at the
     # reservation so parked (unwritten) key slots are never read
     chunk_len = jnp.where(active,
                           jnp.clip(pos_limit - ctx, 0, Q), 0).astype(jnp.int32)
 
+    def attend(q, k_cache, v_cache, layer):
+        with jax.named_scope("prefill_attention"):
+            return paged_prefill_attention(q, k_cache, v_cache, layer,
+                                           block_tables, ctx * active,
+                                           chunk_len)
+
     x = tfm.embed_tokens(params, tokens, model_cfg, position_ids=pos)  # (S,Q,H)
-    cos_full, sin_full = (None, None)
-    if model_cfg.position == "rope":
-        max_len = v2.max_blocks_per_seq * bs
-        cos_full, sin_full = tfm.rope_table(max_len, model_cfg.rot_dim,
-                                            model_cfg.rope_theta)
-    nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
-
-    def layer_body(carry, inp):
-        x, k_cache, v_cache = carry
-        lp, layer, ad = inp
-        from .engine import _adapter_proj_delta, _ffn
-
-        a_in = tfm._norm(x, lp["ln1"], model_cfg.norm, model_cfg.norm_eps)
-        q = tfm._lin(a_in, lp["attn"], "wq", "bq")
-        k = tfm._lin(a_in, lp["attn"], "wk", "bk")
-        v = tfm._lin(a_in, lp["attn"], "wv", "bv")
-        if "wq" in ad:
-            q = q + _adapter_proj_delta(a_in, ad["wq"], row_adapter)
-        if "wk" in ad:
-            k = k + _adapter_proj_delta(a_in, ad["wk"], row_adapter)
-        if "wv" in ad:
-            v = v + _adapter_proj_delta(a_in, ad["wv"], row_adapter)
-        q = tfm.qk_norm(q, lp["attn"], "q_norm", model_cfg
-                        ).reshape(S, Q, nh, hd)
-        k = tfm.qk_norm(k, lp["attn"], "k_norm", model_cfg
-                        ).reshape(S, Q, nkv, hd)
-        v = v.reshape(S, Q, nkv, hd)
-        if model_cfg.position == "rope":
-            cos = cos_full[pos][:, :, None, :].astype(dt)
-            sin = sin_full[pos][:, :, None, :].astype(dt)
-            rd = model_cfg.rot_dim
-
-            def rot(t):
-                tr = t[..., :rd]
-                t1, t2 = tr[..., ::2], tr[..., 1::2]
-                o1 = t1 * cos - t2 * sin
-                o2 = t2 * cos + t1 * sin
-                out = jnp.stack([o1, o2], axis=-1).reshape(tr.shape)
-                if rd == t.shape[-1]:
-                    return out
-                return jnp.concatenate([out, t[..., rd:]], axis=-1)
-
-            q, k = rot(q), rot(k)
-        k_cache = k_cache.at[layer, blk_ids, offsets].set(
-            k.astype(k_cache.dtype))
-        v_cache = v_cache.at[layer, blk_ids, offsets].set(
-            v.astype(v_cache.dtype))
-        o = paged_prefill_attention(q, k_cache, v_cache, layer, block_tables,
-                                    ctx * active, chunk_len)
-        o_flat = o.reshape(S, Q, nh * hd)
-        attn_out = tfm._lin(o_flat, lp["attn"], "wo", "bo")
-        if "wo" in ad:
-            attn_out = attn_out + _adapter_proj_delta(
-                o_flat, ad["wo"], row_adapter)
-        m_src = x if model_cfg.parallel_residual else x + attn_out
-        m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
-        mlp_out, _ = _ffn(m_in, lp, model_cfg, experts)
-        x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
-            else (m_src + mlp_out)
-        return (x, k_cache, v_cache), None
-
-    from .engine import _layer_xs, _scan_layers
-
-    layers, experts = _scan_layers(params)
-    (x, new_k, new_v), _ = jax.lax.scan(
-        layer_body, (x, caches["k"], caches["v"]),
-        _layer_xs(layers, adapters))
-    x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
-    if model_cfg.tie_embeddings:
-        logits = x @ params["embed"]["tokens"].astype(dt).T
-    else:
-        logits = x @ params["lm_head"]["w"].astype(dt)
-        if "b" in params["lm_head"]:
-            logits = logits + params["lm_head"]["b"].astype(dt)
-    return logits.astype(jnp.float32), x, {"k": new_k, "v": new_v}
+    x, caches, _ = serving_layers(
+        params, caches, x, pos, (blk_ids, pos % bs), attend, model_cfg, v2,
+        adapters, row_adapter)
+    return tfm.lm_logits(params, x, model_cfg).astype(jnp.float32), x, caches
 
 
 def _accept_and_emit(logits, draft, draft_probs, rng, temps, seeds):
@@ -194,8 +127,6 @@ def _accept_and_emit(logits, draft, draft_probs, rng, temps, seeds):
     Returns (emitted (S, k+1) int32, accept_len (S,) int32) where
     ``emitted[:, :a+1]`` = accepted drafts + 1 correction/bonus token.
     """
-    from .engine import _row_keys
-
     S, Qk, _ = logits.shape
     k = Qk - 1
 
@@ -250,13 +181,8 @@ def build_self_draft_step(model_cfg: tfm.TransformerConfig, v2):
 
     Returns (emitted (S, k+1), accept_len (S,), new_hidden (S, H), caches).
     """
-    from ...linear.spec_heads import apply_spec_heads
-
-    def spec_body(params, heads, caches, next_tok, ctx, block_tables,
-                  pos_limit, last_hidden, rng, temps, seeds,
-                  adapters=None, row_adapter=None):
-        from .engine import _row_keys
-
+    def spec_step(params, heads, caches, next_tok, ctx, block_tables,
+                  pos_limit, last_hidden, rng, temps, seeds, *adapter_args):
         head_logits = apply_spec_heads(heads, last_hidden)  # (S, k, V) f32
         d_rng, v_rng = jax.random.split(rng)
         q = jax.nn.softmax(
@@ -271,26 +197,10 @@ def build_self_draft_step(model_cfg: tfm.TransformerConfig, v2):
         # target argmax — identity holds, only acceptance rate moves
         logits, hidden, caches = verify_body(
             params, caches, tokens, ctx, block_tables, pos_limit,
-            model_cfg, v2, adapters=adapters, row_adapter=row_adapter)
+            model_cfg, v2, *adapter_args)
         emitted, a = _accept_and_emit(logits, draft, q, v_rng, temps, seeds)
         new_hidden = _take_rows(hidden, a).astype(jnp.float32)  # (S, H)
         return emitted, a, new_hidden, caches
-
-    if v2.adapter_slots:
-        def spec_step(params, heads, caches, next_tok, ctx, block_tables,
-                      pos_limit, last_hidden, rng, temps, seeds,
-                      adapters, row_adapter):
-            return spec_body(params, heads, caches, next_tok, ctx,
-                             block_tables, pos_limit, last_hidden, rng,
-                             temps, seeds, adapters, row_adapter)
-    else:
-        def spec_step(params, heads, caches, next_tok, ctx, block_tables,
-                      pos_limit, last_hidden, rng, temps, seeds):
-            return spec_body(params, heads, caches, next_tok, ctx,
-                             block_tables, pos_limit, last_hidden, rng,
-                             temps, seeds)
-
-    from .engine import _memo
 
     return _memo(("spec_self_draft", model_cfg, dataclasses.astuple(v2)),
                  lambda: jax.jit(spec_step, donate_argnums=(2,)))
@@ -309,14 +219,10 @@ def build_draft_spec_step(model_cfg: tfm.TransformerConfig,
 
     Returns (emitted (S, k+1), accept_len (S,), caches, draft_caches).
     """
-    from .engine import _decode_body
-
     k = v2.spec_k
 
     def spec_step(params, draft_params, caches, draft_caches, next_tok, ctx,
                   block_tables, pos_limit, rng, temps, seeds):
-        from .engine import _row_keys
-
         active = ctx > 0
         sampled_row = temps > 0.0
 
@@ -348,8 +254,6 @@ def build_draft_spec_step(model_cfg: tfm.TransformerConfig,
             model_cfg, v2)
         emitted, a = _accept_and_emit(logits, draft, q, v_rng, temps, seeds)
         return emitted, a, caches, draft_caches
-
-    from .engine import _memo
 
     return _memo(("spec_draft", model_cfg, draft_cfg,
                   dataclasses.astuple(v2)),

@@ -392,11 +392,29 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     Partial rotary (gpt-neox/phi): when the table covers fewer than D/2
     frequencies, only the first 2*len(freqs) dims rotate; the rest pass
     through unchanged."""
+    return _rotate_pairs(x, cos, sin, (None, slice(None), None, slice(None)))
+
+
+def rope_at(x: jax.Array, cos_full: jax.Array, sin_full: jax.Array,
+            positions: jax.Array) -> jax.Array:
+    """``apply_rope`` for rows that each sit at a position of their own:
+    ``x (..., H, D)`` with ``positions`` of shape ``x.shape[:-2]`` into the
+    tables of ``rope_table`` (the serving step programs: ragged tokens, one
+    token a row, ``Q`` positions a row)."""
+    return _rotate_pairs(x, cos_full[positions], sin_full[positions],
+                         (..., None, slice(None)))
+
+
+def _rotate_pairs(x, cos, sin, expand):
+    """The rotation itself; ``cos[expand]`` broadcasts against
+    ``x[..., ::2]`` (indexed here, after the slices of ``x``, so that
+    ``apply_rope`` traces the operations in the order it always did), and
+    dims past twice its width pass through."""
     rot = 2 * cos.shape[-1]
     xr = x[..., :rot]
     x1, x2 = xr[..., ::2], xr[..., 1::2]
-    c = cos[None, :, None, :].astype(x.dtype)
-    s = sin[None, :, None, :].astype(x.dtype)
+    c = cos[expand].astype(x.dtype)
+    s = sin[expand].astype(x.dtype)
     o1 = x1 * c - x2 * s
     o2 = x2 * c + x1 * s
     out = jnp.stack([o1, o2], axis=-1).reshape(xr.shape)
@@ -708,15 +726,22 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: TransformerConfig,
             attn_fn: Optional[AttentionFn] = None,
             moe_fn: Optional[Callable] = None) -> jax.Array:
     """tokens (B, S) int32 → logits (B, S, V) in compute dtype."""
-    dt = jnp.dtype(cfg.dtype)
     x = forward_hidden(params, tokens, cfg, attn_fn=attn_fn, moe_fn=moe_fn)
     with jax.named_scope("lm_head"):
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["tokens"].astype(dt).T
-        else:
-            logits = x @ params["lm_head"]["w"].astype(dt)
-            if "b" in params["lm_head"]:  # gpt-j ties off with a bias
-                logits = logits + params["lm_head"]["b"].astype(dt)
+        return lm_logits(params, x, cfg)
+
+
+def lm_logits(params: Dict[str, Any], hidden: jax.Array,
+              cfg: TransformerConfig) -> jax.Array:
+    """Hidden state after the final norm ``(..., H)`` → logits ``(..., V)`` in
+    its dtype: the head of ``forward``, of the v1 engine and of the v2
+    engine's three step bodies."""
+    dt = hidden.dtype
+    if cfg.tie_embeddings:
+        return hidden @ params["embed"]["tokens"].astype(dt).T
+    logits = hidden @ params["lm_head"]["w"].astype(dt)
+    if "b" in params["lm_head"]:  # gpt-j ties off with a bias
+        logits = logits + params["lm_head"]["b"].astype(dt)
     return logits
 
 

@@ -290,17 +290,31 @@ def test_elastic_agent_survives_cascading_crash(tmp_path):
     """Every worker exiting nonzero at once (coordinator death) must NOT ban
     the healthy hosts — the group restarts with full membership."""
     import sys
+    import threading
     from deepspeed_tpu.elasticity.elastic_agent import AgentConfig, ElasticAgent
 
     state = tmp_path / "attempt"
     script = tmp_path / "worker.py"
-    # first group: every worker exits 1; later groups: clean exit
+    # first group: every worker exits 1, and all at one instant: a worker
+    # marks itself, waits (bounded) until all three have, and leaves at a
+    # time all three read off the newest marker.  Workers that crash as each
+    # gets there (interpreters start 100s of ms apart on a loaded machine)
+    # let a poll see one dead and two starting: that is the one-bad-host
+    # case of the tests above, and the agent rightly restarts twice.
+    # Later groups: clean exit.
     script.write_text(f"""
 import os, sys, time
+marks = [r"{state}" + "-" + h for h in ("h1", "h2", "h3")]
 p = r"{state}" + "-" + os.environ["DSTPU_ELASTIC_MEMBER"]
 if not os.path.exists(p):
     open(p, "w").close()
-    sys.exit(1)
+    give_up = time.monotonic() + 10.0
+    while not all(map(os.path.exists, marks)) and time.monotonic() < give_up:
+        time.sleep(0.005)
+    if time.monotonic() < give_up:
+        together = max(map(os.path.getmtime, marks)) + 0.3
+        time.sleep(max(0.0, together - time.time()))
+    os._exit(1)
 time.sleep(0.2)
 """)
     agent = ElasticAgent(
@@ -308,7 +322,15 @@ time.sleep(0.2)
         members_fn=lambda: ["h1", "h2", "h3"],
         agent_config=AgentConfig(max_restarts=4, poll_interval_s=0.1,
                                  term_timeout_s=2.0))
-    rc = agent.run()
+    # the test's own limit: a group that never ends must fail here, not at
+    # the suite's
+    result = []
+    runner = threading.Thread(target=lambda: result.append(agent.run()),
+                              daemon=True)
+    runner.start()
+    runner.join(timeout=60.0)
+    assert not runner.is_alive(), "the agent did not finish in 60 s"
+    (rc,) = result
     assert rc == 0
     assert agent.banned == set()  # one synchronized crash bans nobody
     assert agent.restart_count == 1  # single restart with full membership

@@ -101,16 +101,19 @@ def _pool_passes(compiled_text: str, pool) -> list:
 
 
 def _lower_step_program(program: str, cfg, sds):
-    """``decode_step`` or ``mixed_step`` of a W8A16 model of ``cfg``, lowered
-    on shapes alone at the serving cells' sizes (32 rows, 512 tokens a step,
-    block 64, 416 blocks: ``benchmark/configs/*-w8.json``) → (lowered, the
-    pool's shape, the quantized parameters' shapes)."""
+    """``decode_step``, ``mixed_step`` or the self-draft ``spec_step`` (four
+    proposals a row) of a W8A16 model of ``cfg``, lowered on shapes alone at
+    the serving cells' sizes (32 rows, 512 tokens a step, block 64, 416
+    blocks: ``benchmark/configs/*-w8.json``) → (lowered, the pool's shape,
+    the quantized parameters' shapes)."""
     from deepspeed_tpu.inference.quantization import quantize_model_params
     from deepspeed_tpu.inference.v2 import engine as v2e
     from deepspeed_tpu.models import transformer as tfm
 
     v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
-                      num_blocks=416, max_blocks_per_seq=64)
+                      num_blocks=416, max_blocks_per_seq=64,
+                      spec_mode="self_draft" if program == "spec_step"
+                      else "off")
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda key: quantize_model_params(
@@ -126,6 +129,19 @@ def _lower_step_program(program: str, cfg, sds):
             params, caches, rows(jnp.int32), rows(jnp.int32), tables,
             rows(jnp.int32), rows(jnp.float32),
             sds((2,), jnp.uint32), rows(jnp.int32))
+    elif program == "spec_step":
+        from deepspeed_tpu.inference.v2.spec import build_self_draft_step
+        from deepspeed_tpu.linear.spec_heads import init_spec_heads
+
+        heads = jax.tree.map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda key: init_spec_heads(
+                key, cfg, v2.spec_k, base_params=tfm.init_params(key, cfg)),
+                jax.random.PRNGKey(1)))
+        lowered = build_self_draft_step(cfg, v2).lower(
+            params, heads, caches, rows(jnp.int32), rows(jnp.int32), tables,
+            rows(jnp.int32), sds((v2.max_seqs, cfg.hidden_size), jnp.float32),
+            sds((2,), jnp.uint32), rows(jnp.float32), rows(jnp.int32))
     else:
         tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
         lowered = v2e.build_ragged_forward(cfg, v2).lower(
@@ -292,15 +308,18 @@ def test_fused_adamw_compiles(one_chip, mosaic, n):
         kernels=["fused_adamw"])
 
 
-@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step",
+                                     "spec_step"])
 def test_step_programs_carry_their_names(one_chip, mosaic, program):
-    """The server's two step programs lower, for the described chip at
-    Mistral-7B's widths and depth (W8A16, the serving cells' sizes), to
-    modules ``jit_decode_step`` and ``jit_mixed_step``: the trace's ``XLA
-    Modules`` line and every operation name of the benchmark's breakdown
-    start with them.  Their kernels and the forward's scopes are in the
-    lowered text by name, and the compiled program leaves the K/V pools
-    where they lie."""
+    """The server's two step programs and the self-draft speculation step
+    lower, for the described chip at Mistral-7B's widths and depth (W8A16,
+    the serving cells' sizes), to modules ``jit_decode_step``,
+    ``jit_mixed_step`` and ``jit_spec_step``: the trace's ``XLA Modules``
+    line and every operation name of the benchmark's breakdown start with
+    them.  Their kernels and the forward's scopes are in the lowered text by
+    name (the verify body has ``cache_write`` from the layer body it shares
+    with the other two), and the compiled program leaves the K/V pools where
+    they lie."""
     import dataclasses
 
     from deepspeed_tpu.models import transformer as tfm
@@ -310,10 +329,11 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
     lowered, pool, _ = _lower_step_program(
         program, cfg, functools.partial(_sds, sharding=one_chip))
     assert pool == (32, 416, 64, KV, D)
+    chunked = ["paged_attention_prefill", "mixed_gemm", "prefill_attention",
+               "cache_write"]
     inside = {"decode_step": ["paged_attention_decode", "mixed_gemm",
                               "decode_attention", "cache_write", "sampler"],
-              "mixed_step": ["paged_attention_prefill", "mixed_gemm",
-                             "prefill_attention", "cache_write"]}[program]
+              "mixed_step": chunked, "spec_step": chunked}[program]
     text = lowered.as_text(debug_info=True)
     assert f"module @jit_{program} " in text
     assert "tpu_custom_call" in text
