@@ -566,9 +566,11 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
         experts_hit=[round(a["moe_experts_hit"], 1) for a in steps[-3:]],
         gemm_tile_events=len(events), grouped_k_n_rows_tilem_tn=grouped)
     fallen = [a for a in events if "fallback" in a]
-    if check_kernels and (fallen or not grouped):
+    sliced = [a for a in events if a.get("layers") == 0]
+    if check_kernels and (fallen or sliced or not grouped):
         raise AssertionError(f"{phase}: of {len(events)} GEMM tile events, "
-                             f"grouped {grouped}, fallen back: {fallen}")
+                             f"grouped {grouped}, fallen back: {fallen}, "
+                             f"on a sliced layer's copy: {sliced}")
     sequences = [p + out[u] for p, u in zip(prompts, uids)]
     m, rank, std = reference_margins(engine.params, cfg, sequences)
     worst, exact, checked = 0.0, 0, 0
@@ -589,18 +591,22 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
 def check_gemm_tiles(phase: str, port: int) -> None:
     """``GET /debug/trace`` of the warmed server: every mixed GEMM the step
     programs traced left a ``kernel/mixed_gemm_tiles`` event with its tile,
-    and none gave way to dequantize-then-matmul."""
+    none gave way to dequantize-then-matmul, and each read the layer stack in
+    place (``layers`` > 0; 0 is a layer the scan sliced, which is a copy)."""
     events = [e["args"] for e in _get_json(port, "/debug/trace")["traceEvents"]
               if e["name"] == "kernel/mixed_gemm_tiles"]
     fallen = [a for a in events if "fallback" in a]
+    sliced = [a for a in events if a["layers"] == 0]
     tiles = sorted({(a["m"], a["k"], a["n"], a["tm"], a["tn"], a["tk"],
                      a["grid_steps"]) for a in events if "fallback" not in a})
     log(phase, mixed_gemm_tile_events=len(events),
+        on_the_layer_stack=len(events) - len(sliced),
         m_k_n_tm_tn_tk_steps=tiles)
-    if not events or fallen:
+    if not events or fallen or sliced:
         raise AssertionError(
             f"{phase}: /debug/trace shows {len(events)} "
-            f"kernel/mixed_gemm_tiles events, fallen back: {fallen}")
+            f"kernel/mixed_gemm_tiles events, fallen back: {fallen}, on a "
+            f"sliced layer's copy: {sliced}")
 
 
 def check_mixed_gemm(phase: str, params, cfg) -> None:

@@ -4,8 +4,11 @@ Reference: ``deepspeed/inference/quantization`` (``_init_group_wise_weight_
 quantization``, matmul_4bit/8bit paths) — weights live in HBM as int8/int4
 and dequantize inside the GEMM. Here the projection weights of every
 transformer layer become ``QuantizedWeight`` pytree nodes that
-``models/transformer._lin`` routes through the Pallas mixed GEMM; stacked
-(L, K, N) layers slice transparently under the layer scan.  The routed
+``models/transformer._lin`` routes through the Pallas mixed GEMM.  A layer
+scan over the stacked (L, K, N) nodes hands its body one layer's node, at the
+price of a copy of that layer's codes before each GEMM; the v2 engine's
+layer loop keeps the stacks whole and the kernels read them by layer
+(``inference/v2/programs.py:hoist_quantized``).  The routed
 experts of an MoE layer (``moe.w_in`` / ``w_gate`` / ``w_out``, stacked
 (L, E, K, N)) are quantized per expert in the same format and served by the
 grouped mixed GEMM (``ops/pallas/grouped_mixed_gemm``).

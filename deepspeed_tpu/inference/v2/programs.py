@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from ...models import transformer as tfm
-from ...moe.dropless import hoist_expert_codes, serving_moe_block
+from ...moe.dropless import serving_moe_block
+from ...ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
 from ...ops.pallas.paged_attention import (paged_decode_attention,
                                            paged_prefill_attention)
 
@@ -153,17 +154,14 @@ def prefill_scatter_coords(seq_index, position_ids, chunk_start, max_seqs: int,
     return scat_row, scat_col, row, jnp.clip(qp_col, 0, Qp - 1)
 
 
-def _ffn(m_in, lp, model_cfg: tfm.TransformerConfig, experts=None,
-         valid=None):
+def _ffn(m_in, lp, model_cfg: tfm.TransformerConfig, valid=None):
     """The feed-forward half of a serving layer, the one place the three step
     bodies (mixed, decode, verify) get it: ``m_in (..., H)`` → ``(out, moe
     stats or None)``.  An MoE model runs ``moe/dropless.serving_moe_block``:
     every top-k assignment is computed, so a row's output does not depend on
-    the rows beside it.  ``experts`` is what ``hoist_expert_codes`` kept out
-    of the layer scan; ``valid`` marks the rows the stats count."""
+    the rows beside it.  ``valid`` marks the rows the stats count."""
     if model_cfg.num_experts > 0:
-        return serving_moe_block(m_in, lp["moe"], model_cfg, stacked=experts,
-                                 valid=valid)
+        return serving_moe_block(m_in, lp["moe"], model_cfg, valid=valid)
     if m_in.ndim == 2:  # as the dense programs were always traced: one
         # batch of all rows (a dense model's step programs stay bit for bit
         # the parent's, and hit its entries in the compile cache)
@@ -194,6 +192,26 @@ def _with_stats(tokens, moe_stats):
 # ---------------------------------------------------------------------------
 
 
+def hoist_quantized(layers):
+    """Split a stacked ``layers`` tree for a layer scan: → (``xs``, what the
+    scan slices: the tree's nodes with None for every ``QuantizedWeight``, be
+    it an attention or MLP projection or the experts; ``layer_params(sliced,
+    layer)``, which the scan's body calls for its layer's tree, with a
+    ``LayerOf`` the whole stack at ``layer`` where a node was kept out:
+    ``tfm._lin`` and the routed FFN hand those to kernels that read the stack
+    in place)."""
+    def is_q(node):
+        return isinstance(node, QuantizedWeight)
+
+    nodes, treedef = jax.tree.flatten(layers, is_leaf=is_q)
+
+    def layer_params(sliced, layer):
+        return treedef.unflatten([LayerOf(n, layer) if is_q(n) else s
+                                  for n, s in zip(nodes, sliced)])
+
+    return [None if is_q(n) else n for n in nodes], layer_params
+
+
 def serving_layers(params, caches, x, positions, write_at, attend,
                    model_cfg: tfm.TransformerConfig, v2, adapters=None,
                    slots=None, valid=None):
@@ -221,12 +239,13 @@ def serving_layers(params, caches, x, positions, write_at, attend,
         max_len = v2.max_blocks_per_seq * v2.block_size
         cos_full, sin_full = tfm.rope_table(max_len, model_cfg.rot_dim,
                                             model_cfg.rope_theta)
-    # the quantized expert codes stay whole, out of what the scan slices
-    layers, experts = hoist_expert_codes(params["layers"])
+    # the quantized codes and scales stay whole, out of what the scan slices
+    layers, layer_params = hoist_quantized(params["layers"])
 
     def layer_body(carry, inp):
         x, k_cache, v_cache = carry
-        lp, layer, ad = inp
+        sliced, layer, ad = inp
+        lp = layer_params(sliced, layer)
 
         def proj(h, w_key, b_key):
             out = tfm._lin(h, lp["attn"], w_key, b_key)
@@ -254,13 +273,17 @@ def serving_layers(params, caches, x, positions, write_at, attend,
         attn_out = proj(o_flat, "wo", "bo")
         m_src = x if model_cfg.parallel_residual else x + attn_out
         m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
-        mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, experts, valid)
+        mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, valid)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
         return (x, k_cache, v_cache), moe_stats
 
     # The scan steps over (the layer's parameters, its index, its adapter
-    # factors or {}).  The K/V pools are NOT there: they ride the carry whole,
+    # factors or {}).  The quantized projections are NOT there: a kernel
+    # cannot fuse the slice of its operand, so a sliced layer's codes would be
+    # copied before every GEMM (more than half of a decode step's device time
+    # at Mistral-7B's widths); the kernels read the stacks at (layer, k, n).
+    # The K/V pools are NOT there either: they ride the carry whole,
     # each layer scatters the step's rows into them at [layer, block, offset]
     # and the paged kernels read them at (layer, block), so a step program
     # holds a pool in no form but the one donated buffer (a pool handed to the

@@ -30,7 +30,8 @@ from jax import lax
 
 from ..linear.optimized_linear import (LoRAWeight, expand_axes_for_lora,
                                        lora_forward)
-from ..ops.pallas.mixed_gemm import QuantizedWeight, mixed_gemm_frozen
+from ..ops.pallas.mixed_gemm import (LayerOf, QuantizedWeight,
+                                     mixed_gemm_frozen)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -559,6 +560,8 @@ def _lin(x, p, w_key, b_key):
         y = lora_forward(x, w)
     elif isinstance(w, QuantizedWeight):  # W8A16/W4A16 in-kernel dequant
         y = mixed_gemm_frozen(x, w)
+    elif isinstance(w, LayerOf):  # the same, on the layer stack in place
+        y = mixed_gemm_frozen(x, w.stack, w.layer)
     else:
         y = x @ w.astype(x.dtype)
     if b_key in p:

@@ -34,10 +34,8 @@ import jax.numpy as jnp
 
 from ..ops.pallas.grouped_matmul import grouped_matmul, tile_aligned_layout
 from ..ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
-from ..ops.pallas.mixed_gemm import QuantizedWeight
+from ..ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
 
-#: expert projections of a layer's ``moe`` dict
-EXPERT_KEYS = ("w_in", "w_gate", "w_out")
 _MIN_TILE_M, _MAX_TILE_M = 16, 512
 
 
@@ -87,17 +85,19 @@ def route(x2: jax.Array, router: jax.Array, cfg) -> Routing:
         return Routing(weights, experts.astype(jnp.int32), probs, logits)
 
 
-def _expert_gemm(a, w, tile_group, pad_sizes, used_tiles, tile_m, layer):
+def _expert_gemm(a, w, tile_group, pad_sizes, used_tiles, tile_m):
+    if isinstance(w, LayerOf):  # the layer stack (L, E, K, N), read in place
+        return grouped_mixed_gemm(a, w.stack, tile_group, pad_sizes,
+                                  used_tiles, tile_m=tile_m, layer=w.layer)
     if isinstance(w, QuantizedWeight):
         return grouped_mixed_gemm(a, w, tile_group, pad_sizes, used_tiles,
-                                  tile_m=tile_m, layer=layer)
+                                  tile_m=tile_m)
     return grouped_matmul(a, w.astype(a.dtype), tile_group, pad_sizes,
                           tile_m=tile_m)
 
 
 def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
                routing: Optional[Routing] = None,
-               layer: Optional[jax.Array] = None,
                valid: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, jax.Array]:
     """The routed expert FFN on ``x2 (N, H)``: route → layout → experts →
@@ -106,10 +106,9 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     the rows ``valid (N,)`` marks (all rows when None).
 
     ``p`` is a layer's ``moe`` dict.  Its expert weights are ``(E, K, N)``
-    arrays or ``QuantizedWeight`` nodes; with ``layer`` (an int32 scalar) the
-    nodes are the whole stack ``(L, E, K, N)`` and the kernel reads that
-    layer's experts in place (a scan that sliced them would copy one layer's
-    codes before every call)."""
+    arrays or ``QuantizedWeight`` nodes, or a ``LayerOf`` the whole stack
+    ``(L, E, K, N)``, whose layer's experts the kernel reads in place (a scan
+    that sliced them would copy one layer's codes before every call)."""
     N, H = x2.shape
     E, k = cfg.num_experts, cfg.moe_top_k
     dt = x2.dtype
@@ -135,7 +134,7 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     with jax.named_scope("moe_experts"):
         def gmm(a, key):
             return _expert_gemm(a, p[key], tile_group, pad_sizes, used_tiles,
-                                tile_m, layer)
+                                tile_m)
 
         if "w_gate" in p:
             hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
@@ -151,36 +150,14 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     return y, stats
 
 
-def hoist_expert_codes(layers: Dict[str, Any]
-                       ) -> Tuple[Dict[str, Any], Optional[Dict[str, Any]]]:
-    """Split a stacked ``layers`` tree for a layer scan: → (what the scan
-    slices, the quantized expert nodes it must not slice, or None).  The
-    scanned ``moe`` dict gets a ``layer`` index in their place, which
-    :func:`serving_moe_block` hands to the kernel."""
-    moe = layers.get("moe") or {}
-    stacked = {k: moe[k] for k in EXPERT_KEYS
-               if isinstance(moe.get(k), QuantizedWeight)}
-    if not stacked:
-        return layers, None
-    n_layers = next(iter(stacked.values())).codes.shape[0]
-    rest = {k: v for k, v in moe.items() if k not in stacked}
-    rest["layer"] = jnp.arange(n_layers, dtype=jnp.int32)
-    return {**layers, "moe": rest}, stacked
-
-
 def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
-                      stacked: Optional[Dict[str, Any]] = None,
                       valid: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """The MoE FFN of every inference engine: ``x (..., H)`` → ``(y, stats)``
     (``stats`` as :func:`routed_ffn` gives them).  ``p`` is the layer's
-    ``moe`` dict as the scan sliced it; ``stacked`` what
-    :func:`hoist_expert_codes` kept out of the scan."""
-    layer = None
-    if stacked is not None:
-        p, layer = {**p, **stacked}, p["layer"]
+    ``moe`` dict."""
     x2 = x.reshape(-1, x.shape[-1])
-    y, stats = routed_ffn(x2, p, cfg, layer=layer,
+    y, stats = routed_ffn(x2, p, cfg,
                           valid=None if valid is None else valid.reshape(-1))
     y = y.reshape(x.shape)
     if getattr(cfg, "moe_use_residual", False):

@@ -48,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...observability.trace import tracer
 from . import backend
 from .mixed_gemm import (_CHUNK_N, _VMEM_LIMIT, GemmTiles, QuantizedWeight,
-                         dequantize_gemm_weight, pick_gemm_tiles)
+                         dequantize_gemm_weight, layer_of_stack,
+                         pick_gemm_tiles)
 
 
 def pick_grouped_tiles(rows: int, tile_m: int, k: int, n: int, bits: int,
@@ -154,8 +155,7 @@ def grouped_mixed_gemm(x: jax.Array, qw: QuantizedWeight,
         backend.warn_fallback(
             "grouped_mixed_gemm", f"bits={qw.bits}, M={M}, tile_m={tile_m}, "
             f"K={K}, N={N}, group={qw.group} do not tile")
-        one = qw if layer is None else jax.tree.map(lambda a: a[layer], qw)
-        w = dequantize_gemm_weight(one).astype(x.dtype)
+        w = dequantize_gemm_weight(layer_of_stack(qw, layer)).astype(x.dtype)
         return jax.lax.ragged_dot(x, w, padded_group_sizes)
     codes, scales = qw.codes, qw.scales
     if layer is None:

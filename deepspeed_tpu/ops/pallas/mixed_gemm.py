@@ -6,13 +6,13 @@ Reference: the CUTLASS mixed GEMM family backing weight-quantized inference
 stays int8/int4 in HBM and dequantizes in registers inside the GEMM.
 
 TPU-native design: a Pallas kernel with grid (M/tm, N/tn, K/tk).  One grid
-step streams a ``(tk, tn)`` tile of codes out of HBM together with the
-``tk / group`` scale rows that belong to it, and walks the tile one
-quantization group and one column chunk at a time: dequantize in VMEM with
-that group's scale row, feed the MXU in bfloat16, accumulate in f32.  A tile
-is therefore several groups deep and thousands of columns wide: a grid step
-costs about a third of a microsecond whatever it moves, so it has to move
-megabytes.  ``pick_gemm_tiles`` is the one place that chooses ``(tm, tn,
+step streams a ``(tk, tn)`` tile of codes out of HBM (the scales of the
+tile's whole K column, ``(K / group, tn)``, arrive once a column of tiles)
+and walks the tile one quantization group and one column chunk at a time:
+dequantize in VMEM with that group's scale row, feed the MXU in bfloat16,
+accumulate in f32.  A tile is therefore several groups deep and thousands
+of columns wide: a grid step costs about a third of a microsecond whatever
+it moves, so it has to move megabytes.  ``pick_gemm_tiles`` is the one place that chooses ``(tm, tn,
 tk)``, from the shapes the call can see: the whole padded M up to 512 rows
 (the weights are read and dequantized once), the widest ``tn`` a VMEM budget
 holds, and a ``tk`` of as many whole groups as make a step about 2 MB of
@@ -23,8 +23,17 @@ instead of 2·K·N (bf16) — the same bandwidth win the reference gets, which
 is what matters for memory-bound decode.
 
 ``QuantizedWeight`` is a pytree node (static bits/group), so stacked
-per-layer weights slice transparently under ``lax.scan`` and shard under
-GSPMD like any other param leaf.
+per-layer weights shard under GSPMD like any other param leaf, and a
+``lax.scan`` over them hands its body one layer's node.  That slice is not
+free to this kernel: a ``pallas_call`` is a custom call, XLA cannot fuse the
+slice of an operand into it, so the layer's codes are written out and read
+again before every call (58.7 MB for one MLP projection of Mistral-7B: the
+copies cost 2.2 times the GEMMs they fed, PERF.md S2a).  So the layer is an
+index, not a slice: ``mixed_gemm(x, stack, layer)`` takes the codes ``(L, K,
+N)`` and scales ``(L, K / group, N)`` whole with ``layer`` scalar-prefetched,
+and the block index maps read the layer's tiles where they lie.  A 2-D weight
+is the stack of one layer.  ``LayerOf`` is what a layer loop hands ``_lin`` in
+place of the slice (``inference/v2/programs.py:serving_layers``).
 """
 
 from __future__ import annotations
@@ -86,6 +95,15 @@ class QuantizedWeight:
     @classmethod
     def tree_unflatten(cls, aux, leaves):
         return cls(leaves[0], leaves[1], *aux)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerOf:
+    """One layer of a stacked :class:`QuantizedWeight` that a layer loop kept
+    whole: the stack and the layer's index (an int32 scalar, traced), in the
+    place of the slice a ``lax.scan`` over the stack would have copied."""
+    stack: QuantizedWeight
+    layer: jax.Array
 
 
 def quantize_gemm_weight(w: jax.Array, bits: int = 8,
@@ -242,11 +260,13 @@ def _unpack_decode_fp6(c):
     return minifloat_decode(codes, 3, 2)
 
 
-def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int,
-                       group: int):
+def _mixed_gemm_kernel(lay_ref, x_ref, c_ref, s_ref, o_ref, acc_ref, *,
+                       bits: int, group: int):
     """One (tm, tn) output tile's step over a k-tile of ``g`` quantization
-    groups: codes (rows of g groups, tn), scales (g, 1, tn), each group
-    dequantized with its own scale row into the same f32 accumulator."""
+    groups: codes (rows of g groups, tn) and the scales of the tile's whole
+    K column ``(K / group, tn)``, each group dequantized with its own scale
+    row into the same f32 accumulator."""
+    del lay_ref  # the index maps read it
     kk = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -256,8 +276,9 @@ def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int,
 
     tn = o_ref.shape[1]
     rows = _code_rows(group, bits)
-    # static loops: every slice is a static, tile-aligned window
-    for gi in range(s_ref.shape[0]):
+    g = c_ref.shape[0] // rows  # groups a k-tile
+    # static loops: every slice of the codes is a static, tile-aligned window
+    for gi in range(g):
         x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
         for c0 in range(0, tn, _CHUNK_N):
             cols = slice(c0, min(c0 + _CHUNK_N, tn))
@@ -266,7 +287,7 @@ def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int,
                 c = _unpack_int4(c)
             if bits == 6:
                 c = _unpack_decode_fp6(c)
-            w = (c.astype(jnp.float32) * s_ref[gi, :, cols]
+            w = (c.astype(jnp.float32) * s_ref[pl.ds(kk * g + gi, 1), cols]
                  ).astype(jnp.bfloat16)
             acc_ref[:, cols] += jax.lax.dot_general(
                 x, w, (((1,), (0,)), ((), ())),
@@ -277,34 +298,48 @@ def _mixed_gemm_kernel(x_ref, c_ref, s_ref, o_ref, acc_ref, *, bits: int,
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
-def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, tiles: GemmTiles):
+def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, layer: jax.Array,
+                 tiles: GemmTiles):
+    """``qw``: the layer stack, codes ``(L, rows of K, N)`` and scales
+    ``(L, K / group, N)``, read in place at ``layer``."""
     M, K = x2.shape
     N = qw.out_features
     tm, tn, tk = tiles.tm, tiles.tn, tiles.tk
-    g = tk // qw.group
-    grid = (M // tm, N // tn, K // tk)
     kernel = functools.partial(_mixed_gemm_kernel, bits=qw.bits,
                                group=qw.group)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((_code_rows(tk, qw.bits), tn),
-                         lambda i, j, kk: (kk, j)),
-            # scales get a unit middle axis so every block dim is either
-            # lane-aligned or covers the full array dim (Mosaic legality)
-            pl.BlockSpec((g, 1, tn), lambda i, j, kk: (kk, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(M // tm, N // tn, K // tk),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda i, j, kk, lay: (i, kk)),
+                pl.BlockSpec((None, _code_rows(tk, qw.bits), tn),
+                             lambda i, j, kk, lay: (lay[0], kk, j)),
+                # the scales of the tile's whole K column, as they are stored
+                # (a full dimension is always a legal block): the block index
+                # does not move along kk, so a column is fetched once, and no
+                # reshaped copy of the scales exists
+                pl.BlockSpec((None, K // qw.group, tn),
+                             lambda i, j, kk, lay: (lay[0], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, lay: (i, j)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((M, N), x2.dtype),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=backend.interpret(),
         name="mixed_gemm",
-    )(x2, qw.codes, qw.scales[:, None, :])
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), x2, qw.codes, qw.scales)
+
+
+def layer_of_stack(qw: QuantizedWeight, layer: Optional[jax.Array]
+                   ) -> QuantizedWeight:
+    """Layer ``layer`` of a stacked weight as XLA slices it (what the
+    fallbacks and the backward dequantize); ``qw`` itself without a layer."""
+    return qw if layer is None else jax.tree.map(lambda a: a[layer], qw)
 
 
 def dequantize_gemm_weight(qw: QuantizedWeight) -> jax.Array:
@@ -433,15 +468,22 @@ def int8_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     return out.reshape(*lead, N)
 
 
-def mixed_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
+def mixed_gemm(x: jax.Array, qw: QuantizedWeight,
+               layer: Optional[jax.Array] = None) -> jax.Array:
     """``x @ dequant(qw)`` with in-kernel dequantization.
 
-    ``x``: (..., K). Falls back to the XLA dequant+matmul when shapes do not
-    tile (also the numeric oracle for tests).
+    ``x``: (..., K); ``qw``: codes ``(K, N)``, or the layer stack ``(L, K,
+    N)`` with ``layer`` an int32 scalar, which the kernel reads in place (a
+    slice of the stack would be copied before the call: module text).  Falls
+    back to the XLA dequant+matmul of the one layer when shapes do not tile
+    (also the numeric oracle for tests).
     """
-    if qw.codes.ndim != 2:
-        raise ValueError("mixed_gemm wants per-layer (K, N) codes; got "
-                         f"{qw.codes.shape} — slice stacked layers via scan")
+    stacked = qw.codes.ndim == 3
+    if qw.codes.ndim not in (2, 3) or stacked != (layer is not None):
+        raise ValueError(
+            f"mixed_gemm: codes {qw.codes.shape} "
+            f"{'need a' if stacked else 'take no'} layer index: (K, N) "
+            f"codes alone, or the stack (L, K, N) with layer=")
     K = x.shape[-1]
     N = qw.out_features
     # ragged M (e.g. prefill with an odd token count) pads up to the sublane
@@ -451,20 +493,25 @@ def mixed_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     tiles = pick_gemm_tiles(M + pad_m, K, N, qw.bits, qw.group,
                             x2.dtype.itemsize)
     # chosen once per shape, while the caller's program is traced: the ring
-    # (``/debug/trace``) shows which of a server's GEMMs run on the kernel
+    # (``/debug/trace``) shows which of a server's GEMMs run on the kernel,
+    # and on how many layers' codes in place (0: a 2-D operand)
     tracer.add_event("kernel/mixed_gemm_tiles", attrs={
         "m": M, "k": K, "n": N, "bits": qw.bits, "group": qw.group,
+        "layers": qw.codes.shape[0] if stacked else 0,
         **(dataclasses.asdict(tiles) if tiles else {"fallback": 1})})
     if tiles is not None:
         xp = jnp.pad(x2, ((0, pad_m), (0, 0))) if pad_m else x2
-        out = _gemm_pallas(xp, qw, tiles)
+        if not stacked:  # the stack of one layer
+            qw, layer = jax.tree.map(lambda a: a[None], qw), jnp.int32(0)
+        out = _gemm_pallas(xp, qw, layer, tiles)
         if pad_m:
             out = out[:M]
     else:
         backend.warn_fallback(
             "mixed_gemm", f"bits={qw.bits}, M={M}, K={K}, N={N}, "
             f"group={qw.group} do not tile")
-        out = x2 @ dequantize_gemm_weight(qw).astype(x2.dtype)
+        one = layer_of_stack(qw, layer)
+        out = x2 @ dequantize_gemm_weight(one).astype(x2.dtype)
     return out.reshape(*lead, N)
 
 
@@ -474,30 +521,35 @@ def mixed_gemm(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _frozen_gemm(bits, group, k, x, codes, scales):
-    return mixed_gemm(x, QuantizedWeight(codes, scales, bits, group, k))
+def _frozen_gemm(bits, group, k, x, codes, scales, layer):
+    return mixed_gemm(x, QuantizedWeight(codes, scales, bits, group, k),
+                      layer)
 
 
-def _frozen_gemm_fwd(bits, group, k, x, codes, scales):
-    return _frozen_gemm(bits, group, k, x, codes, scales), (codes, scales)
+def _frozen_gemm_fwd(bits, group, k, x, codes, scales, layer):
+    return (_frozen_gemm(bits, group, k, x, codes, scales, layer),
+            (codes, scales, layer))
 
 
 def _frozen_gemm_bwd(bits, group, k, res, g):
-    codes, scales = res
-    # cotangent flows to the activations only: dx = g @ W^T with W
-    # dequantized at the cotangent dtype.  The weight is frozen, so its
-    # cotangents are structural zeros (float0 for the integer codes) — the
-    # backward never builds a dW buffer.
-    w = dequantize_gemm_weight(QuantizedWeight(codes, scales, bits, group, k))
-    gx = g @ jnp.swapaxes(w.astype(g.dtype), -1, -2)
+    codes, scales, layer = res
+    # cotangent flows to the activations only: dx = g @ W^T with W (the one
+    # layer's) dequantized at the cotangent dtype.  The weight is frozen, so
+    # its cotangents are structural zeros (float0 for the integer codes and
+    # the layer index) — the backward never builds a dW buffer.
+    qw = layer_of_stack(QuantizedWeight(codes, scales, bits, group, k), layer)
+    gx = g @ jnp.swapaxes(dequantize_gemm_weight(qw).astype(g.dtype), -1, -2)
     return (gx, np.zeros(codes.shape, dtype=jax.dtypes.float0),
-            jnp.zeros(scales.shape, scales.dtype))
+            jnp.zeros(scales.shape, scales.dtype),
+            None if layer is None
+            else np.zeros(jnp.shape(layer), dtype=jax.dtypes.float0))
 
 
 _frozen_gemm.defvjp(_frozen_gemm_fwd, _frozen_gemm_bwd)
 
 
-def mixed_gemm_frozen(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
+def mixed_gemm_frozen(x: jax.Array, qw: QuantizedWeight,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
     """:func:`mixed_gemm` for frozen weights inside a differentiated graph.
 
     ``pallas_call`` has no JVP rule, so the bare kernel breaks under
@@ -506,4 +558,5 @@ def mixed_gemm_frozen(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     *through* this matmul).  The custom VJP keeps the kernel forward and
     differentiates w.r.t. ``x`` only, via the dequant oracle — which is a
     training-only cost; inference traces never call it."""
-    return _frozen_gemm(qw.bits, qw.group, qw.k, x, qw.codes, qw.scales)
+    return _frozen_gemm(qw.bits, qw.group, qw.k, x, qw.codes, qw.scales,
+                        layer)

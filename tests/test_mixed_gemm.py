@@ -121,6 +121,65 @@ def test_stacked_layers_slice_under_scan():
     np.testing.assert_allclose(out, ref, atol=5e-2, rtol=5e-2)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("n", [256, 300], ids=["tiled", "fallback"])
+@pytest.mark.parametrize("m", [8, 32, 512])
+@pytest.mark.parametrize("bits", [8, 4, 6])
+def test_layer_of_a_stack_is_read_in_place(bits, m, n, layer):
+    """``mixed_gemm(x, stack, layer=i)`` on the codes ``(L, K, N)`` whole is
+    bit for bit ``mixed_gemm(x, stack[i])``: eager, under ``jit`` with the
+    layer traced, and in a ``lax.scan`` over the layers' indices (what the
+    served layer loop does, in place of a scan that slices the stack and
+    makes the kernel's operand a copy).  N = 300 does not tile: the fallback
+    dequantizes the one layer.  The ring event says how many layers the
+    operand held."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import mixed_gemm as mg
+
+    L, K, group = 3, 256, 128
+    kx, kw = jax.random.split(jax.random.PRNGKey(bits * 1000 + m + n))
+    x = jax.random.normal(kx, (m, K), jnp.bfloat16)
+    # another magnitude a layer and a group: the wrong layer's codes or the
+    # wrong row of the scales' K column cannot pass
+    w = jax.random.normal(kw, (L, K, n), jnp.float32) * (
+        2.0 ** jnp.arange(L))[:, None, None] * jnp.repeat(
+        jnp.asarray([1.0, 3.0]), group)[None, :, None]
+    stack = quantize_gemm_weight(w, bits=bits, group=group)
+    assert stack.codes.ndim == 3 and stack.scales.shape == (L, K // group, n)
+    assert (mg.pick_gemm_tiles(m, K, n, bits, group) is None) == (n == 300)
+    one = jax.tree.map(lambda a: a[layer], stack)
+
+    want = mixed_gemm(x, one)
+    np.testing.assert_array_equal(mixed_gemm(x, stack, layer=layer), want)
+    event = tracer.spans(name="kernel/mixed_gemm_tiles")[-1].attrs
+    assert event["layers"] == L and ("fallback" in event) == (n == 300)
+
+    want_jit = jax.jit(mixed_gemm)(x, one)
+    got = jax.jit(lambda x_, qw, i: mixed_gemm(x_, qw, layer=i))(
+        x, stack, jnp.int32(layer))
+    np.testing.assert_array_equal(got, want_jit)
+    _, scanned = jax.lax.scan(
+        lambda c, i: (c, mixed_gemm(x, stack, layer=i)), None,
+        jnp.arange(L, dtype=jnp.int32))
+    np.testing.assert_array_equal(scanned[layer], want_jit)
+    _oracle_check(x.astype(jnp.float32), one, want.astype(jnp.float32))
+
+
+def test_stack_and_layer_go_together():
+    """Stacked codes without a layer, and a layer with 2-D codes, raise: the
+    one would multiply by L matrices, the other index K."""
+    from deepspeed_tpu.ops.pallas.mixed_gemm import mixed_gemm_frozen
+
+    w = jax.random.normal(jax.random.PRNGKey(0), (3, 256, 256), jnp.float32)
+    stack = quantize_gemm_weight(w, bits=8, group=128)
+    x = jnp.ones((8, 256), jnp.bfloat16)
+    for gemm in (mixed_gemm, mixed_gemm_frozen):
+        with pytest.raises(ValueError, match="need a layer index"):
+            gemm(x, stack)
+        with pytest.raises(ValueError, match="take no layer index"):
+            gemm(x, jax.tree.map(lambda a: a[0], stack), jnp.int32(0))
+
+
 def test_quantized_inference_end_to_end():
     from deepspeed_tpu.inference.engine import InferenceConfig, InferenceEngine
     from deepspeed_tpu.inference.quantization import quantized_bytes
@@ -333,10 +392,10 @@ def test_tile_choice_is_recorded_at_trace_time():
     assert len(tracer.spans(name="kernel/mixed_gemm_tiles")) == before + 1
     t = mg.pick_gemm_tiles(M, K, N, 8, 128, 4)
     assert last() == {"m": M, "k": K, "n": N, "bits": 8, "group": 128,
-                      "tm": t.tm, "tn": t.tn, "tk": t.tk,
+                      "layers": 0, "tm": t.tm, "tn": t.tn, "tk": t.tk,
                       "grid_steps": t.grid_steps,
                       "code_bytes_per_step": t.code_bytes_per_step}
     qw49 = quantize_gemm_weight(w[:98, :33], bits=8, group=49)
     mixed_gemm(x[:, :98], qw49)
     assert last() == {"m": M, "k": 98, "n": 33, "bits": 8, "group": 49,
-                      "fallback": 1}
+                      "layers": 0, "fallback": 1}

@@ -174,3 +174,83 @@ def test_greedy_serving_token_identity_pre_post_swap(monkeypatch):
     dequant_out = _greedy_tokens(cfg, params, prompts, max_new=8)
 
     assert kernel_out == dequant_out
+
+
+# -- the quantized stacks through the one serving layer body -----------------
+
+_STACK_V2 = dict(max_tokens_per_step=16, max_seqs=4, block_size=8,
+                 num_blocks=64, max_blocks_per_seq=8, dtype="bfloat16",
+                 quantize_bits=8, quantize_group=128)
+
+
+def _adapter_pack(cfg, rank=4):
+    from deepspeed_tpu.inference.v2.engine import adapter_target_shapes
+
+    rng = np.random.default_rng(7)
+    return {target: ((rng.standard_normal((cfg.num_layers, K, rank))
+                      / np.sqrt(K)).astype(np.float32),
+                     (0.5 * rng.standard_normal((cfg.num_layers, rank, N))
+                      ).astype(np.float32))
+            for target, (K, N) in adapter_target_shapes(cfg).items()}
+
+
+@pytest.mark.parametrize("path,over", [
+    ("steps", {}), ("burst", {}),
+    ("self_draft", dict(spec_mode="self_draft", spec_k=3)),
+    ("adapter", dict(adapter_slots=2, adapter_rank=4)),
+], ids=["steps", "burst", "self_draft", "adapter"])
+def test_served_stacks_give_the_sliced_scans_tokens(monkeypatch, path, over):
+    """A three-layer W8A16 model through ``serving_layers``: chunked prefill
+    (20-token prompts, 16 tokens a step), single decode steps, the burst
+    program, the self-draft verify step, and per-row LoRA deltas on the
+    quantized base give exactly the tokens of the parent's programs, whose
+    layer scan sliced every ``QuantizedWeight`` (and copied it: what
+    ``hoist_quantized`` is for).  Every mixed GEMM a served program traced
+    read a stack of three layers in place and none fell back; the sliced
+    programs' events say ``layers`` 0."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.v2 import programs
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.serving.adapters import AdapterRegistry
+
+    cfg = dataclasses.replace(tfm.get_config("tiny", dtype="bfloat16"),
+                              num_layers=3, num_kv_heads=2)
+    params = jax.tree.map(lambda a: a * 3.0 if a.ndim >= 2 else a,
+                          tfm.init_params(jax.random.PRNGKey(1), cfg))
+    rng = np.random.default_rng(1)
+    first = rng.integers(1, 256, 20).tolist()
+    prompts = [first, first[:12] + rng.integers(1, 256, 4).tolist(), [42]]
+
+    def serve():
+        monkeypatch.setattr(programs, "_BUILD_CACHE", {})  # trace anew
+        tracer.clear()
+        eng = InferenceEngineV2(cfg, params, V2Config(**{**_STACK_V2,
+                                                         **over}))
+        slot = 0
+        if path == "adapter":
+            reg = AdapterRegistry(eng)
+            reg.register("a", pack=_adapter_pack(cfg))
+            slot = reg.acquire("a")
+        uids = [eng.put(list(p), max_new_tokens=10,
+                        **({"adapter_slot": slot if i != 1 else 0}
+                           if path == "adapter" else {}))
+                for i, p in enumerate(prompts)]
+        res = eng.generate_all(burst=4 if path == "burst" else 1)
+        if path == "burst":
+            assert eng.burst_steps > 0
+        if path == "self_draft":
+            assert eng.spec_steps > 0
+        events = [s.attrs
+                  for s in tracer.spans(name="kernel/mixed_gemm_tiles")]
+        assert events and not any("fallback" in e for e in events)
+        return [res[u] for u in uids], {e["layers"] for e in events}
+
+    got, layers = serve()
+    assert layers == {cfg.num_layers}
+    monkeypatch.setattr(  # the scan slices it all
+        programs, "hoist_quantized", lambda tree: (tree, lambda lp, _: lp))
+    want, layers = serve()
+    assert layers == {0}
+    assert got == want

@@ -264,7 +264,9 @@ def test_fencing_epoch_lifecycle(make_registry):
     assert reply == {"ev": "hello_err", "reason": "stale_epoch"}
     s.close()
     sc.close()
-    assert metrics.fleet["registrations"] == 3
+    # counted after the slot is wired, on the registry's thread
+    wait_until(lambda: metrics.fleet["registrations"] == 3,
+               msg="three registrations counted")
 
 
 # ---------------------------------------------------------------------------

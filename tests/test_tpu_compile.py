@@ -100,6 +100,36 @@ def _pool_passes(compiled_text: str, pool) -> list:
         and re.search("copy|slice", f"{name} {opcode}")]
 
 
+def _weight_passes(compiled_text: str, params) -> list:
+    """The instructions of a compiled program that copy or slice with a
+    result the shape of one layer of a quantized projection: its ``s8`` codes
+    or its ``f32`` scales, with or without unit dimensions (an expert stack
+    counts whole and an expert at a time), by (name, opcode, result).  Each
+    was a pass over up to 58.7 MB before the GEMM that reads it, where W8A16
+    exists to read the codes once; a step program is to hold none.  (XLA's own
+    prefetch of a whole scale stack, or of a few layers of one, to the fast
+    memory is not a layer's shape and is not counted.)"""
+    from deepspeed_tpu.ops.pallas.mixed_gemm import QuantizedWeight
+
+    def squeeze(dims):
+        return tuple(d for d in dims if d != 1)
+
+    shapes = set()
+    def is_q(node):
+        return isinstance(node, QuantizedWeight)
+
+    for qw in filter(is_q, jax.tree.leaves(params["layers"], is_leaf=is_q)):
+        for dtype, a in (("s8", qw.codes), ("f32", qw.scales)):
+            shapes |= {(dtype, squeeze(a.shape[1:])),
+                       (dtype, squeeze(a.shape[-2:]))}
+    return [(name, opcode, result) for name, result, opcode in re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", compiled_text,
+        re.M) if re.search("copy|slice", f"{name} {opcode}")
+        and any((dtype, squeeze(map(int, dims.split(",")))) in shapes
+                for dtype, dims in re.findall(r"\b(s8|f32)\[([\d,]+)\]",
+                                              result))]
+
+
 def _lower_step_program(program: str, cfg, sds):
     """``decode_step``, ``mixed_step`` or the self-draft ``spec_step`` (four
     proposals a row) of a W8A16 model of ``cfg``, lowered on shapes alone at
@@ -149,6 +179,31 @@ def _lower_step_program(program: str, cfg, sds):
             rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
             rows(jnp.int32))
     return lowered, pool, params
+
+
+#: ``temp_size_in_bytes`` of the parent's step programs (afc6556, whose layer
+#: scan sliced every quantized projection) at the cells' sizes.  Mistral's
+#: decode and verify steps held a layer's MLP codes there; its mixed step and
+#: OLMoE's programs held their slices in the fast memory, outside this count.
+_PARENT_TEMP = {("mistral-7b", "decode_step"): 156_327_424,
+                ("mistral-7b", "mixed_step"): 404_226_048,
+                ("mistral-7b", "spec_step"): 156_511_232,
+                ("olmoe-1b-7b", "decode_step"): 1_032_704,
+                ("olmoe-1b-7b", "mixed_step"): 68_173_312}
+
+
+def _assert_weights_stay_in_place(compiled, params, model, program):
+    """No pass over a layer's codes or scales, the dense kernel still there by
+    name, and no more temp than the parent's.  OLMoE is allowed 1.1 MB over
+    it: XLA now prefetches the attention projections' scale stacks (1 MB
+    each) a few layers at a time, and every such ``slice-start`` holds 16 KB
+    of temp for its 512 bytes of bookkeeping (0.48 / 1.03 MB in all)."""
+    text = compiled.as_text()
+    assert _weight_passes(text, params) == []
+    assert re.search(r"%mixed_gemm[.\d]* = [^\n]*custom-call\(", text)
+    slack = 1_100_000 if model == "olmoe-1b-7b" else 0
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _PARENT_TEMP[model, program] + slack, temp
 
 
 def _assert_pools_stay_in_place(compiled, pool):
@@ -224,22 +279,30 @@ def test_flash_attention_on_a_mesh_compiles(topo, mosaic):
     assert f"bf16[1,{H},2048,{D}]" in text  # one batch row a chip
 
 
-@pytest.mark.parametrize("bits", [8, 4, 6])
-@pytest.mark.parametrize("k,n", [(HIDDEN, H * D), (HIDDEN, KV * D),
-                                 (HIDDEN, MLP), (MLP, HIDDEN)],
-                         ids=["wq-wo", "wk-wv", "w_in-w_gate", "w_out"])
-@pytest.mark.parametrize("m", [32, 512], ids=["decode", "prefill"])
-def test_mixed_gemm_compiles(one_chip, mosaic, bits, k, n, m):
+_WIDTHS = {"wq-wo": (HIDDEN, H * D), "wk-wv": (HIDDEN, KV * D),
+           "w_in-w_gate": (HIDDEN, MLP), "w_out": (MLP, HIDDEN)}
+
+
+@pytest.mark.parametrize("m,bits,width,layers", [
+    *((m, bits, width, 0) for m in (32, 512) for width in _WIDTHS
+      for bits in (8, 4, 6)),
+    # what the served layer loop calls: the stack of 32 layers, read in place
+    *((m, 8, width, 32) for m in (32, 512) for width in _WIDTHS),
+    (32, 4, "w_in-w_gate", 32), (32, 6, "w_out", 32)])
+def test_mixed_gemm_compiles(one_chip, mosaic, bits, width, m, layers):
     """The seven projections of a layer at the rows the serving cells run
     (32 decode rows, a chunk of 512 tokens), at the tile the picker gives
     them: the only place a machine without the chip sees a tile that
     overflows VMEM or a block Mosaic refuses.  An int8 call reads its
-    weights once (one M tile) in steps of at least 1 MB of codes."""
+    weights once (one M tile) in steps of at least 1 MB of codes.  On the
+    layer stack (``layers`` > 0, the layer a traced scalar) the compiled call
+    holds no copy or slice of a layer's codes or scales."""
     from deepspeed_tpu.ops.pallas.mixed_gemm import (QuantizedWeight,
                                                      mixed_gemm,
                                                      pick_gemm_tiles,
                                                      quantize_gemm_weight)
 
+    k, n = _WIDTHS[width]
     tiles = pick_gemm_tiles(m, k, n, bits, 256)
     assert tiles.tm == m and tiles.tk % 256 == 0 and k % tiles.tk == 0
     assert tiles.grid_steps == (n // tiles.tn) * (k // tiles.tk)
@@ -247,13 +310,19 @@ def test_mixed_gemm_compiles(one_chip, mosaic, bits, k, n, m):
         assert tiles.code_bytes_per_step == tiles.tk * tiles.tn >= 1 << 20
     qw = jax.eval_shape(
         functools.partial(quantize_gemm_weight, bits=bits, group=256),
-        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
-    _compile(
-        lambda x, c, s: mixed_gemm(x, QuantizedWeight(c, s, bits, 256, k)),
-        _sds((m, k), jnp.bfloat16, one_chip),
-        _sds(qw.codes.shape, qw.codes.dtype, one_chip),
-        _sds(qw.scales.shape, qw.scales.dtype, one_chip),
-        kernels=["mixed_gemm"])
+        jax.ShapeDtypeStruct(((layers,) if layers else ()) + (k, n),
+                             jnp.bfloat16))
+    args = [_sds((m, k), jnp.bfloat16, one_chip),
+            _sds(qw.codes.shape, qw.codes.dtype, one_chip),
+            _sds(qw.scales.shape, qw.scales.dtype, one_chip)]
+    if layers:
+        args.append(_sds((), jnp.int32, one_chip))
+    text = _compile(
+        lambda x, c, s, *layer: mixed_gemm(
+            x, QuantizedWeight(c, s, bits, 256, k), *layer),
+        *args, kernels=["mixed_gemm"])
+    if layers:
+        assert _weight_passes(text, {"layers": {"w": qw}}) == []
 
 
 @pytest.mark.parametrize("m", [32, 512], ids=["decode", "prefill"])
@@ -318,15 +387,16 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
     line and every operation name of the benchmark's breakdown start with
     them.  Their kernels and the forward's scopes are in the lowered text by
     name (the verify body has ``cache_write`` from the layer body it shares
-    with the other two), and the compiled program leaves the K/V pools where
-    they lie."""
+    with the other two), and the compiled program leaves the K/V pools and
+    the quantized projections where they lie: the kernels read both by
+    (layer, ...), and no layer of either is copied or sliced out first."""
     import dataclasses
 
     from deepspeed_tpu.models import transformer as tfm
 
     cfg = dataclasses.replace(tfm.get_config("mistral-7b"), dtype="bfloat16",
                               param_dtype="bfloat16")
-    lowered, pool, _ = _lower_step_program(
+    lowered, pool, params = _lower_step_program(
         program, cfg, functools.partial(_sds, sharding=one_chip))
     assert pool == (32, 416, 64, KV, D)
     chunked = ["paged_attention_prefill", "mixed_gemm", "prefill_attention",
@@ -340,7 +410,9 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
     for name in inside:
         assert re.search(rf'[/"]{name}/', text), \
             f"{name} is not in the lowered program's operation names"
-    _assert_pools_stay_in_place(lowered.compile(), pool)
+    compiled = lowered.compile()
+    _assert_pools_stay_in_place(compiled, pool)
+    _assert_weights_stay_in_place(compiled, params, "mistral-7b", program)
 
 
 # olmoe-1b-7b: 64 experts of width 1024 on hidden 2048, top 8; the decode
@@ -393,8 +465,8 @@ def test_olmoe_step_programs_compile(one_chip, mosaic, program):
     """The two step programs of OLMoE-1B-7B (all 16 layers, W8A16 experts and
     all, the serving cell's sizes) compile for the described chip; the
     grouped kernel is in them by name once a projection (the layer loop holds
-    three calls, the expert codes go in whole: no slice of them), the routed
-    FFN's four scopes and the q/k norm are in the lowered operation names,
+    three calls, the expert codes go in whole, and so do the four attention
+    projections': no slice of either), the routed FFN's four scopes and the q/k norm are in the lowered operation names,
     and the K/V pools stay where they lie (at MHA widths a layer of one is
     109 MB: every pass the parent made over it cost 4.6 ms)."""
     import dataclasses
@@ -423,6 +495,7 @@ def test_olmoe_step_programs_compile(one_chip, mosaic, program):
     assert not re.search(r"s8\[64,\d+,\d+\][^\n]* dynamic-slice\(",
                          compiled_text)
     _assert_pools_stay_in_place(compiled, pool)
+    _assert_weights_stay_in_place(compiled, params, "olmoe-1b-7b", program)
 
 
 def test_mesh_follows_the_torus(topo):
