@@ -1,0 +1,3 @@
+"""``mixed_step_ms_p50`` (the same reader, see it) for a cell at saturation, whose end-to-end metric it moves is the tokens a second (serve_out_tokens_per_s) and not the time to first token."""
+
+from benchmark.layer_metrics.mixed_step_ms_p50 import read  # noqa: F401

@@ -1,0 +1,139 @@
+"""The ``serve_swa_moe`` driver rehearsed at the program's ``tiny-mellum2``
+preset (one period of it) through ``run.run_cell``: a temporary copy of the
+benchmark to which a tiny configuration, a tiny traffic mix and a cell are
+added, as ``olmoe_rehearsal.py`` does for ``serve_moe``.  Shared by
+``benchmark/tests/test_serve_swa_moe.py`` and ``tests/test_mellum2_cell.py``
+(the repository's tier-1 run collects only ``tests/``)."""
+
+import json
+import os
+import shutil
+
+from benchmark import run
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+FIXTURE = os.path.join(HERE, "fixture_v5e.xplane.pb.gz")
+CELL = "t-mellum2"
+YARN = {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+        "original_max_position_embeddings": 16, "beta_fast": 4.0,
+        "beta_slow": 1.0, "attention_factor": 1.1386}
+CONFIG = {
+    "hidden_size": 128, "intermediate_size": 512, "moe_intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
+    "num_hidden_layers": 8, "vocab_size": 256, "rms_norm_eps": 1e-6,
+    "tie_word_embeddings": False, "max_position_embeddings": 256,
+    "model_type": "mellum", "num_experts": 8, "num_experts_per_tok": 2,
+    "norm_topk_prob": True, "attention_bias": False, "sliding_window": 8,
+    "layer_types": ["sliding_attention", "sliding_attention",
+                    "sliding_attention", "full_attention"] * 2,
+    "rope_parameters": {
+        "full_attention": YARN,
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000.0}},
+    "name": "tiny-mellum2-w8", "source": "the repository's tiny-mellum2 preset",
+    "driver": "serve_swa_moe", "preset": "tiny-mellum2",
+    "reduced": ["num_hidden_layers"], "as_run": {"num_hidden_layers": 4},
+    "program": {
+        "published": {"num_experts": "num_experts",
+                      "num_experts_per_tok": "moe_top_k",
+                      "norm_topk_prob": "moe_norm_topk",
+                      "moe_intermediate_size": "intermediate_size",
+                      "head_dim": "head_dim"},
+        "implied": {"qk_norm": False, "norm": "rmsnorm"},
+        "must_be_off": ["attention_bias"], "unused": ["intermediate_size"],
+        "layer_kinds": {"sliding_attention": "sliding",
+                        "full_attention": "full"},
+        "rope": {"rope_theta": "theta", "factor": "factor",
+                 "original_max_position_embeddings":
+                     "original_max_position_embeddings",
+                 "beta_fast": "beta_fast", "beta_slow": "beta_slow",
+                 "attention_factor": "attention_factor"}},
+    "overrides": {"dtype": "bfloat16", "param_dtype": "bfloat16",
+                  "num_layers": 4},
+    "engine": {"weight_bits": 8, "weight_group": 128,
+               "v2": {"max_tokens_per_step": 32, "max_seqs": 4,
+                      "block_size": 8, "num_blocks": 96,
+                      "num_window_blocks": 29, "max_blocks_per_seq": 16,
+                      "dtype": "bfloat16", "quantize_bits": 0},
+               "serving": {"num_replicas": 1, "max_queue": 64,
+                           "drain_timeout_s": 30.0}},
+    # at toy widths (8 experts, top 2, renormalised) a router tie that flips
+    # in bf16 swaps half of a token's expert output: the bounds are loose
+    # here, the chip's are in benchmark/configs/mellum2-12b-w8.json
+    "check": {"margin": 0.5, "reference_len": 96, "window_sequences": 3,
+              "warmup_prompt": 40, "warmup_tokens": 6,
+              "logit_prompts": [40, 75, 9], "logit_tokens": 18,
+              "logit_pad": 32,
+              "logit_tol_median": 0.15, "logit_tol": 1.5,
+              "router_layer": 1, "router_tol": 1e-4},
+}
+TRAFFIC = {"loop": "closed", "clients": 6,
+           "prompt_tokens": {"median": 36, "sigma": 0.5, "min": 10, "max": 80},
+           "output_tokens": {"median": 8, "sigma": 0.5, "min": 2, "max": 16},
+           "lead_s": 1.0, "ramp_s": 1.0, "request_timeout_s": 60.0,
+           "trace_after_s": 0.5, "trace_seconds": 1.0,
+           "start_gap_s": 0.01}
+
+
+def make_copy(root: str) -> str:
+    """A checkout at ``root`` with the benchmark, the tiny configuration and
+    traffic as new files and the cell ``t-mellum2`` wherever
+    ``mellum2-code-sat`` is listed."""
+    shutil.copytree(os.path.join(ROOT, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for rel, content in (("configs/tiny-mellum2-w8.json", CONFIG),
+                         ("traffic/tiny-ctx.json", TRAFFIC)):
+        with open(os.path.join(root, "benchmark", rel), "w") as f:
+            json.dump(content, f)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({
+        "name": "tiny-mellum2-w8", "source": CONFIG["source"],
+        "reduced": ["num_hidden_layers"],
+        "file": "benchmark/configs/tiny-mellum2-w8.json", "why": "rehearsal"})
+    spec["workloads"].append({"name": CELL, "config": "tiny-mellum2-w8",
+                              "traffic": "tiny-ctx", "chips": 1,
+                              "why": "rehearsal"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "mellum2-code-sat" in m.get("workloads", ()):
+            m["workloads"].append(CELL)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return root
+
+
+def fake_device(chips):
+    """The tests' bypass of the TPU check; the command has none."""
+    return {"platform": "cpu", "kind": "cpu", "count": chips,
+            "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
+
+
+def rehearse(root: str, trace: bool = False) -> dict:
+    return run.run_cell(CELL, seed=2147480017, seconds=3.0, trace=trace,
+                        device_check=fake_device, root=root)
+
+
+def check_untraced(result: dict) -> None:
+    assert result["correct"] and result["failed"] == 0
+    assert result["attempted"] > 5
+    assert set(result["metrics"]) == {"serve_out_tokens_per_s", "itl_p90_ms",
+                                      "setup_s"}
+    assert all(m["value"] > 0 for m in result["metrics"].values())
+
+
+def check_traced(result: dict) -> None:
+    """The per-layer line of the rehearsed cell: the program-span metrics of
+    the two pools and the window are read from the engine's own step spans;
+    the device-trace ones need a TPU's trace and are left out on the CPU."""
+    m = result["metrics"]
+    assert result["correct"]
+    assert 0 < m["kv_read_vs_full_pct"]["value"] < 100
+    assert 0 < m["global_pool_used_pct"]["value"] <= 100
+    assert 0 < m["window_pool_used_pct"]["value"] <= 100
+    assert 0 < m["mixed_step_fill_pct.tps"]["value"] <= 100
+    assert m["mixed_step_ms_p50.tps"]["value"] > 0
+    assert m["serve_compiles_in_window"]["value"] == 0
+    for name in ("prefill_attn_roofline_pct", "moe_gemm_mixed_roofline_pct",
+                 "attn_busy_pct"):  # no TPU kernel in a CPU trace
+        assert name not in m

@@ -85,6 +85,14 @@ class Sizes:
     moe_layers: int = 2
     moe_requests: Tuple[Tuple[int, int], ...] = (
         (48, 16), (600, 20), (20, 24), (130, 12))
+    # -- window and global layers: one period (S S S F) at Mellum2's widths
+    # (64 experts of 2304 x 896, top 8) is 1.67 GB of int8 codes at group
+    # 128; prompts pass two windows of 1,024, tables hold 2,560 tokens
+    swa_preset: str = "mellum2-12b-a2.5b"
+    swa_layers: int = 4
+    swa_max_blocks_per_seq: int = 40
+    swa_requests: Tuple[Tuple[int, int], ...] = (
+        (2300, 12), (1100, 20), (300, 16))
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -588,6 +596,99 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     memory_line(phase, jax.local_devices()[0])
 
 
+def phase_swa_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
+                         ) -> None:
+    """A model with sliding-window AND global attention layers (one period
+    of Mellum2's pattern, int8 experts at group 128, YaRN on the global
+    layer) through ``InferenceEngineV2``: contexts past two windows, so the
+    window layers' blocks go back to their pool while the sequences run;
+    every served token within ``margin`` of the maximum of the plain
+    reference (``benchmark/reference/swa_moe_decoder.py``) over the same
+    codes; both kinds of layer on the paged kernels (the ring's
+    ``kernel/paged_attention_window`` events, none fallen back); no GEMM
+    fallen back; both pools whole after the drain."""
+    from benchmark.drivers.serve_swa_moe import published_model
+    from benchmark.reference import swa_moe_decoder as reference
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-swa-moe-int8"
+    cfg = tfm.get_config(sz.swa_preset, num_layers=sz.swa_layers,
+                         dtype="bfloat16", param_dtype="bfloat16")
+    log(phase, preset=sz.swa_preset, layers=cfg.num_layers,
+        kinds=cfg.layer_kinds, window=cfg.sliding_window,
+        experts=cfg.num_experts, params_m=round(cfg.num_params() / 1e6, 1))
+    params = jax.jit(lambda k: tfm.init_params(k, cfg))(
+        jax.random.PRNGKey(seed))
+    tracer.clear()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.num_blocks,
+        max_blocks_per_seq=sz.swa_max_blocks_per_seq, quantize_bits=8,
+        quantize_group=128))
+    del params
+    if engine.kv_win is None:
+        raise AssertionError(f"{phase}: the engine built one pool")
+    rng = np.random.default_rng([seed, 31])
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n, _ in sz.swa_requests]
+    uids = [engine.put(p, max_new_tokens=n)
+            for p, (_, n) in zip(prompts, sz.swa_requests)]
+    whole = engine.generate_all()
+    out = {u: whole[u][len(p):] for p, u in zip(prompts, uids)}
+    for uid, (_, n) in zip(uids, sz.swa_requests):
+        if len(out[uid]) != n:
+            raise AssertionError(f"{phase}: asked {n} tokens, got "
+                                 f"{len(out[uid])}")
+    pools = {"global": engine.kv, "window": engine.kv_win}
+    for name, m in pools.items():
+        m.allocator.check_consistency()
+        if m.allocator.free_blocks != m.allocator.num_blocks or m.reserved:
+            raise AssertionError(f"{phase}: the {name} pool leaked blocks")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    windows = sorted({(a["kind"], a["window"]) for name, a in events
+                      if name == "kernel/paged_attention_window"
+                      and "fallback" not in a})
+    read, full = (sum(a[k] for a in steps)
+                  for k in ("kv_blocks_read", "kv_blocks_full"))
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        pool_blocks={n: m.allocator.num_blocks for n, m in pools.items()},
+        window_blocks_freed=engine.kv_win.trimmed,
+        kv_read_vs_full_pct=round(100 * read / full, 1),
+        kernel_events=len(events), window_kernels=windows)
+    fallen = [e for e in events if "fallback" in e[1]]
+    if check_kernels and (fallen or len(windows) < 2):
+        raise AssertionError(f"{phase}: kernels fallen back: {fallen}; "
+                             f"windowed kernels traced: {windows}")
+    if engine.kv_win.trimmed < sum(
+            (n - cfg.sliding_window) // sz.block_size
+            for n, _ in sz.swa_requests if n > cfg.sliding_window):
+        raise AssertionError(f"{phase}: only {engine.kv_win.trimmed} window "
+                             f"blocks were freed behind the window")
+    model = published_model(cfg)
+    served_params = engine.params
+    del engine
+    gc.collect()
+    worst, exact, checked = 0.0, 0, 0
+    for p, u in zip(prompts, uids):
+        seq = np.zeros(-(-(len(p) + len(out[u])) // 256) * 256, np.int32)
+        seq[:len(p) + len(out[u])] = p + out[u]
+        m, rank = reference.served_margins(served_params, model,
+                                           jnp.asarray(seq), len(p))
+        m, rank = np.asarray(m)[:len(out[u])], np.asarray(rank)[:len(out[u])]
+        worst = max(worst, float(m.max()))
+        exact += int((rank == 0).sum())
+        checked += len(out[u])
+    log(phase, served_tokens=checked, reference_argmax=exact,
+        worst_margin=round(worst, 4), allowed=sz.margin)
+    if not worst <= sz.margin:
+        raise AssertionError(f"{phase}: a served token lies {worst:.3f} "
+                             f"under the reference's maximum")
+    memory_line(phase, jax.local_devices()[0])
+
+
 def check_gemm_tiles(phase: str, port: int) -> None:
     """``GET /debug/trace`` of the warmed server: every mixed GEMM the step
     programs traced left a ``kernel/mixed_gemm_tiles`` event with its tile,
@@ -749,6 +850,8 @@ def main() -> int:
         phase_server(sz, args.seed, quantize_bits=8)
         gc.collect()
         phase_moe_server(sz, args.seed)
+        gc.collect()
+        phase_swa_moe_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -153,6 +153,12 @@ class InferenceEngine:
                 "tokens over the whole sequence) — autoregressive decode "
                 "with it is incoherent; serve experts trained with top-k "
                 "routing (dataclasses.replace(cfg, moe_routing='dropless'))")
+        if len(model_config.layer_period) > 1 or model_config.rope_params:
+            raise NotImplementedError(
+                "the v1 engine serves one kind of attention layer with plain "
+                "RoPE; a model with layer_types or rope_params (window and "
+                "global layers, YaRN) is served by the v2 engine "
+                "(inference/v2), which keeps a cache for each kind")
         self.model_config = dataclasses.replace(model_config, dtype=icfg.dtype)
         # a training engine in the same process may have pinned the tp×sp
         # gather anchors — they name mesh axes this engine's mesh lacks

@@ -98,9 +98,10 @@ def _adapter_proj_delta(x, ab, slots):
 
 def ragged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
                          seq_index, position_ids, cfg: tfm.TransformerConfig,
-                         block_size: int):
+                         block_size: int, window: int = 0):
     """Correct-for-everything gather path. q: (T, H, D); caches
-    (num_blocks, bs, KV, D); returns (T, H, D)."""
+    (num_blocks, bs, KV, D); returns (T, H, D).  ``window`` (0: none): a
+    token sees the keys less than ``window`` positions behind it."""
     import math
 
     T, H, D = q.shape
@@ -124,6 +125,8 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
     key_pos = jnp.arange(S_max)[None, None, :]
     valid = key_pos <= position_ids[:, None, None]  # causal within sequence
     valid &= key_pos < context_lens[row][:, None, None]
+    if window:
+        valid &= key_pos > position_ids[:, None, None] - window
     valid &= (seq_index >= 0)[:, None, None]
     scores = jnp.where(valid, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -212,6 +215,66 @@ def hoist_quantized(layers):
     return [None if is_q(n) else n for n in nodes], layer_params
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What is static about one layer of the pattern's period: its active
+    ``window`` (0: none), the ``pool`` its K and V live in (0: ``k`` / ``v``,
+    1: ``k_win`` / ``v_win``; also the index of its block table) and its
+    RoPE."""
+    window: int
+    pool: int
+    rope: tfm.RopeParams
+
+
+def active_window(window: int, v2) -> int:
+    """The one rule: a window counts only where it is smaller than the
+    engine's longest context.  At or past it no query ever looks back that
+    far, and the layer is served as full attention was, program for
+    program."""
+    return window if 0 < window < v2.max_blocks_per_seq * v2.block_size else 0
+
+
+def layer_plan(model_cfg: tfm.TransformerConfig, v2) -> tuple:
+    """One ``LayerKind`` a layer of ``model_cfg.layer_period``.  Layers
+    without an active window share pool 0 with every layer when the model
+    has one kind only; with both kinds the windowed layers get pool 1."""
+    windows = [active_window(model_cfg.window_of(kind), v2)
+               for kind in model_cfg.layer_period]
+    two = len(set(windows)) > 1
+    return tuple(LayerKind(w, int(two and w > 0), model_cfg.rope_of(kind))
+                 for w, kind in zip(windows, model_cfg.layer_period))
+
+
+def pool_layers(model_cfg: tfm.TransformerConfig, v2) -> tuple:
+    """How many layers each pool of ``layer_plan`` holds: ``(L,)`` for a
+    model with one kind of layer, ``(global, windowed)`` with two."""
+    plan = layer_plan(model_cfg, v2)
+    periods = model_cfg.num_layers // len(plan)
+    return tuple(periods * sum(k.pool == p for k in plan)
+                 for p in range(1 + max(k.pool for k in plan)))
+
+
+def tables_of(block_tables) -> tuple:
+    """A step program's ``block_tables`` argument as one table a pool: the
+    array of a model with one pool, or the pair (global, windowed)."""
+    return block_tables if isinstance(block_tables, tuple) else (block_tables,)
+
+
+def pools_of(caches) -> list:
+    """``caches`` as one (K, V) pair a pool."""
+    return [(caches["k"], caches["v"])] + (
+        [(caches["k_win"], caches["v_win"])] if "k_win" in caches else [])
+
+
+def write_blocks(caches, block_tables, rows, positions, ok, block_size: int):
+    """Where each of a step's rows writes its K and V, one array of block
+    ids a pool: its table's entry for the row's position, or the pool's
+    scratch block (its last) where ``ok`` is false."""
+    return tuple(
+        jnp.where(ok, table[rows, positions // block_size], k.shape[1] - 1)
+        for table, (k, _) in zip(tables_of(block_tables), pools_of(caches)))
+
+
 def serving_layers(params, caches, x, positions, write_at, attend,
                    model_cfg: tfm.TransformerConfig, v2, adapters=None,
                    slots=None, valid=None):
@@ -220,31 +283,35 @@ def serving_layers(params, caches, x, positions, write_at, attend,
 
     ``x (..., H)`` are the embedded rows and ``positions`` (``x.shape[:-1]``)
     their places in their sequences; ``write_at = (blk_ids, offsets)`` is
-    where each row's K and V go in its layer of the pools, which the caller
+    where each row's K and V go in its layer of the pools (``blk_ids``: one
+    array a pool, as ``write_blocks`` makes them), which the caller
     has already pointed at the scratch block (the pool's last) for every row
-    that must not write; ``attend(q, k_pool, v_pool, layer) -> o`` is the
-    caller's paged attention over the pools with the step's rows written
-    (``q`` and ``o`` are ``(..., heads, head_dim)``); ``adapters`` is the
+    that must not write; ``attend(q, k_pool, v_pool, layer, kind) -> o`` is
+    the caller's paged attention over the layer's pool with the step's rows
+    written (``q`` and ``o`` are ``(..., heads, head_dim)``; ``layer`` counts
+    within the pool and ``kind`` is the layer's ``LayerKind``: which table,
+    which window); ``adapters`` is the
     per-slot LoRA stack with ``slots``, the slot each row reads (shaped as
     ``_adapter_proj_delta`` takes it), or None; ``valid`` marks the rows an
     MoE model's stats count.
 
-    → (hidden state after the final norm, ``{"k", "v"}`` pools, an MoE
-    model's per-layer stats ``(L, 2)`` or None)."""
+    → (hidden state after the final norm, the pools as ``caches`` names
+    them, an MoE model's per-layer stats ``(L, 2)`` or None)."""
     blk_ids, offsets = write_at
     rows = x.shape[:-1]
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
-    cos_full, sin_full = (None, None)
+    plan = layer_plan(model_cfg, v2)
+    ropes = {}
     if model_cfg.position == "rope":
         max_len = v2.max_blocks_per_seq * v2.block_size
-        cos_full, sin_full = tfm.rope_table(max_len, model_cfg.rot_dim,
-                                            model_cfg.rope_theta)
+        for kind in plan:  # one table a distinct RoPE, made outside the scan
+            if kind.rope not in ropes:
+                ropes[kind.rope] = tfm.rope_table_of(
+                    max_len, model_cfg.rot_dim, kind.rope)
     # the quantized codes and scales stay whole, out of what the scan slices
     layers, layer_params = hoist_quantized(params["layers"])
 
-    def layer_body(carry, inp):
-        x, k_cache, v_cache = carry
-        sliced, layer, ad = inp
+    def one_layer(x, pools, sliced, layer, ad, kind, pool_layer):
         lp = layer_params(sliced, layer)
 
         def proj(h, w_key, b_key):
@@ -262,21 +329,26 @@ def serving_layers(params, caches, x, positions, write_at, attend,
                         ).reshape(rows + (nkv, hd))
         v = v.reshape(rows + (nkv, hd))
         if model_cfg.position == "rope":
+            cos_full, sin_full = ropes[kind.rope]
             q = tfm.rope_at(q, cos_full, sin_full, positions)
             k = tfm.rope_at(k, cos_full, sin_full, positions)
+        k_cache, v_cache = pools[kind.pool]
         with jax.named_scope("cache_write"):
-            k_cache = k_cache.at[layer, blk_ids, offsets].set(
+            k_cache = k_cache.at[pool_layer, blk_ids[kind.pool], offsets].set(
                 k.astype(k_cache.dtype))
-            v_cache = v_cache.at[layer, blk_ids, offsets].set(
+            v_cache = v_cache.at[pool_layer, blk_ids[kind.pool], offsets].set(
                 v.astype(v_cache.dtype))
-        o_flat = attend(q, k_cache, v_cache, layer).reshape(rows + (nh * hd,))
+        o_flat = attend(q, k_cache, v_cache, pool_layer, kind
+                        ).reshape(rows + (nh * hd,))
         attn_out = proj(o_flat, "wo", "bo")
         m_src = x if model_cfg.parallel_residual else x + attn_out
         m_in = tfm._norm(m_src, lp["ln2"], model_cfg.norm, model_cfg.norm_eps)
         mlp_out, moe_stats = _ffn(m_in, lp, model_cfg, valid)
         x = (x + attn_out + mlp_out) if model_cfg.parallel_residual \
             else (m_src + mlp_out)
-        return (x, k_cache, v_cache), moe_stats
+        pools = [(k_cache, v_cache) if p == kind.pool else pool
+                 for p, pool in enumerate(pools)]
+        return x, pools, moe_stats
 
     # The scan steps over (the layer's parameters, its index, its adapter
     # factors or {}).  The quantized projections are NOT there: a kernel
@@ -290,12 +362,56 @@ def serving_layers(params, caches, x, positions, write_at, attend,
     # scan as ``xs`` is sliced a layer at a time, re-stacked into ``ys`` and
     # copied: six passes over 1.7 GB a step at the serving cells' sizes).
     num_layers = jax.tree.leaves(layers)[0].shape[0]
-    (x, new_k, new_v), moe_stats = jax.lax.scan(
-        layer_body, (x, caches["k"], caches["v"]),
-        (layers, jnp.arange(num_layers, dtype=jnp.int32),
-         {} if adapters is None else adapters))
+    adapters = {} if adapters is None else adapters
+    if len(plan) == 1:
+        def layer_body(carry, inp):
+            x, pools = carry
+            sliced, layer, ad = inp
+            x, pools, moe_stats = one_layer(x, pools, sliced, layer, ad,
+                                            plan[0], layer)
+            return (x, pools), moe_stats
+
+        xs = (layers, jnp.arange(num_layers, dtype=jnp.int32), adapters)
+    else:
+        # Kinds differ: the scan steps over PERIODS of the pattern, a period's
+        # layers unrolled inside with their kinds static, so each picks its
+        # RoPE table, its pool, its block table and its window without a
+        # ``cond``.  A layer's index in its pool counts the pool's layers of
+        # the periods before and of this one before it.
+        p = len(plan)
+        in_pool = [sum(k.pool == kind.pool for k in plan) for kind in plan]
+        before = [sum(k.pool == kind.pool for k in plan[:i])
+                  for i, kind in enumerate(plan)]
+
+        def by_period(tree):
+            return jax.tree.map(
+                lambda a: a.reshape((-1, p) + a.shape[1:]), tree)
+
+        def layer_body(carry, inp):
+            x, pools = carry
+            sliced, period, ad = inp
+            stats = []
+            for i, kind in enumerate(plan):
+                x, pools, moe_stats = one_layer(
+                    x, pools, jax.tree.map(lambda a: a[i], sliced),
+                    period * p + i, jax.tree.map(lambda a: a[i], ad), kind,
+                    period * in_pool[i] + before[i])
+                stats.append(moe_stats)
+            return (x, pools), (None if stats[0] is None
+                                else jnp.stack(stats))
+
+        xs = (by_period(layers),
+              jnp.arange(num_layers // p, dtype=jnp.int32),
+              by_period(adapters))
+    (x, pools), moe_stats = jax.lax.scan(layer_body, (x, pools_of(caches)),
+                                         xs)
+    if moe_stats is not None and len(plan) > 1:
+        moe_stats = moe_stats.reshape((num_layers,) + moe_stats.shape[2:])
     x = tfm._norm(x, params["final_norm"], model_cfg.norm, model_cfg.norm_eps)
-    return x, {"k": new_k, "v": new_v}, moe_stats
+    new = {"k": pools[0][0], "v": pools[0][1]}
+    if len(pools) > 1:
+        new["k_win"], new["v_win"] = pools[1]
+    return x, new, moe_stats
 
 
 def _decode_body(params, caches, token_ids, position_ids, block_tables,
@@ -311,15 +427,16 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
     x = tfm.embed_tokens(params, token_ids, model_cfg,
                          position_ids=position_ids)
     active = context_lens > 0
-    blk_ids = jnp.where(
-        active,
-        block_tables[jnp.arange(token_ids.shape[0]), position_ids // bs],
-        caches["k"].shape[1] - 1)
+    blk_ids = write_blocks(caches, block_tables,
+                           jnp.arange(token_ids.shape[0]), position_ids,
+                           active, bs)
+    tables = tables_of(block_tables)
 
-    def attend(q, k_cache, v_cache, layer):
+    def attend(q, k_cache, v_cache, layer, kind):
         with jax.named_scope("decode_attention"):
             return paged_decode_attention(q, k_cache, v_cache, layer,
-                                          block_tables, context_lens)
+                                          tables[kind.pool], context_lens,
+                                          window=kind.window)
 
     x, caches, moe_stats = serving_layers(
         params, caches, x, position_ids, (blk_ids, position_ids % bs), attend,
@@ -345,15 +462,17 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
         # invalid tokens' writes park in a scratch block (last block id is
         # reserved by the engine for this)
         valid = seq_index >= 0
-        row = jnp.clip(seq_index, 0, block_tables.shape[0] - 1)
-        blk_ids = jnp.where(valid, block_tables[row, position_ids // bs],
-                            caches["k"].shape[1] - 1)
+        tables = tables_of(block_tables)
+        max_seqs = tables[0].shape[0]
+        row = jnp.clip(seq_index, 0, max_seqs - 1)
+        blk_ids = write_blocks(caches, block_tables, row, position_ids, valid,
+                               bs)
         # per-token scatter coordinates into the per-sequence chunk layout
         # (max_seqs, Qp): row = sequence, col = offset within this step's
         # chunk (padding handled by positive OOB sentinels — see helper)
         Qp = v2.max_tokens_per_step
         scat_row, scat_col, gath_row, gath_col = prefill_scatter_coords(
-            seq_index, position_ids, chunk_start, block_tables.shape[0], Qp)
+            seq_index, position_ids, chunk_start, max_seqs, Qp)
 
         # per-token adapter slot: each ragged token reads its row's slot
         # (padding tokens pin to the null slot — their outputs are dropped
@@ -362,18 +481,18 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
         if adapters is not None:
             tok_slot = jnp.where(valid, row_adapter[row], 0)
 
-        def attend(q, k_cache, v_cache, layer):
+        def attend(q, k_cache, v_cache, layer, kind):
             # chunked-prefill attention over paged KV: reorganize the ragged
             # (T, H, D) q into per-sequence chunks and run the paged Pallas
             # prefill kernel — never materializes the old (T, S_max, KV, D)
             # per-token gather
             with jax.named_scope("prefill_attention"):
-                q_seq = jnp.zeros((block_tables.shape[0], Qp) + q.shape[1:],
-                                  q.dtype)
+                q_seq = jnp.zeros((max_seqs, Qp) + q.shape[1:], q.dtype)
                 q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
                 o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
-                                                layer, block_tables,
-                                                chunk_start, chunk_len)
+                                                layer, tables[kind.pool],
+                                                chunk_start, chunk_len,
+                                                window=kind.window)
                 # padding rows read in-range garbage (clamped col), dropped
                 # later
                 return o_seq[gath_row, gath_col]  # (T, H, D)

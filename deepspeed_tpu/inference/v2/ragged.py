@@ -138,36 +138,94 @@ class SequenceDescriptor:
     #: null slot whose factors are all-zero, so base-only requests add an
     #: exact-zero delta and stay bit-identical to an adapterless engine)
     adapter_slot: int = 0
+    #: ``blocks`` is indexed by logical block: ``blocks[j]`` holds positions
+    #: ``[j * block_size, (j + 1) * block_size)``.  A windowed pool frees
+    #: from the front: entries before ``first_block`` are stale ids that
+    #: nothing reads, and ``reserved_blocks`` is what admission set aside
+    #: for the chain (a windowed pool allocates as the sequence advances).
+    first_block: int = 0
+    reserved_blocks: int = 0
+    #: the same three for the window layers' pool of a model with two kinds
+    #: of attention layer (its global layers' chain is ``blocks``)
+    win_blocks: List[int] = dataclasses.field(default_factory=list)
+    win_first_block: int = 0
+    win_reserved_blocks: int = 0
 
     @property
     def cur_len(self) -> int:
         return len(self.tokens)
 
 
+def window_bound(window: int, max_chunk: int, block_size: int,
+                 max_blocks_per_seq: int) -> int:
+    """The most blocks of a windowed pool one sequence holds at a time: a
+    step of ``max_chunk`` tokens whose oldest query sits at ``s`` touches
+    positions ``s - window + 1 .. s + max_chunk - 1``, which lie in at most
+    that many blocks wherever ``s`` falls in its block."""
+    return min(max_blocks_per_seq,
+               (window + max(max_chunk, 1) - 2) // block_size + 2)
+
+
 class KVCacheManager:
-    """Paged KV cache bookkeeping (host side).
+    """Paged KV cache bookkeeping (host side) for ONE pool.
 
     The device-side cache is a (layers, num_blocks, block_size, kv_heads,
     head_dim) array; this manager owns the allocator and per-sequence block
-    tables (reference ``BlockedKVCache``)."""
+    tables (reference ``BlockedKVCache``).
 
-    def __init__(self, num_blocks: int, block_size: int, max_blocks_per_seq: int):
+    ``window`` > 0 makes it the pool of sliding-window layers: a query at
+    position ``p`` reads keys ``p - window < j <= p`` only, so a sequence
+    holds at most ``bound`` blocks however long it grows (the window, one
+    step's chunk of up to ``max_chunk`` tokens, and the partial blocks at
+    both ends).  Admission then *reserves* that many (``reserve``), a step
+    allocates what its chunk writes (``ensure_capacity``) and ``trim`` frees
+    what fell behind the window; the table stays indexed by logical block,
+    its freed entries stale and never read.  ``chain`` names the
+    ``SequenceDescriptor`` fields the pool's chain lives in (``"win_"`` for
+    the second pool of a model with both kinds of layer)."""
+
+    def __init__(self, num_blocks: int, block_size: int,
+                 max_blocks_per_seq: int, window: int = 0, max_chunk: int = 0,
+                 chain: str = ""):
         self.allocator = BlockedAllocator(num_blocks)
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
+        self.window = window
+        self.bound = max_blocks_per_seq if not window else window_bound(
+            window, max_chunk, block_size, max_blocks_per_seq)
+        self.reserved = 0  # windowed: blocks set aside for running sequences
+        self.trimmed = 0  # windowed: blocks freed behind the window so far
+        self._blocks, self._first, self._reserved = (
+            chain + "blocks", chain + "first_block", chain + "reserved_blocks")
         # attached by the engine when the prefix cache is enabled; lets
         # capacity checks reclaim unreferenced cached blocks under pressure
         self.prefix_cache = None
 
+    def chain(self, seq: SequenceDescriptor) -> List[int]:
+        return getattr(seq, self._blocks)
+
+    def reservation(self, total_tokens: int) -> int:
+        """Blocks a sequence of ``total_tokens`` is admitted against."""
+        return min(-(-total_tokens // self.block_size), self.bound)
+
+    @property
+    def unreserved_blocks(self) -> int:
+        """What admission may still promise: free blocks, less (windowed)
+        what running sequences were promised and have not yet taken."""
+        if not self.window:
+            return self.allocator.free_blocks
+        return self.allocator.num_blocks - self.reserved
+
     def blocks_needed(self, seq: SequenceDescriptor, new_tokens: int) -> int:
         total = seq.seen_tokens + new_tokens
-        have = len(seq.blocks)
+        have = len(self.chain(seq))
         need = -(-total // self.block_size)  # ceil
         return max(0, need - have)
 
     def ensure_capacity(self, seq: SequenceDescriptor, new_tokens: int) -> bool:
         need = self.blocks_needed(seq, new_tokens)
-        if len(seq.blocks) + need > self.max_blocks_per_seq:
+        chain = self.chain(seq)
+        if len(chain) + need > self.max_blocks_per_seq:
             return False
         short = need - self.allocator.free_blocks
         if short > 0 and self.prefix_cache is not None:
@@ -175,12 +233,48 @@ class KVCacheManager:
         if need > self.allocator.free_blocks:
             return False
         if need:
-            seq.blocks.extend(self.allocator.allocate(need))
+            chain.extend(self.allocator.allocate(need))
         return True
 
+    def reserve(self, seq: SequenceDescriptor, total_tokens: int,
+                chunk: int) -> bool:
+        """Admission: set the sequence's whole budget aside.  A full pool
+        allocates it now; a windowed one counts ``reservation`` blocks and
+        allocates this step's ``chunk``."""
+        if not self.window:
+            return self.ensure_capacity(seq, total_tokens)
+        total = seq.seen_tokens + total_tokens
+        need = self.reservation(total)
+        if (need > self.unreserved_blocks
+                or -(-total // self.block_size) > self.max_blocks_per_seq):
+            return False
+        if not self.ensure_capacity(seq, chunk):
+            return False
+        setattr(seq, self._reserved, need)
+        self.reserved += need
+        return True
+
+    def trim(self, seq: SequenceDescriptor, next_pos: int) -> int:
+        """Free the blocks no query at ``next_pos`` or later can see (the
+        oldest key it reads is ``next_pos - window + 1``) → how many."""
+        if not self.window:
+            return 0
+        first = getattr(seq, self._first)
+        live = min(max(0, (next_pos - self.window + 1) // self.block_size),
+                   len(self.chain(seq)))
+        if live <= first:
+            return 0
+        self.allocator.free(self.chain(seq)[first:live])
+        setattr(seq, self._first, live)
+        self.trimmed += live - first
+        return live - first
+
     def release(self, seq: SequenceDescriptor) -> None:
-        self.allocator.free(seq.blocks)
-        seq.blocks = []
+        self.allocator.free(self.chain(seq)[getattr(seq, self._first):])
+        setattr(seq, self._blocks, [])
+        setattr(seq, self._first, 0)
+        self.reserved -= getattr(seq, self._reserved)
+        setattr(seq, self._reserved, 0)
 
 
 @dataclasses.dataclass
@@ -200,6 +294,8 @@ class RaggedBatch:
     num_tokens: int
     num_seqs: int
     uids: List[int]
+    #: the window layers' tables of a model with both kinds of layer
+    win_tables: Optional[np.ndarray] = None
 
 
 class DecodeStateTable:
@@ -213,9 +309,16 @@ class DecodeStateTable:
     preallocated array and flushes into ``seq.tokens`` at retire."""
 
     def __init__(self, max_seqs: int, max_blocks_per_seq: int,
-                 max_ctx: int):
+                 max_ctx: int, two_pools: bool = False,
+                 main_grows: bool = False):
         self.max_seqs = max_seqs
+        # whether the main pool is windowed: its chains then grow while the
+        # sequence runs, and ``sync`` copies them again
+        self.main_grows = main_grows
         self.block_tables = np.zeros((max_seqs, max_blocks_per_seq), np.int32)
+        # the window layers' tables of a model with both kinds of layer
+        self.win_tables = np.zeros_like(self.block_tables) \
+            if two_pools else None
         self.ctx = np.zeros(max_seqs, np.int32)  # tokens already in cache
         self.next_tok = np.zeros(max_seqs, np.int32)  # next input token
         self.gen = np.zeros(max_seqs, np.int32)
@@ -245,6 +348,8 @@ class DecodeStateTable:
         bt = self.block_tables[row]
         bt[:] = 0
         bt[:len(seq.blocks)] = seq.blocks
+        if self.win_tables is not None:
+            self.win_tables[row] = 0
         self.budget[row] = seq.max_new_tokens
         self.limit[row] = seq.cur_len + seq.max_new_tokens
         self.temp[row] = -1.0 if seq.temperature is None else seq.temperature
@@ -262,6 +367,11 @@ class DecodeStateTable:
         if seq.seen_tokens < seq.cur_len:
             self.next_tok[row] = seq.tokens[seq.seen_tokens]
         self.gen[row] = seq.generated
+        # chains that grow while the sequence runs (a windowed pool's)
+        if self.main_grows:
+            self.block_tables[row, :len(seq.blocks)] = seq.blocks
+        if self.win_tables is not None:
+            self.win_tables[row, :len(seq.win_blocks)] = seq.win_blocks
 
     def flush_tokens(self, seq: SequenceDescriptor) -> None:
         """Append the row's accumulated decode history to ``seq.tokens``."""
@@ -290,10 +400,12 @@ class DecodeStateTable:
 
 
 class RaggedBatchBuilder:
-    def __init__(self, max_tokens: int, max_seqs: int, max_blocks_per_seq: int):
+    def __init__(self, max_tokens: int, max_seqs: int, max_blocks_per_seq: int,
+                 two_pools: bool = False):
         self.max_tokens = max_tokens
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
+        self.two_pools = two_pools
 
     def build(self, seqs: List[Tuple[SequenceDescriptor, int]]) -> RaggedBatch:
         """seqs: (descriptor, n_new_tokens) pairs already capacity-checked."""
@@ -303,6 +415,7 @@ class RaggedBatchBuilder:
         position_ids = np.zeros(self.max_tokens, np.int32)
         seq_index = np.full(self.max_tokens, -1, np.int32)
         block_tables = np.zeros((self.max_seqs, self.max_blocks_per_seq), np.int32)
+        win_tables = np.zeros_like(block_tables) if self.two_pools else None
         context_lens = np.zeros(self.max_seqs, np.int32)
         logits_rows = np.zeros(self.max_seqs, np.int32)
         chunk_start = np.zeros(self.max_seqs, np.int32)
@@ -319,6 +432,8 @@ class RaggedBatchBuilder:
             position_ids[sl] = np.arange(start, start + len(new_tokens))
             seq_index[sl] = row
             block_tables[row, :len(seq.blocks)] = seq.blocks
+            if win_tables is not None:
+                win_tables[row, :len(seq.win_blocks)] = seq.win_blocks
             context_lens[row] = start + len(new_tokens)
             logits_rows[row] = cursor + len(new_tokens) - 1
             chunk_start[row] = start
@@ -327,4 +442,4 @@ class RaggedBatchBuilder:
             uids.append(seq.uid)
         return RaggedBatch(token_ids, position_ids, seq_index, block_tables,
                            context_lens, logits_rows, chunk_start, chunk_len,
-                           cursor, len(seqs), uids)
+                           cursor, len(seqs), uids, win_tables)

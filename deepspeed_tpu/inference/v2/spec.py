@@ -81,20 +81,22 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
     pos = ctx[:, None] + jnp.arange(Q)[None, :]  # (S, Q)
     active = ctx > 0
     write_ok = active[:, None] & (pos < pos_limit[:, None])
+    # one pool: the engine refuses speculation for a model whose layers
+    # keep two (a window layer's table has no room for k tokens ahead)
     blk_col = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
-    blk_ids = jnp.where(write_ok,
-                        jnp.take_along_axis(block_tables, blk_col, axis=1),
-                        caches["k"].shape[1] - 1)
+    blk_ids = (jnp.where(write_ok,
+                         jnp.take_along_axis(block_tables, blk_col, axis=1),
+                         caches["k"].shape[1] - 1),)
     # attention window per row: chunk [ctx, ctx+chunk_len) — clipped at the
     # reservation so parked (unwritten) key slots are never read
     chunk_len = jnp.where(active,
                           jnp.clip(pos_limit - ctx, 0, Q), 0).astype(jnp.int32)
 
-    def attend(q, k_cache, v_cache, layer):
+    def attend(q, k_cache, v_cache, layer, kind):
         with jax.named_scope("prefill_attention"):
             return paged_prefill_attention(q, k_cache, v_cache, layer,
                                            block_tables, ctx * active,
-                                           chunk_len)
+                                           chunk_len, window=kind.window)
 
     x = tfm.embed_tokens(params, tokens, model_cfg, position_ids=pos)  # (S,Q,H)
     x, caches, _ = serving_layers(

@@ -35,6 +35,23 @@ from ..ops.pallas.mixed_gemm import (LayerOf, QuantizedWeight,
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeParams:
+    """Rotary embedding of one kind of layer: plain RoPE at ``theta``, or with
+    ``factor`` > 0 static YaRN (Peng et al. 2023, as Hugging Face's
+    ``rope_type: "yarn"`` computes it): the frequencies a context of
+    ``original_max_position_embeddings`` turns fewer than ``beta_slow`` times
+    are divided by ``factor``, those it turns more than ``beta_fast`` times
+    stay, a linear ramp between; cos and sin are scaled by
+    ``attention_factor``."""
+    theta: float = 10000.0
+    factor: float = 0.0  # 0 => plain RoPE, the fields below unused
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -69,6 +86,14 @@ class TransformerConfig:
     partial_rotary_factor: float = 1.0
     # sliding-window attention (0 == full); Mistral-style band
     sliding_window: int = 0
+    # the attention kind of each layer, "sliding" | "full": the whole list or
+    # one period of it (tiled over num_layers, cut where they end).  Empty: every layer alike,
+    # "sliding" when sliding_window > 0.  The window applies to "sliding"
+    # layers only (Mellum2: sliding, sliding, sliding, full)
+    layer_types: Tuple[str, ...] = ()
+    # RoPE by layer kind, (kind, RopeParams) pairs; a kind not listed rotates
+    # plainly at rope_theta (Mellum2: YaRN on the "full" layers only)
+    rope_params: Tuple[Tuple[str, RopeParams], ...] = ()
     # MoE (0 == dense); see deepspeed_tpu/moe for the layer implementation
     num_experts: int = 0
     moe_top_k: int = 2
@@ -117,6 +142,43 @@ class TransformerConfig:
             raise ValueError(
                 "gated_mlp with a non-silu activation is not wired for MoE "
                 "expert blocks (they hardcode silu gating)")
+        # hashable whatever the caller handed in (a JSON list): the config
+        # is a jit memo key
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_params",
+                           tuple((k, r) for k, r in self.rope_params))
+        if self.layer_types:
+            if set(self.layer_types) - {"sliding", "full"}:
+                raise ValueError(f"layer_types holds kinds other than "
+                                 f"'sliding' and 'full': {self.layer_types}")
+            if "sliding" in self.layer_types and self.sliding_window <= 0:
+                raise ValueError("'sliding' layers need sliding_window > 0")
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The attention kind of every layer, ``num_layers`` long."""
+        if not self.layer_types:
+            return (("sliding" if self.sliding_window > 0 else "full"),
+                    ) * self.num_layers
+        reps = -(-self.num_layers // len(self.layer_types))
+        return (self.layer_types * reps)[:self.num_layers]
+
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The shortest run of kinds that, repeated, gives ``layer_kinds``:
+        what a layer scan steps over (one kind: a period of one layer)."""
+        kinds = self.layer_kinds
+        for p in range(1, len(kinds) + 1):
+            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
+                return kinds[:p]
+        return kinds
+
+    def window_of(self, kind: str) -> int:
+        return self.sliding_window if kind == "sliding" else 0
+
+    def rope_of(self, kind: str) -> RopeParams:
+        return dict(self.rope_params).get(kind) or \
+            RopeParams(theta=self.rope_theta)
 
     @property
     def rot_dim(self) -> int:
@@ -176,6 +238,22 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                         rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
                         num_experts=64, moe_top_k=8, moe_norm_topk=False,
                         moe_routing="dropless", qk_norm=True, attn_impl="flash"),
+    # JetBrains/Mellum2-12B-A2.5B-Instruct as published: 12.15 B, about 2.5 B
+    # active; three window layers (1024) to one global layer, seven times;
+    # YaRN (8192 x 16) on the global layers only; intermediate_size is the
+    # width of ONE expert (the published 7168 is a dense width no layer has)
+    "mellum2-12b-a2.5b": dict(
+        vocab_size=98304, hidden_size=2304, intermediate_size=896,
+        num_layers=28, num_heads=32, num_kv_heads=4, head_dim_override=128,
+        max_seq_len=131072, rope_theta=500000.0, norm_eps=1e-6,
+        tie_embeddings=False, sliding_window=1024,
+        layer_types=("sliding", "sliding", "sliding", "full"),
+        rope_params=(("full", RopeParams(
+            theta=500000.0, factor=16.0,
+            original_max_position_embeddings=8192, beta_fast=32.0,
+            beta_slow=1.0, attention_factor=1.2772588722239782)),),
+        num_experts=64, moe_top_k=8, moe_norm_topk=True,
+        moe_routing="dropless", attn_impl="flash"),
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
     "tiny-moe": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -186,6 +264,18 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                        tie_embeddings=False, num_experts=8, moe_top_k=2,
                        moe_norm_topk=False, moe_routing="dropless",
                        qk_norm=True),
+    # Mellum2's block at toy widths: two periods of S S S F, a window of 8,
+    # YaRN whose ramp lies inside 64 positions, renormalised top-2 gates
+    "tiny-mellum2": dict(
+        vocab_size=256, hidden_size=128, intermediate_size=128, num_layers=8,
+        num_heads=4, num_kv_heads=2, max_seq_len=256, rope_theta=10000.0,
+        norm_eps=1e-6, tie_embeddings=False, sliding_window=8,
+        layer_types=("sliding", "sliding", "sliding", "full"),
+        rope_params=(("full", RopeParams(
+            theta=10000.0, factor=4.0, original_max_position_embeddings=16,
+            beta_fast=4.0, beta_slow=1.0, attention_factor=1.1386)),),
+        num_experts=8, moe_top_k=2, moe_norm_topk=True,
+        moe_routing="dropless", attn_impl="flash"),
     "tiny-prmoe": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
                        num_layers=2, num_heads=4, max_seq_len=128,
                        num_experts=4, moe_top_k=2, moe_use_residual=True),
@@ -384,6 +474,40 @@ def rope_table(seq_len: int, head_dim: int, theta: float) -> Tuple[jax.Array, ja
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)  # (seq, head_dim/2)
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_inv_freq(head_dim: int, rope: RopeParams) -> jax.Array:
+    """Static YaRN's ``head_dim / 2`` inverse frequencies: frequency ``j``
+    keeps ``theta ** (-2j / head_dim)`` below ``low`` (it turns more than
+    ``beta_fast`` times in the original context), is divided by ``factor``
+    above ``high`` (fewer than ``beta_slow`` turns), and is blended linearly
+    between."""
+    def turns_at(n):  # the dimension that turns n times in the old context
+        return head_dim * math.log(
+            rope.original_max_position_embeddings / (2 * math.pi * n)
+        ) / (2 * math.log(rope.theta))
+
+    low = max(math.floor(turns_at(rope.beta_fast)), 0)
+    high = min(math.ceil(turns_at(rope.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # as the published code: no division by zero
+    j = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    extra = rope.theta ** (-2.0 * j / head_dim)
+    keep = 1.0 - jnp.clip((j - low) / (high - low), 0.0, 1.0)
+    return keep * extra + (1.0 - keep) * extra / rope.factor
+
+
+def rope_table_of(seq_len: int, head_dim: int, rope: RopeParams
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``rope_table`` for one kind of layer: plain RoPE exactly as
+    ``rope_table`` makes it, or YaRN's frequencies with cos and sin scaled by
+    its ``attention_factor``."""
+    if not rope.factor:
+        return rope_table(seq_len, head_dim, rope.theta)
+    t = jnp.arange(seq_len, dtype=jnp.float32)
+    freqs = jnp.outer(t, yarn_inv_freq(head_dim, rope))
+    return (jnp.cos(freqs) * rope.attention_factor,
+            jnp.sin(freqs) * rope.attention_factor)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -664,24 +788,30 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         # flash/ring kernels take no bias operand (mirror of the
         # sliding_window constraint below)
         raise ValueError("position='alibi' requires attn_impl='xla'")
-    if attn_fn is None:
-        attn_fn = resolve_attention(cfg.attn_impl)
-        if cfg.sliding_window > 0:
-            if cfg.attn_impl != "flash":
-                raise ValueError(
-                    "sliding_window requires attn_impl='flash'")
-            attn_fn = partial(attn_fn, window=cfg.sliding_window)
+    # one (attention function, RoPE table) a kind of layer in the period
+    period = cfg.layer_period
     B, S = tokens.shape
+    attn_fns, ropes = [], []
+    for kind in period:
+        fn = attn_fn
+        if fn is None:
+            fn = resolve_attention(cfg.attn_impl)
+            if cfg.window_of(kind) > 0:
+                if cfg.attn_impl != "flash":
+                    raise ValueError(
+                        "sliding_window requires attn_impl='flash'")
+                fn = partial(fn, window=cfg.window_of(kind))
+        attn_fns.append(fn)
+        ropes.append(rope_table_of(S, cfg.rot_dim, cfg.rope_of(kind))
+                     if cfg.position == "rope" else (None, None))
 
     with jax.named_scope("embed"):
         x = embed_tokens(params, tokens, cfg)
-    cos, sin = (None, None)
-    if cfg.position == "rope":
-        cos, sin = rope_table(S, cfg.rot_dim, cfg.rope_theta)
 
     from jax.ad_checkpoint import checkpoint_name
 
-    def layer_body(carry, layer_params):
+    def layer_body(carry, layer_params, kind=0):
+        attn_fn, (cos, sin) = attn_fns[kind], ropes[kind]
         # ZeRO-Infinity param streaming: when the engine enabled offload_param,
         # this layer's slice rides host→device DMA here (and the remat'd
         # backward re-streams it); otherwise identity.
@@ -717,10 +847,24 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     policy = _remat_policy(cfg.remat_policy)
     body = layer_body
     if policy is not None:
-        body = jax.checkpoint(layer_body, policy=policy, prevent_cse=False)
+        body = jax.checkpoint(layer_body, policy=policy, prevent_cse=False,
+                              static_argnums=(2,) if len(period) > 1 else ())
 
     with jax.named_scope("layers"):
-        x, _ = lax.scan(body, x, params["layers"])
+        if len(period) == 1:
+            x, _ = lax.scan(body, x, params["layers"])
+        else:
+            # kinds differ: the scan steps over periods of the pattern, the
+            # layers of one period unrolled inside with their kinds static
+            def period_body(carry, period_params):
+                for i in range(len(period)):
+                    carry, _ = body(
+                        carry, jax.tree.map(lambda a: a[i], period_params), i)
+                return carry, None
+
+            x, _ = lax.scan(period_body, x, jax.tree.map(
+                lambda a: a.reshape((-1, len(period)) + a.shape[1:]),
+                params["layers"]))
 
     return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
 

@@ -113,7 +113,16 @@ def quantize_gemm_weight(w: jax.Array, bits: int = 8,
     fp_quantizer) — scales map each group's absmax to the fp6 max (28)."""
     assert bits in (8, 6, 4), bits
     *lead, K, N = w.shape
-    if K % group != 0:  # shrink the group to a divisor (odd K still works)
+    if K % group != 0:
+        if K > group and K % 128 == 0:
+            # a width the kernels tile (896 = 7 x 128) under a group that
+            # does not divide it: shrinking the group silently (to 224) would
+            # send every GEMM over this width to the XLA fallback
+            raise ValueError(
+                f"quantize group {group} does not divide the width K = {K} "
+                f"of a {tuple(w.shape)} weight; use a group that does "
+                f"(128 divides every lane-aligned width)")
+        # shrink the group to a divisor (odd K still works)
         group = aligned_divisor(K, group, 1) or K
     wf = w.astype(jnp.float32).reshape(*lead, K // group, group, N)
     if bits == 6:

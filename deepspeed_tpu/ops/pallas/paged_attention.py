@@ -14,6 +14,13 @@ Both kernels take the K/V pools whole, ``(L, num_blocks, block_size, KV, D)``,
 and the layer as one more scalar-prefetch operand: a block is fetched as
 ``k_hbm.at[layer, blk]``, so a step program's layer scan never slices a layer
 out of the pool for them (a Pallas call cannot fuse its operand's slice).
+
+Both take a static ``window`` (0: none): a query at position ``p`` then reads
+keys ``p - window < j <= p`` only.  The block loop starts at the first block
+the row's (the prefill kernel: the tile's) oldest query can see, so a block
+table's entries behind the window are never read (the engine frees those
+blocks while the sequence runs), and the mask adds ``pos > q_pos - window``
+per query.  With ``window=0`` each kernel traces what it always did.
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...observability.trace import tracer
 from . import backend
 
 
@@ -35,8 +43,19 @@ def _layer_operand(layer) -> jax.Array:
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
+def _note_window(kind: str, window: int, fallback: bool) -> None:
+    """One ring event a traced call of a windowed layer, as
+    ``kernel/mixed_gemm_tiles``: which kernel, its window, that the block
+    loop's first block is computed from the row (not the constant 0), or
+    that the call gave way to XLA."""
+    if window:
+        tracer.add_event("kernel/paged_attention_window", attrs={
+            "kind": kind, "window": window,
+            **({"fallback": 1} if fallback else {"first_block_static": 0})})
+
+
 def _decode_attention_xla(q, k_cache, v_cache, layer, block_tables,
-                          context_lens):
+                          context_lens, window: int = 0):
     """Blockwise decode fallback for kernel-unfriendly shapes: a lax.scan
     over the block-table columns with online softmax.  Peak temp memory is
     O(S·KV·block_size), NOT O(S·S_max) — the r3 verdict's "gather path
@@ -60,7 +79,10 @@ def _decode_attention_xla(q, k_cache, v_cache, layer, block_tables,
         scores = jnp.einsum("skrd,stkd->skrt", qf, k)  # (S, KV, rep, BS)
         scores = scores.reshape(S, H, BS)
         pos = j * BS + jnp.arange(BS)[None, None, :]
-        scores = jnp.where(pos < context_lens[:, None, None], scores, -1e30)
+        seen = pos < context_lens[:, None, None]
+        if window:  # the query sits at context_lens - 1
+            seen &= pos >= context_lens[:, None, None] - window
+        scores = jnp.where(seen, scores, -1e30)
         m_new = jnp.maximum(m, scores.max(-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(scores - m_new)
@@ -85,11 +107,17 @@ def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
                    q_ref, k_hbm, v_hbm,  # inputs
                    o_ref,  # output
                    k_buf, v_buf, copy_sems,  # scratch
-                   *, block_size: int, max_blocks: int, group: int):
+                   *, block_size: int, max_blocks: int, group: int,
+                   window: int = 0):
     s = pl.program_id(0)
     layer = layer_ref[0]
     ctx = context_lens_ref[s]
     nblocks = pl.cdiv(ctx, block_size)
+    # the first block the query (at ctx - 1) sees a key of
+    first = jnp.maximum(ctx - window, 0) // block_size if window else 0
+
+    def since_first(j):  # the DMA slots alternate from the first block read
+        return j - first if window else j
 
     q = q_ref[0].astype(jnp.float32)  # (H, D)
     H, D = q.shape
@@ -112,17 +140,17 @@ def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
 
     @pl.when(nblocks > 0)
     def _start_first():
-        ka, va = get_dma(0, 0)
+        ka, va = get_dma(0, first)
         ka.start()
         va.start()
 
     def body(j, carry):
         acc, m, l = carry
-        slot = j % 2
+        slot = since_first(j) % 2
 
         @pl.when(j + 1 < nblocks)
         def _prefetch_next():
-            ka, va = get_dma((j + 1) % 2, j + 1)
+            ka, va = get_dma(since_first(j + 1) % 2, j + 1)
             ka.start()
             va.start()
 
@@ -139,7 +167,10 @@ def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
             qs, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)  # (H, KV·bs)
         pos = j * block_size + col_pos
-        scores = jnp.where(kv_match & (pos < ctx), scores, -jnp.inf)
+        keep = kv_match & (pos < ctx)
+        if window:
+            keep &= pos >= ctx - window
+        scores = jnp.where(keep, scores, -jnp.inf)
 
         m_cur = jnp.max(scores, axis=1, keepdims=True)  # (H, 1)
         m_new = jnp.maximum(m, m_cur)
@@ -158,20 +189,22 @@ def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
     acc0 = jnp.zeros((H, D), jnp.float32)
     m0 = jnp.full((H, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nblocks, body, (acc0, m0, l0))
+    acc, m, l = jax.lax.fori_loop(first, nblocks, body, (acc0, m0, l0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                            layer: jax.Array, block_tables: jax.Array,
-                           context_lens: jax.Array) -> jax.Array:
+                           context_lens: jax.Array, window: int = 0
+                           ) -> jax.Array:
     """q: (max_seqs, H, D) — one decode token per sequence.
     k/v_cache: the whole pools, (L, num_blocks, block_size, KV, D); layer:
     int32 scalar (traced in a layer scan), the pool's layer to read;
     block_tables: (max_seqs, max_blocks) int32; context_lens: (max_seqs,)
     int32.  Context length INCLUDES the current token (its KV already
-    written)."""
+    written).  ``window`` (static; 0: none): the sliding window of the
+    layer, see the module text."""
     S, H, D = q.shape
     _, NB, BS, KV, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
@@ -179,13 +212,15 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     # Mosaic DMA slices need the lane dim 128-aligned and sublanes 8-aligned;
     # small-model shapes fall back to the (correct, slower) XLA gather path.
-    if not backend.interpret() and (D % 128 != 0 or BS % 8 != 0):
+    fallback = not backend.interpret() and (D % 128 != 0 or BS % 8 != 0)
+    _note_window("decode", window, fallback)
+    if fallback:
         backend.warn_fallback(
             "paged_decode_attention",
             f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
             f"a multiple of 8 (Mosaic DMA slice alignment)")
         return _decode_attention_xla(q, k_cache, v_cache, layer,
-                                     block_tables, context_lens)
+                                     block_tables, context_lens, window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -204,7 +239,7 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )
     return pl.pallas_call(
         functools.partial(_decode_kernel, block_size=BS, max_blocks=max_blocks,
-                          group=group),
+                          group=group, **({"window": window} if window else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=backend.interpret(),
@@ -218,7 +253,7 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def _prefill_attention_xla(q, k_cache, v_cache, layer, block_tables,
-                           chunk_start, chunk_len):
+                           chunk_start, chunk_len, window: int = 0):
     """Blockwise prefill fallback.  q: (S, Qp, H, D) — each sequence's
     prefill chunk, rows ≥ chunk_len invalid.  A lax.scan over block-table
     columns with online softmax: peak temp memory is O(S·Qp·block_size),
@@ -245,6 +280,8 @@ def _prefill_attention_xla(q, k_cache, v_cache, layer, block_tables,
         valid = (t_pos <= q_pos[:, None, :, None]) & \
             (t_pos < ctx_end[:, None, None, None]) & \
             q_valid[:, None, :, None]
+        if window:
+            valid &= t_pos > q_pos[:, None, :, None] - window
         scores = jnp.where(valid, scores, -1e30)
         m_new = jnp.maximum(m, scores.max(-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -272,7 +309,7 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
                     q_ref, k_hbm, v_hbm,  # inputs
                     o_ref,  # output
                     k_buf, v_buf, copy_sems,  # scratch
-                    *, block_size: int, group: int, tq: int):
+                    *, block_size: int, group: int, tq: int, window: int = 0):
     s = pl.program_id(0)
     t = pl.program_id(1)
     layer = layer_ref[0]
@@ -283,6 +320,13 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
     # causal upper bound for this tile; 0 blocks when the tile is inactive
     kv_hi = jnp.minimum(ctx_end, start + tile_lo + tq)
     nblocks = jnp.where(tile_lo < qlen, pl.cdiv(kv_hi, block_size), 0)
+    # the first block the tile's oldest query (at start + tile_lo) sees a
+    # key of; each query's own band is the mask's
+    first = (jnp.maximum(start + tile_lo - window + 1, 0) // block_size
+             if window else 0)
+
+    def since_first(j):  # the DMA slots alternate from the first block read
+        return j - first if window else j
 
     q = q_ref[0].astype(jnp.float32)  # (tq, H, D)
     TQ, H, D = q.shape
@@ -309,17 +353,17 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
 
     @pl.when(nblocks > 0)
     def _start_first():
-        ka, va = get_dma(0, 0)
+        ka, va = get_dma(0, first)
         ka.start()
         va.start()
 
     def body(j, carry):
         acc, m, l = carry
-        slot = j % 2
+        slot = since_first(j) % 2
 
         @pl.when(j + 1 < nblocks)
         def _prefetch_next():
-            ka, va = get_dma((j + 1) % 2, j + 1)
+            ka, va = get_dma(since_first(j + 1) % 2, j + 1)
             ka.start()
             va.start()
 
@@ -335,6 +379,8 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
             preferred_element_type=jnp.float32)  # (rows, cols)
         pos = j * block_size + col_pos
         keep = kv_match & (pos <= q_abs) & (pos < ctx_end) & q_valid
+        if window:
+            keep &= pos > q_abs - window
         scores = jnp.where(keep, scores, -jnp.inf)
 
         m_cur = jnp.max(scores, axis=1, keepdims=True)
@@ -354,7 +400,7 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
     acc0 = jnp.zeros((rows, D), jnp.float32)
     m0 = jnp.full((rows, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((rows, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nblocks, body, (acc0, m0, l0))
+    acc, m, l = jax.lax.fori_loop(first, nblocks, body, (acc0, m0, l0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe).reshape(TQ, H, D).astype(o_ref.dtype)
 
@@ -362,7 +408,8 @@ def _prefill_kernel(layer_ref, block_tables_ref, chunk_start_ref,
 def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, layer: jax.Array,
                             block_tables: jax.Array, chunk_start: jax.Array,
-                            chunk_len: jax.Array, tq: int = 16) -> jax.Array:
+                            chunk_len: jax.Array, tq: int = 16,
+                            window: int = 0) -> jax.Array:
     """Chunked-prefill attention over paged KV (the reference's ragged-batch
     ``blocked_flash`` prefill kernel, ``inference/v2/kernels/ragged_ops/``).
 
@@ -375,7 +422,8 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     Returns (max_seqs, Qp, H, D).
 
     Causal within the sequence: q row i (absolute pos chunk_start+i) sees
-    cache positions ≤ its own.  Never materializes (T, S_max, …) — the
+    cache positions ≤ its own (with ``window``, static: and > its own less
+    the window; see the module text).  Never materializes (T, S_max, …) — the
     VERDICT r02 gather-path fix — and streams KV blocks with double-buffered
     DMA like the decode kernel.
     """
@@ -383,13 +431,16 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     _, NB, BS, KV, _ = k_cache.shape
     group = H // KV
 
-    if not backend.interpret() and (D % 128 != 0 or BS % 8 != 0):
+    fallback = not backend.interpret() and (D % 128 != 0 or BS % 8 != 0)
+    _note_window("prefill", window, fallback)
+    if fallback:
         backend.warn_fallback(
             "paged_prefill_attention",
             f"head_dim={D} is not a multiple of 128 or block_size={BS} not "
             f"a multiple of 8 (Mosaic DMA slice alignment)")
         return _prefill_attention_xla(q, k_cache, v_cache, layer,
-                                      block_tables, chunk_start, chunk_len)
+                                      block_tables, chunk_start, chunk_len,
+                                      window)
     tq = min(tq, Qp)
     while Qp % tq != 0:  # static divisor for the tile grid
         tq -= 1
@@ -410,7 +461,8 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_prefill_kernel, block_size=BS, group=group, tq=tq),
+        functools.partial(_prefill_kernel, block_size=BS, group=group, tq=tq,
+                          **({"window": window} if window else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Qp, H, D), q.dtype),
         interpret=backend.interpret(),

@@ -130,30 +130,40 @@ def _weight_passes(compiled_text: str, params) -> list:
                                               result))]
 
 
-def _lower_step_program(program: str, cfg, sds):
+def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     """``decode_step``, ``mixed_step`` or the self-draft ``spec_step`` (four
     proposals a row) of a W8A16 model of ``cfg``, lowered on shapes alone at
     the serving cells' sizes (32 rows, 512 tokens a step, block 64, 416
-    blocks: ``benchmark/configs/*-w8.json``) → (lowered, the pool's shape,
-    the quantized parameters' shapes)."""
+    blocks: ``benchmark/configs/*-w8.json``; ``sizes`` are another
+    configuration's) → (lowered, the pool's shape, or the two pools' of a
+    model with window and global layers, the quantized parameters'
+    shapes)."""
     from deepspeed_tpu.inference.quantization import quantize_model_params
     from deepspeed_tpu.inference.v2 import engine as v2e
+    from deepspeed_tpu.inference.v2.programs import pool_layers
     from deepspeed_tpu.models import transformer as tfm
 
-    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
-                      num_blocks=416, max_blocks_per_seq=64,
-                      spec_mode="self_draft" if program == "spec_step"
-                      else "off")
+    v2 = v2e.V2Config(**{**dict(
+        max_tokens_per_step=512, max_seqs=32, block_size=64, num_blocks=416,
+        max_blocks_per_seq=64,
+        spec_mode="self_draft" if program == "spec_step" else "off"),
+        **sizes})
     params = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda key: quantize_model_params(
-            tfm.init_params(key, cfg), bits=8, group=256),
+            tfm.init_params(key, cfg), bits=8, group=group),
             jax.random.PRNGKey(0)))
-    pool = (cfg.num_layers, v2.num_blocks, v2.block_size, cfg.kv_heads,
+    layers = pool_layers(cfg, v2)
+    pool = (layers[0], v2.num_blocks, v2.block_size, cfg.kv_heads,
             cfg.head_dim)
     caches = {"k": sds(pool, jnp.bfloat16), "v": sds(pool, jnp.bfloat16)}
     rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
     tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
+    if len(layers) == 2:  # the window layers' pool and table beside them
+        win = (layers[1], v2.num_window_blocks) + pool[2:]
+        caches.update(k_win=sds(win, jnp.bfloat16),
+                      v_win=sds(win, jnp.bfloat16))
+        pool, tables = (pool, win), (tables, tables)
     if program == "decode_step":
         lowered = v2e.build_decode_forward(cfg, v2).lower(
             params, caches, rows(jnp.int32), rows(jnp.int32), tables,
@@ -496,6 +506,65 @@ def test_olmoe_step_programs_compile(one_chip, mosaic, program):
                          compiled_text)
     _assert_pools_stay_in_place(compiled, pool)
     _assert_weights_stay_in_place(compiled, params, "olmoe-1b-7b", program)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_mellum2_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of Mellum2-12B-A2.5B (every width as published,
+    W8A16 at group 128, two periods of S S S F, the serving cell's engine
+    sizes: a global pool of 3,000 blocks, a window pool of 801, tables of
+    132) compile for the described chip.  Both kinds of attention layer run
+    the paged kernel (the ring holds a ``kernel/paged_attention_window``
+    event of window 1024 for the program's kind, none with ``fallback``),
+    every GEMM its kernel (no ``kernel/*_tiles`` event with ``fallback``:
+    group 128 tiles the experts' 2304 x 896 and 896 x 2304), the routed
+    FFN's scopes are in the lowered names, and neither pool is copied."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg = dataclasses.replace(
+        tfm.get_config("mellum2-12b-a2.5b", num_layers=8),
+        dtype="bfloat16", param_dtype="bfloat16")
+    tracer.clear()
+    lowered, pools, params = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip), group=128,
+        num_blocks=3000, num_window_blocks=801, max_blocks_per_seq=132)
+    assert pools == ((2, 3000, 64, 4, 128), (6, 801, 64, 4, 128))
+    assert params["layers"]["moe"]["w_out"].codes.shape == (8, 64, 896, 2304)
+    assert params["layers"]["moe"]["w_out"].group == 128
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    assert not [e for e in events if "fallback" in e[1]], events
+    kind = "decode" if program == "decode_step" else "prefill"
+    windows = [a for name, a in events
+               if name == "kernel/paged_attention_window"]
+    assert windows and all(a == {"kind": kind, "window": 1024,
+                                 "first_block_static": 0} for a in windows)
+    text = lowered.as_text(debug_info=True)
+    for name in ("grouped_mixed_gemm", "mixed_gemm", "moe_route",
+                 "moe_dispatch", "moe_experts", "moe_combine",
+                 f"{kind}_attention"):
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
+    # a period's four layers are unrolled in the loop: four attention calls
+    # (three banded, one full: two kernels of one name), twelve expert GEMMs
+    calls = re.findall(rf"%(paged_attention_{kind}[.\d]*) = [^\n]*custom-call\(",
+                       compiled_text)
+    assert len(calls) == 4, calls
+    calls = re.findall(r"%(grouped_mixed_gemm[.\d]*) = [^\n]*custom-call\(",
+                       compiled_text)
+    assert len(calls) == 12, calls
+    for pool in pools:
+        assert _pool_passes(compiled_text, pool) == []
+    assert _weight_passes(compiled_text, params) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        2 * 2 * int(np.prod(pool)) for pool in pools)
+    assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
 
 
 def test_mesh_follows_the_torus(topo):
