@@ -481,6 +481,9 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
             raise AssertionError(f"{phase}: /healthz said {health}")
         if quantize_bits:
             check_gemm_tiles(phase, port)
+        check_prefill_tiles(phase, [
+            e["args"] for e in _get_json(port, "/debug/trace")["traceEvents"]
+            if e["name"] == "kernel/paged_attention_prefill_tiles"])
     finally:
         pool.drain(scfg.drain_timeout_s)
         server.shutdown()
@@ -573,6 +576,9 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
         experts_hit=[round(a["moe_experts_hit"], 1) for a in steps[-3:]],
         gemm_tile_events=len(events), grouped_k_n_rows_tilem_tn=grouped)
+    check_prefill_tiles(phase, [
+        s.attrs for s in tracer.spans()
+        if s.name == "kernel/paged_attention_prefill_tiles"])
     fallen = [a for a in events if "fallback" in a]
     sliced = [a for a in events if a.get("layers") == 0]
     if check_kernels and (fallen or sliced or not grouped):
@@ -658,6 +664,9 @@ def phase_swa_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
         window_blocks_freed=engine.kv_win.trimmed,
         kv_read_vs_full_pct=round(100 * read / full, 1),
         kernel_events=len(events), window_kernels=windows)
+    check_prefill_tiles(phase, [
+        a for name, a in events
+        if name == "kernel/paged_attention_prefill_tiles"])
     fallen = [e for e in events if "fallback" in e[1]]
     if check_kernels and (fallen or len(windows) < 2):
         raise AssertionError(f"{phase}: kernels fallen back: {fallen}; "
@@ -708,6 +717,22 @@ def check_gemm_tiles(phase: str, port: int) -> None:
             f"{phase}: /debug/trace shows {len(events)} "
             f"kernel/mixed_gemm_tiles events, fallen back: {fallen}, on a "
             f"sliced layer's copy: {sliced}")
+
+
+def check_prefill_tiles(phase: str, events: list) -> None:
+    """The ``kernel/paged_attention_prefill_tiles`` events of the warmed
+    server (one a call site of the mixed step program traced): each names the
+    tiles the picker chose for the flat queries, and none gave way to the
+    blockwise XLA path."""
+    fallen = [a for a in events if "fallback" in a]
+    tiles = sorted({(a["t"], a["heads"], a["kv"], a["window"], a["tq"],
+                     a["kb"]) for a in events if "fallback" not in a})
+    log(phase, prefill_attention_tile_events=len(events),
+        t_heads_kv_window_tq_kb=tiles)
+    if not events or fallen:
+        raise AssertionError(
+            f"{phase}: {len(events)} kernel/paged_attention_prefill_tiles "
+            f"events, fallen back: {fallen}")
 
 
 def check_mixed_gemm(phase: str, params, cfg) -> None:

@@ -33,7 +33,8 @@ from ...observability.trace import tracer
 from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
                        build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
-                       layer_plan, pool_layers, sample_rows)
+                       layer_plan, mixed_step_attn_tiles, pool_layers,
+                       sample_rows)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder,
                      SequenceDescriptor, window_bound)
@@ -298,6 +299,9 @@ class InferenceEngineV2:
             two_pools=self.kv_win is not None,
             main_grows=self._windowed is self.kv)
         self._kv_step = None  # a windowed model's block counters of a step
+        # the mixed step's prefill attention tiling, for ``attn_q_slots``
+        self._attn_tiles = mixed_step_attn_tiles(self.model_cfg, self.cfg)
+        self._attn_q_slots = None  # of a mixed step that ran the device
         self._prefilling = 0  # running seqs still before their first token
         self.steps = 0  # step() calls so far: the spans' ``step``
         self.fast_steps = 0  # telemetry: SoA decode steps taken
@@ -1277,6 +1281,7 @@ class InferenceEngineV2:
         sub = {"kind": kind, "step": self.steps}  # on the step and its children
         self._moe_stats = None
         self._kv_step = None
+        self._attn_q_slots = None
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
@@ -1296,6 +1301,8 @@ class InferenceEngineV2:
         if self._moe_stats is not None:  # an MoE model's step ran the device
             attrs["moe_rows"], attrs["moe_rows_padded"] = self._moe_rows[kind]
             attrs["moe_experts_hit"], attrs["moe_rows_max"] = self._moe_stats
+        if self._attn_q_slots is not None:  # a mixed step ran the device
+            attrs["attn_q_slots"] = self._attn_q_slots
         if self._kv_step is not None:  # a windowed model's step ran the device
             m = self._windowed
             attrs["window_blocks_freed"] = \
@@ -1345,6 +1352,10 @@ class InferenceEngineV2:
         if self._windowed is not None:
             self._count_kv(batch.chunk_start[:len(picks)].astype(np.int64),
                            batch.chunk_len[:len(picks)].astype(np.int64))
+        # the query slots the prefill kernel multiplies: each row's tokens
+        # rounded up to its tiles (``tokens`` / this: the share that hold one)
+        self._attn_q_slots = int(
+            self._attn_tiles.slots(batch.chunk_len).sum())
         batch_args = (
             jnp.asarray(batch.token_ids), jnp.asarray(batch.position_ids),
             jnp.asarray(batch.seq_index), tables,

@@ -23,8 +23,10 @@ import jax.numpy as jnp
 from ...models import transformer as tfm
 from ...moe.dropless import serving_moe_block
 from ...ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
-from ...ops.pallas.paged_attention import (paged_decode_attention,
-                                           paged_prefill_attention)
+from ...ops.pallas.paged_attention import (PrefillTiles,
+                                           paged_decode_attention,
+                                           paged_prefill_attention,
+                                           pick_prefill_tiles)
 
 # Built forward functions are memoized per (builder, configs): every engine
 # over the same shapes — serving replicas, test fixtures — shares ONE jitted
@@ -132,29 +134,6 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("ths,tshd->thd", probs, v_t.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def prefill_scatter_coords(seq_index, position_ids, chunk_start, max_seqs: int,
-                           Qp: int):
-    """Coordinates for scattering the ragged (T, H, D) q into the per-sequence
-    (max_seqs, Qp, H, D) chunk layout, plus the gather coordinates to read the
-    attention output back.
-
-    Padding tokens (seq_index == -1) MUST get POSITIVE out-of-range sentinels
-    (row == max_seqs, col == Qp): JAX normalizes negative scatter indices
-    (idx + size) *before* the ``mode="drop"`` check, so a -1 sentinel would
-    wrap onto row max_seqs-1 and collide with a real sequence's write —
-    duplicate-index ``.set`` order is nondeterministic on TPU (r3 advisor,
-    high).  Only idx >= size is genuinely dropped.
-
-    Returns (scat_row, scat_col, gather_row, gather_col); gather coords are
-    clamped in-range (padding rows read garbage that callers drop)."""
-    row = jnp.clip(seq_index, 0, max_seqs - 1)
-    qp_col = position_ids - chunk_start[row]
-    valid = seq_index >= 0
-    scat_row = jnp.where(valid, row, max_seqs)
-    scat_col = jnp.where(valid, qp_col, Qp)
-    return scat_row, scat_col, row, jnp.clip(qp_col, 0, Qp - 1)
 
 
 def _ffn(m_in, lp, model_cfg: tfm.TransformerConfig, valid=None):
@@ -450,6 +429,17 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
 # ---------------------------------------------------------------------------
 
 
+def mixed_step_attn_tiles(model_cfg: tfm.TransformerConfig, v2) -> PrefillTiles:
+    """The tiling of the mixed step's prefill attention: the kernel's picker
+    on the sizes its queries have in ``build_ragged_forward``'s program, for
+    the engine to count ``attn_q_slots`` from (the kernel leaves what it
+    picked in its ring event; ``tests/test_inference_v2.py`` holds the two
+    together)."""
+    return pick_prefill_tiles(v2.max_tokens_per_step, model_cfg.num_heads,
+                              model_cfg.kv_heads, model_cfg.head_dim,
+                              v2.block_size, jnp.dtype(model_cfg.dtype))
+
+
 def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
     bs = v2.block_size
 
@@ -467,12 +457,9 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
         row = jnp.clip(seq_index, 0, max_seqs - 1)
         blk_ids = write_blocks(caches, block_tables, row, position_ids, valid,
                                bs)
-        # per-token scatter coordinates into the per-sequence chunk layout
-        # (max_seqs, Qp): row = sequence, col = offset within this step's
-        # chunk (padding handled by positive OOB sentinels — see helper)
-        Qp = v2.max_tokens_per_step
-        scat_row, scat_col, gath_row, gath_col = prefill_scatter_coords(
-            seq_index, position_ids, chunk_start, max_seqs, Qp)
+        # the builder lays each row's tokens end to end in row order, so a
+        # row's queries begin where the rows before it end
+        q_start = jnp.cumsum(chunk_len) - chunk_len
 
         # per-token adapter slot: each ragged token reads its row's slot
         # (padding tokens pin to the null slot — their outputs are dropped
@@ -482,20 +469,14 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
             tok_slot = jnp.where(valid, row_adapter[row], 0)
 
         def attend(q, k_cache, v_cache, layer, kind):
-            # chunked-prefill attention over paged KV: reorganize the ragged
-            # (T, H, D) q into per-sequence chunks and run the paged Pallas
-            # prefill kernel — never materializes the old (T, S_max, KV, D)
-            # per-token gather
+            # chunked-prefill attention over paged KV on the ragged (T, H, D)
+            # q as the layer made it: the kernel walks each row's tokens
+            # where they lie (a padding token comes out zero)
             with jax.named_scope("prefill_attention"):
-                q_seq = jnp.zeros((max_seqs, Qp) + q.shape[1:], q.dtype)
-                q_seq = q_seq.at[scat_row, scat_col].set(q, mode="drop")
-                o_seq = paged_prefill_attention(q_seq, k_cache, v_cache,
-                                                layer, tables[kind.pool],
-                                                chunk_start, chunk_len,
-                                                window=kind.window)
-                # padding rows read in-range garbage (clamped col), dropped
-                # later
-                return o_seq[gath_row, gath_col]  # (T, H, D)
+                return paged_prefill_attention(q, k_cache, v_cache, layer,
+                                               tables[kind.pool], q_start,
+                                               chunk_start, chunk_len,
+                                               window=kind.window)
 
         x, caches, moe_stats = serving_layers(
             params, caches, x, position_ids, (blk_ids, position_ids % bs),

@@ -93,10 +93,13 @@ def verify_body(params, caches, tokens, ctx, block_tables, pos_limit,
                           jnp.clip(pos_limit - ctx, 0, Q), 0).astype(jnp.int32)
 
     def attend(q, k_cache, v_cache, layer, kind):
+        # the (S, Q) queries flat, row s from token s * Q on: a row whose
+        # reservation ends inside its Q leaves a gap, which comes out zero
         with jax.named_scope("prefill_attention"):
-            return paged_prefill_attention(q, k_cache, v_cache, layer,
-                                           block_tables, ctx * active,
-                                           chunk_len, window=kind.window)
+            return paged_prefill_attention(
+                q.reshape((-1,) + q.shape[2:]), k_cache, v_cache, layer,
+                block_tables, jnp.arange(q.shape[0], dtype=jnp.int32) * Q,
+                ctx * active, chunk_len, window=kind.window).reshape(q.shape)
 
     x = tfm.embed_tokens(params, tokens, model_cfg, position_ids=pos)  # (S,Q,H)
     x, caches, _ = serving_layers(

@@ -40,6 +40,24 @@ def _reset_topology():
     topology.reset_topology()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """A compiled CPU program keeps its code mapped: some hundreds of memory
+    maps each for a step program that holds an interpret-mode Pallas kernel
+    (130-400 a kernel), and a process may hold ``vm.max_map_count`` (65,530)
+    of them; past that the next compile dies of a segmentation fault, in
+    whatever test happens to run then.  So a worker that has gathered half of
+    that drops the compiled programs of the modules it has finished."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to count
+        return
+    if held > 30_000:
+        jax.clear_caches()
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Lockdep gate (ISSUE 17): under ``DSTPU_LOCKDEP=1`` every suite in
     this pytest process ran with named-lock order tracking; assert the

@@ -386,10 +386,12 @@ def test_kernels_read_the_band_only(window):
                                                         ctx)), window)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
-    # prefill: a chunk of 13 queries a row ending at the row's length
-    qp, tq = 16, 4
+    # prefill: a chunk of 13 queries a row ending at the row's length, the
+    # rows' queries flat: row r from token 16 * r on (3 tokens of gap a row)
+    qp = 16
     start = np.asarray([n - min(n, 13) for n in lens], np.int32)
     clen = ctx - start
+    q_start = np.arange(3, dtype=np.int32) * qp
     qs = rng.standard_normal((3, qp, heads, d)).astype(np.float32)
     dead = tables.copy()
     for r in range(3):  # behind the chunk's oldest query's window
@@ -399,14 +401,16 @@ def test_kernels_read_the_band_only(window):
         want[r, :clen[r]] = _dense(
             qs[r, :clen[r]], k[layer], v[layer], tables, r,
             start[r] + np.arange(clen[r]), window, bs)
-    got = pa.paged_prefill_attention(qs, k_nan, v_nan, layer, dead, start,
-                                     clen, tq=tq, window=window)
-    for r in range(3):
-        np.testing.assert_allclose(np.asarray(got)[r, :clen[r]],
-                                   want[r, :clen[r]], atol=2e-5)
+    got = pa.paged_prefill_attention(qs.reshape(-1, heads, d), k_nan, v_nan,
+                                     layer, dead, q_start, start, clen,
+                                     window=window)
+    np.testing.assert_allclose(np.asarray(got).reshape(qs.shape), want,
+                               atol=2e-5)
     got = pa._prefill_attention_xla(*map(jnp.asarray, (
-        qs, k, v, layer, tables, start, clen)), window)
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+        qs.reshape(-1, heads, d), k, v, layer, tables, q_start, start, clen)),
+        window)
+    np.testing.assert_allclose(np.asarray(got).reshape(qs.shape), want,
+                               atol=2e-5)
     flat = np.concatenate([qs[r, :clen[r]] for r in range(3)])
     got = programs.ragged_attention_xla(*map(jnp.asarray, (
         flat, k[layer], v[layer], tables, ctx,

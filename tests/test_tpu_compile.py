@@ -232,27 +232,77 @@ def _assert_pools_stay_in_place(compiled, pool):
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
 
 
-@pytest.mark.parametrize("kernel", ["decode", "prefill"])
-def test_paged_attention_compiles(one_chip, mosaic, kernel):
+#: the served shapes of the paged kernels: query heads, KV heads, window
+_SERVED_ATTENTION = {"mistral-7b": (H, KV, 0), "olmoe-1b-7b": (16, 16, 0),
+                     "mellum2-window-layer": (32, 4, 1024)}
+
+
+@pytest.mark.parametrize("kernel,model", [
+    ("decode", "mistral-7b"),
+    *(("prefill", model) for model in _SERVED_ATTENTION)])
+def test_paged_attention_compiles(one_chip, mosaic, kernel, model):
+    """Each kernel alone on the whole pool (layers, blocks, ...) with the
+    layer a traced scalar: Mosaic takes ``k_hbm.at[layer, blk]``.  The
+    prefill kernel on the flat queries of a step of 512 tokens in bfloat16,
+    at every served share of query heads a KV head (4, 1, 8) and Mellum2's
+    window; its ring event names the picker's tiles and no fallback."""
+    from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_prefill_attention)
 
-    """Each kernel alone on the whole pool (layers, blocks, ...) with the
-    layer a traced scalar: Mosaic takes ``k_hbm.at[layer, blk]``."""
-    seqs, layers, blocks, bs, max_blocks = 16, 4, 256, 64, 16
+    heads, kv, window = _SERVED_ATTENTION[model]
+    seqs, layers, blocks, bs, max_blocks = 32, 4, 256, 64, 16
     sds = functools.partial(_sds, sharding=one_chip)
-    pool = sds((layers, blocks, bs, KV, D), jnp.bfloat16)
+    pool = sds((layers, blocks, bs, kv, D), jnp.bfloat16)
     layer = sds((), jnp.int32)
     tables, lens = sds((seqs, max_blocks), jnp.int32), sds((seqs,), jnp.int32)
     if kernel == "decode":
         text = _compile(paged_decode_attention,
-                        sds((seqs, H, D), jnp.bfloat16), pool, pool, layer,
-                        tables, lens, kernels=["paged_attention_decode"])
+                        sds((seqs, heads, D), jnp.bfloat16), pool, pool,
+                        layer, tables, lens,
+                        kernels=["paged_attention_decode"])
     else:
-        text = _compile(paged_prefill_attention,
-                        sds((seqs, 512, H, D), jnp.bfloat16), pool, pool,
-                        layer, tables, lens, lens,
+        tracer.clear()
+        text = _compile(functools.partial(paged_prefill_attention,
+                                          window=window),
+                        sds((512, heads, D), jnp.bfloat16), pool, pool,
+                        layer, tables, lens, lens, lens,
                         kernels=["paged_attention_prefill"])
+        assert f"bf16[512,{heads},{D}]" in text
+        event, = [s.attrs for s in tracer.spans()
+                  if s.name == "kernel/paged_attention_prefill_tiles"]
+        assert event == {"t": 512, "heads": heads, "kv": kv, "d": D,
+                         "block": bs, "window": window, "tq": "8/128",
+                         "kb": 4, "grid_steps": 1}
+    assert _pool_passes(text, (layers, blocks, bs, kv, D)) == []
+
+
+@pytest.mark.parametrize("tokens,grid_steps", [(2048, 2), (4096, 4),
+                                               (8192, 8), (1000, 1)])
+def test_prefill_attention_compiles_at_any_budget(one_chip, mosaic, tokens,
+                                                  grid_steps):
+    """``max_tokens_per_step`` is the server's to set: past the 8 MiB of
+    queries a grid step holds (1,024 tokens of Mistral's 32 heads) the kernel
+    walks the budget in spans, so no budget is refused for its VMEM (whole in
+    VMEM, 8,192 tokens asked for 142 MB of the chip's 128) and none gives
+    way to the XLA path."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_prefill_attention)
+
+    seqs, layers, blocks, bs, max_blocks = 32, 4, 256, 64, 16
+    sds = functools.partial(_sds, sharding=one_chip)
+    pool = sds((layers, blocks, bs, KV, D), jnp.bfloat16)
+    tables, lens = sds((seqs, max_blocks), jnp.int32), sds((seqs,), jnp.int32)
+    tracer.clear()
+    text = _compile(paged_prefill_attention,
+                    sds((tokens, H, D), jnp.bfloat16), pool, pool,
+                    sds((), jnp.int32), tables, lens, lens, lens,
+                    kernels=["paged_attention_prefill"])
+    event, = [s.attrs for s in tracer.spans()
+              if s.name == "kernel/paged_attention_prefill_tiles"]
+    assert (event["tq"], event["grid_steps"]) == ("8/128", grid_steps)
+    assert "fallback" not in event
     assert _pool_passes(text, (layers, blocks, bs, KV, D)) == []
 
 
@@ -423,6 +473,24 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
     compiled = lowered.compile()
     _assert_pools_stay_in_place(compiled, pool)
     _assert_weights_stay_in_place(compiled, params, "mistral-7b", program)
+    if program == "mixed_step":
+        _assert_attention_walks_the_tokens(compiled)
+
+
+def _assert_attention_walks_the_tokens(compiled):
+    """The mixed step's prefill attention reads the step's queries as the
+    layer made them (ISSUE 32): the kernel's name once in the layer body,
+    nothing laid out by (row, budget) = ``[32,512,32,128]`` (the parent
+    zero-filled one such array, scattered into it, and gathered out of
+    another), and a temp below the parent's 404,007,936 B, which held
+    both."""
+    text = compiled.as_text()
+    calls = re.findall(r"%(paged_attention_prefill[.\d]*) = [^\n]*custom-call\(",
+                       text)
+    assert len(calls) == 1, calls
+    assert f"[32,512,{H},{D}]" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 404_007_936 - 2 * 32 * 512 * H * D, temp
 
 
 # olmoe-1b-7b: 64 experts of width 1024 on hidden 2048, top 8; the decode
