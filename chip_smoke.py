@@ -93,6 +93,24 @@ class Sizes:
     swa_max_blocks_per_seq: int = 40
     swa_requests: Tuple[Tuple[int, int], ...] = (
         (2300, 12), (1100, 20), (300, 16))
+    # -- one mixer a layer of three kinds: E M E M * at Nemotron-3-Nano's
+    # widths (128 experts of 2688 x 1856, top 6, a shared expert; Mamba-2 of
+    # 64 heads x 64 x 128) is 2.8 GB of int8 codes at group 128; a prompt
+    # crosses the step budget three times, a second batch reuses the slots
+    ssm_preset: str = "nemotron3-nano-30b-a3b"
+    ssm_pattern: str = "EMEM*"
+    ssm_max_blocks_per_seq: int = 24
+    ssm_requests: Tuple[Tuple[int, int], ...] = (
+        (1300, 8), (300, 12), (40, 10), (9, 14))
+    ssm_second: Tuple[Tuple[int, int], ...] = ((70, 8), (600, 6), (5, 12))
+    # the tapped rows against the reference held to the program's routing
+    # choices (median, worst: the serving cell's bounds), and the share of
+    # served tokens within ``margin`` of the free reference's maximum
+    ssm_logit_tol: Tuple[float, float] = (0.12, 0.2)
+    ssm_served_min: float = 0.6
+    # the tapped sequences' slots of the engine's state array against the
+    # reference pass's final states, of the largest element (the cell's bound)
+    ssm_state_tol: float = 0.15
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -772,6 +790,237 @@ def check_mixed_gemm(phase: str, params, cfg) -> None:
 # ---------------------------------------------------------------------------
 
 
+def check_state_updates(phase: str, cfg) -> None:
+    """The program's two state updates alone, on the device at the model's
+    sizes (``ssd_chunk_scan`` on rows of 1, 127, 128, 129 and 300 tokens in
+    one call, each from the state of its slot, then ``ssm_decode_update``),
+    against the reference's recurrence one token at a time: the final states
+    within 1e-3 of the largest state and ``y`` within 2e-2 (it carries the
+    chunk's bfloat16 products); the recurrence with its state kept in
+    bfloat16 between tokens has to read over 1e-3.  (The serving cell
+    compares the engine's own state arrays: ``serve_ssm_moe.check_logits``.)"""
+    from benchmark.reference import ssm_moe_decoder as reference
+    from deepspeed_tpu.ops.pallas.ssm import ssd_chunk_scan, ssm_decode_update
+
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N, Q = cfg.mamba_n_groups, cfg.mamba_state_size, cfg.mamba_chunk_size
+    lens = np.asarray([1, Q - 1, Q, Q + 1, 2 * Q + 44], np.int32)
+    T, R = int(lens.sum()), len(lens)
+    k = jax.random.split(jax.random.PRNGKey(5), 8)
+    dtype = jnp.dtype(cfg.dtype)
+    x = jax.random.normal(k[0], (T, H, P)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (T, H)) - 3.0)
+    A = -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.77))
+    B = jax.random.normal(k[3], (T, G, N)).astype(dtype)
+    C = jax.random.normal(k[4], (T, G, N)).astype(dtype)
+    D = jax.random.normal(k[5], (H,))
+    ssm0 = jax.random.normal(k[6], (1, R + 1, H, P, N))
+    starts = jnp.asarray(np.cumsum(lens) - lens, jnp.int32)
+    fresh = jnp.asarray([False, False, True, False, False])
+
+    @jax.jit
+    def program(ssm):
+        y, ssm = ssd_chunk_scan(ssm, jnp.int32(0), x, dt, A, B, C, D, starts,
+                                jnp.asarray(lens),
+                                jnp.arange(R, dtype=jnp.int32), fresh,
+                                jnp.asarray(lens >= 2), Q)
+
+        def put(a):  # the row of one token, in slot order
+            return jnp.zeros((R + 1,) + a.shape[1:], a.dtype).at[0].set(a[0])
+
+        one = jnp.zeros((R + 1,), bool).at[0].set(True)
+        y1, ssm = ssm_decode_update(ssm, jnp.int32(0), put(x), put(dt), A,
+                                    put(B), put(C), D, one,
+                                    jnp.zeros((R + 1,), bool))
+        return y.at[0].set(y1[0]), ssm
+
+    y, got = program(ssm0)
+    f32 = jnp.float32
+    heads = lambda m: jnp.repeat(m.astype(f32), H // G, 1)  # noqa: E731
+    longest = int(lens.max())
+
+    def row(a, r):  # a row's tokens, padded to the longest row
+        s, n = int(starts[r]), int(lens[r])
+        return jnp.pad(a[s:s + n], ((0, longest - n),) + ((0, 0),)
+                       * (a.ndim - 1))
+
+    recur = jax.jit(reference.recurrence, static_argnums=7)
+    out = {}
+    for name, low in (("float32", False), ("bfloat16", True)):
+        worst_y = worst_s = 0.0
+        for r in range(R):
+            s, n = int(starts[r]), int(lens[r])
+            first = jnp.zeros((H, P, N)) if bool(fresh[r]) else ssm0[0, r]
+            # a padded token has dt 0: no decay and no input, the state stays
+            want, state = recur(row(x, r).astype(f32), row(dt, r), A,
+                                heads(row(B, r)), heads(row(C, r)), D, first,
+                                low)
+            worst_y = max(worst_y, float(jnp.abs(y[s:s + n] - want[:n]).max()
+                                         / jnp.abs(want[:n]).max()))
+            worst_s = max(worst_s, float(jnp.abs(got[0, r] - state).max()
+                                         / jnp.abs(state).max()))
+        out[name] = (worst_y, worst_s)
+    log(phase, state_updates_rows=lens.tolist(),
+        state_rel=float(f"{out['float32'][1]:.3g}"),
+        y_rel=float(f"{out['float32'][0]:.3g}"),
+        state_rel_kept_in_bfloat16=float(f"{out['bfloat16'][1]:.3g}"))
+    if not (out["float32"][1] <= 1e-3 and out["float32"][0] <= 2e-2
+            and out["bfloat16"][1] > 1e-3):
+        raise AssertionError(f"{phase}: the state updates against the "
+                             f"recurrence one token at a time: {out}")
+
+
+def phase_ssm_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
+                         ) -> None:
+    """A model of one mixer a layer (Mamba-2, routed MoE with a shared
+    expert, attention without positions: a cut of Nemotron-3-Nano's pattern,
+    int8 at group 128) through ``InferenceEngineV2``: per-sequence state in
+    slots beside the paged K/V, a prompt chunked three times with decode
+    rows riding in its mixed steps, then a second batch through the SAME
+    slots (what the first left there must not be read: a row that starts a
+    sequence starts from zeros), then the first batch once more with the
+    logits of the engine's own step programs tapped; the tiles of both state
+    updates and of the grouped GEMM at the experts' width 1856 (stored 1920)
+    printed, none fallen back; every block and every slot free after each
+    drain.  Against the plain reference
+    (``benchmark/reference/ssm_moe_decoder.py``) over the same codes, as the
+    serving cell compares (``PERF.md`` section 6: with seeded random weights
+    128 near-uniform router scores tie, bfloat16 falls on the other side of
+    a tie now and then, and every later position reads the flip through the
+    states): the tapped rows against the reference HELD TO THE PROGRAM'S
+    ROUTING CHOICES, median and worst row (a state read from the wrong slot
+    or left from the sequence before fails the worst row), and of the served
+    tokens the share within ``margin`` of the free reference's maximum."""
+    from benchmark.drivers.serve_ssm_moe import (draw_small_tensors,
+                                                 low_bits_share,
+                                                 published_model, row_errors)
+    from benchmark.reference import ssm_moe_decoder as reference
+    from benchmark.routing_tap import RoutedLogitTap
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-ssm-moe-int8"
+    cfg = tfm.get_config(sz.ssm_preset, num_layers=len(sz.ssm_pattern),
+                         mixer_pattern=sz.ssm_pattern, dtype="bfloat16",
+                         param_dtype="bfloat16")
+    log(phase, preset=sz.ssm_preset, pattern=sz.ssm_pattern,
+        experts=cfg.num_experts, top=cfg.moe_top_k,
+        params_m=round(cfg.num_params() / 1e6, 1))
+    params = jax.jit(lambda k: draw_small_tensors(
+        tfm.init_params(k, cfg), seed))(jax.random.PRNGKey(seed))
+    tracer.clear()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.num_blocks,
+        max_blocks_per_seq=sz.ssm_max_blocks_per_seq, quantize_bits=8,
+        quantize_group=128))
+    del params
+    rng = np.random.default_rng([seed, 37])
+    served, tapped = [], []
+    for batch, tap_it in ((sz.ssm_requests, False), (sz.ssm_second, False),
+                          (sz.ssm_requests, True)):
+        prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+                   for n, _ in batch]
+        tap = RoutedLogitTap(engine) if tap_it else None
+        try:
+            uids = [engine.put(p, max_new_tokens=n)
+                    for p, (_, n) in zip(prompts, batch)]
+            # step by step under the tap (a state model never bursts)
+            whole = engine.generate_all(burst=1) if tap_it \
+                else engine.generate_all()
+        finally:
+            if tap is not None:
+                tap.remove()
+        for p, u, (_, n) in zip(prompts, uids, batch):
+            if len(whole[u]) - len(p) != n:
+                raise AssertionError(f"{phase}: asked {n} tokens, got "
+                                     f"{len(whole[u]) - len(p)}")
+            if tap is None:
+                served.append((p, whole[u][len(p):]))
+            else:
+                tapped.append((p, whole[u][len(p):], tap.logits[u],
+                               tap.forced(u, len(whole[u])), np.asarray(
+                                   engine.caches["ssm"][:, tap.slots[u]],
+                                   np.float32)))
+        engine.kv.check_consistency()
+        if not engine.drained():
+            raise AssertionError(
+                f"{phase}: {engine.free_state_slots} of "
+                f"{engine.total_state_slots} state slots and "
+                f"{engine.free_blocks} of {engine.total_blocks} blocks free "
+                f"after the drain")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"
+             and "ssm_tokens" in s.attrs]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    started = sum(a["state_rows_started"] for a in steps)
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        rows_started=started, ssm_tokens=sum(a["ssm_tokens"] for a in steps),
+        slots=engine.total_state_slots, kernel_events=len(events))
+    for name in ("kernel/ssm_decode_update", "kernel/ssd_chunk_scan_tiles",
+                 "kernel/grouped_mixed_gemm_tiles"):
+        seen = {tuple(sorted(a.items())) for n, a in events if n == name}
+        if not seen:
+            raise AssertionError(f"{phase}: no {name} event")
+        for attrs in sorted(seen):
+            log(phase, event=name, **dict(attrs))
+    check_prefill_tiles(phase, [
+        a for name, a in events
+        if name == "kernel/paged_attention_prefill_tiles"])
+    fallen = [e for e in events if "fallback" in e[1]]
+    if check_kernels and fallen:
+        raise AssertionError(f"{phase}: kernels fallen back: {fallen}")
+    if started != len(served) + len(tapped):
+        raise AssertionError(f"{phase}: {started} rows started from zeros, "
+                             f"{len(served) + len(tapped)} sequences were "
+                             f"served")
+    model = published_model(cfg)
+    served_params = engine.params
+    del engine
+    gc.collect()
+    check_state_updates(phase, cfg)
+    errs, agree, state = row_errors(served_params, model, tapped, 256)
+    median, worst_row = float(np.median(errs)), float(errs.max())
+    low_bits = low_bits_share(np.stack([t[4] for t in tapped]))
+    log(phase, tapped_rows=len(errs), median_row=round(median, 4),
+        worst_row=round(worst_row, 4), allowed=sz.ssm_logit_tol,
+        reference_router_agrees=[round(float(a), 3) for a in agree],
+        slot_state_rel=float(f"{state.max():.3g}"),
+        state_allowed=sz.ssm_state_tol, state_low_bits=low_bits)
+    if not (state.max() <= sz.ssm_state_tol and low_bits > 0.5):
+        raise AssertionError(
+            f"{phase}: the tapped sequences' state slots lie "
+            f"{state.max():.3g} of the largest element from the reference's "
+            f"final states ({sz.ssm_state_tol} allowed); {low_bits} of their "
+            f"elements hold what bfloat16 cannot")
+    if not (median <= sz.ssm_logit_tol[0] and worst_row <= sz.ssm_logit_tol[1]):
+        raise AssertionError(
+            f"{phase}: the step programs' logits lie {median:.3f} (median "
+            f"row) / {worst_row:.3f} (worst row) from the reference held to "
+            f"their routing choices (a state read from the wrong slot or "
+            f"left from the sequence before would read so)")
+    within, worst, exact, checked = 0, 0.0, 0, 0
+    for p, out in served:
+        seq = np.zeros(-(-(len(p) + len(out)) // 256) * 256, np.int32)
+        seq[:len(p) + len(out)] = p + out
+        m, rank = reference.served_margins(served_params, model,
+                                           jnp.asarray(seq), len(p))
+        m, rank = np.asarray(m)[:len(out)], np.asarray(rank)[:len(out)]
+        within += int((m <= sz.margin).sum())
+        worst = max(worst, float(m.max()))
+        exact += int((rank == 0).sum())
+        checked += len(out)
+    log(phase, served_tokens=checked, reference_argmax=exact,
+        within_margin=within, margin=sz.margin, worst_margin=round(worst, 4),
+        share_asked=sz.ssm_served_min)
+    if not within >= sz.ssm_served_min * checked:
+        raise AssertionError(
+            f"{phase}: {within} of {checked} served tokens lie within "
+            f"{sz.margin} of the free reference's maximum, "
+            f"{sz.ssm_served_min:.0%} asked")
+    memory_line(phase, jax.local_devices()[0])
+
+
 def phase_zero3(sz: Sizes, seed: int) -> None:
     phase = "zero3x4"
     n = len(jax.devices())
@@ -877,6 +1126,8 @@ def main() -> int:
         phase_moe_server(sz, args.seed)
         gc.collect()
         phase_swa_moe_server(sz, args.seed)
+        gc.collect()
+        phase_ssm_moe_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

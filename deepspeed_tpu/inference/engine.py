@@ -153,6 +153,13 @@ class InferenceEngine:
                 "tokens over the whole sequence) — autoregressive decode "
                 "with it is incoherent; serve experts trained with top-k "
                 "routing (dataclasses.replace(cfg, moe_routing='dropless'))")
+        if getattr(model_config, "mixer_pattern", ()):
+            raise NotImplementedError(
+                "the v1 engine serves stacked attention + FFN layers with one "
+                "K/V cache; a model of one mixer a layer (mixer_pattern: "
+                "state-space layers with per-sequence state) is served by the "
+                "v2 engine (inference/v2), which keeps that state in slots "
+                "beside the paged K/V")
         if len(model_config.layer_period) > 1 or model_config.rope_params:
             raise NotImplementedError(
                 "the v1 engine serves one kind of attention layer with plain "

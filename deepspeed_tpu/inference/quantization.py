@@ -23,14 +23,36 @@ import functools
 from typing import Any, Dict
 
 import jax
+import jax.numpy as jnp
 
 from ..ops.pallas.mixed_gemm import QuantizedWeight, quantize_gemm_weight
 from ..utils.logging import logger
 
 # projection weights inside each layer's attn/mlp/moe dicts (the router, the
 # PR-MoE shared expert and its coefficient are not among the keys)
-_QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate"})
-_QUANT_PARENTS = frozenset({"attn", "mlp", "moe"})
+_QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate",
+                         # a routed MoE's shared expert; a Mamba-2 layer's
+                         # z and xBC projections (models/ssm_hybrid.py; its
+                         # 64-wide dt projection stays bf16)
+                         "sh_w_in", "sh_w_out", "w_z", "w_xbc"})
+_QUANT_PARENTS = frozenset({"attn", "mlp", "moe", "mamba"})
+
+
+def pad_expert_width(w: jax.Array, key: str) -> jax.Array:
+    """A routed expert's matrix ``(..., E, K, N)`` with the experts' inner
+    width zero-padded to the next multiple of 128 where it is wider than 128
+    and no multiple (nemotron_h: 1856 = 29 x 64 → 1920): the grouped kernel
+    tiles N in lane-aligned divisors and K in groups of 128, and 1856 has
+    neither.  The result is exact: a zero column of ``w_in`` / ``w_gate``
+    gives an activation of 0 for silu, gelu and relu² alike, and the zero
+    rows of ``w_out`` it meets add nothing.  The padded bytes are fetched."""
+    axis = -2 if key == "w_out" else -1
+    width = w.shape[axis]
+    if w.ndim < 3 or width <= 128 or width % 128 == 0:
+        return w
+    pad = [(0, 0)] * w.ndim
+    pad[axis] = (0, -width % 128)
+    return jnp.pad(w, pad)
 
 
 def quantize_model_params(params: Dict[str, Any], bits: int = 8,
@@ -39,7 +61,9 @@ def quantize_model_params(params: Dict[str, Any], bits: int = 8,
     """Replace layer projection weights with QuantizedWeight nodes."""
     def walk(tree, parent=None):
         if isinstance(tree, dict):
-            return {k: (quantize(v, bits=bits, group=group)
+            return {k: (quantize(pad_expert_width(v, k) if parent == "moe"
+                                 and k in ("w_in", "w_gate", "w_out") else v,
+                                 bits=bits, group=group)
                         if (parent in _QUANT_PARENTS and k in _QUANT_KEYS
                             and getattr(v, "ndim", 0) >= 2)
                         else walk(v, k))

@@ -34,7 +34,7 @@ from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
                        build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
                        layer_plan, mixed_step_attn_tiles, pool_layers,
-                       sample_rows)
+                       sample_rows, state_arrays)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder,
                      SequenceDescriptor, window_bound)
@@ -53,6 +53,16 @@ def _device_ms(sp_dispatch, sp_wait) -> Optional[float]:
     if sp_wait is None:
         return None
     return (sp_wait.t_end - sp_dispatch.t_start) * 1e3
+
+
+def _refuse_each(asked, because) -> None:
+    """Raise for the first ``(V2Config field, set?, why)`` of ``asked`` that
+    is set: the engine's rule for what a kind of model cannot be combined
+    with is to refuse it by name."""
+    for name, on, why in asked:
+        if on:
+            raise ValueError(
+                f"V2Config.{name} cannot be combined with {because(why)}")
 
 
 class AdmissionError(ValueError):
@@ -210,13 +220,25 @@ class InferenceEngineV2:
         pools = pool_layers(self.model_cfg, self.cfg)
         if self._window:
             self._refuse_with_window()
+        # A model with state layers (Mamba-2) keeps per-SEQUENCE state beside
+        # the paged K/V: one slot a sequence in ``caches["ssm"]`` / ``["conv"]``
+        # (programs.state_arrays), allotted by the one manager.  {} for every
+        # other model, which builds no state array and no slot allocator
+        state = state_arrays(self.model_cfg, self.cfg)
+        if self.model_cfg.mixer_pattern and not (state and pools[0]):
+            raise NotImplementedError(
+                "a mixer_pattern model is served with at least one Mamba-2 "
+                "and one attention layer")
+        if state:
+            self._refuse_with_state()
         max_chunk = self.cfg.max_tokens_per_step
         # one block of each pool reserved as write-scratch for padded tokens
         self.kv = KVCacheManager(
             self.cfg.num_blocks - 1, self.cfg.block_size,
             self.cfg.max_blocks_per_seq,
             window=self._window if len(pools) == 1 else 0,
-            max_chunk=max_chunk)
+            max_chunk=max_chunk,
+            state_slots=self.cfg.max_seqs if state else 0)
         self.kv_win = None
         if len(pools) == 2:
             win_blocks = self.cfg.num_window_blocks or (
@@ -261,7 +283,9 @@ class InferenceEngineV2:
         self.builder = RaggedBatchBuilder(self.cfg.max_tokens_per_step,
                                           self.cfg.max_seqs,
                                           self.cfg.max_blocks_per_seq,
-                                          two_pools=self.kv_win is not None)
+                                          two_pools=self.kv_win is not None,
+                                          state_scratch=self.cfg.max_seqs
+                                          if state else -1)
         dt = jnp.dtype(self.cfg.dtype)
 
         def pool(layers, blocks):
@@ -274,12 +298,25 @@ class InferenceEngineV2:
         if self.kv_win is not None:
             self.caches["k_win"] = pool(pools[1], win_blocks)
             self.caches["v_win"] = pool(pools[1], win_blocks)
+        for name, (shape, dtype) in state.items():
+            self.caches[name] = jnp.zeros(shape, dtype)
+        # SSM state bytes a row reads and writes a step, all state layers
+        self._state_row_bytes = 0
+        if state:
+            shape, dtype = state["ssm"]
+            self._state_row_bytes = 2 * shape[0] * int(
+                np.prod(shape[2:])) * jnp.dtype(dtype).itemsize
+        self._state_step = None
         self._fwd = build_ragged_forward(self.model_cfg, self.cfg)
         self._decode_fwd = build_decode_forward(self.model_cfg, self.cfg)
         # an MoE model's step programs: assignments (rows x top-k) and the
         # rows of the grouped layout they are computed on, by step kind
         self._moe_rows: Dict[str, Tuple[int, int]] = {}
-        if self.model_cfg.num_experts > 0:
+        # the layers that route (a mixer-pattern model: its "E" layers)
+        self._moe_layers = (self.model_cfg.layers_of("E")
+                            if self.model_cfg.mixer_pattern
+                            else self.model_cfg.num_layers)
+        if self.model_cfg.num_experts > 0 and self._moe_layers:
             from ...moe.dropless import padded_rows
 
             E, k = self.model_cfg.num_experts, self.model_cfg.moe_top_k
@@ -374,14 +411,40 @@ class InferenceEngineV2:
              "speculation writes k tokens ahead of the context (and the "
              "draft model's cache shares the target's block tables)"),
         ]
-        for name, on, why in asked:
-            if on:
-                raise ValueError(
-                    f"V2Config.{name} cannot be combined with a model whose "
-                    f"attention layers have an active sliding window "
-                    f"({self._window} < the engine's longest context): {why}, "
-                    f"and a window layer's blocks are freed behind the "
-                    f"window while the sequence runs")
+        _refuse_each(asked, lambda why: (
+            f"a model whose attention layers have an active sliding window "
+            f"({self._window} < the engine's longest context): {why}, and a "
+            f"window layer's blocks are freed behind the window while the "
+            f"sequence runs"))
+
+    def _refuse_with_state(self) -> None:
+        """What shares, moves or rolls back a sequence's past by its K/V
+        blocks alone: with state layers a shared prefix, a demoted block or a
+        rejected speculation would need a SNAPSHOT of the sequence's state at
+        that position, which nothing keeps yet.  Refused by name, never
+        served wrong."""
+        cfg = self.cfg
+        asked = [
+            ("enable_prefix_cache", cfg.enable_prefix_cache,
+             "the prefix cache (with it prefix export / import and "
+             "copy-on-write forks) starts a sequence behind a shared prefix, "
+             "where its state layers have no state to start from"),
+            ("kv_host_pool_mb / kv_host_pool_bytes",
+             cfg.kv_host_pool_mb or cfg.kv_host_pool_bytes,
+             "the host paging tier demotes and promotes prefix blocks"),
+            ("kv_spill_dir", cfg.kv_spill_dir,
+             "the spill tier holds demoted prefix blocks"),
+            ("kv_coldstore_dir", cfg.kv_coldstore_dir,
+             "the cold store holds demoted prefix blocks"),
+            ("spec_mode", cfg.spec_mode != "off",
+             "speculation rolls rejected tokens back by masking their K/V "
+             "(and the draft model's cache has no state arrays); a state "
+             "cannot be rolled back without a snapshot"),
+            ("adapter_slots", cfg.adapter_slots,
+             "the adapter stack is laid out for one attention block a layer"),
+        ]
+        _refuse_each(asked, lambda why: (
+            f"a model that has state layers (mixer_pattern with 'M'): {why}"))
 
     def _quantized(self, raw_params: Any) -> Any:
         """``raw_params`` as this engine serves them: untouched without
@@ -491,6 +554,19 @@ class InferenceEngineV2:
         return sum(m.allocator.free_blocks for m in self._managers)
 
     @property
+    def total_state_slots(self) -> int:
+        """State slots of a model with state layers (0: it has none)."""
+        return self.kv.slots.num_slots if self.kv.slots else 0
+
+    @property
+    def free_state_slots(self) -> int:
+        return self.kv.free_slots
+
+    def drained(self) -> bool:
+        """Every block of every pool and every state slot is back."""
+        return all(m.drained() for m in self._managers)
+
+    @property
     def evictable_blocks(self) -> int:
         """Prefix-tree blocks no live sequence shares (refcount 1)."""
         return self.prefix_cache.evictable_blocks if self.prefix_cache else 0
@@ -538,6 +614,11 @@ class InferenceEngineV2:
             if self.pager.coldstore is not None:
                 stats.update(self.pager.coldstore.stats())
         stats["pinned_blocks"] = self.pinned_blocks
+        # what a full server ran out of: blocks, or (a model with state
+        # layers) state slots
+        stats["free_blocks"] = self.free_blocks
+        stats["state_slots"] = self.total_state_slots
+        stats["state_slots_free"] = self.free_state_slots
         return stats
 
     def prefix_summary(self, max_digests: int = 1024) -> Dict[str, Any]:
@@ -871,10 +952,15 @@ class InferenceEngineV2:
                 "request could never be scheduled")
         if strict:
             if self.num_running + self.num_waiting >= self.cfg.max_seqs:
+                # a model with state layers: a sequence slot is a state slot
                 raise AdmissionError(
                     f"all {self.cfg.max_seqs} sequence slots in use "
                     f"({self.num_running} running, {self.num_waiting} "
-                    "waiting)")
+                    "waiting)" + (
+                        f"; {self.kv.free_slots} of {self.total_state_slots}"
+                        f" state slots free, {self.free_blocks} of "
+                        f"{self.total_blocks} KV blocks free"
+                        if self.kv.slots is not None else ""))
             # evictable prefix-cache blocks count as free: admission must
             # not starve on a warm cache (the scheduler evicts on demand)
             for i, m in enumerate(self._managers):  # every pool has to hold it
@@ -885,7 +971,9 @@ class InferenceEngineV2:
                         f"KV block pool exhausted: request needs "
                         f"{m.reservation(need)} blocks"
                         f"{' of the window layers' if i else ''}, "
-                        f"{avail} unreserved")
+                        f"{avail} unreserved"
+                        + (f" ({self.kv.free_slots} state slots free)"
+                           if self.kv.slots is not None else ""))
         self._uid += 1
         seq = SequenceDescriptor(uid=self._uid, tokens=list(prompt_tokens),
                                  max_new_tokens=max_new_tokens,
@@ -968,10 +1056,14 @@ class InferenceEngineV2:
                  chunk: int) -> bool:
         """Admission: every pool sets the sequence's whole budget aside, or
         none does."""
+        if not self.kv.take_slot(seq):  # a model with state layers
+            return False
         for i, m in enumerate(self._managers):
             if not m.reserve(seq, total_tokens, chunk):
                 for done in self._managers[:i]:
                     done.release(seq)
+                if i == 0 and self.kv.slots is not None:
+                    self.kv.release(seq)  # the slot just taken
                 return False
         return True
 
@@ -1115,6 +1207,24 @@ class InferenceEngineV2:
             + int(seen.sum()) * (L - Lw),
             "trimmed": self._windowed.trimmed}
 
+    def _count_state(self, rows: int, tokens: int, started: int,
+                     lens: Optional["np.ndarray"] = None) -> None:
+        """A state model's step, from what the host holds: the slots taken,
+        the rows that began from zeros, the tokens through the scan and the
+        state bytes the step reads and writes; of a mixed step (``lens``:
+        its rows' tokens) also what the chunked scan walks: the rows of two
+        tokens and more, their tokens and their pieces of a chunk."""
+        self._state_step = {
+            "state_slots_used": self.total_state_slots - self.free_state_slots,
+            "state_rows_started": started, "ssm_tokens": tokens,
+            "ssm_state_bytes": rows * self._state_row_bytes}
+        if lens is not None:
+            many = lens[lens >= 2]
+            chunk = self.model_cfg.mamba_chunk_size
+            self._state_step.update(
+                ssm_scan_rows=len(many), ssm_scan_tokens=int(many.sum()),
+                ssm_scan_pieces=int((-(-many // chunk)).sum()))
+
     def _row_temps(self, temperature: float) -> jax.Array:
         """Effective per-row temperature vector: rows whose request pinned a
         temperature keep it; rows that didn't (temp < 0) inherit the
@@ -1157,6 +1267,9 @@ class InferenceEngineV2:
             self._window_open_blocks()
             self._count_kv(t.ctx[t.active].astype(np.int64),
                            np.ones(int(t.active.sum()), np.int64))
+        if self.kv.slots is not None:
+            self._count_state(int(t.active.sum()), int(t.active.sum()),
+                              int((t.active & (t.ctx == 0)).sum()))
         args = (*self._table_inputs(), self._row_temps(temperature),
                 self._step_rng(rng), jnp.asarray(t.seed),
                 *self._adapter_args())
@@ -1183,7 +1296,7 @@ class InferenceEngineV2:
         if not self._moe_rows:
             return fetched
         n = self.cfg.max_seqs
-        self._moe_stats = (float(fetched[n]) / self.model_cfg.num_layers,
+        self._moe_stats = (float(fetched[n]) / self._moe_layers,
                            int(fetched[n + 1]))
         return fetched[:n]
 
@@ -1281,6 +1394,7 @@ class InferenceEngineV2:
         sub = {"kind": kind, "step": self.steps}  # on the step and its children
         self._moe_stats = None
         self._kv_step = None
+        self._state_step = None
         self._attn_q_slots = None
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
@@ -1303,6 +1417,8 @@ class InferenceEngineV2:
             attrs["moe_experts_hit"], attrs["moe_rows_max"] = self._moe_stats
         if self._attn_q_slots is not None:  # a mixed step ran the device
             attrs["attn_q_slots"] = self._attn_q_slots
+        if self._state_step is not None:  # a state model's step ran the device
+            attrs.update(self._state_step)
         if self._kv_step is not None:  # a windowed model's step ran the device
             m = self._windowed
             attrs["window_blocks_freed"] = \
@@ -1361,6 +1477,13 @@ class InferenceEngineV2:
             jnp.asarray(batch.seq_index), tables,
             jnp.asarray(batch.context_lens), jnp.asarray(batch.logits_rows),
             jnp.asarray(batch.chunk_start), jnp.asarray(batch.chunk_len))
+        if batch.state_slots is not None:  # a model with state layers
+            n = len(picks)
+            # behind the two adapter arguments, which such a model never has
+            batch_args += (None, None, jnp.asarray(batch.state_slots))
+            self._count_state(
+                n, tokens, int((batch.chunk_start[:n] == 0).sum()),
+                batch.chunk_len[:n])
         ad_args = ()
         if self.adapter_stack is not None:
             # batch rows are picks order here (seq_index indexes into the
@@ -1460,8 +1583,10 @@ class InferenceEngineV2:
             # spec mode never bursts: the speculative step is already a
             # multi-token in-graph program with its own budget clamp
             # nor does a windowed pool: its tables are kept step by step
+            # nor a model with state layers: its counters are a step's
             steady = (burst > 1 and self._spec_fwd is None
                       and self._windowed is None
+                      and self.kv.slots is None
                       and not self.waiting and self.running
                       and self._prefilling == 0)
             if steady:

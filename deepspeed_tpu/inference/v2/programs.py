@@ -20,9 +20,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ...models import ssm_hybrid
 from ...models import transformer as tfm
 from ...moe.dropless import serving_moe_block
 from ...ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
+from ...ops.pallas.ssm import ssm_decode_update
 from ...ops.pallas.paged_attention import (PrefillTiles,
                                            paged_decode_attention,
                                            paged_prefill_attention,
@@ -157,7 +159,10 @@ def _moe_step_stats(per_layer):
     rows-per-expert of any layer.  None for a dense model."""
     if per_layer is None:
         return None
-    return jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max()])
+    stats = jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max()])
+    if per_layer.shape[1] > 2:  # cfg.moe_tap_choices: tooling only
+        stats = jnp.concatenate([stats, per_layer[:, 2:].reshape(-1)])
+    return stats
 
 
 def _with_stats(tokens, moe_stats):
@@ -227,6 +232,8 @@ def layer_plan(model_cfg: tfm.TransformerConfig, v2) -> tuple:
 def pool_layers(model_cfg: tfm.TransformerConfig, v2) -> tuple:
     """How many layers each pool of ``layer_plan`` holds: ``(L,)`` for a
     model with one kind of layer, ``(global, windowed)`` with two."""
+    if model_cfg.mixer_pattern:  # one mixer a layer: K/V for the "*" layers
+        return (model_cfg.layers_of("*"),)
     plan = layer_plan(model_cfg, v2)
     periods = model_cfg.num_layers // len(plan)
     return tuple(periods * sum(k.pool == p for k in plan)
@@ -254,9 +261,185 @@ def write_blocks(caches, block_tables, rows, positions, ok, block_size: int):
         for table, (k, _) in zip(tables_of(block_tables), pools_of(caches)))
 
 
+@dataclasses.dataclass(frozen=True)
+class StepRows:
+    """What a model with state layers has to know of a step's rows.  A decode
+    step (``row is None``): rows are the engine's table rows, which ARE the
+    state slots; ``active (R,)`` marks those that take the step and ``fresh``
+    those at position 0.  A mixed step: token ``t`` is the ``offset[t]``-th
+    of row ``row[t]`` (clipped for padding tokens, which ``valid`` excludes),
+    whose ``row_len`` tokens begin at ``row_start``; ``slots (R,)`` is where
+    each row's state lives (unused rows: the scratch slot, the last) and
+    ``fresh`` marks the rows whose chunk starts a sequence: they start from
+    zeros whatever the slot holds."""
+    active: jax.Array
+    fresh: jax.Array
+    row: jax.Array = None
+    offset: jax.Array = None
+    row_start: jax.Array = None
+    row_len: jax.Array = None
+    slots: jax.Array = None
+    valid: jax.Array = None
+
+
+def state_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
+    """Shapes and dtypes of the per-sequence state of a model with Mamba-2
+    layers, or {}: ``ssm (L_M, slots + 1, H, P, N)`` float32 and the conv's
+    kept inputs ``conv (L_M, slots + 1, K - 1, C)`` in the activation dtype
+    (columns on the lanes: a last dimension of K - 1 = 3 would be padded to
+    128 in HBM).  A slot a row of the engine's table, and one scratch slot."""
+    L = model_cfg.layers_of("M")
+    if not L:
+        return {}
+    c = model_cfg
+    return {"ssm": ((L, v2.max_seqs + 1, c.mamba_num_heads, c.mamba_head_dim,
+                     c.mamba_state_size), jnp.float32),
+            "conv": ((L, v2.max_seqs + 1, c.mamba_conv_kernel - 1,
+                      c.mamba_conv_dim), jnp.dtype(v2.dtype))}
+
+
+def _mamba_decode(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
+    """A Mamba-2 layer on one token a slot: the conv over the slot's kept
+    columns and the new one, one recurrence step, both states in place."""
+    R = a_in.shape[0]
+    z, xbc, dt = ssm_hybrid.mamba_in_proj(a_in, p)
+    with jax.named_scope("ssm_conv"):
+        held = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)[:R]
+        cols = jnp.where(rows.fresh[:, None, None], 0, held)
+        out = ssm_hybrid.conv_taps(
+            [cols[:, j] for j in range(cols.shape[1])] + [xbc], p)
+        new = jnp.concatenate([cols[:, 1:], xbc[:, None]], axis=1)
+        conv = conv.at[layer, :R].set(
+            jnp.where(rows.active[:, None, None], new, held))
+    x, B, C, dt, A, D = ssm_hybrid.ssm_inputs(out, dt, p, cfg)
+
+    def pad(a):  # the scratch slot takes no step
+        return jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))
+
+    with jax.named_scope("ssm_scan"):
+        y, ssm = ssm_decode_update(ssm, layer, pad(x), pad(dt), A, pad(B),
+                                   pad(C), D, pad(rows.active),
+                                   pad(rows.fresh))
+    return ssm_hybrid.mamba_out(y[:R], z, p, cfg), ssm, conv
+
+
+def _mamba_mixed(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
+    """A Mamba-2 layer on a mixed step's flat rows: the rows of two tokens
+    and more through the chunked scan, each from its slot's state; the rows
+    of one token (the decode rows) in one dense pass over the slots."""
+    S1 = ssm.shape[1]
+    many = rows.row_len >= 2
+    (z, x, B, C, dt, A, D), y, ssm, kept = ssm_hybrid.mamba_rows(
+        a_in, p, cfg, ssm, conv, layer, rows.row, rows.offset, rows.row_start,
+        rows.row_len, rows.slots, rows.fresh, many)
+    with jax.named_scope("ssm_scan"):
+        one = rows.row_len == 1
+        at = jnp.where(one, rows.slots, S1)  # past the end: dropped
+        first = jnp.clip(rows.row_start, 0, x.shape[0] - 1)
+
+        def by_slot(a, fill=0):
+            return jnp.full((S1,) + a.shape[1:], fill, a.dtype
+                            ).at[at].set(a, mode="drop")
+
+        y1, ssm = ssm_decode_update(
+            ssm, layer, by_slot(x[first]), by_slot(dt[first]), A,
+            by_slot(B[first]), by_slot(C[first]), D,
+            by_slot(one), by_slot(rows.fresh))
+        y = jnp.where((one[rows.row] & rows.valid)[:, None, None],
+                      y1[rows.slots[rows.row]], y)
+    with jax.named_scope("ssm_conv"):
+        conv = conv.at[layer, rows.slots].set(kept)
+    return ssm_hybrid.mamba_out(y, z, p, cfg), ssm, conv
+
+
+#: the one kind of attention layer a mixer-pattern model has: no window, the
+#: one pool, no rotation
+_PLAIN_ATTENTION = LayerKind(0, 0, tfm.RopeParams())
+
+
+def hybrid_layers(params, caches, x, write_at, attend,
+                  model_cfg: tfm.TransformerConfig, valid, rows: StepRows):
+    """``serving_layers`` for a model of one mixer a layer
+    (``model_cfg.mixer_pattern``): norm, the layer's mixer, residual.  The
+    layers run in pattern order as ``ssm_hybrid.segments`` cuts it: each run
+    of a repeated unit (``E M``) is one ``lax.scan`` over its repeats with
+    the unit's layers unrolled inside, so a start traces a handful of bodies
+    whatever the depth.  Each kind's parameters are its own stack, whose
+    quantized projections the kernels read in place at the layer's index in
+    THAT stack; the K/V pool (the attention layers only) and both state
+    arrays ride the carry whole and are updated in place."""
+    if rows is None:
+        raise ValueError("a model with state layers is served by the mixed "
+                         "and decode steps only")
+    blk_ids, offsets = write_at
+    lead = x.shape[:-1]
+    nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
+    stacks = {kind: hoist_quantized(tree)
+              for kind, tree in params["layers"].items()}
+    mamba = _mamba_decode if rows.row is None else _mamba_mixed
+
+    def one_layer(kind, idx, carry):
+        x, k_cache, v_cache, ssm, conv = carry
+        kept, layer_params = stacks[kind]
+        lp = layer_params(jax.tree.map(lambda a: a[idx], kept), idx)
+        a_in = tfm._norm(x, lp["norm"], "rmsnorm", model_cfg.norm_eps)
+        stats = None
+        if kind == "M":
+            out, ssm, conv = mamba(a_in, lp["mamba"], model_cfg, ssm, conv,
+                                   idx, rows)
+        elif kind == "E":
+            out, stats = serving_moe_block(a_in, lp["moe"], model_cfg,
+                                           valid=valid)
+        else:
+            q, k, v = (tfm._lin(a_in, lp["attn"], w, b) for w, b in
+                       (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
+            k, v = k.reshape(lead + (nkv, hd)), v.reshape(lead + (nkv, hd))
+            with jax.named_scope("cache_write"):
+                k_cache = k_cache.at[idx, blk_ids[0], offsets].set(
+                    k.astype(k_cache.dtype))
+                v_cache = v_cache.at[idx, blk_ids[0], offsets].set(
+                    v.astype(v_cache.dtype))
+            o = attend(q.reshape(lead + (nh, hd)), k_cache, v_cache, idx,
+                       _PLAIN_ATTENTION)
+            out = tfm._lin(o.reshape(lead + (nh * hd,)), lp["attn"], "wo",
+                           "bo")
+        return (x + out, k_cache, v_cache, ssm, conv), stats
+
+    carry = (x, caches["k"], caches["v"], caches["ssm"], caches["conv"])
+    done = {kind: 0 for kind in ssm_hybrid.KINDS}
+    moe_stats = []
+    for unit, reps in ssm_hybrid.segments(model_cfg.mixer_pattern):
+        per_unit = {kind: unit.count(kind) for kind in ssm_hybrid.KINDS}
+        base = dict(done)
+
+        def unit_body(carry, rep, unit=unit, per_unit=per_unit, base=base):
+            stats, seen = [], {kind: 0 for kind in ssm_hybrid.KINDS}
+            for kind in unit:
+                idx = base[kind] + rep * per_unit[kind] + seen[kind]
+                seen[kind] += 1
+                carry, st = one_layer(kind, idx, carry)
+                if st is not None:
+                    stats.append(st)
+            return carry, (jnp.stack(stats) if stats else None)
+
+        if reps == 1:
+            carry, st = unit_body(carry, jnp.int32(0))
+        else:
+            carry, st = jax.lax.scan(unit_body, carry,
+                                     jnp.arange(reps, dtype=jnp.int32))
+        if st is not None:
+            moe_stats.append(st.reshape(-1, st.shape[-1]))
+        for kind in ssm_hybrid.KINDS:
+            done[kind] += reps * per_unit[kind]
+    x, k_cache, v_cache, ssm, conv = carry
+    x = tfm._norm(x, params["final_norm"], "rmsnorm", model_cfg.norm_eps)
+    return (x, {"k": k_cache, "v": v_cache, "ssm": ssm, "conv": conv},
+            jnp.concatenate(moe_stats) if moe_stats else None)
+
+
 def serving_layers(params, caches, x, positions, write_at, attend,
                    model_cfg: tfm.TransformerConfig, v2, adapters=None,
-                   slots=None, valid=None):
+                   slots=None, valid=None, rows: StepRows = None):
     """Every layer of the served model over one step's rows, then the final
     norm: the one layer body of the mixed, decode and verify steps.
 
@@ -275,7 +458,14 @@ def serving_layers(params, caches, x, positions, write_at, attend,
     MoE model's stats count.
 
     → (hidden state after the final norm, the pools as ``caches`` names
-    them, an MoE model's per-layer stats ``(L, 2)`` or None)."""
+    them, an MoE model's per-layer stats ``(L, 2)`` or None).
+
+    A model of one mixer a layer (``mixer_pattern``) goes to
+    ``hybrid_layers``, with ``rows`` (``StepRows``) what its state layers
+    need to know of the step; every other model traces what it always did."""
+    if model_cfg.mixer_pattern:
+        return hybrid_layers(params, caches, x, write_at, attend, model_cfg,
+                             valid, rows)
     blk_ids, offsets = write_at
     rows = x.shape[:-1]
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
@@ -417,9 +607,11 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
                                           tables[kind.pool], context_lens,
                                           window=kind.window)
 
+    rows = StepRows(active, active & (position_ids == 0)) \
+        if model_cfg.mixer_pattern else None
     x, caches, moe_stats = serving_layers(
         params, caches, x, position_ids, (blk_ids, position_ids % bs), attend,
-        model_cfg, v2, adapters, row_adapter, active)
+        model_cfg, v2, adapters, row_adapter, active, rows)
     return (tfm.lm_logits(params, x, model_cfg).astype(jnp.float32), caches,
             _moe_step_stats(moe_stats))
 
@@ -443,9 +635,15 @@ def mixed_step_attn_tiles(model_cfg: tfm.TransformerConfig, v2) -> PrefillTiles:
 def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
     bs = v2.block_size
 
+    stateful = bool(model_cfg.mixer_pattern)
+
     def mixed_step(params, caches, token_ids, position_ids, seq_index,
                    block_tables, context_lens, logits_rows, chunk_start,
-                   chunk_len, adapters=None, row_adapter=None):
+                   chunk_len, adapters=None, row_adapter=None,
+                   state_slots=None):
+        # ``adapters`` / ``row_adapter``: the adapter stack and each row's
+        # slot of it; ``state_slots (max_seqs,)``: each row's state slot (a
+        # model with state layers, which is refused adapters)
         x = tfm.embed_tokens(params, token_ids, model_cfg,
                              position_ids=position_ids)  # (T, H)
         # KV write positions: token t → (block_tables[seq, pos//bs], pos%bs);
@@ -478,9 +676,16 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
                                                chunk_start, chunk_len,
                                                window=kind.window)
 
+        rows = None
+        if stateful:
+            rows = StepRows(
+                active=chunk_len > 0, fresh=(chunk_len > 0) & (chunk_start == 0),
+                row=row, offset=jnp.arange(token_ids.shape[0]) - q_start[row],
+                row_start=q_start, row_len=chunk_len, slots=state_slots,
+                valid=valid)
         x, caches, moe_stats = serving_layers(
             params, caches, x, position_ids, (blk_ids, position_ids % bs),
-            attend, model_cfg, v2, adapters, tok_slot, valid)
+            attend, model_cfg, v2, adapters, tok_slot, valid, rows)
         last_hidden = x[logits_rows]  # (max_seqs, H)
         logits = tfm.lm_logits(params, last_hidden, model_cfg)
         # last_hidden rides along for the self-draft speculation heads (the

@@ -115,6 +115,51 @@ class BlockedAllocator:
             raise AssertionError(f"negative demoted count {self.demoted}")
 
 
+class StateSlots:
+    """The slots of per-sequence state (a model with Mamba-2 layers keeps an
+    SSM state and the conv's last inputs a sequence, not a block): a free
+    list beside the block allocator, inside the one ``KVCacheManager``.  A
+    sequence takes a slot when its first chunk is scheduled and gives it back
+    when it retires, is cancelled, times out or is aborted.  What a slot held
+    is never cleared: a step is told which rows start a sequence, and those
+    start from zeros whatever the slot holds.  A slot is also the sequence's
+    row in ``DecodeStateTable``, so a decode step's rows lie in slot order."""
+
+    def __init__(self, num_slots: int):
+        if num_slots <= 0:
+            raise ValueError("num_slots must be positive")
+        self.num_slots = num_slots
+        self._free: List[int] = list(range(num_slots - 1, -1, -1))
+        self._owner: Dict[int, int] = {}  # slot -> uid
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    def take(self, uid: int) -> int:
+        if not self._free:
+            raise MemoryError("state slots exhausted")
+        slot = self._free.pop()
+        self._owner[slot] = uid
+        return slot
+
+    def give(self, slot: int) -> None:
+        if slot not in self._owner:
+            raise ValueError(f"state slot {slot} given back twice")
+        del self._owner[slot]
+        self._free.append(slot)
+
+    def check_consistency(self) -> None:
+        if len(self._free) != len(set(self._free)):
+            raise AssertionError("duplicate slots in the free list")
+        if set(self._free) & set(self._owner):
+            raise AssertionError("a state slot is both free and owned")
+        if len(self._free) + len(self._owner) != self.num_slots:
+            raise AssertionError(
+                f"slot accounting broken: {len(self._owner)} owned + "
+                f"{len(self._free)} free != {self.num_slots} total")
+
+
 @dataclasses.dataclass
 class SequenceDescriptor:
     """Reference: ``sequence_descriptor.py`` — one tracked request."""
@@ -150,6 +195,9 @@ class SequenceDescriptor:
     win_blocks: List[int] = dataclasses.field(default_factory=list)
     win_first_block: int = 0
     win_reserved_blocks: int = 0
+    #: where the sequence's per-sequence state lives (a model with state
+    #: layers; ``StateSlots``), -1 while it holds none
+    state_slot: int = -1
 
     @property
     def cur_len(self) -> int:
@@ -182,12 +230,18 @@ class KVCacheManager:
     what fell behind the window; the table stays indexed by logical block,
     its freed entries stale and never read.  ``chain`` names the
     ``SequenceDescriptor`` fields the pool's chain lives in (``"win_"`` for
-    the second pool of a model with both kinds of layer)."""
+    the second pool of a model with both kinds of layer).
+
+    ``state_slots`` > 0 (a model with state layers) gives the manager the
+    sequences' state slots too (``StateSlots``): ``take_slot`` at admission,
+    ``release`` gives slot and blocks back together, and
+    ``check_consistency`` holds both allocators to their invariants."""
 
     def __init__(self, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int, window: int = 0, max_chunk: int = 0,
-                 chain: str = ""):
+                 chain: str = "", state_slots: int = 0):
         self.allocator = BlockedAllocator(num_blocks)
+        self.slots = StateSlots(state_slots) if state_slots else None
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.window = window
@@ -269,7 +323,34 @@ class KVCacheManager:
         self.trimmed += live - first
         return live - first
 
+    def take_slot(self, seq: SequenceDescriptor) -> bool:
+        """Admission: the sequence's state slot, if one is free."""
+        if self.slots is None or seq.state_slot >= 0:
+            return True
+        if not self.slots.free_slots:
+            return False
+        seq.state_slot = self.slots.take(seq.uid)
+        return True
+
+    @property
+    def free_slots(self) -> int:
+        return self.slots.free_slots if self.slots else 0
+
+    def check_consistency(self) -> None:
+        self.allocator.check_consistency()
+        if self.slots is not None:
+            self.slots.check_consistency()
+
+    def drained(self) -> bool:
+        """Every block and every state slot is back."""
+        return (self.allocator.free_blocks == self.allocator.num_blocks
+                and (self.slots is None
+                     or self.slots.free_slots == self.slots.num_slots))
+
     def release(self, seq: SequenceDescriptor) -> None:
+        if self.slots is not None and seq.state_slot >= 0:
+            self.slots.give(seq.state_slot)
+            seq.state_slot = -1
         self.allocator.free(self.chain(seq)[getattr(seq, self._first):])
         setattr(seq, self._blocks, [])
         setattr(seq, self._first, 0)
@@ -296,6 +377,9 @@ class RaggedBatch:
     uids: List[int]
     #: the window layers' tables of a model with both kinds of layer
     win_tables: Optional[np.ndarray] = None
+    #: a model with state layers: each row's state slot (unused rows: the
+    #: scratch slot), (max_seqs,) int32
+    state_slots: Optional[np.ndarray] = None
 
 
 class DecodeStateTable:
@@ -341,7 +425,11 @@ class DecodeStateTable:
         self._free = list(range(max_seqs - 1, -1, -1))
 
     def admit(self, seq: SequenceDescriptor) -> int:
-        row = self._free.pop()
+        if seq.state_slot >= 0:  # a state slot IS the row (``StateSlots``)
+            row = seq.state_slot
+            self._free.remove(row)
+        else:
+            row = self._free.pop()
         self.row_of[seq.uid] = row
         self.seq_at[row] = seq
         self.active[row] = True
@@ -401,11 +489,13 @@ class DecodeStateTable:
 
 class RaggedBatchBuilder:
     def __init__(self, max_tokens: int, max_seqs: int, max_blocks_per_seq: int,
-                 two_pools: bool = False):
+                 two_pools: bool = False, state_scratch: int = -1):
         self.max_tokens = max_tokens
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
         self.two_pools = two_pools
+        # a model with state layers: the scratch slot unused rows point at
+        self.state_scratch = state_scratch
 
     def build(self, seqs: List[Tuple[SequenceDescriptor, int]]) -> RaggedBatch:
         """seqs: (descriptor, n_new_tokens) pairs already capacity-checked."""
@@ -420,6 +510,8 @@ class RaggedBatchBuilder:
         logits_rows = np.zeros(self.max_seqs, np.int32)
         chunk_start = np.zeros(self.max_seqs, np.int32)
         chunk_len = np.zeros(self.max_seqs, np.int32)
+        state_slots = None if self.state_scratch < 0 else np.full(
+            self.max_seqs, self.state_scratch, np.int32)
         uids = []
         cursor = 0
         for row, (seq, n_new) in enumerate(seqs):
@@ -438,8 +530,10 @@ class RaggedBatchBuilder:
             logits_rows[row] = cursor + len(new_tokens) - 1
             chunk_start[row] = start
             chunk_len[row] = len(new_tokens)
+            if state_slots is not None:
+                state_slots[row] = seq.state_slot
             cursor += len(new_tokens)
             uids.append(seq.uid)
         return RaggedBatch(token_ids, position_ids, seq_index, block_tables,
                            context_lens, logits_rows, chunk_start, chunk_len,
-                           cursor, len(seqs), uids, win_tables)
+                           cursor, len(seqs), uids, win_tables, state_slots)

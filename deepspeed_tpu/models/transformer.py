@@ -65,13 +65,17 @@ class TransformerConfig:
     # architecture switches
     # rmsnorm (llama) | layernorm (gpt2) | gemma_rmsnorm ((1+w) scaling)
     norm: str = "rmsnorm"
-    activation: str = "silu"  # silu => SwiGLU; gelu => GELU MLP; relu (opt)
+    # silu => SwiGLU; gelu => GELU MLP; relu (opt); relu2 => relu(x)**2,
+    # ungated (nemotron_h's experts)
+    activation: str = "silu"
     # gated two-branch MLP with a non-silu activation (gemma's gated gelu);
     # silu implies gated regardless
     gated_mlp: bool = False
     # multiply embedding output by sqrt(hidden_size) (gemma normalizer)
     embed_scale_by_sqrt_dim: bool = False
-    position: str = "rope"  # rope (llama) | learned (gpt2) | alibi (bloom)
+    # rope (llama) | learned (gpt2) | alibi (bloom) | none (nemotron_h's
+    # attention layers: the state-space layers carry the order)
+    position: str = "rope"
     tie_embeddings: bool = True
     # LayerNorm right after the embedding lookup (bloom
     # word_embeddings_layernorm)
@@ -116,6 +120,36 @@ class TransformerConfig:
     # "shared expert" MLP runs beside the MoE and a learned 2-way softmax
     # coefficient mixes the two outputs per token
     moe_use_residual: bool = False
+    # the router's kind: "softmax" over all experts, or "sigmoid" scores with
+    # a per-expert correction bias added for the CHOICE only (nemotron_h,
+    # DeepSeek-V3's rule with one group): the chosen experts' unbiased scores
+    # are renormalised (``moe_norm_topk``) and scaled by ``moe_routed_scaling``
+    moe_router: str = "softmax"
+    moe_routed_scaling: float = 1.0
+    # width of a shared expert that every token passes beside the routed
+    # ones, its output added unweighted (0: none)
+    moe_shared_size: int = 0
+    # test and benchmark tooling (benchmark/routing_tap.py): a step program
+    # BUILT for a config with this set carries every row's chosen experts
+    # out behind ``routed_ffn``'s two stats.  A comparison of logits has to
+    # hold a float32 reference to the choices the program made where seeded
+    # random routers tie.  A served model's config leaves it False
+    moe_tap_choices: bool = False
+    # ONE mixer a layer, the kind of each by ``mixer_pattern`` (nemotron_h's
+    # ``hybrid_override_pattern``): "M" a Mamba-2 layer, "E" an MoE layer,
+    # "*" an attention layer; ``num_layers`` long, and NOT one period
+    # repeated.  Empty: every layer is attention + FFN.  The parameters are
+    # stacked by kind (models/ssm_hybrid.py)
+    mixer_pattern: Tuple[str, ...] = ()
+    # Mamba-2 sizes: d_inner = heads x head_dim, B and C in ``groups`` groups
+    # of ``state`` each, a depthwise causal conv of ``conv_kernel`` taps over
+    # [x | B | C]; ``chunk``: how the scan is blocked, not what it computes
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_state_size: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
     # dtypes
     dtype: str = "bfloat16"  # compute dtype
     param_dtype: str = "float32"  # master weights
@@ -145,6 +179,20 @@ class TransformerConfig:
         # hashable whatever the caller handed in (a JSON list): the config
         # is a jit memo key
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "mixer_pattern", tuple(self.mixer_pattern))
+        if self.mixer_pattern:
+            if set(self.mixer_pattern) - {"M", "E", "*"}:
+                raise ValueError(f"mixer_pattern holds kinds other than 'M', "
+                                 f"'E' and '*': {self.mixer_pattern}")
+            if len(self.mixer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"mixer_pattern names {len(self.mixer_pattern)} layers, "
+                    f"num_layers is {self.num_layers}")
+            if self.layer_types or self.sliding_window:
+                raise ValueError("mixer_pattern with window layers is not "
+                                 "something the program computes")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
         object.__setattr__(self, "rope_params",
                            tuple((k, r) for k, r in self.rope_params))
         if self.layer_types:
@@ -180,6 +228,20 @@ class TransformerConfig:
         return dict(self.rope_params).get(kind) or \
             RopeParams(theta=self.rope_theta)
 
+    def layers_of(self, kind: str) -> int:
+        """Layers of mixer kind ``kind`` ("M", "E", "*") in the pattern."""
+        return sum(k == kind for k in self.mixer_pattern)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the conv runs over: x, B and C side by side."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_state_size)
+
     @property
     def rot_dim(self) -> int:
         """Rotated head dims (partial rotary rounds down to even)."""
@@ -192,6 +254,10 @@ class TransformerConfig:
         return 6 * n_params + attn
 
     def num_params(self, include_embed: bool = True) -> int:
+        if self.mixer_pattern:
+            from .ssm_hybrid import num_params
+
+            return num_params(self, include_embed)
         h, f, v, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         kvh = self.kv_heads * self.head_dim
         qh = self.num_heads * self.head_dim  # != h with head_dim_override
@@ -254,8 +320,35 @@ PRESETS: Dict[str, Dict[str, Any]] = {
             beta_slow=1.0, attention_factor=1.2772588722239782)),),
         num_experts=64, moe_top_k=8, moe_norm_topk=True,
         moe_routing="dropless", attn_impl="flash"),
+    # nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 as published: 31.58 B, about
+    # 3.2 B active; one mixer a layer by hybrid_override_pattern (23 Mamba-2,
+    # 23 MoE, 6 attention); intermediate_size is the width of ONE expert
+    "nemotron3-nano-30b-a3b": dict(
+        vocab_size=131072, hidden_size=2688, intermediate_size=1856,
+        num_layers=52, num_heads=32, num_kv_heads=2, head_dim_override=128,
+        max_seq_len=262144, rope_theta=10000.0, norm_eps=1e-5,
+        tie_embeddings=False, position="none", activation="relu2",
+        mixer_pattern=tuple(
+            "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+        mamba_num_heads=64, mamba_head_dim=64, mamba_n_groups=8,
+        mamba_state_size=128, mamba_conv_kernel=4, mamba_chunk_size=128,
+        num_experts=128, moe_top_k=6, moe_norm_topk=True,
+        moe_router="sigmoid", moe_routed_scaling=2.5, moe_shared_size=3712,
+        moe_routing="dropless", attn_impl="flash"),
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
+    # nemotron_h's three kinds of layer at toy widths: a pattern that is no
+    # period repeated, fewer groups than heads, widths (hidden, an expert)
+    # that are multiples of 64 and not of 128
+    "tiny-nemotron3": dict(
+        vocab_size=256, hidden_size=192, intermediate_size=192, num_layers=9,
+        num_heads=4, num_kv_heads=2, head_dim_override=32, max_seq_len=512,
+        norm_eps=1e-5, tie_embeddings=False, position="none",
+        activation="relu2", mixer_pattern=tuple("MEM*EMEME"),
+        mamba_num_heads=8, mamba_head_dim=16, mamba_n_groups=2,
+        mamba_state_size=32, mamba_conv_kernel=4, mamba_chunk_size=16,
+        num_experts=8, moe_top_k=3, moe_norm_topk=True, moe_router="sigmoid",
+        moe_routed_scaling=2.5, moe_shared_size=128, moe_routing="dropless"),
     "tiny-moe": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                      num_heads=4, max_seq_len=128, num_experts=4, moe_top_k=2),
     # OLMoE's block at toy widths (tests, the benchmark's rehearsal)
@@ -302,7 +395,12 @@ def _dense_init(key, shape, in_axis_size, dtype):
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Create the parameter pytree. Per-layer weights are stacked on a leading
-    ``layers`` axis so the forward pass can ``lax.scan`` over them."""
+    ``layers`` axis so the forward pass can ``lax.scan`` over them (a model
+    with ``mixer_pattern``: one stack a kind of layer, models/ssm_hybrid.py)."""
+    if cfg.mixer_pattern:
+        from .ssm_hybrid import init_params as init_hybrid
+
+        return init_hybrid(rng, cfg)
     pd = jnp.dtype(cfg.param_dtype)
     h, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.kv_heads
@@ -377,6 +475,10 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
 
     Pass ``params`` for HF-converted trees that carry linear biases
     (qwen2/opt/gpt-neox …): bias leaves get matching axes entries."""
+    if cfg.mixer_pattern:
+        from .ssm_hybrid import param_axes as hybrid_axes
+
+        return hybrid_axes(cfg)
     ln = {"scale": ("layers", "embed")}
     if cfg.norm == "layernorm":
         ln = {"scale": ("layers", "embed"), "bias": ("layers", "embed")}
@@ -740,6 +842,8 @@ def apply_activation(x, kind: str):
         return jax.nn.gelu(x, approximate=True)
     if kind == "silu":
         return jax.nn.silu(x)
+    if kind == "relu2":  # nemotron_h's experts
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown activation {kind!r}")
 
 
@@ -782,6 +886,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     ``attn_fn``/``moe_fn`` are injection points for Pallas flash attention,
     Ulysses/ring sequence parallelism and expert-parallel MoE dispatch.
     """
+    if cfg.mixer_pattern:
+        from .ssm_hybrid import forward_hidden as hybrid_hidden
+
+        return hybrid_hidden(params, tokens, cfg, attn_fn=attn_fn)
     dt = jnp.dtype(cfg.dtype)
     if cfg.position == "alibi" and cfg.attn_impl != "xla":
         # the additive logit bias rides the einsum path only; the Pallas

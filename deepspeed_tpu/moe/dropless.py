@@ -11,14 +11,17 @@ Four stages, each under a named scope a device trace can find:
 
 ``moe_route``     float32 softmax of the router logits, top-k, the weights by
                   the config's rule (``moe_norm_topk``: renormalised to sum 1,
-                  or the raw probabilities);
+                  or the raw probabilities); or sigmoid scores with a
+                  correction bias for the choice (``moe_router``);
 ``moe_dispatch``  tokens scattered once into the tile-aligned grouped layout
                   (``ops/pallas/grouped_matmul.tile_aligned_layout``), whose
                   ``tile_m`` follows the step's assignments (:func:`moe_tile_m`);
 ``moe_experts``   the expert FFN as three grouped GEMMs: bf16
                   (``grouped_matmul``) or int8 codes dequantized in the kernel
                   (``grouped_mixed_gemm``), by the weight's type;
-``moe_combine``   weighted expert outputs gathered back and summed per token.
+``moe_combine``   weighted expert outputs gathered back and summed per token;
+``moe_shared``    a shared expert (``sh_w_in`` / ``sh_w_out``), where the model
+                  has one: every row through ``mixed_gemm``, added after.
 
 Training (``moe/layer.py`` with ``moe_routing='dropless'``) calls
 :func:`dropless_moe_block_with_losses`, which is the same four stages plus
@@ -37,7 +40,6 @@ from ..ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
 from ..ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
 
 _MIN_TILE_M, _MAX_TILE_M = 16, 512
-
 
 def moe_tile_m(assignments: int, num_experts: int) -> int:
     """Rows of one M tile of the grouped layout, from the two static sizes of
@@ -72,11 +74,27 @@ class Routing(NamedTuple):
     logits: jax.Array  # (N, E) float32
 
 
-def route(x2: jax.Array, router: jax.Array, cfg) -> Routing:
-    """``x2 (N, H)`` → top-k experts and their weights, in float32."""
+def route(x2: jax.Array, router: jax.Array, cfg,
+          bias: Optional[jax.Array] = None) -> Routing:
+    """``x2 (N, H)`` → top-k experts and their weights, in float32, by the
+    config's rule: softmax over all experts, or (``moe_router: "sigmoid"``)
+    sigmoid scores, the choice made on the scores plus the per-expert
+    correction ``bias (E,)``, the weights the chosen experts' UNBIASED scores,
+    renormalised (``moe_norm_topk``) and scaled by ``moe_routed_scaling``
+    (``probs`` are then the scores)."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        if getattr(cfg, "moe_router", "softmax") == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            choice = scores if bias is None \
+                else scores + bias.astype(jnp.float32)
+            _, experts = jax.lax.top_k(choice, cfg.moe_top_k)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+            if getattr(cfg, "moe_norm_topk", True):
+                weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights * cfg.moe_routed_scaling
+            return Routing(weights, experts.astype(jnp.int32), scores, logits)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, cfg.moe_top_k)
         if getattr(cfg, "moe_norm_topk", True):
@@ -114,7 +132,8 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     dt = x2.dtype
     T = N * k
     tile_m = moe_tile_m(T, E)
-    r = routing if routing is not None else route(x2, p["router"], cfg)
+    r = routing if routing is not None else route(
+        x2, p["router"], cfg, p.get("router_bias"))
 
     with jax.named_scope("moe_dispatch"):
         expert_flat = r.experts.reshape(T)
@@ -130,6 +149,8 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
                 length=E)
         stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]
                           ).astype(jnp.int32)
+        if getattr(cfg, "moe_tap_choices", False):  # tooling only
+            stats = jnp.concatenate([stats, expert_flat])
 
     with jax.named_scope("moe_experts"):
         def gmm(a, key):
@@ -138,6 +159,8 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
 
         if "w_gate" in p:
             hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
+        elif getattr(cfg, "activation", "") == "relu2":  # ungated
+            hmid = jnp.square(jax.nn.relu(gmm(xs, "w_in")))
         else:
             hmid = jax.nn.gelu(gmm(xs, "w_in"), approximate=True)
         ys = gmm(hmid, "w_out")  # (M_pad, H)
@@ -159,6 +182,14 @@ def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
     x2 = x.reshape(-1, x.shape[-1])
     y, stats = routed_ffn(x2, p, cfg,
                           valid=None if valid is None else valid.reshape(-1))
+    if "sh_w_in" in p:
+        # the shared expert: every row, unweighted, beside the routed ones
+        from ..models.transformer import _lin, apply_activation
+
+        with jax.named_scope("moe_shared"):
+            mid = apply_activation(_lin(x2, p, "sh_w_in", "sh_b_in"),
+                                   cfg.activation)
+            y = y + _lin(mid, p, "sh_w_out", "sh_b_out")
     y = y.reshape(x.shape)
     if getattr(cfg, "moe_use_residual", False):
         from .layer import _prmoe_combine

@@ -47,21 +47,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...observability.trace import tracer
 from . import backend
-from .mixed_gemm import (_CHUNK_N, _VMEM_LIMIT, GemmTiles, QuantizedWeight,
-                         dequantize_gemm_weight, layer_of_stack,
-                         pick_gemm_tiles)
+from .mixed_gemm import (_VMEM_LIMIT, GemmTiles, QuantizedWeight,
+                         column_chunks, dequantize_gemm_weight,
+                         layer_of_stack, pick_gemm_tiles)
 
 
 def pick_grouped_tiles(rows: int, tile_m: int, k: int, n: int, bits: int,
                        group: int, x_itemsize: int = 2
                        ) -> Optional[GemmTiles]:
-    """``pick_gemm_tiles`` for ``rows`` laid out in M tiles of ``tile_m``;
-    None unless the tile it picks is int8 and holds all of K (see the module
-    text) on rows that tile."""
+    """``pick_gemm_tiles`` for ``rows`` laid out in M tiles of ``tile_m``:
+    the widest int8 tile that holds all of K (see the module text) on rows
+    that tile, or None.  (An expert width that is no multiple of 128, such
+    as 1856 = 29 x 64, has no lane-aligned ``tn``: the quantizer stores such
+    a width zero-padded to the next multiple, ``inference/quantization.py``.)"""
     if bits != 8 or rows % tile_m or tile_m % 16:
         return None
-    tiles = pick_gemm_tiles(rows, k, n, bits, group, x_itemsize, tm=tile_m)
-    return tiles if tiles is not None and tiles.tk == k else None
+    return pick_gemm_tiles(rows, k, n, bits, group, x_itemsize, tm=tile_m,
+                           whole_k=True)
 
 
 def _kernel(tile_group_ref, used_ref, layer_ref, x_ref, c_ref, s_ref, o_ref,
@@ -77,8 +79,7 @@ def _kernel(tile_group_ref, used_ref, layer_ref, x_ref, c_ref, s_ref, o_ref,
         # static loops: every slice is a static, tile-aligned window
         for gi in range(s_ref.shape[0]):
             x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
-            for c0 in range(0, tn, _CHUNK_N):
-                cols = slice(c0, min(c0 + _CHUNK_N, tn))
+            for cols in column_chunks(tn):
                 c = c_ref[gi * group:(gi + 1) * group, cols]
                 w = (c.astype(jnp.float32) * s_ref[gi:gi + 1, cols]
                      ).astype(jnp.bfloat16)

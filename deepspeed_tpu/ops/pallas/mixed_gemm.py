@@ -172,6 +172,17 @@ _MIN_STEPS = 4
 _CHUNK_N = 512
 
 
+def column_chunks(tn: int):
+    """The column windows a grid step dequantizes one at a time: ``_CHUNK_N``
+    wide, the last as wide as what is left; a tail of one lane tile (128)
+    joins the window before it (a tile 640 or 2688 wide: Mosaic has no
+    one-tile load of a scale row at a dynamic row)."""
+    edges = list(range(0, tn, _CHUNK_N)) + [tn]
+    if len(edges) > 2 and edges[-1] - edges[-2] <= 128:
+        del edges[-2]
+    return [slice(a, b) for a, b in zip(edges, edges[1:])]
+
+
 def _code_rows(k_rows: int, bits: int) -> int:
     """Byte rows of codes that hold ``k_rows`` rows of K: int8 1:1, int4 two
     codes a byte, fp6 four codes in three bytes."""
@@ -190,8 +201,8 @@ class GemmTiles:
 
 @functools.lru_cache(maxsize=None)
 def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
-                    x_itemsize: int = 2, tm: Optional[int] = None
-                    ) -> Optional[GemmTiles]:
+                    x_itemsize: int = 2, tm: Optional[int] = None,
+                    whole_k: bool = False) -> Optional[GemmTiles]:
     """The ``(tm, tn, tk)`` tile of an ``(m, k) @ (k, n)`` mixed GEMM (``m``
     already padded to the sublane multiple), or None when the shapes do not
     tile (→ the dequantize-then-matmul fallback).
@@ -205,7 +216,9 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
     the call at ``_MIN_STEPS`` steps or more.
 
     A caller whose rows already lie in M tiles (the grouped GEMM of MoE
-    experts) passes its own ``tm``, a divisor of ``m``; the rest follows."""
+    experts) passes its own ``tm``, a divisor of ``m``; the rest follows.
+    With ``whole_k`` (the grouped GEMM's rule: a step holds all of K) ``tn``
+    walks down until the tile is all of K deep; None if none is."""
     # int4 packs two codes per byte (group must be even); fp6 packs 4 K-rows
     # per 3 byte-rows (group must divide by 4, and the byte-row tile must be
     # sublane-aligned); int8 has no pack constraint
@@ -230,6 +243,8 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
                  if groups % g == 0 and g * group * tn <= _TILE_WEIGHTS
                  and (m // tm) * (n // tn) * (groups // g) >= _MIN_STEPS],
                 default=1)
+        if whole_k and g != groups:
+            continue  # a narrower tile may hold all of K
         tk = g * group
         codes = _code_rows(tk, bits) * tn
         chunk = min(tn, _CHUNK_N)
@@ -289,8 +304,7 @@ def _mixed_gemm_kernel(lay_ref, x_ref, c_ref, s_ref, o_ref, acc_ref, *,
     # static loops: every slice of the codes is a static, tile-aligned window
     for gi in range(g):
         x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
-        for c0 in range(0, tn, _CHUNK_N):
-            cols = slice(c0, min(c0 + _CHUNK_N, tn))
+        for cols in column_chunks(tn):
             c = c_ref[gi * rows:(gi + 1) * rows, cols]
             if bits == 4:
                 c = _unpack_int4(c)

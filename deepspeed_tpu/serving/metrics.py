@@ -159,6 +159,9 @@ class ServingMetrics:
             "demotions": 0, "promotions": 0,
             "promote_wait_ms": 0.0, "rehydrated_blocks": 0,
             "gc_spill_files": 0,
+            # what admission counts: free blocks, and the per-sequence state
+            # slots of a model with state layers (0 of 0 for every other)
+            "free_blocks": 0, "state_slots": 0, "state_slots_free": 0,
         }
         # crash-durable cold tier mirror (manifest-verified checkpoint
         # store below the host pool, inference/v2/coldstore.py; summed

@@ -68,6 +68,11 @@ class _Completions(http.server.BaseHTTPRequestHandler):
     def do_POST(self):
         body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
         self.arrived.append(body["prompt"])
+        # a server that answers at once has eight client threads and their
+        # handlers spin on one interpreter lock, and a handler can then wait
+        # longer for it than the 20 ms between two clients' first requests
+        # (the order noted here swapped one run in three): answer in 5 ms
+        time.sleep(0.005)
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream")
         self.end_headers()
@@ -87,7 +92,12 @@ class _Completions(http.server.BaseHTTPRequestHandler):
 
 def test_ordered_start_sends_first_requests_in_client_order():
     _Completions.arrived = []
-    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _Completions)
+    class Server(http.server.ThreadingHTTPServer):
+        # past the default backlog of 5 a connection's SYN is dropped and
+        # its client stalls a second, past the window's end
+        request_queue_size = 128
+
+    server = Server(("127.0.0.1", 0), _Completions)
     threading.Thread(target=server.serve_forever, daemon=True).start()
     try:
         t_open = time.monotonic() + 0.5
