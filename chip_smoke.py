@@ -372,6 +372,7 @@ def reference_margins(params, cfg, sequences: List[List[int]]):
 def phase_server(sz: Sizes, seed: int, quantize_bits: int,
                  check_kernels: bool = True) -> None:
     from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.serving.balancer import ReplicaPool
     from deepspeed_tpu.serving.config import ServingConfig
     from deepspeed_tpu.serving.metrics import ServingMetrics
@@ -379,6 +380,7 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
 
     phase = f"server-int{quantize_bits}" if quantize_bits else "server"
     device = jax.devices()[0]
+    tracer.clear()  # this phase's steps and kernel events alone
     if quantize_bits:
         layers, jobs = sz.quant_layers, sz.quant_requests
         why = ("int8 codes are 0.22 GB a layer, so the full depth is 7.5 GB; "
@@ -502,6 +504,7 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
         check_prefill_tiles(phase, [
             e["args"] for e in _get_json(port, "/debug/trace")["traceEvents"]
             if e["name"] == "kernel/paged_attention_prefill_tiles"])
+        check_step_copies(phase)
     finally:
         pool.drain(scfg.drain_timeout_s)
         server.shutdown()
@@ -597,6 +600,7 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     check_prefill_tiles(phase, [
         s.attrs for s in tracer.spans()
         if s.name == "kernel/paged_attention_prefill_tiles"])
+    check_step_copies(phase)
     fallen = [a for a in events if "fallback" in a]
     sliced = [a for a in events if a.get("layers") == 0]
     if check_kernels and (fallen or sliced or not grouped):
@@ -685,6 +689,7 @@ def phase_swa_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     check_prefill_tiles(phase, [
         a for name, a in events
         if name == "kernel/paged_attention_prefill_tiles"])
+    check_step_copies(phase)
     fallen = [e for e in events if "fallback" in e[1]]
     if check_kernels and (fallen or len(windows) < 2):
         raise AssertionError(f"{phase}: kernels fallen back: {fallen}; "
@@ -751,6 +756,36 @@ def check_prefill_tiles(phase: str, events: list) -> None:
         raise AssertionError(
             f"{phase}: {len(events)} kernel/paged_attention_prefill_tiles "
             f"events, fallen back: {fallen}")
+
+
+def check_step_copies(phase: str) -> None:
+    """The ``engine/step`` spans of the phase (it cleared the ring at its
+    start): every decode and every mixed step that ran the device made ONE
+    host-to-device copy before its program was called (``h2d_copies``,
+    ``h2d_bytes``: PERF.md section 3); the median ``engine/h2d`` by kind of
+    step is printed beside it, in ms."""
+    import statistics
+
+    from deepspeed_tpu.observability.trace import tracer
+
+    spans = tracer.spans()
+    steps = [s.attrs for s in spans if s.name == "engine/step"
+             and s.attrs["kind"] in ("decode", "mixed")
+             and "device_ms" in s.attrs]
+    by_kind: Dict[str, List[float]] = {}
+    for s in spans:
+        if s.name == "engine/h2d":
+            by_kind.setdefault(s.attrs["kind"], []).append(s.duration_s * 1e3)
+    copies = sorted({(a["kind"], a.get("h2d_copies"), a.get("h2d_bytes"))
+                     for a in steps})
+    log(phase, steps_on_the_device=len(steps), kind_h2d_copies_bytes=copies,
+        engine_h2d_ms_p50={k: round(statistics.median(v), 3)
+                           for k, v in sorted(by_kind.items())})
+    kinds = {a["kind"] for a in steps}
+    if kinds != {"decode", "mixed"} or any(c != 1 for _, c, _ in copies):
+        raise AssertionError(
+            f"{phase}: wanted decode and mixed steps of one host-to-device "
+            f"copy each; ran (kind, h2d_copies, h2d_bytes) {copies}")
 
 
 def check_mixed_gemm(phase: str, params, cfg) -> None:
@@ -967,6 +1002,7 @@ def phase_ssm_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     check_prefill_tiles(phase, [
         a for name, a in events
         if name == "kernel/paged_attention_prefill_tiles"])
+    check_step_copies(phase)
     fallen = [e for e in events if "fallback" in e[1]]
     if check_kernels and fallen:
         raise AssertionError(f"{phase}: kernels fallen back: {fallen}")

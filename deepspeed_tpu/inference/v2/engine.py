@@ -33,11 +33,11 @@ from ...observability.trace import tracer
 from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
                        build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
-                       layer_plan, mixed_step_attn_tiles, pool_layers,
-                       sample_rows, state_arrays)
+                       build_unpack, layer_plan, mixed_step_attn_tiles,
+                       pool_layers, sample_rows, state_arrays)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
-                     RaggedBatchBuilder,
-                     SequenceDescriptor, window_bound)
+                     RaggedBatchBuilder, SequenceDescriptor, StepLayout,
+                     decode_layout, window_bound)
 from .spec import build_draft_spec_step, build_self_draft_step
 
 
@@ -285,7 +285,19 @@ class InferenceEngineV2:
                                           self.cfg.max_blocks_per_seq,
                                           two_pools=self.kv_win is not None,
                                           state_scratch=self.cfg.max_seqs
-                                          if state else -1)
+                                          if state else -1,
+                                          adapters=self.adapter_stack
+                                          is not None)
+        # A step's host inputs reach the device in ONE copy: each kind of
+        # step lays them in one int32 buffer (the decode step's here, the
+        # mixed step's the builder's) and a small program takes it apart
+        # there (``_to_device``).  ``_h2d``: the copies and bytes of the step
+        # under way, for its span
+        self._decode_layout = decode_layout(
+            self.cfg.max_seqs, self.cfg.max_blocks_per_seq,
+            two_pools=self.kv_win is not None,
+            adapters=self.adapter_stack is not None)
+        self._h2d = None
         dt = jnp.dtype(self.cfg.dtype)
 
         def pool(layers, blocks):
@@ -345,6 +357,7 @@ class InferenceEngineV2:
         self.burst_steps = 0  # telemetry: multi-token burst programs run
         self._uid = 0
         self._rng = jax.random.PRNGKey(0)
+        self._step_key = None  # the next step's key, once split off
         # -- speculative decoding (inference/v2/spec.py) ---------------
         mode = self.cfg.spec_mode
         if mode not in ("off", "draft", "self_draft"):
@@ -1151,8 +1164,9 @@ class InferenceEngineV2:
         return True
 
     def _table_inputs(self):
-        """Decode dispatch inputs straight off the SoA table (padded static
-        shapes; inactive rows carry ctx 0)."""
+        """The speculative and the burst decode's inputs straight off the
+        SoA table, an array each (padded static shapes; inactive rows carry
+        ctx 0); the decode step's go in one buffer (``_pack_decode``)."""
         t = self.table
         ctx_in = ((t.ctx + 1) * t.active).astype(np.int32)
         tables = jnp.asarray(t.block_tables)
@@ -1225,19 +1239,70 @@ class InferenceEngineV2:
                 ssm_scan_rows=len(many), ssm_scan_tokens=int(many.sum()),
                 ssm_scan_pieces=int((-(-many // chunk)).sum()))
 
-    def _row_temps(self, temperature: float) -> jax.Array:
+    def _row_temps(self, temperature: float) -> "np.ndarray":
         """Effective per-row temperature vector: rows whose request pinned a
         temperature keep it; rows that didn't (temp < 0) inherit the
         step-level scalar."""
         t = self.table
-        return jnp.asarray(np.where(t.temp >= 0.0, t.temp,
-                                    np.float32(temperature))
-                           .astype(np.float32))
+        return np.where(t.temp >= 0.0, t.temp,
+                        np.float32(temperature)).astype(np.float32)
+
+    def _pack_decode(self, temperature: float) -> "np.ndarray":
+        """The decode step's host inputs off the SoA table (padded static
+        shapes; inactive rows carry ctx 0), in their one buffer."""
+        t = self.table
+        buf = self._decode_layout.new()
+        v = self._decode_layout.views(buf)
+        v["token_ids"][:] = t.next_tok
+        v["position_ids"][:] = t.ctx
+        np.multiply(t.ctx + 1, t.active, out=v["context_lens"])
+        v["temps"][:] = self._row_temps(temperature)
+        v["seeds"][:] = t.seed
+        v["block_tables"][:] = t.block_tables
+        if t.win_tables is not None:
+            v["win_tables"][:] = t.win_tables
+        if self.adapter_stack is not None:
+            v["row_adapter"][:] = t.adapter
+        return buf
+
+    def _to_device(self, layout: StepLayout, buf: "np.ndarray"
+                   ) -> Dict[str, jax.Array]:
+        """The step's one host-to-device copy: ``buf`` goes to the program
+        that takes it apart on the device as it is, and the call makes the
+        copy (one trip into the runtime, not two) → its fields as device
+        arrays."""
+        copies, nbytes = self._h2d or (0, 0)
+        self._h2d = (copies + 1, nbytes + buf.nbytes)
+        return build_unpack(layout)(buf)
+
+    @staticmethod
+    def _tables(fields: Dict[str, jax.Array]):
+        """A step program's ``block_tables``: a table a pool."""
+        if "win_tables" in fields:
+            return fields["block_tables"], fields["win_tables"]
+        return fields["block_tables"]
 
     def _step_rng(self, rng: Optional[jax.Array]) -> jax.Array:
-        if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
+        """The step's key: the caller's, or the next of the engine's stream
+        (``rng_state -> (rng_state', step_key)``, the key carried on the
+        device from step to step).  A decode or mixed step has had it split
+        off while the step before was on the device (``_split_ahead``)."""
+        if rng is not None:
+            return rng
+        if self._step_key is None:
+            self._split_ahead()
+        rng, self._step_key = self._step_key, None
         return rng
+
+    def _split_ahead(self) -> None:
+        """Split the next step's key off the engine's, unless it is held
+        already: called once a step's program is under way, so host and
+        device do it behind that program and the next step finds its key
+        there.  The eager ``jax.random.split`` the engine always made (a
+        program and two slices): jitted as one program it lowers threefry
+        anew at every start, 0.45 s of set-up on the chip's host."""
+        if self._step_key is None:
+            self._rng, self._step_key = jax.random.split(self._rng)
 
     def _advance_rows(self, sel: "np.ndarray") -> "np.ndarray":
         """Vectorized post-decode bookkeeping. ``sel``: (k, ns) new tokens
@@ -1270,12 +1335,17 @@ class InferenceEngineV2:
         if self.kv.slots is not None:
             self._count_state(int(t.active.sum()), int(t.active.sum()),
                               int((t.active & (t.ctx == 0)).sum()))
-        args = (*self._table_inputs(), self._row_temps(temperature),
-                self._step_rng(rng), jnp.asarray(t.seed),
-                *self._adapter_args())
+        f = self._to_device(self._decode_layout,
+                            self._pack_decode(temperature))
+        args = (f["token_ids"], f["position_ids"], self._tables(f),
+                f["context_lens"], f["temps"], self._step_rng(rng),
+                f["seeds"])
+        if self.adapter_stack is not None:
+            args += (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         toks, self.caches = self._decode_fwd(self.params, self.caches, *args)
+        self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp_dispatch)
         sp_wait = tracer.begin("engine/wait", **sub)
         sampled = self._split_stats(np.asarray(toks))
@@ -1315,7 +1385,7 @@ class InferenceEngineV2:
         rng = self._step_rng(rng)
         next_tok, ctx, block_tables, _ = self._table_inputs()
         limit = jnp.asarray(t.limit)
-        temps = self._row_temps(temperature)
+        temps = jnp.asarray(self._row_temps(temperature))
         seeds = jnp.asarray(t.seed)
         hidden = jnp.asarray(self._spec_hidden) if self_draft else None
         tracer.end(sp)
@@ -1396,6 +1466,7 @@ class InferenceEngineV2:
         self._kv_step = None
         self._state_step = None
         self._attn_q_slots = None
+        self._h2d = None
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
@@ -1415,6 +1486,8 @@ class InferenceEngineV2:
         if self._moe_stats is not None:  # an MoE model's step ran the device
             attrs["moe_rows"], attrs["moe_rows_padded"] = self._moe_rows[kind]
             attrs["moe_experts_hit"], attrs["moe_rows_max"] = self._moe_stats
+        if self._h2d is not None:  # copies made before the step's program
+            attrs["h2d_copies"], attrs["h2d_bytes"] = self._h2d
         if self._attn_q_slots is not None:  # a mixed step ran the device
             attrs["attn_q_slots"] = self._attn_q_slots
         if self._state_step is not None:  # a state model's step ran the device
@@ -1462,9 +1535,7 @@ class InferenceEngineV2:
         batch = self.builder.build(picks)
         tracer.end(sp)
         sp = tracer.begin("engine/h2d", **sub)
-        tables = jnp.asarray(batch.block_tables)
-        if batch.win_tables is not None:  # a table a pool
-            tables = (tables, jnp.asarray(batch.win_tables))
+        f = self._to_device(self.builder.layout, batch.packed)
         if self._windowed is not None:
             self._count_kv(batch.chunk_start[:len(picks)].astype(np.int64),
                            batch.chunk_len[:len(picks)].astype(np.int64))
@@ -1473,25 +1544,21 @@ class InferenceEngineV2:
         self._attn_q_slots = int(
             self._attn_tiles.slots(batch.chunk_len).sum())
         batch_args = (
-            jnp.asarray(batch.token_ids), jnp.asarray(batch.position_ids),
-            jnp.asarray(batch.seq_index), tables,
-            jnp.asarray(batch.context_lens), jnp.asarray(batch.logits_rows),
-            jnp.asarray(batch.chunk_start), jnp.asarray(batch.chunk_len))
+            f["token_ids"], f["position_ids"], f["seq_index"],
+            self._tables(f), f["context_lens"], f["logits_rows"],
+            f["chunk_start"], f["chunk_len"])
         if batch.state_slots is not None:  # a model with state layers
             n = len(picks)
             # behind the two adapter arguments, which such a model never has
-            batch_args += (None, None, jnp.asarray(batch.state_slots))
+            batch_args += (None, None, f["state_slots"])
             self._count_state(
                 n, tokens, int((batch.chunk_start[:n] == 0).sum()),
                 batch.chunk_len[:n])
         ad_args = ()
         if self.adapter_stack is not None:
-            # batch rows are picks order here (seq_index indexes into the
-            # pick rows, not the SoA table), so build the slot vector fresh
-            row_ad = np.zeros(self.cfg.max_seqs, np.int32)
-            for row, (seq, _) in enumerate(picks):
-                row_ad[row] = seq.adapter_slot
-            ad_args = (self.adapter_stack, jnp.asarray(row_ad))
+            # the batch's rows are in picks order (seq_index indexes into
+            # the pick rows, not the SoA table): the builder's slot vector
+            ad_args = (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         logits, hidden, self.caches, *rest = self._fwd(
@@ -1517,6 +1584,7 @@ class InferenceEngineV2:
         sampled = _with_stats(
             sample_rows(logits, jnp.asarray(temps), self._step_rng(rng),
                         jnp.asarray(seeds)), moe_stats)
+        self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp)
         sp_wait = tracer.begin("engine/wait", **sub)
         sampled = self._split_stats(np.asarray(sampled))
@@ -1561,7 +1629,7 @@ class InferenceEngineV2:
         t = self.table
         toks, self.caches = self._multi_decode[k](
             self.params, self.caches, *self._table_inputs(),
-            self._step_rng(rng), self._row_temps(temperature),
+            self._step_rng(rng), jnp.asarray(self._row_temps(temperature)),
             jnp.asarray(t.seed), *self._adapter_args())
         toks = np.asarray(toks)  # (k, max_seqs)
         rows = np.nonzero(t.active)[0]

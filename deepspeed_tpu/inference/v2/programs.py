@@ -16,6 +16,7 @@ cache, paging, adapters: the host side) nor ``spec.py``; both import it.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -762,6 +763,29 @@ def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2,
     return _memo(("multi_decode", model_cfg, dataclasses.astuple(v2),
                   num_steps),
                  lambda: jax.jit(multi_decode_step, donate_argnums=(1,)))
+
+
+def build_unpack(layout):
+    """The program that takes a step's one buffer apart on the device
+    (``layout``: a ``ragged.StepLayout``; hashable, so engines over the same
+    sizes share the program): ``buf -> {field: array}``.  Slices, reshapes
+    and a bitcast where a field is no int32, nothing else: what it traces
+    and lowers at every start is a few milliseconds (the engine's key is NOT
+    split here: threefry inside a jitted program lowers in 0.2-0.8 s on the
+    chip's host at every start, PERF.md section 6, PR 34)."""
+
+    def unpack_step_inputs(buf):
+        fields = {}
+        for name, at, shape, dtype in layout.fields:
+            x = jax.lax.slice(buf, (at,), (at + math.prod(shape),)
+                              ).reshape(shape)
+            if dtype != "int32":
+                x = jax.lax.bitcast_convert_type(x, jnp.dtype(dtype))
+            fields[name] = x
+        return fields
+
+    # lint: allow(jit-no-donate) — its one argument is the host's NumPy buffer
+    return _memo(("unpack", layout), lambda: jax.jit(unpack_step_inputs))
 
 
 def build_cow_copy():

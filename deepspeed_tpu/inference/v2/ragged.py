@@ -17,6 +17,7 @@ block table.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -358,6 +359,67 @@ class KVCacheManager:
         setattr(seq, self._reserved, 0)
 
 
+@dataclasses.dataclass(frozen=True)
+class StepLayout:
+    """Where each host input of a step lies in the ONE int32 buffer that
+    takes them to the device (a step then makes one host-to-device copy, and
+    ``programs.build_unpack`` takes the buffer apart there): ``fields`` are
+    ``(name, first word, shape, dtype)`` in the buffer's order, ``size`` its
+    words.  A float32 field lies in it bit for bit.  The layout follows what
+    the engine was built with (rows, tokens a step, blocks a sequence, a
+    second table, state slots, adapters) and nothing that changes later."""
+
+    fields: Tuple[Tuple[str, int, Tuple[int, ...], str], ...]
+    size: int
+
+    @classmethod
+    def of(cls, *fields) -> "StepLayout":
+        """``fields``: ``(name, shape)`` or ``(name, shape, dtype)``, or None
+        for one this engine does not have."""
+        laid, at = [], 0
+        for name, shape, *dtype in filter(None, fields):
+            laid.append((name, at, tuple(shape), (dtype or ["int32"])[0]))
+            at += math.prod(shape)
+        return cls(tuple(laid), at)
+
+    def new(self) -> np.ndarray:
+        """A step's buffer, zeroed.  Fresh every step: what is on its way to
+        the device is never written again."""
+        return np.zeros(self.size, np.int32)
+
+    def views(self, buf: np.ndarray) -> Dict[str, np.ndarray]:
+        """Each field as an array that IS its words of ``buf``."""
+        return {name: buf[at:at + math.prod(shape)].view(dtype).reshape(shape)
+                for name, at, shape, dtype in self.fields}
+
+
+def decode_layout(max_seqs: int, max_blocks_per_seq: int,
+                  two_pools: bool = False, adapters: bool = False
+                  ) -> StepLayout:
+    """The decode step's: the program's arguments by their names, one row a
+    sequence (``DecodeStateTable``'s rows)."""
+    rows, table = (max_seqs,), (max_seqs, max_blocks_per_seq)
+    return StepLayout.of(
+        ("token_ids", rows), ("position_ids", rows), ("context_lens", rows),
+        ("temps", rows, "float32"), ("seeds", rows),
+        ("block_tables", table), ("win_tables", table) if two_pools else None,
+        ("row_adapter", rows) if adapters else None)
+
+
+def mixed_layout(max_tokens: int, max_seqs: int, max_blocks_per_seq: int,
+                 two_pools: bool = False, state: bool = False,
+                 adapters: bool = False) -> StepLayout:
+    """The mixed step's: ``RaggedBatch``'s arrays."""
+    toks, rows = (max_tokens,), (max_seqs,)
+    table = (max_seqs, max_blocks_per_seq)
+    return StepLayout.of(
+        ("token_ids", toks), ("position_ids", toks), ("seq_index", toks),
+        ("block_tables", table), ("win_tables", table) if two_pools else None,
+        ("context_lens", rows), ("logits_rows", rows), ("chunk_start", rows),
+        ("chunk_len", rows), ("state_slots", rows) if state else None,
+        ("row_adapter", rows) if adapters else None)
+
+
 @dataclasses.dataclass
 class RaggedBatch:
     """One scheduled forward (reference ``RaggedBatchWrapper``): flattened
@@ -380,6 +442,11 @@ class RaggedBatch:
     #: a model with state layers: each row's state slot (unused rows: the
     #: scratch slot), (max_seqs,) int32
     state_slots: Optional[np.ndarray] = None
+    #: with adapters: each row's slot of the adapter stack, (max_seqs,) int32
+    row_adapter: Optional[np.ndarray] = None
+    #: the one int32 buffer every array above is a view of
+    #: (``RaggedBatchBuilder.layout``): what the step copies to the device
+    packed: Optional[np.ndarray] = None
 
 
 class DecodeStateTable:
@@ -489,30 +556,28 @@ class DecodeStateTable:
 
 class RaggedBatchBuilder:
     def __init__(self, max_tokens: int, max_seqs: int, max_blocks_per_seq: int,
-                 two_pools: bool = False, state_scratch: int = -1):
+                 two_pools: bool = False, state_scratch: int = -1,
+                 adapters: bool = False):
         self.max_tokens = max_tokens
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
-        self.two_pools = two_pools
         # a model with state layers: the scratch slot unused rows point at
         self.state_scratch = state_scratch
+        self.layout = mixed_layout(max_tokens, max_seqs, max_blocks_per_seq,
+                                   two_pools, state_scratch >= 0, adapters)
 
     def build(self, seqs: List[Tuple[SequenceDescriptor, int]]) -> RaggedBatch:
-        """seqs: (descriptor, n_new_tokens) pairs already capacity-checked."""
+        """seqs: (descriptor, n_new_tokens) pairs already capacity-checked.
+        The batch's arrays are filled where they lie in its one buffer."""
         if len(seqs) > self.max_seqs:
             raise ValueError(f"{len(seqs)} sequences > max_seqs {self.max_seqs}")
-        token_ids = np.zeros(self.max_tokens, np.int32)
-        position_ids = np.zeros(self.max_tokens, np.int32)
-        seq_index = np.full(self.max_tokens, -1, np.int32)
-        block_tables = np.zeros((self.max_seqs, self.max_blocks_per_seq), np.int32)
-        win_tables = np.zeros_like(block_tables) if self.two_pools else None
-        context_lens = np.zeros(self.max_seqs, np.int32)
-        logits_rows = np.zeros(self.max_seqs, np.int32)
-        chunk_start = np.zeros(self.max_seqs, np.int32)
-        chunk_len = np.zeros(self.max_seqs, np.int32)
-        state_slots = None if self.state_scratch < 0 else np.full(
-            self.max_seqs, self.state_scratch, np.int32)
-        uids = []
+        packed = self.layout.new()
+        # the layout's fields are the batch's arrays, by name
+        b = RaggedBatch(**self.layout.views(packed), num_tokens=0,
+                        num_seqs=len(seqs), uids=[], packed=packed)
+        b.seq_index[:] = -1
+        if b.state_slots is not None:
+            b.state_slots[:] = self.state_scratch
         cursor = 0
         for row, (seq, n_new) in enumerate(seqs):
             start = seq.seen_tokens
@@ -520,20 +585,21 @@ class RaggedBatchBuilder:
             if cursor + len(new_tokens) > self.max_tokens:
                 raise ValueError("ragged batch token budget exceeded")
             sl = slice(cursor, cursor + len(new_tokens))
-            token_ids[sl] = new_tokens
-            position_ids[sl] = np.arange(start, start + len(new_tokens))
-            seq_index[sl] = row
-            block_tables[row, :len(seq.blocks)] = seq.blocks
-            if win_tables is not None:
-                win_tables[row, :len(seq.win_blocks)] = seq.win_blocks
-            context_lens[row] = start + len(new_tokens)
-            logits_rows[row] = cursor + len(new_tokens) - 1
-            chunk_start[row] = start
-            chunk_len[row] = len(new_tokens)
-            if state_slots is not None:
-                state_slots[row] = seq.state_slot
+            b.token_ids[sl] = new_tokens
+            b.position_ids[sl] = np.arange(start, start + len(new_tokens))
+            b.seq_index[sl] = row
+            b.block_tables[row, :len(seq.blocks)] = seq.blocks
+            if b.win_tables is not None:
+                b.win_tables[row, :len(seq.win_blocks)] = seq.win_blocks
+            b.context_lens[row] = start + len(new_tokens)
+            b.logits_rows[row] = cursor + len(new_tokens) - 1
+            b.chunk_start[row] = start
+            b.chunk_len[row] = len(new_tokens)
+            if b.state_slots is not None:
+                b.state_slots[row] = seq.state_slot
+            if b.row_adapter is not None:
+                b.row_adapter[row] = seq.adapter_slot
             cursor += len(new_tokens)
-            uids.append(seq.uid)
-        return RaggedBatch(token_ids, position_ids, seq_index, block_tables,
-                           context_lens, logits_rows, chunk_start, chunk_len,
-                           cursor, len(seqs), uids, win_tables, state_slots)
+            b.uids.append(seq.uid)
+        b.num_tokens = cursor
+        return b

@@ -593,8 +593,8 @@ _CHILDREN = {
 def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     """Every ``engine/step`` of the kind has one child per phase, inside its
     interval and in order, each with the step's ``kind`` and ``step``; the
-    step itself says how long the device was (presumed) busy, and how full
-    its batch was."""
+    step itself says how long the device was (presumed) busy, how full its
+    batch was, and what it copied to the device before its program."""
     from deepspeed_tpu.observability.trace import tracer
 
     cfg, params = tiny_model
@@ -631,6 +631,15 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
             * 1e3)  # start of engine/dispatch to end of engine/wait
         for k in kids:  # a child carries what a reader joins on, no more
             assert set(k.attrs) == {"kind", "step"}
+        # the copies made before the step's program, on the step itself (a
+        # speculative step keeps its own arguments and counts none)
+        layout = {"decode": eng._decode_layout,
+                  "mixed": eng.builder.layout}.get(kind)
+        if layout is None:
+            assert "h2d_copies" not in st.attrs
+        else:
+            assert (st.attrs["h2d_copies"], st.attrs["h2d_bytes"]) == (
+                1, layout.size * 4)
     if kind == "mixed":  # the long prompt fills whole chunks of the budget
         assert max(s.attrs["tokens"] for s in steps) == 16
 
@@ -703,3 +712,414 @@ def test_a_failing_phase_stays_in_the_ring(devices, tiny_model):
     again = spans[-1]
     assert again.name == "engine/step" and again.parent_id is None
     assert "error" not in again.attrs and again.attrs["device_ms"] >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# a step's host inputs reach the device in one copy (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+# the four layouts: what an engine can be built with that adds a field to a
+# step's buffer.  Sizes no other test uses, so that the unpack programs (one a
+# layout, shared by every engine over the same sizes) are these tests' own
+_LAYOUTS = {
+    "plain": dict(),
+    "two_tables": dict(two_pools=True),
+    "state_slots": dict(state=True),
+    "adapters": dict(adapters=True),
+}
+_ROWS, _BLOCKS, _TOKENS = 5, 9, 24
+
+
+def _layout(name, step):
+    from deepspeed_tpu.inference.v2.ragged import decode_layout, mixed_layout
+
+    kw = dict(_LAYOUTS[name])
+    rows = _ROWS + 1  # not the engines' below: their programs are theirs
+    if step == "decode":
+        kw.pop("state", None)  # a decode row IS its state slot
+        return decode_layout(rows, _BLOCKS, **kw)
+    return mixed_layout(_TOKENS, rows, _BLOCKS, **kw)
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_pack_then_unpack_gives_every_field_back(devices, name, step):
+    """What the host writes into a step's one buffer is what the unpack
+    program hands the step program, bit for bit, the temperatures (float32
+    bits among int32 words) included."""
+    from deepspeed_tpu.inference.v2.programs import build_unpack
+
+    layout = _layout(name, step)
+    names = [f[0] for f in layout.fields]
+    assert ("win_tables" in names) == (name == "two_tables")
+    assert ("row_adapter" in names) == (name == "adapters")
+    assert ("state_slots" in names) == (name == "state_slots"
+                                        and step == "mixed")
+    assert layout.size == sum(int(np.prod(f[2])) for f in layout.fields)
+    rs = np.random.default_rng(len(names))
+    buf = layout.new()
+    assert buf.dtype == np.int32 and not buf.any()
+    want = {}
+    for field, view in layout.views(buf).items():
+        if view.dtype == np.float32:  # any bits: -0.0, a denormal, a NaN's
+            bits = rs.integers(-2**31, 2**31, view.shape).astype(np.int32)
+            bits.flat[:3] = [-2**31, 1, 0x7fc00001]
+            view[...] = bits.view(np.float32)
+        else:
+            view[...] = rs.integers(-2**31, 2**31, view.shape)
+        want[field] = view.copy()
+    fields = build_unpack(layout)(buf)
+    assert sorted(fields) == sorted(want) == sorted(names)
+    for field, arr in fields.items():
+        assert arr.shape == want[field].shape
+        assert arr.dtype == want[field].dtype
+        np.testing.assert_array_equal(
+            np.asarray(arr).view(np.int32), want[field].view(np.int32))
+
+
+def _one_copy_engine(name):
+    """A tiny engine whose steps use layout ``name`` (the model kind that
+    has it), at sizes of these tests' own."""
+    v2 = dict(max_tokens_per_step=_TOKENS, max_seqs=_ROWS, block_size=8,
+              num_blocks=96, max_blocks_per_seq=_BLOCKS, dtype="float32")
+    preset = {"two_tables": "tiny-mellum2",
+              "state_slots": "tiny-nemotron3"}.get(name, "tiny")
+    cfg = tfm.get_config(preset, dtype="float32")
+    if name == "adapters":
+        v2.update(adapter_slots=3, adapter_rank=2)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+
+    def build():
+        eng = InferenceEngineV2(cfg, params, V2Config(**v2))
+        if name == "adapters":
+            from deepspeed_tpu.inference.v2.engine import \
+                adapter_target_shapes
+
+            rs = np.random.default_rng(5)
+            L = cfg.num_layers
+            eng.set_adapter_slot(1, {
+                t: (rs.standard_normal((L, K, 2)).astype(np.float32),
+                    rs.standard_normal((L, 2, N)).astype(np.float32))
+                for t, (K, N) in adapter_target_shapes(cfg).items()})
+        return eng
+
+    return build
+
+
+def _parents_argument_path(eng):
+    """The parent's way of bringing a step's inputs to the device, kept here
+    as the reference: an array a field (``jnp.asarray`` each; the decode
+    step's straight off the SoA table as ``_table_inputs`` and
+    ``_row_temps`` read it) and the engine's key split eagerly on the host,
+    unpacked in Python."""
+    def to_device(layout, buf):
+        names = [f[0] for f in layout.fields]
+        if "seeds" in names:  # the decode step: off the table, not the buffer
+            t = eng.table
+            tok, ctx, tables, ctx_in = eng._table_inputs()
+            fields = {"token_ids": tok, "position_ids": ctx,
+                      "context_lens": ctx_in,
+                      "temps": jnp.asarray(np.where(
+                          t.temp >= 0.0, t.temp,
+                          np.float32(eng.step_temperature))
+                          .astype(np.float32)),
+                      "seeds": jnp.asarray(t.seed)}
+            if isinstance(tables, tuple):
+                fields["block_tables"], fields["win_tables"] = tables
+            else:
+                fields["block_tables"] = tables
+            if "row_adapter" in names:
+                fields["row_adapter"] = jnp.asarray(t.adapter)
+        else:
+            fields = {n: jnp.asarray(v.copy())
+                      for n, v in layout.views(buf).items()}
+        return fields
+
+    def step_rng(rng):
+        if rng is None:
+            eng._rng, rng = jax.random.split(eng._rng)
+        return rng
+
+    eng._to_device, eng._step_rng = to_device, step_rng
+    eng._split_ahead = lambda: None
+    return eng
+
+
+# requests (prompt length, new tokens, temperature, seed, adapter slot) that
+# arrive at the given step: decode steps and mixed steps alternate, rows come
+# and go, greedy rows sit beside sampled ones (pinned and inherited
+# temperatures)
+_ARRIVALS = {
+    0: [(30, 12, None, 0, 0), (5, 22, 0.8, 7, 1)],
+    6: [(11, 9, 0.0, 0, 0)],
+    13: [(40, 8, 1.3, 3, 1), (3, 18, None, 9, 0)],
+    30: [(7, 14, 0.5, 1, 0)],
+    44: [(26, 9, None, 2, 1), (2, 15, 0.9, 5, 0)],
+}
+
+
+def _drive(eng, adapters, watch=None):
+    """Run ``_ARRIVALS`` to the end → ([(kind, {uid: tokens})] a step, the
+    step keys in order).  ``watch(eng)`` wraps what it wants to observe."""
+    from deepspeed_tpu.inference.v2 import engine as engine_mod
+
+    keys, steps = [], []
+    decode_fwd, sample = eng._decode_fwd, engine_mod.sample_rows
+
+    def decode(params, caches, *args):
+        keys.append(np.asarray(args[5]))
+        return decode_fwd(params, caches, *args)
+
+    def sample_rows(logits, temps, rng, seeds):
+        keys.append(np.asarray(rng))
+        return sample(logits, temps, rng, seeds)
+
+    eng._decode_fwd = decode
+    engine_mod.sample_rows = sample_rows
+    if watch:
+        watch(eng)
+    rs = np.random.default_rng(2)
+    try:
+        n = 0
+        while n <= max(_ARRIVALS) or eng.running or eng.waiting:
+            for length, new, temp, seed, slot in _ARRIVALS.get(n, ()):
+                eng.put(rs.integers(1, 200, length).tolist(),
+                        max_new_tokens=new, temperature=temp, seed=seed,
+                        adapter_slot=slot if adapters else 0)
+            # the step-level temperature, which rows without one inherit
+            eng.step_temperature = 0.7 if n % 2 else 0.0
+            steps.append(eng.step(temperature=eng.step_temperature))
+            n += 1
+    finally:
+        engine_mod.sample_rows = sample
+    return steps, keys
+
+
+@pytest.fixture(scope="module", params=list(_LAYOUTS))
+def one_copy_run(request, devices):
+    """The run of ``_ARRIVALS`` on an engine of each layout, watched, and the
+    same run on its twin that brings the inputs over the parent's way."""
+    from deepspeed_tpu.inference.v2.programs import build_unpack
+    from deepspeed_tpu.observability.trace import tracer
+
+    name = request.param
+    build = _one_copy_engine(name)
+    seen = {"copies": [], "explicit": [], "bufs": []}
+
+    def watch(eng):
+        # from a step's start to the call of its program: implicit copies
+        # (a NumPy array handed to a jitted program) are refused, explicit
+        # ones (jax.device_put, jnp.asarray, jnp.array) counted, and the one
+        # copy function allowed its one
+        to_device, impl = eng._to_device, eng._step_impl
+        programs = {"_fwd": eng._fwd, "_decode_fwd": eng._decode_fwd}
+        guard = [None]
+
+        def shut():
+            seen["open"] = False
+            if guard[0] is not None:
+                guard[0].__exit__(None, None, None)
+                guard[0] = None
+
+        def counted(layout, buf):
+            seen["bufs"].append((type(buf), buf.dtype, buf.nbytes))
+            seen["copies"][-1] += 1
+            with jax.transfer_guard_host_to_device("allow"):
+                return to_device(layout, buf)
+
+        def step_impl(*args):
+            seen["copies"].append(0)
+            seen["explicit"].append(0)
+            guard[0] = jax.transfer_guard_host_to_device("disallow")
+            guard[0].__enter__()
+            seen["open"] = True
+            try:
+                return impl(*args)
+            finally:
+                shut()
+
+        def program(fn):
+            def call(*args):
+                shut()  # the step's program is being called
+                return fn(*args)
+            return call
+
+        eng._to_device, eng._step_impl = counted, step_impl
+        for attr, fn in programs.items():
+            setattr(eng, attr, program(fn))
+
+    explicit = {}
+    for mod, fn in ((jax, "device_put"), (jnp, "asarray"), (jnp, "array")):
+        real = getattr(mod, fn)
+
+        def counting(*a, _real=real, **k):
+            import sys
+            if (seen.get("open") and sys._getframe(1).f_globals.get(
+                    "__name__", "").startswith("deepspeed_tpu.inference.v2")):
+                seen["explicit"][-1] += 1
+            return _real(*a, **k)
+
+        explicit[(mod, fn)] = real
+        setattr(mod, fn, counting)
+    tracer.clear()
+    thread = threading.current_thread().name
+    try:
+        eng = build()
+        steps, keys = _drive(eng, name == "adapters", watch)
+    finally:
+        for (mod, fn), real in explicit.items():
+            setattr(mod, fn, real)
+    spans = [s for s in tracer.spans()
+             if s.thread == thread and s.name == "engine/step"]
+    ref_steps, ref_keys = _drive(_parents_argument_path(build()),
+                                 name == "adapters")
+    sizes = {step: build_unpack(layout)._cache_size()
+             for step, layout in (("decode", eng._decode_layout),
+                                  ("mixed", eng.builder.layout))}
+
+    return dict(name=name, eng=eng, steps=steps, keys=keys, spans=spans,
+                ref_steps=ref_steps, ref_keys=ref_keys, seen=seen,
+                unpack_programs=sizes)
+
+
+def test_a_step_makes_one_host_to_device_copy(one_copy_run):
+    """A decode step and a mixed step each make exactly one host-to-device
+    copy before their program is called: the step's span says so
+    (``h2d_copies``, ``h2d_bytes``: the buffer's), the one copy function was
+    called once a step with one NumPy int32 buffer, and between a step's
+    start and its program nothing else was copied, neither explicitly nor by
+    handing a jitted program a NumPy array."""
+    run = one_copy_run
+    eng = run["eng"]
+    ran = ["device_ms" in s.attrs for s in run["spans"]]  # not an idle step
+    spans = [s for s, on in zip(run["spans"], ran) if on]
+    kinds = [s.attrs["kind"] for s in spans]
+    assert len(spans) >= 50 and {"decode", "mixed"} == set(kinds)
+    assert kinds.count("decode") >= 10 and kinds.count("mixed") >= 6
+    nbytes = {"decode": eng._decode_layout.size * 4,
+              "mixed": eng.builder.layout.size * 4}
+    for s in spans:
+        assert s.attrs["h2d_copies"] == 1
+        assert s.attrs["h2d_bytes"] == nbytes[s.attrs["kind"]]
+    assert run["seen"]["copies"] == [int(on) for on in ran]
+    assert run["seen"]["explicit"] == [0] * len(ran)
+    assert all(b == (np.ndarray, np.dtype(np.int32), nbytes[k])
+               for b, k in zip(run["seen"]["bufs"], kinds))
+
+
+def test_tokens_and_step_keys_are_the_parents(one_copy_run):
+    """Over a run that mixes decode and mixed steps, greedy and sampled
+    rows, every step emits the tokens it emits when its inputs are brought
+    over the parent's way, and the steps' keys are the same keys in the same
+    order: the split moved to the device, the stream did not."""
+    run = one_copy_run
+    assert len(run["steps"]) == len(run["ref_steps"])
+    assert run["steps"] == run["ref_steps"]
+    # a key a step that ran the device
+    assert len(run["keys"]) == sum("device_ms" in s.attrs
+                                   for s in run["spans"])
+    np.testing.assert_array_equal(np.stack(run["keys"]),
+                                  np.stack(run["ref_keys"]))
+    assert len({k.tobytes() for k in run["keys"]}) == len(run["keys"])
+    sampled = [t for out in run["steps"] for t in out.values()]
+    assert sampled  # and the sampled rows did draw: greedy alone differs
+    host = jax.random.PRNGKey(0)
+    for key in run["keys"][:5]:  # the engine's stream from its first key
+        host, want = jax.random.split(host)
+        np.testing.assert_array_equal(key, np.asarray(want))
+
+
+def test_the_unpack_programs_compile_once(one_copy_run):
+    """Fifty steps and more with rows coming and going: one compiled unpack
+    program for the decode steps and one for the mixed steps."""
+    assert len(one_copy_run["keys"]) >= 50
+    assert one_copy_run["unpack_programs"] == {"decode": 1, "mixed": 1}
+
+
+def test_a_callers_key_is_used_as_it_is(devices, tiny_model):
+    """``step(rng=...)``: the step's sampler draws under the caller's key,
+    and the engine's own stream goes on from where it was."""
+    cfg, params = tiny_model
+    eng = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=16, max_seqs=4, block_size=8, num_blocks=64,
+        max_blocks_per_seq=8, dtype="float32"))
+    eng.put([7, 8, 9], max_new_tokens=5, temperature=0.9)
+    seen = []
+    decode_fwd = eng._decode_fwd
+    eng._decode_fwd = lambda p, c, *a: (seen.append(np.asarray(a[5])),
+                                        decode_fwd(p, c, *a))[1]
+    mine = jax.random.PRNGKey(123)
+    eng.step(rng=mine)  # mixed
+    eng.step(rng=mine)  # decode
+    np.testing.assert_array_equal(seen[0], np.asarray(mine))
+    eng.step()  # without one: the first two keys of the engine's stream
+    eng.step()
+    host = jax.random.PRNGKey(0)
+    for key in seen[1:]:
+        host, want = jax.random.split(host)
+        np.testing.assert_array_equal(key, np.asarray(want))
+
+
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_the_step_programs_are_called_as_the_harness_expects(devices, name):
+    """The benchmark's ``correct`` phase and its traced run replace
+    ``engine._decode_fwd`` and ``engine._fwd`` (``benchmark/logit_tap.py``,
+    ``logit_tap_donated.py``, ``routing_tap.py``, ``serve_moe.StepProgram``)
+    and read their arguments by position: a stand-in for those wrappers sees
+    the decode program called with ``(params, caches, token_ids,
+    position_ids, block_tables, context_lens, temps, rng, seeds)`` (and the
+    adapter pair behind them), every leaf an array with ``.shape`` and
+    ``.dtype``, ``args[:4]`` what ``_decode_body`` takes, and ``(tokens,
+    caches)`` back; the mixed program with its ten (a state model's
+    ``state_slots`` behind two ``None``)."""
+    from deepspeed_tpu.inference.v2.engine import _decode_body
+
+    eng = _one_copy_engine(name)()
+    S, B, T = _ROWS, _BLOCKS, _TOKENS
+    i32, f32 = jnp.int32, jnp.float32
+    table = ((S, B), i32)
+    tables = (table, table) if name == "two_tables" else table
+    calls = {"decode": [], "mixed": []}
+    fwd, decode_fwd = eng._fwd, eng._decode_fwd
+
+    def shapes(args):
+        return jax.tree.map(
+            lambda a: None if a is None else (a.shape, a.dtype), args,
+            is_leaf=lambda a: a is None)
+
+    def tapped_decode(params, caches, *args):
+        logits = jax.jit(lambda p, c, *a: _decode_body(
+            p, c, *a, eng.model_cfg, eng.cfg)[0])(params, caches, *args[:4])
+        out = decode_fwd(params, caches, *args)
+        calls["decode"].append((shapes(args), logits.shape, len(out),
+                                out[0].shape, sorted(out[1])))
+        return out
+
+    def tapped_fwd(params, caches, *args):
+        out = fwd(params, caches, *args)
+        calls["mixed"].append((shapes(args), out[0].shape, sorted(out[2])))
+        return out
+
+    eng._fwd, eng._decode_fwd = tapped_fwd, tapped_decode
+    eng.put(list(range(1, 31)), max_new_tokens=3,
+            adapter_slot=1 if name == "adapters" else 0)
+    while eng.running or eng.waiting:
+        eng.step()
+    rows = ((S,), i32)
+    stack = shapes(eng.adapter_stack) if name == "adapters" else None
+    decode_args = (rows, rows, tables, rows, ((S,), f32),
+                   ((2,), jnp.uint32), rows)
+    mixed_args = (((T,), i32),) * 3 + (tables,) + (rows,) * 4
+    if name == "adapters":
+        decode_args += (stack, rows)
+        mixed_args += (stack, rows)
+    if name == "state_slots":
+        mixed_args += (None, None, rows)
+    vocab = eng.model_cfg.vocab_size
+    pools = sorted(eng.caches)
+    moe = 2 if eng.model_cfg.num_experts else 0  # the stats behind the rows
+    assert calls["decode"] and calls["mixed"]
+    for got in calls["decode"]:
+        assert got == (decode_args, (S, vocab), 2, (S + moe,), pools)
+    for got in calls["mixed"]:
+        assert got == (mixed_args, (S, vocab), pools)
