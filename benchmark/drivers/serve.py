@@ -121,26 +121,39 @@ class SpanCollector:
         return [s for s in self.spans.values() if t0 <= s["t_end"] < t1]
 
 
-def check_served(params, model: Mapping[str, Any], sequences, pad_to: int,
-                 margin: float, log: Callable[[str], None]) -> Dict[str, Any]:
+def check_served(params, model: Mapping[str, Any], sequences, check,
+                 log: Callable[[str], None]) -> Dict[str, Any]:
     """Every served token against the plain reference, which reads the whole
     sequence (prompt, then what the server sent) in one uncached pass over
     the same parameters.  Tokens are not compared: with random weights the
     largest logit changes on rounding.  The served token's reference logit
-    has to lie within ``margin`` of the reference's maximum.
+    has to lie within ``check["margin"]`` of the reference's maximum.
 
-    Why 0.5 (``chip_smoke.py``'s rule, measured there): random-init logits
-    have a standard deviation near 1 over the vocabulary and a maximum near
-    4; the top two lie about 0.2 apart; bf16 through 32 layers moves a logit
-    by under 0.1 (worst seen 0.07); a wrong token lies about 4 under the
-    maximum.  So 0.5 passes bf16 and int8-weight rounding and fails a wrong
-    position, a stale cache block or a skipped layer."""
+    A sequence is padded (causal: the padding changes nothing) to the next
+    multiple of ``reference_pad``, so that the longest request of the window
+    is read at its own length and the reference is compiled for a few
+    lengths only.
+
+    The limit and the readings it was set from are in PERF.md section 2
+    (``correct``): over bf16 through 32 layers and int8 weights a right
+    program's worst served token lies 0.05-0.13 under the maximum, the top
+    two logits lie about 0.2 apart, a wrong token about 4 under.
+
+    With ``control_bits`` (never in a committed configuration: tools and
+    tests ask for it) the run is the control's: at the same positions the
+    tokens that ``reference/dense_control.py`` puts first at that many bits
+    are judged in the served tokens' place, and the served tokens' own
+    reading goes to the log."""
     import jax.numpy as jnp
 
-    worst, exact, checked = 0.0, 0, 0
+    pad = check["reference_pad"]
+    bits = check.get("control_bits", 0)
+    if bits:
+        from benchmark.reference import dense_control
+    worst, exact, checked, control = 0.0, 0, 0, 0.0
     for prompt, served in sequences:
-        seq = np.zeros(pad_to, np.int32)  # causal: the padding changes nothing
         n = len(prompt) + len(served)
+        seq = np.zeros(-(-n // pad) * pad, np.int32)
         seq[:n] = prompt + served
         m, rank = reference.served_margins(params, model, jnp.asarray(seq),
                                            len(prompt))
@@ -150,11 +163,42 @@ def check_served(params, model: Mapping[str, Any], sequences, pad_to: int,
         worst = max(worst, float(m.max()))
         exact += int((rank == 0).sum())
         checked += len(served)
-    log(f"reference: {checked} served tokens of {len(sequences)} sequences, "
+        if bits:
+            c, _ = dense_control.control_margins(
+                params, model, jnp.asarray(seq), len(prompt), bits)
+            control = max(control, float(np.asarray(c)[:len(served)].max()))
+    log(f"reference: {checked} served tokens of {len(sequences)} sequences "
+        f"(lengths {[len(p) + len(t) for p, t in sequences]}), "
         f"{exact} are the reference's argmax, worst margin {worst:.4f} "
-        f"(allowed {margin})")
+        f"(allowed {check['margin']})"
+        + (f"; THE CONTROL at {bits} bits is judged in their place: worst "
+           f"margin {control:.4f}" if bits else ""))
+    if bits:
+        worst = control
     return {"tokens_checked": checked, "argmax_equal": exact,
-            "worst_margin": worst, "ok": checked > 0 and worst <= margin}
+            "worst_margin": worst,
+            "ok": checked > 0 and worst <= check["margin"]}
+
+
+def pick_sequences(finished: List[dict], check: Mapping[str, Any],
+                   seed: int) -> List[dict]:
+    """The window's finished requests that the reference reads, for every
+    serving driver: the longest that fits ``reference_len``, and
+    ``window_sequences - 1`` others drawn from the seed.  A request is drawn
+    by what it is (its stream and index, which fix its prompt), not by where
+    it stands among the finished: a request more or fewer at the window's
+    edges, which the machine's load decides, moves no other pick."""
+    fits = [r for r in finished
+            if r["n_prompt"] + len(r["tokens"]) <= check["reference_len"]]
+    if not fits or check["window_sequences"] < 1:
+        return []
+    fits.sort(key=lambda r: (r["stream"], r["index"]))
+    longest = max(fits, key=lambda r: r["n_prompt"] + len(r["tokens"]))
+    others = sorted(
+        (r for r in fits if r is not longest),
+        key=lambda r: np.random.default_rng(
+            [seed, 0xC4EC, r["stream"], r["index"]]).random())
+    return [longest] + others[:check["window_sequences"] - 1]
 
 
 def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
@@ -180,10 +224,13 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
     port = server.server_port
     total_blocks = engine.total_blocks
 
+    # the configuration's check; a cell whose traffic needs another sample
+    # (short outputs: more sequences) says so in its traffic file
+    check = {**config["check"], **traffic.get("check", {})}
+
     # warm-up: one request whose prompt is longer than a step's token budget
     # compiles the mixed step (twice run: chunked prefill), the sampler and
     # the decode step; shapes are static, so these are all there are
-    check = config["check"]
     warm = {"prompt": np.random.default_rng([seed, 0xBEEF]).integers(
                 1, cfg.vocab_size, size=check["warmup_prompt"]).tolist(),
             "max_tokens": check["warmup_tokens"]}
@@ -249,6 +296,9 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
     programs_in_window = compiles.between(t_open, t_close)
     del engine, pool, server  # the KV cache goes; the reference needs room
     gc.collect()
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use", 0)
+              for d in jax.local_devices()]
+    log(f"engine freed: {max(in_use) / 1e9:.2f} GB in use on the device")
 
     # what the window holds
     if traffic["loop"] == "open":
@@ -261,21 +311,24 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
     attempted = len(in_window)
     failed = sum(r["status"] not in ("ok", "cut") for r in in_window)
 
-    # correct: the warm-up request and up to three of the window's complete
-    # sequences that fit the reference's length, token by token
-    fits = [r for r in in_window if r["status"] == "ok"
-            and r["n_prompt"] + len(r["tokens"]) <= check["reference_len"]]
+    # correct: the warm-up request and a few of the window's complete
+    # sequences, the longest among them, token by token
     picked = [(warm["prompt"], rec.tokens)] + [
-        (r["prompt"], r["tokens"]) for r in fits[:check["window_sequences"]]]
-    served = check_served(params, model, picked, check["reference_len"],
-                          check["margin"], log)
+        (r["prompt"], r["tokens"]) for r in pick_sequences(
+            [r for r in in_window if r["status"] == "ok"], check, seed)]
+    served = check_served(params, model, picked, check, log)
     correct = (served["ok"] and free == total_blocks and failed == 0
                and attempted > 0)
+    # every number compared, beside its limit
+    checks = {"worst_margin": [served["worst_margin"], check["margin"]],
+              "kv_blocks_free": [free, total_blocks],
+              "failed_requests": [failed, 0]}
 
     for r in records:  # prompts were for the check only
         r.pop("prompt", None)
     return {
         "correct": correct,
+        "checks": checks,
         "attempted": attempted,
         "failed": failed,
         "setup_s": setup_s,

@@ -408,14 +408,13 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
     failed = sum(r["status"] not in ("ok", "cut") for r in in_window)
 
     # correct: step-program logits against the reference; and the warm-up
-    # request and up to three of the window's complete sequences that fit
-    # the reference's length, token by token
+    # request and a few of the window's complete sequences that fit the
+    # reference's length (``serve.pick_sequences``), token by token
     agree = check_logits(params, model, tapped, check, log)
     routed = check_router(params, model, cfg, tapped, check, log)
-    fits = [r for r in in_window if r["status"] == "ok"
-            and r["n_prompt"] + len(r["tokens"]) <= check["reference_len"]]
     picked = [(warm["prompt"], rec.tokens)] + [
-        (r["prompt"], r["tokens"]) for r in fits[:check["window_sequences"]]]
+        (r["prompt"], r["tokens"]) for r in serve.pick_sequences(
+            [r for r in in_window if r["status"] == "ok"], check, seed)]
     served = check_served(params, model, picked, check["reference_len"],
                           check["margin"], log)
     if fallbacks:

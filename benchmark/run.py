@@ -161,6 +161,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         dev["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    if "checks" in obs:  # what ``correct`` compared: name -> [number, limit]
+        result["checks"] = obs["checks"]
+        for name, (value, limit) in obs["checks"].items():
+            log(f"compared {name}: {value} (limit {limit})")
     return result
 
 

@@ -48,23 +48,33 @@ CONFIG = {
                      "original_max_position_embeddings",
                  "beta_fast": "beta_fast", "beta_slow": "beta_slow",
                  "attention_factor": "attention_factor"}},
-    "overrides": {"dtype": "bfloat16", "param_dtype": "bfloat16",
+    # float32 and no weight quantization, where the chip's cell is bfloat16
+    # over int8 codes: at toy widths (8 experts, top 2, renormalised) a router
+    # tie that flips under bf16 rounding swaps half of a token's expert
+    # output, and 6-9 % of a RIGHT program's served sequences then read over
+    # the margin (worst 2.2-2.6, where one altered token reads 2.7 at the
+    # median: PR 36).  Which requests end inside the window depends on the
+    # machine's load, so the rehearsal failed whenever load picked such a
+    # sequence.  In float32 over plain weights every served token of every
+    # window sequence IS the reference's first (95 of 95 sequences read 0.0,
+    # the tapped logits differ by 0.0000), so whichever requests are picked a
+    # right program passes, and the margin keeps the chip's 0.5, which an
+    # altered token fails.  The chip holds bf16 and the int8 codes:
+    # benchmark/configs/mellum2-12b-w8.json.
+    "overrides": {"dtype": "float32", "param_dtype": "float32",
                   "num_layers": 4},
-    "engine": {"weight_bits": 8, "weight_group": 128,
+    "engine": {"weight_bits": 0, "weight_group": 128,
                "v2": {"max_tokens_per_step": 32, "max_seqs": 4,
                       "block_size": 8, "num_blocks": 96,
                       "num_window_blocks": 29, "max_blocks_per_seq": 16,
-                      "dtype": "bfloat16", "quantize_bits": 0},
+                      "dtype": "float32", "quantize_bits": 0},
                "serving": {"num_replicas": 1, "max_queue": 64,
                            "drain_timeout_s": 30.0}},
-    # at toy widths (8 experts, top 2, renormalised) a router tie that flips
-    # in bf16 swaps half of a token's expert output: the bounds are loose
-    # here, the chip's are in benchmark/configs/mellum2-12b-w8.json
     "check": {"margin": 0.5, "reference_len": 96, "window_sequences": 3,
               "warmup_prompt": 40, "warmup_tokens": 6,
               "logit_prompts": [40, 75, 9], "logit_tokens": 18,
               "logit_pad": 32,
-              "logit_tol_median": 0.15, "logit_tol": 1.5,
+              "logit_tol_median": 1e-3, "logit_tol": 1e-2,
               "router_layer": 1, "router_tol": 1e-4},
 }
 TRAFFIC = {"loop": "closed", "clients": 6,

@@ -43,7 +43,8 @@ NEW_FILES = {
                        "quantize_bits": 0},
                 "serving": {"num_replicas": 1, "max_queue": 64,
                             "drain_timeout_s": 30.0}},
-        check={"margin": 0.5, "reference_len": 96, "window_sequences": 3,
+        check={"margin": 0.5, "reference_len": 96, "reference_pad": 32,
+               "window_sequences": 3,
                "warmup_prompt": 40, "warmup_tokens": 6}),
     "traffic/steps-64.json": {
         "loop": "steps", "seq_len": 64, "warmup_steps": 2, "in_flight": 2,
@@ -60,7 +61,7 @@ def read(obs):
 '''
 CELLS = {"t-train": ("tiny-train", "steps-64", "train-1chip"),
          "t-closed": ("tiny-w8", "tiny-closed", "chat-decode-sat"),
-         "t-open": ("tiny-w8", "tiny-open", "doc-prefill-rate")}
+         "t-open": ("tiny-w8", "tiny-open", "doc-prefill-loaded")}
 
 
 @pytest.fixture(scope="module")

@@ -34,6 +34,29 @@ def test_serve_swa_moe_driver_traced(copy, monkeypatch):
     rehearsal.check_traced(rehearsal.rehearse(copy, trace=True))
 
 
+def test_an_altered_token_is_not_correct(copy, monkeypatch):
+    """The margin the rehearsal holds has teeth: every fifth token a step
+    hands to its requests is another token (the engine's own state keeps the
+    right one); no request fails, and ``correct`` is false."""
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2
+
+    step, count = InferenceEngineV2.step, [0]
+
+    def altered(self, *args, **kwargs):
+        out = step(self, *args, **kwargs)
+        for tokens in out.values():
+            for i in range(len(tokens)):
+                count[0] += 1
+                if count[0] % 5 == 0:
+                    tokens[i] = (int(tokens[i]) + 100) % 255 + 1
+        return out
+
+    monkeypatch.setattr(InferenceEngineV2, "step", altered)
+    result = rehearsal.rehearse(copy)
+    assert result["failed"] == 0 and result["attempted"] > 5
+    assert not result["correct"]
+
+
 @pytest.mark.parametrize("edit,says", [
     (lambda c: c["rope_parameters"]["full_attention"].update(factor=8.0),
      "rope_parameters.full_attention.factor"),
