@@ -42,17 +42,37 @@ from .spec import build_draft_spec_step, build_self_draft_step
 
 
 # What a step's body hands back to ``step``: the tokens by uid, the tokens in
-# its batch, and ``device_ms`` (None where it dispatched nothing).
-_StepResult = Tuple[Dict[int, List[int]], int, Optional[float]]
+# its batch, and where its split is made (None where it dispatched nothing):
+# its ``engine/dispatch`` span with the thread's CPU clock where that opened,
+# its ``engine/wait`` span with the clock where that closed (``_thread_cpu``;
+# four of None with tracing off).
+_StepResult = Tuple[Dict[int, List[int]], int,
+                    Optional[Tuple[Any, Optional[float], Any, Optional[float]]]]
 
 
-def _device_ms(sp_dispatch, sp_wait) -> Optional[float]:
-    """Milliseconds from the start of ``engine/dispatch`` to the end of
-    ``engine/wait``: host clocks round the first enqueue and the token fetch,
-    between which the device is presumed busy.  None with tracing off."""
-    if sp_wait is None:
-        return None
-    return (sp_wait.t_end - sp_dispatch.t_start) * 1e3
+def _thread_cpu() -> Optional[float]:
+    """The calling thread's CPU clock, for the step's split; None with
+    tracing off, which reads no clock.  A system call (trace.py)."""
+    return time.thread_time() if tracer.enabled else None
+
+
+def _host_split(sp, cpu_entry, sp_dispatch, cpu_called, sp_wait, cpu_fetched
+                ) -> Dict[str, float]:
+    """``engine/step`` ``sp``, about to close, split where the device's work
+    begins and ends, in milliseconds.  ``device_ms``: from the start of
+    ``engine/dispatch`` to the end of ``engine/wait``, host clocks round the
+    first enqueue and the token fetch, between which the device is presumed
+    busy.  ``pre_ms``: from the step's entry to there; ``post_ms``: from the
+    fetch's return to now; ``pre_cpu_ms`` / ``post_cpu_ms``: the engine
+    thread's CPU time over the same two intervals (its clock read at their
+    four ends: ``cpu_entry``, ``cpu_called``, ``cpu_fetched``, now), so wall
+    less CPU is what the thread waited for inside its own step."""
+    return {
+        "device_ms": (sp_wait.t_end - sp_dispatch.t_start) * 1e3,
+        "pre_ms": (sp_dispatch.t_start - sp.t_start) * 1e3,
+        "pre_cpu_ms": (cpu_called - cpu_entry) * 1e3,
+        "post_ms": (time.monotonic() - sp_wait.t_end) * 1e3,
+        "post_cpu_ms": (time.thread_time() - cpu_fetched) * 1e3}
 
 
 def _refuse_each(asked, because) -> None:
@@ -1343,6 +1363,7 @@ class InferenceEngineV2:
         if self.adapter_stack is not None:
             args += (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
+        cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         toks, self.caches = self._decode_fwd(self.params, self.caches, *args)
         self._split_ahead()  # the next step's key, behind this program
@@ -1350,6 +1371,7 @@ class InferenceEngineV2:
         sp_wait = tracer.begin("engine/wait", **sub)
         sampled = self._split_stats(np.asarray(toks))
         tracer.end(sp_wait)
+        cpu_fetched = _thread_cpu()
         sp = tracer.begin("engine/finish", **sub)
         rows = np.nonzero(t.active)[0]
         sel = sampled[rows].astype(np.int32)[None, :]  # (1, ns)
@@ -1358,7 +1380,7 @@ class InferenceEngineV2:
         if self._windowed is not None:
             self._window_trim_rows()
         tracer.end(sp)
-        return out, len(rows), _device_ms(sp_dispatch, sp_wait)
+        return out, len(rows), (sp_dispatch, cpu_called, sp_wait, cpu_fetched)
 
     def _split_stats(self, fetched: "np.ndarray") -> "np.ndarray":
         """The tokens of a step's one fetch; an MoE model's two stats behind
@@ -1390,6 +1412,7 @@ class InferenceEngineV2:
         hidden = jnp.asarray(self._spec_hidden) if self_draft else None
         tracer.end(sp)
         hidden_np = None
+        cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         if self_draft:
             emitted, alen, new_hidden, self.caches = self._spec_fwd(
@@ -1408,6 +1431,7 @@ class InferenceEngineV2:
         emitted = np.asarray(emitted)  # (max_seqs, k+1)
         alen = np.asarray(alen)
         tracer.end(sp_wait)
+        cpu_fetched = _thread_cpu()
         sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
         k = self.cfg.spec_k
@@ -1437,7 +1461,8 @@ class InferenceEngineV2:
             if t.gen[r] >= t.budget[r]:
                 self._finish(seq)
         tracer.end(sp)
-        return out, len(active), _device_ms(sp_dispatch, sp_wait)
+        return out, len(active), (sp_dispatch, cpu_called, sp_wait,
+                                  cpu_fetched)
 
     def step(self, temperature: float = 0.0, rng: Optional[jax.Array] = None
              ) -> Dict[int, List[int]]:
@@ -1451,9 +1476,19 @@ class InferenceEngineV2:
         compiled program.  ``engine/step`` has a child span per phase
         (``engine/schedule``, ``build``, ``h2d``, ``dispatch``, ``sample``,
         ``wait``, ``finish``), each carrying ``kind`` and ``step``, and
-        itself ends with ``device_ms`` (first enqueue to fetch: host clocks,
-        the device *presumed* busy between them), ``tokens`` and ``budget``,
-        so a reader needs no join."""
+        itself ends with ``tokens``, ``budget`` and, where it reached the
+        device, its own split (``_host_split``): ``pre_ms`` (entry to the
+        call of the step's program: schedule, build, pack, the unpack
+        program's call), ``device_ms`` (first enqueue to fetch: host clocks,
+        the device *presumed* busy between them), ``post_ms`` (the fetch's
+        return to ``step``'s), and the engine thread's CPU time over the
+        first and the last (``pre_cpu_ms``, ``post_cpu_ms``), so a reader
+        needs no join: the three add up to the span, and wall less CPU is
+        what the thread waited for.  The unpack program is enqueued inside
+        ``engine/h2d``, before ``device_ms`` opens, and that is still the
+        right start: it is a dozen slices of one small buffer, and once it
+        is done the device waits for the step's program like before it, so
+        the wait the host causes ends where ``engine/dispatch`` opens."""
         steady = (not self.waiting and self.running
                   and self._prefilling == 0)
         kind = (("spec" if self._spec_fwd is not None else "decode")
@@ -1470,16 +1505,15 @@ class InferenceEngineV2:
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
+        cpu_entry = _thread_cpu()
         try:
-            out, tokens, device_ms = self._step_impl(temperature, rng, sub)
+            out, tokens, dispatched = self._step_impl(temperature, rng, sub)
         except Exception:
             tracer.end(sp, error=True)
             raise
         emitted = sum(len(v) for v in out.values())
         attrs = {"emitted": emitted, "tokens": tokens,
                  "budget": self.cfg.max_tokens_per_step}
-        if device_ms is not None:  # the step reached the device, tracing on
-            attrs["device_ms"] = device_ms
         if kind == "spec":
             attrs["proposed"] = self.spec_proposed - prop0
             attrs["accepted"] = self.spec_accepted - acc0
@@ -1501,6 +1535,9 @@ class InferenceEngineV2:
                     for k in self._managers]
             attrs["blocks_used_global"] = used[0] if m is not self.kv else 0
             attrs["blocks_used_window"] = used[-1]
+        if sp is not None and dispatched is not None:  # it reached the device
+            # last: ``post_ms`` runs to here
+            attrs.update(_host_split(sp, cpu_entry, *dispatched))
         tracer.end(sp, **attrs)
         recorder.record_step({
             "kind": kind, "t_start": t0, "t_end": time.monotonic(),
@@ -1560,6 +1597,7 @@ class InferenceEngineV2:
             # the pick rows, not the SoA table): the builder's slot vector
             ad_args = (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
+        cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         logits, hidden, self.caches, *rest = self._fwd(
             self.params, self.caches, *batch_args, *ad_args)
@@ -1591,6 +1629,7 @@ class InferenceEngineV2:
         hidden_np = (np.asarray(hidden)
                      if self.cfg.spec_mode == "self_draft" else None)
         tracer.end(sp_wait)
+        cpu_fetched = _thread_cpu()
 
         sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
@@ -1616,7 +1655,7 @@ class InferenceEngineV2:
             if seq.uid in self.table.row_of:
                 self.table.sync(seq)
         tracer.end(sp)
-        return out, tokens, _device_ms(sp_dispatch, sp_wait)
+        return out, tokens, (sp_dispatch, cpu_called, sp_wait, cpu_fetched)
 
     def _burst_decode(self, k: int, temperature: float = 0.0,
                       rng: Optional[jax.Array] = None) -> None:

@@ -14,7 +14,23 @@ Design constraints (ISSUE 9):
   opened and closed live, see below) one ``jax.profiler.TraceAnnotation``,
   which costs a flag test while no profile is being taken.  No sampling
   daemon, no network, no allocation spikes.  ``DSTPU_TRACE=0`` disables
-  all of it (context managers become no-ops).
+  all of it (context managers become no-ops, and no clock is read).
+* **a second host clock where it is asked for** — a live span opened with
+  ``cpu=True`` also reads its thread's CPU time (``time.thread_time()``) at
+  both ends and closes with ``cpu_ms``, the milliseconds the thread was
+  running between them.  Duration less ``cpu_ms`` is the time the thread
+  was NOT running: blocked on the interpreter lock, on a lock of its own,
+  in a fetch from the device, or descheduled; the two clocks cannot tell
+  those apart.  A span closed on another thread than it was opened on has
+  no one thread to ask and carries none.  It is asked for where a reader
+  needs it (``broker/turn``; ``engine/step`` reads the same clock itself at
+  the four points its split is made at) and not on every span, because the
+  thread clock is a system call: on a TPU v5e host a read takes 6 us and
+  slows what follows it, and on every span of a serving step (18 reads) it
+  cost 0.5-1.8 % of the tokens a second (PERF.md section 6, PR 37).  That
+  host also advances the clock in ticks of 10 ms: read ``cpu_ms`` there as
+  a sum over many spans.  To see which phase of a step holds a wait, open
+  that phase's span with ``cpu=True`` for the run.
 * **one mechanism, two clocks** — a span opened with :meth:`Tracer.span`
   or a :meth:`Tracer.begin` / :meth:`Tracer.end` pair also enters and
   leaves a ``TraceAnnotation`` of the same name carrying its small scalar
@@ -92,6 +108,12 @@ class Span:
     # the live span's jax.profiler.TraceAnnotation, entered at begin()
     annotation: Any = dataclasses.field(default=None, repr=False,
                                         compare=False)
+    # a live span opened with ``cpu=True``: its thread, and that thread's CPU
+    # clock (time.thread_time()) where it opened
+    tid: Optional[int] = dataclasses.field(default=None, repr=False,
+                                           compare=False)
+    cpu_start: Optional[float] = dataclasses.field(default=None, repr=False,
+                                                   compare=False)
 
     @property
     def duration_s(self) -> float:
@@ -129,11 +151,13 @@ class Tracer:
         return st
 
     def begin(self, name: str, trace_id: Optional[str] = None,
-              parent_id: Optional[int] = None, **attrs: Any) -> Optional[Span]:
+              parent_id: Optional[int] = None, cpu: bool = False,
+              **attrs: Any) -> Optional[Span]:
         """Open a span (records ``t_start`` now); close with :meth:`end`.
         Inherits trace_id/parent from the current thread's open span unless
-        given explicitly.  Returns None (and records nothing) when
-        disabled."""
+        given explicitly.  With ``cpu`` the thread's CPU clock is read here
+        and at :meth:`end`, which adds ``cpu_ms``.  Returns None (and
+        records nothing, reads no clock) when disabled."""
         if not self.enabled:
             return None
         stack = self._stack()
@@ -147,13 +171,17 @@ class Tracer:
                   span_id=next(self._ids), parent_id=parent_id,
                   t_start=time.monotonic(), t_end=None, attrs=attrs,
                   thread=threading.current_thread().name)
+        if cpu:
+            sp.tid, sp.cpu_start = threading.get_ident(), time.thread_time()
         sp.annotation = _annotate(name, attrs)
         stack.append(sp)
         return sp
 
     def end(self, sp: Optional[Span], **attrs: Any) -> None:
         """Close a span opened by :meth:`begin`, adding ``attrs`` (which the
-        ring keeps; the profiler's annotation has only those of ``begin``).
+        ring keeps; the profiler's annotation has only those of ``begin``)
+        and, for a span opened with ``cpu=True``, ``cpu_ms``, the CPU time
+        of the thread that opened it, if that is the thread closing it.
         Children that an exception left open on this thread are closed with
         it, innermost first, marked ``error``: the phase that failed stays
         in the ring and no annotation stays open."""
@@ -170,6 +198,8 @@ class Tracer:
                 closing.insert(-1, child)
         for s in closing:
             s.t_end = now
+            if s.tid is not None and s.tid == threading.get_ident():
+                s.attrs["cpu_ms"] = (time.thread_time() - s.cpu_start) * 1e3
             ann, s.annotation = s.annotation, None
             if ann is not None:
                 ann.__exit__(None, None, None)
@@ -180,8 +210,8 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, trace_id: Optional[str] = None,
-             **attrs: Any) -> Iterator[Optional[Span]]:
-        sp = self.begin(name, trace_id=trace_id, **attrs)
+             cpu: bool = False, **attrs: Any) -> Iterator[Optional[Span]]:
+        sp = self.begin(name, trace_id=trace_id, cpu=cpu, **attrs)
         try:
             yield sp
         finally:

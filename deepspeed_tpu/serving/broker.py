@@ -625,15 +625,20 @@ class RequestBroker:
                         if r.state in _TERMINAL]:
                 del self._by_rid[rid]
 
-    def _dispatch(self, out: Dict[int, List[int]], now: float) -> None:
+    def _dispatch(self, out: Dict[int, List[int]], now: float
+                  ) -> Tuple[int, int]:
         # engine steps deliver token LISTS: one entry normally, up to
         # spec_k+1 from a speculative step.  A stop token mid-list cancels
         # the request and drops the speculative suffix after it.
+        # → (tokens put on streams, requests that got one): each such
+        # request's HTTP thread wakes, which is what the next step pays for
+        emitted = streams = 0
         for uid, toks in out.items():
             with self._lock:
                 req = self._by_uid.get(uid)
             if req is None:
                 continue
+            before = req.delivered
             for tok in toks:
                 if tok in req.stop_ids:
                     with self._wake:
@@ -653,6 +658,9 @@ class RequestBroker:
                 if uid not in self.engine.running:  # budget exhausted
                     with self._wake:
                         self._finalize_locked(req, "length")
+            emitted += req.delivered - before
+            streams += req.delivered > before
+        return emitted, streams
 
     def _run(self) -> None:
         # ``broker/turn``: the loop's host work between two engine steps,
@@ -709,10 +717,10 @@ class RequestBroker:
                 tracer.end(turn, next="step")
                 turn = idle = None
                 out = self.engine.step(temperature=self.cfg.temperature)
-                turn = tracer.begin("broker/turn")
+                turn = tracer.begin("broker/turn", cpu=True)
                 sp = tracer.begin("broker/emit")
-                self._dispatch(out, time.monotonic())
-                tracer.end(sp)
+                emitted, streams = self._dispatch(out, time.monotonic())
+                tracer.end(sp, emitted=emitted, streams=streams)
                 if self._own_gauges:
                     self.metrics.set_gauges(
                         len(self._queue), self.engine.num_running,

@@ -1,8 +1,26 @@
 """One serving cell of the benchmark, traced, and beside its result line the
-host path by span (PERF.md section 5): the median of every ``engine/*`` and
-``broker/*`` span of the window by kind of step, in ms, with what a step
-spends outside dispatch-to-fetch (its length less ``device_ms``) and the
-``h2d_copies`` / ``h2d_bytes`` its spans carry.
+host path by span (PERF.md section 5): every ``engine/*`` and ``broker/*``
+span of the window by kind of step, in ms, as ``[median, mean, mean cpu_ms,
+mean less mean cpu_ms]`` (the span's length twice, its thread's CPU time, and
+the time the thread was not running; a program from before ``cpu_ms`` gives
+``[median, mean]``), with what a step spends outside dispatch-to-fetch (its
+length less ``device_ms``), the two host parts of the step's own split in the
+same form (``step:pre``, ``step:post``) and the ``h2d_copies`` / ``h2d_bytes``
+its spans carry.  The CPU columns are means because the thread
+clock of the chip's host advances in ticks of 10 ms: one span reads 0 or 10,
+the sum over a window's spans is the thread's CPU time.  The program asks for
+that clock on ``broker/turn`` alone (and ``engine/step`` reads it for its
+split): to see WHICH phase holds a step's wait, open that phase's span with
+``cpu=True`` in ``inference/v2/engine.py`` for the run (``engine/h2d``, say)
+and its row gains the two CPU columns.  ``burst``: the mean ``streams`` and
+``emitted`` of a ``broker/emit`` (the HTTP threads it wakes, and the tokens
+it gave them) beside the mean wait of the device steps' host parts, and that
+wait a stream.  And ``starved``:
+the three sums ``device_starved_pct`` is made of (``pre_ms``, ``post_ms``,
+the turns that ended in a step) beside the time with nothing to run
+(``broker/idle``), in seconds, over the window and over the traced part of
+it, where the device's own idle share (``serve_device_idle_pct``) stands
+beside them and what is left over is a step's launch and the fetch's tail.
 
     chiprun -- python scripts/host_path_by_span.py --workload chat-decode-sat \
         --seed 3400000001 [--root .bench_checkout/parent]
@@ -20,13 +38,20 @@ import json
 import os
 import statistics
 import sys
+import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+STEP_ATTRS = ("h2d_copies", "h2d_bytes")
+
+
 def by_span(spans) -> dict:
-    """→ {kind of step: {span name: median ms, ...}}; ``broker/*`` spans
-    carry no kind and go under ``"loop"``."""
+    """→ {kind of step: {span name: [median ms, mean ms, mean ``cpu_ms``,
+    mean ms less mean ``cpu_ms``], ...}}; the two host parts of a step's own
+    split stand beside the spans in the same form (``step:pre``,
+    ``step:post``), its other numbers (``h2d_copies``, ...) are medians;
+    ``broker/*`` spans carry no kind and go under ``"loop"``."""
     out: dict = {}
     for s in spans:
         name, attrs = s["name"], s["attrs"]
@@ -34,17 +59,89 @@ def by_span(spans) -> dict:
             continue
         ms = (s["t_end"] - s["t_start"]) * 1e3
         kind = out.setdefault(attrs.get("kind", "loop"), {})
-        kind.setdefault(name, []).append(ms)
+        kind.setdefault(name, []).append((ms, attrs.get("cpu_ms")))
         if name == "engine/step" and "device_ms" in attrs:
-            kind.setdefault("host = step - device_ms", []).append(
-                ms - attrs["device_ms"])
-            for k in ("h2d_copies", "h2d_bytes"):
-                if k in attrs:
-                    kind.setdefault(k, []).append(attrs[k])
+            own = {"host = step - device_ms": ms - attrs["device_ms"],
+                   **{k: attrs[k] for k in STEP_ATTRS if k in attrs}}
+            if "pre_ms" in attrs:
+                own["pre + device + post - step"] = (
+                    attrs["pre_ms"] + attrs["device_ms"] + attrs["post_ms"]
+                    - ms)
+                for part in ("pre", "post"):
+                    kind.setdefault(f"step:{part}", []).append(
+                        (attrs[f"{part}_ms"], attrs[f"{part}_cpu_ms"]))
+            for k, v in own.items():
+                kind.setdefault(k, []).append(v)
+
+    def row(values):
+        if not isinstance(values[0], tuple):
+            return round(statistics.median(values), 3)
+        ms = [m for m, _ in values]
+        cpu = [c for _, c in values if c is not None]
+        cols = [statistics.median(ms), statistics.fmean(ms)]
+        if len(cpu) == len(ms):
+            cols += [statistics.fmean(cpu), cols[1] - statistics.fmean(cpu)]
+        return [round(x, 3) for x in cols]
+
     return {kind: {"steps": len(v.get("engine/step", ())),
-                   **{n: round(statistics.median(x), 3)
-                      for n, x in sorted(v.items())}}
+                   **{n: row(x) for n, x in sorted(v.items())}}
             for kind, v in sorted(out.items())}
+
+
+def burst(spans) -> dict:
+    """What a step's wait scales with, counted where it is caused: the mean
+    ``streams`` (requests that got a token: each one's HTTP thread wakes) and
+    ``emitted`` of the ``broker/emit`` spans, and by kind of step the mean
+    wall less CPU of the host parts of the steps that reached the device,
+    with that wait a stream.  Empty for a program from before those
+    attributes."""
+    emits = [s["attrs"] for s in spans
+             if s["name"] == "broker/emit" and "streams" in s["attrs"]]
+    waits: dict = {}
+    for a in (s["attrs"] for s in spans if s["name"] == "engine/step"):
+        if "pre_cpu_ms" in a:
+            waits.setdefault(a["kind"], []).append(
+                (a["pre_ms"] - a["pre_cpu_ms"])
+                + (a["post_ms"] - a["post_cpu_ms"]))
+    if not emits or not waits:
+        return {}
+    streams = statistics.fmean(a["streams"] for a in emits)
+    out = {"emits": len(emits), "streams_mean": round(streams, 4),
+           "emitted_mean": round(
+               statistics.fmean(a["emitted"] for a in emits), 4)}
+    for kind, ms in sorted(waits.items()):
+        out[f"{kind}_wait_ms_mean"] = round(statistics.fmean(ms), 4)
+        if streams:
+            out[f"{kind}_wait_ms_a_stream"] = round(
+                statistics.fmean(ms) / streams, 4)
+    return out
+
+
+def starved(spans, t0: float, t1: float) -> dict:
+    """What ``device_starved_pct`` sums, and the time with nothing to run,
+    as far as each lies inside ``[t0, t1)``, in seconds."""
+    def inside(a, b):
+        return max(0.0, min(b, t1) - max(a, t0))
+
+    out = {"seconds": t1 - t0, "steps": 0, "pre_s": 0.0, "post_s": 0.0,
+           "turn_s": 0.0, "nothing_to_run_s": 0.0}
+    for s in spans:
+        a = s["attrs"]
+        if s["name"] == "engine/step" and "pre_ms" in a:
+            out["steps"] += t0 <= s["t_end"] < t1
+            out["pre_s"] += inside(s["t_start"],
+                                   s["t_start"] + a["pre_ms"] / 1e3)
+            out["post_s"] += inside(s["t_end"] - a["post_ms"] / 1e3,
+                                    s["t_end"])
+        elif s["name"] == "broker/turn" and a.get("next") == "step":
+            out["turn_s"] += inside(s["t_start"], s["t_end"])
+        elif s["name"] == "broker/idle":
+            out["nothing_to_run_s"] += inside(s["t_start"], s["t_end"])
+    out["starved_pct"] = 100.0 * (out["pre_s"] + out["post_s"]
+                                  + out["turn_s"]) / out["seconds"]
+    out["nothing_to_run_pct"] = 100.0 * out["nothing_to_run_s"] / \
+        out["seconds"]
+    return {k: round(v, 4) for k, v in out.items()}
 
 
 def main() -> int:
@@ -77,11 +174,39 @@ def main() -> int:
         return Driver
 
     run.load_module = load_module
+    # when the profiler ran, on the spans' clock
+    from benchmark import common
+    traced, start, stop = [], common.TraceSession.start, \
+        common.TraceSession.stop
+
+    def started(self):
+        start(self)
+        traced.append(time.monotonic())
+
+    def stopped(self):
+        traced.append(time.monotonic())
+        stop(self)
+
+    common.TraceSession.start, common.TraceSession.stop = started, stopped
     result = run.run_cell(opts.workload, opts.seed, opts.seconds, True,
                           root=root)
+    window = seen["window"]
+    in_trace = starved(seen["spans"], *traced)
+    idle = result["metrics"].get("serve_device_idle_pct", {}).get("value")
+    if idle is not None and in_trace["steps"]:
+        # what neither host clock sees: the launch and the fetch's tail
+        in_trace["device_idle_pct"] = round(idle, 4)
+        left = idle - in_trace["starved_pct"] - in_trace["nothing_to_run_pct"]
+        in_trace["residual_pct"] = round(left, 4)
+        in_trace["residual_ms_a_step"] = round(
+            left / 100.0 * in_trace["seconds"] / in_trace["steps"] * 1e3, 4)
     line = {"workload": opts.workload, "seed": opts.seed, "root": opts.root,
             "device": result["device"]["kind"],
-            "by_span_ms_p50": by_span(seen["spans"]),
+            "by_span_ms": by_span(seen["spans"]),
+            "burst": burst(seen["spans"]),
+            "starved": {"window": starved(seen["spans"], window["t_open"],
+                                          window["t_close"]),
+                        "traced": in_trace},
             "idle_gaps": result["breakdown"]["idle_gaps"]}
     os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
     with open(os.path.join(HERE, "chiprun_out", "host_path_by_span.jsonl"),

@@ -1,0 +1,454 @@
+"""The host path's second clock (ISSUE 37): ``cpu_ms`` on a live span that
+asks for it (``cpu=True``), the step's own split (``pre_ms`` / ``post_ms``
+and their CPU twins) on an engine at test size, ``emitted`` / ``streams`` on
+``broker/emit``, and the seven readers that turn them into per-layer
+metrics, each over hand-made spans."""
+
+import importlib.util
+import json
+import os
+import statistics
+import threading
+import time
+
+import jax
+import pytest
+
+from benchmark import run
+from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+from deepspeed_tpu.models import transformer as tfm
+from deepspeed_tpu.observability import Tracer
+from deepspeed_tpu.observability import tracer as global_tracer
+from deepspeed_tpu.serving import RequestBroker, ServingConfig
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V2 = dict(max_tokens_per_step=16, max_seqs=4, block_size=8, num_blocks=64,
+          max_blocks_per_seq=8, dtype="float32")
+SPLIT = ("pre_ms", "pre_cpu_ms", "post_ms", "post_cpu_ms")
+
+
+# -- the tracer's second clock -----------------------------------------------
+
+
+def _spin(seconds):
+    """Burn the thread's own CPU for that long, by its own clock."""
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        pass
+
+
+def test_a_span_that_sleeps_reads_little_cpu():
+    tr = Tracer(enabled=True)
+    with tr.span("sleeps", cpu=True) as sp:
+        time.sleep(0.03)
+    assert sp.duration_s >= 0.03
+    assert 0.0 <= sp.attrs["cpu_ms"] < 5.0
+
+
+def test_a_span_that_spins_reads_its_wall_time():
+    """Within 30 % of the wall clock, and never over it by more than a
+    millisecond.  A loaded machine takes the core away mid-spin, for as
+    long as its load lasts, and that is the very thing wall less CPU reads
+    (six workers failed a best of five here).  So every attempt is held to
+    the thread's own two clocks read around the span, which pin ``cpu_ms``
+    whatever the load: the 30 ms the spin burned and no more than the thread
+    burned, and no more time off the core than those clocks saw.  The wall's
+    30 % is held in the first attempt that keeps its core, if one does."""
+    tr = Tracer(enabled=True)
+    for _ in range(10):
+        wall0, cpu0 = time.monotonic(), time.thread_time()
+        with tr.span("spins", cpu=True) as sp:
+            _spin(0.03)
+        own_cpu_ms = (time.thread_time() - cpu0) * 1e3
+        own_wall_ms = (time.monotonic() - wall0) * 1e3
+        wall_ms, cpu_ms = sp.duration_s * 1e3, sp.attrs["cpu_ms"]
+        assert 29.99 <= cpu_ms <= min(own_cpu_ms, wall_ms + 1.0) + 1e-6
+        assert wall_ms - cpu_ms <= own_wall_ms - own_cpu_ms + 0.5
+        if wall_ms - cpu_ms <= 0.3 * wall_ms:
+            break
+
+
+def _ended_elsewhere(tr):
+    sp = tr.begin("handed-over", cpu=True)
+    other = threading.Thread(target=tr.end, args=(sp,))
+    other.start()
+    other.join(timeout=30)
+    assert not other.is_alive()
+    return sp
+
+
+@pytest.mark.parametrize("make", [
+    lambda tr: tr.add_span("retro", 1.0, 2.0, attrs={"n": 1}),
+    lambda tr: tr.add_event("instant"),
+    _ended_elsewhere,
+    lambda tr: tr.end(sp := tr.begin("not-asked-for")) or sp,
+], ids=["add_span", "add_event", "ended-on-another-thread", "not-asked-for"])
+def test_no_cpu_ms_where_there_is_no_one_thread_to_ask_or_nobody_asked(make):
+    tr = Tracer(enabled=True)
+    sp = make(tr)
+    assert sp.t_end is not None and "cpu_ms" not in sp.attrs
+    assert tr.spans()[-1] is sp
+
+
+@pytest.mark.parametrize("enabled, reads", [(False, 0), (True, 4)],
+                         ids=["disabled", "every-edge-that-asks"])
+def test_the_thread_clock_is_read_at_the_edges_that_ask_and_never_when_off(
+        monkeypatch, enabled, reads):
+    """Two live spans that ask have four edges; one that does not ask, and
+    the retroactive ones, read nothing (a read is a system call: 6 us on
+    the chip's host)."""
+    tr = Tracer(enabled=enabled)
+    clock, calls = time.thread_time, []
+
+    def counted():
+        calls.append(1)
+        return clock()
+
+    monkeypatch.setattr(time, "thread_time", counted)
+    tr.end(tr.begin("live", cpu=True, kind="decode"), emitted=3)
+    tr.end(tr.begin("its-sibling", cpu=True, kind="decode"))
+    tr.end(tr.begin("does-not-ask", kind="decode"))
+    tr.add_span("retro", 1.0, 2.0)
+    tr.add_event("instant")
+    assert len(calls) == reads
+    if enabled:
+        assert all(s.attrs["cpu_ms"] >= 0.0 for s in tr.spans()[:2])
+
+
+def test_a_span_inside_another_reads_its_own_share():
+    tr = Tracer(enabled=True)
+    with tr.span("outer", cpu=True) as outer:
+        _spin(0.002)
+        with tr.span("inner", cpu=True) as inner:
+            _spin(0.002)
+    assert 1.9 <= inner.attrs["cpu_ms"] <= outer.attrs["cpu_ms"] - 1.9
+
+
+def test_cpu_ms_is_exported_like_any_attribute():
+    tr = Tracer(enabled=True)
+    with tr.span("live", cpu=True, kind="decode") as sp:
+        pass
+    assert tr.spans()[0].to_dict()["attrs"]["cpu_ms"] == sp.attrs["cpu_ms"]
+    event = tr.to_chrome_trace()["traceEvents"][-1]
+    assert event["name"] == "live"
+    assert event["args"] == {"kind": "decode", "cpu_ms": sp.attrs["cpu_ms"]}
+    _, (wire,) = tr.export_since(0)
+    assert wire["attrs"]["cpu_ms"] == sp.attrs["cpu_ms"]
+
+
+# -- the step's own split, on an engine at test size --------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = tfm.get_config("tiny", dtype="float32")
+    return cfg, tfm.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _engine(tiny_model, **over):
+    cfg, params = tiny_model
+    return InferenceEngineV2(cfg, params, V2Config(**{**V2, **over}))
+
+
+def _run_dry(eng, requests):
+    for n, budget in requests:
+        eng.put(list(range(1, 1 + n)), budget)
+    got = 0
+    while eng.running or eng.waiting:
+        got += sum(len(v) for v in eng.step().values())
+    return got
+
+
+@pytest.mark.parametrize("over, kinds", [
+    ({}, {"mixed", "decode"}),
+    ({"spec_mode": "self_draft", "spec_k": 2}, {"mixed", "spec"}),
+], ids=["plain", "speculative"])
+def test_every_device_step_carries_its_split(devices, tiny_model, over,
+                                             kinds):
+    global_tracer.clear()
+    eng = _engine(tiny_model, **over)
+    eng.step()  # nothing to run: dispatches nothing
+    assert _run_dry(eng, ((5, 6), (20, 4), (3, 8))) == 18
+    steps = global_tracer.spans(name="engine/step")
+    assert "device_ms" not in steps[0].attrs
+    assert not any(k in steps[0].attrs for k in SPLIT)
+    assert {s.attrs["kind"] for s in steps[1:]} == kinds
+    spans = global_tracer.spans()
+    short = []
+    for s in steps[1:]:
+        a = s.attrs
+        assert all(k in a for k in SPLIT + ("device_ms",))
+        # the three parts are the span, to within the clock reads (a loaded
+        # machine can take the core away between two of them: the median)
+        whole = a["pre_ms"] + a["device_ms"] + a["post_ms"]
+        assert whole <= s.duration_s * 1e3 + 1e-6
+        short.append(s.duration_s * 1e3 - whole)
+        for part in ("pre", "post"):
+            assert 0.0 <= a[f"{part}_cpu_ms"] <= a[f"{part}_ms"] + 1.0
+        # the split lies where the children do: ``pre_ms`` ends where
+        # ``engine/dispatch`` opens, ``post_ms`` opens where ``engine/wait``
+        # closes; the step reads the second clock itself, at those points,
+        # and no span of it asks for one
+        kids = {k.name: k for k in spans if k.parent_id == s.span_id}
+        assert "cpu_ms" not in a
+        assert not any("cpu_ms" in k.attrs for k in kids.values())
+        assert a["pre_ms"] == pytest.approx(
+            (kids["engine/dispatch"].t_start - s.t_start) * 1e3)
+        assert a["pre_ms"] >= kids["engine/h2d"].duration_s * 1e3
+        assert a["post_ms"] >= kids["engine/finish"].duration_s * 1e3
+        assert a["device_ms"] == pytest.approx(
+            (kids["engine/wait"].t_end
+             - kids["engine/dispatch"].t_start) * 1e3)
+    assert statistics.median(short) < 0.2
+
+
+@pytest.mark.parametrize("enabled, reads_a_step", [(False, 0), (True, 4)],
+                         ids=["tracing-off", "tracing-on"])
+def test_a_device_step_reads_the_thread_clock_four_times_or_never(
+        devices, tiny_model, monkeypatch, enabled, reads_a_step):
+    """The split's four ends (entry, the program's call, the fetch's return,
+    the step's end) and nothing else; with tracing off no clock is read and
+    nothing is recorded."""
+    global_tracer.clear()
+    monkeypatch.setattr(global_tracer, "enabled", enabled)
+    eng = _engine(tiny_model)
+    clock, calls = time.thread_time, []
+
+    def counted():
+        calls.append(1)
+        return clock()
+
+    monkeypatch.setattr(time, "thread_time", counted)
+    assert _run_dry(eng, ((5, 3),)) == 3
+    steps = global_tracer.spans(name="engine/step")
+    assert len(calls) == reads_a_step * len(steps)
+    if not enabled:
+        assert global_tracer.spans() == []
+    else:
+        assert len(steps) == 3 and all("pre_cpu_ms" in s.attrs for s in steps)
+
+
+def test_broker_emit_counts_what_the_clients_got(devices, tiny_model):
+    """``emitted`` / ``streams`` on ``broker/emit``: the tokens put on
+    streams and the requests that got one, which is what the clients read;
+    a stop token is not put on a stream and is not counted."""
+    global_tracer.clear()
+    broker = RequestBroker(_engine(tiny_model), ServingConfig())
+    # queued before the loop starts, so that all three ride in one step
+    handles = [broker.submit(list(range(1, 1 + n)), max_new_tokens=m)
+               for n, m in ((5, 6), (20, 4), (3, 8))]
+    broker.start()
+    try:
+        tokens = [h.result(timeout=120) for h in handles]
+        first = tokens[0]  # the same prompt again, stopped at its third
+        tokens.append(broker.submit(
+            list(range(1, 6)), max_new_tokens=6,
+            stop_token_ids=[first[2]]).result(timeout=120))
+    finally:
+        broker.stop(drain=True, timeout=60)
+    got = [len(t) for t in tokens]
+    assert got == [6, 4, 8, first.index(first[2])]
+    emits = global_tracer.spans(name="broker/emit")
+    assert sum(e.attrs["emitted"] for e in emits) == sum(got)
+    # one token a stream a step without speculation
+    assert all(e.attrs["streams"] == e.attrs["emitted"] for e in emits)
+    assert max(e.attrs["streams"] for e in emits) == 3
+    steps = global_tracer.spans(name="engine/step")
+    # the step that sampled the stop token emitted it; the broker did not
+    assert sum(s.attrs["emitted"] for s in steps) == sum(got) + 1
+    # the turn asks for the second clock, its children do not
+    for name, asks in (("broker/turn", True), ("broker/emit", False),
+                       ("broker/admit", False)):
+        assert all(("cpu_ms" in s.attrs) == asks
+                   for s in global_tracer.spans(name=name))
+
+
+# -- the readers, over hand-made spans ----------------------------------------
+
+T_OPEN, T_CLOSE = 100.0, 110.0
+
+
+def span(name, t_start, t_end, **attrs):
+    return {"name": name, "t_start": t_start, "t_end": t_end, "attrs": attrs}
+
+
+def step(kind, t_start, pre, pre_cpu, post, post_cpu, device_ms=20.0,
+         **attrs):
+    return span("engine/step", t_start,
+                t_start + (pre + device_ms + post) / 1e3, kind=kind,
+                device_ms=device_ms, pre_ms=pre, pre_cpu_ms=pre_cpu,
+                post_ms=post, post_cpu_ms=post_cpu, **attrs)
+
+
+def fetched_at(t, emitted, kind="decode"):
+    """A device step whose fetch returned at ``t``, a millisecond before
+    the step did."""
+    return span("engine/step", t - 0.02, t + 0.001, kind=kind, post_ms=1.0,
+                emitted=emitted)
+
+
+def obs_of(spans):
+    return {"window": {"t_open": T_OPEN, "t_close": T_CLOSE,
+                       "seconds": T_CLOSE - T_OPEN}, "spans": list(spans)}
+
+
+STEPS = [
+    step("decode", 100.0, 4.0, 1.0, 0.5, 0.4, h2d_copies=1),  # waited 3.1
+    step("decode", 101.0, 3.0, 1.0, 0.2, 0.2, h2d_copies=1),  # 2.0
+    step("decode", 102.0, 5.0, 1.0, 1.0, 0.5, h2d_copies=1),  # 4.5
+    step("mixed", 103.0, 2.0, 1.5, 0.3, 0.1, tokens=512,
+         attn_q_slots=752, h2d_copies=1),  # 0.7
+    step("mixed", 104.0, 3.0, 1.0, 0.4, 0.4, tokens=256,
+         attn_q_slots=400, h2d_copies=2),  # 2.0
+    # scheduled nothing: no split, no copies, no slots
+    span("engine/step", 105.0, 105.001, kind="mixed", tokens=0, emitted=0),
+]
+TURNS = [
+    span("broker/turn", 100.5, 100.501, next="step", cpu_ms=0.9),  # 0.1
+    span("broker/turn", 100.6, 100.603, next="step", cpu_ms=1.0),  # 2.0
+    span("broker/turn", 100.7, 100.702, next="step"),  # no second clock
+    span("broker/turn", 100.8, 100.850, next="idle", cpu_ms=1.0),
+]
+# ten intervals: nine of 20 ms that end in steps of 10 tokens, one of 60 ms
+# that ends in a step of 100: the tokens' ninth decile is 60 ms where the
+# intervals' is 24; then an idle wait, whose pair of steps is left out
+_TIMES = [100.0 + 0.02 * i for i in range(6)] + [100.16] + [
+    100.16 + 0.02 * i for i in range(1, 5)]
+INTERVALS = [fetched_at(t, 100 if t == 100.16 else 10) for t in _TIMES] + [
+    span("broker/idle", 100.25, 100.70),
+    fetched_at(100.74, 32), fetched_at(100.76, 10)]
+CASES = {
+    # means, not medians: the chip's host ticks its thread clock at 10 ms
+    "decode_host_wait_ms_mean": (STEPS, 3.2),
+    "mixed_host_wait_ms_mean": (STEPS, 1.35),
+    "loop_turn_wait_ms_mean": (TURNS, 1.05),
+    # pre + post of five steps, the three turns that ended in a step
+    "device_starved_pct": (STEPS + TURNS, 100.0 * (19.4 + 6.0) / 1e4),
+    "step_interval_p90_ms": (INTERVALS, 60.0),
+    "attn_q_fill_pct": (STEPS, 100.0 * 768 / 1152),
+    "step_h2d_copies_max": (STEPS, 2.0),
+}
+NEW = ("cpu_ms", "emitted", "streams") + SPLIT
+
+
+def reader(name):
+    return run.load_module(os.path.join(ROOT, "benchmark"), "layer_metrics",
+                           name, "metric").read
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_reader_over_hand_made_spans(name):
+    spans, want = CASES[name]
+    assert reader(name)(obs_of(spans)) == pytest.approx(want, rel=1e-9)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_reader_finds_nothing_where_the_attribute_is_absent(name):
+    """A program from before its attribute (and an empty window) reads
+    ``None``, never 0."""
+    read = reader(name)
+    assert read(obs_of([])) is None
+    old = {"attn_q_fill_pct": ("attn_q_slots",),
+           "step_h2d_copies_max": ("h2d_copies",)}.get(name, NEW)
+    spans = [span(s["name"], s["t_start"], s["t_end"],
+                  **{k: v for k, v in s["attrs"].items() if k not in old})
+             for s in CASES[name][0]]
+    assert read(obs_of(spans)) is None
+
+
+def test_the_wait_is_a_mean_because_a_host_may_tick_its_thread_clock():
+    """The chip's host advances ``time.thread_time()`` in ticks of 10 ms
+    (PERF.md section 6, PR 37).  Twenty steps of 4.5 ms on the host, 1 ms of
+    it the thread's own work: under such a clock eighteen read 0 ms of CPU
+    and two read 10.  The mean is the 3.5 ms they waited; a median of the
+    steps' differences would read 4.5, the wall clock."""
+    ticked = [step("decode", 100.0 + i, 4.0, 10.0 if i in (3, 13) else 0.0,
+                   0.5, 0.0) for i in range(20)]
+    turns = [span("broker/turn", 100.5 + i, 100.502 + i, next="step",
+                  cpu_ms=10.0 if i == 7 else 0.0) for i in range(20)]
+    assert reader("decode_host_wait_ms_mean")(obs_of(ticked)) == \
+        pytest.approx(3.5)
+    assert reader("loop_turn_wait_ms_mean")(obs_of(turns)) == \
+        pytest.approx(1.5)
+
+
+def test_the_interval_decile_weighs_tokens_and_skips_idle_pairs():
+    read = reader("step_interval_p90_ms")
+    even = [dict(s, attrs=dict(s["attrs"], emitted=10))
+            for s in INTERVALS if s["name"] == "engine/step"][:11]
+    assert read(obs_of(even)) == pytest.approx(24.0)  # every weight alike
+    # without the idle span the long pair counts: 32 tokens at 500 ms
+    no_idle = [s for s in INTERVALS if s["name"] != "broker/idle"]
+    assert read(obs_of(no_idle)) == pytest.approx(500.0)
+    assert read(obs_of(INTERVALS[-3:])) == pytest.approx(20.0)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_new_entry_finds_its_file_and_its_cells(name):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    (entry,) = [m for m in spec["per_layer"] if m["name"] == name]
+    assert spec["per_layer"].index(entry) >= 46  # appended, none moved
+    assert callable(reader(name))
+    assert entry["layer"] in {m["layer"] for m in spec["per_layer"][:46]}
+    assert entry["source"] == ("program_counter"
+                               if name == "step_h2d_copies_max"
+                               else "program_span")
+    moved = next(m for m in spec["end_to_end"] if m["name"] == entry["moves"])
+    if name == "decode_host_wait_ms_mean":
+        assert sorted(entry["workloads"]) == [
+            "chat-decode-sat", "nemotron3-chat-wide-sat", "olmoe-decode-sat"]
+        assert moved["name"] == "serve_out_tokens_per_s"
+    else:
+        assert sorted(entry["workloads"]) == sorted(moved["workloads"])
+        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 5
+    for cell in entry["workloads"]:
+        assert cell in moved["workloads"]
+        assert entry in run.metrics_of(spec, "per_layer", cell)
+
+
+# -- scripts/host_path_by_span.py ----------------------------------------------
+
+
+def test_the_by_span_script_prints_both_clocks_and_the_three_sums():
+    spec = importlib.util.spec_from_file_location(
+        "host_path_by_span", os.path.join(ROOT, "scripts",
+                                          "host_path_by_span.py"))
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    spans = STEPS + TURNS + [
+        span("engine/h2d", 100.0, 100.004, kind="decode", cpu_ms=1.0),
+        span("engine/h2d", 101.0, 101.003, kind="decode", cpu_ms=1.0),
+        span("engine/wait", 100.1, 100.12, kind="decode"),  # the parent's
+        span("broker/idle", 109.5, 110.5)]
+    table = script.by_span(spans)
+    assert table["decode"]["steps"] == 3
+    # [median, mean, mean cpu_ms, mean less mean cpu_ms]
+    assert table["decode"]["engine/h2d"] == [3.5, 3.5, 1.0, 2.5]
+    assert table["decode"]["engine/wait"] == [20.0, 20.0]
+    assert table["decode"]["step:pre"] == [4.0, 4.0, 1.0, 3.0]
+    assert table["decode"]["step:post"] == [0.5, 0.567, 0.367, 0.2]
+    assert table["decode"]["h2d_copies"] == 1.0
+    assert table["decode"]["pre + device + post - step"] == 0.0
+    assert table["loop"]["broker/idle"] == [1000.0, 1000.0]
+    assert table["loop"]["broker/turn"][:2] == [2.5, 14.0]  # one without
+    whole = script.starved(spans, T_OPEN, T_CLOSE)
+    assert whole["steps"] == 5
+    assert (whole["pre_s"], whole["post_s"], whole["turn_s"]) == (
+        0.017, 0.0024, 0.006)
+    assert whole["nothing_to_run_s"] == 0.5  # clipped at the window's close
+    assert whole["starved_pct"] == pytest.approx(0.254)
+    # an interval that cuts a step's ``pre_ms`` in two takes its half
+    part = script.starved(spans, 100.002, 100.5)
+    assert part["pre_s"] == 0.002 and part["steps"] == 1
+    # the burst where it is caused: streams and tokens an emit, beside the
+    # steps' mean wait by kind and that wait a stream
+    assert script.burst(spans) == {}  # no ``broker/emit`` counted anything
+    emits = [span("broker/emit", 100.5, 100.501, emitted=32, streams=32),
+             span("broker/emit", 100.6, 100.601, emitted=36, streams=32),
+             span("broker/emit", 100.7, 100.701)]  # the parent's
+    assert script.burst(spans + emits) == {
+        "emits": 2, "streams_mean": 32.0, "emitted_mean": 34.0,
+        "decode_wait_ms_mean": 3.2, "decode_wait_ms_a_stream": 0.1,
+        "mixed_wait_ms_mean": 1.35, "mixed_wait_ms_a_stream": 0.0422}
+    assert script.burst(emits) == {}  # no step with the second clock

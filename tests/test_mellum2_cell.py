@@ -61,17 +61,20 @@ def _ordered():
 
 class _Completions(http.server.BaseHTTPRequestHandler):
     """``/v1/completions`` as the generator reads it: one event a token,
-    then the finish reason, then ``[DONE]``; notes who arrived when."""
-
-    arrived = []
+    then the finish reason, then ``[DONE]``; notes whose connection was
+    accepted when."""
 
     def do_POST(self):
         body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
-        self.arrived.append(body["prompt"])
+        # the handler threads race for the interpreter lock (under six test
+        # workers one can wait longer for it than the 20 ms between two
+        # clients' first requests: the driver's run of PR 36 saw two swapped),
+        # so a request is filed under its connection's place in the accept
+        # loop, which takes them one at a time in the order they were made
+        self.server.arrived[self.server.accepted[self.client_address]] = \
+            body["prompt"]
         # a server that answers at once has eight client threads and their
-        # handlers spin on one interpreter lock, and a handler can then wait
-        # longer for it than the 20 ms between two clients' first requests
-        # (the order noted here swapped one run in three): answer in 5 ms
+        # handlers spin on one interpreter lock: answer in 5 ms
         time.sleep(0.005)
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream")
@@ -90,15 +93,31 @@ class _Completions(http.server.BaseHTTPRequestHandler):
         pass
 
 
-def test_ordered_start_sends_first_requests_in_client_order():
-    _Completions.arrived = []
-    class Server(http.server.ThreadingHTTPServer):
-        # past the default backlog of 5 a connection's SYN is dropped and
-        # its client stalls a second, past the window's end
-        request_queue_size = 128
+class _Server(http.server.ThreadingHTTPServer):
+    # past the default backlog of 5 a connection's SYN is dropped and
+    # its client stalls a second, past the window's end
+    request_queue_size = 128
 
-    server = Server(("127.0.0.1", 0), _Completions)
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.accepted = {}  # a connection's (host, port) -> its place
+        self.arrived = {}   # place -> the prompt it carried
+
+    def get_request(self):
+        request, address = super().get_request()
+        self.accepted[address] = len(self.accepted)
+        return request, address
+
+
+def test_ordered_start_sends_first_requests_in_client_order():
+    server = _Server(("127.0.0.1", 0), _Completions)
     threading.Thread(target=server.serve_forever, daemon=True).start()
+    # eight clients, their handlers and the accept loop share this process's
+    # interpreter lock, and a thread that wakes queues for it behind every
+    # runnable one for a switch interval each: at the default 5 ms a client
+    # can reach its connect later than the client 20 ms behind it
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(0.0005)
     try:
         t_open = time.monotonic() + 0.5
         out = _ordered().run({
@@ -106,13 +125,15 @@ def test_ordered_start_sends_first_requests_in_client_order():
             "port": server.server_port, "t_open": t_open,
             "t_close": t_open + 0.4, "timeout_s": 10.0})
     finally:
+        sys.setswitchinterval(interval)
         server.shutdown()
         server.server_close()
     firsts = [loadgen.draw_request(11, c, 0, TRAFFIC, 256)["prompt"]
               for c in range(8)]
     # client order, not a race (this server answers at once, so a client's
     # second request may come before the next client's first)
-    assert [p for p in _Completions.arrived if p in firsts] == firsts
+    arrived = [p for _, p in sorted(server.arrived.items())]
+    assert [p for p in arrived if p in firsts] == firsts
     records = out["records"]
     assert all(r["status"] in ("ok", "cut") for r in records)
     by_client = {c: [r for r in records if r["stream"] == c] for c in range(8)}
