@@ -318,6 +318,15 @@ class InferenceEngineV2:
             two_pools=self.kv_win is not None,
             adapters=self.adapter_stack is not None)
         self._h2d = None
+        # The NEXT decode step's buffer and its fields on the device, made at
+        # the end of the step before while no streaming thread is awake
+        # (``_stage_next``: the buffer's views, the device's fields, the
+        # copy's count), and what the step under way did with the ones staged
+        # for it: ``"used"`` / ``"fresh"`` (``_decode_inputs``), and the bytes
+        # it dropped unused
+        self._staged: Optional[Tuple[Any, Dict[str, jax.Array], Any]] = None
+        self._stage_use: Optional[str] = None
+        self._stage_dropped = 0
         dt = jnp.dtype(self.cfg.dtype)
 
         def pool(layers, blocks):
@@ -1267,22 +1276,27 @@ class InferenceEngineV2:
         return np.where(t.temp >= 0.0, t.temp,
                         np.float32(temperature)).astype(np.float32)
 
-    def _pack_decode(self, temperature: float) -> "np.ndarray":
-        """The decode step's host inputs off the SoA table (padded static
-        shapes; inactive rows carry ctx 0), in their one buffer."""
+    def _decode_fields(self, temperature: float) -> Dict[str, "np.ndarray"]:
+        """The decode step's host inputs off the SoA table, by the names of
+        its layout's fields (padded static shapes; inactive rows carry ctx
+        0)."""
         t = self.table
-        buf = self._decode_layout.new()
-        v = self._decode_layout.views(buf)
-        v["token_ids"][:] = t.next_tok
-        v["position_ids"][:] = t.ctx
-        np.multiply(t.ctx + 1, t.active, out=v["context_lens"])
-        v["temps"][:] = self._row_temps(temperature)
-        v["seeds"][:] = t.seed
-        v["block_tables"][:] = t.block_tables
+        fields = {"token_ids": t.next_tok, "position_ids": t.ctx,
+                  "context_lens": (t.ctx + 1) * t.active,
+                  "temps": self._row_temps(temperature), "seeds": t.seed,
+                  "block_tables": t.block_tables}
         if t.win_tables is not None:
-            v["win_tables"][:] = t.win_tables
+            fields["win_tables"] = t.win_tables
         if self.adapter_stack is not None:
-            v["row_adapter"][:] = t.adapter
+            fields["row_adapter"] = t.adapter
+        return fields
+
+    def _pack_decode(self, temperature: float) -> "np.ndarray":
+        """``_decode_fields`` in their one buffer."""
+        buf = self._decode_layout.new()
+        fields = self._decode_fields(temperature)
+        for name, view in self._decode_layout.views(buf).items():
+            view[...] = fields[name]
         return buf
 
     def _to_device(self, layout: StepLayout, buf: "np.ndarray"
@@ -1301,6 +1315,68 @@ class InferenceEngineV2:
         if "win_tables" in fields:
             return fields["block_tables"], fields["win_tables"]
         return fields["block_tables"]
+
+    def _stage_next(self, temperature: float, sub: Dict[str, Any]) -> None:
+        """At the end of a step, once its bookkeeping is done: where the next
+        step will be a decode step unless the caller changes the table first
+        (nothing waits, nothing prefills, no speculation), make that step's
+        one copy NOW, as ``_decode_step_fast`` would at its head: open the
+        windowed pool's next blocks, pack the buffer, hand it to the unpack
+        program.  ``step`` then returns to a broker that wakes a streaming
+        thread a row, and the next step's first trip into the runtime is its
+        program's call, not a small copy that lets go of the interpreter lock
+        and queues the engine thread behind all of them (PERF.md section 5).
+        The copy is counted with what is staged, for the step that runs on
+        it; this step's own count is on its span already."""
+        if (self.waiting or not self.running or self._prefilling
+                or self._spec_fwd is not None):
+            return
+        sp = tracer.begin("engine/stage", **sub)
+        if self._windowed is not None:
+            self._window_open_blocks()
+        buf = self._pack_decode(temperature)
+        self._h2d = None
+        self._staged = (self._decode_layout.views(buf),
+                        self._to_device(self._decode_layout, buf), self._h2d)
+        tracer.end(sp)
+
+    def _take_staged(self, temperature: Optional[float] = None):
+        """What the step before staged, off the engine, if the step under
+        way is a decode step (it passes its ``temperature``) and the staged
+        buffer is byte for byte the one it would pack; else None, with the
+        bytes dropped unused counted for the span.  ``put``, ``cancel``, a
+        stop token's ``_finish``, another ``temperature`` or adapter row, a
+        caller's burst: all of it shows in the buffer, so no path has a
+        counter to remember.  Compared a field at a time as ``bytes``, which
+        holds the interpreter lock throughout: packing a second buffer does
+        not (``np.zeros`` and an assignment let go of it from 500 elements
+        on), and the engine thread would queue behind every streaming thread
+        the broker just woke, the very wait the staging takes out."""
+        staged, self._staged = self._staged, None
+        self._stage_dropped = 0
+        if staged is None:
+            return None
+        if temperature is not None and all(
+                staged[0][name].tobytes() == np.ascontiguousarray(
+                    x, staged[0][name].dtype).tobytes()
+                for name, x in self._decode_fields(temperature).items()):
+            return staged
+        self._stage_dropped = self._decode_layout.size * 4
+        return None
+
+    def _decode_inputs(self, temperature: float) -> Dict[str, jax.Array]:
+        """The decode step's fields on the device: the ones the step before
+        staged where they are still what this step needs, else a copy made
+        now (the first decode step after an admission, a cancel, a stop
+        token, and every caller that is not the broker)."""
+        staged = self._take_staged(temperature)
+        if staged is None:
+            self._stage_use = "fresh"
+            return self._to_device(self._decode_layout,
+                                   self._pack_decode(temperature))
+        self._stage_use = "used"
+        _, fields, self._h2d = staged
+        return fields
 
     def _step_rng(self, rng: Optional[jax.Array]) -> jax.Array:
         """The step's key: the caller's, or the next of the engine's stream
@@ -1355,8 +1431,9 @@ class InferenceEngineV2:
         if self.kv.slots is not None:
             self._count_state(int(t.active.sum()), int(t.active.sum()),
                               int((t.active & (t.ctx == 0)).sum()))
-        f = self._to_device(self._decode_layout,
-                            self._pack_decode(temperature))
+        # staged by the step before (the span then holds the check alone) or
+        # copied here
+        f = self._decode_inputs(temperature)
         args = (f["token_ids"], f["position_ids"], self._tables(f),
                 f["context_lens"], f["temps"], self._step_rng(rng),
                 f["seeds"])
@@ -1484,7 +1561,12 @@ class InferenceEngineV2:
         return to ``step``'s), and the engine thread's CPU time over the
         first and the last (``pre_cpu_ms``, ``post_cpu_ms``), so a reader
         needs no join: the three add up to the span, and wall less CPU is
-        what the thread waited for.  The unpack program is enqueued inside
+        what the thread waited for.  A decode step says whose copy fed its
+        program (``staged``: ``"used"``, the one the step before staged, or
+        ``"fresh"``, made here; ``h2d_copies`` / ``h2d_bytes`` count it either
+        way), and any step that found staged fields it could not use
+        ``stage_discarded`` 1 with their ``stage_bytes``.  The unpack program
+        is enqueued inside ``engine/stage`` of the step before or inside
         ``engine/h2d``, before ``device_ms`` opens, and that is still the
         right start: it is a dozen slices of one small buffer, and once it
         is done the device waits for the step's program like before it, so
@@ -1502,6 +1584,9 @@ class InferenceEngineV2:
         self._state_step = None
         self._attn_q_slots = None
         self._h2d = None
+        self._stage_use = None
+        if kind != "decode":  # what was staged for a decode step: dropped
+            self._take_staged()
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
@@ -1520,8 +1605,13 @@ class InferenceEngineV2:
         if self._moe_stats is not None:  # an MoE model's step ran the device
             attrs["moe_rows"], attrs["moe_rows_padded"] = self._moe_rows[kind]
             attrs["moe_experts_hit"], attrs["moe_rows_max"] = self._moe_stats
-        if self._h2d is not None:  # copies made before the step's program
+        if self._h2d is not None:  # the copies that fed the step's program
             attrs["h2d_copies"], attrs["h2d_bytes"] = self._h2d
+        if self._stage_use is not None:  # a decode step: whose copy it ran on
+            attrs["staged"] = self._stage_use
+        if self._stage_dropped:  # staged for this step and not what it needs
+            attrs["stage_discarded"] = 1
+            attrs["stage_bytes"] = self._stage_dropped
         if self._attn_q_slots is not None:  # a mixed step ran the device
             attrs["attn_q_slots"] = self._attn_q_slots
         if self._state_step is not None:  # a state model's step ran the device
@@ -1535,6 +1625,10 @@ class InferenceEngineV2:
                     for k in self._managers]
             attrs["blocks_used_global"] = used[0] if m is not self.kv else 0
             attrs["blocks_used_window"] = used[-1]
+        if dispatched is not None:
+            # the next decode step's copy, inside this step's ``post_ms``;
+            # after the counters above, which are this step's
+            self._stage_next(temperature, sub)
         if sp is not None and dispatched is not None:  # it reached the device
             # last: ``post_ms`` runs to here
             attrs.update(_host_split(sp, cpu_entry, *dispatched))

@@ -16,7 +16,20 @@ what it costs to bring a step's small host inputs to the device, four ways.
     step's dispatch);
 (d) (b) with the table's fields laid out as views of the one buffer, so that
     packing copies nothing;
-and ``b_then_wake``: (b) with the threads woken after the step is called.
+``b_then_wake``: (b) with the threads woken after the step is called;
+and ``stage_then_wake`` (ISSUE 38, the engine's order since): ``b_direct``'s
+pack and unpack call made with no thread awake (``stage_ms``), THEN the
+wake, THEN what the step still does before its program (``prep_ms``): the
+table's fields compared with the staged buffer's a field at a time as
+``bytes``, which holds the interpreter lock throughout, and the call;
+``stage_then_wake_packed`` is the same with the check ISSUE 38 first wrote,
+a fresh pack compared with the staged buffer, whose ``np.zeros`` and
+assignments let go of the lock; ``wake_then_call`` is the floor: the wake,
+then the call on arguments that were on the device all along, nothing
+staged, nothing checked.  ``to_device_ms`` is the time from the wake to the
+start of the step program on the device, from a profile of ``--traced``
+steps more (``b_direct``, ``b_then_wake``, the two ``stage_then_wake`` and
+``wake_then_call``; not on a CPU).
 
 The sizes are the serving cells' (rows x blocks a sequence): Mistral's and
 OLMoE's 32 x 64, Nemotron's 64 x 24, Mellum2's 32 x 132 with two tables.  A
@@ -32,9 +45,12 @@ and to ``chiprun_out/host_path_alone.jsonl``, the device's line first.
 """
 
 import argparse
+import bisect
+import glob
 import json
 import os
 import select
+import shutil
 import socket
 import statistics
 import sys
@@ -109,6 +125,7 @@ class Streams:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
+    ap.add_argument("--traced", type=int, default=60)
     ap.add_argument("--rehearse", action="store_true")
     opts = ap.parse_args()
 
@@ -158,6 +175,42 @@ def main():
                 + temps.astype(jnp.int32) + rng[0].astype(jnp.int32))
 
     split = jax.jit(lambda key: tuple(jax.random.split(key)))
+
+    def to_device(traced_run, n, wake_first):
+        """Median ms from a step's wake to the start of its ``jit_step`` on
+        the device, over a profiled run (host annotation and device events
+        on one clock: ``benchmark/trace_reduce.py``)."""
+        from benchmark import common, trace_reduce
+
+        def wake(i):
+            with jax.profiler.TraceAnnotation("bench/wake"):
+                streams.wake(n, i)
+
+        session = common.TraceSession(lambda msg: None)
+        try:
+            session.start()
+            try:
+                traced_run(wake)
+            finally:
+                session.stop()
+            (path,) = glob.glob(os.path.join(
+                session.dir, "plugins", "profile", "*", "*.xplane.pb"))
+            trace = trace_reduce.load(path)
+        finally:
+            shutil.rmtree(session.dir, ignore_errors=True)
+        wakes = sorted(e.start for e in trace.host_spans
+                       if e.name == "bench/wake")
+        runs = sorted(e.start for e in trace.device_modules.get(0, ())
+                      if trace_reduce.module_name(e.name) == "jit_step")
+        # each run against the last wake before it; where the run comes
+        # first (``b_then_wake``), each wake against the last run before it
+        if wake_first:
+            ms = [(r - wakes[i - 1]) / 1e6 for r in runs
+                  if (i := bisect.bisect_right(wakes, r))]
+        else:
+            ms = [(runs[i - 1] - w) / 1e6 for w in wakes
+                  if (i := bisect.bisect_right(runs, w))]
+        return round(statistics.median(ms), 4) if ms else None
 
     for name, (rows, blocks, tables) in SIZES.items():
         rs = np.random.default_rng(0)
@@ -243,41 +296,94 @@ def main():
             v["temps"][:] = temps_of(plain)
             return unpacked(jax.device_put(v["token_ids"].base))
 
+        staged = {}
+
+        def stage():  # with no thread awake: the step before's last act
+            staged["buf"] = pack()
+            staged["views"] = layout.views(staged["buf"])
+            staged["args"] = unpacked(staged["buf"])
+
+        def check():  # what is left before the program: the engine's compare
+            t, v = plain, staged["views"]
+            fields = {"token_ids": t.next_tok, "position_ids": t.ctx,
+                      "context_lens": (t.ctx + 1) * t.active,
+                      "temps": temps_of(t), "seeds": t.seed,
+                      "block_tables": t.block_tables}
+            if t.win_tables is not None:
+                fields["win_tables"] = t.win_tables
+            assert all(v[k].tobytes() == np.ascontiguousarray(
+                x, v[k].dtype).tobytes() for k, x in fields.items())
+            return staged["args"]
+
+        def check_packed():  # the same by a second buffer
+            assert pack().tobytes() == staged["buf"].tobytes()
+            return staged["args"]
+
         # ``b_then_wake``: (b) with the threads woken AFTER the step is
         # called (the order of S5's lever 2), for what the threads cost
         variants = {"a": a, "b": b, "b_direct": b_direct, "c": c, "d": d,
-                    "b_then_wake": b}
+                    "b_then_wake": b, "stage_then_wake": check,
+                    "stage_then_wake_packed": check_packed,
+                    "wake_then_call": lambda: staged["args"]}
+        stage()
         for fn in variants.values():  # compile everything before timing
             np.asarray(step(*fn()))
+
+        def run(label, fn, n, steps, wake):
+            """``steps`` steps beside ``n`` threads → (stage, prep, call)
+            ms a step; ``wake(i)`` wakes them, where the variant does."""
+            rows, since = [], list(streams.done)
+            for i in range(steps):
+                t_s = time.perf_counter()
+                if label.startswith("stage_then_wake"):
+                    stage()
+                t_w = time.perf_counter()
+                if label != "b_then_wake":
+                    wake(i)
+                t0 = time.perf_counter()
+                args = fn()
+                t1 = time.perf_counter()
+                res = step(*args)
+                t2 = time.perf_counter()
+                if label[0] in "bds":  # the next step's key, behind it
+                    state["key"], state["next"] = jax.random.split(
+                        state["key"])
+                if label == "b_then_wake":
+                    wake(i)
+                np.asarray(res)
+                streams.settle(n, since, i + 1)
+                rows.append(((t_w - t_s) * 1e3, (t1 - t0) * 1e3,
+                             (t2 - t1) * 1e3))
+                # freeing a device array lets go of the interpreter lock:
+                # here, with no thread awake, not where the next step
+                # rebinds the names (the engine frees a step's fields where
+                # the step returns)
+                args = res = None
+            return rows
+
         for n in THREADS:
             for label, fn in variants.items():
-                prep, call, since = [], [], list(streams.done)
-                for i in range(opts.steps + 20):
-                    if label != "b_then_wake":
-                        streams.wake(n, i)
-                    t0 = time.perf_counter()
-                    args = fn()
-                    t1 = time.perf_counter()
-                    res = step(*args)
-                    t2 = time.perf_counter()
-                    if label[0] in "bd":  # the next step's key, behind it
-                        state["key"], state["next"] = jax.random.split(
-                            state["key"])
-                    if label == "b_then_wake":
-                        streams.wake(n, i)
-                    np.asarray(res)
-                    streams.settle(n, since, i + 1)
-                    if i >= 20:
-                        prep.append((t1 - t0) * 1e3)
-                        call.append((t2 - t1) * 1e3)
+                rows = run(label, fn, n, opts.steps + 20,
+                           lambda i: streams.wake(n, i))[20:]
+                prep, call = [r[1] for r in rows], [r[2] for r in rows]
                 q = statistics.quantiles(prep, n=10)
-                say({"size": name, "threads": n, "variant": label,
-                     "prep_ms_p50": round(statistics.median(prep), 4),
-                     "prep_ms_p90": round(q[8], 4),
-                     "call_ms_p50": round(statistics.median(call), 4),
-                     "prep_call_ms_p50": round(statistics.median(
-                         p + c for p, c in zip(prep, call)), 4),
-                     "buffer_bytes": layout.size * 4})
+                line = {"size": name, "threads": n, "variant": label,
+                        "prep_ms_p50": round(statistics.median(prep), 4),
+                        "prep_ms_p90": round(q[8], 4),
+                        "call_ms_p50": round(statistics.median(call), 4),
+                        "prep_call_ms_p50": round(statistics.median(
+                            p + c for p, c in zip(prep, call)), 4),
+                        "buffer_bytes": layout.size * 4}
+                if label.startswith("stage_then_wake"):
+                    line["stage_ms_p50"] = round(
+                        statistics.median(r[0] for r in rows), 4)
+                if (label in ("b_direct", "b_then_wake", "stage_then_wake",
+                              "stage_then_wake_packed", "wake_then_call")
+                        and backend == "tpu" and opts.traced):
+                    line["to_device_ms_p50"] = to_device(
+                        lambda wake: run(label, fn, n, opts.traced, wake), n,
+                        label != "b_then_wake")
+                say(line)
     streams.stop()
 
 

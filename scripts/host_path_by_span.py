@@ -15,7 +15,11 @@ split): to see WHICH phase holds a step's wait, open that phase's span with
 and its row gains the two CPU columns.  ``burst``: the mean ``streams`` and
 ``emitted`` of a ``broker/emit`` (the HTTP threads it wakes, and the tokens
 it gave them) beside the mean wait of the device steps' host parts, and that
-wait a stream.  And ``starved``:
+wait a stream.  ``staging`` (ISSUE 38): the share of the decode steps that
+ran on the copy the step before staged (``staged`` = ``"used"``) or made
+their own (``"fresh"``), and of the stagings (``engine/stage``, a row of the
+table like any span) the share thrown away unused, by the kind of step that
+found them.  And ``starved``:
 the three sums ``device_starved_pct`` is made of (``pre_ms``, ``post_ms``,
 the turns that ended in a step) beside the time with nothing to run
 (``broker/idle``), in seconds, over the window and over the traced part of
@@ -117,6 +121,30 @@ def burst(spans) -> dict:
     return out
 
 
+def staging(spans) -> dict:
+    """Whose copy the decode steps ran on, and what became of the stagings:
+    the decode steps, the share of them that say ``staged`` = ``"used"`` and
+    ``"fresh"``, the ``engine/stage`` spans, and the share of those that a
+    later step dropped (``stage_discarded``), by that step's kind, with
+    their bytes.  Empty for a program from before ``staged``."""
+    steps = [s["attrs"] for s in spans if s["name"] == "engine/step"]
+    use = [a["staged"] for a in steps if "staged" in a]
+    if not use:
+        return {}
+    stagings = sum(s["name"] == "engine/stage" for s in spans)
+    dropped = [a for a in steps if a.get("stage_discarded")]
+    out = {"decode_steps": len(use),
+           "used_pct": 100.0 * use.count("used") / len(use),
+           "fresh_pct": 100.0 * use.count("fresh") / len(use),
+           "stagings": stagings, "discarded": len(dropped),
+           "discarded_bytes": sum(a["stage_bytes"] for a in dropped)}
+    if stagings:
+        out["discarded_pct"] = 100.0 * len(dropped) / stagings
+    for kind in sorted({a["kind"] for a in dropped}):
+        out[f"discarded_by_{kind}"] = sum(a["kind"] == kind for a in dropped)
+    return {k: round(v, 4) for k, v in out.items()}
+
+
 def starved(spans, t0: float, t1: float) -> dict:
     """What ``device_starved_pct`` sums, and the time with nothing to run,
     as far as each lies inside ``[t0, t1)``, in seconds."""
@@ -204,6 +232,7 @@ def main() -> int:
             "device": result["device"]["kind"],
             "by_span_ms": by_span(seen["spans"]),
             "burst": burst(seen["spans"]),
+            "staging": staging(seen["spans"]),
             "starved": {"window": starved(seen["spans"], window["t_open"],
                                           window["t_close"]),
                         "traced": in_trace},

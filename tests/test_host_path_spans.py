@@ -2,7 +2,8 @@
 asks for it (``cpu=True``), the step's own split (``pre_ms`` / ``post_ms``
 and their CPU twins) on an engine at test size, ``emitted`` / ``streams`` on
 ``broker/emit``, and the seven readers that turn them into per-layer
-metrics, each over hand-made spans."""
+metrics, each over hand-made spans.  And what ISSUE 38 added to the step:
+``engine/stage``, ``staged``, ``stage_discarded`` / ``stage_bytes``."""
 
 import importlib.util
 import json
@@ -226,6 +227,119 @@ def test_a_device_step_reads_the_thread_clock_four_times_or_never(
         assert global_tracer.spans() == []
     else:
         assert len(steps) == 3 and all("pre_cpu_ms" in s.attrs for s in steps)
+
+
+# what happens between two steps → what the NEXT step says of the staging
+_BETWEEN = {
+    "nothing": (lambda eng, uids: None, "decode", "used", False),
+    "put": (lambda eng, uids: eng.put([5, 6, 7, 8], 3), "mixed", None, True),
+    "cancel": (lambda eng, uids: eng.cancel(uids[1]), "decode", "fresh",
+               True),
+    "temperature": (lambda eng, uids: setattr(eng, "step_temperature", 0.9),
+                    "decode", "fresh", True),
+    # a pinned row's temperature is the row's: with every row taken and
+    # pinned the buffer does not change (a free row reads the step's)
+    "temperature-all-pinned": (
+        lambda eng, uids: setattr(eng, "step_temperature", 0.9), "decode",
+        "used", False),
+    "burst": (lambda eng, uids: eng._burst_decode(2), "decode", "fresh",
+              True),
+}
+
+
+@pytest.mark.parametrize("between", sorted(_BETWEEN))
+def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
+                                                 monkeypatch, between):
+    """``staged`` on a decode step (``"used"``: the copy the step before
+    staged; ``"fresh"``: made here), ``stage_discarded`` / ``stage_bytes``
+    on any step that found staged fields it could not use, ``h2d_copies`` 1
+    either way; and the calls of the unpack program lie where they should:
+    a ``"used"`` step makes none before its program is called (its
+    ``pre_ms`` holds no trip into the runtime), its one is in
+    ``engine/stage``, for the step after it."""
+    from deepspeed_tpu.inference.v2 import engine as engine_mod
+
+    act, kind, staged, discarded = _BETWEEN[between]
+    calls, real = [], engine_mod.build_unpack
+
+    def build_unpack(layout):
+        program = real(layout)
+
+        def call(buf):
+            calls.append(time.monotonic())
+            return program(buf)
+
+        return call
+
+    monkeypatch.setattr(engine_mod, "build_unpack", build_unpack)
+    eng = _engine(tiny_model)
+    eng.step_temperature = 0.0
+    pinned = 0.5 if between == "temperature-all-pinned" else None
+    uids = [eng.put(list(range(1, 1 + n)), 12, temperature=pinned)
+            for n in ((5, 6, 2, 3) if pinned else (5, 9))]
+    for _ in range(3):  # mixed, then decode steps, all rows decoding
+        eng.step(temperature=eng.step_temperature)
+    assert eng._staged is not None and eng._prefilling == 0
+    act(eng, uids)
+    global_tracer.clear()
+    eng.step(temperature=eng.step_temperature)
+    (st,) = global_tracer.spans(name="engine/step")
+    a = st.attrs
+    assert a["kind"] == kind and a.get("staged") == staged
+    assert ("stage_discarded" in a) == ("stage_bytes" in a) == discarded
+    if discarded:
+        assert (a["stage_discarded"], a["stage_bytes"]) == (
+            1, eng._decode_layout.size * 4)
+    assert a["h2d_copies"] == 1
+    kids = {k.name: k for k in global_tracer.spans()
+            if k.parent_id == st.span_id}
+    inside = {name: sum(k.t_start <= t <= k.t_end for t in calls)
+              for name, k in kids.items()}
+    in_step = sum(st.t_start <= t <= st.t_end for t in calls)
+    before_the_program = sum(
+        st.t_start <= t <= kids["engine/dispatch"].t_start for t in calls)
+    assert before_the_program == (0 if staged == "used" else 1)
+    assert inside["engine/h2d"] == before_the_program
+    # every one of these steps ends steady: it stages the next one's
+    assert list(kids)[-1] == "engine/stage" and inside["engine/stage"] == 1
+    assert in_step == before_the_program + 1
+    assert eng._staged is not None
+
+
+def test_nothing_is_staged_where_the_next_step_is_no_decode_step(
+        devices, tiny_model):
+    """A step that leaves a request waiting, a row still in its prompt or
+    nothing running stages nothing, and an idle step stages nothing and
+    drops what was there."""
+    global_tracer.clear()
+    eng = _engine(tiny_model)
+    eng.put(list(range(1, 41)), 3)  # three chunks of the budget of 16
+    eng.step()
+    eng.step()
+    assert eng._staged is None and eng._prefilling == 1
+    eng.step()  # the last chunk: the next step is a decode step
+    assert eng._staged is not None
+    eng.step()
+    eng.step()  # the budget ran out: nothing runs
+    assert eng._staged is None and not eng.running
+    eng.put([1, 2, 3], 4)
+    eng.step()
+    assert eng._staged is not None
+    (uid,) = eng.running
+    eng.cancel(uid)
+    eng.step()  # idle: reaches no device, drops the staging
+    assert eng._staged is None
+    steps = global_tracer.spans(name="engine/step")
+    stages = [any(k.name == "engine/stage" and k.parent_id == s.span_id
+                  for k in global_tracer.spans()) for s in steps]
+    assert stages == [False, False, True, True, False, True, False]
+    assert [s.attrs.get("staged") for s in steps] == [
+        None, None, None, "used", "used", None, None]
+    last = steps[-1].attrs
+    assert "device_ms" not in last and "h2d_copies" not in last
+    assert (last["stage_discarded"], last["stage_bytes"]) == (
+        1, eng._decode_layout.size * 4)
+    assert sum("stage_discarded" in s.attrs for s in steps) == 1
 
 
 def test_broker_emit_counts_what_the_clients_got(devices, tiny_model):
@@ -452,3 +566,24 @@ def test_the_by_span_script_prints_both_clocks_and_the_three_sums():
         "decode_wait_ms_mean": 3.2, "decode_wait_ms_a_stream": 0.1,
         "mixed_wait_ms_mean": 1.35, "mixed_wait_ms_a_stream": 0.0422}
     assert script.burst(emits) == {}  # no step with the second clock
+    # whose copy the decode steps ran on, and what became of the stagings
+    assert script.staging(spans) == {}  # a program from before ``staged``
+    staged = [
+        step("decode", 106.0, 1.0, 0.9, 1.5, 1.2, staged="used"),
+        step("decode", 106.1, 1.0, 0.9, 1.5, 1.2, staged="used"),
+        step("decode", 106.2, 1.0, 0.9, 1.5, 1.2, staged="used"),
+        step("decode", 106.3, 2.0, 0.9, 1.5, 1.2, staged="fresh",
+             stage_discarded=1, stage_bytes=8832),
+        step("mixed", 106.4, 2.0, 0.9, 1.5, 1.2, stage_discarded=1,
+             stage_bytes=8832),
+        span("engine/step", 106.5, 106.501, kind="mixed", tokens=0,
+             stage_discarded=1, stage_bytes=8832)] + [
+        span("engine/stage", 106.02 + i / 10, 106.021 + i / 10, kind="decode")
+        for i in range(6)]
+    assert script.staging(spans + staged) == {
+        "decode_steps": 4, "used_pct": 75.0, "fresh_pct": 25.0,
+        "stagings": 6, "discarded": 3, "discarded_bytes": 26496,
+        "discarded_pct": 50.0, "discarded_by_decode": 1,
+        "discarded_by_mixed": 2}
+    assert script.by_span(spans + staged)["decode"]["engine/stage"] == [
+        1.0, 1.0]

@@ -585,6 +585,9 @@ _CHILDREN = {
               "engine/dispatch", "engine/sample", "engine/wait",
               "engine/finish"],
 }
+# the last child of a step that ends steady: the NEXT decode step's copy
+# (ISSUE 38); a speculative engine stages nothing
+_STAGE = "engine/stage"
 
 
 @pytest.mark.parametrize("kind,over", [
@@ -592,9 +595,11 @@ _CHILDREN = {
                                              "spec_k": 2})])
 def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     """Every ``engine/step`` of the kind has one child per phase, inside its
-    interval and in order, each with the step's ``kind`` and ``step``; the
-    step itself says how long the device was (presumed) busy, how full its
-    batch was, and what it copied to the device before its program."""
+    interval and in order, each with the step's ``kind`` and ``step``, and
+    ``engine/stage`` behind them where the step ends steady and nowhere
+    else; the step itself says how long the device was (presumed) busy, how
+    full its batch was, what was copied to the device for its program and,
+    a decode step, whether the step before had staged that copy."""
     from deepspeed_tpu.observability.trace import tracer
 
     cfg, params = tiny_model
@@ -605,17 +610,25 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     eng.put([7, 8, 9], max_new_tokens=6)
     tracer.clear()
     thread = threading.current_thread().name
+    ends_steady = {}
     while eng.running or eng.waiting:
         eng.step()
+        ends_steady[eng.steps] = bool(
+            kind != "spec" and not eng.waiting and eng.running
+            and eng._prefilling == 0)
     spans = [s for s in tracer.spans() if s.thread == thread]
     steps = [s for s in spans
              if s.name == "engine/step" and s.attrs["kind"] == kind]
     assert steps, f"no {kind} step ran"
     numbers = [s.attrs["step"] for s in spans if s.name == "engine/step"]
     assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    assert kind == "spec" or (True in ends_steady.values()
+                              and False in ends_steady.values())
     for st in steps:
         kids = [s for s in spans if s.parent_id == st.span_id]
-        assert [k.name for k in kids] == _CHILDREN[kind]
+        staged_next = ends_steady[st.attrs["step"]]
+        assert [k.name for k in kids] == _CHILDREN[kind] + (
+            [_STAGE] if staged_next else [])
         edges = [st.t_start]
         for k in kids:
             assert k.attrs["kind"] == kind
@@ -626,12 +639,17 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
         assert 0.0 <= st.attrs["device_ms"] <= st.duration_s * 1e3
         assert st.attrs["budget"] == 16
         assert 0 < st.attrs["tokens"] <= 16
+        by_name = {k.name: k for k in kids}
         assert st.attrs["device_ms"] == pytest.approx(
-            (kids[-2].t_end - kids[-4 if kind == "mixed" else -3].t_start)
-            * 1e3)  # start of engine/dispatch to end of engine/wait
+            (by_name["engine/wait"].t_end
+             - by_name["engine/dispatch"].t_start) * 1e3)
+        # the staging lies in the step's ``post_ms``, behind the fetch
+        if staged_next:
+            assert st.attrs["post_ms"] >= (
+                st.t_end - by_name[_STAGE].t_start) * 1e3 - 1e-6
         for k in kids:  # a child carries what a reader joins on, no more
             assert set(k.attrs) == {"kind", "step"}
-        # the copies made before the step's program, on the step itself (a
+        # the copies that fed the step's program, on the step itself (a
         # speculative step keeps its own arguments and counts none)
         layout = {"decode": eng._decode_layout,
                   "mixed": eng.builder.layout}.get(kind)
@@ -640,6 +658,14 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
         else:
             assert (st.attrs["h2d_copies"], st.attrs["h2d_bytes"]) == (
                 1, layout.size * 4)
+        # nothing touched the table between two steps here: a decode step
+        # runs on what the step before staged whenever that step staged
+        assert "stage_discarded" not in st.attrs
+        if kind == "decode":
+            assert st.attrs["staged"] == (
+                "used" if ends_steady.get(st.attrs["step"] - 1) else "fresh")
+        else:
+            assert "staged" not in st.attrs
     if kind == "mixed":  # the long prompt fills whole chunks of the budget
         assert max(s.attrs["tokens"] for s in steps) == 16
 
@@ -886,8 +912,10 @@ def _drive(eng, adapters, watch=None):
                 eng.put(rs.integers(1, 200, length).tolist(),
                         max_new_tokens=new, temperature=temp, seed=seed,
                         adapter_slot=slot if adapters else 0)
-            # the step-level temperature, which rows without one inherit
-            eng.step_temperature = 0.7 if n % 2 else 0.0
+            # the step-level temperature, which rows without one inherit:
+            # three steps each, so that a decode step can run on what the
+            # step before staged, and the next change throws a staging away
+            eng.step_temperature = 0.7 if n // 3 % 2 else 0.0
             steps.append(eng.step(temperature=eng.step_temperature))
             n += 1
     finally:
@@ -904,14 +932,17 @@ def one_copy_run(request, devices):
 
     name = request.param
     build = _one_copy_engine(name)
-    seen = {"copies": [], "explicit": [], "bufs": []}
+    seen = {"copies": [], "staged": [], "explicit": [], "bufs": []}
 
     def watch(eng):
         # from a step's start to the call of its program: implicit copies
         # (a NumPy array handed to a jitted program) are refused, explicit
         # ones (jax.device_put, jnp.asarray, jnp.array) counted, and the one
-        # copy function allowed its one
-        to_device, impl = eng._to_device, eng._step_impl
+        # copy function allowed its one; the copy a step makes for the NEXT
+        # decode step once its own work is done (``_stage_next``) is counted
+        # apart
+        to_device, impl, stage = (eng._to_device, eng._step_impl,
+                                  eng._stage_next)
         programs = {"_fwd": eng._fwd, "_decode_fwd": eng._decode_fwd}
         guard = [None]
 
@@ -923,12 +954,20 @@ def one_copy_run(request, devices):
 
         def counted(layout, buf):
             seen["bufs"].append((type(buf), buf.dtype, buf.nbytes))
-            seen["copies"][-1] += 1
+            seen["staged" if seen.get("staging") else "copies"][-1] += 1
             with jax.transfer_guard_host_to_device("allow"):
                 return to_device(layout, buf)
 
+        def stage_next(*args):
+            seen["staging"] = True
+            try:
+                return stage(*args)
+            finally:
+                seen["staging"] = False
+
         def step_impl(*args):
             seen["copies"].append(0)
+            seen["staged"].append(0)
             seen["explicit"].append(0)
             guard[0] = jax.transfer_guard_host_to_device("disallow")
             guard[0].__enter__()
@@ -945,6 +984,7 @@ def one_copy_run(request, devices):
             return call
 
         eng._to_device, eng._step_impl = counted, step_impl
+        eng._stage_next = stage_next
         for attr, fn in programs.items():
             setattr(eng, attr, program(fn))
 
@@ -983,12 +1023,13 @@ def one_copy_run(request, devices):
 
 
 def test_a_step_makes_one_host_to_device_copy(one_copy_run):
-    """A decode step and a mixed step each make exactly one host-to-device
-    copy before their program is called: the step's span says so
-    (``h2d_copies``, ``h2d_bytes``: the buffer's), the one copy function was
-    called once a step with one NumPy int32 buffer, and between a step's
-    start and its program nothing else was copied, neither explicitly nor by
-    handing a jitted program a NumPy array."""
+    """A decode step's and a mixed step's program each run on exactly one
+    host-to-device copy: the step's span says so (``h2d_copies``,
+    ``h2d_bytes``: the buffer's), the one copy function was called once for
+    it with one NumPy int32 buffer (in the step, or at the end of the step
+    before for a decode step that says ``staged="used"``), and between a
+    step's start and its program nothing else was copied, neither
+    explicitly nor by handing a jitted program a NumPy array."""
     run = one_copy_run
     eng = run["eng"]
     ran = ["device_ms" in s.attrs for s in run["spans"]]  # not an idle step
@@ -1001,10 +1042,24 @@ def test_a_step_makes_one_host_to_device_copy(one_copy_run):
     for s in spans:
         assert s.attrs["h2d_copies"] == 1
         assert s.attrs["h2d_bytes"] == nbytes[s.attrs["kind"]]
-    assert run["seen"]["copies"] == [int(on) for on in ran]
+    # a step that runs on the staged copy makes none before its program
+    used = [s.attrs.get("staged") == "used" for s in run["spans"]]
+    assert run["seen"]["copies"] == [int(on and not u)
+                                     for on, u in zip(ran, used)]
+    assert used.count(True) >= 5 and not used[0]
+    assert all(run["seen"]["staged"][i - 1] == 1
+               for i, u in enumerate(used) if u)
+    assert set(run["seen"]["staged"]) == {0, 1}
     assert run["seen"]["explicit"] == [0] * len(ran)
+    # the buffers in the order they were copied: a step's own, then the one
+    # it staged
+    order = []
+    for s, own, staged in zip(run["spans"], run["seen"]["copies"],
+                              run["seen"]["staged"]):
+        order += [s.attrs["kind"]] * own + ["decode"] * staged
+    assert len(order) == len(run["seen"]["bufs"])
     assert all(b == (np.ndarray, np.dtype(np.int32), nbytes[k])
-               for b, k in zip(run["seen"]["bufs"], kinds))
+               for b, k in zip(run["seen"]["bufs"], order))
 
 
 def test_tokens_and_step_keys_are_the_parents(one_copy_run):
