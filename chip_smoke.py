@@ -111,6 +111,24 @@ class Sizes:
     # the tapped sequences' slots of the engine's state array against the
     # reference pass's final states, of the largest element (the cell's bound)
     ssm_state_tol: float = 0.15
+    # -- latent attention, a learned selection of keys, a share of the experts:
+    # the dense layer that picks and one period (shared shared shared full)
+    # at GLM-5.2's widths with 16 of 256 experts and an eighth of the
+    # vocabulary is 4.3 GB of int8 codes at group 128; a prompt passes two
+    # index_topk (4,400: nine chunks), one stays under one; tables of 80
+    # blocks hold 5,120 tokens
+    latent_preset: str = "glm-5.2"
+    latent_periods: int = 1
+    latent_held: int = 16
+    latent_vocab: int = 19360
+    latent_max_blocks_per_seq: int = 80
+    latent_blocks: int = 321
+    latent_requests: Tuple[Tuple[int, int], ...] = (
+        (4400, 10), (2300, 8), (600, 12), (9, 14))
+    latent_second: Tuple[Tuple[int, int], ...] = ((70, 8), (2100, 6))
+    # the tapped rows against the reference held to the program's picks and
+    # experts (median, worst: the serving cell's bounds)
+    latent_logit_tol: Tuple[float, float] = (0.12, 0.3)
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -1057,6 +1075,133 @@ def phase_ssm_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     memory_line(phase, jax.local_devices()[0])
 
 
+def phase_latent_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
+                            ) -> None:
+    """A model with latent attention, a learned indexer whose pick three
+    layers share, a leading dense layer and a SHARE of its routed experts (a
+    cut of GLM-5.2, int8 at group 128) through ``InferenceEngineV2``: a
+    latent pool and an indexer-key pool behind one block table, a prompt past
+    two ``index_topk`` chunked nine times with decode rows riding in its
+    mixed steps, then a second batch through the same blocks, then the first
+    batch once more with the logits, the picks and the expert choices of the
+    engine's own step programs tapped; the grouped GEMM's tiles at 16 held
+    experts printed, none fallen back; every block of both pools free after
+    each drain.  Against the plain reference
+    (``benchmark/reference/latent_sparse_moe_decoder.py``) over the same
+    codes, as the serving cell compares: the tapped rows against the
+    reference HELD TO THE PROGRAM'S PICKS AND EXPERTS, and the indexer and
+    the router directly (``serve_latent_moe.check_logits`` /
+    ``check_router``)."""
+    from benchmark.drivers import serve_latent_moe as drv
+    from benchmark.selection_tap import SelectionTap
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-latent-moe-int8"
+    n = 1 + 4 * sz.latent_periods
+    cfg = tfm.get_config(
+        sz.latent_preset, num_layers=n, vocab_size=sz.latent_vocab,
+        moe_experts_held=sz.latent_held,
+        indexer_types=("full",) + ("shared", "shared", "shared",
+                                   "full") * sz.latent_periods,
+        mlp_layer_types=("dense",) + ("sparse",) * (n - 1),
+        dtype="bfloat16", param_dtype="bfloat16")
+    log(phase, preset=sz.latent_preset, layers=n, held=cfg.experts_held,
+        experts=cfg.num_experts, top=cfg.moe_top_k,
+        index_topk=cfg.index_topk, params_m=round(cfg.num_params() / 1e6, 1))
+    params = drv.make_params(cfg, seed, 8, 128)
+    tracer.clear()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.latent_blocks,
+        max_blocks_per_seq=sz.latent_max_blocks_per_seq))
+    rng = np.random.default_rng([seed, 41])
+    tapped = []
+    for batch, tap_it in ((sz.latent_requests, False),
+                          (sz.latent_second, False),
+                          (sz.latent_requests[:3], True)):
+        prompts = [rng.integers(1, cfg.vocab_size, size=m).tolist()
+                   for m, _ in batch]
+        tap = SelectionTap(engine) if tap_it else None
+        try:
+            uids = [engine.put(p, max_new_tokens=m)
+                    for p, (_, m) in zip(prompts, batch)]
+            whole = engine.generate_all(burst=1)
+        finally:
+            if tap is not None:
+                tap.remove()
+        for p, u, (_, m) in zip(prompts, uids, batch):
+            if len(whole[u]) - len(p) != m:
+                raise AssertionError(f"{phase}: asked {m} tokens, got "
+                                     f"{len(whole[u]) - len(p)}")
+            if tap is not None:
+                tapped.append((p, whole[u][len(p):], tap.logits[u],
+                               tap.forced(u, len(whole[u])),
+                               tap.picked(u, len(whole[u]))))
+        engine.kv.check_consistency()
+        if not engine.drained():
+            raise AssertionError(
+                f"{phase}: {engine.free_blocks} of {engine.total_blocks} "
+                f"blocks free after the drain")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"
+             and "dsa_keys_visible" in s.attrs]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    local = sum(a.get("moe_assignments_local", 0) for a in steps)
+    made = sum(a["moe_assignments"] for a in steps)
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        keys_visible=sum(a["dsa_keys_visible"] for a in steps),
+        keys_selected=sum(a["dsa_keys_selected"] for a in steps),
+        local_share=round(local / max(made, 1), 4),
+        pools={k: list(v.shape) for k, v in engine.caches.items()})
+    for name in ("kernel/grouped_mixed_gemm_tiles",
+                 "kernel/latent_attention_prefill_tiles"):
+        seen = {tuple(sorted(a.items())) for m, a in events if m == name}
+        if not seen:
+            raise AssertionError(f"{phase}: no {name} event")
+        for attrs in sorted(seen):
+            log(phase, event=name, **dict(attrs))
+    fell = [e for e in events if e[1].get("fallback")]
+    if fell and check_kernels:
+        raise AssertionError(f"{phase}: a kernel fell back to XLA: {fell}")
+    if check_kernels:
+        require_kernel(phase, "decode_step", engine._decode_fwd.lower(
+            *_decode_shapes(engine)).compile().as_text())
+    del engine
+    gc.collect()
+    model = drv.published_model(cfg)
+    drv._CHECK.update(cfg=cfg)
+    drv._NOTES.update(ok=True, dtypes={}, shapes={})
+    check = {"logit_pad": 256, "logit_tol_median": sz.latent_logit_tol[0],
+             "logit_tol": sz.latent_logit_tol[1], "agree_min": 0.7,
+             "index_tol": 1e-4, "select_band": 0.05,
+             "select_agree_min": 0.9, "router_tol": 1e-4}
+    got = drv.check_logits(params, model, tapped, check,
+                           lambda m: log(phase, check=m))
+    routed = drv.check_router(params, model, cfg, tapped, check,
+                              lambda m: log(phase, check=m))
+    if not (got["ok"] and routed["ok"]):
+        raise AssertionError(f"{phase}: the step programs' logits, picks or "
+                             f"router differ from the reference: {got} "
+                             f"{routed}")
+
+
+def _decode_shapes(engine):
+    """The decode step's arguments as shapes, for a compile from the cache."""
+    v2 = engine.cfg
+
+    def rows(dtype):
+        return jax.ShapeDtypeStruct((v2.max_seqs,), dtype)
+
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          (engine.params, engine.caches))
+    return (*shapes, rows(jnp.int32), rows(jnp.int32),
+            jax.ShapeDtypeStruct((v2.max_seqs, v2.max_blocks_per_seq),
+                                 jnp.int32),
+            rows(jnp.int32), rows(jnp.float32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32), rows(jnp.int32))
+
+
 def phase_zero3(sz: Sizes, seed: int) -> None:
     phase = "zero3x4"
     n = len(jax.devices())
@@ -1164,6 +1309,8 @@ def main() -> int:
         phase_swa_moe_server(sz, args.seed)
         gc.collect()
         phase_ssm_moe_server(sz, args.seed)
+        gc.collect()
+        phase_latent_moe_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

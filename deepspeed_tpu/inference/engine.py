@@ -160,6 +160,13 @@ class InferenceEngine:
                 "state-space layers with per-sequence state) is served by the "
                 "v2 engine (inference/v2), which keeps that state in slots "
                 "beside the paged K/V")
+        if getattr(model_config, "kv_lora_rank", 0):
+            raise NotImplementedError(
+                "the v1 engine keeps K and V heads in one cache; a model with "
+                "latent attention (kv_lora_rank > 0: a latent a token, a "
+                "learned selection of keys) is served by the v2 engine "
+                "(inference/v2), which keeps a latent pool and the "
+                "indexer's keys")
         if len(model_config.layer_period) > 1 or model_config.rope_params:
             raise NotImplementedError(
                 "the v1 engine serves one kind of attention layer with plain "

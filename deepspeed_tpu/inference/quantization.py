@@ -34,8 +34,16 @@ _QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate",
                          # a routed MoE's shared expert; a Mamba-2 layer's
                          # z and xBC projections (models/ssm_hybrid.py; its
                          # 64-wide dt projection stays bf16)
-                         "sh_w_in", "sh_w_out", "w_z", "w_xbc"})
-_QUANT_PARENTS = frozenset({"attn", "mlp", "moe", "mamba"})
+                         "sh_w_in", "sh_w_out", "w_z", "w_xbc",
+                         # a gated shared expert; latent attention's and its
+                         # indexer's projections (models/latent_sparse.py).
+                         # NOT among them, and so bf16: ``w_kvb`` (the
+                         # absorbed paths use it a head at a time, as two
+                         # einsums no GEMM kernel computes), ``w_kr`` (64
+                         # columns) and ``w_iw`` (32 columns)
+                         "sh_w_gate", "w_qa", "w_qb", "w_kva", "w_iq",
+                         "w_ik"})
+_QUANT_PARENTS = frozenset({"attn", "mlp", "moe", "mamba", "index"})
 
 
 def pad_expert_width(w: jax.Array, key: str) -> jax.Array:

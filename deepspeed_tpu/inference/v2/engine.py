@@ -28,13 +28,15 @@ import numpy as np
 from ...models import transformer as tfm
 from ...observability.recorder import recorder
 from ...observability.trace import tracer
+from ...ops.pallas.latent_attention import TILE_Q
 # ``_decode_body`` and ``_memo`` are not used here: ``benchmark/logit_tap.py``
 # imports them from this module
 from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
                        build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
-                       build_unpack, layer_plan, mixed_step_attn_tiles,
-                       pool_layers, sample_rows, state_arrays)
+                       build_unpack, latent_arrays, layer_plan,
+                       mixed_step_attn_tiles, pool_layers, sample_rows,
+                       state_arrays)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder, SequenceDescriptor, StepLayout,
                      decode_layout, window_bound)
@@ -251,6 +253,13 @@ class InferenceEngineV2:
                 "and one attention layer")
         if state:
             self._refuse_with_state()
+        # A model with latent attention keeps, in place of K and V heads, one
+        # latent a token a layer and the indexer's key of the layers that
+        # pick (programs.latent_arrays): two pools sized by kind behind the
+        # ONE block table and allocator (both grow with the context)
+        latent = latent_arrays(self.model_cfg, self.cfg)
+        if latent:
+            self._refuse_with_latent()
         max_chunk = self.cfg.max_tokens_per_step
         # one block of each pool reserved as write-scratch for padded tokens
         self.kv = KVCacheManager(
@@ -334,8 +343,14 @@ class InferenceEngineV2:
                               self.model_cfg.kv_heads,
                               self.model_cfg.head_dim), dt)
 
-        self.caches = {"k": pool(pools[0], self.cfg.num_blocks),
-                       "v": pool(pools[0], self.cfg.num_blocks)}
+        if latent:
+            self.caches = {name: jnp.zeros(shape, dt)
+                           for name, shape in latent.items()}
+        else:
+            self.caches = {"k": pool(pools[0], self.cfg.num_blocks),
+                           "v": pool(pools[0], self.cfg.num_blocks)}
+        self._latent = bool(latent)
+        self._latent_step = None
         if self.kv_win is not None:
             self.caches["k_win"] = pool(pools[1], win_blocks)
             self.caches["v_win"] = pool(pools[1], win_blocks)
@@ -357,13 +372,23 @@ class InferenceEngineV2:
         self._moe_layers = (self.model_cfg.layers_of("E")
                             if self.model_cfg.mixer_pattern
                             else self.model_cfg.num_layers)
+        if latent:
+            from ...models.latent_sparse import layers_of
+
+            self._moe_layers = layers_of(self.model_cfg, "S")
+        # a layer that holds a SHARE of its experts reports a third stat,
+        # the assignments that were local
+        self._moe_share = (self.model_cfg.experts_held
+                           != self.model_cfg.num_experts)
         if self.model_cfg.num_experts > 0 and self._moe_layers:
-            from ...moe.dropless import padded_rows
+            from ...moe.dropless import padded_rows, share_padded_rows
 
             E, k = self.model_cfg.num_experts, self.model_cfg.moe_top_k
             for kind, rows in (("decode", self.cfg.max_seqs),
                                ("mixed", self.cfg.max_tokens_per_step)):
-                self._moe_rows[kind] = (rows * k, padded_rows(rows * k, E))
+                self._moe_rows[kind] = (rows * k, share_padded_rows(
+                    rows * k, E, self.model_cfg.experts_held)
+                    if self._moe_share else padded_rows(rows * k, E))
         self._moe_stats = None  # (experts hit, rows max) of the last step
         self._multi_decode = {}  # num_steps -> jitted burst decoder
         self.running: Dict[int, SequenceDescriptor] = {}
@@ -487,6 +512,34 @@ class InferenceEngineV2:
         ]
         _refuse_each(asked, lambda why: (
             f"a model that has state layers (mixer_pattern with 'M'): {why}"))
+
+    def _refuse_with_latent(self) -> None:
+        """What moves, shares or looks ahead in the cache by a K and a V
+        array of heads: every such byte-mover reads ``caches["k"]`` and
+        ``["v"]``, and a latent model has neither (its pools are a latent a
+        token and the indexer's key).  Refused by name, never served wrong."""
+        cfg = self.cfg
+        asked = [
+            ("enable_prefix_cache", cfg.enable_prefix_cache,
+             "the prefix cache (with it prefix export / import and "
+             "copy-on-write forks) copies and shares K and V blocks"),
+            ("kv_host_pool_mb / kv_host_pool_bytes",
+             cfg.kv_host_pool_mb or cfg.kv_host_pool_bytes,
+             "the host paging tier demotes and promotes K and V blocks"),
+            ("kv_spill_dir", cfg.kv_spill_dir,
+             "the spill tier holds demoted K and V blocks"),
+            ("kv_coldstore_dir", cfg.kv_coldstore_dir,
+             "the cold store holds demoted K and V blocks"),
+            ("spec_mode", cfg.spec_mode != "off",
+             "the verify step attends over K and V heads (and neither a "
+             "draft model's cache nor the bolt-on heads share the "
+             "indexer's selection)"),
+            ("adapter_slots", cfg.adapter_slots,
+             "the adapter stack is laid out for q, k, v and o projections"),
+        ]
+        _refuse_each(asked, lambda why: (
+            f"a model with latent attention (kv_lora_rank > 0), whose pools "
+            f"hold a latent a token and the indexer's keys: {why}"))
 
     def _quantized(self, raw_params: Any) -> Any:
         """``raw_params`` as this engine serves them: untouched without
@@ -1268,6 +1321,41 @@ class InferenceEngineV2:
                 ssm_scan_rows=len(many), ssm_scan_tokens=int(many.sum()),
                 ssm_scan_pieces=int((-(-many // chunk)).sum()))
 
+    def _count_latent(self, start: "np.ndarray", n: "np.ndarray") -> None:
+        """A latent model's step, from what the host holds (``start`` /
+        ``n``: each row's first position and tokens this step) and the layer
+        pattern.  Summed over rows and layers: the (query, key) pairs before
+        the selection (a query at ``p`` sees ``p + 1`` keys) and after it
+        (``index_topk`` of them at most), those after it again by the path
+        that attends (``_single``: the rows of one token; ``_prefill``: the
+        rows of two and more) beside the keys that path has to READ
+        (``latent_keys_*``: a row's picked keys once, however many of its
+        queries picked them: the smaller of its picks and its context); the
+        pairs the layers that pick score and the indexer keys they read (a
+        row's context once); the blocks the latent pool has out; the expert
+        assignments the routed layers make (the local ones come back with
+        the step)."""
+        c = self.model_cfg
+        # every layer attends; the layers that pick hold the indexer's pool
+        L, Lf = c.num_layers, self.caches["index"].shape[0]
+        cols = np.arange(int(n.max(initial=0)))[None]
+        seen = np.where(cols < n[:, None], start[:, None] + 1 + cols, 0)
+        picked = np.minimum(seen, c.index_topk).sum(1)  # a row's pairs
+        read = np.minimum(picked, start + n)  # keys it cannot but read
+        one = n == 1
+        visible = int(seen.sum())
+        self._latent_step = {
+            "dsa_keys_visible": visible * L,
+            "dsa_keys_selected": int(picked.sum()) * L,
+            "dsa_selected_single": int(picked[one].sum()) * L,
+            "dsa_selected_prefill": int(picked[~one].sum()) * L,
+            "latent_keys_single": int(read[one].sum()) * L,
+            "latent_keys_prefill": int(read[~one].sum()) * L,
+            "dsa_index_pairs": visible * Lf,
+            "dsa_index_keys": int((start + n).sum()) * Lf,
+            "latent_blocks_used": self.total_blocks - self.free_blocks,
+            "moe_assignments": int(n.sum()) * c.moe_top_k * self._moe_layers}
+
     def _row_temps(self, temperature: float) -> "np.ndarray":
         """Effective per-row temperature vector: rows whose request pinned a
         temperature keep it; rows that didn't (temp < 0) inherit the
@@ -1431,6 +1519,9 @@ class InferenceEngineV2:
         if self.kv.slots is not None:
             self._count_state(int(t.active.sum()), int(t.active.sum()),
                               int((t.active & (t.ctx == 0)).sum()))
+        if self._latent:
+            self._count_latent(t.ctx[t.active].astype(np.int64),
+                               np.ones(int(t.active.sum()), np.int64))
         # staged by the step before (the span then holds the check alone) or
         # copied here
         f = self._decode_inputs(temperature)
@@ -1467,6 +1558,8 @@ class InferenceEngineV2:
         n = self.cfg.max_seqs
         self._moe_stats = (float(fetched[n]) / self._moe_layers,
                            int(fetched[n + 1]))
+        if self._moe_share and self._latent_step is not None:
+            self._latent_step["moe_assignments_local"] = int(fetched[n + 2])
         return fetched[:n]
 
     def _spec_decode_step(self, temperature: float, rng: Optional[jax.Array],
@@ -1582,6 +1675,7 @@ class InferenceEngineV2:
         self._moe_stats = None
         self._kv_step = None
         self._state_step = None
+        self._latent_step = None
         self._attn_q_slots = None
         self._h2d = None
         self._stage_use = None
@@ -1616,6 +1710,8 @@ class InferenceEngineV2:
             attrs["attn_q_slots"] = self._attn_q_slots
         if self._state_step is not None:  # a state model's step ran the device
             attrs.update(self._state_step)
+        if self._latent_step is not None:  # a latent model's step did
+            attrs.update(self._latent_step)
         if self._kv_step is not None:  # a windowed model's step ran the device
             m = self._windowed
             attrs["window_blocks_freed"] = \
@@ -1672,8 +1768,16 @@ class InferenceEngineV2:
                            batch.chunk_len[:len(picks)].astype(np.int64))
         # the query slots the prefill kernel multiplies: each row's tokens
         # rounded up to its tiles (``tokens`` / this: the share that hold one)
-        self._attn_q_slots = int(
-            self._attn_tiles.slots(batch.chunk_len).sum())
+        if self._latent:
+            n = batch.chunk_len[:len(picks)].astype(np.int64)
+            self._count_latent(
+                batch.chunk_start[:len(picks)].astype(np.int64), n)
+            # the prefill path's tiles of one row, and a slot a single row
+            self._attn_q_slots = int(
+                (-(-n[n >= 2] // TILE_Q) * TILE_Q).sum() + (n == 1).sum())
+        else:
+            self._attn_q_slots = int(
+                self._attn_tiles.slots(batch.chunk_len).sum())
         batch_args = (
             f["token_ids"], f["position_ids"], f["seq_index"],
             self._tables(f), f["context_lens"], f["logits_rows"],
@@ -1788,6 +1892,7 @@ class InferenceEngineV2:
             steady = (burst > 1 and self._spec_fwd is None
                       and self._windowed is None
                       and self.kv.slots is None
+                      and not self._latent
                       and not self.waiting and self.running
                       and self._prefilling == 0)
             if steady:

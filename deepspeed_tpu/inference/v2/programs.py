@@ -1,6 +1,8 @@
 """The v2 engine's step programs: what is traced and runs on the chip.
 
-One serving decoder layer, ``serving_layers``, under three callers: the mixed
+One serving decoder layer, ``serving_layers`` (with ``hybrid_layers`` for a
+model of one mixer a layer and ``latent_layers`` for one with latent
+attention behind it), under three callers: the mixed
 step (``build_ragged_forward``: chunks of prefill and decode tokens in one
 ragged batch), the decode step (``_decode_body``: one token a row) and the
 verify step of speculation (``spec.py:verify_body``: ``Q`` consecutive
@@ -21,9 +23,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ...models import ssm_hybrid
+from ...models import latent_sparse, ssm_hybrid
 from ...models import transformer as tfm
 from ...moe.dropless import serving_moe_block
+from ...ops.pallas import latent_attention
 from ...ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
 from ...ops.pallas.ssm import ssm_decode_update
 from ...ops.pallas.paged_attention import (PrefillTiles,
@@ -158,8 +161,8 @@ def _moe_step_stats(per_layer):
     """Per-layer ``(L, 2)`` stats of ``_ffn`` → int32 ``(2,)`` for the step:
     experts hit summed over layers (the host divides by L), and the largest
     rows-per-expert of any layer.  None for a dense model."""
-    if per_layer is None:
-        return None
+    if per_layer is None or per_layer.ndim == 1:  # a latent model's: made
+        return per_layer
     stats = jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max()])
     if per_layer.shape[1] > 2:  # cfg.moe_tap_choices: tooling only
         stats = jnp.concatenate([stats, per_layer[:, 2:].reshape(-1)])
@@ -233,6 +236,8 @@ def layer_plan(model_cfg: tfm.TransformerConfig, v2) -> tuple:
 def pool_layers(model_cfg: tfm.TransformerConfig, v2) -> tuple:
     """How many layers each pool of ``layer_plan`` holds: ``(L,)`` for a
     model with one kind of layer, ``(global, windowed)`` with two."""
+    if model_cfg.kv_lora_rank:  # one pool of latents (and the indexer's)
+        return (model_cfg.num_layers,)
     if model_cfg.mixer_pattern:  # one mixer a layer: K/V for the "*" layers
         return (model_cfg.layers_of("*"),)
     plan = layer_plan(model_cfg, v2)
@@ -248,7 +253,10 @@ def tables_of(block_tables) -> tuple:
 
 
 def pools_of(caches) -> list:
-    """``caches`` as one (K, V) pair a pool."""
+    """``caches`` as one (K, V) pair a pool (a latent model: its one pool of
+    latents and the indexer's keys, which share the block table)."""
+    if "latent" in caches:
+        return [(caches["latent"], caches["index"])]
     return [(caches["k"], caches["v"])] + (
         [(caches["k_win"], caches["v_win"])] if "k_win" in caches else [])
 
@@ -438,6 +446,190 @@ def hybrid_layers(params, caches, x, write_at, attend,
             jnp.concatenate(moe_stats) if moe_stats else None)
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentRows:
+    """What a latent-attention model's layers have to know of a step's rows:
+    the block table, which rows hold ONE token this step (``single``: every
+    active row of a decode step, the decode rows riding in a mixed step) and
+    the position of that token; of a mixed step also where each row's tokens
+    begin in the flat batch, their first position and how many there are
+    (``paged_prefill_attention``'s three).  The rows of one token take the
+    decode path, the rows of two and more the prefill path."""
+    tables: jax.Array
+    positions: jax.Array
+    single: jax.Array
+    q_start: jax.Array = None
+    chunk_start: jax.Array = None
+    chunk_len: jax.Array = None
+
+
+def latent_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
+    """Shapes of the two pools of a model with latent attention, or {}:
+    ``latent (L, blocks, block, W)``, a token's latent and rotated key
+    (``latent_attention.pool_width``), and ``index (L_full, blocks, block,
+    index_head_dim)``, the indexer's key of the layers that pick.  Both grow
+    with the context and are read through the one block table."""
+    c = model_cfg
+    if not c.kv_lora_rank:
+        return {}
+    width = latent_attention.pool_width(c.kv_lora_rank, c.qk_rope_head_dim)
+    return {"latent": (c.num_layers, v2.num_blocks, v2.block_size, width),
+            "index": (latent_sparse.layers_of(c, "I"), v2.num_blocks,
+                      v2.block_size, c.index_head_dim)}
+
+
+def latent_layers(params, caches, x, positions, write_at,
+                  model_cfg: tfm.TransformerConfig, v2, valid,
+                  rows: LatentRows):
+    """``serving_layers`` for a model with latent attention
+    (``models/latent_sparse.py``).  The layers run as ``ssm_hybrid.segments``
+    cuts the pattern of (picks its own keys or shares, dense or routed FFN):
+    the leading layers unrolled, then one ``lax.scan`` over the periods of
+    four with a period's layers unrolled inside.  Each stack's quantized
+    projections are read in place at the layer's index in THAT stack; both
+    pools ride the carry whole, and so does THE SELECTION: the keys a "full"
+    layer picked for every query of the step, which the "shared" layers
+    behind it attend over (a mask ``(T, S)`` for the prefill rows' queries,
+    ``index_topk`` positions a row for the rows of one token).
+
+    → (hidden state after the final norm, the pools, int32 stats of the
+    step: held experts hit summed over the routed layers, the largest rows of
+    one, the assignments that were local; behind them what a tapped config
+    asks for)."""
+    cfg, la, ls = model_cfg, latent_attention, latent_sparse
+    if rows is None:
+        raise ValueError("a model with latent attention is served by the "
+                         "mixed and decode steps only")
+    blk_ids, offsets = write_at
+    mixed = rows.q_start is not None
+    T = x.shape[0]
+    R, blocks = rows.tables.shape
+    S = blocks * v2.block_size
+    W = caches["latent"].shape[-1]
+    K = min(cfg.index_topk, S)
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rope = ls.rope_tables(cfg, S)
+    scale = ls.softmax_scale(cfg)
+    stacks = {kind: hoist_quantized(tree)
+              for kind, tree in params["layers"].items()}
+    tiles = la.prefill_tiles(rows.chunk_len, T) if mixed else None
+
+    def of(kind, idx):
+        kept, layer_params = stacks[kind]
+        return layer_params(jax.tree.map(lambda a: a[idx], kept), idx)
+
+    def per_row(a):  # the single rows' tokens out of the flat batch
+        return a[jnp.clip(rows.q_start, 0, T - 1)] if mixed else a
+
+    def one_layer(letter, idx, carry):
+        x, latent, index, sel = carry
+        lp = of("A", idx["A"])
+        p = lp["attn"]
+        a_in = tfm._norm(x, lp["ln1"], "rmsnorm", cfg.norm_eps)
+        c_q, q_nope, q_rope = ls.queries(a_in, p, cfg, rope, positions)
+        entry = ls.cache_entry(a_in, p, cfg, rope, positions, W)
+        with jax.named_scope("cache_write"):
+            latent = latent.at[idx["A"], blk_ids[0], offsets].set(
+                entry.astype(latent.dtype))
+        tap = None
+        if letter.isupper():  # picks its own keys, for itself and the shared
+            ip = of("I", idx["I"])["index"]
+            ki = ls.index_key(a_in, ip, cfg, rope, positions)
+            with jax.named_scope("cache_write"):
+                index = index.at[idx["I"], blk_ids[0], offsets].set(
+                    ki.astype(index.dtype))
+            qi, w = ls.index_queries(c_q, a_in, ip, cfg, rope, positions)
+            r_idx, r_ok = la.select_rows(
+                per_row(qi), per_row(w), index, idx["I"], rows.tables,
+                rows.positions, rows.single, K)
+            mask = sel[0]
+            if mixed:
+                mask = la.select_tiles(qi, w, index, idx["I"], rows.tables,
+                                       tiles, rows.q_start, rows.chunk_start,
+                                       K)
+            sel = (mask, r_idx, r_ok)
+            if cfg.dsa_tap:  # tooling only: every query's pick, packed
+                picks = la.rows_as_mask(r_idx, r_ok, S)
+                if mixed:
+                    picks = mask.at[jnp.where(rows.single, rows.q_start, T)
+                                    ].set(picks, mode="drop")
+                tap = la.pack_mask(picks)
+        mask, r_idx, r_ok = sel
+        q_lat = ls.absorb(q_nope, q_rope, p["w_kvb"], W)
+        o = la.latent_decode_attention(
+            per_row(q_lat), latent, idx["A"], rows.tables, r_idx, r_ok,
+            scale=scale, latent=rkv)
+        if mixed:
+            o = la.latent_prefill_attention(
+                q_lat, latent, idx["A"], rows.tables, mask, tiles,
+                rows.q_start, rows.chunk_start, scale=scale, latent=rkv
+            ).at[jnp.where(rows.single, rows.q_start, T)].set(o, mode="drop")
+        x = x + tfm._lin(ls.unabsorb(o, p["w_kvb"], dn, x.dtype), p, "wo",
+                         "bo")
+        m_in = tfm._norm(x, lp["ln2"], "rmsnorm", cfg.norm_eps)
+        stats = None
+        if letter in "Dd":
+            out = tfm._mlp_block(m_in[None], of("D", idx["D"])["mlp"],
+                                 cfg)[0]
+        else:
+            out, stats = serving_moe_block(m_in, of("S", idx["S"])["moe"],
+                                           cfg, valid=valid)
+        return (x + out, latent, index, sel), stats, tap
+
+    def stack_of(letter):
+        return {"I": letter.isupper(), "D": letter in "Dd",
+                "S": letter in "Ss", "A": True}
+
+    sel = (jnp.zeros((T, S) if mixed else (1, 1), bool),
+           jnp.zeros((R, K), jnp.int32), jnp.zeros((R, K), bool))
+    carry = (x, caches["latent"], caches["index"], sel)
+    done = {kind: 0 for kind in ls.KINDS}
+    moe_stats, taps = [], []
+    for unit, reps in ssm_hybrid.segments(ls.pattern(cfg)):
+        per_unit = {kind: sum(stack_of(c)[kind] for c in unit)
+                    for kind in ls.KINDS}
+        base = dict(done)
+
+        def unit_body(carry, rep, unit=unit, per_unit=per_unit, base=base):
+            stats, tapped = [], []
+            seen = {kind: 0 for kind in ls.KINDS}
+            for letter in unit:
+                idx = {kind: base[kind] + rep * per_unit[kind] + seen[kind]
+                       for kind in ls.KINDS}
+                for kind in ls.KINDS:
+                    seen[kind] += stack_of(letter)[kind]
+                carry, st, tap = one_layer(letter, idx, carry)
+                if st is not None:
+                    stats.append(st)
+                if tap is not None:
+                    tapped.append(tap)
+            return carry, (jnp.stack(stats) if stats else None,
+                           jnp.stack(tapped) if tapped else None)
+
+        if reps == 1:
+            carry, (st, tp) = unit_body(carry, jnp.int32(0))
+        else:
+            carry, (st, tp) = jax.lax.scan(
+                unit_body, carry, jnp.arange(reps, dtype=jnp.int32))
+        if st is not None:
+            moe_stats.append(st.reshape(-1, st.shape[-1]))
+        if tp is not None:
+            taps.append(tp.reshape((-1,) + tp.shape[-2:]))
+        for kind in ls.KINDS:
+            done[kind] += reps * per_unit[kind]
+    x, latent, index, _ = carry
+    x = tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
+    stats = None
+    if moe_stats:
+        per_layer = jnp.concatenate(moe_stats)
+        stats = jnp.concatenate(
+            [jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max(),
+                        per_layer[:, 2].sum()]),
+             per_layer[:, 3:].reshape(-1)]
+            + [t.reshape(-1) for t in taps])
+    return x, {"latent": latent, "index": index}, stats
+
+
 def serving_layers(params, caches, x, positions, write_at, attend,
                    model_cfg: tfm.TransformerConfig, v2, adapters=None,
                    slots=None, valid=None, rows: StepRows = None):
@@ -463,7 +655,14 @@ def serving_layers(params, caches, x, positions, write_at, attend,
 
     A model of one mixer a layer (``mixer_pattern``) goes to
     ``hybrid_layers``, with ``rows`` (``StepRows``) what its state layers
-    need to know of the step; every other model traces what it always did."""
+    need to know of the step; a model with latent attention
+    (``kv_lora_rank``) to ``latent_layers``, with ``rows`` (``LatentRows``)
+    what its two attention paths need (it takes no ``attend``: a pick of
+    keys comes between the write and the attention); every other model
+    traces what it always did."""
+    if model_cfg.kv_lora_rank:
+        return latent_layers(params, caches, x, positions, write_at,
+                             model_cfg, v2, valid, rows)
     if model_cfg.mixer_pattern:
         return hybrid_layers(params, caches, x, write_at, attend, model_cfg,
                              valid, rows)
@@ -610,6 +809,8 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
 
     rows = StepRows(active, active & (position_ids == 0)) \
         if model_cfg.mixer_pattern else None
+    if model_cfg.kv_lora_rank:
+        rows = LatentRows(tables[0], position_ids, active)
     x, caches, moe_stats = serving_layers(
         params, caches, x, position_ids, (blk_ids, position_ids % bs), attend,
         model_cfg, v2, adapters, row_adapter, active, rows)
@@ -684,6 +885,9 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
                 row=row, offset=jnp.arange(token_ids.shape[0]) - q_start[row],
                 row_start=q_start, row_len=chunk_len, slots=state_slots,
                 valid=valid)
+        if model_cfg.kv_lora_rank:
+            rows = LatentRows(tables[0], chunk_start, chunk_len == 1,
+                              q_start, chunk_start, chunk_len)
         x, caches, moe_stats = serving_layers(
             params, caches, x, position_ids, (blk_ids, position_ids % bs),
             attend, model_cfg, v2, adapters, tok_slot, valid, rows)

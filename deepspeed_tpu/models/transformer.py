@@ -150,6 +150,42 @@ class TransformerConfig:
     mamba_state_size: int = 0
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
+    # Latent attention (MLA; models/latent_sparse.py), on when kv_lora_rank >
+    # 0: queries through a rank-``q_lora_rank`` bottleneck, keys and values
+    # from ONE latent of ``kv_lora_rank`` values a token and one shared
+    # rotated key of ``qk_rope_head_dim``; a head's query-key width is
+    # ``qk_nope_head_dim + qk_rope_head_dim`` (the softmax scale's), its value
+    # width ``v_head_dim``.  The paged cache holds the latent and the rotated
+    # key, ``kv_lora_rank + qk_rope_head_dim`` values a token a layer
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A learned indexer (DSA) picks the ``index_topk`` keys a query attends
+    # over: ``index_n_heads`` heads of ``index_head_dim`` score every visible
+    # key, on the layers ``indexer_types`` (``num_layers`` long) calls "full";
+    # a "shared" layer attends over the pick of the nearest full layer before
+    # it.  The first layer is "full"
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Tuple[str, ...] = ()
+    # the FFN of each layer of a latent model, "dense" (``intermediate_size``)
+    # or "sparse" (routed experts of ``moe_intermediate_size`` and the shared
+    # expert), ``num_layers`` long; the parameters are stacked by kind
+    mlp_layer_types: Tuple[str, ...] = ()
+    moe_intermediate_size: int = 0  # 0: an expert is ``intermediate_size`` wide
+    # THE CHIP'S SHARE of the experts: the router keeps ``num_experts``
+    # outputs and ``moe_top_k`` choices, the layer holds and computes experts
+    # ``moe_first_expert`` to ``+ moe_experts_held`` only, and assignments to
+    # the others are counted and left out (0: every expert is held)
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    # test and benchmark tooling (benchmark/selection_tap.py): a step program
+    # BUILT for a config with this set carries the indexer's scores and picks
+    # of its "full" layers out.  A served model's config leaves it False
+    dsa_tap: bool = False
     # dtypes
     dtype: str = "bfloat16"  # compute dtype
     param_dtype: str = "float32"  # master weights
@@ -191,6 +227,13 @@ class TransformerConfig:
             if self.layer_types or self.sliding_window:
                 raise ValueError("mixer_pattern with window layers is not "
                                  "something the program computes")
+        object.__setattr__(self, "indexer_types", tuple(self.indexer_types))
+        object.__setattr__(self, "mlp_layer_types",
+                           tuple(self.mlp_layer_types))
+        if self.kv_lora_rank:
+            from .latent_sparse import check_config
+
+            check_config(self)
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         object.__setattr__(self, "rope_params",
@@ -233,6 +276,16 @@ class TransformerConfig:
         return sum(k == kind for k in self.mixer_pattern)
 
     @property
+    def expert_width(self) -> int:
+        """Inner width of one routed expert."""
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this chip holds (all of them unless told)."""
+        return self.moe_experts_held or self.num_experts
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_num_heads * self.mamba_head_dim
 
@@ -254,6 +307,10 @@ class TransformerConfig:
         return 6 * n_params + attn
 
     def num_params(self, include_embed: bool = True) -> int:
+        if self.kv_lora_rank:
+            from .latent_sparse import num_params
+
+            return num_params(self, include_embed)
         if self.mixer_pattern:
             from .ssm_hybrid import num_params
 
@@ -335,6 +392,43 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         num_experts=128, moe_top_k=6, moe_norm_topk=True,
         moe_router="sigmoid", moe_routed_scaling=2.5, moe_shared_size=3712,
         moe_routing="dropless", attn_impl="flash"),
+    # zai-org/GLM-5.2 as published (glm_moe_dsa): 744 B, about 40 B active;
+    # latent attention (MLA), a learned indexer that picks 2,048 keys a query
+    # on every fourth layer and shares the pick with the three behind it,
+    # three leading dense layers, 256 routed experts (top 8) and one shared;
+    # intermediate_size is the DENSE width, an expert's is
+    # moe_intermediate_size.  The multi-token-prediction layer is not part of
+    # the main model and is not built
+    "glm-5.2": dict(
+        vocab_size=154880, hidden_size=6144, intermediate_size=12288,
+        num_layers=78, num_heads=64, num_kv_heads=64, head_dim_override=192,
+        max_seq_len=1048576, rope_theta=8000000.0, norm_eps=1e-5,
+        tie_embeddings=False, kv_lora_rank=512, q_lora_rank=2048,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_topk=2048, index_n_heads=32, index_head_dim=128,
+        indexer_types=("full",) * 3 + ("shared", "shared", "shared",
+                                       "full") * 18 + ("shared",) * 3,
+        mlp_layer_types=("dense",) * 3 + ("sparse",) * 75,
+        num_experts=256, moe_top_k=8, moe_norm_topk=True,
+        moe_router="sigmoid", moe_routed_scaling=2.5,
+        moe_intermediate_size=2048, moe_shared_size=2048,
+        moe_routing="dropless", attn_impl="flash"),
+    # the same block at toy widths: one dense layer, then two periods of
+    # shared shared shared full; 4 of 16 experts held (the second share of
+    # four); index_topk 16, so that a context of 40 is past two of them
+    "tiny-glm52": dict(
+        vocab_size=256, hidden_size=128, intermediate_size=256, num_layers=9,
+        num_heads=4, num_kv_heads=4, head_dim_override=24, max_seq_len=512,
+        rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
+        kv_lora_rank=32, q_lora_rank=64, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=32, index_topk=16, index_n_heads=4,
+        index_head_dim=16,
+        indexer_types=("full",) + ("shared", "shared", "shared", "full") * 2,
+        mlp_layer_types=("dense",) + ("sparse",) * 8,
+        num_experts=16, moe_top_k=4, moe_norm_topk=True, moe_router="sigmoid",
+        moe_routed_scaling=2.5, moe_intermediate_size=128,
+        moe_shared_size=128, moe_experts_held=4, moe_first_expert=4,
+        moe_routing="dropless"),
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
     # nemotron_h's three kinds of layer at toy widths: a pattern that is no
@@ -397,6 +491,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Create the parameter pytree. Per-layer weights are stacked on a leading
     ``layers`` axis so the forward pass can ``lax.scan`` over them (a model
     with ``mixer_pattern``: one stack a kind of layer, models/ssm_hybrid.py)."""
+    if cfg.kv_lora_rank:
+        from .latent_sparse import init_params as init_latent
+
+        return init_latent(rng, cfg)
     if cfg.mixer_pattern:
         from .ssm_hybrid import init_params as init_hybrid
 
@@ -475,6 +573,10 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
 
     Pass ``params`` for HF-converted trees that carry linear biases
     (qwen2/opt/gpt-neox …): bias leaves get matching axes entries."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "a latent-attention model (kv_lora_rank > 0) is served, not "
+            "trained: no sharding rules are written for its parameters yet")
     if cfg.mixer_pattern:
         from .ssm_hybrid import param_axes as hybrid_axes
 
@@ -886,6 +988,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     ``attn_fn``/``moe_fn`` are injection points for Pallas flash attention,
     Ulysses/ring sequence parallelism and expert-parallel MoE dispatch.
     """
+    if cfg.kv_lora_rank:
+        from .latent_sparse import forward_hidden as latent_hidden
+
+        return latent_hidden(params, tokens, cfg, attn_fn)
     if cfg.mixer_pattern:
         from .ssm_hybrid import forward_hidden as hybrid_hidden
 

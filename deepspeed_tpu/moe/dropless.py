@@ -67,6 +67,36 @@ def padded_rows(assignments: int, num_experts: int) -> int:
     return (-(-assignments // tile) + num_experts) * tile
 
 
+#: the most M tiles the layout of a SHARE of the experts may spend on the
+#: assignments themselves (each tile the step does not fill still costs a
+#: grid step of every N tile of three GEMMs)
+_SHARE_MAX_TILES = 32
+
+
+def share_tile_m(assignments: int, num_experts: int, held: int) -> int:
+    """``moe_tile_m`` for a layer that holds ``held`` of ``num_experts``
+    experts.  The layout is static and has to hold EVERY assignment (all T
+    may fall on the held experts; nothing is dropped), while a step is
+    expected to send ``T x held / num_experts`` of them here.  Sized for the
+    expected rows alone (T = 4,096 over 16 of 256: tiles of 32) the layout
+    would be 144 tiles of which 16 hold rows, and the 128 empty ones cost
+    more grid steps than the full ones cost time; sized for T it would be
+    tiles of 512 that hold 16 rows each.  So: the tile of the expected rows,
+    but no smaller than what cuts T into ``_SHARE_MAX_TILES`` tiles (128 at
+    T = 4,096; 16 at a decode step's 128)."""
+    tile = moe_tile_m(-(-assignments * held // num_experts), held)
+    while tile < _MAX_TILE_M and assignments > _SHARE_MAX_TILES * tile:
+        tile *= 2
+    return tile
+
+
+def share_padded_rows(assignments: int, num_experts: int, held: int) -> int:
+    """``padded_rows`` of a share's layout: tiles for all T assignments, one
+    more for each held expert and one for the group that lives elsewhere."""
+    tile = share_tile_m(assignments, num_experts, held)
+    return (-(-assignments // tile) + held + 1) * tile
+
+
 class Routing(NamedTuple):
     weights: jax.Array  # (N, k) float32: the gate weight of each assignment
     experts: jax.Array  # (N, k) int32
@@ -129,6 +159,9 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     that sliced them would copy one layer's codes before every call)."""
     N, H = x2.shape
     E, k = cfg.num_experts, cfg.moe_top_k
+    held = getattr(cfg, "experts_held", E)
+    if held != E:
+        return _routed_ffn_share(x2, p, cfg, routing, valid)
     dt = x2.dtype
     T = N * k
     tile_m = moe_tile_m(T, E)
@@ -173,6 +206,66 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     return y, stats
 
 
+def _routed_ffn_share(x2, p, cfg, routing, valid):
+    """``routed_ffn`` for a layer that holds experts ``moe_first_expert`` to
+    ``+ moe_experts_held`` of ``num_experts`` (one chip of an expert-parallel
+    group, without its exchange): the router scores ALL experts and every
+    row picks its top-k among all; the assignments that fall on a held
+    expert are laid out, computed and combined under their own weights, the
+    others are counted and LEFT OUT, so the output is this share's part of
+    the layer's routed sum (the parts of all shares add up to the whole
+    layer: ``tests/test_glm52.py``).  → ``(y, stats)`` with ``stats`` int32
+    ``(3,)``: held experts that got a row, the largest rows of one, and the
+    assignments that were local, over the rows ``valid`` marks."""
+    N, H = x2.shape
+    E, k = cfg.num_experts, cfg.moe_top_k
+    held, first = cfg.experts_held, cfg.moe_first_expert
+    dt = x2.dtype
+    T = N * k
+    tile_m = share_tile_m(T, E, held)
+    r = routing if routing is not None else route(
+        x2, p["router"], cfg, p.get("router_bias"))
+
+    with jax.named_scope("moe_dispatch"):
+        expert_flat = r.experts.reshape(T)
+        local = (expert_flat >= first) & (expert_flat < first + held)
+        # group ``held``: the experts that live elsewhere, laid out LAST, so
+        # the tiles that hold rows to compute are the first ``used_tiles``
+        group = jnp.where(local, expert_flat - first, held)
+        positions, tile_group, pad_sizes, M_pad = tile_aligned_layout(
+            group, held + 1, T, tile_m)
+        tile_group = jnp.minimum(tile_group, held - 1)  # a block that exists
+        counts = jnp.bincount(group, length=held + 1)[:held]
+        used_tiles = jnp.sum(-(-counts // tile_m)).astype(jnp.int32)
+        at = jnp.where(local, positions, M_pad)  # elsewhere: written nowhere
+        xs = jnp.zeros((M_pad, H), dt).at[at].set(
+            jnp.repeat(x2, k, axis=0), mode="drop")
+        if valid is not None:
+            counts = jnp.bincount(
+                group, weights=jnp.repeat(valid, k).astype(jnp.int32),
+                length=held + 1)[:held]
+        stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                           jnp.sum(counts)]).astype(jnp.int32)
+        if getattr(cfg, "moe_tap_choices", False):  # tooling only
+            stats = jnp.concatenate([stats, expert_flat])
+
+    with jax.named_scope("moe_experts"):
+        def gmm(a, key):
+            return _expert_gemm(a, p[key], tile_group, pad_sizes[:held],
+                                used_tiles, tile_m)
+
+        hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
+        ys = gmm(hmid, "w_out")
+
+    with jax.named_scope("moe_combine"):
+        # rows past ``used_tiles`` were never computed: read as zero, not as
+        # whatever the buffer held
+        picked = jnp.where(local[:, None], ys[jnp.minimum(at, M_pad - 1)], 0)
+        y = jnp.sum(picked.reshape(N, k, H).astype(jnp.float32)
+                    * r.weights[..., None], axis=1).astype(dt)
+    return y, stats
+
+
 def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
                       valid: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, jax.Array]:
@@ -187,8 +280,13 @@ def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
         from ..models.transformer import _lin, apply_activation
 
         with jax.named_scope("moe_shared"):
-            mid = apply_activation(_lin(x2, p, "sh_w_in", "sh_b_in"),
-                                   cfg.activation)
+            if "sh_w_gate" in p:  # a gated shared expert (SwiGLU)
+                mid = apply_activation(
+                    _lin(x2, p, "sh_w_gate", "sh_b_gate"), cfg.activation
+                ) * _lin(x2, p, "sh_w_in", "sh_b_in")
+            else:
+                mid = apply_activation(_lin(x2, p, "sh_w_in", "sh_b_in"),
+                                       cfg.activation)
             y = y + _lin(mid, p, "sh_w_out", "sh_b_out")
     y = y.reshape(x.shape)
     if getattr(cfg, "moe_use_residual", False):

@@ -6,7 +6,9 @@ spans, what ``correct`` sees of an altered token and of the control),
 imported as ``tests/test_mellum2_cell.py`` imports its rehearsal.  One test
 is replaced: the count of the cell's per-layer metrics, which that file
 fixes at PR 36's sixteen and PR 37 raised by six (a file under
-``benchmark/`` is a ``benchmark`` PR's to edit)."""
+``benchmark/`` is a ``benchmark`` PR's to edit); and a second, for the same
+reason: ``test_every_listed_name_is_found`` fixes the benchmark at seven
+cells, and PR 40 added the eighth."""
 
 import os
 import sys
@@ -15,6 +17,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark", "tests"))
 from test_doc_prefill_loaded import *  # noqa: E402,F401,F403  (its tests)
 from test_doc_prefill_loaded import CELL, run  # noqa: E402
+from test_doc_prefill_loaded import \
+    test_every_listed_name_is_found as _seven_cells  # noqa: E402
 
 WAITED_FOR = ("mixed_host_wait_ms_mean", "loop_turn_wait_ms_mean",
               "device_starved_pct", "step_interval_p90_ms",
@@ -31,3 +35,39 @@ def test_the_new_cell_reports_what_the_retired_one_reported(spec):  # noqa: F811
     alone = [m["name"] for m in spec["per_layer"]
              if m.get("workloads") == [CELL]]
     assert len(alone) == 7
+
+
+def _without(spec, cell):
+    """``spec`` less one cell: off every list, and the metrics it alone
+    reported gone."""
+    import copy
+
+    less = copy.deepcopy(spec)
+    less["workloads"] = [w for w in less["workloads"] if w["name"] != cell]
+    for m in less["end_to_end"] + less["per_layer"]:
+        if cell in m.get("workloads", ()):
+            m["workloads"].remove(cell)
+    less["per_layer"] = [m for m in less["per_layer"]
+                         if m.get("workloads") != []]
+    return less
+
+
+def test_every_listed_name_is_found(spec):  # noqa: F811
+    """The file's own test on the benchmark less the cell PR 40 added (its
+    count of seven cells holds of those), and the eighth cell's names found
+    as it finds the others': seven again, the new one among them."""
+    _seven_cells(_without(spec, "glm52-ctx8k-sat"))
+    _seven_cells(_without(spec, "olmoe-decode-sat"))
+    assert len(spec["workloads"]) == 8
+
+
+def test_mixed_gap_share_is_listed_for_every_serving_cell(spec):  # noqa: F811
+    """The file's test with the count of serving cells as it is now (five
+    there; PR 40 added the sixth)."""
+    entry = next(m for m in spec["per_layer"]
+                 if m["name"] == "mixed_gap_share_pct")
+    itl = next(m for m in spec["end_to_end"] if m["name"] == "itl_p90_ms")
+    assert (entry["moves"], entry["source"], entry["layer"]) == (
+        "itl_p90_ms", "program_span", "scheduler")
+    assert sorted(entry["workloads"]) == sorted(itl["workloads"])
+    assert len(entry["workloads"]) == 6

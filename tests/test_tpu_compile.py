@@ -185,7 +185,9 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     shapes)."""
     from deepspeed_tpu.inference.quantization import quantize_model_params
     from deepspeed_tpu.inference.v2 import engine as v2e
-    from deepspeed_tpu.inference.v2.programs import pool_layers, state_arrays
+    from deepspeed_tpu.inference.v2.programs import (latent_arrays,
+                                                     pool_layers,
+                                                     state_arrays)
     from deepspeed_tpu.models import transformer as tfm
 
     v2 = v2e.V2Config(**{**dict(
@@ -204,6 +206,10 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     caches = {"k": sds(pool, jnp.bfloat16), "v": sds(pool, jnp.bfloat16)}
     for name, (shape, dtype) in state_arrays(cfg, v2).items():
         caches[name] = sds(shape, dtype)  # a model with state layers
+    latent = latent_arrays(cfg, v2)
+    if latent:  # a latent pool and the indexer's, in place of K and V
+        caches = {k: sds(shape, jnp.bfloat16) for k, shape in latent.items()}
+        pool = (latent["latent"], latent["index"])
     rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
     tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
     if len(layers) == 2:  # the window layers' pool and table beside them
@@ -763,6 +769,69 @@ def test_nemotron3_step_programs_compile(one_chip, mosaic, program):
     state = 4 * 65 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
     assert mem.alias_size_in_bytes >= 2 * 2 * int(np.prod(pool)) + state
     assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_glm52_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of GLM-5.2 (every width as published, W8A16 at
+    group 128, the chip's 16 of 256 experts, an eighth of the vocabulary; the
+    cut ``D sssS``: the dense layer that picks and ONE period, so the cell's
+    two traced bodies; the serving cell's engine sizes: 16 rows, 4,353
+    blocks, tables of 272) compile for the described chip.  Every GEMM runs
+    its kernel (no ``kernel/*_tiles`` event with ``fallback``: the grouped
+    GEMM holds all of K = 6144 in a step at tiles of 256 columns), the
+    prefill path leaves its ring event, the lowered program names the latent
+    attention's and the indexer's scopes beside the ``moe_*`` ones, and both
+    pools are updated in place."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg = dataclasses.replace(
+        tfm.get_config(
+            "glm-5.2", num_layers=5, vocab_size=19360, moe_experts_held=16,
+            indexer_types=("full", "shared", "shared", "shared", "full"),
+            mlp_layer_types=("dense",) + ("sparse",) * 4),
+        dtype="bfloat16", param_dtype="bfloat16")
+    tracer.clear()
+    lowered, pools, params = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip), group=128,
+        max_seqs=16, num_blocks=4353, max_blocks_per_seq=272)
+    assert pools == ((5, 4353, 64, 640), (2, 4353, 64, 128))
+    moe = params["layers"]["S"]["moe"]
+    assert moe["w_in"].codes.shape == (4, 16, 6144, 2048)
+    assert moe["router"].shape == (4, 6144, 256)  # all experts are scored
+    assert params["layers"]["A"]["attn"]["w_kvb"].dtype == jnp.bfloat16
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    assert not [e for e in events if "fallback" in e[1]], events
+    grouped = {(a["k"], a["n"], a["tn"], a["tk"], a["tile_m"])
+               for name, a in events
+               if name == "kernel/grouped_mixed_gemm_tiles"}
+    tile_m = 16 if program == "decode_step" else 128
+    assert grouped == {(6144, 2048, 256, 6144, tile_m),
+                       (2048, 6144, 1024, 2048, tile_m)}
+    assert ("kernel/latent_attention_prefill_tiles" in
+            {name for name, _ in events}) == (program == "mixed_step")
+    text = lowered.as_text(debug_info=True)
+    scopes = ["grouped_mixed_gemm", "mixed_gemm", "moe_route", "moe_dispatch",
+              "moe_experts", "moe_combine", "moe_shared", "dsa_index_scores",
+              "dsa_topk", "dsa_index_proj", "latent_attention_decode",
+              "latent_q_proj", "latent_kv_proj", "latent_absorb_q",
+              "latent_absorb_o"]
+    if program == "mixed_step":
+        scopes.append("latent_attention_prefill")
+    for name in scopes:
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
+    for pool in pools:
+        assert _pool_passes(compiled_text, pool) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p)) for p in pools)
+    assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
 
 
 def test_mesh_follows_the_torus(topo):
