@@ -5,34 +5,49 @@ The routed experts of a served MoE model, weight-only quantized: what
 projection.  Rows arrive in the tile-aligned grouped layout
 (``grouped_matmul.tile_aligned_layout``): every M tile belongs to one expert,
 and a scalar-prefetched ``tile_group`` steers the tile's code and scale
-blocks to that expert's, so the body is ``mixed_gemm``'s dequantize walk (one
-quantization group and one column chunk at a time, bf16 into the MXU, f32
-accumulation) over one expert's matrix.
+blocks to that expert's.  The grid is ``mixed_gemm``'s, (M tiles, N tiles,
+K tiles) with a float32 accumulator in VMEM that the first K tile zeroes and
+the last writes out, the body is ``mixed_gemm``'s too
+(``mixed_gemm.dequantize_walk``: one quantization group and one column chunk
+at a time, bf16 into the MXU), and so is the tile: ``pick_gemm_tiles`` with
+the caller's ``tile_m`` for ``tm`` (``moe/dropless.moe_tile_m``) gives the
+widest ``tn`` and as many whole groups of K as make a step about 2 MB of
+codes.  An expert of 2 MB (OLMoE 2048 x 1024, Mellum2 2304 x 896) is one
+step; Nemotron-3's 2688 x 1920 is all of N in three K tiles, GLM-5.2's
+6144 x 2048 in six.  Measured on the chip (``scripts/grouped_gemm_alone.py``,
+PERF.md section 5): width is what a tile must have (640 columns stream a
+fifth slower than 896 or more), depth from 640 rows on makes no difference
+while the codes' HBM time bounds the call.  The scale block is the tile's
+whole K column ``(K / group, tn)`` indexed at the K tile's first group: the
+stored ``(…, K / group, N)`` array is read as it lies, with no reshaped copy
+of the scales.
 
 Three things differ from the dense kernel, all because experts are many and
 small:
 
-* **A grid step holds all of K.**  An expert matrix is a couple of megabytes
-  (OLMoE: 2048 x 1024 = 2 MB of int8 codes), the size ``pick_gemm_tiles``
-  wants a step to move, so the grid is (M tiles, N tiles) with no K axis.
-  The scale block is then ``(K / group, tn)`` with its first dimension whole:
-  the stored ``(…, K / group, N)`` array is read as it lies, with no reshaped
-  copy of the scales.  Consecutive M tiles of one expert name the same
-  blocks, so the pipeline fetches an expert's codes once however many tiles
-  its rows fill.
-* **Tiles past the rows are skipped.**  The layout always holds
-  ``num_experts`` spare tiles; ``used_tiles`` (scalar-prefetched) tells the
-  body where the rows end.  A skipped tile fetches nothing new (its block
-  index is the last expert's) and computes nothing; its output rows are
-  never read.
+* **Tiles past the rows are skipped, and fetch nothing.**  The layout
+  always holds ``num_experts`` spare tiles; ``used_tiles``
+  (scalar-prefetched) tells the body where the rows end.  A skipped step
+  computes nothing and names, for every operand and for the output, the
+  blocks of the LAST STEP THAT HAD ROWS (``live_step``: M tile, N tile and
+  K tile all held), so the pipeline moves nothing for it; its output rows
+  are never written and never read.
+* **An expert's codes are fetched once a tile of its rows.**  Consecutive M
+  tiles of one expert name the same blocks only where the expert is one
+  grid step (OLMoE, Mellum2); a larger expert is fetched again for each M
+  tile its rows fill.  ``moe_tile_m`` sizes tiles at twice the mean rows an
+  expert gets, so under near-uniform routing an expert is one tile (at 128
+  rows a tile its matmuls take about as long as the fetch).  A trace shows
+  where that fails: the ring event's ``steps_per_expert`` above 1 beside a
+  step whose ``moe_rows_max`` is above ``tile_m``.
 * **The layer is an index, not a slice.**  Codes and scales may be the whole
   stack ``(L, E, K, N)`` with ``layer`` scalar-prefetched: a ``pallas_call``
   cannot fuse a slice of its operand, so a layer scan that sliced the experts
   would write and read one layer's codes (0.4 GB for OLMoE) before each call.
 
-``tile_m`` is the caller's (``moe/dropless.moe_tile_m``); ``tn`` comes from
-``pick_gemm_tiles`` called with that ``tm``.  Shapes that do not tile fall
-back to dequantize-then-``ragged_dot`` with ``backend.warn_fallback``.
+int8 codes on rows that tile run the kernel whatever the expert's size;
+other widths of code and rows that do not tile fall back to
+dequantize-then-``ragged_dot`` with ``backend.warn_fallback``.
 """
 
 from __future__ import annotations
@@ -48,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ...observability.trace import tracer
 from . import backend
 from .mixed_gemm import (_VMEM_LIMIT, GemmTiles, QuantizedWeight,
-                         column_chunks, dequantize_gemm_weight,
+                         dequantize_gemm_weight, dequantize_walk,
                          layer_of_stack, pick_gemm_tiles)
 
 
@@ -56,37 +71,38 @@ def pick_grouped_tiles(rows: int, tile_m: int, k: int, n: int, bits: int,
                        group: int, x_itemsize: int = 2
                        ) -> Optional[GemmTiles]:
     """``pick_gemm_tiles`` for ``rows`` laid out in M tiles of ``tile_m``:
-    the widest int8 tile that holds all of K (see the module text) on rows
-    that tile, or None.  (An expert width that is no multiple of 128, such
-    as 1856 = 29 x 64, has no lane-aligned ``tn``: the quantizer stores such
-    a width zero-padded to the next multiple, ``inference/quantization.py``.)"""
+    the dense rule's int8 tile (the widest ``tn``, as many whole groups of K
+    as make a step about 2 MB of codes) on rows that tile, or None.  (An
+    expert width that is no multiple of 128, such as 1856 = 29 x 64, has no
+    lane-aligned ``tn``: the quantizer stores such a width zero-padded to
+    the next multiple, ``inference/quantization.py``.)"""
     if bits != 8 or rows % tile_m or tile_m % 16:
         return None
-    return pick_gemm_tiles(rows, k, n, bits, group, x_itemsize, tm=tile_m,
-                           whole_k=True)
+    return pick_gemm_tiles(rows, k, n, bits, group, x_itemsize, tm=tile_m)
 
 
 def _kernel(tile_group_ref, used_ref, layer_ref, x_ref, c_ref, s_ref, o_ref,
             acc_ref, *, group: int):
-    """One (tile_m, tn) output tile: the rows of one expert against all of
-    K of its codes ``c_ref (K, tn)`` and scales ``s_ref (K / group, tn)``."""
+    """One (tile_m, tn) output tile's step over a k-tile: the rows of one
+    expert against ``c_ref (tk, tn)`` of its codes and the scales of the
+    tile's whole K column ``s_ref (K / group, tn)``."""
     del tile_group_ref, layer_ref  # the index maps read them
+    kk, nk = pl.program_id(2), pl.num_programs(2)
 
     @pl.when(pl.program_id(0) < used_ref[0])
     def _compute():
-        tn = o_ref.shape[1]
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        # static loops: every slice is a static, tile-aligned window
-        for gi in range(s_ref.shape[0]):
-            x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
-            for cols in column_chunks(tn):
-                c = c_ref[gi * group:(gi + 1) * group, cols]
-                w = (c.astype(jnp.float32) * s_ref[gi:gi + 1, cols]
-                     ).astype(jnp.bfloat16)
-                acc_ref[:, cols] += jax.lax.dot_general(
-                    x, w, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+        dequantize_walk(x_ref, c_ref, s_ref, o_ref, acc_ref, kk, nk, bits=8,
+                        group=group)
+
+
+def live_step(i, j, kk, used, nj: int, nk: int):
+    """Grid step ``(i, j, kk)`` of M, N and K tiles, or for an M tile past
+    the ``used`` ones the last step that had rows, ``(used - 1, nj - 1,
+    nk - 1)``: a skipped step names the blocks the step before it named, so
+    the pipeline fetches nothing for it."""
+    on = i < used
+    return (jnp.where(on, i, jnp.maximum(used - 1, 0)),
+            jnp.where(on, j, nj - 1), jnp.where(on, kk, nk - 1))
 
 
 def _grouped_pallas(x, codes, scales, tile_group, used_tiles, layer,
@@ -94,26 +110,42 @@ def _grouped_pallas(x, codes, scales, tile_group, used_tiles, layer,
     """``codes (L, E, K, N)``, ``scales (L, E, K / group, N)``."""
     M, K = x.shape
     N = codes.shape[-1]
-    tm, tn = tiles.tm, tiles.tn
+    tm, tn, tk = tiles.tm, tiles.tn, tiles.tk
+    nj, nk = N // tn, K // tk
+
+    def at(block):
+        """The index map of an operand whose block a LIVE step ``(i, j, kk)``
+        names ``block(i, j, kk, tile_group, layer)``."""
+        def index_map(i, j, kk, tg, used, lay):
+            i, j, kk = live_step(i, j, kk, used[0], nj, nk)
+            return block(i, j, kk, tg, lay[0])
+        return index_map
+
     return pl.pallas_call(
         functools.partial(_kernel, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(M // tm, N // tn),
+            grid=(M // tm, nj, nk),
             in_specs=[
-                pl.BlockSpec((tm, K), lambda i, j, tg, used, lay: (i, 0)),
-                pl.BlockSpec((None, None, K, tn),
-                             lambda i, j, tg, used, lay: (lay[0], tg[i], 0, j)),
+                pl.BlockSpec((tm, tk), at(lambda i, j, kk, tg, lay: (i, kk))),
+                pl.BlockSpec((None, None, tk, tn),
+                             at(lambda i, j, kk, tg, lay: (lay, tg[i], kk, j))),
+                # the scales of the tile's whole K column, as they are stored:
+                # the block does not move along kk, and no reshaped copy of
+                # the scales exists
                 pl.BlockSpec((None, None, K // group, tn),
-                             lambda i, j, tg, used, lay: (lay[0], tg[i], 0, j)),
+                             at(lambda i, j, kk, tg, lay: (lay, tg[i], 0, j))),
             ],
+            # a skipped step keeps the last live tile's block, which is
+            # written once it is left: no tile of rows that nobody reads
+            # goes to HBM
             out_specs=pl.BlockSpec((tm, tn),
-                                   lambda i, j, tg, used, lay: (i, j)),
+                                   at(lambda i, j, kk, tg, lay: (i, j))),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=backend.interpret(),
         name="grouped_mixed_gemm",
@@ -148,7 +180,9 @@ def grouped_mixed_gemm(x: jax.Array, qw: QuantizedWeight,
     # chosen once per shape, while the caller's program is traced
     tracer.add_event("kernel/grouped_mixed_gemm_tiles", attrs={
         "e": E, "k": K, "n": N, "rows": M, "tile_m": tile_m,
-        **({"tn": tiles.tn, "tk": tiles.tk,
+        **({"tn": tiles.tn, "tk": tiles.tk, "k_tiles": K // tiles.tk,
+            "m_tiles": M // tile_m,
+            "steps_per_expert": (N // tiles.tn) * (K // tiles.tk),
             "grid_steps": tiles.grid_steps,
             "code_bytes_per_step": tiles.code_bytes_per_step}
            if tiles else {"fallback": 1})})

@@ -201,8 +201,8 @@ class GemmTiles:
 
 @functools.lru_cache(maxsize=None)
 def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
-                    x_itemsize: int = 2, tm: Optional[int] = None,
-                    whole_k: bool = False) -> Optional[GemmTiles]:
+                    x_itemsize: int = 2, tm: Optional[int] = None
+                    ) -> Optional[GemmTiles]:
     """The ``(tm, tn, tk)`` tile of an ``(m, k) @ (k, n)`` mixed GEMM (``m``
     already padded to the sublane multiple), or None when the shapes do not
     tile (→ the dequantize-then-matmul fallback).
@@ -216,9 +216,7 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
     the call at ``_MIN_STEPS`` steps or more.
 
     A caller whose rows already lie in M tiles (the grouped GEMM of MoE
-    experts) passes its own ``tm``, a divisor of ``m``; the rest follows.
-    With ``whole_k`` (the grouped GEMM's rule: a step holds all of K) ``tn``
-    walks down until the tile is all of K deep; None if none is."""
+    experts) passes its own ``tm``, a divisor of ``m``; the rest follows."""
     # int4 packs two codes per byte (group must be even); fp6 packs 4 K-rows
     # per 3 byte-rows (group must divide by 4, and the byte-row tile must be
     # sublane-aligned); int8 has no pack constraint
@@ -243,8 +241,6 @@ def pick_gemm_tiles(m: int, k: int, n: int, bits: int, group: int,
                  if groups % g == 0 and g * group * tn <= _TILE_WEIGHTS
                  and (m // tm) * (n // tn) * (groups // g) >= _MIN_STEPS],
                 default=1)
-        if whole_k and g != groups:
-            continue  # a narrower tile may hold all of K
         tk = g * group
         codes = _code_rows(tk, bits) * tn
         chunk = min(tn, _CHUNK_N)
@@ -284,33 +280,37 @@ def _unpack_decode_fp6(c):
     return minifloat_decode(codes, 3, 2)
 
 
-def _mixed_gemm_kernel(lay_ref, x_ref, c_ref, s_ref, o_ref, acc_ref, *,
-                       bits: int, group: int):
-    """One (tm, tn) output tile's step over a k-tile of ``g`` quantization
-    groups: codes (rows of g groups, tn) and the scales of the tile's whole
-    K column ``(K / group, tn)``, each group dequantized with its own scale
-    row into the same f32 accumulator."""
-    del lay_ref  # the index maps read it
-    kk = pl.program_id(2)
-    nk = pl.num_programs(2)
-
+def dequantize_walk(x_ref, c_ref, s_ref, o_ref, acc_ref, kk, nk, *,
+                    bits: int, group: int):
+    """One (tm, tn) output tile's step over k-tile ``kk`` of ``nk``, the one
+    body of the dense and the grouped kernel: ``acc_ref (tm, tn) += x_ref
+    (tm, tk) @ dequant(c_ref)``, zeroed at the first k-tile and written to
+    ``o_ref`` at the last.  ``c_ref`` holds the code rows of a k-tile of
+    whole quantization groups, ``s_ref`` the scales of the tile's whole K
+    column ``(K / group, tn)``.  One group and one column chunk at a time:
+    dequantize with the group's scale row, bfloat16 into the MXU, float32
+    into ``acc_ref``.  (The caller reads ``kk`` and ``nk`` off the grid at
+    the kernel's top level: the interpreter has no ``program_id`` inside a
+    ``pl.when``.)"""
     @pl.when(kk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    tn = o_ref.shape[1]
+    tn = acc_ref.shape[1]
     rows = _code_rows(group, bits)
     g = c_ref.shape[0] // rows  # groups a k-tile
+    first_group = kk * g
     # static loops: every slice of the codes is a static, tile-aligned window
     for gi in range(g):
         x = x_ref[:, gi * group:(gi + 1) * group].astype(jnp.bfloat16)
+        scale_row = pl.ds(first_group + gi, 1)
         for cols in column_chunks(tn):
             c = c_ref[gi * rows:(gi + 1) * rows, cols]
             if bits == 4:
                 c = _unpack_int4(c)
             if bits == 6:
                 c = _unpack_decode_fp6(c)
-            w = (c.astype(jnp.float32) * s_ref[pl.ds(kk * g + gi, 1), cols]
+            w = (c.astype(jnp.float32) * s_ref[scale_row, cols]
                  ).astype(jnp.bfloat16)
             acc_ref[:, cols] += jax.lax.dot_general(
                 x, w, (((1,), (0,)), ((), ())),
@@ -319,6 +319,15 @@ def _mixed_gemm_kernel(lay_ref, x_ref, c_ref, s_ref, o_ref, acc_ref, *,
     @pl.when(kk == nk - 1)
     def _flush():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+
+
+def _mixed_gemm_kernel(lay_ref, x_ref, c_ref, s_ref, o_ref, acc_ref, *,
+                       bits: int, group: int):
+    """``dequantize_walk`` over codes (rows of g groups, tn) of the layer the
+    index maps chose."""
+    del lay_ref  # the index maps read it
+    dequantize_walk(x_ref, c_ref, s_ref, o_ref, acc_ref, pl.program_id(2),
+                    pl.num_programs(2), bits=bits, group=group)
 
 
 def _gemm_pallas(x2: jax.Array, qw: QuantizedWeight, layer: jax.Array,

@@ -462,9 +462,10 @@ def test_nemotron_engine_holds_state_beside_one_kv_pool(tiny):
 
 @pytest.mark.parametrize("rows, tile_m", [(2432, 16), (11264, 64)])
 def test_grouped_picker_at_nemotrons_experts(rows, tile_m):
-    """1856 = 29 x 64 stored as 1920: a tile with all of K in a step, for
-    the decode step's layout (64 rows x top 6 over 128 experts) and the mixed
-    step's (512 tokens)."""
+    """1856 = 29 x 64 stored as 1920: the dense rule's tile (all of N, K in
+    three tiles of whole groups, 1.7 MB of codes a step), for the decode
+    step's layout (64 rows x top 6 over 128 experts) and the mixed step's
+    (512 tokens)."""
     from deepspeed_tpu.moe.dropless import moe_tile_m, padded_rows
 
     assert (moe_tile_m(64 * 6, 128), padded_rows(64 * 6, 128)) == (16, 2432)
@@ -473,7 +474,8 @@ def test_grouped_picker_at_nemotrons_experts(rows, tile_m):
                                                128)
     down = grouped_mixed_gemm.pick_grouped_tiles(rows, tile_m, 1920, 2688, 8,
                                                  128)
-    assert (up.tn, up.tk) == (640, 2688) and (down.tn, down.tk) == (896, 1920)
+    assert (up.tn, up.tk) == (1920, 896) and (down.tn, down.tk) == (2688, 640)
+    assert up.code_bytes_per_step == down.code_bytes_per_step == 1720320
     # the unpadded width has no tile at any group
     for group in (32, 64, 128):
         assert grouped_mixed_gemm.pick_grouped_tiles(

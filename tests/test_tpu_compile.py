@@ -595,6 +595,54 @@ def test_grouped_mixed_gemm_compiles(one_chip, mosaic, k, n, tokens):
     assert event["grid_steps"] == rows // tile_m
 
 
+@pytest.mark.parametrize("experts, tokens, top_k, h, f, tiles", [
+    # Nemotron-3: 128 experts top 6, 64 rows and 512 tokens a step
+    (128, 64, 6, 2688, 1920, ((1920, 896), (2688, 640))),
+    (128, 512, 6, 2688, 1920, ((1920, 896), (2688, 640))),
+    # GLM-5.2's matrices (a share's layout has other rows, the same tiles)
+    (16, 16, 8, 6144, 2048, ((2048, 1024), (3072, 512))),
+    (16, 512, 8, 6144, 2048, ((2048, 1024), (3072, 512))),
+    # Mixtral's expert: no tile holds all of K
+    (8, 32, 2, 4096, 14336, ((3584, 512), (4096, 512))),
+], ids=["nemotron3-decode", "nemotron3-mixed", "glm52-decode", "glm52-mixed",
+        "mixtral-decode"])
+def test_grouped_mixed_gemm_compiles_with_k_in_tiles(one_chip, mosaic,
+                                                     experts, tokens, top_k,
+                                                     h, f, tiles):
+    """The grouped W8A16 GEMM at expert shapes larger than a grid step's 2 MB
+    of codes (group 128): the up and the down matrix compile under the dense
+    rule's tile, K walked in the grid with the accumulator in VMEM, the
+    scale block the tile's whole K column (21, 15, 48, 16, 32 and 112 rows:
+    no multiple of the sublane tile has to divide it), and nothing falls
+    back."""
+    from deepspeed_tpu.moe.dropless import moe_tile_m, padded_rows
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
+    from deepspeed_tpu.ops.pallas.mixed_gemm import QuantizedWeight
+
+    layers, group = 2, 128
+    tile_m = moe_tile_m(tokens * top_k, experts)
+    rows = padded_rows(tokens * top_k, experts)
+    for (k, n), (tn, tk) in zip(((h, f), (f, h)), tiles):
+        tracer.clear()
+        _compile(
+            lambda x, c, s, tg, sizes, used, layer: grouped_mixed_gemm(
+                x, QuantizedWeight(c, s, 8, group, k), tg, sizes, used,
+                tile_m=tile_m, layer=layer),
+            _sds((rows, k), jnp.bfloat16, one_chip),
+            _sds((layers, experts, k, n), jnp.int8, one_chip),
+            _sds((layers, experts, k // group, n), jnp.float32, one_chip),
+            _sds((rows // tile_m,), jnp.int32, one_chip),
+            _sds((experts,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+            kernels=["grouped_mixed_gemm"])
+        (event,) = [s.attrs for s in tracer.spans()
+                    if s.name == "kernel/grouped_mixed_gemm_tiles"]
+        assert "fallback" not in event
+        assert (event["tn"], event["tk"], event["k_tiles"]) == (tn, tk,
+                                                                k // tk)
+
+
 @pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
 def test_olmoe_step_programs_compile(one_chip, mosaic, program):
     """The two step programs of OLMoE-1B-7B (all 16 layers, W8A16 experts and
@@ -707,9 +755,9 @@ def test_nemotron3_step_programs_compile(one_chip, mosaic, program):
     bodies and no stack of one layer; the serving cell's engine sizes: 64 rows,
     2,048 blocks, tables of 24) compile for the described chip.  Every GEMM
     runs its kernel (no ``kernel/*_tiles`` event with ``fallback``: the
-    experts' width 1856 is stored as 1920, tiles (2688, 640) and (1920, 896)
-    with all of K in a step), both state updates leave their ring events and
-    their names in the lowered program beside the ``ssm_*``, ``moe_*`` and
+    experts' width 1856 is stored as 1920, all of N a tile and K walked in
+    three, (896, 1920) and (640, 2688)), both state updates leave their ring
+    events and their names in the lowered program beside the ``ssm_*``, ``moe_*`` and
     ``moe_shared`` scopes, and the K/V pool and both state arrays are
     updated in place."""
     import dataclasses
@@ -734,7 +782,7 @@ def test_nemotron3_step_programs_compile(one_chip, mosaic, program):
     assert not [e for e in events if "fallback" in e[1]], events
     grouped = {(a["k"], a["n"], a["tn"], a["tk"]) for name, a in events
                if name == "kernel/grouped_mixed_gemm_tiles"}
-    assert grouped == {(2688, 1920, 640, 2688), (1920, 2688, 896, 1920)}
+    assert grouped == {(2688, 1920, 1920, 896), (1920, 2688, 2688, 640)}
     names = {name for name, _ in events}
     assert "kernel/ssm_decode_update" in names
     assert ("kernel/ssd_chunk_scan_tiles" in names) == (
@@ -779,7 +827,7 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     two traced bodies; the serving cell's engine sizes: 16 rows, 4,353
     blocks, tables of 272) compile for the described chip.  Every GEMM runs
     its kernel (no ``kernel/*_tiles`` event with ``fallback``: the grouped
-    GEMM holds all of K = 6144 in a step at tiles of 256 columns), the
+    GEMM walks K = 6144 in six tiles of 1024, all 2048 columns a tile), the
     prefill path leaves its ring event, the lowered program names the latent
     attention's and the indexer's scopes beside the ``moe_*`` ones, and both
     pools are updated in place."""
@@ -810,8 +858,8 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
                for name, a in events
                if name == "kernel/grouped_mixed_gemm_tiles"}
     tile_m = 16 if program == "decode_step" else 128
-    assert grouped == {(6144, 2048, 256, 6144, tile_m),
-                       (2048, 6144, 1024, 2048, tile_m)}
+    assert grouped == {(6144, 2048, 2048, 1024, tile_m),
+                       (2048, 6144, 3072, 512, tile_m)}
     assert ("kernel/latent_attention_prefill_tiles" in
             {name for name, _ in events}) == (program == "mixed_step")
     text = lowered.as_text(debug_info=True)
