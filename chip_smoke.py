@@ -29,7 +29,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +129,17 @@ class Sizes:
     # the tapped rows against the reference held to the program's picks and
     # experts (median, worst: the serving cell's bounds)
     latent_logit_tol: Tuple[float, float] = (0.12, 0.3)
+    # -- a latent model TRAINED on one chip of eight: the dense layer and five
+    # routed ones at DeepSeek-V2-Lite's widths, 8 of 64 experts, an eighth of
+    # the vocabulary: 0.635 B parameters, 8.9 GB at 14 B a parameter; two
+    # rows of 8,192 (the benchmark cell's configuration and batch)
+    latent_train_preset: str = "deepseek-v2-lite"
+    latent_train_layers: int = 6
+    latent_train_held: int = 8
+    latent_train_vocab: int = 12800
+    latent_train_micro: int = 2
+    latent_train_seq: int = 8192
+    latent_train_steps: int = 2
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -195,7 +206,9 @@ class Recorded:
 
 
 def build_trainer(sz: Sizes, seed: int, num_layers: int,
-                  ds_extra: Dict[str, Any]):
+                  ds_extra: Dict[str, Any], preset: Optional[str] = None,
+                  overrides: Optional[Dict[str, Any]] = None,
+                  micro: Optional[int] = None, seq: Optional[int] = None):
     """``deepspeed_tpu.initialize`` as ``examples/train.py`` drives it."""
     import deepspeed_tpu
     from deepspeed_tpu.parallel import topology
@@ -203,8 +216,8 @@ def build_trainer(sz: Sizes, seed: int, num_layers: int,
     from deepspeed_tpu.sequence.tiled_compute import tiled_loss_fn
 
     topology.reset_topology()
-    cfg = tfm.get_config(sz.preset, num_layers=num_layers,
-                         param_dtype="bfloat16")
+    cfg = tfm.get_config(preset or sz.preset, num_layers=num_layers,
+                         param_dtype="bfloat16", **(overrides or {}))
     params = jax.jit(lambda k: tfm.init_params(k, cfg))(
         jax.random.PRNGKey(seed))
 
@@ -215,7 +228,7 @@ def build_trainer(sz: Sizes, seed: int, num_layers: int,
                      param_axes=tfm.param_axes(cfg),
                      flops_per_token=cfg.flops_per_token())
     config = {
-        "train_micro_batch_size_per_gpu": sz.train_micro,
+        "train_micro_batch_size_per_gpu": micro or sz.train_micro,
         "optimizer": {"type": "AdamW", "params": {"lr": sz.lr}},
         "zero_optimization": {"stage": 0},
         "bf16": {"enabled": True},
@@ -225,7 +238,8 @@ def build_trainer(sz: Sizes, seed: int, num_layers: int,
     engine, _, _, _ = deepspeed_tpu.initialize(model=spec, config=config)
     batch = {"input_ids": np.random.default_rng(seed).integers(
         0, cfg.vocab_size,
-        size=(engine.train_batch_size, sz.train_seq)).astype(np.int32)}
+        size=(engine.train_batch_size, seq or sz.train_seq)
+    ).astype(np.int32)}
     return cfg, params, engine, batch
 
 
@@ -280,6 +294,64 @@ def phase_trainer(sz: Sizes, seed: int, check_kernels: bool = True) -> None:
     if check_kernels:
         require_kernel("kernels", "train_step", engine._train_step.lower(
             engine.state, placed.placed).compile().as_text())
+
+
+def phase_latent_moe_trainer(sz: Sizes, seed: int,
+                             check_kernels: bool = True) -> None:
+    """A latent model without an indexer TRAINED through the same entry
+    points: latent attention through the flash kernel at a query-key width
+    that is not the value width, a share of the routed experts with its
+    backward, the shared experts, the balance loss and the counters in the
+    step's metrics.  Fails on a kernel that gave way to its reference."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "latent_moe_trainer"
+    n = sz.latent_train_layers
+    log(phase, preset=sz.latent_train_preset, layers=n,
+        held=sz.latent_train_held, vocab=sz.latent_train_vocab,
+        why="one chip of eight that share each layer: the dense layer and "
+            "the routed ones that fit at 14 B a parameter")
+    tracer.clear()
+    cfg, params, engine, batch = build_trainer(
+        sz, seed, n, {}, preset=sz.latent_train_preset, overrides=dict(
+            vocab_size=sz.latent_train_vocab,
+            moe_experts_held=sz.latent_train_held,
+            mlp_layer_types=("dense",) + ("sparse",) * (n - 1)),
+        micro=sz.latent_train_micro, seq=sz.latent_train_seq)
+    del params
+    gc.collect()
+    log(phase, params_m=round(cfg.num_params() / 1e6, 1),
+        micro_batch=sz.latent_train_micro, seq=sz.latent_train_seq,
+        attn=cfg.attn_impl)
+    placed = engine.place_batch(batch)
+    _, step_s = timed_steps(phase, engine, placed, 1, sz.latent_train_steps)
+    out = engine.train_batch(placed)
+    log(phase, tokens_per_second=round(
+        engine.train_batch_size * sz.latent_train_seq / step_s, 1),
+        **{k: round(float(out[k]), 4) for k in (
+            "ce_loss", "moe_aux_loss", "moe_local_rows", "moe_rows_max",
+            "moe_experts_hit", "grad_norm")})
+    memory_line(phase, jax.devices()[0])
+    events = [s for s in tracer.spans() if s.name in (
+        "kernel/flash_attention_tiles", "kernel/grouped_matmul_tiles")]
+    log(phase, kernel_events=sorted({
+        json.dumps({"name": s.name, **s.attrs}, sort_keys=True)
+        for s in events}))
+    if not any(s.name == "train/step" for s in tracer.spans()):
+        raise AssertionError(f"{phase}: no train/step span in the ring")
+    if check_kernels:
+        bad = [s.attrs for s in events if s.attrs.get("fallback")]
+        if bad or not events:
+            raise AssertionError(f"{phase}: kernel fallbacks {bad} "
+                                 f"(events {len(events)})")
+        compiled = engine._train_step.lower(engine.state,
+                                            placed.placed).compile()
+        ma = compiled.memory_analysis()
+        log(phase, arguments_gb=round(ma.argument_size_in_bytes / 1e9, 3),
+            temp_gb=round(ma.temp_size_in_bytes / 1e9, 3),
+            output_gb=round(ma.output_size_in_bytes / 1e9, 3),
+            alias_gb=round(ma.alias_size_in_bytes / 1e9, 3))
+        require_kernel("kernels", "train_step", compiled.as_text())
 
 
 # ---------------------------------------------------------------------------
@@ -1275,6 +1347,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", default="",
+                    help="run one phase only (its function's name less "
+                         "'phase_'), e.g. latent_moe_trainer")
     args = ap.parse_args()
 
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
@@ -1295,10 +1370,14 @@ def main() -> int:
         jax=jax.__version__, compile_cache=cache, seed=args.seed)
     t0 = time.perf_counter()
     sz = Sizes()
-    if args.chips == 4:
+    if args.phase:
+        globals()[f"phase_{args.phase}"](sz, args.seed)
+    elif args.chips == 4:
         phase_zero3(sz, args.seed)
     else:
         phase_trainer(sz, args.seed)
+        gc.collect()
+        phase_latent_moe_trainer(sz, args.seed)
         gc.collect()
         phase_server(sz, args.seed, quantize_bits=0)
         gc.collect()

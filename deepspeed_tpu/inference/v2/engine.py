@@ -519,6 +519,12 @@ class InferenceEngineV2:
         ``["v"]``, and a latent model has neither (its pools are a latent a
         token and the indexer's key).  Refused by name, never served wrong."""
         cfg = self.cfg
+        if not self.model_cfg.index_topk:
+            raise NotImplementedError(
+                "a latent model without an indexer (index_topk 0, no query "
+                "compression) is trained, not served: the absorbed step "
+                "programs (programs.latent_layers) read the indexer's stack "
+                "and the compressed query (ROADMAP R2b)")
         asked = [
             ("enable_prefix_cache", cfg.enable_prefix_cache,
              "the prefix cache (with it prefix export / import and "

@@ -1,8 +1,11 @@
-"""A decoder with LATENT attention (MLA), a learned indexer that picks the
-keys each query attends over (DSA) and shares its pick with the layers behind
-it, leading dense layers and routed experts of which this chip may hold a
-share (``model_type: glm_moe_dsa``, GLM-5.2).  A layer, on ``h =
-RMSNorm(x)`` at position ``t``:
+"""A decoder with LATENT attention (MLA), leading dense layers and routed
+experts of which this chip may hold a share; with a learned indexer that picks
+the keys each query attends over (DSA) and shares its pick with the layers
+behind it (``model_type: glm_moe_dsa``, GLM-5.2: served), or without one and
+without query compression (``model_type: deepseek_v2``, DeepSeek-V2-Lite:
+``index_topk`` 0, ``q_lora_rank`` 0, ``[q_nope | q_rope]_j = (h W_q)_j``;
+TRAINED, :func:`forward_train`).  A layer, on ``h = RMSNorm(x)`` at position
+``t``:
 
     c_q = RMSNorm(h W_qa);  [q_nope | q_rope]_j = (c_q W_qb)_j     a head j
     [c_kv | k_rope] = h [W_kva | W_kr];  c_kv <- RMSNorm(c_kv)
@@ -28,7 +31,12 @@ and into the output (``sum p c_kv`` goes through ``W_kvb^V``), the same
 mathematics (:func:`absorb`, :func:`unabsorb`;
 ``ops/pallas/latent_attention.py`` attends).  :func:`forward_hidden`, the
 whole-sequence forward, is the EXPANDED form; ``tests/test_glm52.py`` holds
-the two to each other.
+the two to each other.  Without an indexer the expanded form is dense causal
+attention at a query-key width (nope + rope) that is not the value width:
+:func:`forward_train` hands it to ``attn_fn`` (the flash kernel, which takes
+a value width of its own), scans the layers by stack under the config's
+remat policy, and returns the routed layers' balance loss and counters beside
+the hidden states.
 
 The parameters are stacked BY KIND (as ``ssm_hybrid.py``'s):
 ``params["layers"]["A"]`` every layer's norms and attention, ``["I"]`` the
@@ -39,6 +47,7 @@ routed ones; :func:`layer_plan` says which index of which stack a layer reads.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -51,14 +60,22 @@ KINDS = ("A", "I", "D", "S")
 
 def check_config(cfg) -> None:
     n = cfg.num_layers
-    for name, kinds in (("indexer_types", {"full", "shared"}),
-                        ("mlp_layer_types", {"dense", "sparse"})):
+    checked = [("mlp_layer_types", {"dense", "sparse"})]
+    if cfg.index_topk:
+        checked.append(("indexer_types", {"full", "shared"}))
+        if not cfg.q_lora_rank:
+            raise ValueError("the indexer's queries are made from the "
+                             "compressed query: index_topk needs q_lora_rank")
+    elif cfg.indexer_types:
+        raise ValueError("indexer_types without index_topk: a model has an "
+                         "indexer or it has none")
+    for name, kinds in checked:
         got = getattr(cfg, name)
         if len(got) != n or set(got) - kinds:
             raise ValueError(f"{name} names {len(got)} layers of kinds "
                              f"{sorted(set(got))}; num_layers is {n} and the "
                              f"kinds are {sorted(kinds)}")
-    if cfg.indexer_types[0] != "full":
+    if cfg.index_topk and cfg.indexer_types[0] != "full":
         raise ValueError("the first layer has no layer before it to share a "
                          "selection with: indexer_types[0] must be 'full'")
     if cfg.mixer_pattern or cfg.layer_types or cfg.sliding_window \
@@ -91,7 +108,9 @@ class Layer:
 
 def layer_plan(cfg) -> Tuple[Layer, ...]:
     plan, fulls, seen = [], 0, {"D": 0, "S": 0}
-    for it, mt in zip(cfg.indexer_types, cfg.mlp_layer_types):
+    # no indexer: no layer picks, every layer attends over all it sees
+    picks = cfg.indexer_types or ("none",) * cfg.num_layers
+    for it, mt in zip(picks, cfg.mlp_layer_types):
         fulls += it == "full"
         ffn = "D" if mt == "dense" else "S"
         plan.append(Layer(it == "full", fulls - 1, ffn, seen[ffn]))
@@ -137,17 +156,21 @@ def init_params(rng: jax.Array, cfg) -> Dict[str, Any]:
     def ones(*shape):
         return {"scale": jnp.ones(shape, pd)}
 
+    if rq:  # queries through a bottleneck
+        q_proj = {"w_qa": dense(next(keys), (L, h, rq), h, pd),
+                  "q_a_norm": ones(L, rq),
+                  "w_qb": dense(next(keys), (L, rq, nh * (dn + dr)), rq, pd)}
+    else:
+        q_proj = {"w_q": dense(next(keys), (L, h, nh * (dn + dr)), h, pd)}
     layers: Dict[str, Any] = {
         "A": {"ln1": ones(L, h), "ln2": ones(L, h), "attn": {
-            "w_qa": dense(next(keys), (L, h, rq), h, pd),
-            "q_a_norm": ones(L, rq),
-            "w_qb": dense(next(keys), (L, rq, nh * (dn + dr)), rq, pd),
+            **q_proj,
             "w_kva": dense(next(keys), (L, h, rkv), h, pd),
             "w_kr": dense(next(keys), (L, h, dr), h, pd),
             "kv_a_norm": ones(L, rkv),
             "w_kvb": dense(next(keys), (L, rkv, nh, dn + dv), rkv, pd),
             "wo": dense(next(keys), (L, nh * dv, h), nh * dv, pd)}},
-        "I": {"index": {
+        "I": {} if not cfg.index_topk else {"index": {
             "w_iq": dense(next(keys), (Lf, rq, J * D), rq, pd),
             "w_ik": dense(next(keys), (Lf, h, D), h, pd),
             "ik_norm": {"scale": jnp.ones((Lf, D), pd),
@@ -161,7 +184,8 @@ def init_params(rng: jax.Array, cfg) -> Dict[str, Any]:
             "router": dense(next(keys), (Ls, h, E), h, pd),
             # a checkpoint tensor; drawn small, so that it changes some
             # choices and a program that drops it is seen
-            "router_bias": 0.02 * jax.random.normal(next(keys), (Ls, E)),
+            **({"router_bias": 0.02 * jax.random.normal(next(keys), (Ls, E))}
+               if cfg.moe_router == "sigmoid" else {}),
             # the experts THIS CHIP holds
             "w_in": dense(next(keys), (Ls, held, h, fe), h, pd),
             "w_gate": dense(next(keys), (Ls, held, h, fe), h, pd),
@@ -170,12 +194,55 @@ def init_params(rng: jax.Array, cfg) -> Dict[str, Any]:
             "sh_w_gate": dense(next(keys), (Ls, h, fs), h, pd),
             "sh_w_out": dense(next(keys), (Ls, fs, h), fs, pd)}},
     }
+    layers = {k: v for k, v in layers.items() if v}  # no empty stack
     return {
         "embed": {"tokens": dense(next(keys), (cfg.vocab_size, h), h, pd)},
         "layers": layers,
         "final_norm": {"scale": jnp.ones((h,), pd)},
         "lm_head": {"w": dense(next(keys), (h, cfg.vocab_size), h, pd)},
     }
+
+
+def param_axes(cfg) -> Dict[str, Any]:
+    """Logical axes of :func:`init_params`'s tree.  The heads' axis is
+    ``heads`` (tensor parallel), the held experts' ``expert`` (so that a mesh
+    with an ``ep`` axis divides them), latents and the router whole."""
+    ln = {"scale": ("layers", "embed")}
+    low = {"scale": ("layers", None)}
+    attn = {"w_kva": ("layers", "embed", None),
+            "w_kr": ("layers", "embed", None), "kv_a_norm": dict(low),
+            "w_kvb": ("layers", None, "heads", None),
+            "wo": ("layers", "heads", "embed")}
+    if cfg.q_lora_rank:
+        attn.update(w_qa=("layers", "embed", None), q_a_norm=dict(low),
+                    w_qb=("layers", None, "heads"))
+    else:
+        attn["w_q"] = ("layers", "embed", "heads")
+    layers: Dict[str, Any] = {
+        "A": {"ln1": dict(ln), "ln2": dict(ln), "attn": attn}}
+    if cfg.index_topk:
+        layers["I"] = {"index": {
+            "w_iq": ("layers", None, None), "w_ik": ("layers", "embed", None),
+            "ik_norm": {"scale": ("layers", None), "bias": ("layers", None)},
+            "w_iw": ("layers", "embed", None)}}
+    if layers_of(cfg, "D"):
+        layers["D"] = {"mlp": {"w_in": ("layers", "embed", "mlp"),
+                               "w_gate": ("layers", "embed", "mlp"),
+                               "w_out": ("layers", "mlp", "embed")}}
+    if layers_of(cfg, "S"):
+        moe = {"router": ("layers", "embed", None),
+               "w_in": ("layers", "expert", "embed", "mlp"),
+               "w_gate": ("layers", "expert", "embed", "mlp"),
+               "w_out": ("layers", "expert", "mlp", "embed"),
+               "sh_w_in": ("layers", "embed", "mlp"),
+               "sh_w_gate": ("layers", "embed", "mlp"),
+               "sh_w_out": ("layers", "mlp", "embed")}
+        if cfg.moe_router == "sigmoid":
+            moe["router_bias"] = ("layers", None)
+        layers["S"] = {"moe": moe}
+    return {"embed": {"tokens": ("vocab", "embed")}, "layers": layers,
+            "final_norm": {"scale": ("embed",)},
+            "lm_head": {"w": ("embed", "vocab")}}
 
 
 def num_params(cfg, include_embed: bool = True) -> int:
@@ -188,11 +255,13 @@ def num_params(cfg, include_embed: bool = True) -> int:
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     J, D = cfg.index_n_heads, cfg.index_head_dim
     per = {
-        "A": 2 * h + h * rq + rq + rq * nh * (dn + dr) + h * (rkv + dr) + rkv
+        "A": 2 * h + (h * rq + rq + rq * nh * (dn + dr) if rq
+                      else h * nh * (dn + dr)) + h * (rkv + dr) + rkv
         + rkv * nh * (dn + dv) + nh * dv * h,
         "I": rq * J * D + h * D + 2 * D + h * J,
         "D": 3 * h * f,
-        "S": h * cfg.num_experts + cfg.num_experts
+        "S": h * cfg.num_experts
+        + (cfg.num_experts if cfg.moe_router == "sigmoid" else 0)
         + 3 * cfg.experts_held * h * fe + 3 * h * fs,
     }
     total = sum(per[k] * layers_of(cfg, k) for k in KINDS) + h
@@ -208,9 +277,12 @@ def num_params(cfg, include_embed: bool = True) -> int:
 
 def rope_tables(cfg, max_len: int):
     """cos and sin ``(max_len, qk_rope_head_dim / 2)``, for the attention's
-    rotated part and the indexer's alike (``rope_type: default``: no
-    scaling)."""
-    return tfm.rope_table(max_len, cfg.qk_rope_head_dim, cfg.rope_theta)
+    rotated part and the indexer's alike: plain RoPE at ``rope_theta``
+    (``rope_type: default``), or what ``rope_params`` says of "full" layers
+    (YaRN's blended frequencies, cos and sin times its
+    ``attention_factor``)."""
+    return tfm.rope_table_of(max_len, cfg.qk_rope_head_dim,
+                             cfg.rope_of("full"))
 
 
 def _rope_first(x, rope, positions, dims: int):
@@ -221,13 +293,18 @@ def _rope_first(x, rope, positions, dims: int):
 
 def queries(a, p, cfg, rope, positions):
     """``a (..., h)`` → ``(c_q (..., q_lora_rank)`` after its norm, ``q_nope
-    (..., H, nope)``, ``q_rope (..., H, rope)`` rotated)."""
+    (..., H, nope)``, ``q_rope (..., H, rope)`` rotated).  Without query
+    compression (``q_lora_rank`` 0) ``c_q`` is None and the heads are ``a
+    W_q``."""
     nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     with jax.named_scope("latent_q_proj"):
-        c_q = tfm._norm(tfm._lin(a, p, "w_qa", "b_qa"), p["q_a_norm"],
-                        "rmsnorm", cfg.norm_eps)
-        q = tfm._lin(c_q, p, "w_qb", "b_qb").reshape(
-            a.shape[:-1] + (nh, dn + dr))
+        if cfg.q_lora_rank:
+            c_q = tfm._norm(tfm._lin(a, p, "w_qa", "b_qa"), p["q_a_norm"],
+                            "rmsnorm", cfg.norm_eps)
+            q = tfm._lin(c_q, p, "w_qb", "b_qb")
+        else:
+            c_q, q = None, tfm._lin(a, p, "w_q", "b_q")
+        q = q.reshape(a.shape[:-1] + (nh, dn + dr))
         return c_q, q[..., :dn], _rope_first(q[..., dn:], rope, positions, dr)
 
 
@@ -292,7 +369,14 @@ def index_key(a, p, cfg, rope, positions):
 
 
 def softmax_scale(cfg) -> float:
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    """``1 / sqrt(nope + rope)``; under DeepSeek's YaRN times ``m(factor,
+    mscale_all_dim) ** 2`` with ``m(s, a) = 0.1 a ln s + 1`` (DeepSeek-V2-
+    Lite: 192 ** -0.5 x 1.2608 ** 2 = 0.1147)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    rope = cfg.rope_of("full")
+    if rope.factor and rope.mscale_all_dim:
+        scale *= (0.1 * rope.mscale_all_dim * math.log(rope.factor) + 1) ** 2
+    return scale
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +390,8 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array, cfg,
     expanded form: ``k_nope`` and ``v`` of every key are made from its latent
     and every query attends under an ``(S, S)`` mask of its selection; the
     layers unrolled."""
+    if not cfg.index_topk:  # dense causal attention: the trained forward
+        return forward_train(params, tokens, cfg, attn_fn)[0]
     from ..moe.dropless import serving_moe_block
     from ..ops.pallas.latent_attention import index_scores, topk_mask
 
@@ -351,3 +437,144 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array, cfg,
         else:
             x = x + serving_moe_block(m, fp["moe"], cfg)[0]
     return tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
+
+
+# ---------------------------------------------------------------------------
+# the trained forward: no indexer, dense causal attention through ``attn_fn``
+# ---------------------------------------------------------------------------
+
+#: the counters a routed layer's ``stats`` hold, as the step's metrics name
+#: them (``moe/dropless._routed_ffn_share``: held experts that got a row, the
+#: largest rows of one, the assignments that were local)
+MOE_COUNTERS = ("moe_experts_hit", "moe_rows_max", "moe_local_rows")
+
+
+def attend_expanded(a, p, cfg, rope, positions, attn_fn):
+    """One layer's latent attention on ``a (B, S, h)`` in the EXPANDED form:
+    every key's ``k_nope`` and ``v`` made from its latent, the one rotated
+    key broadcast to the heads, ``attn_fn`` over a query-key width of nope +
+    rope and a value width of ``v_head_dim`` (``v`` is never padded)."""
+    B, S, _ = a.shape
+    nh, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    rkv = cfg.kv_lora_rank
+    with jax.named_scope("mla_qkv"):
+        _, q_nope, q_rope = queries(a, p, cfg, rope, positions)
+        entry = cache_entry(a, p, cfg, rope, positions)
+        kv = jnp.einsum("bsc,chn->bshn", entry[..., :rkv],
+                        p["w_kvb"].astype(a.dtype),
+                        preferred_element_type=jnp.float32).astype(a.dtype)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            entry[..., None, rkv:], (B, S, nh, cfg.qk_rope_head_dim))],
+            axis=-1)
+        v = kv[..., dn:]
+    with jax.named_scope("mla_attn"):
+        o = attn_fn(q, k, v, softmax_scale(cfg))
+    with jax.named_scope("mla_out"):
+        return tfm._lin(o.reshape(B, S, nh * dv), p, "wo", "bo")
+
+
+def _scaled_attention(cfg, attn_fn):
+    """``(q, k, v, scale) -> o`` over ``attn_fn`` (None: the config's): the
+    flash kernel takes the scale; the other implementations divide by
+    ``sqrt(D)`` themselves, so the rest of it is folded into ``q``."""
+    if attn_fn is None and cfg.attn_impl == "flash":
+        from ..ops.pallas.flash_attention import flash_attention
+
+        return lambda q, k, v, scale: flash_attention(
+            q, k, v, causal=True, sm_scale=scale)
+    fn = attn_fn or tfm.resolve_attention(cfg.attn_impl)
+    return lambda q, k, v, scale: fn(
+        q * jnp.asarray(scale * q.shape[-1] ** 0.5, q.dtype), k, v,
+        causal=True)
+
+
+#: a DENSE FFN whose intermediate over the whole batch would take more than
+#: this many bytes runs over a slice of the sequence at a time, each slice
+#: rematerialised in the backward (``sequence/tiled_compute.tiled_map``)
+_FFN_SLICE_BYTES = 128 << 20
+
+
+def _ffn_seq_tile(batch: int, seq: int, cfg) -> int:
+    """The positions a dense FFN takes at a time: the sequence halved until
+    ``batch x positions x intermediate_size`` fits :data:`_FFN_SLICE_BYTES`
+    (2 x 8,192 x 10,944 in bfloat16: 2,048 positions, 90 MB)."""
+    row = batch * cfg.intermediate_size * jnp.dtype(cfg.dtype).itemsize
+    tile = seq
+    while tile % 2 == 0 and tile * row > _FFN_SLICE_BYTES:
+        tile //= 2
+    return tile
+
+
+def forward_train(params: Dict[str, Any], tokens: jax.Array, cfg,
+                  attn_fn=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """tokens (B, S) → (hidden states (B, S, h) after the final norm, extras).
+    A model WITHOUT an indexer.  The layers are scanned stack by stack (each
+    run of layers of one FFN kind is one ``lax.scan`` over its slice of
+    stacks "A" and "D" / "S") under ``cfg.remat_policy``; ``extras`` holds,
+    for a model with routed layers, ``moe_aux_loss`` (the layers' balance
+    losses summed, before the coefficient) and the :data:`MOE_COUNTERS` as
+    means over the routed layers, float32 scalars."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..moe.dropless import dropless_moe_block_with_losses
+    from ..sequence.tiled_compute import tiled_map
+
+    if cfg.index_topk:
+        raise ValueError("forward_train attends over every key a query "
+                         "sees: a model with an indexer is served")
+    Bn, S = tokens.shape
+    attend = _scaled_attention(cfg, attn_fn)
+    with jax.named_scope("embed"):
+        x = tfm.embed_tokens(params, tokens, cfg)
+    rope = rope_tables(cfg, S)
+    pos = jnp.broadcast_to(jnp.arange(S), (Bn, S))
+    lay = params["layers"]
+
+    def layer_body(x, lp, ffn):
+        a_p, f_p = lp
+        a = tfm._norm(x, a_p["ln1"], "rmsnorm", cfg.norm_eps)
+        x = x + checkpoint_name(
+            attend_expanded(a, a_p["attn"], cfg, rope, pos, attend),
+            "attn_out")
+        m = tfm._norm(x, a_p["ln2"], "rmsnorm", cfg.norm_eps)
+        if ffn == "D":
+            mlp = tiled_map(lambda t: tfm._mlp_block(t, f_p["mlp"], cfg), m,
+                            _ffn_seq_tile(Bn, S, cfg), axis=1)
+            return x + checkpoint_name(mlp, "mlp_out"), None
+        y, aux, _, stats = dropless_moe_block_with_losses(m, f_p["moe"], cfg)
+        return x + checkpoint_name(y, "mlp_out"), (
+            aux, stats[:3].astype(jnp.float32))
+
+    policy = tfm._remat_policy(cfg.remat_policy)
+    body = layer_body
+    if policy is not None:
+        body = jax.checkpoint(layer_body, policy=policy, prevent_cse=False,
+                              static_argnums=(2,))
+
+    plan = layer_plan(cfg)
+    aux_sum, stats_sum, routed = jnp.zeros((), jnp.float32), 0.0, 0
+    with jax.named_scope("layers"):
+        start = 0
+        while start < len(plan):  # one scan a run of layers of one FFN kind
+            ffn, end = plan[start].ffn, start + 1
+            while end < len(plan) and plan[end].ffn == ffn:
+                end += 1
+            f0 = plan[start].ffn_index
+            stacks = (
+                jax.tree.map(lambda w: w[start:end], lay["A"]),
+                jax.tree.map(lambda w: w[f0:f0 + end - start], lay[ffn]))
+            x, out = jax.lax.scan(
+                lambda c, lp, ffn=ffn: body(c, lp, ffn), x, stacks)
+            if ffn == "S":
+                aux_sum = aux_sum + out[0].sum()
+                stats_sum = stats_sum + out[1].sum(axis=0)
+                routed += end - start
+            start = end
+    x = tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
+    extras: Dict[str, jax.Array] = {}
+    if routed:
+        extras["moe_aux_loss"] = aux_sum
+        for i, name in enumerate(MOE_COUNTERS):
+            extras[name] = stats_sum[i] / routed
+    return x, extras

@@ -49,6 +49,11 @@ class RopeParams:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    # DeepSeek's YaRN: where > 0 the attention's softmax scale is multiplied
+    # by ``m(factor, mscale_all_dim) ** 2`` with ``m(s, a) = 0.1 a ln s + 1``
+    # (models/latent_sparse.softmax_scale); ``attention_factor`` is then
+    # ``m(factor, mscale) / m(factor, mscale_all_dim)``
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,6 +187,14 @@ class TransformerConfig:
     # the others are counted and left out (0: every expert is held)
     moe_experts_held: int = 0
     moe_first_expert: int = 0
+    # the balance loss a TRAINED routed layer adds to the cross-entropy, times
+    # this coefficient (0: none), summed over the routed layers.
+    # ``moe_seq_aux``: DeepSeek's sequence-wise form, per sequence
+    # ``sum_i f_i P_i`` with ``f_i = E / (K S) x`` the sequence's tokens that
+    # chose expert i and ``P_i`` its mean probability; else Switch's top-1
+    # form over the whole batch (moe/dropless.balance_loss)
+    moe_aux_loss_coef: float = 0.0
+    moe_seq_aux: bool = False
     # test and benchmark tooling (benchmark/selection_tap.py): a step program
     # BUILT for a config with this set carries the indexer's scores and picks
     # of its "full" layers out.  A served model's config leaves it False
@@ -413,6 +426,48 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         moe_router="sigmoid", moe_routed_scaling=2.5,
         moe_intermediate_size=2048, moe_shared_size=2048,
         moe_routing="dropless", attn_impl="flash"),
+    # deepseek-ai/DeepSeek-V2-Lite as published (deepseek_v2): 15.7 B, about
+    # 2.4 B active; latent attention WITHOUT query compression (q_lora_rank
+    # null) and without an indexer, one leading dense layer, 64 routed experts
+    # (softmax, top 6, raw probabilities as gates) and two shared experts run
+    # as one SwiGLU of 2 x 1408; YaRN x 40 over 4,096 with mscale 0.707 in the
+    # softmax scale; trained with the sequence-wise balance loss (``seq_aux``;
+    # the coefficient is not in config.json: 0.001 assumed)
+    "deepseek-v2-lite": dict(
+        vocab_size=102400, hidden_size=2048, intermediate_size=10944,
+        num_layers=27, num_heads=16, num_kv_heads=16, head_dim_override=128,
+        max_seq_len=163840, rope_theta=10000.0, norm_eps=1e-6,
+        tie_embeddings=False, kv_lora_rank=512, q_lora_rank=0,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_params=(("full", RopeParams(
+            theta=10000.0, factor=40.0,
+            original_max_position_embeddings=4096, beta_fast=32.0,
+            beta_slow=1.0, attention_factor=1.0, mscale_all_dim=0.707)),),
+        mlp_layer_types=("dense",) + ("sparse",) * 26,
+        num_experts=64, moe_top_k=6, moe_norm_topk=False,
+        moe_router="softmax", moe_routed_scaling=1.0,
+        moe_intermediate_size=1408, moe_shared_size=2816,
+        moe_aux_loss_coef=0.001, moe_seq_aux=True,
+        moe_routing="dropless", attn_impl="flash"),
+    # the same block at toy widths: one dense layer and three routed ones,
+    # 2 of 8 experts held (the second share of four), a query-key width (24 +
+    # 8) that is not the value width (16), YaRN whose ramp lies inside 64
+    # positions
+    "tiny-dsv2lite": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=160, num_layers=4,
+        num_heads=4, num_kv_heads=4, head_dim_override=24, max_seq_len=256,
+        rope_theta=10000.0, norm_eps=1e-6, tie_embeddings=False,
+        kv_lora_rank=32, q_lora_rank=0, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=16,
+        rope_params=(("full", RopeParams(
+            theta=10000.0, factor=4.0, original_max_position_embeddings=16,
+            beta_fast=4.0, beta_slow=1.0, attention_factor=1.0,
+            mscale_all_dim=0.707)),),
+        mlp_layer_types=("dense",) + ("sparse",) * 3,
+        num_experts=8, moe_top_k=3, moe_norm_topk=False,
+        moe_router="softmax", moe_intermediate_size=48, moe_shared_size=96,
+        moe_experts_held=2, moe_first_expert=2,
+        moe_aux_loss_coef=0.01, moe_seq_aux=True, moe_routing="dropless"),
     # the same block at toy widths: one dense layer, then two periods of
     # shared shared shared full; 4 of 16 experts held (the second share of
     # four); index_topk 16, so that a context of 40 is past two of them
@@ -574,9 +629,9 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
     Pass ``params`` for HF-converted trees that carry linear biases
     (qwen2/opt/gpt-neox …): bias leaves get matching axes entries."""
     if cfg.kv_lora_rank:
-        raise NotImplementedError(
-            "a latent-attention model (kv_lora_rank > 0) is served, not "
-            "trained: no sharding rules are written for its parameters yet")
+        from .latent_sparse import param_axes as latent_axes
+
+        return latent_axes(cfg)
     if cfg.mixer_pattern:
         from .ssm_hybrid import param_axes as hybrid_axes
 

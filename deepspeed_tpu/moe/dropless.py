@@ -23,9 +23,13 @@ Four stages, each under a named scope a device trace can find:
 ``moe_shared``    a shared expert (``sh_w_in`` / ``sh_w_out``), where the model
                   has one: every row through ``mixed_gemm``, added after.
 
-Training (``moe/layer.py`` with ``moe_routing='dropless'``) calls
-:func:`dropless_moe_block_with_losses`, which is the same four stages plus
-the router's auxiliary losses; serving pays for no loss.
+Training (``moe/layer.py`` with ``moe_routing='dropless'``, and the latent
+model's trained forward) calls :func:`dropless_moe_block_with_losses`: the
+same stages on one routing, a chip's share of the experts and the shared
+expert included, plus the router's losses under ``moe_aux``; serving pays for
+no loss.  Every stage is differentiable: the grouped GEMM's backward is two
+more kernels (``ops/pallas/grouped_matmul.py``), and rows of the layout that
+no kernel wrote are read through ``jnp.where`` only, forward and backward.
 """
 
 from __future__ import annotations
@@ -141,7 +145,7 @@ def _expert_gemm(a, w, tile_group, pad_sizes, used_tiles, tile_m):
         return grouped_mixed_gemm(a, w, tile_group, pad_sizes, used_tiles,
                                   tile_m=tile_m)
     return grouped_matmul(a, w.astype(a.dtype), tile_group, pad_sizes,
-                          tile_m=tile_m)
+                          tile_m=tile_m, used_tiles=used_tiles)
 
 
 def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
@@ -206,6 +210,13 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
     return y, stats
 
 
+#: a share's layout whose rows x hidden would take this many bytes or more
+#: is computed in ROUNDS (:func:`_share_in_rounds`) and the array is never
+#: made; a smaller one (every serving step: 77 MB at most in the benchmark's
+#: cells, where a trained step of 16,384 tokens has 421 MB) is made at once
+_ROUNDS_FROM_BYTES = 128 << 20
+
+
 def _routed_ffn_share(x2, p, cfg, routing, valid):
     """``routed_ffn`` for a layer that holds experts ``moe_first_expert`` to
     ``+ moe_experts_held`` of ``num_experts`` (one chip of an expert-parallel
@@ -214,9 +225,10 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
     expert are laid out, computed and combined under their own weights, the
     others are counted and LEFT OUT, so the output is this share's part of
     the layer's routed sum (the parts of all shares add up to the whole
-    layer: ``tests/test_glm52.py``).  → ``(y, stats)`` with ``stats`` int32
-    ``(3,)``: held experts that got a row, the largest rows of one, and the
-    assignments that were local, over the rows ``valid`` marks."""
+    layer: ``tests/test_glm52.py``, ``tests/test_dsv2lite.py``), and so is
+    every gradient.  → ``(y, stats)`` with ``stats`` int32 ``(3,)``: held
+    experts that got a row, the largest rows of one, and the assignments
+    that were local, over the rows ``valid`` marks."""
     N, H = x2.shape
     E, k = cfg.num_experts, cfg.moe_top_k
     held, first = cfg.experts_held, cfg.moe_first_expert
@@ -238,8 +250,6 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
         counts = jnp.bincount(group, length=held + 1)[:held]
         used_tiles = jnp.sum(-(-counts // tile_m)).astype(jnp.int32)
         at = jnp.where(local, positions, M_pad)  # elsewhere: written nowhere
-        xs = jnp.zeros((M_pad, H), dt).at[at].set(
-            jnp.repeat(x2, k, axis=0), mode="drop")
         if valid is not None:
             counts = jnp.bincount(
                 group, weights=jnp.repeat(valid, k).astype(jnp.int32),
@@ -248,6 +258,16 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
                            jnp.sum(counts)]).astype(jnp.int32)
         if getattr(cfg, "moe_tap_choices", False):  # tooling only
             stats = jnp.concatenate([stats, expert_flat])
+
+    if M_pad * H * dt.itemsize >= _ROUNDS_FROM_BYTES:
+        return _share_in_rounds(x2, p, r.weights, local.reshape(N, k),
+                                at.reshape(N, k), tile_group,
+                                pad_sizes[:held], used_tiles, tile_m,
+                                E), stats
+
+    with jax.named_scope("moe_dispatch"):
+        xs = jnp.zeros((M_pad, H), dt).at[at].set(
+            jnp.repeat(x2, k, axis=0), mode="drop")
 
     with jax.named_scope("moe_experts"):
         def gmm(a, key):
@@ -266,14 +286,146 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
     return y, stats
 
 
+@jax.custom_vjp
+def _rows_in(x2, src, filled, pos, mine):
+    """A round's rows in expert order: ``xs (C, H) = x2[src]`` where an
+    assignment fills the row, zero elsewhere.  The backward is a GATHER too:
+    a token's cotangent is the sum of its own (at most k) rows of ``dxs``,
+    found through ``pos (N, k)`` where ``mine`` says the assignment lies in
+    this round.  (XLA would transpose the gather into a scatter-add of C
+    rows, which the TPU serialises: 7 ms a pass at 22,528 x 2,048.)  Rows
+    that no kernel wrote are never selected."""
+    return jnp.where(filled[:, None], x2[src], 0)
+
+
+def _rows_in_fwd(x2, src, filled, pos, mine):
+    return _rows_in(x2, src, filled, pos, mine), (pos, mine)
+
+
+def _rows_in_bwd(res, dxs):
+    pos, mine = res
+    acc = jnp.zeros((pos.shape[0], dxs.shape[1]), jnp.float32)
+    for j in range(pos.shape[1]):
+        acc = acc + jnp.where(mine[:, j, None],
+                              dxs[pos[:, j]].astype(jnp.float32), 0)
+    return acc.astype(dxs.dtype), None, None, None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(ys, weights, pos, mine, src, gate, filled):
+    """A round's weighted outputs summed a token: ``y (N, H)`` float32 ``=
+    sum_j mine[n, j] weights[n, j] ys[pos[n, j]]``, k gathers of N rows.  The
+    backward: a row's cotangent is its token's, times its gate (a gather of C
+    rows through ``src``); a weight's is its row's dot with the token's."""
+    acc = jnp.zeros((pos.shape[0], ys.shape[1]), jnp.float32)
+    for j in range(pos.shape[1]):
+        acc = acc + jnp.where(
+            mine[:, j, None],
+            ys[pos[:, j]].astype(jnp.float32) * weights[:, j, None], 0)
+    return acc
+
+
+def _rows_out_fwd(ys, weights, pos, mine, src, gate, filled):
+    return (_rows_out(ys, weights, pos, mine, src, gate, filled),
+            (ys, pos, mine, src, gate, filled))
+
+
+def _rows_out_bwd(res, dy):
+    ys, pos, mine, src, gate, filled = res
+    dys = jnp.where(filled[:, None], dy[src] * gate[:, None], 0
+                    ).astype(ys.dtype)
+    dw = jnp.stack([jnp.where(mine[:, j], jnp.sum(
+        ys[pos[:, j]].astype(jnp.float32) * dy, axis=-1), 0)
+        for j in range(pos.shape[1])], axis=1)
+    return dys, dw, None, None, None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+def _share_in_rounds(x2, p, weights, local, at, tile_group, pad_sizes,
+                     used_tiles, tile_m: int, num_experts: int):
+    """The share's FFN on a layout too large to hold: a trained step of
+    16,384 tokens x top 6 has 98,304 assignments, the layout must have room
+    for all of them (every one may be local; nothing is dropped), and about
+    an eighth are.  So no array of the layout's rows x H is ever made.  The
+    layout stays what it is, index arithmetic (``at (N, k)``: each local
+    assignment's row; ``local``: which are), and is walked in ROUNDS of
+    ``round_tiles`` tiles, about one and a half times the expected local
+    rows: a round gathers its rows' tokens (``_rows_in``), runs the three
+    grouped GEMMs on them and sums the weighted outputs a token
+    (``_rows_out``); a round past the rows (``lax.cond``) does nothing.
+    Under near-uniform routing the first round is the only one that runs,
+    and under any routing every local assignment is computed.  Each round is
+    rematerialised in the backward; forward and backward move rows by
+    gathers alone."""
+    N, H = x2.shape
+    k = at.shape[1]
+    T, held = N * k, pad_sizes.shape[0]
+    tiles = tile_group.shape[0]
+    round_tiles = min(tiles, -(-3 * T * held // (2 * num_experts * tile_m))
+                      + held)
+    R = -(-tiles // round_tiles)
+    C = round_tiles * tile_m
+
+    with jax.named_scope("moe_dispatch"):
+        # the token (-1: no assignment lies there) and the gate of each row
+        # of the layout (int32 / float32 vectors)
+        rows = R * C
+        flat = jnp.where(local, at, rows).reshape(T)  # elsewhere: nowhere
+        src = jnp.full((rows,), -1, jnp.int32).at[flat].set(
+            jnp.arange(T, dtype=jnp.int32) // k, mode="drop")
+        gate = jnp.zeros((rows,), jnp.float32).at[flat].set(
+            weights.reshape(T), mode="drop")
+        tg = jnp.pad(tile_group, (0, R * round_tiles - tiles), mode="edge")
+        starts = jnp.cumsum(pad_sizes) - pad_sizes
+
+    def one_round(acc, inp):
+        j, src_j, gate_j, tg_j = inp
+        filled_j, src_j = src_j >= 0, jnp.maximum(src_j, 0)
+        used_j = jnp.clip(used_tiles - j * round_tiles, 0, round_tiles)
+        # the rows of each held expert that lie in this round
+        sizes_j = jnp.clip(jnp.minimum(starts + pad_sizes, (j + 1) * C)
+                           - jnp.maximum(starts, j * C), 0, C)
+
+        def compute(acc):
+            with jax.named_scope("moe_dispatch"):
+                mine = local & (at >= j * C) & (at < (j + 1) * C)
+                pos = jnp.clip(at - j * C, 0, C - 1)
+                xs = _rows_in(x2, src_j, filled_j, pos, mine)
+            with jax.named_scope("moe_experts"):
+                def gmm(a, key):
+                    return _expert_gemm(a, p[key], tg_j, sizes_j, used_j,
+                                        tile_m)
+
+                hmid = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_in")
+                ys = gmm(hmid, "w_out")
+            with jax.named_scope("moe_combine"):
+                return acc + _rows_out(ys, weights, pos, mine, src_j, gate_j,
+                                       filled_j)
+
+        return jax.lax.cond(used_j > 0, compute, lambda a: a, acc), None
+
+    acc, _ = jax.lax.scan(
+        jax.checkpoint(one_round), jnp.zeros((N, H), jnp.float32),
+        (jnp.arange(R, dtype=jnp.int32), src.reshape(R, C),
+         gate.reshape(R, C), tg.reshape(R, round_tiles)))
+    return acc.astype(x2.dtype)
+
+
 def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
-                      valid: Optional[jax.Array] = None
+                      valid: Optional[jax.Array] = None,
+                      routing: Optional[Routing] = None
                       ) -> Tuple[jax.Array, jax.Array]:
-    """The MoE FFN of every inference engine: ``x (..., H)`` → ``(y, stats)``
-    (``stats`` as :func:`routed_ffn` gives them).  ``p`` is the layer's
-    ``moe`` dict."""
+    """The MoE FFN of every inference engine, and of the trained forward
+    (:func:`dropless_moe_block_with_losses`, which hands in the ``routing``
+    its losses were made from): ``x (..., H)`` → ``(y, stats)`` (``stats`` as
+    :func:`routed_ffn` gives them).  ``p`` is the layer's ``moe`` dict."""
     x2 = x.reshape(-1, x.shape[-1])
-    y, stats = routed_ffn(x2, p, cfg,
+    y, stats = routed_ffn(x2, p, cfg, routing=routing,
                           valid=None if valid is None else valid.reshape(-1))
     if "sh_w_in" in p:
         # the shared expert: every row, unweighted, beside the routed ones
@@ -296,17 +448,45 @@ def serving_moe_block(x: jax.Array, p: Dict[str, Any], cfg, *,
     return y, stats
 
 
-def dropless_moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg
-                                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x: (B, S, H) → (y, aux_loss, z_loss); router losses as in
-    ``moe/layer.py`` (Switch aux loss + St-MoE z-loss)."""
-    B, S, H = x.shape
-    E = cfg.num_experts
-    x2 = x.reshape(B * S, H)
-    r = route(x2, p["router"], cfg)
-    z_loss = jnp.mean(jax.nn.logsumexp(r.logits, axis=-1) ** 2)
+def balance_loss(r: Routing, cfg, batch: int) -> jax.Array:
+    """The router's balance loss over ``r`` (``batch x S`` rows, a sequence's
+    rows together).  ``moe_seq_aux``: DeepSeek's sequence-wise form, the mean
+    over the sequences of ``sum_i f_i P_i`` with ``f_i = E / (K S) x`` the
+    sequence's tokens that chose expert i among their top K and ``P_i`` the
+    expert's mean probability over the sequence; else Switch's: ``E sum_i
+    (share of rows whose FIRST choice is i) x (mean probability of i)`` over
+    all rows.  The gradient flows through the probabilities alone.  Over ALL
+    ``num_experts``, whatever share of them is held here: the router is
+    whole on every chip of an expert-parallel group."""
+    E, k = cfg.num_experts, cfg.moe_top_k
+    if getattr(cfg, "moe_seq_aux", False):
+        S = r.probs.shape[0] // batch
+        chosen = jax.nn.one_hot(r.experts.reshape(batch, S * k), E,
+                                dtype=jnp.float32).sum(axis=1)  # (B, E)
+        f = chosen * (E / (k * S))
+        P = r.probs.reshape(batch, S, E).mean(axis=1)
+        return jnp.mean(jnp.sum(f * P, axis=-1))
     me = jnp.mean(r.probs, axis=0)
     ce = jnp.mean(jax.nn.one_hot(r.experts[:, 0], E), axis=0)
-    aux_loss = E * jnp.sum(me * ce)
-    y, _ = routed_ffn(x2, p, cfg, routing=r)
-    return y.reshape(B, S, H), aux_loss, z_loss
+    return E * jnp.sum(me * ce)
+
+
+def dropless_moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg
+                                   ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                              jax.Array]:
+    """THE trained MoE FFN: ``x (B, S, H)`` → ``(y, aux_loss, z_loss,
+    stats)``.  :func:`serving_moe_block`'s mathematics (the routed experts,
+    all or this chip's share of them, the shared expert, the PR-MoE mix) on
+    one routing, plus the router's losses made from the same routing
+    (:func:`balance_loss`; St-MoE's z-loss) under scope ``moe_aux``.
+    Gradients reach the held experts, the shared expert and, through the
+    gates of the assignments computed here and through the losses, the
+    router; what experts held elsewhere would add is left out of the forward
+    and of the backward alike.  ``stats`` as :func:`routed_ffn` gives them."""
+    B, S, H = x.shape
+    r = route(x.reshape(B * S, H), p["router"], cfg, p.get("router_bias"))
+    with jax.named_scope("moe_aux"):
+        z_loss = jnp.mean(jax.nn.logsumexp(r.logits, axis=-1) ** 2)
+        aux_loss = balance_loss(r, cfg, B)
+    y, stats = serving_moe_block(x, p, cfg, routing=r)
+    return y, aux_loss, z_loss, stats

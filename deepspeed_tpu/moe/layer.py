@@ -142,9 +142,8 @@ def moe_block_with_losses(x: jax.Array, p: Dict[str, Any], cfg
     if getattr(cfg, "moe_routing", "capacity") == "dropless":
         from .dropless import dropless_moe_block_with_losses
 
-        y, aux, z = dropless_moe_block_with_losses(x, p, cfg)
-        if getattr(cfg, "moe_use_residual", False):
-            y = _prmoe_combine(x, y, p, cfg)
+        # the shared expert and the PR-MoE mix are inside
+        y, aux, z, _ = dropless_moe_block_with_losses(x, p, cfg)
         return y, aux, z
     dt = x.dtype
     E = cfg.num_experts

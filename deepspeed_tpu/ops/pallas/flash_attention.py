@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...observability.trace import tracer
 from . import backend
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -236,6 +237,7 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
                block_k, window=0, bias_kv=None,
                bias_qk=None) -> Tuple[jax.Array, jax.Array]:
     B, H, S, D = q.shape
+    Dv = v.shape[3]  # the value width: D, or its own (latent attention)
     KV = k.shape[1]
     Skv = k.shape[2]
     nq = pl.cdiv(S, block_q)
@@ -270,7 +272,7 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_k, D),
                      lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
+        pl.BlockSpec((1, 1, block_k, Dv),
                      lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
     ]
     inputs += [q, k, v]
@@ -282,15 +284,15 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
                           has_b1=has_b1, has_b2=has_b2),
         grid, in_specs,
         [
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         ],
         [
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         [
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -404,6 +406,7 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, window: int,
 def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     q, k, v, q_seg, k_seg, mask_tab, out, lse = res
     B, H, S, D = q.shape
+    Dv = v.shape[3]
     KV = k.shape[1]
     Skv = k.shape[2]
     group = H // KV
@@ -413,6 +416,10 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)  # (B, H, S, 1)
+    for which in ("dkdv", "dq"):  # once a traced backward, as the forward's
+        tracer.add_event("kernel/flash_attention_tiles", attrs={
+            "d_qk": D, "d_v": Dv, "block_q": block_q, "block_k": block_k,
+            "pass": which})
 
     # dk, dv: one pass per kv block; the innermost grid dim walks all
     # (group, q-block) pairs so GQA groups accumulate directly into the
@@ -433,9 +440,9 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
                                                  iqg % nq, 0)),
         pl.BlockSpec((1, 1, block_k, D),
                      lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
+        pl.BlockSpec((1, 1, block_k, Dv),
                      lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
-        pl.BlockSpec((1, 1, block_q, D),
+        pl.BlockSpec((1, 1, block_q, Dv),
                      lambda b, kv, ik, iqg, *_: (b, kv * group + iqg // nq,
                                                  iqg % nq, 0)),
         pl.BlockSpec((1, 1, block_q, 1),
@@ -456,16 +463,16 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
         [
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
         ],
         [
             jax.ShapeDtypeStruct((B, KV, Skv, D), k.dtype),
-            jax.ShapeDtypeStruct((B, KV, Skv, D), v.dtype),
+            jax.ShapeDtypeStruct((B, KV, Skv, Dv), v.dtype),
         ],
         [
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         mask_tab, inputs)
 
@@ -483,9 +490,9 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_k, D),
                      lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
+        pl.BlockSpec((1, 1, block_k, Dv),
                      lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
     ]
@@ -558,7 +565,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 1024, block_k: int = 1024,
                     segment_ids=None, window: int = 0,
                     block_mask=None) -> jax.Array:
-    """Fused attention. q: (B, S, H, D); k/v: (B, S, KV, D) with KV | H.
+    """Fused attention. q: (B, S, H, D); k: (B, S, KV, D), v: (B, S, KV, Dv)
+    with KV | H.  ``Dv`` may differ from ``D`` (latent attention's expanded
+    form: a query-key width of 192 beside a value width of 128); the output,
+    ``dO`` and ``dV`` are ``Dv`` wide and ``v`` is never padded to ``D``.
 
     Differentiable (custom VJP); supports causal masking, GQA, sliding-
     window (``window`` > 0 keeps keys in (query-window, query]), packed-
@@ -598,6 +608,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # the band width (never raise it above the caller's request)
         if 0 < window < cap:
             cap = min(cap, max(128, window // 128 * 128))
+        # a query-key width past one lane tile (latent attention's 192 is
+        # held as 256): the dK/dV kernel's blocks and float32 accumulators at
+        # 1024 x 1024 pass the 16 MB of scoped VMEM; 512 fits, and wastes
+        # less of a causal diagonal tile
+        if D > NUM_LANES:
+            cap = min(cap, 512)
         # largest sublane-aligned divisor, so raising the default can never
         # push a previously-fused shape onto the O(S²) fallback (e.g.
         # S=1536: divisor 768, not min()=1024 → unusable); when none exists
@@ -620,7 +636,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError(
                 f"block_mask shape {block_mask.shape} != grid ({nq}, {nk}) "
                 f"for S={S}, block_q={block_q}, block_k={block_k}")
+    # chosen once per shape, while the caller's program is traced
+    event = {"d_qk": D, "d_v": v.shape[3], "block_q": block_q,
+             "block_k": block_k}
     if not usable:
+        tracer.add_event("kernel/flash_attention_tiles",
+                         attrs={**event, "fallback": 1})
         backend.warn_fallback(
             "flash_attention",
             f"S={S}, Skv={k.shape[1]}, H={H}, KV={KV} do not tile into "
@@ -645,6 +666,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
+    tracer.add_event("kernel/flash_attention_tiles",
+                     attrs={**event, "pass": "fwd"})
     out = _flash_attention_bhsd(qt, kt, vt, q_seg3, k_seg3, mask_tab,
                                 sm_scale, causal, block_q, block_k, window)
     return out.transpose(0, 2, 1, 3)

@@ -16,123 +16,273 @@ alignment padding ≤ E·tm rows, negligible at MoE token counts.)
 ``jax.lax.ragged_dot`` is the fallback off-TPU and for shapes the Mosaic
 tiling rules reject; it accepts the same padded layout (padding rows are
 zeros whose outputs the caller discards).
+
+The backward is two more kernels of the same layout: ``grouped_matmul_dlhs``
+(``g @ rhs[e]^T``, the experts read as they are stored) and
+``grouped_matmul_drhs`` (``lhs_e^T @ g_e``, an expert's consecutive tiles
+summed in VMEM).  A layout made for a SHARE of the experts holds tiles for
+every assignment and fills an eighth of them: ``used_tiles`` lets all three
+kernels skip the rest and fetch nothing for them.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...observability.trace import tracer
 from . import backend
 
-def _pick_tile_k(K: int) -> int:
-    for cand in (1024, 512, 256, 128):
-        if K % cand == 0:
-            return cand
-    return 0
+#: scoped VMEM a call may take: the widest blocks here (a whole K or N of an
+#: expert width that has no divisor of 256 or more, float32 accumulator
+#: beside them) are about 14 MB double-buffered
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _use_pallas(M: int, K: int, N: int, tile_m: int, tile_n: int) -> bool:
+def _pick_tile(n: int, cap: int) -> int:
+    """The block of a K or N dimension ``n``: the largest power-of-two
+    multiple of 128 up to ``cap`` that divides it; where that is under 256
+    (an expert width such as 1408 = 11 x 128) and ``n`` is at most 2048, all
+    of ``n``, because steps of 128 columns leave the MXU mostly waiting; 0
+    when ``n`` is no multiple of 128."""
+    tile = cap
+    while tile >= 128 and n % tile:
+        tile //= 2
+    if tile < 128:
+        return 0
+    if tile < 256 and n <= 2048:
+        return n
+    return tile
+
+
+def _use_pallas(M: int, K: int, N: int, tile_m: int) -> bool:
     if backend.interpret():  # the host CPU runs ragged_dot, by design
         return False
     # Mosaic tiling: K and N 128-aligned lanes, the M tile whole packed bf16
     # sublane tiles (16 rows: a decode step's few rows an expert)
-    ok = (M % tile_m == 0 and _pick_tile_k(K) > 0 and N % tile_n == 0
-          and tile_m % 16 == 0 and tile_n % 128 == 0)
+    ok = (M % tile_m == 0 and _pick_tile(K, 1024) > 0
+          and _pick_tile(N, 1024) > 0 and tile_m % 16 == 0)
     if not ok:
         backend.warn_fallback(
             "grouped_matmul", f"M={M}, K={K}, N={N} do not tile into "
-            f"tile_m={tile_m}, tile_n={tile_n} (16-row, 128-lane tiles)")
+            f"tile_m={tile_m} (16-row, 128-lane tiles)")
     return ok
 
 
-def _gmm_kernel(tile_group_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
-                nk: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    acc_ref[:] += jnp.dot(lhs_ref[:], rhs_ref[0],
-                          preferred_element_type=jnp.float32)
-
-    @pl.when(pl.program_id(2) == nk - 1)
-    def _flush():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
+def _live(i, j, kk, used, nj: int, nk: int):
+    """Grid step ``(i, j, kk)``, or for an M tile past the ``used`` ones the
+    last step that had rows (``grouped_mixed_gemm.live_step``'s rule): a
+    skipped step names the blocks the step before it named, so the pipeline
+    moves nothing for it."""
+    on = i < used
+    return (jnp.where(on, i, jnp.maximum(used - 1, 0)),
+            jnp.where(on, j, nj - 1), jnp.where(on, kk, nk - 1))
 
 
-@functools.partial(jax.jit, static_argnames=("tile_m", "tile_n"))
+def _gmm_kernel(tile_group_ref, used_ref, lhs_ref, rhs_ref, out_ref, acc_ref,
+                *, nk: int, transpose_rhs: bool):
+    """One (tile_m, tile_n) output tile's step over a K tile; with
+    ``transpose_rhs`` the expert's block is ``(tile_n, tile_k)`` as it is
+    stored, and the last dimension of both is contracted."""
+    del tile_group_ref  # the index maps read it
+    i, kk = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(i < used_ref[0])
+    def _compute():
+        @pl.when(kk == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] += jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[0],
+            (((1,), (1 if transpose_rhs else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(kk == nk - 1)
+        def _flush():
+            out_ref[:] = acc_ref[:].astype(out_ref.dtype)
+
+
 def _gmm_pallas(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
-                tile_m: int, tile_n: int) -> jax.Array:
+                used_tiles: jax.Array, tile_m: int,
+                transpose_rhs: bool = False) -> jax.Array:
+    """``rhs (E, K, N)``, or with ``transpose_rhs`` ``(E, N, K)`` read as it
+    lies (the backward's ``g @ rhs^T`` makes no transposed copy of the
+    experts)."""
     M, K = lhs.shape
-    E, _, N = rhs.shape
-    tile_k = _pick_tile_k(K)
-    nk = K // tile_k
-    grid = (M // tile_m, N // tile_n, nk)  # k innermost: sequential accum
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tile_k, tile_n = _pick_tile(K, 1024), _pick_tile(N, 1024)
+    nj, nk = N // tile_n, K // tile_k
+
+    def at(block):
+        def index_map(i, j, kk, tg, used):
+            i, j, kk = _live(i, j, kk, used[0], nj, nk)
+            return block(i, j, kk, tg)
+        return index_map
+
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec((1, tile_n, tile_k),
+                                at(lambda i, j, kk, tg: (tg[i], j, kk)))
+    else:
+        rhs_spec = pl.BlockSpec((1, tile_k, tile_n),
+                                at(lambda i, j, kk, tg: (tg[i], kk, j)))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, nk=nk),
+        functools.partial(_gmm_kernel, nk=nk, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
+            num_scalar_prefetch=2,
+            grid=(M // tile_m, nj, nk),  # k innermost: sequential accum
             in_specs=[
-                pl.BlockSpec((tile_m, tile_k), lambda i, j, kk, tg: (i, kk)),
-                pl.BlockSpec((1, tile_k, tile_n),
-                             lambda i, j, kk, tg: (tg[i], kk, j)),
+                pl.BlockSpec((tile_m, tile_k),
+                             at(lambda i, j, kk, tg: (i, kk))),
+                rhs_spec,
             ],
+            # a skipped step keeps the last live tile's block: no tile of
+            # rows that nobody reads goes to HBM
             out_specs=pl.BlockSpec((tile_m, tile_n),
-                                   lambda i, j, kk, tg: (i, j)),
+                                   at(lambda i, j, kk, tg: (i, j))),
             scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        name="grouped_matmul",
-    )(tile_group, lhs, rhs)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=backend.interpret(),
+        name="grouped_matmul_dlhs" if transpose_rhs else "grouped_matmul",
+    )(tile_group, used_tiles, lhs, rhs)
+
+
+def _drhs_kernel(tile_group_ref, used_ref, lhs_ref, g_ref, out_ref, acc_ref):
+    """One M tile's ``lhs^T @ g`` added to its expert's ``(tile_k, tile_n)``
+    block: the tiles of one expert are consecutive, so the accumulator is
+    zeroed at an expert's first tile and written at its last."""
+    i = pl.program_id(2)
+    used = used_ref[0]
+    last = jnp.maximum(used - 1, 0)
+
+    @pl.when(i < used)
+    def _compute():
+        here = tile_group_ref[i]
+        before = tile_group_ref[jnp.maximum(i - 1, 0)]
+        after = tile_group_ref[jnp.minimum(i + 1, last)]
+
+        @pl.when((i == 0) | (before != here))
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] += jax.lax.dot_general(
+            lhs_ref[:], g_ref[:], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when((i == last) | (after != here))
+        def _flush():
+            out_ref[0] = acc_ref[:].astype(out_ref.dtype)
+
+
+def _drhs_pallas(lhs: jax.Array, g: jax.Array, tile_group: jax.Array,
+                 used_tiles: jax.Array, num_groups: int, tile_m: int,
+                 dtype) -> jax.Array:
+    """``out[e] = sum over the rows r of expert e of lhs[r]^T g[r]``, ``(E, K,
+    N)``: the weight gradient of ``grouped_matmul``.  An expert without a
+    tile is never visited and its block never written: the caller zeroes
+    it."""
+    M, K = lhs.shape
+    N = g.shape[1]
+    tile_k, tile_n = _pick_tile(K, 512), _pick_tile(N, 1024)
+
+    def row(i, used):
+        return jnp.where(i < used, i, jnp.maximum(used - 1, 0))
+
+    return pl.pallas_call(
+        _drhs_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(K // tile_k, N // tile_n, M // tile_m),  # rows innermost
+            in_specs=[
+                pl.BlockSpec((tile_m, tile_k),
+                             lambda kb, nb, i, tg, used: (row(i, used[0]),
+                                                          kb)),
+                pl.BlockSpec((tile_m, tile_n),
+                             lambda kb, nb, i, tg, used: (row(i, used[0]),
+                                                          nb)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tile_k, tile_n),
+                lambda kb, nb, i, tg, used: (tg[row(i, used[0])], kb, nb)),
+            scratch_shapes=[pltpu.VMEM((tile_k, tile_n), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((num_groups, K, N), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=backend.interpret(),
+        name="grouped_matmul_drhs",
+    )(tile_group, used_tiles, lhs, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gmm(lhs, rhs, tile_group, padded_group_sizes, used_tiles, tile_m):
+    return _gmm_pallas(lhs, rhs, tile_group, used_tiles, tile_m)
+
+
+def _gmm_fwd(lhs, rhs, tile_group, padded_group_sizes, used_tiles, tile_m):
+    out = _gmm_pallas(lhs, rhs, tile_group, used_tiles, tile_m)
+    return out, (lhs, rhs, tile_group, padded_group_sizes, used_tiles)
+
+
+def _gmm_bwd(tile_m, res, g):
+    """dlhs[r] = g[r] @ rhs[g(r)]^T, the same kernel over the experts as they
+    are stored; drhs[e] = lhs_e^T @ g_e, one pass over the rows.  Both skip
+    the tiles past ``used_tiles``, as the forward does: rows there are never
+    written and never read."""
+    lhs, rhs, tile_group, padded_group_sizes, used_tiles = res
+    g = g.astype(lhs.dtype)
+    dlhs = _gmm_pallas(g, rhs, tile_group, used_tiles, tile_m,
+                       transpose_rhs=True)
+    drhs = _drhs_pallas(lhs, g, tile_group, used_tiles, rhs.shape[0], tile_m,
+                        rhs.dtype)
+    drhs = jnp.where((padded_group_sizes > 0)[:, None, None], drhs, 0)
+    return dlhs, drhs, None, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
                    padded_group_sizes: jax.Array, tile_m: int = 512,
-                   tile_n: int = 1024) -> jax.Array:
+                   used_tiles: Optional[jax.Array] = None) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[tile_group[r // tile_m]]``.
 
     ``lhs``: (M, K) tile-aligned grouped rows (M multiple of tile_m);
     ``rhs``: (E, K, N); ``tile_group``: (M // tile_m,) int32 expert per tile;
-    ``padded_group_sizes``: (E,) row counts of the padded layout (for the
-    ragged_dot fallback).  Differentiable: backward runs through ragged_dot's
-    transpose rules (full-precision grads).
+    ``padded_group_sizes``: (E,) row counts of the padded layout (the
+    ragged_dot fallback's groups, and which experts have a tile at all);
+    ``used_tiles``: int32 scalar, the tiles that hold rows (None: all).  Tiles
+    past it are skipped in the forward and in both backward kernels: their
+    output rows are NEVER WRITTEN, so a caller reads them through a mask
+    (``jnp.where``, never a product).  Differentiable: dlhs through the same
+    kernel, drhs through ``grouped_matmul_drhs``, float32 sums in both.
     """
     M, K = lhs.shape
     E, K2, N = rhs.shape
     assert K == K2, (lhs.shape, rhs.shape)
-
-    # shrink-only clamp: largest 128-multiple tile dividing N
-    while tile_n > 128 and N % tile_n != 0:
-        tile_n //= 2
-    if not _use_pallas(M, K, N, tile_m, tile_n):
+    usable = _use_pallas(M, K, N, tile_m)
+    # chosen once per shape, while the caller's program is traced
+    tracer.add_event("kernel/grouped_matmul_tiles", attrs={
+        "e": E, "k": K, "n": N, "rows": M, "tile_m": tile_m,
+        **({"tile_n": _pick_tile(N, 1024), "tile_k": _pick_tile(K, 1024)}
+           if usable else
+           {"xla": 1} if backend.interpret() else {"fallback": 1})})
+    if not usable:
         return jax.lax.ragged_dot(lhs, rhs, padded_group_sizes)
-
-    @jax.custom_vjp
-    def f(lhs, rhs):
-        return _gmm_pallas(lhs, rhs, tile_group, tile_m, tile_n)
-
-    def f_fwd(lhs, rhs):
-        return f(lhs, rhs), (lhs, rhs)
-
-    def f_bwd(res, g):
-        lhs, rhs = res
-        # dlhs[r] = g[r] @ rhs[g(r)]^T — the same grouped matmul with
-        # transposed weights; drhs via ragged_dot's transpose rule
-        dlhs = grouped_matmul(g, rhs.swapaxes(1, 2), tile_group,
-                              padded_group_sizes, tile_m, tile_n)
-        _, vjp = jax.vjp(
-            lambda r: jax.lax.ragged_dot(lhs, r, padded_group_sizes), rhs)
-        (drhs,) = vjp(g)
-        return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype)
-
-    f.defvjp(f_fwd, f_bwd)
-    return f(lhs, rhs)
+    if used_tiles is None:
+        used_tiles = jnp.int32(M // tile_m)
+    return _gmm(lhs, rhs, tile_group, padded_group_sizes,
+                jnp.reshape(used_tiles, (1,)).astype(jnp.int32), tile_m)
 
 
 def tile_aligned_layout(expert_flat: jax.Array, num_experts: int, T: int,

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import comm
 from ..accelerator import get_accelerator
+from ..observability.trace import tracer
 from ..parallel.topology import MeshTopology, set_topology
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
@@ -65,12 +66,19 @@ class LazyMetrics(collections.abc.Mapping):
     def __init__(self, device_metrics: Dict[str, jax.Array]):
         self._dev: Optional[Dict[str, jax.Array]] = device_metrics
         self._host: Dict[str, float] = {}
+        # while the tracer is on, the step leaves a ``train/step`` span in
+        # the ring, from its dispatch to the fetch that brought its metrics,
+        # with every one of them: whatever the loss function returned
+        self._t_dispatch = time.monotonic() if tracer.enabled else None
 
     def _materialize(self) -> Dict[str, float]:
         if self._dev is not None:
             host = jax.device_get(self._dev)
             self._dev = None
             self._host = {k: float(v) for k, v in host.items()}
+            if self._t_dispatch is not None and tracer.enabled:
+                tracer.add_span("train/step", self._t_dispatch,
+                                time.monotonic(), attrs=dict(self._host))
         return self._host
 
     def __getitem__(self, k):

@@ -124,7 +124,15 @@ def tiled_loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array], cfg,
 
     # forward up to final norm, but not the lm head
     dt = jnp.dtype(cfg.dtype)
-    x = tfm.forward_hidden(params, tokens, cfg, attn_fn=attn_fn)
+    extras: Dict[str, jax.Array] = {}
+    if cfg.kv_lora_rank and not cfg.index_topk:
+        # a trained latent model: the routed layers' balance loss and their
+        # counters come out beside the hidden states
+        from ..models.latent_sparse import forward_train
+
+        x, extras = forward_train(params, tokens, cfg, attn_fn=attn_fn)
+    else:
+        x = tfm.forward_hidden(params, tokens, cfg, attn_fn=attn_fn)
     if cfg.tie_embeddings:
         w, transpose, hb = params["embed"]["tokens"].astype(dt), True, None
     else:
@@ -135,5 +143,13 @@ def tiled_loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array], cfg,
                                              head_bias=hb)
     denom = jnp.maximum(mask.astype(jnp.float32).sum(), 1.0)
     loss = nll_sum / denom
-    return loss, {"loss": loss, "accuracy": correct_sum / denom,
-                  "tokens": denom}
+    metrics = {"accuracy": correct_sum / denom, "tokens": denom}
+    if "moe_aux_loss" in extras:
+        # the loss that is differentiated is the cross-entropy plus the
+        # balance loss times its coefficient; the metrics carry the parts
+        # (``ce_loss``, ``moe_aux_loss``) and the layers' counters
+        metrics.update(extras, ce_loss=loss)
+        with jax.named_scope("moe_aux"):
+            loss = loss + cfg.moe_aux_loss_coef * extras["moe_aux_loss"]
+    metrics["loss"] = loss
+    return loss, metrics

@@ -8,7 +8,7 @@ is replaced: the count of the cell's per-layer metrics, which that file
 fixes at PR 36's sixteen and PR 37 raised by six (a file under
 ``benchmark/`` is a ``benchmark`` PR's to edit); and a second, for the same
 reason: ``test_every_listed_name_is_found`` fixes the benchmark at seven
-cells, and PR 40 added the eighth."""
+cells, PR 40 added the eighth and PR 42 the ninth."""
 
 import os
 import sys
@@ -53,12 +53,20 @@ def _without(spec, cell):
 
 
 def test_every_listed_name_is_found(spec):  # noqa: F811
-    """The file's own test on the benchmark less the cell PR 40 added (its
-    count of seven cells holds of those), and the eighth cell's names found
-    as it finds the others': seven again, the new one among them."""
-    _seven_cells(_without(spec, "glm52-ctx8k-sat"))
-    _seven_cells(_without(spec, "olmoe-decode-sat"))
-    assert len(spec["workloads"]) == 8
+    """The file's own test on the benchmark less the cells PR 40 and PR 42
+    added (its count of seven cells holds of those), and the eighth and the
+    ninth cell's names found as it finds the others': seven again, the new
+    ones among them; the third training cell is listed behind the two."""
+    less = _without(spec, "dsv2lite-train-8k")  # PR 42's, the ninth
+    _seven_cells(_without(less, "glm52-ctx8k-sat"))
+    _seven_cells(_without(less, "olmoe-decode-sat"))
+    _seven_cells(_without(_without(spec, "glm52-ctx8k-sat"),
+                          "olmoe-decode-sat"))
+    assert len(spec["workloads"]) == 9
+    tokens = next(m for m in spec["end_to_end"]
+                  if m["name"] == "train_tokens_per_s")
+    assert tokens["workloads"] == ["train-1chip", "zero3-4chip",
+                                   "dsv2lite-train-8k"]
 
 
 def test_mixed_gap_share_is_listed_for_every_serving_cell(spec):  # noqa: F811

@@ -129,7 +129,7 @@ def test_dropless_matches_dense_reference(devices):
 
     from deepspeed_tpu.moe.dropless import dropless_moe_block_with_losses
 
-    y, aux, zl = jax.jit(
+    y, aux, zl, _ = jax.jit(
         lambda x, p: dropless_moe_block_with_losses(jnp.asarray(x), p, cfg)
     )(x, lp)
     ref = _dense_moe_reference(x, lp, cfg)
@@ -165,7 +165,7 @@ def test_dropless_never_drops_tokens(devices):
 
     from deepspeed_tpu.moe.dropless import dropless_moe_block_with_losses
 
-    y, _, _ = jax.jit(lambda x, p: dropless_moe_block_with_losses(
+    y, _, _, _ = jax.jit(lambda x, p: dropless_moe_block_with_losses(
         jnp.asarray(x), p, cfg))(x, lp)
     h = (jax.nn.silu(x @ lp["w_gate"][2]) * (x @ lp["w_in"][2])) @ lp["w_out"][2]
     np.testing.assert_allclose(np.asarray(y), np.asarray(h), atol=2e-5,

@@ -379,6 +379,103 @@ def test_flash_attention_window_compiles(one_chip, mosaic, grad):
         "flash_attention_bwd_dq"] if grad else ["flash_attention_fwd"])
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_at_unequal_widths_compiles(one_chip, mosaic, grad):
+    """Latent attention's expanded form at DeepSeek-V2-Lite's sizes: 16
+    heads, a query-key width of 192 (one and a half lane tiles) beside a
+    value width of 128, two rows of 8,192; blocks of 512 (at 1,024 the dK/dV
+    kernel passes the scoped VMEM), and ``v`` is not padded."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    qk = _sds((2, 8192, 16, 192), jnp.bfloat16, one_chip)
+    v = _sds((2, 8192, 16, 128), jnp.bfloat16, one_chip)
+    fn = functools.partial(flash_attention, causal=True, sm_scale=0.1147)
+    if grad:
+        fn = jax.grad(lambda q_, k_, v_: flash_attention(
+            q_, k_, v_, causal=True, sm_scale=0.1147
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    tracer.clear()
+    text = _compile(fn, qk, qk, v, kernels=[
+        "flash_attention_fwd", "flash_attention_bwd_dkv",
+        "flash_attention_bwd_dq"] if grad else ["flash_attention_fwd"])
+    assert "bf16[2,16,8192,128]" in text  # the output is 128 wide
+    events = [s.attrs for s in tracer.spans()
+              if s.name == "kernel/flash_attention_tiles"]
+    assert {e["pass"] for e in events} == (
+        {"fwd", "dkdv", "dq"} if grad else {"fwd"})
+    assert all((e["d_qk"], e["d_v"], e["block_q"], e["block_k"])
+               == (192, 128, 512, 512) and "fallback" not in e
+               for e in events)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_backward_compiles(one_chip, mosaic, k, n):
+    """The held experts' grouped GEMM with its backward at DeepSeek-V2-Lite's
+    widths on a round's 22,528 rows: forward, dlhs over the experts as they
+    are stored, drhs with an expert's tiles summed in VMEM; 1,408 = 11 x 128
+    is taken whole (steps of 128 columns would leave the MXU waiting)."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    rows, tile_m, experts = 22528, 512, 8
+
+    def fn(lhs, rhs, tg, sizes, used):
+        return jax.value_and_grad(lambda a, b: jnp.square(grouped_matmul(
+            a, b, tg, sizes, tile_m=tile_m, used_tiles=used
+        ).astype(jnp.float32)).sum(), argnums=(0, 1))(lhs, rhs)
+
+    tracer.clear()
+    _compile(fn, _sds((rows, k), jnp.bfloat16, one_chip),
+             _sds((experts, k, n), jnp.bfloat16, one_chip),
+             _sds((rows // tile_m,), jnp.int32, one_chip),
+             _sds((experts,), jnp.int32, one_chip),
+             _sds((), jnp.int32, one_chip),
+             kernels=["grouped_matmul", "grouped_matmul_dlhs",
+                      "grouped_matmul_drhs"])
+    event = next(s.attrs for s in tracer.spans()
+                 if s.name == "kernel/grouped_matmul_tiles")
+    assert (event["e"], event["k"], event["n"], event["rows"],
+            event["tile_m"]) == (experts, k, n, rows, tile_m)
+    assert "fallback" not in event and 1408 in (event["tile_k"],
+                                                event["tile_n"])
+
+
+def test_latent_moe_train_step_carries_its_scopes(one_chip, mosaic):
+    """The trained forward and backward of DeepSeek-V2-Lite's dense layer
+    and one routed layer (8 of 64 experts, an eighth of the vocabulary, 2 x
+    8,192 tokens) lower for the chip with every kernel in place (the two
+    tests above compile them), and every scope the benchmark's readers sum
+    is on an operation.  Lowered, not compiled: the compile is 40 s."""
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.sequence.tiled_compute import tiled_loss_fn
+
+    cfg = tfm.get_config(
+        "deepseek-v2-lite", num_layers=2, vocab_size=12800,
+        moe_experts_held=8, mlp_layer_types=("dense", "sparse"),
+        dtype="bfloat16", param_dtype="bfloat16")
+    shapes = jax.eval_shape(lambda key: tfm.init_params(key, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), shapes)
+    ids = _sds((2, 8192), jnp.int32, one_chip)
+    text = jax.jit(lambda p, b: jax.value_and_grad(lambda p_: tiled_loss_fn(
+        p_, {"input_ids": b}, cfg, tile_size=512), has_aux=True)(p)
+    ).lower(params, ids).as_text(debug_info=True)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                   "flash_attention_bwd_dq", "grouped_matmul",
+                   "grouped_matmul_dlhs", "grouped_matmul_drhs"):
+        assert re.search(rf'[/"]{kernel}["/]', text), kernel
+    assert text.count("tpu_custom_call") >= 12
+    for scope in ("mla_qkv", "mla_attn", "mla_out", "moe_route",
+                  "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
+                  "moe_aux"):
+        assert re.search(rf'[/"]{scope}/', text), scope
+    # the share's layout is walked in rounds: no array of all 98,304
+    # assignments x hidden exists
+    assert "98304x2048x" not in text and "102912x" not in text
+
+
 def test_flash_attention_on_a_mesh_compiles(topo, mosaic):
     """GSPMD cannot partition a Mosaic kernel; on the engine's mesh the call
     becomes a shard_map over the batch, and each chip runs one row."""
