@@ -33,7 +33,10 @@ chip's share of the routed experts, with what that model adds:
 * the step's counters (``moe_local_rows``, ``moe_rows_max``,
   ``moe_experts_hit``, ``moe_aux_loss``) are read out of the metrics the loss
   is fetched with, and in a traced run the window's time is reduced by kernel
-  and scope name (``benchmark/kernel_time.py``) for the per-layer readers.
+  and scope name (``benchmark/kernel_time.py``) for the per-layer readers:
+  the window's, and the step program's whole executions one by one, each to
+  be read beside the counters of its OWN step (``step_counters``,
+  ``traced_from``: a roofline share's work and time are of the same steps).
 
 ``train.py`` is called for the batches and copied for the loop; it is not
 edited.
@@ -62,6 +65,8 @@ SCOPES = ("mla_qkv", "mla_attn", "mla_out", "moe_route", "moe_dispatch",
           "moe_experts", "moe_combine", "moe_shared", "moe_aux")
 COUNTERS = ("moe_local_rows", "moe_rows_max", "moe_experts_hit",
             "moe_aux_loss")
+#: the host span round the loop's one fetch a step
+FETCH = "bench/fetch_loss"
 #: the stacks the gradients and the first step's updates are compared by
 STACKS = ("attention", "dense_mlp", "held_experts", "shared_experts",
           "router", "embedding", "head")
@@ -450,6 +455,10 @@ class TraceSession(common.TraceSession):
                     ",", 1)[0].split()[0]
                 reduced["by_name"] = kernel_time.reduce(trace, {
                     module: kernel_time.scopes_of_text(text, SCOPES)})
+                # the step's executions the window holds whole, for the
+                # shares whose work is a step's own
+                reduced["by_name"]["steps"] = kernel_time.whole_steps(
+                    trace, module, FETCH)
             return reduced
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
@@ -547,6 +556,7 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
     gc.callbacks.append(timed)
     trace_from = traffic["trace_after_s"] if session else float("inf")
     trace_to = float("inf")  # set when the profiler starts
+    traced_from = None  # the steps fetched before the profiler started
     t_open = time.monotonic()
     setup_s = t_open - t_ready
     log(f"window opens; set-up {setup_s:.1f}s")
@@ -563,6 +573,7 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
         if now >= trace_from:  # between two steps, on this thread
             session.start()
             trace_from, trace_to = float("inf"), now + traffic["trace_seconds"]
+            traced_from = len(counters)
         elif now >= trace_to:
             session.stop()
             trace_to = float("inf")
@@ -575,7 +586,7 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
         t4 = time.monotonic()
         step += 1
         if len(pending) > in_flight:
-            with jax.profiler.TraceAnnotation("bench/fetch_loss"):
+            with jax.profiler.TraceAnnotation(FETCH):
                 fetch(pending.popleft())
         t5 = time.monotonic()
         parts.append((t1 - t0, t2 - t1, t3 - t2, t4 - t3, t5 - t4))
@@ -660,6 +671,10 @@ def run(*, cell: Mapping[str, Any], config: Mapping[str, Any],
         "train": {"steps": steps, "tokens_per_step": tokens_per_step,
                   "done_times": done_times, "in_flight": in_flight,
                   "seq_len": seq_len, "rows": rows, "counters": mean,
+                  # step by step, and the first whose fetch the trace holds
+                  "step_counters": [dict(zip(COUNTERS, map(float, c)))
+                                    for c in counters],
+                  "traced_from": traced_from,
                   # the counters are a replica's: its own tokens' rows
                   "tokens_per_replica": tokens_per_step // topo.dp_world_size,
                   "flops_per_token": latent_moe_flops.train_flops_per_token(

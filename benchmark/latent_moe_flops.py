@@ -11,7 +11,7 @@ FLOPs; a kernel's share counts what each of its CALLS must compute.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Mapping, Optional, Tuple
+from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
 FLASH_FWD = "flash_attention_fwd"
 FLASH_BWD = ("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
@@ -100,9 +100,11 @@ def grouped_call(model: Mapping[str, Any], local_rows: float,
 
 def kernel_seconds(by_name: Optional[Mapping[str, Any]], names) -> Tuple[
         float, float]:
-    """→ (seconds, calls) of the kernels ``names`` in a traced window's
-    reduction by name (``kernel_time.reduce``), whatever program they ran
-    in."""
+    """→ (seconds, calls) of the kernels ``names`` in a reduction by name,
+    whatever program they ran in: the traced window's (``kernel_time.reduce``:
+    a share of the window's busy time) or ONE whole execution of the step
+    program (an entry of ``kernel_time.whole_steps``: what a roofline share
+    counts)."""
     if not by_name:
         return 0.0, 0.0
     keys = [k for k in by_name["kernel_s"] if k.rsplit("/", 1)[-1] in names]
@@ -112,6 +114,29 @@ def kernel_seconds(by_name: Optional[Mapping[str, Any]], names) -> Tuple[
 
 def by_name(obs) -> Optional[Mapping[str, Any]]:
     return (obs.get("trace") or {}).get("by_name")
+
+
+def whole_steps(obs) -> Sequence[Mapping[str, Any]]:
+    """The step program's executions that lie wholly inside the traced
+    window (``kernel_time.whole_steps``, under ``by_name["steps"]``).  A
+    roofline share is taken over these alone, on both sides: a call that the
+    window's edge cuts is neither counted nor timed."""
+    return (by_name(obs) or {}).get("steps") or ()
+
+
+def counters_of(obs, step: Mapping[str, Any]) -> Optional[Mapping[str, float]]:
+    """The counters of the very step whose execution ``step`` is: the driver
+    hands every step's (``train["step_counters"]``, in the order fetched) and
+    how many it had fetched when the profiler started
+    (``train["traced_from"]``); the trace says which of its fetches brought
+    this execution's metrics (``step["fetch"]``).  None where any of the
+    three is missing."""
+    train = obs.get("train") or {}
+    counters, first = train.get("step_counters"), train.get("traced_from")
+    if not counters or first is None or step.get("fetch") is None:
+        return None
+    i = first + step["fetch"]
+    return counters[i] if i < len(counters) else None
 
 
 def busy_share(obs, names=(), scopes=()) -> Optional[float]:
@@ -127,37 +152,63 @@ def busy_share(obs, names=(), scopes=()) -> Optional[float]:
 
 def flash_roofline(obs, backward: bool) -> Optional[float]:
     """100 x the least time the MXU could take for the flash kernel's calls
-    in the window (bf16 peak of ``peaks.json``) over the time they took."""
-    t, train = by_name(obs), obs.get("train") or {}
-    if not t or "model" not in obs or "rows" not in train:
+    in the traced window's whole steps (bf16 peak of ``peaks.json``) over the
+    time they took."""
+    train = obs.get("train") or {}
+    if "model" not in obs or "rows" not in train:
         return None
     fwd, bwd = flash_call_flops(obs["model"], train["rows"],
                                 train["seq_len"])
-    if backward:
-        seconds = kernel_seconds(t, FLASH_BWD)[0]
-        calls = kernel_seconds(t, FLASH_BWD[1:])[1]
-    else:
-        seconds, calls = kernel_seconds(t, (FLASH_FWD,))
+    # the dK/dV and the dQ kernel together are ONE backward
+    timed = FLASH_BWD if backward else (FLASH_FWD,)
+    steps = whole_steps(obs)
+    seconds = sum(kernel_seconds(step, timed)[0] for step in steps)
+    calls = sum(kernel_seconds(step, timed[-1:])[1] for step in steps)
     if not seconds or not calls:
         return None
     peak = obs["device"]["peaks"]["bf16_flops_per_s"]
     return 100.0 * calls * (bwd if backward else fwd) / peak / seconds
 
 
+def grouped_passes(step: Mapping[str, Any]) -> float:
+    """The grouped GEMM's calls over ONE routed layer's rows in a step, from
+    the step's own calls by name: every round of every layer makes one
+    ``grouped_matmul_dlhs`` call for each of an expert's three matrices
+    (gate, up, down: ``expert_params``), so a third of those calls is the
+    ROUNDS that ran, and all the calls (forward, each rematerialised forward,
+    dlhs, drhs) over the rounds are the calls a layer's rows pass through
+    when one round holds them: 12 with the forward rematerialised once.  A
+    second round (``moe/dropless._share_in_rounds``: a layer whose local rows
+    pass 22,528 with their padding) adds calls and no rows, and leaves this
+    number where it was."""
+    rounds = kernel_seconds(step, GROUPED[1:2])[1] / 3.0
+    return kernel_seconds(step, GROUPED)[1] / rounds if rounds else 0.0
+
+
 def grouped_roofline(obs) -> Optional[float]:
-    """100 x the least time the chip could take for the grouped GEMM's calls
-    (forward, dlhs, drhs) in the window, the larger of FLOPs over the bf16
-    peak and bytes over the HBM rate a call, over the time they took."""
-    t, train = by_name(obs), obs.get("train") or {}
-    c = train.get("counters")
-    if not t or not c or "model" not in obs:
+    """100 x the least time the chip could take for the grouped GEMM's work
+    (forward, dlhs, drhs) in the traced window's whole steps over the time
+    its calls took there.  The work of a step: its routed layers x
+    :func:`grouped_passes` x one call over the rows THAT STEP's router made
+    local (the step's own ``moe_local_rows`` and ``moe_experts_hit``, means
+    over its routed layers; ``grouped_call``: the larger of FLOPs over the
+    bf16 peak and bytes over the HBM rate).  A layer's rows are credited once
+    a pass however many rounds the program walked them in; that a second
+    round fetches the experts' matrices again is the program's choice and is
+    not counted.  A step whose counters the trace cannot place counts on
+    neither side."""
+    if "model" not in obs:
         return None
-    seconds, calls = kernel_seconds(t, GROUPED)
-    if not seconds or not calls:
-        return None
-    flops, nbytes = grouped_call(obs["model"], c["moe_local_rows"],
-                                 c["moe_experts_hit"])
-    peaks = obs["device"]["peaks"]
-    least = max(flops / peaks["bf16_flops_per_s"],
-                nbytes / peaks["hbm_bytes_per_s"])
-    return 100.0 * calls * least / seconds
+    model, peaks = obs["model"], obs["device"]["peaks"]
+    layers = model["num_hidden_layers"] - model["first_k_dense_replace"]
+    least = seconds = 0.0
+    for step in whole_steps(obs):
+        c, passes = counters_of(obs, step), grouped_passes(step)
+        if not c or not passes:
+            continue
+        flops, nbytes = grouped_call(model, c["moe_local_rows"],
+                                     c["moe_experts_hit"])
+        least += layers * passes * max(flops / peaks["bf16_flops_per_s"],
+                                       nbytes / peaks["hbm_bytes_per_s"])
+        seconds += kernel_seconds(step, GROUPED)[0]
+    return 100.0 * least / seconds if seconds else None
