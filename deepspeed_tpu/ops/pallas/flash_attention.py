@@ -11,7 +11,9 @@ Design (classic FlashAttention-2 schedule on the MXU):
 * grid = (batch, heads, q_blocks, kv_blocks); TPU executes the innermost
   (kv) dimension sequentially, so the running max/denominator/accumulator
   live in VMEM scratch across kv steps;
-* causal masking skips fully-masked kv blocks via predication;
+* causal masking skips fully-masked kv blocks via predication, and a
+  skipped step's index maps name the block at the band's edge (the one its
+  neighbour names), so the pipeline fetches nothing for it;
 * GQA: kv block index maps ``h → h * kv_heads // heads`` so grouped heads
   read the same K/V without materializing repeats;
 * segment ids (packed sequences) are masked in-kernel: q ids ride along
@@ -22,6 +24,18 @@ Design (classic FlashAttention-2 schedule on the MXU):
   `sparse_attention` layouts — fixed/bigbird/longformer — compile to this);
 * backward = two kernels (dkdv: grid over kv blocks; dq: grid over q blocks)
   using the saved logsumexp, in the standard recompute formulation;
+* precision: every dot multiplies its operands in the dtype the caller handed
+  in and sums in float32.  q, k, v and dO go to the MXU as they are read; the
+  probabilities ``p`` and ``ds`` are computed in float32 and rounded to the
+  other operand's dtype immediately before the dot that consumes them.  The
+  scores, the softmax (max, sum, ``exp``), ``lse``, ``delta``, the biases,
+  the masks and every accumulator are float32 whatever the input
+  (``operand_dtype`` on the ``kernel/flash_attention_tiles`` event names the
+  dots' dtype).  Mosaic's float32 x float32 dot at default precision is one
+  bfloat16 pass on the v5e as well (it rounds both operands on their way to
+  the MXU), so bfloat16 callers get the same bits either way; what the rule
+  buys them is VMEM: no float32 copy of a block exists, and past one lane
+  tile 16-bit blocks fit at 1024 where float32 ones are held to 512;
 * CPU fallback: interpreter mode (tests), or the XLA einsum path for odd
   shapes.
 """
@@ -80,6 +94,31 @@ def _tile_in_band(q_start, k_start, block_q: int, block_k: int,
     return ok
 
 
+def _kv_block_in_band(iq, ik, block_q: int, block_k: int, causal: bool,
+                      window: int):
+    """``ik`` held to the kv blocks that ``_tile_in_band`` keeps for q block
+    ``iq``.  A step outside the band computes nothing; with its block index
+    held at the band's edge it names the block the neighbouring step names,
+    and the pipeline fetches nothing for it."""
+    if causal or window > 0:
+        ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+    if window > 0:
+        ik = jnp.maximum(ik, jnp.maximum(iq * block_q - window + 1, 0)
+                         // block_k)
+    return ik
+
+
+def _q_block_in_band(iq, ik, block_q: int, block_k: int, causal: bool,
+                     window: int):
+    """``iq`` held to the q blocks that ``_tile_in_band`` keeps for kv block
+    ``ik``: ``_kv_block_in_band`` for the kernel that walks the q blocks."""
+    if window > 0:
+        iq = jnp.minimum(iq, (ik * block_k + block_k + window - 2) // block_q)
+    if causal or window > 0:
+        iq = jnp.maximum(iq, ik * block_k // block_q)
+    return iq
+
+
 def _seg_mask(q_seg_tile, k_seg_tile, block_k: int):
     """(block_q, NUM_LANES) q ids + (1, block_k) kv ids → (bq, bk) keep-mask.
 
@@ -129,9 +168,8 @@ def _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start, k_start,
     applied. Returns (s, keep); ``keep`` is None when nothing masks at the
     element level. Shared by the forward and both backward kernels so mask
     semantics can never desynchronize between passes."""
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                            (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
     keep = None
     if causal or window > 0:
@@ -174,7 +212,7 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
 
     @pl.when(should_run)
     def _compute():
-        v = v_ref[0, 0].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, 0]  # (bk, d)
         s, keep = _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
                                  k_start, sm_scale, causal, window, block_k,
                                  has_seg)  # (bq, bk)
@@ -199,7 +237,8 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
 
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_ref[:] = m_new
         l_ref[:] = l_new
 
@@ -248,6 +287,12 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
     has_b2 = bias_qk is not None
 
     grid = (B, H, nq, nk)
+    def kv_at(iq, ik):  # the kv block of a step, held inside the band
+        return _kv_block_in_band(iq, ik, block_q, block_k, causal, window)
+
+    def kv_rows(b, h, iq, ik, *_):  # a block of this head's kv rows
+        return (b, h // group, kv_at(iq, ik), 0)
+
     in_specs = []
     inputs = []
     if has_seg:
@@ -255,7 +300,7 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
             pl.BlockSpec((1, block_q, NUM_LANES),
                          lambda b, h, iq, ik, *_: (b, iq, 0)),
             pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda b, h, iq, ik, *_: (b, 0, ik)),
+                         lambda b, h, iq, ik, *_: (b, 0, kv_at(iq, ik))),
         ]
         inputs += [q_seg, k_seg]
     if has_b1:  # per-key bias, (B, NUM_SUBLANES, Skv) lane layout
@@ -270,10 +315,8 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
         inputs += [bias_qk]
     in_specs += [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, Dv),
-                     lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
+        pl.BlockSpec((1, 1, block_k, D), kv_rows),
+        pl.BlockSpec((1, 1, block_k, Dv), kv_rows),
     ]
     inputs += [q, k, v]
     out, lse = _pallas_call(
@@ -332,9 +375,9 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, nq: int,
 
     @pl.when(should_run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)  # (bq, d)
+        q = q_ref[0, 0]  # (bq, d)
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]  # (bq, d)
         lse = lse_ref[0, 0]  # (bq, 1)
         delta = delta_ref[0, 0]  # (bq, 1)
 
@@ -345,12 +388,14 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, nq: int,
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
 
-        dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+        dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                         (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * sm_scale  # (bq, bk)
-        dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+        dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
+                                         (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
     @pl.when(iqg == niqg - 1)
@@ -380,9 +425,9 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, window: int,
 
     @pl.when(should_run)
     def _compute():
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = lse_ref[0, 0]  # (bq, 1)
         delta = delta_ref[0, 0]  # (bq, 1)
 
@@ -395,7 +440,8 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, window: int,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * sm_scale
-        dq_acc[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+        dq_acc[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
+                                         (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
@@ -419,38 +465,37 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     for which in ("dkdv", "dq"):  # once a traced backward, as the forward's
         tracer.add_event("kernel/flash_attention_tiles", attrs={
             "d_qk": D, "d_v": Dv, "block_q": block_q, "block_k": block_k,
-            "pass": which})
+            "operand_dtype": jnp.dtype(q.dtype).name, "pass": which})
 
     # dk, dv: one pass per kv block; the innermost grid dim walks all
     # (group, q-block) pairs so GQA groups accumulate directly into the
     # (B, KV, Skv, D) result — no (B, H, Skv, D) f32 intermediate.
+    def q_at(ik, iqg):  # the q block of a step, held inside the band
+        return _q_block_in_band(iqg % nq, ik, block_q, block_k, causal,
+                                window)
+
+    def q_rows(b, kv, ik, iqg, *_):  # a block of this head's q rows
+        return (b, kv * group + iqg // nq, q_at(ik, iqg), 0)
+
     in_specs = []
     inputs = []
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, block_q, NUM_LANES),
-                         lambda b, kv, ik, iqg, *_: (b, iqg % nq, 0)),
+                         lambda b, kv, ik, iqg, *_: (b, q_at(ik, iqg), 0)),
             pl.BlockSpec((1, NUM_SUBLANES, block_k),
                          lambda b, kv, ik, iqg, *_: (b, 0, ik)),
         ]
         inputs += [q_seg, k_seg]
     in_specs += [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, kv, ik, iqg, *_: (b, kv * group + iqg // nq,
-                                                 iqg % nq, 0)),
+        pl.BlockSpec((1, 1, block_q, D), q_rows),
         pl.BlockSpec((1, 1, block_k, D),
                      lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
         pl.BlockSpec((1, 1, block_k, Dv),
                      lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
-        pl.BlockSpec((1, 1, block_q, Dv),
-                     lambda b, kv, ik, iqg, *_: (b, kv * group + iqg // nq,
-                                                 iqg % nq, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, kv, ik, iqg, *_: (b, kv * group + iqg // nq,
-                                                 iqg % nq, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, kv, ik, iqg, *_: (b, kv * group + iqg // nq,
-                                                 iqg % nq, 0)),
+        pl.BlockSpec((1, 1, block_q, Dv), q_rows),
+        pl.BlockSpec((1, 1, block_q, 1), q_rows),
+        pl.BlockSpec((1, 1, block_q, 1), q_rows),
     ]
     inputs += [q, k, v, g, lse, delta]
     dk, dv = _pallas_call(
@@ -476,6 +521,12 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
         ],
         mask_tab, inputs)
 
+    def kv_at(iq, ik):  # the kv block of a step, held inside the band
+        return _kv_block_in_band(iq, ik, block_q, block_k, causal, window)
+
+    def kv_rows(b, h, iq, ik, *_):  # a block of this head's kv rows
+        return (b, h // group, kv_at(iq, ik), 0)
+
     in_specs = []
     inputs = []
     if has_seg:
@@ -483,15 +534,13 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
             pl.BlockSpec((1, block_q, NUM_LANES),
                          lambda b, h, iq, ik, *_: (b, iq, 0)),
             pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda b, h, iq, ik, *_: (b, 0, ik)),
+                         lambda b, h, iq, ik, *_: (b, 0, kv_at(iq, ik))),
         ]
         inputs += [q_seg, k_seg]
     in_specs += [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, Dv),
-                     lambda b, h, iq, ik, *_: (b, h // group, ik, 0)),
+        pl.BlockSpec((1, 1, block_k, D), kv_rows),
+        pl.BlockSpec((1, 1, block_k, Dv), kv_rows),
         pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
@@ -609,10 +658,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if 0 < window < cap:
             cap = min(cap, max(128, window // 128 * 128))
         # a query-key width past one lane tile (latent attention's 192 is
-        # held as 256): the dK/dV kernel's blocks and float32 accumulators at
-        # 1024 x 1024 pass the 16 MB of scoped VMEM; 512 fits, and wastes
-        # less of a causal diagonal tile
-        if D > NUM_LANES:
+        # held as 256): at 1024 x 1024 the dK/dV kernel's float32 tiles (the
+        # (bq, bk) scores, p, dp and ds, and the dk / dv accumulators) beside
+        # float32 blocks pass the 16 MB of scoped VMEM; 512 fits.  The blocks
+        # are in the caller's dtype, and 16-bit ones fit at 1024: a quarter
+        # of the grid steps, each with its fixed cost and its rescaling of
+        # the accumulators
+        if D > NUM_LANES and jnp.dtype(q.dtype).itemsize > 2:
             cap = min(cap, 512)
         # largest sublane-aligned divisor, so raising the default can never
         # push a previously-fused shape onto the O(S²) fallback (e.g.
@@ -638,7 +690,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"for S={S}, block_q={block_q}, block_k={block_k}")
     # chosen once per shape, while the caller's program is traced
     event = {"d_qk": D, "d_v": v.shape[3], "block_q": block_q,
-             "block_k": block_k}
+             "block_k": block_k, "operand_dtype": jnp.dtype(q.dtype).name}
     if not usable:
         tracer.add_event("kernel/flash_attention_tiles",
                          attrs={**event, "fallback": 1})

@@ -6,7 +6,10 @@ through ``run.run_cell`` with the device check stubbed, ``correct`` decided by
 on the gradient of its loss function, a named fault in the reference's place
 coming out not correct, and the yardstick's arithmetic at the published
 sizes.  A later PR that breaks the cell's driver, reference or readers fails
-here."""
+here.  ``benchmark/tests/test_train_latent_moe_readers.py`` (PR 44: the
+grouped GEMM's roofline share and the flash kernels' on hand-made traces, 33
+cases, no chip) is imported whole, as ``tests/test_doc_prefill_loaded_cell.py``
+imports its file: the claims of ISSUE 45 and after rest on that yardstick."""
 
 import json
 import os
@@ -17,6 +20,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark", "tests"))
 import dsv2lite_rehearsal as rehearsal  # noqa: E402
+from test_train_latent_moe_readers import *  # noqa: E402,F401,F403
 
 from benchmark import latent_moe_flops, trace_reduce  # noqa: E402
 from benchmark.drivers import train_latent_moe  # noqa: E402

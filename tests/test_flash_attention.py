@@ -1,6 +1,8 @@
 """Flash-attention kernel numeric tests against the XLA reference
 (reference model: tests/unit/ops per-kernel numeric tests)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,3 +122,276 @@ def test_sliding_window_model_config(devices):
     bad = tfm.get_config("tiny", attn_impl="xla", sliding_window=16)
     with pytest.raises(ValueError):
         tfm.forward(params, tokens, bad)
+
+
+# ---------------------------------------------------------------------------
+# the operand rule (ISSUE 45): every dot multiplies its operands in the dtype
+# the caller handed in and sums in float32; p and ds are rounded to that
+# dtype immediately before their dots; softmax, lse, delta and every
+# accumulator are float32 whatever the input
+# ---------------------------------------------------------------------------
+
+# name → (query-key width, value width, heads, KV heads): the dense models'
+# 128 / 128 under GQA, and latent attention's 192 / 128
+WIDTHS = {"128-128": (128, 128, 4, 2), "192-128": (192, 128, 2, 2)}
+# dots a kernel holds: q kᵀ and p v; q kᵀ, pᵀ dO, dO vᵀ and dsᵀ q; q kᵀ,
+# dO vᵀ and ds k
+KERNEL_DOTS = {"flash_attention_fwd": 2, "flash_attention_bwd_dkv": 4,
+               "flash_attention_bwd_dq": 3}
+# name → flash_attention's keywords and whether segment ids ride along
+MASKS = {"causal": (dict(causal=True), False),
+         "window": (dict(causal=True, window=96), False),
+         "segments": (dict(causal=True), True)}
+SEQ, BLOCK = 256, 128
+
+
+def _inputs(width, dtype, seed=7):
+    d_qk, d_v, heads, kv = WIDTHS[width]
+    kq, kk, kv_, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(kq, (1, SEQ, heads, d_qk), jnp.float32)
+    k = jax.random.normal(kk, (1, SEQ, kv, d_qk), jnp.float32)
+    v = jax.random.normal(kv_, (1, SEQ, kv, d_v), jnp.float32)
+    w = jax.random.normal(kw, (1, SEQ, heads, d_v), jnp.float32)
+    return tuple(x.astype(dtype) for x in (q, k, v, w))
+
+
+def _sub_jaxprs(eqn):
+    for val in eqn.params.values():
+        for x in (val if isinstance(val, (list, tuple)) else [val]):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _pallas_calls(jaxpr, found):
+    """name → the ``pallas_call`` equation of that name, at any depth."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn
+        else:
+            for sub in _sub_jaxprs(eqn):
+                _pallas_calls(sub, found)
+    return found
+
+
+def _dots(jaxpr, found):
+    """[(dot equation, the equations that made its two operands)] of a
+    kernel's body, through the ``pl.when`` branches."""
+    made_by = {v: eqn for eqn in jaxpr.eqns for v in eqn.outvars}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append((eqn, [made_by.get(v) for v in eqn.invars]))
+        for sub in _sub_jaxprs(eqn):
+            _dots(sub, found)
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _traced_kernels(width, dtype):
+    q, k, v, w = _inputs(width, jnp.dtype(dtype))
+
+    def loss(q_, k_, v_):
+        out = flash_attention(q_, k_, v_, causal=True, block_q=BLOCK,
+                              block_k=BLOCK)
+        return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    return _pallas_calls(jaxpr.jaxpr, {})
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_DOTS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_dots_multiply_what_they_are_given(devices, width, dtype, kernel):
+    """Every ``dot_general`` inside a flash kernel takes both operands in the
+    caller's dtype and gives float32, and no conversion to float32 feeds
+    one (with float32 inputs the casts of p and ds are no conversion)."""
+    call = _traced_kernels(width, dtype)[kernel]
+    dots = _dots(call.params["jaxpr"], [])
+    assert len(dots) == KERNEL_DOTS[kernel]
+    for dot, makers in dots:
+        assert [str(x.aval.dtype) for x in dot.invars] == [dtype, dtype]
+        assert dot.outvars[0].aval.dtype == jnp.float32
+        assert dot.params["preferred_element_type"] == jnp.float32
+        for maker in makers:
+            assert not (maker is not None
+                        and maker.primitive.name == "convert_element_type"
+                        and maker.params["new_dtype"] == jnp.float32), maker
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_DOTS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_accumulators_and_lse_are_float32(devices, dtype, kernel):
+    """The scratch a kernel sums into (acc / m / l; dk / dv; dq) is float32
+    whatever the input, as are lse and delta where they cross HBM; what the
+    kernels hand back (out; dk, dv; dq) is in the caller's dtype."""
+    call = _traced_kernels("192-128", dtype)[kernel]
+    body = call.params["jaxpr"]
+    scratch = body.invars[-call.params["grid_mapping"].num_scratch_operands:]
+    assert len(scratch) == {"flash_attention_fwd": 3,
+                            "flash_attention_bwd_dkv": 2,
+                            "flash_attention_bwd_dq": 1}[kernel]
+    assert all(s.aval.dtype == jnp.float32 for s in scratch)
+    rows = [x.aval for x in body.invars if x.aval.shape[-1] == 1
+            and x not in scratch]  # lse, delta: (1, 1, block, 1)
+    outs = [str(a.dtype) for a in call.params["out_avals"]]
+    if kernel == "flash_attention_fwd":
+        assert outs == [dtype, "float32"]  # out, lse
+    else:
+        assert set(outs) == {dtype}
+        assert len(rows) == 2  # lse and delta come in
+    assert all(a.dtype == jnp.float32 for a in rows)
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_lse_of_bfloat16_inputs_is_summed_in_float32(devices, width):
+    """A bf16 x bf16 product is exact in float32, so the saved logsumexp of
+    bf16 inputs agrees with the float32 reference on the same values to
+    float32's rounding: nothing before the softmax was rounded to bf16."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash_fwd
+
+    q, k, v, _ = _inputs(width, jnp.bfloat16)
+    d_qk, _, heads, kv = WIDTHS[width]
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = _flash_fwd(qt, kt, vt, None, None, None, d_qk ** -0.5, True,
+                          BLOCK, BLOCK)
+    assert (out.dtype, lse.dtype) == (jnp.bfloat16, jnp.float32)
+    s = jnp.einsum("bhsd,bhtd->bhst", qt.astype(jnp.float32),
+                   jnp.repeat(kt, heads // kv, axis=1).astype(jnp.float32),
+                   precision="highest") * d_qk ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((SEQ, SEQ), bool)), s, -jnp.inf)
+    ref = jax.nn.logsumexp(s, axis=-1)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+def _rel_l2(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# bf16 keeps 8 bits of mantissa: one rounding moves a value by at most 2^-9
+# of itself.  The forward rounds p once and the output once; a gradient
+# rounds p or ds, reads a dO and an output that were rounded, and is rounded
+# itself.  Independent roundings add in squares, so over a whole tensor the
+# error norm stays under 2^-8 of the result's norm (read here: 0.0018-0.0019
+# forward, 0.0022-0.0026 the gradients), and the element furthest off lies
+# within two roundings of the largest element (read: 0.0019-0.0046 of it).
+BF16_REL_L2 = 2.0 ** -8
+
+
+@functools.lru_cache(maxsize=None)
+def _bf16_against_float32(width, mask):
+    """→ {"out" | "dq" | "dk" | "dv": (kernel on bf16 inputs, reference in
+    float32 on the same values)}."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _reference_attention
+
+    kwargs, with_seg = MASKS[mask]
+    q, k, v, w = _inputs(width, jnp.bfloat16)
+    seg = (jnp.arange(SEQ)[None] // 100).astype(jnp.int32) if with_seg \
+        else None
+    wf = w.astype(jnp.float32)
+
+    def ref(q_, k_, v_):
+        return _reference_attention(
+            q_, k_, v_, causal=True, window=kwargs.get("window", 0),
+            segment_ids=seg, block_mask=None, block_q=1, block_k=1)
+
+    def kernel(q_, k_, v_):
+        return flash_attention(q_, k_, v_, block_q=BLOCK, block_k=BLOCK,
+                               segment_ids=seg, **kwargs)
+
+    f32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    want_out = ref(*f32)
+    want = jax.grad(lambda *a: (ref(*a) * wf).sum(), argnums=(0, 1, 2))(*f32)
+    got_out = kernel(q, k, v)
+    got = jax.grad(lambda *a: (kernel(*a).astype(jnp.float32) * wf).sum(),
+                   argnums=(0, 1, 2))(q, k, v)
+    assert got_out.dtype == jnp.bfloat16
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    return {"out": (got_out, want_out), "dq": (got[0], want[0]),
+            "dk": (got[1], want[1]), "dv": (got[2], want[2])}
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_bfloat16_inputs_match_the_float32_reference(devices, width, mask,
+                                                     which):
+    """Forward and the three gradients on bf16 inputs against
+    ``_reference_attention`` on the same values in float32: the error norm
+    stays under 2^-8 of the result's norm, and no element lies further off
+    than 2^-7 of the largest."""
+    got, want = _bf16_against_float32(width, mask)[which]
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    assert _rel_l2(got, want) < BF16_REL_L2
+    worst = np.abs(np.asarray(got, np.float32) - np.asarray(want)).max()
+    assert worst < 2 * BF16_REL_L2 * np.abs(np.asarray(want)).max()
+
+
+# ---------------------------------------------------------------------------
+# what the operands' dtype decides besides the dots: the blocks past one lane
+# tile, and the blocks a step outside the band names
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype,d_qk,blocks", [
+    ("bfloat16", 192, 1024), ("float16", 192, 1024), ("float32", 192, 512),
+    ("bfloat16", 128, 1024), ("float32", 128, 1024)])
+def test_blocks_past_one_lane_tile_follow_the_operand_width(devices, dtype,
+                                                            d_qk, blocks):
+    """A query-key width past 128 is held to blocks of 512 only where the
+    blocks are float32 (at 1024 they pass the scoped VMEM beside the
+    kernel's float32 tiles); 16-bit blocks take the caller's 1024, as every
+    dtype does at a width of 128.  Read off the event a traced call leaves."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    qk = jax.ShapeDtypeStruct((1, 2048, 2, d_qk), jnp.dtype(dtype))
+    v = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.dtype(dtype))
+    tracer.clear()
+    jax.eval_shape(lambda q_, k_, v_: flash_attention(q_, k_, v_), qk, qk, v)
+    event, = [s.attrs for s in tracer.spans()
+              if s.name == "kernel/flash_attention_tiles"]
+    assert (event["block_q"], event["block_k"]) == (blocks, blocks)
+    assert event["operand_dtype"] == dtype and "fallback" not in event
+
+
+@pytest.mark.parametrize("window", [0, 40, 64, 200])
+@pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 32), (32, 128)])
+def test_a_step_outside_the_band_names_the_bands_edge(block_q, block_k,
+                                                      window):
+    """The index maps hold a skipped step's block at the nearest block its
+    row (the dK/dV kernel: its column) of tiles keeps, so consecutive skipped
+    steps name one block and nothing is fetched for them; a step inside the
+    band names its own block."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _kv_block_in_band, _q_block_in_band, _tile_in_band)
+
+    seq = 512
+    nq, nk = seq // block_q, seq // block_k
+    kept = np.array([[bool(_tile_in_band(iq * block_q, ik * block_k, block_q,
+                                         block_k, True, window))
+                      for ik in range(nk)] for iq in range(nq)])
+    assert kept.any(axis=1).all() and kept.any(axis=0).all()
+    for iq in range(nq):
+        inside = np.flatnonzero(kept[iq])
+        for ik in range(nk):
+            held = int(_kv_block_in_band(iq, ik, block_q, block_k, True,
+                                         window))
+            assert held == int(np.clip(ik, inside[0], inside[-1]))
+    for ik in range(nk):
+        inside = np.flatnonzero(kept[:, ik])
+        for iq in range(nq):
+            held = int(_q_block_in_band(iq, ik, block_q, block_k, True,
+                                        window))
+            assert held == int(np.clip(iq, inside[0], inside[-1]))
+
+
+def test_without_a_band_every_step_names_its_own_block():
+    from deepspeed_tpu.ops.pallas.flash_attention import (_kv_block_in_band,
+                                                          _q_block_in_band)
+
+    for i in range(4):
+        for j in range(4):
+            assert _kv_block_in_band(i, j, 64, 32, False, 0) == j
+            assert _q_block_in_band(i, j, 64, 32, False, 0) == i

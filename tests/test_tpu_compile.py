@@ -363,50 +363,75 @@ def test_prefill_attention_compiles_at_any_budget(one_chip, mosaic, tokens,
     assert _pool_passes(text, (layers, blocks, bs, KV, D)) == []
 
 
+def _flash_events(passes, dtype) -> list:
+    """The ``kernel/flash_attention_tiles`` events of the compile just made:
+    one a pass, none a fallback, each naming the dtype its dots multiply
+    (the caller's: float32 sums, the operands as they were handed in)."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    events = [s.attrs for s in tracer.spans()
+              if s.name == "kernel/flash_attention_tiles"]
+    assert {e["pass"] for e in events} == passes
+    assert all(e["operand_dtype"] == jnp.dtype(dtype).name
+               and "fallback" not in e for e in events)
+    return events
+
+
+_FLASH_KERNELS = ["flash_attention_fwd", "flash_attention_bwd_dkv",
+                  "flash_attention_bwd_dq"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
-def test_flash_attention_window_compiles(one_chip, mosaic, grad):
+def test_flash_attention_window_compiles(one_chip, mosaic, grad, dtype):
+    from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-    q = _sds((2, 2048, H, D), jnp.bfloat16, one_chip)
-    kv = _sds((2, 2048, KV, D), jnp.bfloat16, one_chip)
+    q = _sds((2, 2048, H, D), dtype, one_chip)
+    kv = _sds((2, 2048, KV, D), dtype, one_chip)
     fn = functools.partial(flash_attention, causal=True, window=4096)
     if grad:
         fn = jax.grad(lambda q_, k_, v_: flash_attention(
             q_, k_, v_, causal=True, window=4096).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))
-    _compile(fn, q, kv, kv, kernels=[
-        "flash_attention_fwd", "flash_attention_bwd_dkv",
-        "flash_attention_bwd_dq"] if grad else ["flash_attention_fwd"])
+    tracer.clear()
+    _compile(fn, q, kv, kv,
+             kernels=_FLASH_KERNELS if grad else _FLASH_KERNELS[:1])
+    _flash_events({"fwd", "dkdv", "dq"} if grad else {"fwd"}, dtype)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
-def test_flash_attention_at_unequal_widths_compiles(one_chip, mosaic, grad):
+def test_flash_attention_at_unequal_widths_compiles(one_chip, mosaic, grad,
+                                                    dtype):
     """Latent attention's expanded form at DeepSeek-V2-Lite's sizes: 16
     heads, a query-key width of 192 (one and a half lane tiles) beside a
-    value width of 128, two rows of 8,192; blocks of 512 (at 1,024 the dK/dV
-    kernel passes the scoped VMEM), and ``v`` is not padded."""
+    value width of 128, two rows of 8,192, and ``v`` is not padded.  bfloat16
+    is what the cell trains in: its blocks stay bfloat16 in VMEM and 1,024
+    fit; a float32 caller's are held to 512 (at 1,024 the dK/dV kernel passes
+    the scoped VMEM)."""
     from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-    qk = _sds((2, 8192, 16, 192), jnp.bfloat16, one_chip)
-    v = _sds((2, 8192, 16, 128), jnp.bfloat16, one_chip)
+    qk = _sds((2, 8192, 16, 192), dtype, one_chip)
+    v = _sds((2, 8192, 16, 128), dtype, one_chip)
     fn = functools.partial(flash_attention, causal=True, sm_scale=0.1147)
     if grad:
         fn = jax.grad(lambda q_, k_, v_: flash_attention(
             q_, k_, v_, causal=True, sm_scale=0.1147
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2))
     tracer.clear()
-    text = _compile(fn, qk, qk, v, kernels=[
-        "flash_attention_fwd", "flash_attention_bwd_dkv",
-        "flash_attention_bwd_dq"] if grad else ["flash_attention_fwd"])
-    assert "bf16[2,16,8192,128]" in text  # the output is 128 wide
-    events = [s.attrs for s in tracer.spans()
-              if s.name == "kernel/flash_attention_tiles"]
-    assert {e["pass"] for e in events} == (
-        {"fwd", "dkdv", "dq"} if grad else {"fwd"})
+    text = _compile(fn, qk, qk, v,
+                    kernels=_FLASH_KERNELS if grad else _FLASH_KERNELS[:1])
+    # the output is 128 wide
+    assert f"{'bf16' if dtype == jnp.bfloat16 else 'f32'}[2,16,8192,128]" \
+        in text
+    events = _flash_events({"fwd", "dkdv", "dq"} if grad else {"fwd"}, dtype)
+    block = 1024 if dtype == jnp.bfloat16 else 512
     assert all((e["d_qk"], e["d_v"], e["block_q"], e["block_k"])
-               == (192, 128, 512, 512) and "fallback" not in e
-               for e in events)
+               == (192, 128, block, block) for e in events)
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
