@@ -37,23 +37,50 @@ Four named scopes, which a device trace is reduced by:
                       softmax.  MASKED, not gathered: a gather of 2,048 keys
                       a query moves 2.4 MB a query (6.2 ms for 128 queries on
                       a v5e, gather-bound), where the masked pass over 8,192
-                      keys takes 1.4 ms for the same 128: the keys of a row
-                      are fetched once a tile, not once a query.  Past about
-                      32k of context the gathered form would win.
+                      keys takes 1.3 ms for the same 128: the keys of a row
+                      are fetched once for many queries, not once a query.
+                      Past about 32k of context the gathered form would win.
 
-All of it is XLA under these scopes: the chip's compiler fuses the mask, the
-scale and the exponentials round the two matrix products of a chunk, and the
-measured tile runs at over half the MXU's peak (PERF.md section 6, PR 40).
+The indexer, the top-k and the decode rows are XLA under these scopes.  The
+prefill path is ONE Pallas kernel (``_prefill_kernel``, named as its scope:
+what ``paged_attention._prefill_kernel`` is for K/V heads, at one KV head, a
+group of all the heads, keys ``W`` wide and values the same rows' first
+``kv_lora_rank`` lanes).  The pool stays in HBM; the tiles, the block table
+and the rows' starts ride in as scalars.  A tile's queries are worked an
+ITEM at a time (``PrefillPick.sq`` queries, 64 at the served shapes): the
+item's key chunks (1,024 keys: sixteen blocks fetched through the table,
+double-buffered, the next item's first chunk and queries under this item's
+last) are read only up to the chunk that holds the item's last query, each
+meets the item's rows a ROW BLOCK at a time (``qb`` queries x heads = 512
+rows: scores, weights and both products of a block never leave VMEM), and
+the running maximum, sum and accumulator of all the item's rows stay in VMEM
+scratch for the whole key loop and are written out once, each query to its
+token, by DMA.  The selection rides in as what it does to a score (float32:
+0 for a picked key, ``_NEG`` for any other, so ``score + bias`` IS the masked
+score), a ``(queries, chunk)`` piece at a time, and is spread over the heads
+in VMEM.  The precisions are the XLA body's (``_latent_prefill_xla``, which
+the kernel gives way to where a pool's shapes forbid its DMAs, and which the
+tests hold it to): operands as stored, float32 products and sums, the scale
+on the float32 scores, the weights rounded to the pool's type for ``p . v``.
+Alone on a v5e at the served shapes a chunk of a tile takes 107 us where the
+MXU needs 98 (PERF.md section 6, PR 46); the XLA body took 145.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...observability.trace import tracer
+from . import backend
+from .paged_attention import _across, _layer_operand
 
 LANES = 128
 #: queries of one row a prefill tile holds
@@ -234,26 +261,18 @@ def latent_decode_attention(q_lat: jax.Array, pool: jax.Array,
         return o / jnp.where(l == 0.0, 1.0, l)
 
 
-def latent_prefill_attention(q_lat: jax.Array, pool: jax.Array,
-                             layer: jax.Array, tables: jax.Array,
-                             mask: jax.Array, tiles: Tiles,
-                             q_start: jax.Array, chunk_start: jax.Array, *,
-                             scale: float, latent: int, tq: int = TILE_Q
-                             ) -> jax.Array:
-    """The prefill rows' queries ``q_lat (T, H, W)`` (absorbed, flat) over the
-    keys ``mask (T, S)`` selects for each → ``(T, H, latent)`` float32; a
-    token no tile holds comes out zero.  A tile's ``tq x H`` rows meet the
-    row's keys a chunk at a time, up to the chunk that holds the tile's last
-    query."""
+def _latent_prefill_xla(q_lat: jax.Array, pool: jax.Array, layer: jax.Array,
+                        tables: jax.Array, mask: jax.Array, tiles: Tiles,
+                        q_start: jax.Array, chunk_start: jax.Array, *,
+                        scale: float, latent: int, tq: int = TILE_Q
+                        ) -> jax.Array:
+    """``latent_prefill_attention`` as XLA: what the kernel gives way to where
+    the pool's shapes forbid its fetches, and what the tests hold it to.  A
+    tile gathers its row's whole table and meets it a chunk at a time; a
+    tile's scores and sums are arrays of the program."""
     T, H, W = q_lat.shape
-    blocks = tables.shape[1]
-    bs = pool.shape[2]
-    S = blocks * bs
+    S = tables.shape[1] * pool.shape[2]
     kc = key_chunk(S)
-    # once a traced call, as ``kernel/paged_attention_prefill_tiles``
-    tracer.add_event("kernel/latent_attention_prefill_tiles", attrs={
-        "t": T, "heads": H, "w": W, "tq": tq, "key_chunk": kc, "s_max": S,
-        "form": "absorbed, masked"})
     qp = jnp.pad(q_lat, ((0, tq), (0, 0), (0, 0)))
     mp = jnp.pad(mask, ((0, tq), (0, 0)))
     slot = jnp.arange(tq)
@@ -294,10 +313,339 @@ def latent_prefill_attention(q_lat: jax.Array, pool: jax.Array,
         return jax.lax.dynamic_update_slice(
             out, jnp.where((slot < cnt)[:, None, None], o, old), (t0, 0, 0))
 
+    out = jax.lax.fori_loop(
+        0, tiles.n, tile, jnp.zeros((T + tq, H, latent), jnp.float32))
+    return out[:T]
+
+
+@dataclasses.dataclass(frozen=True)
+class PrefillPick:
+    """The static tiling of one ``latent_prefill_attention`` kernel call: a
+    tile's queries are worked ``sq`` at a time (an ITEM: one fetch of a key
+    chunk serves them all, and their sums stay in VMEM for the item's whole
+    key loop), an item's rows meet a chunk ``qb`` queries x heads at a time,
+    and a chunk is ``kb`` blocks of the pool."""
+    sq: int
+    qb: int
+    kb: int
+
+
+#: queries an item holds; rows (queries x heads) of one pair of products;
+#: keys a fetch.  Measured on the chip at the cell's shapes (PERF.md
+#: section 6, PR 46)
+_ITEM_Q, _BLOCK_ROWS = 64, 512
+
+
+def pick_prefill(heads: int, block_size: int, blocks: int, tq: int = TILE_Q
+                 ) -> PrefillPick:
+    """The one picker, of the call's static shapes: row blocks of about
+    ``_BLOCK_ROWS`` rows, items of ``_ITEM_Q`` queries in whole row blocks (no
+    more than a tile), and as many blocks a fetch as ``_MAX_KEY_CHUNK`` keys
+    hold, a power of two that divides the table (so no fetch runs past it)."""
+    qb = max(1, min(tq, _BLOCK_ROWS // heads))
+    sq = max(qb, min(tq, _ITEM_Q) // qb * qb)
+    kb = 1
+    while kb * 2 * block_size <= _MAX_KEY_CHUNK and blocks % (kb * 2) == 0:
+        kb *= 2
+    return PrefillPick(sq, qb, kb)
+
+
+def _prefill_kernel(layer_ref, tables_ref, q_start_ref, chunk_start_ref,
+                    row_ref, off_ref, cnt_ref, n_ref,  # scalar prefetch
+                    q_hbm, bias_hbm, pool_hbm, zeros_hbm,  # in HBM
+                    o_hbm,  # the output, ``zeros_hbm``'s buffer
+                    items_ref, q_buf, k_buf, bias_buf, m_ref, l_ref, acc_ref,
+                    o_buf, q_sem, k_sem, bias_sem, o_sem,  # scratch
+                    *, pick: PrefillPick, tq: int, scale: float, latent: int):
+    del zeros_hbm
+    T, H, W = q_hbm.shape
+    _, kb, bs, _ = k_buf.shape
+    sq, qb = pick.sq, pick.qb
+    kc, rb = kb * bs, qb * H
+    lanes = bias_buf.shape[-1]
+    layer = layer_ref[0]
+
+    # -- the items, in tile order, one column of ``items_ref`` each: (row,
+    # first token, queries held, key chunks up to the one that holds the
+    # item's last query)
+    def add_tile(w, n_items):
+        s, off, cnt = row_ref[w], off_ref[w], cnt_ref[w]
+
+        def add(u, at):
+            left = cnt - u * sq
+            held = jnp.minimum(left, sq)
+
+            @pl.when(left > 0)
+            def _add():
+                items_ref[0, at] = s
+                items_ref[1, at] = q_start_ref[s] + off + u * sq
+                items_ref[2, at] = held
+                items_ref[3, at] = jax.lax.div(
+                    chunk_start_ref[s] + off + u * sq + held + kc - 1, kc)
+
+            return at + (left > 0).astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, pl.cdiv(tq, sq), add, n_items)
+
+    n_items = jax.lax.fori_loop(0, n_ref[0], add_tile, jnp.int32(0))
+
+    def window(it):
+        """An item's ``sq`` flat tokens, held inside the step's: its queries
+        sit ``shift`` slots into them."""
+        t0 = items_ref[1, it]
+        w0 = jnp.minimum(t0, T - sq)
+        return w0, t0 - w0, items_ref[2, it]
+
+    def q_copy(it, slot):
+        return pltpu.make_async_copy(q_hbm.at[pl.ds(window(it)[0], sq)],
+                                     q_buf.at[slot], q_sem.at[slot])
+
+    def key_copy(it, j, slot, c):
+        blk = tables_ref[items_ref[0, it], j * kb + c]
+        return pltpu.make_async_copy(pool_hbm.at[layer, blk],
+                                     k_buf.at[slot, c], k_sem.at[slot])
+
+    def bias_copy(it, j, slot):
+        return pltpu.make_async_copy(
+            bias_hbm.at[pl.ds(window(it)[0], sq), j], bias_buf.at[slot],
+            bias_sem.at[slot])
+
+    def fetch(it, j, slot):
+        """Start the DMAs of item ``it``'s ``j``-th key chunk, through the
+        row's table, and of its queries' piece of the selection."""
+        def one(c, _):
+            key_copy(it, j, slot, c).start()
+            return 0
+
+        jax.lax.fori_loop(0, kb, one, 0)
+        bias_copy(it, j, slot).start()
+
+    def await_fetch(slot):
+        def one(c, _):
+            key_copy(0, 0, slot, c).wait()  # a wait reads the size alone
+            return 0
+
+        jax.lax.fori_loop(0, kb, one, 0)
+        bias_copy(0, 0, slot).wait()
+
+    def out_copies(it, start: bool):
+        """An item's queries, each to its token of the output: the slots
+        that hold none of its queries are written nowhere."""
+        w0, shift, cnt = window(it)
+
+        def one(i, _):
+            @pl.when((i >= shift) & (i < shift + cnt))
+            def _held():
+                dma = pltpu.make_async_copy(o_buf.at[i], o_hbm.at[w0 + i],
+                                            o_sem.at[0])
+                dma.start() if start else dma.wait()
+
+            return 0
+
+        jax.lax.fori_loop(0, sq, one, 0)
+
+    def spread(slot, b):
+        """Row block ``b``'s piece of the selection, one value a (query, key),
+        over the heads: ``(qb x H, kc)``."""
+        return jnp.concatenate([
+            jnp.concatenate([
+                jnp.broadcast_to(bias_buf[slot, b * qb + i, pl.ds(c, 1), :],
+                                 (H, lanes)) for c in range(kc // lanes)],
+                axis=1) for i in range(qb)], axis=0)
+
+    def run_item(it, g):
+        """One item against its row's keys; ``g`` counts the fetches (the DMA
+        slots alternate)."""
+        _, shift, cnt = window(it)
+        n_chunks = items_ref[3, it]
+        q_slot = jax.lax.rem(it, 2)
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def step(j, g):
+            slot = jax.lax.rem(g, 2)
+            more = j + 1 < n_chunks
+            another = it + 1 < n_items
+
+            # the next chunk, or the next item's first and its queries
+            @pl.when(more | another)
+            def _prefetch():
+                fetch(jnp.where(more, it, it + 1), jnp.where(more, j + 1, 0),
+                      1 - slot)
+
+            @pl.when(jnp.logical_not(more) & another)
+            def _next_queries():
+                q_copy(it + 1, 1 - q_slot).start()
+
+            await_fetch(slot)
+
+            @pl.when(j == 0)
+            def _queries():
+                q_copy(it, q_slot).wait()
+
+            def block(b, _):
+                # a row block that holds a query of the item
+                @pl.when(((b + 1) * qb > shift) & (b * qb < shift + cnt))
+                def _held():
+                    q = q_buf[q_slot, pl.ds(b * qb, qb)].reshape(rb, W)
+                    k = k_buf[slot].reshape(kc, W)
+                    # operands as they are stored, float32 products, the
+                    # scale on the float32 scores; a key the query did not
+                    # pick lands on ``_NEG`` exactly
+                    sc = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32
+                    ) * scale + spread(slot, b)
+                    m_prev = m_ref[b]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(sc, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    # a row with no key yet has ``m_new`` at ``_NEG``:
+                    # against a bound above it every weight is zero
+                    p = jnp.exp(sc - _across(jnp.maximum(m_new, 0.1 * _NEG),
+                                             kc))
+                    l_ref[b] = l_ref[b] * alpha + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    pv = jnp.dot(p.astype(k.dtype), k[:, :latent],
+                                 preferred_element_type=jnp.float32)
+                    acc_ref[b] = acc_ref[b] * _across(alpha, latent) + pv
+                    m_ref[b] = m_new
+
+                return 0
+
+            jax.lax.fori_loop(0, sq // qb, block, 0)
+            return g + 1
+
+        g = jax.lax.fori_loop(0, n_chunks, step, g)
+
+        # the item before's output has had this item's key loop to leave
+        @pl.when(it > 0)
+        def _written():
+            out_copies(it - 1, start=False)
+
+        def finish(b, _):
+            l = l_ref[b][:, :1]
+            o_buf[pl.ds(b * qb, qb)] = (
+                acc_ref[b] / jnp.where(l == 0.0, 1.0, l)
+            ).reshape(qb, H, latent)
+            return 0
+
+        jax.lax.fori_loop(0, sq // qb, finish, 0)
+        out_copies(it, start=True)
+        return g
+
+    @pl.when(n_items > 0)
+    def _start_first():
+        fetch(0, 0, 0)
+        q_copy(0, 0).start()
+
+    jax.lax.fori_loop(0, n_items, run_item, jnp.int32(0))
+
+    @pl.when(n_items > 0)
+    def _written():
+        out_copies(n_items - 1, start=False)
+
+
+@functools.partial(jax.jit, static_argnames=("pick", "tq", "scale", "latent",
+                                             "interpret"))
+def _prefill_pallas(q_lat, pool, layer, tables, mask, tiles: Tiles, q_start,
+                    chunk_start, *, pick: PrefillPick, tq: int, scale: float,
+                    latent: int, interpret: bool):
+    """The kernel's call, under a jit of its own: the step program's nine
+    layers trace and lower it once (as ``paged_attention._prefill_pallas``)."""
+    T, H, W = q_lat.shape
+    bs = pool.shape[2]
+    S = tables.shape[1] * bs
+    sq, qb, kc = pick.sq, pick.qb, pick.kb * bs
+    lanes = math.gcd(kc, LANES)
+    pad = max(sq - T, 0)  # an item's window of tokens lies inside the step's
+    # the selection as what a score is moved by, a chunk's piece of a query
+    # in whole (8, 128) tiles: any token and any chunk is a slice a DMA makes
+    bias = jnp.where(jnp.pad(mask, ((0, pad), (0, 0))), 0.0, _NEG
+                     ).astype(jnp.float32).reshape(
+                         T + pad, S // kc, kc // lanes, lanes)
+    anywhere = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=8,
+        grid=(1,),
+        in_specs=[anywhere] * 4,
+        out_specs=anywhere,
+        scratch_shapes=[
+            pltpu.SMEM((4, tiles.row.shape[0] * pl.cdiv(tq, sq)), jnp.int32),
+            pltpu.VMEM((2, sq, H, W), q_lat.dtype),
+            pltpu.VMEM((2, pick.kb, bs, W), pool.dtype),
+            pltpu.VMEM((2, sq, kc // lanes, lanes), jnp.float32),
+            pltpu.VMEM((sq // qb, qb * H, LANES), jnp.float32),
+            pltpu.VMEM((sq // qb, qb * H, LANES), jnp.float32),
+            pltpu.VMEM((sq // qb, qb * H, latent), jnp.float32),
+            pltpu.VMEM((sq, H, latent), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, pick=pick, tq=tq, scale=scale,
+                          latent=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T + pad, H, latent), jnp.float32),
+        # a token no item holds comes out zero
+        input_output_aliases={11: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name="latent_attention_prefill",
+    )(_layer_operand(layer), tables, q_start, chunk_start,
+      tiles.row, tiles.off, tiles.cnt, tiles.n.reshape(1),
+      jnp.pad(q_lat, ((0, pad), (0, 0), (0, 0))), bias, pool,
+      jnp.zeros((T + pad, H, latent), jnp.float32))
+    return out[:T] if pad else out
+
+
+def latent_prefill_attention(q_lat: jax.Array, pool: jax.Array,
+                             layer: jax.Array, tables: jax.Array,
+                             mask: jax.Array, tiles: Tiles,
+                             q_start: jax.Array, chunk_start: jax.Array, *,
+                             scale: float, latent: int, tq: int = TILE_Q
+                             ) -> jax.Array:
+    """The prefill rows' queries ``q_lat (T, H, W)`` (absorbed, flat) over the
+    keys ``mask (T, S)`` selects for each → ``(T, H, latent)`` float32; a
+    token no tile holds comes out zero.  A tile's ``tq x H`` rows meet the
+    row's keys a chunk at a time, up to the chunk that holds the tile's last
+    query (see the module text)."""
+    T, H, W = q_lat.shape
+    blocks = tables.shape[1]
+    bs = pool.shape[2]
+    S = blocks * bs
+    pick = pick_prefill(H, bs, blocks, tq)
+    kc = pick.kb * bs
+    # what Mosaic's DMAs slice: whole lanes of a pool row and of a chunk's
+    # piece of the selection, whole sublane groups of a block
+    fallback = not backend.interpret() and bool(
+        W % LANES or kc % LANES or bs % (32 // pool.dtype.itemsize))
+    # once a traced call, as ``kernel/paged_attention_prefill_tiles``
+    tracer.add_event("kernel/latent_attention_prefill_tiles", attrs={
+        "t": T, "heads": H, "w": W, "tq": tq, "s_max": S,
+        **({"fallback": 1, "key_chunk": key_chunk(S),
+            "form": "absorbed, masked"} if fallback else
+           {"key_chunk": kc, "form": "absorbed, masked, pallas",
+            "sq": pick.sq, "qb": pick.qb, "kb": pick.kb})})
     with jax.named_scope("latent_attention_prefill"):
-        out = jax.lax.fori_loop(
-            0, tiles.n, tile, jnp.zeros((T + tq, H, latent), jnp.float32))
-        return out[:T]
+        if fallback:
+            backend.warn_fallback(
+                "latent_prefill_attention",
+                f"pool width {W} or key chunk {kc} is not a multiple of "
+                f"{LANES}, or block_size={bs} is not whole sublane groups "
+                f"(Mosaic DMA slice alignment)")
+            return _latent_prefill_xla(
+                q_lat, pool, layer, tables, mask, tiles, q_start,
+                chunk_start, scale=scale, latent=latent, tq=tq)
+        return _prefill_pallas(
+            q_lat, pool, layer, tables, mask, tiles, q_start, chunk_start,
+            pick=pick, tq=tq, scale=scale, latent=latent,
+            interpret=backend.interpret())
 
 
 def rows_as_mask(idx: jax.Array, ok: jax.Array, s_max: int) -> jax.Array:

@@ -982,8 +982,12 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     tile_m = 16 if program == "decode_step" else 128
     assert grouped == {(6144, 2048, 2048, 1024, tile_m),
                        (2048, 6144, 3072, 512, tile_m)}
-    assert ("kernel/latent_attention_prefill_tiles" in
-            {name for name, _ in events}) == (program == "mixed_step")
+    tiled = [a for name, a in events
+             if name == "kernel/latent_attention_prefill_tiles"]
+    assert bool(tiled) == (program == "mixed_step")
+    for a in tiled:  # the kernel engaged: items of 64 queries, 1,024 keys
+        assert (a["form"], a["sq"], a["qb"], a["kb"], a["key_chunk"]) == (
+            "absorbed, masked, pallas", 64, 8, 16, 1024), a
     text = lowered.as_text(debug_info=True)
     scopes = ["grouped_mixed_gemm", "mixed_gemm", "moe_route", "moe_dispatch",
               "moe_experts", "moe_combine", "moe_shared", "dsa_index_scores",
@@ -999,6 +1003,11 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     compiled_text = compiled.as_text()
     for pool in pools:
         assert _pool_passes(compiled_text, pool) == []
+    # the prefill path is a kernel of the program, under its own name: a
+    # tile's scores (32 MB) and accumulator (16 MB) are no buffers of it
+    assert bool(re.search(
+        r"%latent_attention_prefill[.\d]* = .*custom_call_target="
+        r'"tpu_custom_call"', compiled_text)) == (program == "mixed_step")
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p)) for p in pools)
     assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
