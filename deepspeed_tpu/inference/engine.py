@@ -24,6 +24,7 @@ from ..models import transformer as tfm
 from ..parallel.topology import MeshTopology
 from ..runtime.config import MeshConfig, load_config
 from ..runtime.zero.sharding import rules_for_params, sharding_for_tree
+from .v2.programs import KV, kind_of
 
 
 @dataclasses.dataclass
@@ -153,26 +154,15 @@ class InferenceEngine:
                 "tokens over the whole sequence) — autoregressive decode "
                 "with it is incoherent; serve experts trained with top-k "
                 "routing (dataclasses.replace(cfg, moe_routing='dropless'))")
-        if getattr(model_config, "mixer_pattern", ()):
+        kind = kind_of(model_config)
+        if (kind is not KV or len(model_config.layer_period) > 1
+                or model_config.rope_params):
             raise NotImplementedError(
-                "the v1 engine serves stacked attention + FFN layers with one "
-                "K/V cache; a model of one mixer a layer (mixer_pattern: "
-                "state-space layers with per-sequence state) is served by the "
-                "v2 engine (inference/v2), which keeps that state in slots "
-                "beside the paged K/V")
-        if getattr(model_config, "kv_lora_rank", 0):
-            raise NotImplementedError(
-                "the v1 engine keeps K and V heads in one cache; a model with "
-                "latent attention (kv_lora_rank > 0: a latent a token, a "
-                "learned selection of keys) is served by the v2 engine "
-                "(inference/v2), which keeps a latent pool and the "
-                "indexer's keys")
-        if len(model_config.layer_period) > 1 or model_config.rope_params:
-            raise NotImplementedError(
-                "the v1 engine serves one kind of attention layer with plain "
-                "RoPE; a model with layer_types or rope_params (window and "
-                "global layers, YaRN) is served by the v2 engine "
-                "(inference/v2), which keeps a cache for each kind")
+                "the v1 engine serves attention + FFN layers of one kind with "
+                f"plain RoPE over one K/V cache; it was handed {kind.name}"
+                + (" with layer_types or rope_params (window and global "
+                   "layers, YaRN)" if kind is KV else "")
+                + ", which the v2 engine (inference/v2) serves")
         self.model_config = dataclasses.replace(model_config, dtype=icfg.dtype)
         # a training engine in the same process may have pinned the tp×sp
         # gather anchors — they name mesh axes this engine's mesh lacks

@@ -31,15 +31,14 @@ from ...observability.trace import tracer
 from ...ops.pallas.latent_attention import TILE_Q
 # ``_decode_body`` and ``_memo`` are not used here: ``benchmark/logit_tap.py``
 # imports them from this module
-from .programs import (_decode_body, _memo, _with_stats,  # noqa: F401
-                       build_cow_copy, build_decode_forward,
+from .programs import (REFUSED, _decode_body, _memo,  # noqa: F401
+                       _with_stats, build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
-                       build_unpack, latent_arrays, layer_plan,
-                       mixed_step_attn_tiles, pool_layers, sample_rows,
-                       state_arrays)
+                       build_unpack, kind_of, layer_plan,
+                       mixed_step_attn_tiles, sample_rows)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder, SequenceDescriptor, StepLayout,
-                     decode_layout, window_bound)
+                     decode_layout)
 from .spec import build_draft_spec_step, build_self_draft_step
 
 
@@ -77,14 +76,11 @@ def _host_split(sp, cpu_entry, sp_dispatch, cpu_called, sp_wait, cpu_fetched
         "post_cpu_ms": (time.thread_time() - cpu_fetched) * 1e3}
 
 
-def _refuse_each(asked, because) -> None:
-    """Raise for the first ``(V2Config field, set?, why)`` of ``asked`` that
-    is set: the engine's rule for what a kind of model cannot be combined
-    with is to refuse it by name."""
-    for name, on, why in asked:
-        if on:
-            raise ValueError(
-                f"V2Config.{name} cannot be combined with {because(why)}")
+def _live_rows(start: "np.ndarray", n: "np.ndarray"):
+    """Of all the rows the host holds (``n``: a row's tokens this step, or
+    whether it takes its one), those in the step: first position, tokens."""
+    live = n > 0
+    return start[live].astype(np.int64), n[live].astype(np.int64)
 
 
 class AdmissionError(ValueError):
@@ -231,58 +227,41 @@ class InferenceEngineV2:
                     "only — the separate draft model has no adapter stack "
                     "to stay consistent with per-row deltas")
             self.adapter_stack = init_adapter_stack(self.model_cfg, self.cfg)
-        # The layers' kinds (models/transformer.py: layer_types, the window
-        # of the sliding ones) decide the pools: one for a model with one
-        # kind of layer; with both, ``kv`` holds the global layers' blocks
-        # and ``kv_win`` the window layers', whose blocks go back to their
-        # pool as they fall behind the window (ragged.KVCacheManager).  A
-        # model whose every layer is windowed has the one pool, windowed.
-        plan = layer_plan(self.model_cfg, self.cfg)
-        self._window = max(kind.window for kind in plan)  # 0: none active
-        pools = pool_layers(self.model_cfg, self.cfg)
-        if self._window:
-            self._refuse_with_window()
-        # A model with state layers (Mamba-2) keeps per-SEQUENCE state beside
-        # the paged K/V: one slot a sequence in ``caches["ssm"]`` / ``["conv"]``
-        # (programs.state_arrays), allotted by the one manager.  {} for every
-        # other model, which builds no state array and no slot allocator
-        state = state_arrays(self.model_cfg, self.cfg)
-        if self.model_cfg.mixer_pattern and not (state and pools[0]):
-            raise NotImplementedError(
-                "a mixer_pattern model is served with at least one Mamba-2 "
-                "and one attention layer")
-        if state:
-            self._refuse_with_state()
-        # A model with latent attention keeps, in place of K and V heads, one
-        # latent a token a layer and the indexer's key of the layers that
-        # pick (programs.latent_arrays): two pools sized by kind behind the
-        # ONE block table and allocator (both grow with the context)
-        latent = latent_arrays(self.model_cfg, self.cfg)
-        if latent:
-            self._refuse_with_latent()
+        # The model's KIND (programs.ServedKind) says what it caches, and the
+        # arrays it declares what the managers need.  ``k_win``: window AND
+        # global layers keep a pool each: ``kv`` holds the global layers'
+        # blocks and ``kv_win`` the window layers', which go back to their
+        # pool as they fall behind the window (ragged.KVCacheManager); where
+        # every layer is windowed the one pool is.  ``ssm``: per-SEQUENCE
+        # state beside the paged K/V, a slot a sequence from the one manager.
+        # A latent model's two pools share the ONE block table and allocator
+        self.kind = kind_of(self.model_cfg)
+        arrays = self.kind.arrays(self.model_cfg, self.cfg)
+        two_pools = "k_win" in arrays
+        state_slots = self.cfg.max_seqs if "ssm" in arrays else 0
+        self._window = max(k.window for k in  # 0: none active
+                           layer_plan(self.model_cfg, self.cfg))
+        self._refuse()
         max_chunk = self.cfg.max_tokens_per_step
         # one block of each pool reserved as write-scratch for padded tokens
         self.kv = KVCacheManager(
             self.cfg.num_blocks - 1, self.cfg.block_size,
             self.cfg.max_blocks_per_seq,
-            window=self._window if len(pools) == 1 else 0,
+            window=0 if two_pools else self._window,
             max_chunk=max_chunk,
-            state_slots=self.cfg.max_seqs if state else 0)
+            state_slots=state_slots)
         self.kv_win = None
-        if len(pools) == 2:
-            win_blocks = self.cfg.num_window_blocks or (
-                1 + self.cfg.max_seqs * window_bound(
-                    self._window, max_chunk, self.cfg.block_size,
-                    self.cfg.max_blocks_per_seq))
+        if two_pools:
             self.kv_win = KVCacheManager(
-                win_blocks - 1, self.cfg.block_size,
+                arrays["k_win"][0][1] - 1, self.cfg.block_size,
                 self.cfg.max_blocks_per_seq, window=self._window,
                 max_chunk=max_chunk, chain="win_")
         self._managers = [self.kv] + ([self.kv_win] if self.kv_win else [])
         # the manager whose blocks are freed behind the window, if any, and
         # how many layers read a window (the step's counters)
         self._windowed = (self.kv_win or self.kv) if self._window else None
-        self._win_layers = pools[-1] if self._window else 0
+        self._win_layers = (arrays["k_win" if two_pools else "k"][0][0]
+                            if self._window else 0)
         self.prefix_cache = None
         self._cow_copy = None
         self.pager = None
@@ -313,8 +292,7 @@ class InferenceEngineV2:
                                           self.cfg.max_seqs,
                                           self.cfg.max_blocks_per_seq,
                                           two_pools=self.kv_win is not None,
-                                          state_scratch=self.cfg.max_seqs
-                                          if state else -1,
+                                          state_scratch=state_slots or -1,
                                           adapters=self.adapter_stack
                                           is not None)
         # A step's host inputs reach the device in ONE copy: each kind of
@@ -336,46 +314,21 @@ class InferenceEngineV2:
         self._staged: Optional[Tuple[Any, Dict[str, jax.Array], Any]] = None
         self._stage_use: Optional[str] = None
         self._stage_dropped = 0
-        dt = jnp.dtype(self.cfg.dtype)
-
-        def pool(layers, blocks):
-            return jnp.zeros((layers, blocks, self.cfg.block_size,
-                              self.model_cfg.kv_heads,
-                              self.model_cfg.head_dim), dt)
-
-        if latent:
-            self.caches = {name: jnp.zeros(shape, dt)
-                           for name, shape in latent.items()}
-        else:
-            self.caches = {"k": pool(pools[0], self.cfg.num_blocks),
-                           "v": pool(pools[0], self.cfg.num_blocks)}
-        self._latent = bool(latent)
-        self._latent_step = None
-        if self.kv_win is not None:
-            self.caches["k_win"] = pool(pools[1], win_blocks)
-            self.caches["v_win"] = pool(pools[1], win_blocks)
-        for name, (shape, dtype) in state.items():
-            self.caches[name] = jnp.zeros(shape, dtype)
+        self.caches = {name: jnp.zeros(shape, dtype)
+                       for name, (shape, dtype) in arrays.items()}
         # SSM state bytes a row reads and writes a step, all state layers
-        self._state_row_bytes = 0
-        if state:
-            shape, dtype = state["ssm"]
-            self._state_row_bytes = 2 * shape[0] * int(
-                np.prod(shape[2:])) * jnp.dtype(dtype).itemsize
-        self._state_step = None
+        self._state_row_bytes = (
+            2 * self.caches["ssm"].nbytes // (state_slots + 1)
+            if state_slots else 0)
+        # what the kind counts of a step, and the step's under way
+        self._count = getattr(self, self.kind.counters)
+        self._step_counts: Optional[Dict[str, Any]] = None
         self._fwd = build_ragged_forward(self.model_cfg, self.cfg)
         self._decode_fwd = build_decode_forward(self.model_cfg, self.cfg)
         # an MoE model's step programs: assignments (rows x top-k) and the
         # rows of the grouped layout they are computed on, by step kind
         self._moe_rows: Dict[str, Tuple[int, int]] = {}
-        # the layers that route (a mixer-pattern model: its "E" layers)
-        self._moe_layers = (self.model_cfg.layers_of("E")
-                            if self.model_cfg.mixer_pattern
-                            else self.model_cfg.num_layers)
-        if latent:
-            from ...models.latent_sparse import layers_of
-
-            self._moe_layers = layers_of(self.model_cfg, "S")
+        self._moe_layers = self.kind.moe_layers(self.model_cfg)
         # a layer that holds a SHARE of its experts reports a third stat,
         # the assignments that were local
         self._moe_share = (self.model_cfg.experts_held
@@ -401,10 +354,8 @@ class InferenceEngineV2:
             self.cfg.max_blocks_per_seq * self.cfg.block_size,
             two_pools=self.kv_win is not None,
             main_grows=self._windowed is self.kv)
-        self._kv_step = None  # a windowed model's block counters of a step
         # the mixed step's prefill attention tiling, for ``attn_q_slots``
         self._attn_tiles = mixed_step_attn_tiles(self.model_cfg, self.cfg)
-        self._attn_q_slots = None  # of a mixed step that ran the device
         self._prefilling = 0  # running seqs still before their first token
         self.steps = 0  # step() calls so far: the spans' ``step``
         self.fast_steps = 0  # telemetry: SoA decode steps taken
@@ -452,100 +403,24 @@ class InferenceEngineV2:
             dshape = (self.draft_cfg.num_layers, self.cfg.num_blocks,
                       self.cfg.block_size, self.draft_cfg.kv_heads,
                       self.draft_cfg.head_dim)
+            dt = jnp.dtype(self.cfg.dtype)
             self._draft_caches = {"k": jnp.zeros(dshape, dt),
                                   "v": jnp.zeros(dshape, dt)}
             self._draft_fwd = build_ragged_forward(self.draft_cfg, self.cfg)
             self._spec_fwd = build_draft_spec_step(
                 self.model_cfg, self.draft_cfg, self.cfg)
 
-    def _refuse_with_window(self) -> None:
-        """What moves KV bytes by block id and does not know that a window
-        layer's blocks go back to their pool while the sequence runs (or
-        that there are two pools): refused by name, never served wrong."""
-        cfg = self.cfg
-        asked = [
-            ("enable_prefix_cache", cfg.enable_prefix_cache,
-             "the prefix cache (with it prefix export / import and "
-             "copy-on-write forks) shares a finished sequence's blocks"),
-            ("kv_host_pool_mb / kv_host_pool_bytes",
-             cfg.kv_host_pool_mb or cfg.kv_host_pool_bytes,
-             "the host paging tier demotes and promotes prefix blocks"),
-            ("kv_spill_dir", cfg.kv_spill_dir,
-             "the spill tier holds demoted prefix blocks"),
-            ("kv_coldstore_dir", cfg.kv_coldstore_dir,
-             "the cold store holds demoted prefix blocks"),
-            ("spec_mode", cfg.spec_mode != "off",
-             "speculation writes k tokens ahead of the context (and the "
-             "draft model's cache shares the target's block tables)"),
-        ]
-        _refuse_each(asked, lambda why: (
-            f"a model whose attention layers have an active sliding window "
-            f"({self._window} < the engine's longest context): {why}, and a "
-            f"window layer's blocks are freed behind the window while the "
-            f"sequence runs"))
-
-    def _refuse_with_state(self) -> None:
-        """What shares, moves or rolls back a sequence's past by its K/V
-        blocks alone: with state layers a shared prefix, a demoted block or a
-        rejected speculation would need a SNAPSHOT of the sequence's state at
-        that position, which nothing keeps yet.  Refused by name, never
+    def _refuse(self) -> None:
+        """What this kind of model cannot be combined with yet
+        (``programs.REFUSED``, the kind's rows): refused by name, never
         served wrong."""
-        cfg = self.cfg
-        asked = [
-            ("enable_prefix_cache", cfg.enable_prefix_cache,
-             "the prefix cache (with it prefix export / import and "
-             "copy-on-write forks) starts a sequence behind a shared prefix, "
-             "where its state layers have no state to start from"),
-            ("kv_host_pool_mb / kv_host_pool_bytes",
-             cfg.kv_host_pool_mb or cfg.kv_host_pool_bytes,
-             "the host paging tier demotes and promotes prefix blocks"),
-            ("kv_spill_dir", cfg.kv_spill_dir,
-             "the spill tier holds demoted prefix blocks"),
-            ("kv_coldstore_dir", cfg.kv_coldstore_dir,
-             "the cold store holds demoted prefix blocks"),
-            ("spec_mode", cfg.spec_mode != "off",
-             "speculation rolls rejected tokens back by masking their K/V "
-             "(and the draft model's cache has no state arrays); a state "
-             "cannot be rolled back without a snapshot"),
-            ("adapter_slots", cfg.adapter_slots,
-             "the adapter stack is laid out for one attention block a layer"),
-        ]
-        _refuse_each(asked, lambda why: (
-            f"a model that has state layers (mixer_pattern with 'M'): {why}"))
-
-    def _refuse_with_latent(self) -> None:
-        """What moves, shares or looks ahead in the cache by a K and a V
-        array of heads: every such byte-mover reads ``caches["k"]`` and
-        ``["v"]``, and a latent model has neither (its pools are a latent a
-        token and the indexer's key).  Refused by name, never served wrong."""
-        cfg = self.cfg
-        if not self.model_cfg.index_topk:
-            raise NotImplementedError(
-                "a latent model without an indexer (index_topk 0, no query "
-                "compression) is trained, not served: the absorbed step "
-                "programs (programs.latent_layers) read the indexer's stack "
-                "and the compressed query (ROADMAP R2b)")
-        asked = [
-            ("enable_prefix_cache", cfg.enable_prefix_cache,
-             "the prefix cache (with it prefix export / import and "
-             "copy-on-write forks) copies and shares K and V blocks"),
-            ("kv_host_pool_mb / kv_host_pool_bytes",
-             cfg.kv_host_pool_mb or cfg.kv_host_pool_bytes,
-             "the host paging tier demotes and promotes K and V blocks"),
-            ("kv_spill_dir", cfg.kv_spill_dir,
-             "the spill tier holds demoted K and V blocks"),
-            ("kv_coldstore_dir", cfg.kv_coldstore_dir,
-             "the cold store holds demoted K and V blocks"),
-            ("spec_mode", cfg.spec_mode != "off",
-             "the verify step attends over K and V heads (and neither a "
-             "draft model's cache nor the bolt-on heads share the "
-             "indexer's selection)"),
-            ("adapter_slots", cfg.adapter_slots,
-             "the adapter stack is laid out for q, k, v and o projections"),
-        ]
-        _refuse_each(asked, lambda why: (
-            f"a model with latent attention (kv_lora_rank > 0), whose pools "
-            f"hold a latent a token and the indexer's keys: {why}"))
+        for name in self.kind.refuses(self.model_cfg, self.cfg):
+            if any(getattr(self.cfg, f) != getattr(V2Config, f)
+                   for f in name.split(" / ")):
+                raise ValueError(
+                    f"V2Config.{name} cannot be combined with "
+                    + self.kind.because.format(does=REFUSED[name],
+                                               window=self._window))
 
     def _quantized(self, raw_params: Any) -> Any:
         """``raw_params`` as this engine serves them: untouched without
@@ -1286,14 +1161,26 @@ class InferenceEngineV2:
                             & (oldest % self.cfg.block_size == 0))[0]:
             m.trim(t.seq_at[int(r)], int(t.ctx[r]))
 
-    def _count_kv(self, start: "np.ndarray", n: "np.ndarray") -> None:
-        """A windowed model's step, from what the builder holds (``start`` /
-        ``n``: each row's first position and tokens this step): the K/V
+    # -- what a kind counts of a step (programs.ServedKind.counters): ``start``
+    # / ``n``, each row's first position and its tokens as the host holds them
+    # (the table's ``ctx`` / ``active``, the batch's ``chunk_start`` /
+    # ``chunk_len``) → attributes of the step's span
+
+    def _count_kv(self, start: "np.ndarray", n: "np.ndarray", mixed: bool
+                  ) -> Dict[str, Any]:
+        """A mixed step: the query slots the prefill kernel multiplies, a
+        row's tokens rounded up to its tiles.  A windowed model's: the K/V
         blocks its attention has to read, summed over rows and layers (a
         layer reads a row's blocks from the one that holds the oldest key
         its oldest query sees to the one of its newest token), what full
         attention on every layer would have read, and the (query, key)
-        pairs it multiplies."""
+        pairs it multiplies (``trimmed``: the blocks freed so far, which
+        ``step`` turns into the step's own)."""
+        counts = ({"attn_q_slots": int(self._attn_tiles.slots(n).sum())}
+                  if mixed else {})
+        if self._windowed is None:
+            return counts
+        start, n = _live_rows(start, n)
         bs, w = self.cfg.block_size, self._window
         last = -(-(start + n) // bs)  # blocks up to the row's newest token
         full = int(last.sum())
@@ -1302,35 +1189,38 @@ class InferenceEngineV2:
         cols = np.arange(int(n.max()))[None]
         seen = np.where(cols < n[:, None], start[:, None] + 1 + cols, 0)
         L, Lw = self.model_cfg.num_layers, self._win_layers
-        self._kv_step = {
-            "kv_blocks_read": win * Lw + full * (L - Lw),
-            "kv_blocks_full": full * L,
-            "kv_query_keys": int(np.minimum(seen, w).sum()) * Lw
+        return dict(
+            counts, kv_blocks_read=win * Lw + full * (L - Lw),
+            kv_blocks_full=full * L,
+            kv_query_keys=int(np.minimum(seen, w).sum()) * Lw
             + int(seen.sum()) * (L - Lw),
-            "trimmed": self._windowed.trimmed}
+            trimmed=self._windowed.trimmed)
 
-    def _count_state(self, rows: int, tokens: int, started: int,
-                     lens: Optional["np.ndarray"] = None) -> None:
-        """A state model's step, from what the host holds: the slots taken,
-        the rows that began from zeros, the tokens through the scan and the
-        state bytes the step reads and writes; of a mixed step (``lens``:
-        its rows' tokens) also what the chunked scan walks: the rows of two
+    def _count_state(self, start: "np.ndarray", n: "np.ndarray", mixed: bool
+                     ) -> Dict[str, Any]:
+        """A state model's step: the slots taken, the rows that began from
+        zeros, the tokens through the scan, the state bytes read and written;
+        of a mixed step also what the chunked scan walks: the rows of two
         tokens and more, their tokens and their pieces of a chunk."""
-        self._state_step = {
-            "state_slots_used": self.total_state_slots - self.free_state_slots,
-            "state_rows_started": started, "ssm_tokens": tokens,
-            "ssm_state_bytes": rows * self._state_row_bytes}
-        if lens is not None:
-            many = lens[lens >= 2]
+        counts = self._count_kv(start, n, mixed)
+        start, n = _live_rows(start, n)
+        counts.update(
+            state_slots_used=self.total_state_slots - self.free_state_slots,
+            state_rows_started=int((start == 0).sum()),
+            ssm_tokens=int(n.sum()),
+            ssm_state_bytes=len(n) * self._state_row_bytes)
+        if mixed:
+            many = n[n >= 2]
             chunk = self.model_cfg.mamba_chunk_size
-            self._state_step.update(
+            counts.update(
                 ssm_scan_rows=len(many), ssm_scan_tokens=int(many.sum()),
                 ssm_scan_pieces=int((-(-many // chunk)).sum()))
+        return counts
 
-    def _count_latent(self, start: "np.ndarray", n: "np.ndarray") -> None:
-        """A latent model's step, from what the host holds (``start`` /
-        ``n``: each row's first position and tokens this step) and the layer
-        pattern.  Summed over rows and layers: the (query, key) pairs before
+    def _count_latent(self, start: "np.ndarray", n: "np.ndarray", mixed: bool
+                      ) -> Dict[str, Any]:
+        """A latent model's step, with the layer pattern.  Summed over rows
+        and layers: the (query, key) pairs before
         the selection (a query at ``p`` sees ``p + 1`` keys) and after it
         (``index_topk`` of them at most), those after it again by the path
         that attends (``_single``: the rows of one token; ``_prefill``: the
@@ -1339,9 +1229,11 @@ class InferenceEngineV2:
         queries picked them: the smaller of its picks and its context); the
         pairs the layers that pick score and the indexer keys they read (a
         row's context once); the blocks the latent pool has out; the expert
-        assignments the routed layers make (the local ones come back with
-        the step)."""
+        assignments the routed layers make (of a share of the experts, the
+        local ones come back with the step: ``_split_stats``); of a mixed
+        step the query slots: the prefill tiles of a row, one a single row."""
         c = self.model_cfg
+        start, n = _live_rows(start, n)
         # every layer attends; the layers that pick hold the indexer's pool
         L, Lf = c.num_layers, self.caches["index"].shape[0]
         cols = np.arange(int(n.max(initial=0)))[None]
@@ -1350,7 +1242,7 @@ class InferenceEngineV2:
         read = np.minimum(picked, start + n)  # keys it cannot but read
         one = n == 1
         visible = int(seen.sum())
-        self._latent_step = {
+        counts = {
             "dsa_keys_visible": visible * L,
             "dsa_keys_selected": int(picked.sum()) * L,
             "dsa_selected_single": int(picked[one].sum()) * L,
@@ -1361,6 +1253,12 @@ class InferenceEngineV2:
             "dsa_index_keys": int((start + n).sum()) * Lf,
             "latent_blocks_used": self.total_blocks - self.free_blocks,
             "moe_assignments": int(n.sum()) * c.moe_top_k * self._moe_layers}
+        if self._moe_share:
+            counts["moe_assignments_local"] = None  # behind the step's tokens
+        if mixed:
+            counts["attn_q_slots"] = int(
+                (-(-n[~one] // TILE_Q) * TILE_Q).sum() + one.sum())
+        return counts
 
     def _row_temps(self, temperature: float) -> "np.ndarray":
         """Effective per-row temperature vector: rows whose request pinned a
@@ -1520,14 +1418,7 @@ class InferenceEngineV2:
         sp = tracer.begin("engine/h2d", **sub)
         if self._windowed is not None:
             self._window_open_blocks()
-            self._count_kv(t.ctx[t.active].astype(np.int64),
-                           np.ones(int(t.active.sum()), np.int64))
-        if self.kv.slots is not None:
-            self._count_state(int(t.active.sum()), int(t.active.sum()),
-                              int((t.active & (t.ctx == 0)).sum()))
-        if self._latent:
-            self._count_latent(t.ctx[t.active].astype(np.int64),
-                               np.ones(int(t.active.sum()), np.int64))
+        self._step_counts = self._count(t.ctx, t.active, False)
         # staged by the step before (the span then holds the check alone) or
         # copied here
         f = self._decode_inputs(temperature)
@@ -1564,8 +1455,8 @@ class InferenceEngineV2:
         n = self.cfg.max_seqs
         self._moe_stats = (float(fetched[n]) / self._moe_layers,
                            int(fetched[n + 1]))
-        if self._moe_share and self._latent_step is not None:
-            self._latent_step["moe_assignments_local"] = int(fetched[n + 2])
+        if "moe_assignments_local" in self._step_counts:
+            self._step_counts["moe_assignments_local"] = int(fetched[n + 2])
         return fetched[:n]
 
     def _spec_decode_step(self, temperature: float, rng: Optional[jax.Array],
@@ -1679,10 +1570,7 @@ class InferenceEngineV2:
         self.steps += 1
         sub = {"kind": kind, "step": self.steps}  # on the step and its children
         self._moe_stats = None
-        self._kv_step = None
-        self._state_step = None
-        self._latent_step = None
-        self._attn_q_slots = None
+        self._step_counts = None
         self._h2d = None
         self._stage_use = None
         if kind != "decode":  # what was staged for a decode step: dropped
@@ -1712,17 +1600,11 @@ class InferenceEngineV2:
         if self._stage_dropped:  # staged for this step and not what it needs
             attrs["stage_discarded"] = 1
             attrs["stage_bytes"] = self._stage_dropped
-        if self._attn_q_slots is not None:  # a mixed step ran the device
-            attrs["attn_q_slots"] = self._attn_q_slots
-        if self._state_step is not None:  # a state model's step ran the device
-            attrs.update(self._state_step)
-        if self._latent_step is not None:  # a latent model's step did
-            attrs.update(self._latent_step)
-        if self._kv_step is not None:  # a windowed model's step ran the device
+        if self._step_counts:  # the kind's, of a step that ran the device
+            attrs.update(self._step_counts)
+        if "trimmed" in attrs:  # a windowed model's: what the step freed
             m = self._windowed
-            attrs["window_blocks_freed"] = \
-                m.trimmed - self._kv_step.pop("trimmed")
-            attrs.update(self._kv_step)
+            attrs["window_blocks_freed"] = m.trimmed - attrs.pop("trimmed")
             used = [k.allocator.num_blocks - k.allocator.free_blocks
                     for k in self._managers]
             attrs["blocks_used_global"] = used[0] if m is not self.kv else 0
@@ -1769,32 +1651,15 @@ class InferenceEngineV2:
         tracer.end(sp)
         sp = tracer.begin("engine/h2d", **sub)
         f = self._to_device(self.builder.layout, batch.packed)
-        if self._windowed is not None:
-            self._count_kv(batch.chunk_start[:len(picks)].astype(np.int64),
-                           batch.chunk_len[:len(picks)].astype(np.int64))
-        # the query slots the prefill kernel multiplies: each row's tokens
-        # rounded up to its tiles (``tokens`` / this: the share that hold one)
-        if self._latent:
-            n = batch.chunk_len[:len(picks)].astype(np.int64)
-            self._count_latent(
-                batch.chunk_start[:len(picks)].astype(np.int64), n)
-            # the prefill path's tiles of one row, and a slot a single row
-            self._attn_q_slots = int(
-                (-(-n[n >= 2] // TILE_Q) * TILE_Q).sum() + (n == 1).sum())
-        else:
-            self._attn_q_slots = int(
-                self._attn_tiles.slots(batch.chunk_len).sum())
+        self._step_counts = self._count(batch.chunk_start, batch.chunk_len,
+                                        True)
         batch_args = (
             f["token_ids"], f["position_ids"], f["seq_index"],
             self._tables(f), f["context_lens"], f["logits_rows"],
             f["chunk_start"], f["chunk_len"])
-        if batch.state_slots is not None:  # a model with state layers
-            n = len(picks)
+        if batch.state_slots is not None:  # a model with state layers:
             # behind the two adapter arguments, which such a model never has
             batch_args += (None, None, f["state_slots"])
-            self._count_state(
-                n, tokens, int((batch.chunk_start[:n] == 0).sum()),
-                batch.chunk_len[:n])
         ad_args = ()
         if self.adapter_stack is not None:
             # the batch's rows are in picks order (seq_index indexes into
@@ -1894,11 +1759,9 @@ class InferenceEngineV2:
             # spec mode never bursts: the speculative step is already a
             # multi-token in-graph program with its own budget clamp
             # nor does a windowed pool: its tables are kept step by step
-            # nor a model with state layers: its counters are a step's
+            # nor a kind whose every step counts
             steady = (burst > 1 and self._spec_fwd is None
-                      and self._windowed is None
-                      and self.kv.slots is None
-                      and not self._latent
+                      and self._windowed is None and self.kind.bursts
                       and not self.waiting and self.running
                       and self._prefilling == 0)
             if steady:

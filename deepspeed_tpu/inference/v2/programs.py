@@ -1,13 +1,14 @@
 """The v2 engine's step programs: what is traced and runs on the chip.
 
-One serving decoder layer, ``serving_layers`` (with ``hybrid_layers`` for a
-model of one mixer a layer and ``latent_layers`` for one with latent
-attention behind it), under three callers: the mixed
-step (``build_ragged_forward``: chunks of prefill and decode tokens in one
-ragged batch), the decode step (``_decode_body``: one token a row) and the
-verify step of speculation (``spec.py:verify_body``: ``Q`` consecutive
-positions a row); its docstring is the contract of a caller.  The head is
-``tfm.lm_logits``.
+What KIND of model is served is decided once (``kind_of`` → ``ServedKind``:
+what it caches, how its layers run, what a step's rows tell them, what it
+is refused, what its step counts).  ``serving_layers`` is the kind's body
+(``attention_layers``, ``hybrid_layers``, ``latent_layers``) under three
+callers: the mixed step (``build_ragged_forward``: chunks of prefill and
+decode tokens in one ragged batch), the decode step (``_decode_body``: one
+token a row) and the verify step of speculation (``spec.py:verify_body``:
+``Q`` positions a row); its docstring is the contract of a caller.  The
+head is ``tfm.lm_logits``.
 
 This module imports neither ``engine.py`` (allocator, scheduler, prefix
 cache, paging, adapters: the host side) nor ``spec.py``; both import it.
@@ -17,8 +18,10 @@ cache, paging, adapters: the host side) nor ``spec.py``; both import it.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +36,7 @@ from ...ops.pallas.paged_attention import (PrefillTiles,
                                            paged_decode_attention,
                                            paged_prefill_attention,
                                            pick_prefill_tiles)
+from .ragged import window_bound
 
 # Built forward functions are memoized per (builder, configs): every engine
 # over the same shapes — serving replicas, test fixtures — shares ONE jitted
@@ -110,8 +114,6 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
     """Correct-for-everything gather path. q: (T, H, D); caches
     (num_blocks, bs, KV, D); returns (T, H, D).  ``window`` (0: none): a
     token sees the keys less than ``window`` positions behind it."""
-    import math
-
     T, H, D = q.shape
     KV = k_cache.shape[2]
     max_blocks = block_tables.shape[1]
@@ -233,17 +235,32 @@ def layer_plan(model_cfg: tfm.TransformerConfig, v2) -> tuple:
                  for w, kind in zip(windows, model_cfg.layer_period))
 
 
-def pool_layers(model_cfg: tfm.TransformerConfig, v2) -> tuple:
-    """How many layers each pool of ``layer_plan`` holds: ``(L,)`` for a
-    model with one kind of layer, ``(global, windowed)`` with two."""
-    if model_cfg.kv_lora_rank:  # one pool of latents (and the indexer's)
-        return (model_cfg.num_layers,)
-    if model_cfg.mixer_pattern:  # one mixer a layer: K/V for the "*" layers
-        return (model_cfg.layers_of("*"),)
+def _kv_pool(c: tfm.TransformerConfig, v2, layers: int, blocks: int):
+    """(shape, dtype) of a K or a V pool: the one geometry of a K/V block."""
+    return ((layers, blocks, v2.block_size, c.kv_heads, c.head_dim),
+            jnp.dtype(v2.dtype))
+
+
+def kv_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
+    """What a model of attention + FFN layers caches: ``k`` / ``v`` for the
+    layers of ``layer_plan``'s pool 0 (all, with one kind of layer) and,
+    with both kinds, ``k_win`` / ``v_win`` for the windowed ones, of
+    ``num_window_blocks`` blocks (0: what ``max_seqs`` sequences hold at
+    most, ``ragged.window_bound``, and the scratch block)."""
     plan = layer_plan(model_cfg, v2)
     periods = model_cfg.num_layers // len(plan)
-    return tuple(periods * sum(k.pool == p for k in plan)
-                 for p in range(1 + max(k.pool for k in plan)))
+
+    def pool(p, blocks):
+        return _kv_pool(model_cfg, v2,
+                        periods * sum(k.pool == p for k in plan), blocks)
+
+    arrays = {"k": pool(0, v2.num_blocks), "v": pool(0, v2.num_blocks)}
+    if any(k.pool for k in plan):
+        blocks = v2.num_window_blocks or 1 + v2.max_seqs * window_bound(
+            max(k.window for k in plan), v2.max_tokens_per_step,
+            v2.block_size, v2.max_blocks_per_seq)
+        arrays.update(k_win=pool(1, blocks), v_win=pool(1, blocks))
+    return arrays
 
 
 def tables_of(block_tables) -> tuple:
@@ -292,19 +309,38 @@ class StepRows:
 
 
 def state_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
-    """Shapes and dtypes of the per-sequence state of a model with Mamba-2
-    layers, or {}: ``ssm (L_M, slots + 1, H, P, N)`` float32 and the conv's
-    kept inputs ``conv (L_M, slots + 1, K - 1, C)`` in the activation dtype
+    """What a model of one mixer a layer caches: the one K/V pool of its
+    attention layers, and beside it the per-sequence state of its Mamba-2
+    layers: ``ssm (L_M, slots + 1, H, P, N)`` float32 and the conv's kept
+    inputs ``conv (L_M, slots + 1, K - 1, C)`` in the activation dtype
     (columns on the lanes: a last dimension of K - 1 = 3 would be padded to
     128 in HBM).  A slot a row of the engine's table, and one scratch slot."""
-    L = model_cfg.layers_of("M")
-    if not L:
-        return {}
-    c = model_cfg
-    return {"ssm": ((L, v2.max_seqs + 1, c.mamba_num_heads, c.mamba_head_dim,
+    c, dt = model_cfg, jnp.dtype(v2.dtype)
+    L, kv_layers = c.layers_of("M"), c.layers_of("*")
+    if not (L and kv_layers):
+        raise NotImplementedError(
+            "a mixer_pattern model is served with at least one Mamba-2 "
+            "and one attention layer")
+    pool = _kv_pool(c, v2, kv_layers, v2.num_blocks)
+    return {"k": pool, "v": pool,
+            "ssm": ((L, v2.max_seqs + 1, c.mamba_num_heads, c.mamba_head_dim,
                      c.mamba_state_size), jnp.float32),
             "conv": ((L, v2.max_seqs + 1, c.mamba_conv_kernel - 1,
-                      c.mamba_conv_dim), jnp.dtype(v2.dtype))}
+                      c.mamba_conv_dim), dt)}
+
+
+def state_rows(tables, start, n, flat=None) -> StepRows:
+    """``StepRows`` of a step whose rows begin at positions ``start``: a
+    decode step's rows where ``n`` take their one token; a mixed step's of
+    ``n`` tokens each, ``flat = (q_start, row, valid, slots)``: where a row's
+    tokens begin, each token's row, the real tokens, each row's slot."""
+    if flat is None:
+        return StepRows(n, n & (start == 0))
+    q_start, row, valid, slots = flat
+    return StepRows(
+        active=n > 0, fresh=(n > 0) & (start == 0), row=row,
+        offset=jnp.arange(row.shape[0]) - q_start[row], row_start=q_start,
+        row_len=n, slots=slots, valid=valid)
 
 
 def _mamba_decode(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
@@ -366,31 +402,90 @@ def _mamba_mixed(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
 _PLAIN_ATTENTION = LayerKind(0, 0, tfm.RopeParams())
 
 
-def hybrid_layers(params, caches, x, write_at, attend,
-                  model_cfg: tfm.TransformerConfig, valid, rows: StepRows):
+def stacked_layers(layers):
+    """``params["layers"]`` of a model with one stack a kind of layer →
+    ``of(kind, idx)``: layer ``idx`` of that stack, its quantized
+    projections read in place at THAT stack's index (``hoist_quantized``)."""
+    stacks = {kind: hoist_quantized(tree) for kind, tree in layers.items()}
+
+    def of(kind, idx):
+        kept, layer_params = stacks[kind]
+        return layer_params(jax.tree.map(lambda a: a[idx], kept), idx)
+
+    return of
+
+
+def walk_pattern(pattern, draws, one_layer, carry):
+    """The layers of a model whose parameters are stacked by kind, in pattern
+    order as ``ssm_hybrid.segments`` cuts it: each run of a repeated unit
+    (``E M``; a period of four) is one ``lax.scan`` over its repeats with the
+    unit's layers unrolled inside, so a start traces a handful of bodies
+    whatever the depth; a unit that comes once is unrolled where it stands.
+
+    ``draws(letter) -> {stack: bool}``: the stacks whose index a layer of
+    that letter is handed, and whether it is one of the stack's layers (its
+    count moves on).  ``one_layer(letter, idx, carry) -> (carry, outs)``:
+    ``idx[stack]`` is the layer's place in that stack and ``outs`` a tuple
+    with an array or None a slot.  → (the carry, a list a slot: each run's
+    outputs, stacked ``(layers, ...)``)."""
+    done, outs = collections.Counter(), None
+    for unit, reps in ssm_hybrid.segments(tuple(pattern)):
+        per_unit = collections.Counter(
+            stack for letter in unit
+            for stack, own in draws(letter).items() if own)
+        base = dict(done)
+
+        def unit_body(carry, rep, unit=unit, per_unit=per_unit, base=base):
+            seen, got = collections.Counter(), None
+            for letter in unit:
+                drawn = draws(letter)
+                idx = {stack: base.get(stack, 0) + rep * per_unit[stack]
+                       + seen[stack] for stack in drawn}
+                seen.update(stack for stack, own in drawn.items() if own)
+                carry, out = one_layer(letter, idx, carry)
+                got = got or [[] for _ in out]
+                for slot, o in zip(got, out):
+                    if o is not None:
+                        slot.append(o)
+            return carry, tuple(jnp.stack(slot) if slot else None
+                                for slot in got)
+
+        if reps == 1:
+            carry, got = unit_body(carry, jnp.int32(0))
+        else:
+            carry, got = jax.lax.scan(unit_body, carry,
+                                      jnp.arange(reps, dtype=jnp.int32))
+        outs = outs or [[] for _ in got]
+        for slot, o in zip(outs, got):
+            if o is not None:  # (repeats,) (unit's layers, ...) → (layers, ...)
+                slot.append(o.reshape((-1,) + o.shape[1 + (reps > 1):]))
+        for stack, n in per_unit.items():
+            done[stack] += reps * n
+    return carry, outs
+
+
+def hybrid_layers(params, caches, x, positions, write_at, attend,
+                  model_cfg: tfm.TransformerConfig, v2, adapters=None,
+                  slots=None, valid=None, rows: StepRows = None):
     """``serving_layers`` for a model of one mixer a layer
-    (``model_cfg.mixer_pattern``): norm, the layer's mixer, residual.  The
-    layers run in pattern order as ``ssm_hybrid.segments`` cuts it: each run
-    of a repeated unit (``E M``) is one ``lax.scan`` over its repeats with
-    the unit's layers unrolled inside, so a start traces a handful of bodies
-    whatever the depth.  Each kind's parameters are its own stack, whose
-    quantized projections the kernels read in place at the layer's index in
-    THAT stack; the K/V pool (the attention layers only) and both state
-    arrays ride the carry whole and are updated in place."""
+    (``model_cfg.mixer_pattern``): norm, the layer's mixer, residual, in
+    pattern order (``walk_pattern``).  Each kind's parameters are its own
+    stack; the K/V pool (the attention layers only) and both state arrays
+    ride the carry whole and are updated in place.  ``rows`` is what its
+    state layers need to know of the step."""
     if rows is None:
         raise ValueError("a model with state layers is served by the mixed "
                          "and decode steps only")
     blk_ids, offsets = write_at
     lead = x.shape[:-1]
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
-    stacks = {kind: hoist_quantized(tree)
-              for kind, tree in params["layers"].items()}
+    of = stacked_layers(params["layers"])
     mamba = _mamba_decode if rows.row is None else _mamba_mixed
 
     def one_layer(kind, idx, carry):
         x, k_cache, v_cache, ssm, conv = carry
-        kept, layer_params = stacks[kind]
-        lp = layer_params(jax.tree.map(lambda a: a[idx], kept), idx)
+        idx = idx[kind]
+        lp = of(kind, idx)
         a_in = tfm._norm(x, lp["norm"], "rmsnorm", model_cfg.norm_eps)
         stats = None
         if kind == "M":
@@ -412,35 +507,11 @@ def hybrid_layers(params, caches, x, write_at, attend,
                        _PLAIN_ATTENTION)
             out = tfm._lin(o.reshape(lead + (nh * hd,)), lp["attn"], "wo",
                            "bo")
-        return (x + out, k_cache, v_cache, ssm, conv), stats
+        return (x + out, k_cache, v_cache, ssm, conv), (stats,)
 
     carry = (x, caches["k"], caches["v"], caches["ssm"], caches["conv"])
-    done = {kind: 0 for kind in ssm_hybrid.KINDS}
-    moe_stats = []
-    for unit, reps in ssm_hybrid.segments(model_cfg.mixer_pattern):
-        per_unit = {kind: unit.count(kind) for kind in ssm_hybrid.KINDS}
-        base = dict(done)
-
-        def unit_body(carry, rep, unit=unit, per_unit=per_unit, base=base):
-            stats, seen = [], {kind: 0 for kind in ssm_hybrid.KINDS}
-            for kind in unit:
-                idx = base[kind] + rep * per_unit[kind] + seen[kind]
-                seen[kind] += 1
-                carry, st = one_layer(kind, idx, carry)
-                if st is not None:
-                    stats.append(st)
-            return carry, (jnp.stack(stats) if stats else None)
-
-        if reps == 1:
-            carry, st = unit_body(carry, jnp.int32(0))
-        else:
-            carry, st = jax.lax.scan(unit_body, carry,
-                                     jnp.arange(reps, dtype=jnp.int32))
-        if st is not None:
-            moe_stats.append(st.reshape(-1, st.shape[-1]))
-        for kind in ssm_hybrid.KINDS:
-            done[kind] += reps * per_unit[kind]
-    x, k_cache, v_cache, ssm, conv = carry
+    (x, k_cache, v_cache, ssm, conv), (moe_stats,) = walk_pattern(
+        model_cfg.mixer_pattern, lambda kind: {kind: True}, one_layer, carry)
     x = tfm._norm(x, params["final_norm"], "rmsnorm", model_cfg.norm_eps)
     return (x, {"k": k_cache, "v": v_cache, "ssm": ssm, "conv": conv},
             jnp.concatenate(moe_stats) if moe_stats else None)
@@ -464,29 +535,42 @@ class LatentRows:
 
 
 def latent_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
-    """Shapes of the two pools of a model with latent attention, or {}:
+    """What a model with latent attention caches, in place of K and V heads:
     ``latent (L, blocks, block, W)``, a token's latent and rotated key
     (``latent_attention.pool_width``), and ``index (L_full, blocks, block,
     index_head_dim)``, the indexer's key of the layers that pick.  Both grow
     with the context and are read through the one block table."""
-    c = model_cfg
-    if not c.kv_lora_rank:
-        return {}
+    c, dt = model_cfg, jnp.dtype(v2.dtype)
+    if not c.index_topk:
+        raise NotImplementedError(
+            "a latent model without an indexer (index_topk 0, no query "
+            "compression) is trained, not served: the absorbed step "
+            "programs (programs.latent_layers) read the indexer's stack "
+            "and the compressed query (ROADMAP R2b)")
     width = latent_attention.pool_width(c.kv_lora_rank, c.qk_rope_head_dim)
-    return {"latent": (c.num_layers, v2.num_blocks, v2.block_size, width),
-            "index": (latent_sparse.layers_of(c, "I"), v2.num_blocks,
-                      v2.block_size, c.index_head_dim)}
+    return {"latent": ((c.num_layers, v2.num_blocks, v2.block_size, width),
+                       dt),
+            "index": ((latent_sparse.layers_of(c, "I"), v2.num_blocks,
+                       v2.block_size, c.index_head_dim), dt)}
 
 
-def latent_layers(params, caches, x, positions, write_at,
-                  model_cfg: tfm.TransformerConfig, v2, valid,
-                  rows: LatentRows):
+def latent_rows(tables, start, n, flat=None) -> LatentRows:
+    """``LatentRows`` of a step: ``state_rows``' arguments, of which a mixed
+    step's rows of one token are its ``single`` ones."""
+    if flat is None:
+        return LatentRows(tables[0], start, n)
+    return LatentRows(tables[0], start, n == 1, flat[0], start, n)
+
+
+def latent_layers(params, caches, x, positions, write_at, attend,
+                  model_cfg: tfm.TransformerConfig, v2, adapters=None,
+                  slots=None, valid=None, rows: LatentRows = None):
     """``serving_layers`` for a model with latent attention
-    (``models/latent_sparse.py``).  The layers run as ``ssm_hybrid.segments``
-    cuts the pattern of (picks its own keys or shares, dense or routed FFN):
-    the leading layers unrolled, then one ``lax.scan`` over the periods of
-    four with a period's layers unrolled inside.  Each stack's quantized
-    projections are read in place at the layer's index in THAT stack; both
+    (``models/latent_sparse.py``); it takes no ``attend``: a pick of keys
+    comes between the write and the attention, and ``rows`` is what its two
+    attention paths need.  The layers run as ``walk_pattern`` cuts the
+    pattern of (picks its own keys or shares, dense or routed FFN): the
+    leading layers unrolled, then the periods of four.  Both
     pools ride the carry whole, and so does THE SELECTION: the keys a "full"
     layer picked for every query of the step, which the "shared" layers
     behind it attend over (a mask ``(T, S)`` for the prefill rows' queries,
@@ -510,13 +594,8 @@ def latent_layers(params, caches, x, positions, write_at,
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     rope = ls.rope_tables(cfg, S)
     scale = ls.softmax_scale(cfg)
-    stacks = {kind: hoist_quantized(tree)
-              for kind, tree in params["layers"].items()}
+    of = stacked_layers(params["layers"])
     tiles = la.prefill_tiles(rows.chunk_len, T) if mixed else None
-
-    def of(kind, idx):
-        kept, layer_params = stacks[kind]
-        return layer_params(jax.tree.map(lambda a: a[idx], kept), idx)
 
     def per_row(a):  # the single rows' tokens out of the flat batch
         return a[jnp.clip(rows.q_start, 0, T - 1)] if mixed else a
@@ -574,49 +653,18 @@ def latent_layers(params, caches, x, positions, write_at,
         else:
             out, stats = serving_moe_block(m_in, of("S", idx["S"])["moe"],
                                            cfg, valid=valid)
-        return (x + out, latent, index, sel), stats, tap
+        return (x + out, latent, index, sel), (stats, tap)
 
-    def stack_of(letter):
-        return {"I": letter.isupper(), "D": letter in "Dd",
-                "S": letter in "Ss", "A": True}
+    def stack_of(letter):  # every stack's index is made, as it always was:
+        # lowering drops the unused ones, and the pinned counts hold them
+        return {"A": True, "I": letter.isupper(), "D": letter in "Dd",
+                "S": letter in "Ss"}
 
     sel = (jnp.zeros((T, S) if mixed else (1, 1), bool),
            jnp.zeros((R, K), jnp.int32), jnp.zeros((R, K), bool))
-    carry = (x, caches["latent"], caches["index"], sel)
-    done = {kind: 0 for kind in ls.KINDS}
-    moe_stats, taps = [], []
-    for unit, reps in ssm_hybrid.segments(ls.pattern(cfg)):
-        per_unit = {kind: sum(stack_of(c)[kind] for c in unit)
-                    for kind in ls.KINDS}
-        base = dict(done)
-
-        def unit_body(carry, rep, unit=unit, per_unit=per_unit, base=base):
-            stats, tapped = [], []
-            seen = {kind: 0 for kind in ls.KINDS}
-            for letter in unit:
-                idx = {kind: base[kind] + rep * per_unit[kind] + seen[kind]
-                       for kind in ls.KINDS}
-                for kind in ls.KINDS:
-                    seen[kind] += stack_of(letter)[kind]
-                carry, st, tap = one_layer(letter, idx, carry)
-                if st is not None:
-                    stats.append(st)
-                if tap is not None:
-                    tapped.append(tap)
-            return carry, (jnp.stack(stats) if stats else None,
-                           jnp.stack(tapped) if tapped else None)
-
-        if reps == 1:
-            carry, (st, tp) = unit_body(carry, jnp.int32(0))
-        else:
-            carry, (st, tp) = jax.lax.scan(
-                unit_body, carry, jnp.arange(reps, dtype=jnp.int32))
-        if st is not None:
-            moe_stats.append(st.reshape(-1, st.shape[-1]))
-        if tp is not None:
-            taps.append(tp.reshape((-1,) + tp.shape[-2:]))
-        for kind in ls.KINDS:
-            done[kind] += reps * per_unit[kind]
+    carry, (moe_stats, taps) = walk_pattern(
+        ls.pattern(cfg), stack_of, one_layer,
+        (x, caches["latent"], caches["index"], sel))
     x, latent, index, _ = carry
     x = tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
     stats = None
@@ -630,42 +678,12 @@ def latent_layers(params, caches, x, positions, write_at,
     return x, {"latent": latent, "index": index}, stats
 
 
-def serving_layers(params, caches, x, positions, write_at, attend,
-                   model_cfg: tfm.TransformerConfig, v2, adapters=None,
-                   slots=None, valid=None, rows: StepRows = None):
-    """Every layer of the served model over one step's rows, then the final
-    norm: the one layer body of the mixed, decode and verify steps.
-
-    ``x (..., H)`` are the embedded rows and ``positions`` (``x.shape[:-1]``)
-    their places in their sequences; ``write_at = (blk_ids, offsets)`` is
-    where each row's K and V go in its layer of the pools (``blk_ids``: one
-    array a pool, as ``write_blocks`` makes them), which the caller
-    has already pointed at the scratch block (the pool's last) for every row
-    that must not write; ``attend(q, k_pool, v_pool, layer, kind) -> o`` is
-    the caller's paged attention over the layer's pool with the step's rows
-    written (``q`` and ``o`` are ``(..., heads, head_dim)``; ``layer`` counts
-    within the pool and ``kind`` is the layer's ``LayerKind``: which table,
-    which window); ``adapters`` is the
-    per-slot LoRA stack with ``slots``, the slot each row reads (shaped as
-    ``_adapter_proj_delta`` takes it), or None; ``valid`` marks the rows an
-    MoE model's stats count.
-
-    → (hidden state after the final norm, the pools as ``caches`` names
-    them, an MoE model's per-layer stats ``(L, 2)`` or None).
-
-    A model of one mixer a layer (``mixer_pattern``) goes to
-    ``hybrid_layers``, with ``rows`` (``StepRows``) what its state layers
-    need to know of the step; a model with latent attention
-    (``kv_lora_rank``) to ``latent_layers``, with ``rows`` (``LatentRows``)
-    what its two attention paths need (it takes no ``attend``: a pick of
-    keys comes between the write and the attention); every other model
-    traces what it always did."""
-    if model_cfg.kv_lora_rank:
-        return latent_layers(params, caches, x, positions, write_at,
-                             model_cfg, v2, valid, rows)
-    if model_cfg.mixer_pattern:
-        return hybrid_layers(params, caches, x, write_at, attend, model_cfg,
-                             valid, rows)
+def attention_layers(params, caches, x, positions, write_at, attend,
+                     model_cfg: tfm.TransformerConfig, v2, adapters=None,
+                     slots=None, valid=None, rows=None):
+    """``serving_layers`` for a model of attention + FFN layers over one or
+    two paged K/V pools: one ``lax.scan`` over identical layers, or over the
+    periods of ``layer_plan`` with a period's layers unrolled inside."""
     blk_ids, offsets = write_at
     rows = x.shape[:-1]
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
@@ -783,6 +801,133 @@ def serving_layers(params, caches, x, positions, write_at, attend,
     return x, new, moe_stats
 
 
+# ---------------------------------------------------------------------------
+# the kinds of served model
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedKind:
+    """One kind of served model and all that depends on which it is: the
+    engines and the step programs ask IT, never the configuration's fields."""
+    name: str  # as a refusal names the model
+    #: ``(model_cfg, v2) -> {name: (shape, dtype)}``, every array of
+    #: ``caches``: the engine allocates them as they come and reads its
+    #: managers' needs off them (``k_win``: a second pool, ``ssm``: slots)
+    arrays: Callable
+    layers: Callable  # the body: ``serving_layers``' arguments and results
+    #: ``(tables, start, n, flat=None) ->`` the body's ``rows`` argument
+    step_rows: Callable
+    moe_layers: Callable  # ``model_cfg ->`` how many layers route
+    #: the engine's method that counts a step: ``(start, n, mixed) ->``
+    #: attributes of ``engine/step``
+    counters: str
+    #: ``(model_cfg, v2) ->`` its rows of ``REFUSED``, and the sentence why
+    #: (``{does}``: what the option does, ``{window}``: the active window)
+    refuses: Callable
+    because: str
+    #: ``generate_all`` may decode several tokens in one program (a kind
+    #: whose every step counts may not)
+    bursts: bool = False
+
+
+def kind_of(model_cfg: tfm.TransformerConfig) -> ServedKind:
+    """The kind of model ``model_cfg`` is: the one place under ``inference/``
+    that reads the configuration to tell."""
+    if model_cfg.kv_lora_rank:
+        return LATENT
+    return STATE if model_cfg.mixer_pattern else KV
+
+
+#: What some kind of model cannot be combined with yet: the fields of
+#: ``V2Config`` as a refusal names them (one set is enough) -> what the option
+#: does to a cache.  A kind's ``refuses`` names its rows, its ``because``
+#: says what of that the model cannot bear.
+REFUSED = {
+    "enable_prefix_cache":
+        "the prefix cache (with it prefix export / import and copy-on-write "
+        "forks) shares and copies a finished sequence's K and V blocks and "
+        "starts another behind them",
+    "kv_host_pool_mb / kv_host_pool_bytes":
+        "the host paging tier demotes and promotes K and V blocks of a prefix",
+    "kv_spill_dir": "the spill tier holds demoted K and V blocks",
+    "kv_coldstore_dir": "the cold store holds demoted K and V blocks",
+    "spec_mode":
+        "speculation writes k tokens ahead of the context, attends over K "
+        "and V heads to verify them and rolls the rejected ones back by "
+        "masking their K/V (and a draft model's cache shares the target's "
+        "block tables)",
+    "adapter_slots":
+        "the adapter stack is laid out for the q, k, v and o projections of "
+        "one attention block a layer",
+}
+_MOVES_BLOCKS = tuple(name for name in REFUSED if name != "adapter_slots")
+
+KV = ServedKind(
+    name="a model of attention + FFN layers over paged K/V",
+    arrays=kv_arrays, layers=attention_layers,
+    step_rows=lambda tables, start, n, flat=None: None,
+    moe_layers=lambda c: c.num_layers, counters="_count_kv",
+    # what moves KV bytes by block id does not know that a window layer's
+    # blocks go back to their pool while the sequence runs, or of two pools
+    refuses=lambda c, v2: _MOVES_BLOCKS if any(
+        k.window for k in layer_plan(c, v2)) else (),
+    because="a model whose attention layers have an active sliding window "
+            "({window} < the engine's longest context): {does}, and a window "
+            "layer's blocks are freed behind the window while the sequence "
+            "runs",
+    bursts=True)
+STATE = ServedKind(
+    name="a model of one mixer a layer (mixer_pattern: state-space layers "
+         "with per-sequence state beside the paged K/V)",
+    arrays=state_arrays, layers=hybrid_layers, step_rows=state_rows,
+    moe_layers=lambda c: c.layers_of("E"), counters="_count_state",
+    refuses=lambda c, v2: tuple(REFUSED),
+    because="a model that has state layers (mixer_pattern with 'M'): {does}, "
+            "and a sequence's state at an earlier position is kept nowhere "
+            "(that would take a snapshot)")
+LATENT = ServedKind(
+    name="a model with latent attention (kv_lora_rank > 0: a latent a "
+         "token, a learned selection of keys)",
+    arrays=latent_arrays, layers=latent_layers, step_rows=latent_rows,
+    moe_layers=lambda c: latent_sparse.layers_of(c, "S"),
+    counters="_count_latent",
+    refuses=lambda c, v2: tuple(REFUSED),
+    because="a model with latent attention (kv_lora_rank > 0), whose pools "
+            "hold a latent a token and the indexer's keys and no K or V "
+            "heads: {does}")
+
+
+def serving_layers(params, caches, x, positions, write_at, attend,
+                   model_cfg: tfm.TransformerConfig, v2, adapters=None,
+                   slots=None, valid=None, rows=None):
+    """Every layer of the served model over one step's rows, then the final
+    norm: the one layer body of the mixed, decode and verify steps, which is
+    the body of the model's kind (``kind_of``).
+
+    ``x (..., H)`` are the embedded rows and ``positions`` (``x.shape[:-1]``)
+    their places in their sequences; ``write_at = (blk_ids, offsets)`` is
+    where each row's K and V go in its layer of the pools (``blk_ids``: one
+    array a pool, as ``write_blocks`` makes them), which the caller
+    has already pointed at the scratch block (the pool's last) for every row
+    that must not write; ``attend(q, k_pool, v_pool, layer, kind) -> o`` is
+    the caller's paged attention over the layer's pool with the step's rows
+    written (``q`` and ``o`` are ``(..., heads, head_dim)``; ``layer`` counts
+    within the pool and ``kind`` is the layer's ``LayerKind``: which table,
+    which window); ``adapters`` is the
+    per-slot LoRA stack with ``slots``, the slot each row reads (shaped as
+    ``_adapter_proj_delta`` takes it), or None; ``valid`` marks the rows an
+    MoE model's stats count; ``rows`` is what the kind's ``step_rows`` made
+    of the step (None: the verify step, which the kinds that need it are
+    refused).
+
+    → (hidden state after the final norm, the pools as ``caches`` names
+    them, an MoE model's per-layer stats ``(L, 2)`` or None)."""
+    return kind_of(model_cfg).layers(params, caches, x, positions, write_at,
+                                     attend, model_cfg, v2, adapters, slots,
+                                     valid, rows)
+
+
 def _decode_body(params, caches, token_ids, position_ids, block_tables,
                  context_lens, model_cfg, v2, adapters=None,
                  row_adapter=None):
@@ -807,10 +952,7 @@ def _decode_body(params, caches, token_ids, position_ids, block_tables,
                                           tables[kind.pool], context_lens,
                                           window=kind.window)
 
-    rows = StepRows(active, active & (position_ids == 0)) \
-        if model_cfg.mixer_pattern else None
-    if model_cfg.kv_lora_rank:
-        rows = LatentRows(tables[0], position_ids, active)
+    rows = kind_of(model_cfg).step_rows(tables, position_ids, active)
     x, caches, moe_stats = serving_layers(
         params, caches, x, position_ids, (blk_ids, position_ids % bs), attend,
         model_cfg, v2, adapters, row_adapter, active, rows)
@@ -836,8 +978,7 @@ def mixed_step_attn_tiles(model_cfg: tfm.TransformerConfig, v2) -> PrefillTiles:
 
 def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
     bs = v2.block_size
-
-    stateful = bool(model_cfg.mixer_pattern)
+    kind = kind_of(model_cfg)
 
     def mixed_step(params, caches, token_ids, position_ids, seq_index,
                    block_tables, context_lens, logits_rows, chunk_start,
@@ -878,16 +1019,8 @@ def build_ragged_forward(model_cfg: tfm.TransformerConfig, v2):
                                                chunk_start, chunk_len,
                                                window=kind.window)
 
-        rows = None
-        if stateful:
-            rows = StepRows(
-                active=chunk_len > 0, fresh=(chunk_len > 0) & (chunk_start == 0),
-                row=row, offset=jnp.arange(token_ids.shape[0]) - q_start[row],
-                row_start=q_start, row_len=chunk_len, slots=state_slots,
-                valid=valid)
-        if model_cfg.kv_lora_rank:
-            rows = LatentRows(tables[0], chunk_start, chunk_len == 1,
-                              q_start, chunk_start, chunk_len)
+        rows = kind.step_rows(tables, chunk_start, chunk_len,
+                              (q_start, row, valid, state_slots))
         x, caches, moe_stats = serving_layers(
             params, caches, x, position_ids, (blk_ids, position_ids % bs),
             attend, model_cfg, v2, adapters, tok_slot, valid, rows)

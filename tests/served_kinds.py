@@ -1,0 +1,64 @@
+"""Shared by the tests of the served kinds (``programs.ServedKind``): the
+settings that turn a refused option on, and the attributes ``engine/step``
+carried on the parent of the PR that named the kinds (bb36f0b), which
+``benchmark/layer_metrics/`` divides by."""
+
+#: V2Config field -> the settings that turn it on (a field of
+#: ``programs.REFUSED`` that is not here fails the collection of the refusal
+#: tests: add it)
+TURNED_ON = {
+    "enable_prefix_cache": [dict(enable_prefix_cache=True),
+                            dict(enable_prefix_cache=True, kv_host_pool_mb=1)],
+    "kv_host_pool_mb": [dict(kv_host_pool_mb=1)],
+    "kv_host_pool_bytes": [dict(kv_host_pool_bytes=4096)],
+    "kv_spill_dir": [dict(kv_spill_dir="/tmp/x")],
+    "kv_coldstore_dir": [dict(kv_coldstore_dir="/tmp/x")],
+    "spec_mode": [dict(spec_mode="self_draft"), dict(spec_mode="draft")],
+    "adapter_slots": [dict(adapter_slots=2, adapter_rank=4)],
+}
+
+
+def refusal_cases(kind, model_cfg, v2) -> list:
+    """(V2Config overrides, the field the refusal names) for every row of the
+    refusal table that ``kind`` holds for this model, every field of the row
+    and every setting that turns the field on."""
+    return [(over, field) for name in kind.refuses(model_cfg, v2)
+            for field in name.split(" / ") for over in TURNED_ON[field]]
+
+
+#: every step that reached the device, with tracing on
+_STEP = {"kind", "step", "running", "waiting", "prefilling", "emitted",
+         "tokens", "budget", "h2d_copies", "h2d_bytes", "pre_ms", "device_ms",
+         "post_ms", "pre_cpu_ms", "post_cpu_ms"}
+#: a step that found a staged copy it could not use
+_SOMETIMES = {"stage_discarded", "stage_bytes"}
+#: by what the model is, beyond the above: on every step, on mixed steps only
+STEP_ATTRS = {
+    "moe": ({"moe_rows", "moe_rows_padded", "moe_experts_hit",
+             "moe_rows_max"}, set()),
+    "window": ({"kv_blocks_read", "kv_blocks_full", "kv_query_keys",
+                "window_blocks_freed", "blocks_used_global",
+                "blocks_used_window"}, set()),
+    "state": ({"state_slots_used", "state_rows_started", "ssm_tokens",
+               "ssm_state_bytes"},
+              {"ssm_scan_rows", "ssm_scan_tokens", "ssm_scan_pieces"}),
+    "latent": ({"dsa_keys_visible", "dsa_keys_selected",
+                "dsa_selected_single", "dsa_selected_prefill",
+                "latent_keys_single", "latent_keys_prefill",
+                "dsa_index_pairs", "dsa_index_keys", "latent_blocks_used",
+                "moe_assignments", "moe_assignments_local"}, set()),
+}
+
+
+def assert_step_attrs(steps, *what) -> None:
+    """Every ``engine/step`` span's attributes in ``steps`` (a decode and a
+    mixed step among them) are the parent's for a model that is ``what``:
+    the same names on the same kind of step, none more and none fewer."""
+    assert {"mixed", "decode"} <= {a["kind"] for a in steps}
+    for a in steps:
+        mixed = a["kind"] == "mixed"
+        want = _STEP | ({"attn_q_slots"} if mixed else {"staged"})
+        for name in what:
+            always, on_mixed = STEP_ATTRS[name]
+            want |= always | (on_mixed if mixed else set())
+        assert set(a) - _SOMETIMES == want, (a["kind"], set(a) ^ want)

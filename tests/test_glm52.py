@@ -19,6 +19,7 @@ import pytest
 
 from benchmark.drivers import serve_latent_moe as drv
 from benchmark.reference import latent_sparse_moe_decoder as reference
+from deepspeed_tpu.inference.v2 import programs
 from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
 from deepspeed_tpu.models import latent_sparse
 from deepspeed_tpu.models import transformer as tfm
@@ -29,6 +30,7 @@ from deepspeed_tpu.ops.pallas import latent_attention as la
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(HERE), "benchmark", "tests"))
 import glm52_wrong_programs as wrong  # noqa: E402
+from served_kinds import assert_step_attrs, refusal_cases  # noqa: E402
 
 # float32 on both sides: what differs is the order of the sums (the absorbed
 # form against the expanded, paged chunks against an (S, S) mask).  Logits of
@@ -258,18 +260,13 @@ def test_engine_w8a16_matches_reference(tiny):
     assert np.median(errs) < 0.15 and errs.max() < 0.4, errs
 
 
-@pytest.mark.parametrize("over,name", [
-    (dict(enable_prefix_cache=True), "enable_prefix_cache"),
-    (dict(kv_host_pool_mb=1), "kv_host_pool_mb"),
-    (dict(kv_spill_dir="/tmp/x"), "kv_spill_dir"),
-    (dict(kv_coldstore_dir="/tmp/x"), "kv_coldstore_dir"),
-    (dict(spec_mode="self_draft"), "spec_mode"),
-    (dict(spec_mode="draft"), "spec_mode"),
-    (dict(adapter_slots=2, adapter_rank=4), "adapter_slots"),
-])
+@pytest.mark.parametrize("over,name", refusal_cases(
+    programs.LATENT, tfm.get_config("tiny-glm52"), v2_config()))
 def test_refused_with_a_latent_pool(tiny, over, name):
+    """Every row of the refusal table (``programs.REFUSED``) the kind
+    holds."""
     cfg, params, _ = tiny
-    with pytest.raises(ValueError, match=f"V2Config.{name}.*latent"):
+    with pytest.raises(ValueError, match=f"V2Config.*{name}.*latent"):
         InferenceEngineV2(cfg, params, v2_config(**over))
 
 
@@ -280,6 +277,7 @@ def test_step_spans_carry_the_counters(tiny):
     tracer.clear()
     engine.generate_all(burst=1)
     steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
+    assert_step_attrs(steps, "moe", "latent")
     mixed = [a for a in steps if a["kind"] == "mixed"][0]  # tokens 0..31
     seen = sum(range(1, 33))
     assert mixed["dsa_keys_visible"] == seen * 9
@@ -353,48 +351,6 @@ def test_share_tiles():
     assert dropless.moe_tile_m(256, 64) == 16
     assert dropless.moe_tile_m(4096, 64) == 128
     assert dropless.moe_tile_m(3072, 128) == 64
-
-
-def _count(jaxpr, c):
-    for e in jaxpr.eqns:
-        c[e.primitive.name] += 1
-        for v in e.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    _count(inner, c)
-    return c
-
-
-def test_a_state_models_programs_are_the_parents():
-    """A model without latent layers traces no new body: the step programs
-    of the Nemotron-shaped tiny config count, primitive by primitive, what
-    they counted on the parent (``parent_step_program_eqns.json``, key
-    ``tiny-nemotron3``: counted on the parent commit by this function; the
-    other three shapes are ``tests/test_nemotron3.py``'s)."""
-    import collections
-
-    with open(os.path.join(HERE, "parent_step_program_eqns.json")) as f:
-        pinned = json.load(f)["tiny-nemotron3"]
-    cfg = tfm.get_config("tiny-nemotron3", dtype="float32")
-    e = InferenceEngineV2(cfg, tfm.init_params(jax.random.PRNGKey(0), cfg),
-                          v2_config(num_blocks=64))
-    assert set(e.caches) == {"k", "v", "ssm", "conv"} and not e._latent
-    T, S = 32, 4
-
-    def i32(*s):
-        return jnp.zeros(s, jnp.int32)
-
-    mixed = jax.make_jaxpr(e._fwd)(
-        e.params, e.caches, i32(T), i32(T), i32(T), i32(S, 16), i32(S),
-        i32(S), i32(S), i32(S), None, None, i32(S))
-    decode = jax.make_jaxpr(e._decode_fwd)(
-        e.params, e.caches, i32(S), i32(S), i32(S, 16), i32(S),
-        jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))
-    for kind, jaxpr in (("mixed", mixed), ("decode", decode)):
-        assert dict(_count(jaxpr.jaxpr, collections.Counter())) == \
-            pinned[kind], kind
-        assert "latent" not in str(jaxpr) and "dsa_" not in str(jaxpr)
 
 
 def test_v1_engine_refuses_a_latent_model(tiny):

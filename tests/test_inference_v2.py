@@ -14,6 +14,7 @@ from deepspeed_tpu.inference.v2.ragged import (BlockedAllocator, KVCacheManager,
                                                RaggedBatchBuilder,
                                                SequenceDescriptor)
 from deepspeed_tpu.models import transformer as tfm
+from served_kinds import assert_step_attrs
 
 
 def test_blocked_allocator():
@@ -620,6 +621,9 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     steps = [s for s in spans
              if s.name == "engine/step" and s.attrs["kind"] == kind]
     assert steps, f"no {kind} step ran"
+    if kind != "spec":  # a dense model's attributes: the parent's, no more
+        assert_step_attrs([s.attrs for s in spans
+                           if s.name == "engine/step"])
     numbers = [s.attrs["step"] for s in spans if s.name == "engine/step"]
     assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
     assert kind == "spec" or (True in ends_steady.values()

@@ -38,6 +38,7 @@ from deepspeed_tpu.inference.v2.ragged import window_bound
 from deepspeed_tpu.models import transformer as tfm
 from deepspeed_tpu.observability.trace import tracer
 from deepspeed_tpu.ops.pallas import paged_attention as pa
+from served_kinds import assert_step_attrs, refusal_cases
 from deepspeed_tpu.ops.pallas.grouped_mixed_gemm import pick_grouped_tiles
 from deepspeed_tpu.ops.pallas.mixed_gemm import (pick_gemm_tiles,
                                                  quantize_gemm_weight)
@@ -204,7 +205,7 @@ def test_engine_matches_reference():
     # a row gives a block back every 4 tokens it moves past the window
     assert engine.kv_win.trimmed >= sum((n + 24 - 8) // 4 for n in PROMPTS)
     steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
-    assert {"mixed", "decode"} <= {a["kind"] for a in steps}
+    assert_step_attrs(steps, "moe", "window")
     assert all(a["kv_blocks_read"] < a["kv_blocks_full"] for a in steps[3:])
     assert all(a["blocks_used_window"] <= 4 * engine.kv_win.bound
                for a in steps)
@@ -491,18 +492,18 @@ def test_window_past_the_longest_context_is_honoured():
 # -- (h) what does not know two pools refuses --------------------------------
 
 
-@pytest.mark.parametrize("knob,value", [
-    ("enable_prefix_cache", True), ("kv_host_pool_mb", 1),
-    ("kv_host_pool_bytes", 4096), ("kv_spill_dir", "/tmp/x"),
-    ("kv_coldstore_dir", "/tmp/x"), ("spec_mode", "self_draft"),
-    ("spec_mode", "draft")])
-def test_features_that_move_kv_blocks_refuse_a_window(knob, value):
-    """(h) Each is refused by name for a model with an active window, be it
+@pytest.mark.parametrize("over,knob", refusal_cases(
+    programs.KV, mellum_cfg(), v2_config()))
+def test_features_that_move_kv_blocks_refuse_a_window(over, knob):
+    """(h) Each row of the refusal table (``programs.REFUSED``) the kind
+    holds is refused by name for a model with an active window, be it
     Mellum2's two kinds of layer or Mistral past its window, and accepted
-    where the window is inactive."""
-    v2 = v2_config(**{knob: value})
+    where the window is inactive (the kind then holds no row)."""
+    v2 = v2_config(**over)
+    assert programs.KV.refuses(mistral_cfg(256), v2) == ()
     for cfg in (mellum_cfg(), mistral_cfg(12)):
-        with pytest.raises(ValueError, match=knob.split("_bytes")[0]):
+        with pytest.raises(ValueError, match=f"V2Config.*{knob}.*active "
+                                             "sliding window"):
             InferenceEngineV2(cfg, None, v2)
     if knob != "spec_mode":
         cfg = mistral_cfg(256)  # no context of 96 tokens reaches it

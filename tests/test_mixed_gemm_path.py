@@ -167,12 +167,24 @@ def test_greedy_serving_token_identity_pre_post_swap(monkeypatch):
 
     kernel_out = _greedy_tokens(cfg, params, prompts, max_new=8)
 
-    # pre-swap behavior: full-matrix dequant + dense dot in the model fwd
-    monkeypatch.setattr(
-        tfm, "mixed_gemm_frozen",
-        lambda x, qw: x @ mg.dequantize_gemm_weight(qw).astype(x.dtype))
+    # pre-swap behavior: full-matrix dequant + dense dot in the model fwd.
+    # The second engine has the first's sizes: from ``_memo``'s cache it
+    # would be handed the programs that closed over the kernel, and the patch
+    # would never be traced (ROADMAP D11), so the programs are built anew
+    from deepspeed_tpu.inference.v2 import programs
+
+    traced = []
+
+    def dequant_dot(x, qw, layer=None):
+        traced.append(layer)
+        w = mg.dequantize_gemm_weight(mg.layer_of_stack(qw, layer))
+        return x @ w.astype(x.dtype)
+
+    monkeypatch.setattr(programs, "_BUILD_CACHE", {})
+    monkeypatch.setattr(tfm, "mixed_gemm_frozen", dequant_dot)
     dequant_out = _greedy_tokens(cfg, params, prompts, max_new=8)
 
+    assert traced and None not in traced  # every GEMM of the layer stacks
     assert kernel_out == dequant_out
 
 

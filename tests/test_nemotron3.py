@@ -7,9 +7,6 @@ still be."""
 
 import collections
 import dataclasses
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,13 +15,13 @@ import pytest
 from benchmark.drivers.serve_ssm_moe import draw_small_tensors, published_model
 from benchmark.logit_tap import LogitTap
 from benchmark.reference import ssm_moe_decoder as reference
+from deepspeed_tpu.inference.v2 import programs
 from deepspeed_tpu.inference.v2.engine import (AdmissionError,
                                                InferenceEngineV2, V2Config)
 from deepspeed_tpu.models import ssm_hybrid
 from deepspeed_tpu.models import transformer as tfm
 from deepspeed_tpu.ops.pallas import grouped_mixed_gemm, mixed_gemm, ssm
-
-HERE = os.path.dirname(os.path.abspath(__file__))
+from served_kinds import assert_step_attrs, refusal_cases
 
 # float32 on both sides: what differs is the order of the sums (chunks of 16
 # against one token at a time, paged attention against an (S, S) mask).
@@ -261,20 +258,13 @@ def test_cancel_gives_the_slot_back(tiny):
     assert stats["state_slots"] == 2 and stats["state_slots_free"] == 2
 
 
-@pytest.mark.parametrize("over, name", [
-    (dict(enable_prefix_cache=True), "enable_prefix_cache"),
-    (dict(enable_prefix_cache=True, kv_host_pool_mb=1), "enable_prefix_cache"),
-    (dict(kv_host_pool_mb=1), "kv_host_pool_mb"),
-    (dict(kv_spill_dir="/tmp/x"), "kv_spill_dir"),
-    (dict(kv_coldstore_dir="/tmp/x"), "kv_coldstore_dir"),
-    (dict(spec_mode="self_draft"), "spec_mode"),
-    (dict(spec_mode="draft"), "spec_mode"),
-    (dict(adapter_slots=2, adapter_rank=4), "adapter_slots"),
-])
+@pytest.mark.parametrize("over, name", refusal_cases(
+    programs.STATE, tfm.get_config("tiny-nemotron3"), v2_config()))
 def test_refused_with_state_layers(tiny, over, name):
-    """(h) what cannot carry a state yet is refused by name."""
+    """(h) what cannot carry a state yet is refused by name: every row of
+    the refusal table (``programs.REFUSED``) the kind holds."""
     cfg, params, model = tiny
-    with pytest.raises(ValueError, match=f"V2Config.{name}.*state layers"):
+    with pytest.raises(ValueError, match=f"V2Config.*{name}.*state layers"):
         InferenceEngineV2(cfg, params, v2_config(**over))
 
 
@@ -289,6 +279,7 @@ def test_step_spans_carry_state_counters(tiny):
         eng.generate_all(burst=1)
         steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"
                  and "ssm_tokens" in s.attrs][-4:]
+        assert_step_attrs(steps, "moe", "state")
         names = {s.name for s in tracer.spans()}
     finally:
         tracer.enabled = was
@@ -381,69 +372,6 @@ def test_decode_kernel_against_the_recurrence(dtype):
         else:
             np.testing.assert_array_equal(new[1, r], ssm0[1, r])
     np.testing.assert_array_equal(new[0], ssm0[0])
-
-
-# ---------------------------------------------------------------------------
-# what the other models' step programs must still be
-# ---------------------------------------------------------------------------
-
-
-def _count(jaxpr, c):
-    for e in jaxpr.eqns:
-        c[e.primitive.name] += 1
-        for v in e.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
-                    _count(sub.jaxpr, c)
-                elif hasattr(sub, "eqns"):
-                    _count(sub, c)
-    return c
-
-
-def _step_programs(preset, **over):
-    cfg = tfm.get_config(preset, dtype="float32", **over)
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    e = InferenceEngineV2(cfg, params, V2Config(
-        max_tokens_per_step=32, max_seqs=4, block_size=8, num_blocks=64,
-        max_blocks_per_seq=16, dtype="float32"))
-    T, S = 32, 4
-
-    def i32(*s):
-        return jnp.zeros(s, jnp.int32)
-
-    tables = i32(S, 16)
-    if e.kv_win is not None:
-        tables = (tables, i32(S, 16))
-    mixed = jax.make_jaxpr(e._fwd)(
-        e.params, e.caches, i32(T), i32(T), i32(T), tables, i32(S), i32(S),
-        i32(S), i32(S))
-    decode = jax.make_jaxpr(e._decode_fwd)(
-        e.params, e.caches, i32(S), i32(S), tables, i32(S),
-        jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))
-    return e, {"mixed": mixed, "decode": decode}
-
-
-@pytest.mark.parametrize("name, preset, over", [
-    ("tiny-mistral", "tiny", dict(num_kv_heads=2, tie_embeddings=False)),
-    ("tiny-olmoe", "tiny-olmoe", {}),
-    ("tiny-mellum2", "tiny-mellum2", {}),
-])
-def test_other_models_programs_are_the_parents(name, preset, over):
-    """(g) a model without state layers holds no state array, no slot
-    allocator and no ``ssm_*`` / ``moe_shared`` scope, and its step programs
-    count, primitive by primitive, the equations they counted before this
-    model existed (``parent_step_program_eqns.json``: counted on the parent
-    commit by this function)."""
-    with open(os.path.join(HERE, "parent_step_program_eqns.json")) as f:
-        pinned = json.load(f)[name]
-    eng, programs = _step_programs(preset, **over)
-    assert set(eng.caches) <= {"k", "v", "k_win", "v_win"}
-    assert eng.kv.slots is None and eng.total_state_slots == 0
-    for kind, jaxpr in programs.items():
-        assert dict(_count(jaxpr.jaxpr, collections.Counter())) == \
-            pinned[kind], kind
-        text = str(jaxpr)
-        assert "ssm_" not in text and "moe_shared" not in text
 
 
 def test_nemotron_engine_holds_state_beside_one_kv_pool(tiny):

@@ -1,0 +1,138 @@
+"""The seam between the engines and the kinds of model they serve
+(``inference/v2/programs.py``: ``ServedKind``, ``kind_of``): which kind a
+preset is, what it caches against what a built engine holds, and the step
+programs of one tiny model a body, pinned equation by equation."""
+
+import collections
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.inference.v2 import programs
+from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+from deepspeed_tpu.models import transformer as tfm
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+@pytest.mark.parametrize("preset, kind", [
+    ("tiny", programs.KV), ("mistral-7b", programs.KV),
+    ("olmoe-1b-7b", programs.KV), ("mellum2-12b-a2.5b", programs.KV),
+    ("nemotron3-nano-30b-a3b", programs.STATE), ("glm-5.2", programs.LATENT),
+    ("deepseek-v2-lite", programs.LATENT)])
+def test_kind_of_names_the_kind(preset, kind):
+    cfg = tfm.get_config(preset)
+    assert programs.kind_of(cfg) is kind
+    assert kind.counters in vars(InferenceEngineV2)
+    if preset == "deepseek-v2-lite":  # no indexer: trained, not served
+        with pytest.raises(NotImplementedError, match="trained, not served"):
+            kind.arrays(cfg, V2Config())
+
+
+def _cell(name):
+    with open(os.path.join(os.path.dirname(HERE), "benchmark", "configs",
+                           name + ".json")) as f:
+        conf = json.load(f)
+    over = {k: tuple(v) if isinstance(v, list) else v
+            for k, v in conf["overrides"].items()}
+    return tfm.get_config(conf["preset"], **over), \
+        V2Config(**conf["engine"]["v2"])
+
+
+@pytest.mark.parametrize("cell, shapes, moe_layers", [
+    ("mistral-7b-w8", {"k": (32, 800, 64, 8, 128)}, 32),
+    ("olmoe-1b-7b-w8", {"k": (16, 416, 64, 16, 128)}, 16),
+    ("mellum2-12b-w8", {"k": (5, 3000, 64, 4, 128),
+                        "k_win": (15, 801, 64, 4, 128)}, 20),
+    ("nemotron3-nano-30b-w8", {"k": (2, 2048, 64, 2, 128),
+                               "ssm": (7, 65, 64, 64, 128),
+                               "conv": (7, 65, 3, 6144)}, 7),
+    ("glm-5.2-ep16-w8", {"latent": (9, 4353, 64, 640),
+                         "index": (3, 4353, 64, 128)}, 8)])
+def test_arrays_of_the_served_cells(cell, shapes, moe_layers):
+    """What each served configuration caches at its cell's sizes, on shapes
+    alone (``benchmark/configs/*.json``: ``sizing``)."""
+    cfg, v2 = _cell(cell)
+    kind = programs.kind_of(cfg)
+    arrays = kind.arrays(cfg, v2)
+    for name in list(shapes):  # V beside K, the window layers' beside both
+        if name.startswith("k"):
+            shapes["v" + name[1:]] = shapes[name]
+    assert {n: shape for n, (shape, _) in arrays.items()} == shapes
+    assert {str(dt) for n, (_, dt) in arrays.items() if n != "ssm"} == \
+        {"bfloat16"}
+    assert kind.moe_layers(cfg) == moe_layers
+    if "k_win" in shapes:  # the default: what 32 rows hold at most, and one
+        v2.num_window_blocks = 0
+        assert kind.arrays(cfg, v2)["k_win"][0][1] == 1 + 32 * 25
+
+
+def _count(jaxpr, c):
+    for e in jaxpr.eqns:
+        c[e.primitive.name] += 1
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _count(inner, c)
+    return c
+
+
+@pytest.mark.parametrize("name, preset, over, kind, cached", [
+    ("tiny-mistral", "tiny", dict(num_kv_heads=2, tie_embeddings=False),
+     programs.KV, "k v"),
+    ("tiny-olmoe", "tiny-olmoe", {}, programs.KV, "k v"),
+    ("tiny-mellum2", "tiny-mellum2", {}, programs.KV, "k v k_win v_win"),
+    ("tiny-nemotron3", "tiny-nemotron3", {}, programs.STATE,
+     "k v ssm conv"),
+    ("tiny-glm52", "tiny-glm52", {}, programs.LATENT, "latent index")])
+def test_step_programs_are_the_parents(name, preset, over, kind, cached):
+    """The lock on the three bodies: the mixed and the decode step of one tiny
+    model a body (and a shape of the first) count, primitive by primitive, the
+    equations they counted on the parent of the PR that last re-pinned them
+    (``parent_step_program_eqns.json``: counted there by this function; a
+    fold of ROADMAP D2 or D14 re-pins its entries deliberately).  A built
+    engine holds exactly the arrays its kind declares, asks it how many
+    layers route, and a program names no scope of a body it does not run."""
+    with open(os.path.join(HERE, "parent_step_program_eqns.json")) as f:
+        pinned = json.load(f)[name]
+    cfg = tfm.get_config(preset, dtype="float32", **over)
+    v2 = V2Config(max_tokens_per_step=32, max_seqs=4, block_size=8,
+                  num_blocks=64, max_blocks_per_seq=16, dtype="float32")
+    e = InferenceEngineV2(cfg, tfm.init_params(jax.random.PRNGKey(0), cfg),
+                          v2)
+    assert e.kind is kind and set(e.caches) == set(cached.split())
+    assert {n: (a.shape, a.dtype) for n, a in e.caches.items()} == \
+        kind.arrays(e.model_cfg, v2)
+    assert e._moe_layers == kind.moe_layers(cfg)
+    assert (e.kv.slots is not None) == ("ssm" in e.caches) == \
+        bool(e.total_state_slots)
+    assert (e.kv_win is not None) == ("k_win" in e.caches)
+    T, S = 32, 4
+
+    def i32(*s):
+        return jnp.zeros(s, jnp.int32)
+
+    tables = i32(S, 16)
+    if e.kv_win is not None:
+        tables = (tables, i32(S, 16))
+    programs_ = {
+        "mixed": jax.make_jaxpr(e._fwd)(
+            e.params, e.caches, i32(T), i32(T), i32(T), tables, i32(S),
+            i32(S), i32(S), i32(S),
+            *((None, None, i32(S)) if "ssm" in e.caches else ())),
+        "decode": jax.make_jaxpr(e._decode_fwd)(
+            e.params, e.caches, i32(S), i32(S), tables, i32(S),
+            jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))}
+    scopes = {programs.STATE: ("ssm_", "moe_shared"),
+              programs.LATENT: ("latent", "dsa_")}
+    for step, jaxpr in programs_.items():
+        assert dict(_count(jaxpr.jaxpr, collections.Counter())) == \
+            pinned[step], step
+        text = str(jaxpr)
+        for other, names in scopes.items():
+            if other is not kind:
+                assert not any(n in text for n in names), (step, names)

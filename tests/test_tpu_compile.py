@@ -185,9 +185,7 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     shapes)."""
     from deepspeed_tpu.inference.quantization import quantize_model_params
     from deepspeed_tpu.inference.v2 import engine as v2e
-    from deepspeed_tpu.inference.v2.programs import (latent_arrays,
-                                                     pool_layers,
-                                                     state_arrays)
+    from deepspeed_tpu.inference.v2.programs import kind_of
     from deepspeed_tpu.models import transformer as tfm
 
     v2 = v2e.V2Config(**{**dict(
@@ -200,23 +198,18 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
         jax.eval_shape(lambda key: quantize_model_params(
             tfm.init_params(key, cfg), bits=8, group=group),
             jax.random.PRNGKey(0)))
-    layers = pool_layers(cfg, v2)
-    pool = (layers[0], v2.num_blocks, v2.block_size, cfg.kv_heads,
-            cfg.head_dim)
-    caches = {"k": sds(pool, jnp.bfloat16), "v": sds(pool, jnp.bfloat16)}
-    for name, (shape, dtype) in state_arrays(cfg, v2).items():
-        caches[name] = sds(shape, dtype)  # a model with state layers
-    latent = latent_arrays(cfg, v2)
-    if latent:  # a latent pool and the indexer's, in place of K and V
-        caches = {k: sds(shape, jnp.bfloat16) for k, shape in latent.items()}
-        pool = (latent["latent"], latent["index"])
+    # the caches as the engine allocates them: what the model's kind says
+    arrays = kind_of(cfg).arrays(cfg, v2)
+    caches = {name: sds(shape, dtype)
+              for name, (shape, dtype) in arrays.items()}
     rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
     tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
-    if len(layers) == 2:  # the window layers' pool and table beside them
-        win = (layers[1], v2.num_window_blocks) + pool[2:]
-        caches.update(k_win=sds(win, jnp.bfloat16),
-                      v_win=sds(win, jnp.bfloat16))
-        pool, tables = (pool, win), (tables, tables)
+    if "latent" in arrays:  # a latent pool and the indexer's
+        pool = (arrays["latent"][0], arrays["index"][0])
+    elif "k_win" in arrays:  # the window layers' pool and table beside them
+        pool, tables = (arrays["k"][0], arrays["k_win"][0]), (tables, tables)
+    else:
+        pool = arrays["k"][0]
     if program == "decode_step":
         lowered = v2e.build_decode_forward(cfg, v2).lower(
             params, caches, rows(jnp.int32), rows(jnp.int32), tables,
@@ -243,7 +236,7 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
             rows(jnp.int32),
             # a model with state layers: each row's state slot, behind the
             # two adapter arguments it never has
-            *([None, None, rows(jnp.int32)] if cfg.mixer_pattern else []))
+            *([None, None, rows(jnp.int32)] if "ssm" in arrays else []))
     return lowered, pool, params
 
 
