@@ -140,6 +140,19 @@ class Sizes:
     latent_train_micro: int = 2
     latent_train_seq: int = 8192
     latent_train_steps: int = 2
+    # -- EVA attention: four layers of EvaByte-6.5B at its published widths
+    # (0.81 B parameters in int8), both pools sized as the cell sizes them a
+    # row (a whole window of 32 blocks, a summary block a 1,024 tokens);
+    # prompts that close one and two windows in prefill and one that decode
+    # carries over the next edge
+    eva_preset: str = "evabyte-6.5b"
+    eva_layers: int = 4
+    eva_max_blocks_per_seq: int = 96
+    eva_requests: Tuple[Tuple[int, int], ...] = (
+        (4090, 12), (2300, 8), (600, 12), (9, 14))
+    # the tapped rows of all eight heads against the reference (median,
+    # worst: the serving cell's bounds)
+    eva_logit_tol: Tuple[float, float] = (0.12, 0.3)
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -1258,6 +1271,95 @@ def phase_latent_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
                              f"{routed}")
 
 
+def phase_eva_server(sz: Sizes, seed: int, check_kernels: bool = True
+                     ) -> None:
+    """A model with EVA attention (``eva_layers`` layers of EvaByte-6.5B, W8A16
+    at group 256, eight output heads) through ``InferenceEngineV2``: a
+    tumbling window pool and a summary pool in every layer, prompts that
+    close windows in prefill and in decode.  The logits of all eight heads
+    that its own step programs give, tapped, lie within ``eva_logit_tol`` of
+    the plain reference (``benchmark/reference/eva_byte_decoder.py``) over
+    the same codes; attention and the summariser ran their kernels and every
+    GEMM its own (the ring's ``kernel/*_tiles`` events, none fallen back; a
+    ``tpu_custom_call`` in both compiled step programs); the window's blocks
+    went back whole; both pools whole after the drain."""
+    from benchmark.drivers import serve_eva
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-eva-int8"
+    cfg = tfm.get_config(sz.eva_preset, num_layers=sz.eva_layers,
+                         dtype="bfloat16", param_dtype="bfloat16")
+    log(phase, preset=sz.eva_preset, layers=cfg.num_layers,
+        window=cfg.eva_window, chunk=cfg.eva_chunk,
+        heads_out=cfg.num_pred_heads,
+        params_m=round(cfg.num_params() / 1e6, 1))
+    params = serve_eva.draw_norm_offsets(jax.jit(
+        lambda k: tfm.init_params(k, cfg))(jax.random.PRNGKey(seed)), seed)
+    tracer.clear()
+    rows = min(sz.max_seqs, len(sz.eva_requests))
+    per_row = cfg.eva_window // sz.block_size
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=rows,
+        block_size=sz.block_size,
+        num_blocks=1 + rows * -(-sz.eva_max_blocks_per_seq // cfg.eva_chunk),
+        num_window_blocks=1 + rows * per_row,
+        max_blocks_per_seq=sz.eva_max_blocks_per_seq, quantize_bits=8,
+        quantize_group=256))
+    del params
+    check = {"logit_prompts": [n for n, _ in sz.eva_requests],
+             "logit_tokens": max(n for _, n in sz.eva_requests)}
+    tapped = serve_eva.tap_logits(engine, cfg, seed, check)
+    for m in engine._managers:
+        m.check_consistency()
+    if not engine.drained() or engine.kv.reserved or engine.kv_win.reserved:
+        raise AssertionError(f"{phase}: a pool leaked blocks")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    kinds = sorted({a["kind"] for name, a in events
+                    if name == "kernel/eva_attention_tiles"})
+    read, full = (sum(a.get(k, 0) for a in steps) for k in (
+        "eva_window_keys", "eva_keys_full"))
+    read += sum(a.get("eva_summary_keys", 0) for a in steps)
+    closed = sum(a.get("eva_windows_closed", 0) for a in steps)
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        pool_blocks={"summary": engine.kv.allocator.num_blocks,
+                     "window": engine.kv_win.allocator.num_blocks},
+        windows_closed=closed, window_blocks_freed=engine.kv_win.trimmed,
+        eva_keys_read_vs_full_pct=round(100 * read / full, 1),
+        kernel_events=len(events), attention_kernels=kinds)
+    check_step_copies(phase)
+    fallen = [e for e in events if "fallback" in e[1]]
+    if check_kernels and (fallen or kinds != ["decode", "prefill"]):
+        raise AssertionError(f"{phase}: kernels fallen back: {fallen}; "
+                             f"EVA attention kernels traced: {kinds}")
+    want = sum((n + check["logit_tokens"]) // cfg.eva_window  # every row
+               for n, _ in sz.eva_requests)  # decodes the longest budget
+    if closed != want or engine.kv_win.trimmed != want * per_row:
+        raise AssertionError(
+            f"{phase}: {closed} windows closed and {engine.kv_win.trimmed} "
+            f"window blocks freed, the requests pass {want} edges")
+    if check_kernels:
+        require_kernel(phase, "decode_step", engine._decode_fwd.lower(
+            *_decode_shapes(engine)).compile().as_text())
+    model = serve_eva.published_model(cfg)
+    served_params = engine.params
+    del engine
+    gc.collect()
+    errs = np.asarray([e for seq in serve_eva.row_errors(
+        served_params, model, tapped) for e in seq])
+    median, worst = float(np.median(errs)), float(errs.max())
+    log(phase, tapped_rows=len(errs), heads=cfg.num_pred_heads,
+        logit_err_median=round(median, 4), logit_err_worst=round(worst, 4),
+        allowed=sz.eva_logit_tol)
+    if not (median <= sz.eva_logit_tol[0] and worst <= sz.eva_logit_tol[1]):
+        raise AssertionError(
+            f"{phase}: the step programs' logits lie {median:.3f} (median) "
+            f"and {worst:.3f} (worst) from the reference's")
+    memory_line(phase, jax.local_devices()[0])
+
+
 def _decode_shapes(engine):
     """The decode step's arguments as shapes, for a compile from the cache."""
     v2 = engine.cfg
@@ -1267,9 +1369,10 @@ def _decode_shapes(engine):
 
     shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                           (engine.params, engine.caches))
+    table = jax.ShapeDtypeStruct((v2.max_seqs, v2.max_blocks_per_seq),
+                                 jnp.int32)
     return (*shapes, rows(jnp.int32), rows(jnp.int32),
-            jax.ShapeDtypeStruct((v2.max_seqs, v2.max_blocks_per_seq),
-                                 jnp.int32),
+            (table, table) if engine.kv_win is not None else table,
             rows(jnp.int32), rows(jnp.float32),
             jax.ShapeDtypeStruct((2,), jnp.uint32), rows(jnp.int32))
 
@@ -1390,6 +1493,8 @@ def main() -> int:
         phase_ssm_moe_server(sz, args.seed)
         gc.collect()
         phase_latent_moe_server(sz, args.seed)
+        gc.collect()
+        phase_eva_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

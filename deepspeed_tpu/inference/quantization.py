@@ -14,7 +14,11 @@ experts of an MoE layer (``moe.w_in`` / ``w_gate`` / ``w_out``, stacked
 grouped mixed GEMM (``ops/pallas/grouped_mixed_gemm``).
 
 Embeddings / lm_head / norms / the MoE router stay high-precision (gather and tiny tensors
-gain nothing from int codes), matching the reference's exclude list.
+gain nothing from int codes), matching the reference's exclude list.  So do
+an EVA layer's ``eva_phi`` and ``eva_mu`` (two vectors a head, read by the
+summariser and no GEMM) and the eight output heads of a byte model
+(``lm_head``, 4096 x 2560, multiplied in float32): codes are for ``wq``,
+``wk``, ``wv``, ``wo`` and the MLP's three, as a Mistral layer's.
 """
 
 from __future__ import annotations
@@ -63,13 +67,37 @@ def pad_expert_width(w: jax.Array, key: str) -> jax.Array:
     return jnp.pad(w, pad)
 
 
+def pad_mlp_width(w: jax.Array, key: str) -> jax.Array:
+    """A dense MLP's matrix ``(..., K, N)`` with the inner width zero-padded
+    to the next multiple of 1024 where ``pick_gemm_tiles`` would find it no
+    lane-aligned divisor of 512 columns or more (EvaByte: 11008 = 2^8 x 43
+    → 11264 = 2^10 x 11; its widest is 256, rows of 256 B in HBM where
+    "tiles of equal bytes measured faster wide than deep", and along K one
+    group a step or all 43).  Exact, as ``pad_expert_width`` is: a zero
+    column of ``w_in`` / ``w_gate`` gives 0 for every gated activation, and
+    meets a zero row of ``w_out``.  The padded bytes (2.3 % of the MLP's)
+    are fetched; every width a served configuration had before this one has
+    a wide divisor (Mistral 14336: 3584) and is left as it is."""
+    axis = -2 if key == "w_out" else -1
+    width = w.shape[axis]
+    wide = max((d for d in range(128, min(width, 4096) + 1, 128)
+                if width % d == 0), default=width)
+    if w.ndim < 2 or width <= 4096 or wide >= 512:
+        return w
+    pad = [(0, 0)] * w.ndim
+    pad[axis] = (0, -width % 1024)
+    return jnp.pad(w, pad)
+
+
 def quantize_model_params(params: Dict[str, Any], bits: int = 8,
                           group: int = 256,
                           quantize=quantize_gemm_weight) -> Dict[str, Any]:
     """Replace layer projection weights with QuantizedWeight nodes."""
     def walk(tree, parent=None):
         if isinstance(tree, dict):
-            return {k: (quantize(pad_expert_width(v, k) if parent == "moe"
+            return {k: (quantize({"moe": pad_expert_width,
+                                  "mlp": pad_mlp_width}[parent](v, k)
+                                 if parent in ("moe", "mlp")
                                  and k in ("w_in", "w_gate", "w_out") else v,
                                  bits=bits, group=group)
                         if (parent in _QUANT_PARENTS and k in _QUANT_KEYS

@@ -34,7 +34,7 @@ from ...ops.pallas.latent_attention import TILE_Q
 from .programs import (REFUSED, _decode_body, _memo,  # noqa: F401
                        _with_stats, build_cow_copy, build_decode_forward,
                        build_multi_decode_forward, build_ragged_forward,
-                       build_unpack, kind_of, layer_plan,
+                       build_unpack, kind_of,
                        mixed_step_attn_tiles, sample_rows)
 from .ragged import (DecodeStateTable, KVCacheManager, RaggedBatch,
                      RaggedBatchBuilder, SequenceDescriptor, StepLayout,
@@ -234,32 +234,42 @@ class InferenceEngineV2:
         # pool as they fall behind the window (ragged.KVCacheManager); where
         # every layer is windowed the one pool is.  ``ssm``: per-SEQUENCE
         # state beside the paged K/V, a slot a sequence from the one manager.
-        # A latent model's two pools share the ONE block table and allocator
+        # A latent model's two pools share the ONE block table and allocator.
+        # An EVA model's: ``kv`` holds the summaries' blocks, which grow by a
+        # window's worth whenever one closes, and ``kv_win`` the current
+        # window's, which go back whole at that same step
         self.kind = kind_of(self.model_cfg)
         arrays = self.kind.arrays(self.model_cfg, self.cfg)
         two_pools = "k_win" in arrays
         state_slots = self.cfg.max_seqs if "ssm" in arrays else 0
-        self._window = max(k.window for k in  # 0: none active
-                           layer_plan(self.model_cfg, self.cfg))
+        self._window = self.kind.window(self.model_cfg, self.cfg)  # 0: none
+        # a window that TUMBLES (freed whole when it closes) leaves this many
+        # entries in the main pool (its summaries), which then holds those
+        # and no tokens; 0: the window slides
+        self._per_window = self.kind.closes(self.model_cfg)
         self._refuse()
         max_chunk = self.cfg.max_tokens_per_step
         # one block of each pool reserved as write-scratch for padded tokens
         self.kv = KVCacheManager(
             self.cfg.num_blocks - 1, self.cfg.block_size,
             self.cfg.max_blocks_per_seq,
-            window=0 if two_pools else self._window,
-            max_chunk=max_chunk,
+            window=self._window if self._per_window or not two_pools else 0,
+            max_chunk=max_chunk, per_window=self._per_window,
             state_slots=state_slots)
         self.kv_win = None
         if two_pools:
             self.kv_win = KVCacheManager(
                 arrays["k_win"][0][1] - 1, self.cfg.block_size,
                 self.cfg.max_blocks_per_seq, window=self._window,
-                max_chunk=max_chunk, chain="win_")
+                max_chunk=max_chunk, chain="win_",
+                tumbling=bool(self._per_window))
         self._managers = [self.kv] + ([self.kv_win] if self.kv_win else [])
-        # the manager whose blocks are freed behind the window, if any, and
-        # how many layers read a window (the step's counters)
+        # the manager whose blocks are freed behind the window, if any; the
+        # managers whose chains grow while a sequence runs (a decode step
+        # opens their blocks); how many layers read a window (the step's
+        # counters)
         self._windowed = (self.kv_win or self.kv) if self._window else None
+        self._growing = [m for m in self._managers if m.window]
         self._win_layers = (arrays["k_win" if two_pools else "k"][0][0]
                             if self._window else 0)
         self.prefix_cache = None
@@ -353,7 +363,7 @@ class InferenceEngineV2:
             self.cfg.max_seqs, self.cfg.max_blocks_per_seq,
             self.cfg.max_blocks_per_seq * self.cfg.block_size,
             two_pools=self.kv_win is not None,
-            main_grows=self._windowed is self.kv)
+            main_grows=self.kv in self._growing)
         # the mixed step's prefill attention tiling, for ``attn_q_slots``
         self._attn_tiles = mixed_step_attn_tiles(self.model_cfg, self.cfg)
         self._prefilling = 0  # running seqs still before their first token
@@ -988,7 +998,8 @@ class InferenceEngineV2:
             if len(picks) >= self.cfg.max_seqs or budget <= 0:
                 break
             n = min(seq.cur_len - seq.seen_tokens, budget) or 1
-            n = min(n, budget)
+            # (a window that tumbles ends a chunk at its edge)
+            n = min(n, budget, self._managers[-1].chunk_cap(seq.seen_tokens))
             if not all(m.ensure_capacity(seq, n) for m in self._managers):
                 continue  # stalled on memory this step
             picks.append((seq, n))
@@ -1006,7 +1017,8 @@ class InferenceEngineV2:
                     and seq.seen_tokens == 0
                     and self.cfg.spec_mode != "draft"):
                 self._match_prefix(seq)
-            n = min(seq.cur_len - seq.seen_tokens, budget)
+            n = min(seq.cur_len - seq.seen_tokens, budget,
+                    self._managers[-1].chunk_cap(seq.seen_tokens))
             total_needed = (seq.cur_len - seq.seen_tokens) + seq.max_new_tokens
             if n <= 0 or not self._reserve(seq, total_needed, n):
                 if seq.blocks or seq.seen_tokens:
@@ -1142,23 +1154,24 @@ class InferenceEngineV2:
 
     def _window_open_blocks(self) -> None:
         """Before a decode step: the rows whose next token starts a block
-        take it from the windowed pool (admission reserved it)."""
-        t, m = self.table, self._windowed
-        bs = self.cfg.block_size
-        table = t.win_tables if m is self.kv_win else t.block_tables
-        for r in np.nonzero(t.active & (t.ctx % bs == 0))[0]:
-            chain, j = m.chain(t.seq_at[int(r)]), int(t.ctx[r]) // bs
-            if len(chain) <= j:
-                chain.extend(m.allocator.allocate(j + 1 - len(chain)))
-                table[r, j] = chain[j]
+        (or completes a window whose summaries need theirs) take it from the
+        pool that grows (admission reserved it)."""
+        t = self.table
+        for m in self._growing:
+            table = t.win_tables if m is self.kv_win else t.block_tables
+            for r in np.nonzero(t.active & m.opens_at(t.ctx))[0]:
+                chain = m.chain(t.seq_at[int(r)])
+                have, need = len(chain), m.blocks_for(int(t.ctx[r]) + 1)
+                if have < need:
+                    chain.extend(m.allocator.allocate(need - have))
+                    table[r, have:need] = chain[have:]
 
     def _window_trim_rows(self) -> None:
         """After a decode step: the rows whose oldest visible key just left
-        a block give that block back."""
+        a block (a tumbling window: whose window just closed) give back what
+        no later query reads."""
         t, m = self.table, self._windowed
-        oldest = t.ctx - self._window + 1  # key the next query still reads
-        for r in np.nonzero(t.active & (oldest > 0)
-                            & (oldest % self.cfg.block_size == 0))[0]:
+        for r in np.nonzero(t.active & m.trims_at(t.ctx))[0]:
             m.trim(t.seq_at[int(r)], int(t.ctx[r]))
 
     # -- what a kind counts of a step (programs.ServedKind.counters): ``start``
@@ -1260,6 +1273,38 @@ class InferenceEngineV2:
                 (-(-n[~one] // TILE_Q) * TILE_Q).sum() + one.sum())
         return counts
 
+    def _count_eva(self, start: "np.ndarray", n: "np.ndarray", mixed: bool
+                   ) -> Dict[str, Any]:
+        """An EVA model's step, summed over rows and layers.  The keys its
+        queries have to READ: of the row's window up to its newest token
+        (``eva_window_keys``) and one summary a chunk of every window the row
+        has closed (``eva_summary_keys``), beside what full attention would
+        read (``eva_keys_full``: the row's whole context); the (query, key)
+        pairs it multiplies (``eva_query_keys``: a query at ``p`` sees ``p %
+        window + 1`` keys and ``p // window`` windows' summaries).  Over rows
+        alone: the windows this step completes and the summaries a layer
+        writes for them; the blocks both pools have out; of a mixed step the
+        query slots of its tiles."""
+        start, n = _live_rows(start, n)
+        L, W, per = self.model_cfg.num_layers, self._window, self._per_window
+        last = start + n - 1  # a row's tokens lie in one window
+        cols = np.arange(int(n.max(initial=0)))[None]
+        pos = np.where(cols < n[:, None], start[:, None] + cols, -1)
+        closed = int(((start + n) % W == 0).sum())
+        used = [m.allocator.num_blocks - m.allocator.free_blocks
+                for m in self._managers]
+        counts = {
+            "eva_window_keys": int((last % W + 1).sum()) * L,
+            "eva_summary_keys": int((last // W * per).sum()) * L,
+            "eva_keys_full": int((last + 1).sum()) * L,
+            "eva_query_keys": int(np.where(
+                pos >= 0, pos % W + 1 + pos // W * per, 0).sum()) * L,
+            "eva_windows_closed": closed, "eva_chunks_written": closed * per,
+            "blocks_used_summary": used[0], "blocks_used_window": used[1]}
+        if mixed:
+            counts["attn_q_slots"] = int(self._attn_tiles.slots(n).sum())
+        return counts
+
     def _row_temps(self, temperature: float) -> "np.ndarray":
         """Effective per-row temperature vector: rows whose request pinned a
         temperature keep it; rows that didn't (temp < 0) inherit the
@@ -1324,7 +1369,7 @@ class InferenceEngineV2:
                 or self._spec_fwd is not None):
             return
         sp = tracer.begin("engine/stage", **sub)
-        if self._windowed is not None:
+        if self._growing:
             self._window_open_blocks()
         buf = self._pack_decode(temperature)
         self._h2d = None
@@ -1416,7 +1461,7 @@ class InferenceEngineV2:
         self.fast_steps += 1
         t = self.table
         sp = tracer.begin("engine/h2d", **sub)
-        if self._windowed is not None:
+        if self._growing:
             self._window_open_blocks()
         self._step_counts = self._count(t.ctx, t.active, False)
         # staged by the step before (the span then holds the check alone) or
@@ -1689,7 +1734,8 @@ class InferenceEngineV2:
                           else seq.temperature)
             seeds[row] = np.int32(np.uint32(seq.seed & 0xFFFFFFFF))
         sampled = _with_stats(
-            sample_rows(logits, jnp.asarray(temps), self._step_rng(rng),
+            sample_rows(tfm.next_token_logits(logits, self.model_cfg),
+                        jnp.asarray(temps), self._step_rng(rng),
                         jnp.asarray(seeds)), moe_stats)
         self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp)

@@ -3,8 +3,8 @@
 What KIND of model is served is decided once (``kind_of`` → ``ServedKind``:
 what it caches, how its layers run, what a step's rows tell them, what it
 is refused, what its step counts).  ``serving_layers`` is the kind's body
-(``attention_layers``, ``hybrid_layers``, ``latent_layers``) under three
-callers: the mixed step (``build_ragged_forward``: chunks of prefill and
+(``attention_layers``, ``hybrid_layers``, ``latent_layers``, ``eva_layers``)
+under three callers: the mixed step (``build_ragged_forward``: chunks of prefill and
 decode tokens in one ragged batch), the decode step (``_decode_body``: one
 token a row) and the verify step of speculation (``spec.py:verify_body``:
 ``Q`` positions a row); its docstring is the contract of a caller.  The
@@ -271,9 +271,13 @@ def tables_of(block_tables) -> tuple:
 
 def pools_of(caches) -> list:
     """``caches`` as one (K, V) pair a pool (a latent model: its one pool of
-    latents and the indexer's keys, which share the block table)."""
+    latents and the indexer's keys, which share the block table; an EVA
+    model: the summaries', then the window's), in the tables' order."""
     if "latent" in caches:
         return [(caches["latent"], caches["index"])]
+    if "k_sum" in caches:
+        return [(caches["k_sum"], caches["v_sum"]),
+                (caches["k_win"], caches["v_win"])]
     return [(caches["k"], caches["v"])] + (
         [(caches["k_win"], caches["v_win"])] if "k_win" in caches else [])
 
@@ -678,6 +682,118 @@ def latent_layers(params, caches, x, positions, write_at, attend,
     return x, {"latent": latent, "index": index}, stats
 
 
+@dataclasses.dataclass(frozen=True)
+class EvaRows:
+    """What an EVA model's layers have to know of a step's rows: both block
+    tables, each row's first position and its tokens this step (a decode
+    step: one where the row is active), and of a mixed step where each row's
+    tokens begin in the flat batch."""
+    win_tables: jax.Array
+    sum_tables: jax.Array
+    start: jax.Array
+    n: jax.Array
+    q_start: jax.Array = None
+
+
+def eva_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
+    """What a model with EVA attention caches, two pools in EVERY layer:
+    ``k_win`` / ``v_win``, the K and V of a sequence's current window by
+    token (``num_window_blocks`` blocks; 0: ``max_seqs`` whole windows and
+    the scratch block: a row's chunk ends at the window's edge, so it never
+    holds more), and ``k_sum`` / ``v_sum``, one entry a chunk of every window
+    it has closed (``num_blocks`` blocks).  The engine's main table is the
+    summaries', its second the window's."""
+    from ...ops.pallas.eva_attention import check_geometry
+
+    c, bs = model_cfg, v2.block_size
+    check_geometry(c.eva_window, c.eva_chunk, bs)
+    win = _kv_pool(c, v2, c.num_layers, v2.num_window_blocks
+                   or 1 + v2.max_seqs * (c.eva_window // bs))
+    summ = _kv_pool(c, v2, c.num_layers, v2.num_blocks)
+    return {"k_sum": summ, "v_sum": summ, "k_win": win, "v_win": win}
+
+
+def eva_rows(tables, start, n, flat=None) -> EvaRows:
+    """``EvaRows`` of a step: ``state_rows``' arguments."""
+    return EvaRows(tables[1], tables[0], start, n.astype(jnp.int32),
+                   None if flat is None else flat[0])
+
+
+def eva_layers(params, caches, x, positions, write_at, attend,
+               model_cfg: tfm.TransformerConfig, v2, adapters=None,
+               slots=None, valid=None, rows: EvaRows = None):
+    """``serving_layers`` for a model with EVA attention (``models/eva.py``):
+    one ``lax.scan`` over identical layers, the four pools on the carry.  It
+    takes no ``attend``: a layer writes the step's K and V into the window
+    pool, attends over the window and the summaries behind it under one
+    softmax (``ops/pallas/eva_attention.py``), and, for the rows whose
+    window this step completes, makes that window's summaries from the pool
+    it has just written (the host frees the window's blocks after the
+    step)."""
+    from ...ops.pallas import eva_attention as ea
+
+    cfg = model_cfg
+    if rows is None:
+        raise ValueError("a model with EVA attention is served by the mixed "
+                         "and decode steps only")
+    blk_ids, offsets = write_at
+    lead = x.shape[:-1]
+    nh, hd = cfg.num_heads, cfg.head_dim
+    W, C = cfg.eva_window, cfg.eva_chunk
+    cos_full, sin_full = tfm.rope_table_of(
+        v2.max_blocks_per_seq * v2.block_size, cfg.rot_dim,
+        cfg.rope_of("full"))
+    # the window a row's last token of this step completes, or -1
+    ends = rows.start + rows.n
+    closing = jnp.where((rows.n > 0) & (ends % W == 0), ends // W - 1, -1)
+    both = (rows.win_tables, rows.sum_tables)
+    size = dict(window=W, chunk=C)
+    layers, layer_params = hoist_quantized(params["layers"])
+
+    def layer_body(carry, inp):
+        x, k_win, v_win, k_sum, v_sum = carry
+        sliced, layer = inp
+        lp = layer_params(sliced, layer)
+        p = lp["attn"]
+        a_in = tfm._norm(x, lp["ln1"], cfg.norm, cfg.norm_eps)
+        q, k, v = (tfm._lin(a_in, p, w, b).reshape(lead + (nh, hd))
+                   for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
+        q = tfm.rope_at(q, cos_full, sin_full, positions)
+        k = tfm.rope_at(k, cos_full, sin_full, positions)
+        with jax.named_scope("cache_write"):
+            k_win = k_win.at[layer, blk_ids[1], offsets].set(
+                k.astype(k_win.dtype))
+            v_win = v_win.at[layer, blk_ids[1], offsets].set(
+                v.astype(v_win.dtype))
+        pools = (k_win, v_win, k_sum, v_sum)
+        if rows.q_start is None:
+            with jax.named_scope("eva_attention_decode"):
+                o = ea.eva_decode_attention(q, *pools, layer, *both,
+                                            rows.start, rows.n > 0, **size)
+        else:
+            with jax.named_scope("eva_attention_prefill"):
+                o = ea.eva_prefill_attention(q, *pools, layer, *both,
+                                             rows.q_start, rows.start,
+                                             rows.n, **size)
+        with jax.named_scope("eva_summarize"):
+            k_sum, v_sum = ea.eva_summarize(
+                *pools, layer, *both, closing, p["eva_phi"], p["eva_mu"],
+                **size)
+        x = x + tfm._lin(o.reshape(lead + (nh * hd,)), p, "wo", "bo")
+        m_in = tfm._norm(x, lp["ln2"], cfg.norm, cfg.norm_eps)
+        x = x + _ffn(m_in, lp, cfg, valid)[0]
+        return (x, k_win, v_win, k_sum, v_sum), None
+
+    num_layers = jax.tree.leaves(layers)[0].shape[0]
+    (x, k_win, v_win, k_sum, v_sum), _ = jax.lax.scan(
+        layer_body, (x, caches["k_win"], caches["v_win"], caches["k_sum"],
+                     caches["v_sum"]),
+        (layers, jnp.arange(num_layers, dtype=jnp.int32)))
+    x = tfm._norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+    return x, {"k_sum": k_sum, "v_sum": v_sum, "k_win": k_win,
+               "v_win": v_win}, None
+
+
 def attention_layers(params, caches, x, positions, write_at, attend,
                      model_cfg: tfm.TransformerConfig, v2, adapters=None,
                      slots=None, valid=None, rows=None):
@@ -809,7 +925,12 @@ def attention_layers(params, caches, x, positions, write_at, attend,
 @dataclasses.dataclass(frozen=True)
 class ServedKind:
     """One kind of served model and all that depends on which it is: the
-    engines and the step programs ask IT, never the configuration's fields."""
+    engines and the step programs ask IT, never the configuration's fields.
+    Four today: ``KV`` (attention + FFN over one or two paged K/V pools, a
+    window that slides), ``STATE`` (state slots beside paged K/V),
+    ``LATENT`` (a latent pool and an indexer's keys) and ``EVA`` (a window
+    pool that tumbles and a pool of the closed windows' summaries, in every
+    layer)."""
     name: str  # as a refusal names the model
     #: ``(model_cfg, v2) -> {name: (shape, dtype)}``, every array of
     #: ``caches``: the engine allocates them as they come and reads its
@@ -829,6 +950,14 @@ class ServedKind:
     #: ``generate_all`` may decode several tokens in one program (a kind
     #: whose every step counts may not)
     bursts: bool = False
+    #: ``(model_cfg, v2) ->`` the window whose blocks go back to their pool
+    #: while the sequence runs (0: none is active)
+    window: Callable = lambda c, v2: max(
+        k.window for k in layer_plan(c, v2))
+    #: ``model_cfg ->`` the entries a window leaves in the MAIN pool when it
+    #: closes (0: the window slides a token at a time and leaves nothing;
+    #: else it TUMBLES: freed whole, at the step that wrote its last token)
+    closes: Callable = lambda c: 0
 
 
 def kind_of(model_cfg: tfm.TransformerConfig) -> ServedKind:
@@ -836,6 +965,8 @@ def kind_of(model_cfg: tfm.TransformerConfig) -> ServedKind:
     that reads the configuration to tell."""
     if model_cfg.kv_lora_rank:
         return LATENT
+    if model_cfg.eva_window:
+        return EVA
     return STATE if model_cfg.mixer_pattern else KV
 
 
@@ -896,6 +1027,19 @@ LATENT = ServedKind(
     because="a model with latent attention (kv_lora_rank > 0), whose pools "
             "hold a latent a token and the indexer's keys and no K or V "
             "heads: {does}")
+EVA = ServedKind(
+    name="a model with EVA attention (eva_window > 0: a tumbling window of "
+         "exact keys and a learned summary a chunk behind it)",
+    arrays=eva_arrays, layers=eva_layers, step_rows=eva_rows,
+    moe_layers=lambda c: 0, counters="_count_eva",
+    refuses=lambda c, v2: tuple(REFUSED),
+    because="a model with EVA attention (eva_window > 0), which keeps the K "
+            "and V of its current window only and one summary a chunk of "
+            "the windows it has closed: {does}, and a closed window has no "
+            "K or V blocks left to share, move or mask (a summary cannot be "
+            "unmade, and is no prefix's alone to reuse)",
+    window=lambda c, v2: c.eva_window,
+    closes=lambda c: c.eva_window // c.eva_chunk)
 
 
 def serving_layers(params, caches, x, positions, write_at, attend,
@@ -1055,8 +1199,9 @@ def build_decode_forward(model_cfg: tfm.TransformerConfig, v2):
         logits, caches, moe_stats = _decode_body(
             params, caches, token_ids, position_ids, block_tables,
             context_lens, model_cfg, v2, *adapter_args)
-        return _with_stats(sample_rows(logits, temps, rng, seeds),
-                           moe_stats), caches
+        return _with_stats(
+            sample_rows(tfm.next_token_logits(logits, model_cfg), temps, rng,
+                        seeds), moe_stats), caches
 
     return _memo(("decode_fwd", model_cfg, dataclasses.astuple(v2)),
                  lambda: jax.jit(decode_step, donate_argnums=(1,)))
@@ -1089,7 +1234,8 @@ def build_multi_decode_forward(model_cfg: tfm.TransformerConfig, v2,
                                              block_tables, ctx, model_cfg, v2,
                                              *adapter_args)
             rng, step_rng = jax.random.split(rng)
-            nxt = sample_rows(logits, temps, step_rng, seeds)
+            nxt = sample_rows(tfm.next_token_logits(logits, model_cfg),
+                              temps, step_rng, seeds)
             return (caches, nxt, pos + alive, ctx + alive, rng), nxt
 
         (caches, _, _, _, _), toks = jax.lax.scan(

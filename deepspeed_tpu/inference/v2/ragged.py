@@ -191,8 +191,9 @@ class SequenceDescriptor:
     #: for the chain (a windowed pool allocates as the sequence advances).
     first_block: int = 0
     reserved_blocks: int = 0
-    #: the same three for the window layers' pool of a model with two kinds
-    #: of attention layer (its global layers' chain is ``blocks``)
+    #: the same three for the second pool: the window layers' of a model
+    #: with two kinds of attention layer (its global layers' chain is
+    #: ``blocks``), the window's of an EVA model (``blocks``: its summaries')
     win_blocks: List[int] = dataclasses.field(default_factory=list)
     win_first_block: int = 0
     win_reserved_blocks: int = 0
@@ -231,7 +232,20 @@ class KVCacheManager:
     what fell behind the window; the table stays indexed by logical block,
     its freed entries stale and never read.  ``chain`` names the
     ``SequenceDescriptor`` fields the pool's chain lives in (``"win_"`` for
-    the second pool of a model with both kinds of layer).
+    the second pool of a model with two).
+
+    ``tumbling`` beside ``window`` (EVA attention, ``models/eva.py``): the
+    window does not slide a token at a time but is freed WHOLE, by the
+    ``trim`` after the step that wrote its last token (``next_pos`` a
+    multiple of ``window``); a row's chunk ends at the window's edge
+    (``chunk_cap``), so ``bound`` is the window's own blocks and no more.
+
+    ``per_window`` > 0 beside ``window``: the pool holds no tokens but that
+    many ENTRIES for every window a sequence has completed (the window's
+    summaries), so a sequence of ``n`` tokens has ``n // window *
+    per_window`` entries in it.  It reserves and allocates as a windowed
+    pool does, at the step that completes a window, and frees nothing while
+    the sequence runs.
 
     ``state_slots`` > 0 (a model with state layers) gives the manager the
     sequences' state slots too (``StateSlots``): ``take_slot`` at admission,
@@ -240,14 +254,22 @@ class KVCacheManager:
 
     def __init__(self, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int, window: int = 0, max_chunk: int = 0,
-                 chain: str = "", state_slots: int = 0):
+                 chain: str = "", state_slots: int = 0,
+                 tumbling: bool = False, per_window: int = 0):
         self.allocator = BlockedAllocator(num_blocks)
         self.slots = StateSlots(state_slots) if state_slots else None
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.window = window
-        self.bound = max_blocks_per_seq if not window else window_bound(
-            window, max_chunk, block_size, max_blocks_per_seq)
+        self.tumbling = tumbling
+        self.per_window = per_window
+        if per_window or not window:
+            self.bound = max_blocks_per_seq
+        elif tumbling:
+            self.bound = min(max_blocks_per_seq, window // block_size)
+        else:
+            self.bound = window_bound(window, max_chunk, block_size,
+                                      max_blocks_per_seq)
         self.reserved = 0  # windowed: blocks set aside for running sequences
         self.trimmed = 0  # windowed: blocks freed behind the window so far
         self._blocks, self._first, self._reserved = (
@@ -259,9 +281,36 @@ class KVCacheManager:
     def chain(self, seq: SequenceDescriptor) -> List[int]:
         return getattr(seq, self._blocks)
 
+    def blocks_for(self, tokens: int) -> int:
+        """Blocks that hold what a sequence of ``tokens`` has put in the
+        pool: its tokens, or ``per_window`` entries a completed window."""
+        if self.per_window:
+            tokens = tokens // self.window * self.per_window
+        return -(-tokens // self.block_size)
+
     def reservation(self, total_tokens: int) -> int:
         """Blocks a sequence of ``total_tokens`` is admitted against."""
-        return min(-(-total_tokens // self.block_size), self.bound)
+        return min(self.blocks_for(total_tokens), self.bound)
+
+    def chunk_cap(self, start: int) -> int:
+        """The most tokens a step's chunk that begins at ``start`` may hold:
+        a tumbling window's rest (its queries then see one window)."""
+        return self.window - start % self.window if self.tumbling else 1 << 30
+
+    def opens_at(self, ctx: "np.ndarray") -> "np.ndarray":
+        """Of decode rows with ``ctx`` tokens in the cache: those whose next
+        token needs a block the chain may not have yet."""
+        if self.per_window:  # the token that completes a window
+            return (ctx + 1) % self.window == 0
+        return ctx % self.block_size == 0
+
+    def trims_at(self, ctx: "np.ndarray") -> "np.ndarray":
+        """Of decode rows that now hold ``ctx`` tokens: those ``trim`` has a
+        block to free for."""
+        if self.tumbling:
+            return ctx % self.window == 0
+        oldest = ctx - self.window + 1  # the key the next query still reads
+        return (oldest > 0) & (oldest % self.block_size == 0)
 
     @property
     def unreserved_blocks(self) -> int:
@@ -272,10 +321,8 @@ class KVCacheManager:
         return self.allocator.num_blocks - self.reserved
 
     def blocks_needed(self, seq: SequenceDescriptor, new_tokens: int) -> int:
-        total = seq.seen_tokens + new_tokens
-        have = len(self.chain(seq))
-        need = -(-total // self.block_size)  # ceil
-        return max(0, need - have)
+        need = self.blocks_for(seq.seen_tokens + new_tokens)
+        return max(0, need - len(self.chain(seq)))
 
     def ensure_capacity(self, seq: SequenceDescriptor, new_tokens: int) -> bool:
         need = self.blocks_needed(seq, new_tokens)
@@ -301,7 +348,7 @@ class KVCacheManager:
         total = seq.seen_tokens + total_tokens
         need = self.reservation(total)
         if (need > self.unreserved_blocks
-                or -(-total // self.block_size) > self.max_blocks_per_seq):
+                or self.blocks_for(total) > self.max_blocks_per_seq):
             return False
         if not self.ensure_capacity(seq, chunk):
             return False
@@ -311,12 +358,14 @@ class KVCacheManager:
 
     def trim(self, seq: SequenceDescriptor, next_pos: int) -> int:
         """Free the blocks no query at ``next_pos`` or later can see (the
-        oldest key it reads is ``next_pos - window + 1``) → how many."""
-        if not self.window:
+        oldest key it reads is ``next_pos - window + 1``; of a tumbling
+        window, its window's first) → how many."""
+        if not self.window or self.per_window:
             return 0
         first = getattr(seq, self._first)
-        live = min(max(0, (next_pos - self.window + 1) // self.block_size),
-                   len(self.chain(seq)))
+        oldest = (next_pos // self.window * self.window if self.tumbling
+                  else next_pos - self.window + 1)
+        live = min(max(0, oldest // self.block_size), len(self.chain(seq)))
         if live <= first:
             return 0
         self.allocator.free(self.chain(seq)[first:live])
@@ -437,7 +486,8 @@ class RaggedBatch:
     num_tokens: int
     num_seqs: int
     uids: List[int]
-    #: the window layers' tables of a model with both kinds of layer
+    #: the second pool's tables: the window layers' of a model with both kinds
+    #: of layer, the window's of an EVA model
     win_tables: Optional[np.ndarray] = None
     #: a model with state layers: each row's state slot (unused rows: the
     #: scratch slot), (max_seqs,) int32

@@ -199,6 +199,20 @@ class TransformerConfig:
     # BUILT for a config with this set carries the indexer's scores and picks
     # of its "full" layers out.  A served model's config leaves it False
     dsa_tap: bool = False
+    # EVA attention (Zheng et al., arXiv:2302.04542; models/eva.py), on when
+    # eva_window > 0: a query reads the keys of its own TUMBLING window of
+    # ``eva_window`` positions exactly and, behind it, one learned summary
+    # key and value for every ``eva_chunk`` tokens of the closed windows,
+    # all under one softmax.  Each layer holds two vectors a head for the
+    # summaries (``attn.eva_phi``, ``attn.eva_mu``)
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # output heads (EvaByte: head i scores the byte i + 1 positions ahead):
+    # ``lm_head.w`` is ``num_pred_heads x vocab_size`` columns wide, head 0's
+    # first, and the next token is sampled from head 0's
+    num_pred_heads: int = 1
+    # the head's product and its logits in float32, whatever ``dtype`` is
+    fp32_logits: bool = False
     # dtypes
     dtype: str = "bfloat16"  # compute dtype
     param_dtype: str = "float32"  # master weights
@@ -247,6 +261,10 @@ class TransformerConfig:
             from .latent_sparse import check_config
 
             check_config(self)
+        if self.eva_window:
+            from .eva import check_config as check_eva
+
+            check_eva(self)
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         object.__setattr__(self, "rope_params",
@@ -338,9 +356,12 @@ class TransformerConfig:
         if self.num_experts > 0:
             n_mlp = n_mlp * self.num_experts + h * self.num_experts  # experts + router
         per_layer += n_mlp + 2 * h
+        if self.eva_window:  # phi and mu, a head
+            per_layer += 2 * qh
         total = L * per_layer + h  # + final norm
         if include_embed:
-            total += v * h if self.tie_embeddings else 2 * v * h
+            total += v * h if self.tie_embeddings else \
+                (1 + self.num_pred_heads) * v * h
             if self.position == "learned":
                 total += self.max_seq_len * h
         return total
@@ -363,6 +384,16 @@ PRESETS: Dict[str, Dict[str, Any]] = {
     "mixtral-8x7b": dict(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
                          num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=32768,
                          num_experts=8, moe_top_k=2),
+    # EvaByte-6.5B (EvaByte/EvaByte): bytes in, bytes out (vocabulary 320),
+    # EVA attention (a tumbling window of 2,048 exact keys, one summary for
+    # every 16 tokens behind it), norms with a unit offset, eight output
+    # heads, logits in float32
+    "evabyte-6.5b": dict(
+        vocab_size=320, hidden_size=4096, intermediate_size=11008,
+        num_layers=32, num_heads=32, max_seq_len=32768,
+        norm="gemma_rmsnorm", rope_theta=100000.0, norm_eps=1e-5,
+        tie_embeddings=False, eva_window=2048, eva_chunk=16,
+        num_pred_heads=8, fp32_logits=True),
     "mistral-7b": dict(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
                        num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=32768,
                        sliding_window=4096, attn_impl="flash",
@@ -486,6 +517,13 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         moe_routing="dropless"),
     "tiny": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                  num_heads=4, max_seq_len=128),
+    # EvaByte's block at toy widths: a window of 32 in chunks of 4, two
+    # output heads
+    "tiny-evabyte": dict(
+        vocab_size=320, hidden_size=64, intermediate_size=128, num_layers=2,
+        num_heads=4, max_seq_len=512, norm="gemma_rmsnorm",
+        rope_theta=100000.0, norm_eps=1e-5, tie_embeddings=False,
+        eva_window=32, eva_chunk=4, num_pred_heads=2, fp32_logits=True),
     # nemotron_h's three kinds of layer at toy widths: a pattern that is no
     # period repeated, fewer groups than heads, widths (hidden, an expert)
     # that are multiples of 64 and not of 128
@@ -577,6 +615,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.qk_norm:
         layer["attn"]["q_norm"] = {"scale": jnp.ones((L, nh * hd), pd)}
         layer["attn"]["k_norm"] = {"scale": jnp.ones((L, nkv * hd), pd)}
+    if cfg.eva_window:
+        from .eva import init_vectors
+
+        layer["attn"].update(init_vectors(keys[12], cfg, pd))
 
     if cfg.num_experts > 0:
         E = cfg.num_experts
@@ -617,7 +659,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         params["embed_norm"] = {"scale": jnp.ones((h,), pd),
                                 "bias": jnp.zeros((h,), pd)}
     if not cfg.tie_embeddings:
-        params["lm_head"] = {"w": _dense_init(keys[10], (h, cfg.vocab_size), h, pd)}
+        params["lm_head"] = {"w": _dense_init(
+            keys[10], (h, cfg.num_pred_heads * cfg.vocab_size), h, pd)}
     return params
 
 
@@ -1051,6 +1094,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         from .ssm_hybrid import forward_hidden as hybrid_hidden
 
         return hybrid_hidden(params, tokens, cfg, attn_fn=attn_fn)
+    if cfg.eva_window:
+        from .eva import NOT_TRAINED
+
+        raise NotImplementedError(NOT_TRAINED)
     dt = jnp.dtype(cfg.dtype)
     if cfg.position == "alibi" and cfg.attn_impl != "xla":
         # the additive logit bias rides the einsum path only; the Pallas
@@ -1153,12 +1200,24 @@ def lm_logits(params: Dict[str, Any], hidden: jax.Array,
     its dtype: the head of ``forward``, of the v1 engine and of the v2
     engine's three step bodies."""
     dt = hidden.dtype
+    if cfg.fp32_logits:  # the product itself in float32, not its result cast
+        return jnp.matmul(hidden.astype(jnp.float32),
+                          params["lm_head"]["w"].astype(jnp.float32),
+                          precision=lax.Precision.HIGHEST)
     if cfg.tie_embeddings:
         return hidden @ params["embed"]["tokens"].astype(dt).T
     logits = hidden @ params["lm_head"]["w"].astype(dt)
     if "b" in params["lm_head"]:  # gpt-j ties off with a bias
         logits = logits + params["lm_head"]["b"].astype(dt)
     return logits
+
+
+def next_token_logits(logits: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """The columns of ``lm_logits``' result the NEXT token is drawn from: all
+    of them, or head 0's of a model with several output heads."""
+    if cfg.num_pred_heads == 1:
+        return logits
+    return logits[..., :cfg.vocab_size]
 
 
 def shift_labels(batch: Dict[str, jax.Array]

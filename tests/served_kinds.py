@@ -47,6 +47,10 @@ STEP_ATTRS = {
                 "latent_keys_single", "latent_keys_prefill",
                 "dsa_index_pairs", "dsa_index_keys", "latent_blocks_used",
                 "moe_assignments", "moe_assignments_local"}, set()),
+    # new with the kind (PR 48): no parent carried them
+    "eva": ({"eva_window_keys", "eva_summary_keys", "eva_keys_full",
+             "eva_query_keys", "eva_windows_closed", "eva_chunks_written",
+             "blocks_used_window", "blocks_used_summary"}, set()),
 }
 
 

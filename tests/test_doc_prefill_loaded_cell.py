@@ -57,12 +57,16 @@ def test_every_listed_name_is_found(spec):  # noqa: F811
     added (its count of seven cells holds of those), and the eighth and the
     ninth cell's names found as it finds the others': seven again, the new
     ones among them; the third training cell is listed behind the two."""
-    less = _without(spec, "dsv2lite-train-8k")  # PR 42's, the ninth
+    spec_9 = _without(spec, "evabyte-doc-bytes-sat")  # PR 48's, the tenth
+    less = _without(spec_9, "dsv2lite-train-8k")  # PR 42's, the ninth
     _seven_cells(_without(less, "glm52-ctx8k-sat"))
     _seven_cells(_without(less, "olmoe-decode-sat"))
-    _seven_cells(_without(_without(spec, "glm52-ctx8k-sat"),
+    _seven_cells(_without(_without(spec_9, "glm52-ctx8k-sat"),
                           "olmoe-decode-sat"))
-    assert len(spec["workloads"]) == 9
+    # and the tenth cell's names, found as the others': seven with it
+    _seven_cells(_without(_without(_without(spec, "dsv2lite-train-8k"),
+                                   "glm52-ctx8k-sat"), "olmoe-decode-sat"))
+    assert len(spec["workloads"]) == 10
     tokens = next(m for m in spec["end_to_end"]
                   if m["name"] == "train_tokens_per_s")
     assert tokens["workloads"] == ["train-1chip", "zero3-4chip",
@@ -71,11 +75,11 @@ def test_every_listed_name_is_found(spec):  # noqa: F811
 
 def test_mixed_gap_share_is_listed_for_every_serving_cell(spec):  # noqa: F811
     """The file's test with the count of serving cells as it is now (five
-    there; PR 40 added the sixth)."""
+    there; PR 40 added the sixth, PR 48 the seventh)."""
     entry = next(m for m in spec["per_layer"]
                  if m["name"] == "mixed_gap_share_pct")
     itl = next(m for m in spec["end_to_end"] if m["name"] == "itl_p90_ms")
     assert (entry["moves"], entry["source"], entry["layer"]) == (
         "itl_p90_ms", "program_span", "scheduler")
     assert sorted(entry["workloads"]) == sorted(itl["workloads"])
-    assert len(entry["workloads"]) == 6
+    assert len(entry["workloads"]) == 7

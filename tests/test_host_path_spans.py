@@ -511,12 +511,14 @@ def test_new_entry_finds_its_file_and_its_cells(name):
     moved = next(m for m in spec["end_to_end"] if m["name"] == entry["moves"])
     if name == "decode_host_wait_ms_mean":
         assert sorted(entry["workloads"]) == [
-            "chat-decode-sat", "nemotron3-chat-wide-sat", "olmoe-decode-sat"]
+            "chat-decode-sat", "evabyte-doc-bytes-sat",  # PR 48's, decode too
+            "nemotron3-chat-wide-sat", "olmoe-decode-sat"]
         assert moved["name"] == "serve_out_tokens_per_s"
     else:
         assert sorted(entry["workloads"]) == sorted(moved["workloads"])
-        # every serving cell: five when the entry was added, PR 40's sixth
-        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 6
+        # every serving cell: five when the entry was added, PR 40's sixth,
+        # PR 48's seventh
+        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 7
     for cell in entry["workloads"]:
         assert cell in moved["workloads"]
         assert entry in run.metrics_of(spec, "per_layer", cell)

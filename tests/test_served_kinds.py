@@ -22,7 +22,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
     ("tiny", programs.KV), ("mistral-7b", programs.KV),
     ("olmoe-1b-7b", programs.KV), ("mellum2-12b-a2.5b", programs.KV),
     ("nemotron3-nano-30b-a3b", programs.STATE), ("glm-5.2", programs.LATENT),
-    ("deepseek-v2-lite", programs.LATENT)])
+    ("deepseek-v2-lite", programs.LATENT), ("evabyte-6.5b", programs.EVA),
+    ("tiny-evabyte", programs.EVA)])
 def test_kind_of_names_the_kind(preset, kind):
     cfg = tfm.get_config(preset)
     assert programs.kind_of(cfg) is kind
@@ -42,17 +43,22 @@ def _cell(name):
         V2Config(**conf["engine"]["v2"])
 
 
-@pytest.mark.parametrize("cell, shapes, moe_layers", [
-    ("mistral-7b-w8", {"k": (32, 800, 64, 8, 128)}, 32),
-    ("olmoe-1b-7b-w8", {"k": (16, 416, 64, 16, 128)}, 16),
+@pytest.mark.parametrize("cell, shapes, moe_layers, window_default", [
+    ("mistral-7b-w8", {"k": (32, 800, 64, 8, 128)}, 32, None),
+    ("olmoe-1b-7b-w8", {"k": (16, 416, 64, 16, 128)}, 16, None),
     ("mellum2-12b-w8", {"k": (5, 3000, 64, 4, 128),
-                        "k_win": (15, 801, 64, 4, 128)}, 20),
+                        "k_win": (15, 801, 64, 4, 128)}, 20, 1 + 32 * 25),
     ("nemotron3-nano-30b-w8", {"k": (2, 2048, 64, 2, 128),
                                "ssm": (7, 65, 64, 64, 128),
-                               "conv": (7, 65, 3, 6144)}, 7),
+                               "conv": (7, 65, 3, 6144)}, 7, None),
     ("glm-5.2-ep16-w8", {"latent": (9, 4353, 64, 640),
-                         "index": (3, 4353, 64, 128)}, 8)])
-def test_arrays_of_the_served_cells(cell, shapes, moe_layers):
+                         "index": (3, 4353, 64, 128)}, 8, None),
+    # two pools in every layer: 16 summary blocks a row at 16,384 bytes, a
+    # whole window of 32 blocks a row, a scratch block each
+    ("evabyte-6.5b-w8", {"k_sum": (32, 65, 64, 32, 128),
+                         "k_win": (32, 129, 64, 32, 128)}, 0, 1 + 4 * 32)])
+def test_arrays_of_the_served_cells(cell, shapes, moe_layers,
+                                    window_default):
     """What each served configuration caches at its cell's sizes, on shapes
     alone (``benchmark/configs/*.json``: ``sizing``)."""
     cfg, v2 = _cell(cell)
@@ -65,9 +71,9 @@ def test_arrays_of_the_served_cells(cell, shapes, moe_layers):
     assert {str(dt) for n, (_, dt) in arrays.items() if n != "ssm"} == \
         {"bfloat16"}
     assert kind.moe_layers(cfg) == moe_layers
-    if "k_win" in shapes:  # the default: what 32 rows hold at most, and one
+    if "k_win" in shapes:  # the default: what the rows hold at most, and one
         v2.num_window_blocks = 0
-        assert kind.arrays(cfg, v2)["k_win"][0][1] == 1 + 32 * 25
+        assert kind.arrays(cfg, v2)["k_win"][0][1] == window_default
 
 
 def _count(jaxpr, c):
@@ -88,7 +94,10 @@ def _count(jaxpr, c):
     ("tiny-mellum2", "tiny-mellum2", {}, programs.KV, "k v k_win v_win"),
     ("tiny-nemotron3", "tiny-nemotron3", {}, programs.STATE,
      "k v ssm conv"),
-    ("tiny-glm52", "tiny-glm52", {}, programs.LATENT, "latent index")])
+    ("tiny-glm52", "tiny-glm52", {}, programs.LATENT, "latent index"),
+    # pinned by the PR that brought the kind (48): no parent had it
+    ("tiny-evabyte", "tiny-evabyte", {}, programs.EVA,
+     "k_sum v_sum k_win v_win")])
 def test_step_programs_are_the_parents(name, preset, over, kind, cached):
     """The lock on the three bodies: the mixed and the decode step of one tiny
     model a body (and a shape of the first) count, primitive by primitive, the
@@ -128,7 +137,8 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
             e.params, e.caches, i32(S), i32(S), tables, i32(S),
             jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))}
     scopes = {programs.STATE: ("ssm_", "moe_shared"),
-              programs.LATENT: ("latent", "dsa_")}
+              programs.LATENT: ("latent", "dsa_"),
+              programs.EVA: ("eva_",)}
     for step, jaxpr in programs_.items():
         assert dict(_count(jaxpr.jaxpr, collections.Counter())) == \
             pinned[step], step
@@ -136,3 +146,31 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
         for other, names in scopes.items():
             if other is not kind:
                 assert not any(n in text for n in names), (step, names)
+
+
+def test_another_kind_s_start_loads_nothing_of_eva():
+    """A served model of another kind imports and traces nothing of
+    ``ops/pallas/eva_attention.py`` (or ``models/eva.py``) at its start:
+    every kernel body traced there is set-up its cell pays (PERF.md section
+    6, PR 32).  In a process of its own: this one has loaded both."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, jax\n"
+        "from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, "
+        "V2Config\n"
+        "from deepspeed_tpu.models import transformer as tfm\n"
+        "cfg = tfm.get_config('tiny-mellum2', dtype='float32')\n"
+        "e = InferenceEngineV2(cfg, tfm.init_params(jax.random.PRNGKey(0), "
+        "cfg), V2Config(max_tokens_per_step=32, max_seqs=4, block_size=8, "
+        "num_blocks=64, max_blocks_per_seq=16, dtype='float32'))\n"
+        "e.put(list(range(1, 30)), max_new_tokens=3)\n"
+        "e.generate_all(burst=1)\n"
+        "print([m for m in sys.modules if m.endswith(('eva_attention', "
+        "'models.eva'))])\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, cwd=os.path.dirname(HERE),
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]"

@@ -207,7 +207,9 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     if "latent" in arrays:  # a latent pool and the indexer's
         pool = (arrays["latent"][0], arrays["index"][0])
     elif "k_win" in arrays:  # the window layers' pool and table beside them
-        pool, tables = (arrays["k"][0], arrays["k_win"][0]), (tables, tables)
+        # (an EVA model: its summaries' pool, then its window's)
+        main = "k_sum" if "k_sum" in arrays else "k"
+        pool, tables = (arrays[main][0], arrays["k_win"][0]), (tables, tables)
     else:
         pool = arrays["k"][0]
     if program == "decode_step":
@@ -1004,6 +1006,189 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p)) for p in pools)
     assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
+
+
+# -- EvaByte: EVA attention over a window pool and a summary pool ------------
+
+#: (m, k, n, bits, group) -> (tm, tn, tk): what ``pick_gemm_tiles`` gave, on
+#: the parent of the PR that brought EvaByte (9859eca), at every shape the
+#: step programs of the six serving cells hand ``mixed_gemm`` (their
+#: ``kernel/mixed_gemm_tiles`` events, traced at the cells' sizes: Mistral-7B
+#: twice, OLMoE, Mellum2, Nemotron-3, GLM-5.2).  EvaByte's MLP width is
+#: stored padded (``quantization.pad_mlp_width``) so that the picker stays
+#: as it is for all of them
+_SERVED_GEMM_TILES = [
+    (16, 2048, 4096, 8, 128, 16, 4096, 512),
+    (16, 2048, 6144, 8, 128, 16, 3072, 512),
+    (16, 2048, 16384, 8, 128, 16, 4096, 512),
+    (16, 6144, 128, 8, 128, 16, 128, 1536),
+    (16, 6144, 512, 8, 128, 16, 512, 1536),
+    (16, 6144, 2048, 8, 128, 16, 2048, 1024),
+    (16, 6144, 12288, 8, 128, 16, 4096, 512),
+    (16, 12288, 6144, 8, 128, 16, 3072, 512),
+    (16, 16384, 6144, 8, 128, 16, 3072, 512),
+    (32, 2048, 2048, 8, 256, 32, 2048, 512),
+    (32, 2304, 512, 8, 128, 32, 512, 384),
+    (32, 2304, 4096, 8, 128, 32, 4096, 384),
+    (32, 4096, 1024, 8, 256, 32, 1024, 1024),
+    (32, 4096, 2304, 8, 128, 32, 2304, 512),
+    (32, 4096, 4096, 8, 256, 32, 4096, 512),
+    (32, 4096, 14336, 8, 256, 32, 3584, 512),
+    (32, 14336, 4096, 8, 256, 32, 4096, 512),
+    (64, 2688, 256, 8, 128, 64, 256, 384),
+    (64, 2688, 3712, 8, 128, 64, 3712, 384),
+    (64, 2688, 4096, 8, 128, 64, 4096, 384),
+    (64, 2688, 6144, 8, 128, 64, 3072, 384),
+    (64, 3712, 2688, 8, 128, 64, 2688, 128),
+    (64, 4096, 2688, 8, 128, 64, 2688, 512),
+    (512, 2048, 2048, 8, 256, 512, 2048, 512),
+    (512, 2048, 4096, 8, 128, 512, 4096, 512),
+    (512, 2048, 6144, 8, 128, 512, 3072, 512),
+    (512, 2048, 16384, 8, 128, 512, 4096, 512),
+    (512, 2304, 512, 8, 128, 512, 512, 384),
+    (512, 2304, 4096, 8, 128, 512, 4096, 384),
+    (512, 2688, 256, 8, 128, 512, 256, 384),
+    (512, 2688, 3712, 8, 128, 512, 3712, 384),
+    (512, 2688, 4096, 8, 128, 512, 4096, 384),
+    (512, 2688, 6144, 8, 128, 512, 3072, 384),
+    (512, 3712, 2688, 8, 128, 512, 2688, 128),
+    (512, 4096, 1024, 8, 256, 512, 1024, 1024),
+    (512, 4096, 2304, 8, 128, 512, 2304, 512),
+    (512, 4096, 2688, 8, 128, 512, 2688, 512),
+    (512, 4096, 4096, 8, 256, 512, 4096, 512),
+    (512, 4096, 14336, 8, 256, 512, 3584, 512),
+    (512, 6144, 128, 8, 128, 512, 128, 1536),
+    (512, 6144, 512, 8, 128, 512, 512, 1536),
+    (512, 6144, 2048, 8, 128, 512, 2048, 1024),
+    (512, 6144, 12288, 8, 128, 512, 4096, 512),
+    (512, 12288, 6144, 8, 128, 512, 3072, 512),
+    (512, 14336, 4096, 8, 256, 512, 4096, 512),
+    (512, 16384, 6144, 8, 128, 512, 3072, 512),
+]
+
+
+@pytest.mark.parametrize("m,k,n,bits,group,tm,tn,tk", _SERVED_GEMM_TILES)
+def test_gemm_tiles_at_the_served_shapes_are_the_parents(m, k, n, bits, group,
+                                                          tm, tn, tk):
+    from deepspeed_tpu.ops.pallas.mixed_gemm import pick_gemm_tiles
+
+    got = pick_gemm_tiles(m, k, n, bits, group)
+    assert (got.tm, got.tn, got.tk) == (tm, tn, tk)
+
+
+def test_gemm_tiles_at_evabyte_s_shapes():
+    """The published 11008 = 2^8 x 43 would tile 256 columns wide (and K in
+    one group or all 43); stored as 11264 = 2^10 x 11 it tiles as Mistral's
+    14336 does."""
+    from deepspeed_tpu.ops.pallas.mixed_gemm import pick_gemm_tiles
+
+    assert pick_gemm_tiles(8, 4096, 11008, 8, 256).tn == 256
+    assert pick_gemm_tiles(8, 11008, 4096, 8, 256).tk == 256
+    for m in (8, 512):
+        up = pick_gemm_tiles(m, 4096, 11264, 8, 256)
+        down = pick_gemm_tiles(m, 11264, 4096, 8, 256)
+        assert (up.tn, up.tk, up.grid_steps) == (2816, 512, 32)
+        assert (down.tn, down.tk, down.grid_steps) == (4096, 512, 22)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "summarize"])
+def test_eva_kernels_compile(one_chip, mosaic, kernel):
+    """Each EVA kernel alone on the whole pools (layers, blocks, ...) at the
+    cell's shapes (32 heads of 128, a window of 2,048 in chunks of 16, blocks
+    of 64, four rows), the layer a traced scalar; the ring event names the
+    tiles and no fallback, and no pool is copied."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import eva_attention as ea
+
+    sds = functools.partial(_sds, sharding=one_chip)
+    layers, rows, bs = 4, 4, 64
+    win = sds((layers, 129, bs, H, D), jnp.bfloat16)
+    summ = sds((layers, 65, bs, H, D), jnp.bfloat16)
+    tab, lens = sds((rows, 256), jnp.int32), sds((rows,), jnp.int32)
+    layer, size = sds((), jnp.int32), dict(window=2048, chunk=16)
+    tracer.clear()
+    if kernel == "summarize":
+        text = _compile(
+            functools.partial(ea.eva_summarize, **size), win, win, summ,
+            summ, layer, tab, tab, lens, sds((H, D), jnp.bfloat16),
+            sds((H, D), jnp.bfloat16), kernels=["eva_summarize"])
+        event, = [s.attrs for s in tracer.spans()
+                  if s.name == "kernel/eva_summarize_tiles"]
+        assert event == {"heads": H, "d": D, "block": bs, "window": 2048,
+                         "chunk": 16, "blocks_read": 32, "blocks_written": 2}
+    else:
+        q, more = ((sds((rows, H, D), jnp.bfloat16),
+                    (lens, sds((rows,), jnp.bool_))) if kernel == "decode"
+                   else (sds((512, H, D), jnp.bfloat16), (lens, lens, lens)))
+        text = _compile(
+            functools.partial(getattr(ea, f"eva_{kernel}_attention"), **size),
+            q, win, win, summ, summ, layer, tab, tab, *more,
+            kernels=[f"eva_attention_{kernel}"])
+        event, = [s.attrs for s in tracer.spans()
+                  if s.name == "kernel/eva_attention_tiles"]
+        assert event == {"kind": kernel, "t": 32 if kernel == "decode"
+                         else 512, "heads": H, "d": D, "block": bs,
+                         "window": 2048, "chunk": 16, "kb": 4,
+                         "tq": "8" if kernel == "decode" else "8/128"}
+    # (the summariser writes the summary pool in place, and alone, with
+    # nothing donated, that is a copy: the step programs' test holds it)
+    for blocks in (129,) if kernel == "summarize" else (129, 65):
+        assert _pool_passes(text, (layers, blocks, bs, H, D)) == []
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_evabyte_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of EvaByte-6.5B, WHOLE (32 layers, every width
+    as published, W8A16 at group 256, the serving cell's engine sizes: four
+    rows, a window pool of 129 blocks, a summary pool of 65, tables of 256),
+    compile for the described chip.  Attention and the summariser run their
+    kernels and every GEMM its own (no ``kernel/*_tiles`` event with
+    ``fallback``), one call each a layer of the scan, their scopes are in
+    the lowered names, all four pools are updated in place, and arguments and
+    temp together stay under the 14.5 GB line (13.2 GB: the configuration
+    file's ``sizing``)."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg = dataclasses.replace(tfm.get_config("evabyte-6.5b"),
+                              dtype="bfloat16", param_dtype="bfloat16")
+    tracer.clear()
+    lowered, pools, params = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip),
+        max_seqs=4, num_blocks=65, num_window_blocks=129,
+        max_blocks_per_seq=256)
+    assert pools == ((32, 65, 64, 32, 128), (32, 129, 64, 32, 128))
+    assert params["layers"]["mlp"]["w_in"].codes.shape == (32, 4096, 11264)
+    assert params["layers"]["attn"]["eva_phi"].shape == (32, 32, 128)
+    assert params["lm_head"]["w"].shape == (4096, 8 * 320)
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    assert not [e for e in events if "fallback" in e[1]], events
+    kind = "decode" if program == "decode_step" else "prefill"
+    assert [a["kind"] for name, a in events
+            if name == "kernel/eva_attention_tiles"] == [kind]
+    text = lowered.as_text(debug_info=True)
+    for name in ("mixed_gemm", f"eva_attention_{kind}", "eva_summarize",
+                 "cache_write"):
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
+    for kernel, calls in ((f"eva_attention_{kind}", 1), ("eva_summarize", 1),
+                          ("mixed_gemm", 7)):
+        found = re.findall(rf"%({kernel}[.\d]*) = [^\n]*custom-call\(",
+                           compiled_text)
+        assert len(found) == calls, (kernel, found)
+    for pool in pools:
+        assert _pool_passes(compiled_text, pool) == []
+    assert _weight_passes(compiled_text, params) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        2 * 2 * int(np.prod(pool)) for pool in pools)
+    assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
 
 
 def test_mesh_follows_the_torus(topo):
