@@ -626,6 +626,8 @@ def phase_server(sz: Sizes, seed: int, quantize_bits: int,
     # oracle first, at the projections' own shapes.
     if quantize_bits:
         check_mixed_gemm(phase, params, cfg)
+    elif check_kernels:
+        check_decode_attention(phase, cfg, sz)
     sequences = [prompts[i] + results[i][0] for i in range(len(jobs))]
     m, rank, std = reference_margins(params, cfg, sequences)
     picks = np.concatenate([
@@ -696,7 +698,7 @@ def phase_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     events = [s.attrs for s in tracer.spans()
               if s.name.startswith("kernel/") and s.name.endswith("_tiles")]
     grouped = sorted({(a["k"], a["n"], a["rows"], a["tile_m"], a["tn"])
-                      for a in events if "rows" in a and "fallback" not in a})
+                      for a in events if "tile_m" in a and "fallback" not in a})
     log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
         experts_hit=[round(a["moe_experts_hit"], 1) for a in steps[-3:]],
         gemm_tile_events=len(events), grouped_k_n_rows_tilem_tn=grouped)
@@ -736,7 +738,8 @@ def phase_swa_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     every served token within ``margin`` of the maximum of the plain
     reference (``benchmark/reference/swa_moe_decoder.py``) over the same
     codes; both kinds of layer on the paged kernels (the ring's
-    ``kernel/paged_attention_window`` events, none fallen back); no GEMM
+    ``kernel/paged_attention_window`` events and the windowed ones among
+    ``kernel/paged_attention_decode_tiles``, none fallen back); no GEMM
     fallen back; both pools whole after the drain."""
     from benchmark.drivers.serve_swa_moe import published_model
     from benchmark.reference import swa_moe_decoder as reference
@@ -779,9 +782,11 @@ def phase_swa_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
     steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"]
     events = [(s.name, s.attrs) for s in tracer.spans()
               if s.name.startswith("kernel/")]
-    windows = sorted({(a["kind"], a["window"]) for name, a in events
-                      if name == "kernel/paged_attention_window"
-                      and "fallback" not in a})
+    windows = sorted({(a.get("kind", "decode"), a["window"])
+                      for name, a in events
+                      if (name == "kernel/paged_attention_window"
+                          or name == "kernel/paged_attention_decode_tiles"
+                          and a["window"]) and "fallback" not in a})
     read, full = (sum(a[k] for a in steps)
                   for k in ("kv_blocks_read", "kv_blocks_full"))
     log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
@@ -889,6 +894,54 @@ def check_step_copies(phase: str) -> None:
         raise AssertionError(
             f"{phase}: wanted decode and mixed steps of one host-to-device "
             f"copy each; ran (kind, h2d_copies, h2d_bytes) {copies}")
+
+
+def check_decode_attention(phase: str, cfg, sz: Sizes) -> None:
+    """The paged decode kernel twice back to back in one program, on one
+    pool with different tables and contexts (rows without a context first,
+    last and between live ones; contexts on both sides of a block's and of a
+    fetch's end), each call against the blockwise XLA path on the same
+    operands: a copy the first call left unwaited lands in the second's
+    slots, which shows on the chip and nowhere on a CPU (the interpreter
+    copies when a copy is started)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _decode_attention_xla, paged_decode_attention, pick_decode_tiles)
+
+    heads, kv, d, bs = cfg.num_heads, cfg.kv_heads, cfg.head_dim, sz.block_size
+    rows, width = 2 * sz.max_seqs, sz.max_blocks_per_seq
+    kb = pick_decode_tiles(rows, heads, kv, d, bs, jnp.bfloat16).kb
+    edges = [0, 1, bs - 1, bs, bs + 1, kb * bs - 1, kb * bs, kb * bs + 1, 0,
+             0, width * bs, 2 * kb * bs + 7, 0]
+    rng = np.random.default_rng(0)
+    calls = []
+    for call in range(2):
+        ctx = np.asarray([edges[(r + 5 * call) % len(edges)]
+                          for r in range(rows)], np.int32)
+        tables = np.stack([rng.permutation(sz.num_blocks)[:width]
+                           for _ in range(rows)]).astype(np.int32)
+        calls.append((jnp.asarray(tables), jnp.asarray(ctx)))
+    key = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(key[0], (rows, heads, d), jnp.bfloat16)
+    pool = (2, sz.num_blocks, bs, kv, d)
+    k, v = (jax.random.normal(x, pool, jnp.bfloat16) for x in key[1:])
+
+    def both(attend):
+        return jax.jit(lambda q, k, v: [attend(q, k, v, 1, *c)
+                                        for c in calls])(q, k, v)
+
+    worst = 0.0
+    for (_, ctx), got, want in zip(calls, both(paged_decode_attention),
+                                   both(_decode_attention_xla)):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        err = float(np.abs(got - want).max())
+        worst = max(worst, err)
+        # bf16 results of values up to 3-4: a rounding is 2**-7
+        if not err < 3e-2 or got[np.asarray(ctx) == 0].any():
+            raise AssertionError(
+                f"{phase}: paged_decode_attention is {err} off the blockwise "
+                f"XLA path (contexts {np.asarray(ctx).tolist()})")
+    log(phase, decode_attention_vs_xla_worst_abs_err=round(worst, 5),
+        rows=rows, heads=heads, kv=kv, kb=kb, calls=len(calls))
 
 
 def check_mixed_gemm(phase: str, params, cfg) -> None:

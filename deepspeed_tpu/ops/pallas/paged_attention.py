@@ -10,11 +10,33 @@ and the layer as one more scalar-prefetch operand: a block is fetched as
 ``k_hbm.at[layer, blk]``, so a step program's layer scan never slices a layer
 out of the pool for them (a Pallas call cannot fuse its operand's slice).
 The block table arrives via scalar prefetch (SMEM) so each step can DMA the
-right KV blocks HBM→VMEM with double buffering while computing the previous
-ones; online softmax across blocks.
+right KV blocks HBM→VMEM while computing the previous ones (slots two deep
+in the prefill kernel, three in the decode kernel); online softmax across
+fetches.
 
-**Decode** (``paged_decode_attention``): one query token a sequence, grid over
-sequences.
+**Decode** (``paged_decode_attention``): one query token a sequence.  The
+kernel walks the step's rows as ONE list of (row, fetch) inside a grid step
+(the queries and the output of 32-64 rows are 0.25-0.5 MB and sit in VMEM
+whole; past 8 MiB the rows are walked in spans, as the prefill kernel's
+tokens): a fetch is up to ``kb`` consecutive entries of a row's table
+(``DecodeTiles``, ``pick_decode_tiles``: 2,048 (token, KV head) pairs, 0.5 MB
+of bfloat16 each of K and V), waited together and multiplied as one
+``(H, kb x block x KV)`` score slab, and the DMA slots are three deep ACROSS
+rows: while a row's last fetch is multiplied, the first of the next row that
+has a context (and the one after it) is under way, so the HBM stream does not
+stop at a row's end.  A row without a context costs a zero-trip loop and a
+store of zeros; a fetch holds only entries ``[first, nblocks)`` of its own
+row's table, and every copy started is waited before the call ends.  Where
+``head_dim`` is one lane tile (128: every served model) the pools are read
+through a view of a block as its ``block x KV`` (token, KV head) rows, a
+bitcast under XLA's tiled layouts, so a block lands in VMEM as the
+``(rows, D)`` operand both products take, whatever ``KV``: nothing is cast,
+transposed or regrouped.  The score columns are token-major (column ``c`` is
+token ``c // KV``, KV head ``c % KV``; ``p . v`` is indifferent to the
+order), a query head keeps the columns of its own KV head (the match is
+computed once a call), and the operands go to the MXU in the cache's dtype
+with float32 scores, sums and softmax state, the weights rounded to the
+cache's dtype for ``p . v``, exactly as in the prefill kernel below.
 
 **Prefill** (``paged_prefill_attention``): the step's queries flat,
 ``(T, H, D)``, as the layer produced them; per row of the block table where
@@ -63,6 +85,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...observability.trace import tracer
 from . import backend
+
+
+_LANES = 128  # a lane tile; the prefill kernel's running max and sum are
+# kept replicated across it
+
+#: the most a grid step's block of the queries (and of the output) may hold;
+#: Pallas keeps two of each in VMEM, beside about 10 MiB of scratch
+_SPAN_BYTES = 8 << 20
+
+
+def _block_copies(pools, bufs, sems, layer, slot, c, blk) -> list:
+    """The two DMAs (K, V) of block ``blk`` of ``layer`` from the pools in
+    HBM into place ``c`` of ``slot`` of their VMEM buffers."""
+    return [pltpu.make_async_copy(hbm.at[layer, blk], buf.at[slot, c],
+                                  sems.at[slot, kv, c])
+            for kv, (hbm, buf) in enumerate(zip(pools, bufs))]
 
 
 def _layer_operand(layer) -> jax.Array:
@@ -130,95 +168,156 @@ def _decode_attention_xla(q, k_cache, v_cache, layer, block_tables,
     return out.astype(q.dtype)
 
 
-def _decode_kernel(layer_ref, block_tables_ref, context_lens_ref,  # SMEM
-                   q_ref, k_hbm, v_hbm,  # inputs
-                   o_ref,  # output
-                   k_buf, v_buf, copy_sems,  # scratch
-                   *, block_size: int, max_blocks: int, group: int,
-                   window: int = 0):
-    s = pl.program_id(0)
+@dataclasses.dataclass(frozen=True)
+class DecodeTiles:
+    """The static tiling of one ``paged_decode_attention`` call: ``kb`` K/V
+    blocks a fetch (one score slab), ``slots`` fetches held in VMEM (the one
+    multiplied and those under way behind it), ``span`` rows a grid step."""
+    kb: int
+    slots: int
+    span: int
+
+
+#: (token, KV head) pairs a fetch holds at most: the columns of a score slab
+_FETCH_ROWS = 2048
+
+
+def pick_decode_tiles(rows: int, heads: int, kv: int, d: int, block_size: int,
+                      dtype) -> DecodeTiles:
+    """The decode kernel's picker, of the call's static shapes (beside
+    ``pick_prefill_tiles``): the blocks a fetch that hold ``_FETCH_ROWS``
+    (token, KV head) pairs, 16 at most (at blocks of 64: 2 at OLMoE's 16 KV
+    heads, 4 at Mistral's 8, 8 at Mellum2's 4, 16 at Nemotron-3's 2: 0.5 MB
+    of bfloat16 each of K and V whatever the model, so the loop's fixed cost
+    and the MXU's operand loads are paid once for as many columns); three
+    slots, so two fetches are under way behind the one multiplied (3 MB of
+    VMEM in bfloat16, 6 in float32); the span all ``rows`` rows while their
+    queries are at most ``_SPAN_BYTES``, else the whole sublane groups of
+    rows that are.  Measured on the chip at the four served shapes
+    (``scripts/prefill_attention_alone.py --decode-tiles``, PERF.md section
+    5): half as many blocks a fetch cost Nemotron-3's rows 7 % and Mellum2's
+    up to 4 %, a quarter as many 28-36 %, and Mistral's and OLMoE's nothing;
+    twice as many cost those two 1-2 %; two slots cost 2-22 %, four bought
+    nothing."""
+    kb = max(1, min(16, _FETCH_ROWS // (block_size * kv)))
+    a_row = heads * d * jnp.dtype(dtype).itemsize
+    span = rows if rows * a_row <= _SPAN_BYTES else max(
+        8, _SPAN_BYTES // a_row // 8 * 8)
+    return DecodeTiles(kb, 3, span)
+
+
+def _decode_kernel(layer_ref, tables_ref, ctx_ref,  # scalar prefetch (SMEM)
+                   q_ref, k_hbm, v_hbm,  # the span's queries, the pools in HBM
+                   o_ref,  # the span's output
+                   rows_ref, k_buf, v_buf, copy_sems,  # scratch
+                   *, block_size: int, kv: int, spans: int, window: int = 0):
+    rows, H, D = q_ref.shape
+    slots, kb = k_buf.shape[:2]
+    BS, KV, group = block_size, kv, H // kv
+    n = kb * BS * KV
     layer = layer_ref[0]
-    ctx = context_lens_ref[s]
-    nblocks = pl.cdiv(ctx, block_size)
-    # the first block the query (at ctx - 1) sees a key of
-    first = jnp.maximum(ctx - window, 0) // block_size if window else 0
-
-    def since_first(j):  # the DMA slots alternate from the first block read
-        return j - first if window else j
-
-    q = q_ref[0].astype(jnp.float32)  # (H, D)
-    H, D = q.shape
-    KV = H // group
     scale = 1.0 / math.sqrt(D)
-    qs = q * scale
-    # per-(head, kv·slot) validity: head h may only read kv head h//group.
-    # Keeping invalid columns at -inf → p=0 → the p@v matmul combines exactly.
-    head_kv = jax.lax.broadcasted_iota(jnp.int32, (H, KV * block_size), 0) // group
-    col_kv = jax.lax.broadcasted_iota(jnp.int32, (H, KV * block_size), 1) // block_size
-    kv_match = head_kv == col_kv
-    col_pos = jax.lax.broadcasted_iota(jnp.int32, (H, KV * block_size), 1) % block_size
+    # the span's first row
+    base = pl.program_id(0) * rows if spans > 1 else 0
 
-    def get_dma(slot, j):
-        blk = block_tables_ref[s, j]
-        return (pltpu.make_async_copy(k_hbm.at[layer, blk], k_buf.at[slot],
-                                      copy_sems.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[layer, blk], v_buf.at[slot],
-                                      copy_sems.at[slot, 1]))
+    # -- the span's rows, one column of ``rows_ref`` each: the K/V blocks
+    # [first, end) the row's query (at ctx - 1) sees a key of, and the next
+    # row that has a context (``rows``: none; that column reads 0, 0, rows).
+    def add_row(i, live):
+        s = rows - 1 - i
+        ctx = ctx_ref[base + s]
+        rows_ref[0, s] = (jax.lax.div(jnp.maximum(ctx - window, 0), BS)
+                          if window else 0)
+        rows_ref[1, s] = jax.lax.div(ctx + BS - 1, BS)
+        rows_ref[2, s] = live
+        return jnp.where(ctx > 0, s, live)
 
-    @pl.when(nblocks > 0)
-    def _start_first():
-        ka, va = get_dma(0, first)
-        ka.start()
-        va.start()
+    for i, x in enumerate((0, 0, rows)):
+        rows_ref[i, rows] = x
+    live = jax.lax.fori_loop(0, rows, add_row, jnp.int32(rows))
 
-    def body(j, carry):
-        acc, m, l = carry
-        slot = since_first(j) % 2
+    copies = functools.partial(_block_copies, (k_hbm, v_hbm), (k_buf, v_buf),
+                               copy_sems, layer)
 
-        @pl.when(j + 1 < nblocks)
-        def _prefetch_next():
-            ka, va = get_dma(since_first(j + 1) % 2, j + 1)
-            ka.start()
-            va.start()
+    def fetch(g, s, j):
+        """Start the DMAs of the ``g``-th fetch of the span, row ``s``'s
+        blocks ``j`` to ``j + kb`` that the row has (past the last row:
+        none), and → the fetch after it: the row's next ``kb`` blocks, or the
+        first of the next row that has a context."""
+        slot = jax.lax.rem(g, slots)
+        end = rows_ref[1, s]
 
-        ka, va = get_dma(slot, j)
-        ka.wait()
-        va.wait()
-        # (bs, KV, D) → (KV·bs, D): kv-major so column c maps to kv c//bs
-        k = k_buf[slot].astype(jnp.float32).transpose(1, 0, 2) \
-            .reshape(KV * block_size, D)
-        v = v_buf[slot].astype(jnp.float32).transpose(1, 0, 2) \
-            .reshape(KV * block_size, D)
+        def one(c, _):
+            for dma in copies(slot, c, tables_ref[base + s, j + c]):
+                dma.start()
+            return 0
 
-        scores = jax.lax.dot_general(
-            qs, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (H, KV·bs)
-        pos = j * block_size + col_pos
-        keep = kv_match & (pos < ctx)
-        if window:
-            keep &= pos >= ctx - window
-        scores = jnp.where(keep, scores, -jnp.inf)
+        jax.lax.fori_loop(0, jnp.minimum(kb, end - j), one, 0)
+        more = j + kb < end
+        s = jnp.where(more, s, rows_ref[2, s])
+        return s, jnp.where(more, j + kb, rows_ref[0, s])
 
-        m_cur = jnp.max(scores, axis=1, keepdims=True)  # (H, 1)
-        m_new = jnp.maximum(m, m_cur)
-        # fully-masked rows keep m_new == -inf; exp(-inf - -inf) would be
-        # NaN, so rescale against a zeroed stand-in (their p is 0 anyway)
-        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        alpha = jnp.exp(m - m_safe)
-        p = jnp.exp(scores - m_safe)  # invalid cols → 0
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (H, D)
-        acc_new = acc * alpha + pv
-        return acc_new, m_new, l_new
+    def await_fetch(slot, held):
+        def one(c, _):
+            for dma in copies(slot, c, 0):  # a wait reads the size alone
+                dma.wait()
+            return 0
 
-    acc0 = jnp.zeros((H, D), jnp.float32)
-    m0 = jnp.full((H, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((H, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(first, nblocks, body, (acc0, m0, l0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, held, one, 0)
+
+    # a block the row lacks is not fetched: what its slot held before must be
+    # finite where p = 0 meets it, and after this only a row's own blocks are
+    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+    # column c of a fetch's keys: token c // KV of it, KV head c % KV; query
+    # head h reads KV head h // group alone
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, n), 1)
+    own = (jax.lax.rem(col, KV)
+           == jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (H, n), 0),
+                          group))
+    col_pos = jax.lax.div(col, KV)
+
+    # the first fetches, ``slots - 1`` of them under way before any product
+    ahead = jax.lax.fori_loop(
+        0, slots - 1, lambda g, at: fetch(g, *at), (live, rows_ref[0, live]))
+
+    def row(s, carry):
+        ctx, first, end = ctx_ref[base + s], rows_ref[0, s], rows_ref[1, s]
+        q = q_ref[s].astype(k_buf.dtype)
+
+        def step(i, carry):
+            acc, m, l, g, *ahead = carry
+            j = first + i * kb
+            slot = jax.lax.rem(g, slots)
+            ahead = fetch(g + slots - 1, *ahead)
+            await_fetch(slot, jnp.minimum(kb, end - j))
+            scores = jax.lax.dot_general(
+                q, k_buf[slot].reshape(n, D), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            pos = j * BS + col_pos
+            keep = own & (pos < ctx)
+            if window:
+                keep &= pos >= ctx - window
+            scores = jnp.where(keep, scores, -jnp.inf)
+            # every fetch holds a key each head sees: m_new is finite
+            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(scores - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[slot].reshape(n, D),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return (acc * alpha + pv, m_new, l, g + 1, *ahead)
+
+        acc, _, l, *carry = jax.lax.fori_loop(
+            0, jax.lax.div(end - first + kb - 1, kb), step,
+            (jnp.zeros((H, D), jnp.float32),
+             jnp.full((H, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32), *carry))
+        # a row without a context ran no step: zero
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        return tuple(carry)
+
+    jax.lax.fori_loop(0, rows, row, (jnp.int32(0), *ahead))
 
 
 def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -230,17 +329,28 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     int32 scalar (traced in a layer scan), the pool's layer to read;
     block_tables: (max_seqs, max_blocks) int32; context_lens: (max_seqs,)
     int32.  Context length INCLUDES the current token (its KV already
-    written).  ``window`` (static; 0: none): the sliding window of the
-    layer, see the module text."""
+    written); a row whose context is 0 reads nothing (its table need not be
+    valid) and comes out zero.  ``window`` (static; 0: none): the sliding
+    window of the layer.  The rows are walked as one list with the K/V
+    fetches of ``pick_decode_tiles`` in flight across rows (see the module
+    text); one ring event a traced call,
+    ``kernel/paged_attention_decode_tiles``, says what was picked, in which
+    dtype the products' operands are, and whether the call gave way to the
+    blockwise XLA path (``fallback``: shapes Mosaic's DMA cannot slice)."""
     S, H, D = q.shape
     _, NB, BS, KV, _ = k_cache.shape
-    max_blocks = block_tables.shape[1]
-    group = H // KV
 
     # Mosaic DMA slices need the lane dim 128-aligned and sublanes 8-aligned;
     # small-model shapes fall back to the (correct, slower) XLA gather path.
     fallback = not backend.interpret() and (D % 128 != 0 or BS % 8 != 0)
-    _note_window("decode", window, fallback)
+    tiles = pick_decode_tiles(S, H, KV, D, BS, q.dtype)
+    # once a traced call, as ``kernel/paged_attention_prefill_tiles``
+    tracer.add_event("kernel/paged_attention_decode_tiles", attrs={
+        "rows": S, "heads": H, "kv": KV, "d": D, "block": BS,
+        "window": window,
+        **({"fallback": 1} if fallback else
+           {"kb": tiles.kb, "slots": tiles.slots,
+            "operand_dtype": jnp.dtype(k_cache.dtype).name})})
     if fallback:
         backend.warn_fallback(
             "paged_decode_attention",
@@ -249,29 +359,62 @@ def paged_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return _decode_attention_xla(q, k_cache, v_cache, layer,
                                      block_tables, context_lens, window)
 
+    return _decode_pallas(q, k_cache, v_cache, _layer_operand(layer),
+                          block_tables, context_lens, tiles=tiles,
+                          window=window, interpret=backend.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "window", "interpret"))
+def _decode_pallas(q, k_cache, v_cache, layer, block_tables, context_lens, *,
+                   tiles: DecodeTiles, window: int, interpret: bool):
+    """The kernel's call, under a jit of its own (as ``_prefill_pallas``): a
+    step program whose layers call it alike traces and lowers it once."""
+    S, H, D = q.shape
+    L, NB, BS, KV, _ = k_cache.shape
+    span = tiles.span
+    spans = pl.cdiv(S, span)
+    if S % span:  # whole spans: the rows added have no context
+        pad = spans * span - S
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        block_tables = jnp.pad(block_tables, ((0, pad), (0, 0)))
+        context_lens = jnp.pad(context_lens, (0, pad))
+    block = (BS, KV, D)
+    if D == _LANES:
+        # a block's (token, KV head) pairs as its rows: with one lane tile a
+        # row XLA's tiled layouts of the two shapes are byte for byte the
+        # same, so the view is a bitcast (no pass over a pool:
+        # tests/test_tpu_compile.py), and a block lands in VMEM as the
+        # (rows, D) operand the products take, whatever KV
+        block = (BS * KV, D)
+        k_cache, v_cache = (x.reshape(L, NB, *block)
+                            for x in (k_cache, v_cache))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S,),
+        grid=(spans,),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((span, H, D), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+        out_specs=pl.BlockSpec((span, H, D), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, BS, KV, D), k_cache.dtype),
-            pltpu.VMEM((2, BS, KV, D), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((3, span + 1), jnp.int32),
+            pltpu.VMEM((tiles.slots, tiles.kb, *block), k_cache.dtype),
+            pltpu.VMEM((tiles.slots, tiles.kb, *block), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((tiles.slots, 2, tiles.kb)),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=BS, max_blocks=max_blocks,
-                          group=group, **({"window": window} if window else {})),
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_size=BS, kv=KV, spans=spans,
+                          **({"window": window} if window else {})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        interpret=backend.interpret(),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
         name="paged_attention_decode",
-    )(_layer_operand(layer), block_tables, context_lens, q, k_cache, v_cache)
+    )(layer, block_tables, context_lens, q, k_cache, v_cache)
+    return out[:S] if S % span else out
 
 
 # ---------------------------------------------------------------------------
@@ -308,11 +451,6 @@ class PrefillTiles:
             slots += held - left + np.where(left > self.small, self.big,
                                             (left > 0) * self.small)
         return slots
-
-
-#: the most a grid step's block of the queries (and of the output) may hold;
-#: Pallas keeps two of each in VMEM, beside about 10 MiB of scratch
-_SPAN_BYTES = 8 << 20
 
 
 def pick_prefill_tiles(t: int, heads: int, kv: int, d: int, block_size: int,
@@ -390,9 +528,6 @@ def _prefill_attention_xla(q, k_cache, v_cache, layer, block_tables, q_start,
     return jnp.where(held[:, None, None], acc / l, 0.0).astype(q.dtype)
 
 
-_LANES = 128  # the running max and sum are kept replicated across the lanes
-
-
 def _across(x, n: int):
     """``x (..., _LANES)``, every lane of a row the same → ``(..., n)``."""
     if n % _LANES:
@@ -457,10 +592,8 @@ def _prefill_kernel(layer_ref, tables_ref, q_start_ref, chunk_start_ref,
 
     n_tiles = jax.lax.fori_loop(0, S, add_row, jnp.int32(0))
 
-    def copies(slot, c, blk):
-        return [pltpu.make_async_copy(hbm.at[layer, blk], buf.at[slot, c],
-                                      copy_sems.at[slot, kv, c])
-                for hbm, buf, kv in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))]
+    copies = functools.partial(_block_copies, (k_hbm, v_hbm), (k_buf, v_buf),
+                               copy_sems, layer)
 
     def fetch(w, step, slot):
         """Start the DMAs of tile ``w``'s ``step``-th ``kb`` blocks into

@@ -155,6 +155,132 @@ def test_paged_decode_kernel_matches_xla(devices):
                                atol=2e-5, rtol=2e-5)
 
 
+def _dense_decode(q, k, v, tables, ctx, window, bs):
+    """Float32 reference of one decode step: each row's keys gathered in
+    order through its table, the window an explicit band; a row without a
+    context reads zero."""
+    rep = q.shape[1] // k.shape[2]
+    out = np.zeros(q.shape, np.float32)
+    for r, n in enumerate(ctx):
+        if n == 0:
+            continue
+        ks = np.repeat(k[tables[r, :-(-n // bs)]].reshape(
+            -1, *k.shape[-2:])[:n], rep, 1)
+        vs = np.repeat(v[tables[r, :-(-n // bs)]].reshape(
+            -1, *v.shape[-2:])[:n], rep, 1)
+        s = np.einsum("hd,thd->ht", q[r], ks) / np.sqrt(q.shape[-1])
+        if window:
+            s[:, :max(n - window, 0)] = -np.inf
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[r] = np.einsum("ht,thd->hd", w / w.sum(-1, keepdims=True), vs)
+    return out
+
+
+#: query heads, KV heads, window and head width of the served decode shapes:
+#: Mistral, OLMoE, Nemotron-3's attention layers, Mellum2's global and window
+#: layers; at the served width of 128 the kernel reads the pools through a
+#: view of a block as its (token, KV head) rows, at another as they lie
+_DECODE_SHAPES = {"32/8": (32, 8, 0, 128), "16/16": (16, 16, 0, 16),
+                  "32/2": (32, 2, 0, 128), "32/4": (32, 4, 0, 16),
+                  "32/4-window": (32, 4, 21, 128)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows", ["edges", "dead-between", "one-live"])
+@pytest.mark.parametrize("shape", list(_DECODE_SHAPES))
+def test_paged_decode_kernel_at_served_shapes(devices, shape, rows, dtype):
+    """The decode kernel (interpret mode) against the blockwise XLA path and
+    a float32 reference, at every served share of query heads a KV head:
+    contexts on both sides of a block's end, of a fetch's end (``kb``
+    blocks) and at the table's full length, rows without a context first,
+    last and between live ones (the fetch under way crosses them), pools in
+    bfloat16 and float32."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    heads, kv, window, d = _DECODE_SHAPES[shape]
+    bs = 8
+    kb = pa.pick_decode_tiles(16, heads, kv, d, bs, dtype).kb
+    mb = 2 * kb + 4
+    assert kb > 1
+    ctx = np.asarray({
+        "edges": [0, 1, bs - 1, bs, bs + 1, kb * bs - 1, kb * bs,
+                  kb * bs + 1, 2 * kb * bs + 1, mb * bs, 0],
+        "dead-between": [0, 0, kb * bs + 3, 0, 0, 0, mb * bs, 0, bs, 0, 0],
+        "one-live": [0, 0, 0, 0, 5 * bs + 2, 0, 0, 0]}[rows], np.int32)
+    rng = np.random.default_rng([heads, kv, len(ctx)])
+    nblk = -(-ctx // bs)
+    ids = rng.permutation(int(nblk.sum()) + 1)
+    tables = np.full((len(ctx), mb), ids[-1], np.int32)  # a block no row has
+    at = 0
+    for r, n in enumerate(nblk):
+        tables[r, :n] = ids[at:at + n]
+        at += n
+    pool = (3, int(nblk.sum()) + 1, bs, kv, d)
+    q, k, v = (jnp.asarray(rng.standard_normal(sh), dtype)
+               for sh in ((len(ctx), heads, d), pool, pool))
+    layer = jnp.int32(2)
+    got = pa.paged_decode_attention(q, k, v, layer, jnp.asarray(tables),
+                                    jnp.asarray(ctx), window=window)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    got = np.asarray(got, np.float32)
+    via_xla = np.asarray(pa._decode_attention_xla(
+        q, k, v, layer, jnp.asarray(tables), jnp.asarray(ctx), window),
+        np.float32)
+    want = _dense_decode(*(np.asarray(x, np.float32) for x in (q, k[2], v[2])),
+                         tables, ctx, window, bs)
+    # bfloat16: the result's own rounding (2**-8 of values up to 2-3) and
+    # the weights rounded for p.v, as the prefill kernel rounds them
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, want, atol=tol)
+    np.testing.assert_allclose(got, via_xla, atol=tol)
+    assert not got[ctx == 0].any()
+
+
+@pytest.mark.parametrize("kb,slots,span", [(1, 2, 16), (2, 4, 16), (4, 3, 8),
+                                           (3, 2, 8)])
+def test_paged_decode_kernel_under_any_tiling(devices, monkeypatch, kb, slots,
+                                              span):
+    """The decode kernel under tilings the picker does not pick at these
+    sizes: one block a fetch and slots two deep (the parent's), slots four
+    deep, a count of blocks a fetch that divides nothing, and rows past what
+    a grid step holds (spans of 8 of 11 rows: the last is padded with rows
+    without a context, and no fetch crosses a span's end)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "pick_decode_tiles",
+                        lambda *_: pa.DecodeTiles(kb, slots, span))
+    heads, kv, d, bs, mb = 8, 2, 16, 8, 12
+    ctx = np.asarray([0, 1, bs, 3 * bs + 1, 0, 0, mb * bs, 7, 0, 2 * bs - 1,
+                      5 * bs], np.int32)
+    rng = np.random.default_rng(kb)
+    tables = np.stack([rng.permutation(40)[:mb] for _ in ctx]).astype(np.int32)
+    q, k, v = (jnp.asarray(rng.standard_normal(sh), jnp.float32)
+               for sh in ((len(ctx), heads, d), (2, 40, bs, kv, d),
+                          (2, 40, bs, kv, d)))
+    for window in (0, 13):
+        got = pa.paged_decode_attention(q, k, v, 1, jnp.asarray(tables),
+                                        jnp.asarray(ctx), window=window)
+        want = _dense_decode(*(np.asarray(x) for x in (q, k[1], v[1])),
+                             tables, ctx, window, bs)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+def test_pick_decode_tiles():
+    """The picker on the served shapes (blocks of 64): a fetch of 2,048
+    (token, KV head) pairs whatever the KV heads, three slots, every row in
+    one grid step; rows past 8 MiB of queries are walked in spans."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    for (heads, kv), kb in {(16, 16): 2, (32, 8): 4, (32, 4): 8,
+                            (32, 2): 16, (32, 1): 16}.items():
+        assert pa.pick_decode_tiles(64, heads, kv, 128, 64, jnp.bfloat16) == \
+            pa.DecodeTiles(kb, 3, 64)
+    assert pa.pick_decode_tiles(32, 32, 8, 128, 256, jnp.bfloat16).kb == 1
+    assert pa.pick_decode_tiles(4096, 32, 8, 128, 64,
+                                jnp.bfloat16).span == 1024
+
+
 def test_v2_rejects_impossible_request(devices, tiny_model):
     cfg, params = tiny_model
     eng = InferenceEngineV2(cfg, params, V2Config(

@@ -211,9 +211,14 @@ def test_engine_matches_reference():
                for a in steps)
     assert sum(a["window_blocks_freed"] for a in steps) == \
         engine.kv_win.trimmed
+    # each kernel's own event of its windowed calls: the prefill kernel's
+    # beside its tiles', the decode kernel's in its tiles' (global layers
+    # leave one of window 0)
     events = [s.attrs for s in tracer.spans()
-              if s.name == "kernel/paged_attention_window"]
-    assert {a["kind"] for a in events} == {"decode", "prefill"}
+              if s.name == "kernel/paged_attention_window"
+              or s.name == "kernel/paged_attention_decode_tiles"
+              and s.attrs["window"]]
+    assert {a.get("kind", "decode") for a in events} == {"decode", "prefill"}
     assert all(a["window"] == 8 and "fallback" not in a for a in events)
     rows, skipped = check_rows(cfg, params, prompts, logits, out, uids,
                                F32_TOL, F32_MARGIN, 25)
@@ -421,6 +426,47 @@ def test_kernels_read_the_band_only(window):
     np.testing.assert_allclose(
         np.asarray(got), np.concatenate([want[r, :clen[r]]
                                          for r in range(3)]), atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [0, 8, 11, 16])
+def test_decode_kernel_fetches_its_rows_blocks_only(window):
+    """(f) The decode kernel walks the step's rows as one list and has the
+    next row's first blocks under way while this row's last are multiplied:
+    pools of NaN everywhere but the blocks ``[first, nblocks)`` a row's query
+    sees a key of, every other table entry (behind a window, past a row's
+    last block, the whole table of a row without a context: first, last and
+    between live rows) pointing at a block of NaN, and a finite result equal
+    to the band-masked reference's."""
+    rng = np.random.default_rng(100 + window)
+    bs, kv, d, heads = 8, 2, 32, 4
+    lens = [0, 45, 9, 0, 0, 30, 72, 1, 0]
+    k, v, tables, poison = _paged(rng, lens, bs, kv, d)
+    layer = 1
+    q = rng.standard_normal((len(lens), heads, d)).astype(np.float32)
+    ctx = np.asarray(lens, np.int32)
+    dead = np.full_like(tables, poison)
+    k_nan, v_nan = np.full_like(k, np.nan), np.full_like(v, np.nan)
+    for r, n in enumerate(lens):
+        first = max(n - window, 0) // bs if window else 0
+        mine = tables[r, first:-(-n // bs)]
+        dead[r, first:first + len(mine)] = mine
+        k_nan[:, mine], v_nan[:, mine] = k[:, mine], v[:, mine]
+    want = np.stack([
+        _dense(q[r][None], k[layer], v[layer], tables, r, np.asarray([n - 1]),
+               window or n, bs)[0] if n else np.zeros((heads, d), np.float32)
+        for r, n in enumerate(lens)])
+    got = np.asarray(pa.paged_decode_attention(q, k_nan, v_nan, layer, dead,
+                                               ctx, window=window))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # and once more through slots two deep, one block a fetch
+    narrow = dataclasses.replace(pa.pick_decode_tiles(
+        len(lens), heads, kv, d, bs, np.float32), kb=1, slots=2)
+    got = pa._decode_pallas(*map(jnp.asarray, (q, k_nan, v_nan)),
+                            jnp.full((1,), layer, jnp.int32),
+                            jnp.asarray(dead), jnp.asarray(ctx), tiles=narrow,
+                            window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
 
 # -- (g) one kind of layer, all of them windowed (Mistral) --------------------

@@ -286,35 +286,47 @@ def _assert_pools_stay_in_place(compiled, pool):
 
 #: the served shapes of the paged kernels: query heads, KV heads, window
 _SERVED_ATTENTION = {"mistral-7b": (H, KV, 0), "olmoe-1b-7b": (16, 16, 0),
-                     "mellum2-window-layer": (32, 4, 1024)}
+                     "mellum2-window-layer": (32, 4, 1024),
+                     "nemotron3-attention-layer": (32, 2, 0)}
 
 
 @pytest.mark.parametrize("kernel,model", [
-    ("decode", "mistral-7b"),
-    *(("prefill", model) for model in _SERVED_ATTENTION)])
+    (kernel, model) for kernel in ("decode", "prefill")
+    for model in _SERVED_ATTENTION])
 def test_paged_attention_compiles(one_chip, mosaic, kernel, model):
     """Each kernel alone on the whole pool (layers, blocks, ...) with the
-    layer a traced scalar: Mosaic takes ``k_hbm.at[layer, blk]``.  The
-    prefill kernel on the flat queries of a step of 512 tokens in bfloat16,
-    at every served share of query heads a KV head (4, 1, 8) and Mellum2's
-    window; its ring event names the picker's tiles and no fallback."""
+    layer a traced scalar: Mosaic takes ``k_hbm.at[layer, blk]``.  Both at
+    every served share of query heads a KV head (4, 1, 8, 16) and Mellum2's
+    window, in bfloat16: the decode kernel on a step's rows (64 for
+    Nemotron-3: its cell's), the prefill kernel on the flat queries of a step
+    of 512 tokens; each one's ring event names the picker's tiles and no
+    fallback."""
     from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_prefill_attention)
 
     heads, kv, window = _SERVED_ATTENTION[model]
-    seqs, layers, blocks, bs, max_blocks = 32, 4, 256, 64, 16
+    seqs, layers, blocks, bs, max_blocks = (64 if kv == 2 else 32, 4, 256,
+                                            64, 16)
     sds = functools.partial(_sds, sharding=one_chip)
     pool = sds((layers, blocks, bs, kv, D), jnp.bfloat16)
     layer = sds((), jnp.int32)
     tables, lens = sds((seqs, max_blocks), jnp.int32), sds((seqs,), jnp.int32)
+    tracer.clear()
     if kernel == "decode":
-        text = _compile(paged_decode_attention,
+        text = _compile(functools.partial(paged_decode_attention,
+                                          window=window),
                         sds((seqs, heads, D), jnp.bfloat16), pool, pool,
                         layer, tables, lens,
                         kernels=["paged_attention_decode"])
+        assert f"bf16[{seqs},{heads},{D}]" in text
+        event, = [s.attrs for s in tracer.spans()
+                  if s.name == "kernel/paged_attention_decode_tiles"]
+        assert event == {"rows": seqs, "heads": heads, "kv": kv, "d": D,
+                         "block": bs, "window": window,
+                         "kb": 32 // kv, "slots": 3,
+                         "operand_dtype": "bfloat16"}
     else:
-        tracer.clear()
         text = _compile(functools.partial(paged_prefill_attention,
                                           window=window),
                         sds((512, heads, D), jnp.bfloat16), pool, pool,
@@ -327,6 +339,33 @@ def test_paged_attention_compiles(one_chip, mosaic, kernel, model):
                          "block": bs, "window": window, "tq": "8/128",
                          "kb": 4, "grid_steps": 1}
     assert _pool_passes(text, (layers, blocks, bs, kv, D)) == []
+
+
+@pytest.mark.parametrize("rows,heads,kv,d,dtype,span", [
+    (200, 64, 8, 256, jnp.float32, 128), (32, 16, 4, 256, jnp.bfloat16, 32),
+    (32, H, KV, D, jnp.float32, 32)], ids=["spans", "d256-kv4", "f32"])
+def test_decode_attention_compiles_off_the_served_shapes(one_chip, mosaic,
+                                                         rows, heads, kv, d,
+                                                         dtype, span):
+    """What no cell serves and the interpreter cannot judge: rows past what a
+    grid step holds (two spans of 128 rows of 64 float32 heads of 256, the
+    last padded), a ``head_dim`` of two lane tiles (the pools read as they
+    lie, a block landing as ``(block, KV, D)``), and float32 pools."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, pick_decode_tiles)
+
+    assert pick_decode_tiles(rows, heads, kv, d, 64, dtype).span == span
+    sds = functools.partial(_sds, sharding=one_chip)
+    pool = sds((2, 64, 64, kv, d), dtype)
+    tracer.clear()
+    _compile(paged_decode_attention, sds((rows, heads, d), dtype), pool, pool,
+             sds((), jnp.int32), sds((rows, 16), jnp.int32),
+             sds((rows,), jnp.int32), kernels=["paged_attention_decode"])
+    event, = [s.attrs for s in tracer.spans()
+              if s.name == "kernel/paged_attention_decode_tiles"]
+    assert "fallback" not in event
+    assert event["operand_dtype"] == jnp.dtype(dtype).name
 
 
 @pytest.mark.parametrize("tokens,grid_steps", [(2048, 2), (4096, 4),
@@ -804,8 +843,10 @@ def test_mellum2_step_programs_compile(one_chip, mosaic, program):
     W8A16 at group 128, two periods of S S S F, the serving cell's engine
     sizes: a global pool of 3,000 blocks, a window pool of 801, tables of
     132) compile for the described chip.  Both kinds of attention layer run
-    the paged kernel (the ring holds a ``kernel/paged_attention_window``
-    event of window 1024 for the program's kind, none with ``fallback``),
+    the paged kernel (for the mixed program the ring holds a
+    ``kernel/paged_attention_window`` event of window 1024, for the decode
+    program ``kernel/paged_attention_decode_tiles`` events of windows 1024
+    and 0, none with ``fallback``),
     every GEMM its kernel (no ``kernel/*_tiles`` event with ``fallback``:
     group 128 tiles the experts' 2304 x 896 and 896 x 2304), the routed
     FFN's scopes are in the lowered names, and neither pool is copied."""
@@ -828,10 +869,16 @@ def test_mellum2_step_programs_compile(one_chip, mosaic, program):
               if s.name.startswith("kernel/")]
     assert not [e for e in events if "fallback" in e[1]], events
     kind = "decode" if program == "decode_step" else "prefill"
-    windows = [a for name, a in events
-               if name == "kernel/paged_attention_window"]
-    assert windows and all(a == {"kind": kind, "window": 1024,
-                                 "first_block_static": 0} for a in windows)
+    if kind == "decode":  # the decode kernel's tiles name its window
+        windows = {a["window"] for name, a in events
+                   if name == "kernel/paged_attention_decode_tiles"}
+        assert windows == {0, 1024}
+    else:
+        windows = [a for name, a in events
+                   if name == "kernel/paged_attention_window"]
+        assert windows and all(a == {"kind": kind, "window": 1024,
+                                     "first_block_static": 0}
+                               for a in windows)
     text = lowered.as_text(debug_info=True)
     for name in ("grouped_mixed_gemm", "mixed_gemm", "moe_route",
                  "moe_dispatch", "moe_experts", "moe_combine",
