@@ -44,11 +44,28 @@ from .spec import build_draft_spec_step, build_self_draft_step
 
 # What a step's body hands back to ``step``: the tokens by uid, the tokens in
 # its batch, and where its split is made (None where it dispatched nothing):
-# its ``engine/dispatch`` span with the thread's CPU clock where that opened,
-# its ``engine/wait`` span with the clock where that closed (``_thread_cpu``;
-# four of None with tracing off).
+# its ``engine/dispatch`` span with the thread's CPU clock where that opened
+# (two of None where the call found its program under way: its split opens at
+# its entry), its ``engine/wait`` span with the clock where that closed
+# (``_thread_cpu``; four of None with tracing off).
 _StepResult = Tuple[Dict[int, List[int]], int,
                     Optional[Tuple[Any, Optional[float], Any, Optional[float]]]]
+
+
+@dataclasses.dataclass
+class _Underway:
+    """A decode program that has been called and whose tokens no call has
+    fetched yet: what it returned (``out``: the tokens on the device, an MoE
+    model's stats behind them), the rows it ran and whose they were then
+    (``uids``: a row retired since has lost its own), and what its
+    ``engine/step`` span will say of it: the kind's counters, its copies and
+    bytes."""
+
+    out: jax.Array
+    rows: "np.ndarray"
+    uids: "np.ndarray"
+    counts: Dict[str, Any]
+    h2d: Tuple[int, int]
 
 
 def _thread_cpu() -> Optional[float]:
@@ -324,6 +341,26 @@ class InferenceEngineV2:
         self._staged: Optional[Tuple[Any, Dict[str, jax.Array], Any]] = None
         self._stage_use: Optional[str] = None
         self._stage_dropped = 0
+        # Decode-ahead: the decode program that the call before dispatched
+        # BEHIND its own, before it fetched its own tokens, and that the next
+        # call of ``step`` takes for its step (``_decode_step_fast``).  Its
+        # token ids never saw the host: they are the array its predecessor
+        # returned.  While it is set the table reads what it read on the
+        # parent between the same two calls (the step before is whole: counted,
+        # recorded, trimmed), and everything that touches a pool from the host
+        # does so through ``self.caches``, which this program returned: the
+        # device runs it behind the program, so a block that a ``cancel``
+        # frees under it, a demoted or exported block (hashed content ends
+        # before the slot the program writes), a promoted or imported one (a
+        # later owner's writes are dispatched later) need no wait.  Nor do
+        # ``close`` (the pager's alone) and ``swap_params`` (a drained
+        # engine's, and the program holds the weights it was called with):
+        # what is left of the program is dropped by the next ``step``.
+        # ``_ahead_flags``: of the step under way, for its span (whether it
+        # found its program under way, whether it dispatched its successor,
+        # the rows whose token it dropped)
+        self._ahead: Optional[_Underway] = None
+        self._ahead_flags = (0, 0, 0)
         self.caches = {name: jnp.zeros(shape, dtype)
                        for name, (shape, dtype) in arrays.items()}
         # SSM state bytes a row reads and writes a step, all state layers
@@ -369,6 +406,10 @@ class InferenceEngineV2:
         self._prefilling = 0  # running seqs still before their first token
         self.steps = 0  # step() calls so far: the spans' ``step``
         self.fast_steps = 0  # telemetry: SoA decode steps taken
+        # of those, the ones that found their program under way, and the
+        # tokens computed ahead for rows that were retired before the fetch
+        self.ahead_steps = 0
+        self.ahead_dropped = 0
         self.burst_steps = 0  # telemetry: multi-token burst programs run
         self._uid = 0
         self._rng = jax.random.PRNGKey(0)
@@ -1336,15 +1377,18 @@ class InferenceEngineV2:
             view[...] = fields[name]
         return buf
 
-    def _to_device(self, layout: StepLayout, buf: "np.ndarray"
-                   ) -> Dict[str, jax.Array]:
+    def _to_device(self, layout: StepLayout, buf: "np.ndarray",
+                   out: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
         """The step's one host-to-device copy: ``buf`` goes to the program
         that takes it apart on the device as it is, and the call makes the
         copy (one trip into the runtime, not two) → its fields as device
-        arrays."""
+        arrays.  ``out``: what the decode program before returned, where the
+        step is dispatched behind it: the same program takes its
+        ``token_ids`` from there, on the device."""
         copies, nbytes = self._h2d or (0, 0)
         self._h2d = (copies + 1, nbytes + buf.nbytes)
-        return build_unpack(layout)(buf)
+        unpack = build_unpack(layout)
+        return unpack(buf) if out is None else unpack(buf, out)
 
     @staticmethod
     def _tables(fields: Dict[str, jax.Array]):
@@ -1366,8 +1410,8 @@ class InferenceEngineV2:
         The copy is counted with what is staged, for the step that runs on
         it; this step's own count is on its span already."""
         if (self.waiting or not self.running or self._prefilling
-                or self._spec_fwd is not None):
-            return
+                or self._spec_fwd is not None or self._ahead is not None):
+            return  # (a step dispatched ahead has made its copy)
         sp = tracer.begin("engine/stage", **sub)
         if self._growing:
             self._window_open_blocks()
@@ -1390,7 +1434,6 @@ class InferenceEngineV2:
         on), and the engine thread would queue behind every streaming thread
         the broker just woke, the very wait the staging takes out."""
         staged, self._staged = self._staged, None
-        self._stage_dropped = 0
         if staged is None:
             return None
         if temperature is not None and all(
@@ -1437,6 +1480,16 @@ class InferenceEngineV2:
         if self._step_key is None:
             self._rng, self._step_key = jax.random.split(self._rng)
 
+    def _record_rows(self, rows: "np.ndarray", sel: "np.ndarray") -> None:
+        """``sel``: (k, ns) new tokens of ``rows``, as they arrive: into the
+        rows' history, the last of them the next input."""
+        t = self.table
+        k = sel.shape[0]
+        t.hist[rows[:, None],
+               t.hist_len[rows][:, None] + np.arange(k)[None, :]] = sel.T
+        t.hist_len[rows] += k
+        t.next_tok[rows] = sel[-1]
+
     def _advance_rows(self, sel: "np.ndarray") -> "np.ndarray":
         """Vectorized post-decode bookkeeping. ``sel``: (k, ns) new tokens
         for the active rows; retires sequences whose budget is exhausted;
@@ -1444,51 +1497,128 @@ class InferenceEngineV2:
         t = self.table
         rows = np.nonzero(t.active)[0]
         k = sel.shape[0]
-        t.hist[rows[:, None],
-               t.hist_len[rows][:, None] + np.arange(k)[None, :]] = sel.T
-        t.hist_len[rows] += k
-        t.next_tok[rows] = sel[-1]
+        self._record_rows(rows, sel)
         t.ctx[rows] += k
         t.gen[rows] += k
         for r in rows[t.gen[rows] >= t.budget[rows]]:
             self._finish(t.seq_at[int(r)])
         return rows
 
-    def _decode_step_fast(self, temperature: float, rng: Optional[jax.Array],
-                          sub: Dict[str, Any]) -> _StepResult:
-        """Steady-state decode: inputs ARE the table arrays; bookkeeping is
-        vectorized; Python touches only sequences that just completed."""
-        self.fast_steps += 1
+    def _may_go_ahead(self, rng: Optional[jax.Array]) -> bool:
+        """Whether the decode step BEHIND the one whose program was just
+        called may be dispatched before that one's tokens are fetched, by
+        what the engine sees now: the caller leaves the keys to the engine
+        (a key handed to ``step`` is one step's, and the next call's is not
+        known yet), nothing waits, nothing prefills, no speculation, and no
+        active row reaches its budget with the token under way: then a row
+        frees, its caller admits a request, and the next step is a mixed
+        step.  Such a step needs of its predecessor only the token ids, which
+        never leave the device; positions, context lengths, tables,
+        temperatures and seeds the host knows now (a row's whole budget of
+        blocks was set aside at admission)."""
         t = self.table
+        return (rng is None and not self.waiting and not self._prefilling
+                and self._spec_fwd is None and bool(t.active.any())
+                and not (t.gen + 1 >= t.budget)[t.active].any())
+
+    def _call_decode(self, temperature: float, rng: Optional[jax.Array],
+                     sub: Dict[str, Any], behind: Optional[_Underway] = None
+                     ) -> Tuple[_Underway, Any, Optional[float]]:
+        """Pack and call the decode program of the step the table describes
+        (``table.ctx`` / ``active`` / ``seq_at`` are that step's when
+        ``_decode_fwd`` is called: the benchmark's taps read them there) →
+        the program under way, and where its step's split opens: its
+        ``engine/dispatch`` span and the thread's CPU clock there.
+        ``behind``: the program before it, still under way: its tokens are
+        this one's ``token_ids``, as the array it returned, and the staged
+        buffer is not asked for.  The engine's ``_h2d`` is left as it was:
+        the copy made here is the returned program's."""
+        t = self.table
+        held, self._h2d = self._h2d, None
         sp = tracer.begin("engine/h2d", **sub)
         if self._growing:
             self._window_open_blocks()
-        self._step_counts = self._count(t.ctx, t.active, False)
-        # staged by the step before (the span then holds the check alone) or
-        # copied here
-        f = self._decode_inputs(temperature)
+        counts = self._count(t.ctx, t.active, False)
+        if behind is None:
+            # staged by the step before (the span then holds the check alone)
+            # or copied here
+            f = self._decode_inputs(temperature)
+        else:
+            f = self._to_device(self._decode_layout,
+                                self._pack_decode(temperature), behind.out)
         args = (f["token_ids"], f["position_ids"], self._tables(f),
                 f["context_lens"], f["temps"], self._step_rng(rng),
                 f["seeds"])
         if self.adapter_stack is not None:
             args += (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
+        h2d, self._h2d = self._h2d, held
         cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
-        toks, self.caches = self._decode_fwd(self.params, self.caches, *args)
+        out, self.caches = self._decode_fwd(self.params, self.caches, *args)
         self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp_dispatch)
+        rows = np.nonzero(t.active)[0]
+        return (_Underway(out, rows, t.uid[rows], counts, h2d), sp_dispatch,
+                cpu_called)
+
+    def _decode_step_fast(self, temperature: float, rng: Optional[jax.Array],
+                          sub: Dict[str, Any]) -> _StepResult:
+        """Steady-state decode: inputs ARE the table arrays; bookkeeping is
+        vectorized; Python touches only sequences that just completed.
+
+        Two steps in flight: the step's program is the one the call before
+        dispatched ahead, or is called here; then, where ``_may_go_ahead``,
+        the NEXT step's program is called behind it, and only then are this
+        step's tokens fetched, recorded and returned.  The fetch's tail, the
+        bookkeeping, the caller's turn and the next entry then run beside a
+        program.  For the step dispatched ahead the table's counters move
+        before its call (``ctx`` / ``gen``: what this step's token makes of
+        them, known without the token) and the window's blocks are trimmed
+        and opened for it; ``hist`` / ``next_tok`` are filled when the tokens
+        arrive.  What happens to the table between two calls (``put``,
+        ``cancel``, a stop token) finds the program under way already: it is
+        fetched whole by the next call, the tokens of rows retired since are
+        dropped and counted, and a request put since waits one step."""
+        self.fast_steps += 1
+        t = self.table
+        run, self._ahead = self._ahead, None
+        found = run is not None
+        if found:  # its split opens at the call's entry (``step``)
+            sp_dispatch = cpu_called = None
+            self.ahead_steps += 1
+            self._stage_use = "ahead"
+        else:
+            run, sp_dispatch, cpu_called = self._call_decode(temperature, rng,
+                                                             sub)
+        self._h2d, self._step_counts = run.h2d, run.counts
+        ahead = self._may_go_ahead(rng)
+        if ahead:
+            # this step's token counted before it is known; no row ends here
+            t.ctx[t.active] += 1
+            t.gen[t.active] += 1
+            if self._windowed is not None:
+                self._window_trim_rows()
+            self._ahead, _, _ = self._call_decode(
+                temperature, None, {"kind": "decode", "step": self.steps + 1},
+                behind=run)
         sp_wait = tracer.begin("engine/wait", **sub)
-        sampled = self._split_stats(np.asarray(toks))
+        sampled = self._split_stats(np.asarray(run.out))
         tracer.end(sp_wait)
         cpu_fetched = _thread_cpu()
         sp = tracer.begin("engine/finish", **sub)
-        rows = np.nonzero(t.active)[0]
+        rows = run.rows[t.uid[run.rows] == run.uids]  # still whose they were
+        dropped = len(run.rows) - len(rows)
+        self.ahead_dropped += dropped
+        self._ahead_flags = (int(found), int(ahead), dropped)
         sel = sampled[rows].astype(np.int32)[None, :]  # (1, ns)
         out = {t.seq_at[int(r)].uid: [int(s)] for r, s in zip(rows, sel[0])}
-        self._advance_rows(sel)
-        if self._windowed is not None:
-            self._window_trim_rows()
+        if ahead:
+            self._record_rows(rows, sel)
+        else:  # (the active rows are ``rows``: none was admitted since)
+            self._advance_rows(sel)
+            if self._windowed is not None:
+                self._window_trim_rows()
         tracer.end(sp)
         return out, len(rows), (sp_dispatch, cpu_called, sp_wait, cpu_fetched)
 
@@ -1606,8 +1736,10 @@ class InferenceEngineV2:
         right start: it is a dozen slices of one small buffer, and once it
         is done the device waits for the step's program like before it, so
         the wait the host causes ends where ``engine/dispatch`` opens."""
-        steady = (not self.waiting and self.running
-                  and self._prefilling == 0)
+        # a decode program under way is this call's step, whatever the table
+        # has come to since (``_decode_step_fast``)
+        steady = self._ahead is not None or (
+            not self.waiting and self.running and self._prefilling == 0)
         kind = (("spec" if self._spec_fwd is not None else "decode")
                 if steady else "mixed")
         running, waiting = self.num_running, len(self.waiting)
@@ -1618,6 +1750,7 @@ class InferenceEngineV2:
         self._step_counts = None
         self._h2d = None
         self._stage_use = None
+        self._stage_dropped = 0
         if kind != "decode":  # what was staged for a decode step: dropped
             self._take_staged()
         t0 = time.monotonic()
@@ -1642,6 +1775,8 @@ class InferenceEngineV2:
             attrs["h2d_copies"], attrs["h2d_bytes"] = self._h2d
         if self._stage_use is not None:  # a decode step: whose copy it ran on
             attrs["staged"] = self._stage_use
+            (attrs["ahead"], attrs["ahead_next"],
+             attrs["ahead_dropped"]) = self._ahead_flags
         if self._stage_dropped:  # staged for this step and not what it needs
             attrs["stage_discarded"] = 1
             attrs["stage_bytes"] = self._stage_dropped
@@ -1659,6 +1794,8 @@ class InferenceEngineV2:
             # after the counters above, which are this step's
             self._stage_next(temperature, sub)
         if sp is not None and dispatched is not None:  # it reached the device
+            if dispatched[0] is None:  # its program was under way at its entry
+                dispatched = (sp, cpu_entry) + dispatched[2:]
             # last: ``post_ms`` runs to here
             attrs.update(_host_split(sp, cpu_entry, *dispatched))
         tracer.end(sp, **attrs)
@@ -1673,7 +1810,7 @@ class InferenceEngineV2:
     def _step_impl(self, temperature: float, rng: Optional[jax.Array],
                    sub: Dict[str, Any]) -> _StepResult:
         """The step body.  ``sub`` is what each of its spans carries."""
-        if not self.waiting and self.running and self._prefilling == 0:
+        if sub["kind"] != "mixed":
             # steady state: every running sequence is decoding — SoA path
             if self._spec_fwd is not None:
                 return self._spec_decode_step(temperature, rng, sub)
@@ -1777,6 +1914,9 @@ class InferenceEngineV2:
         """Decode ``k`` tokens for every running sequence in one jitted
         program (multi-token decode; host loop eliminated). Bookkeeping is
         vectorized over the SoA table (blocks were reserved at admission)."""
+        if self._ahead is not None:
+            raise RuntimeError("a decode step is under way: ``step`` takes "
+                               "its tokens before a burst can run")
         if k not in self._multi_decode:
             self._multi_decode[k] = build_multi_decode_forward(
                 self.model_cfg, self.cfg, k)
@@ -1808,6 +1948,7 @@ class InferenceEngineV2:
             # nor a kind whose every step counts
             steady = (burst > 1 and self._spec_fwd is None
                       and self._windowed is None and self.kind.bursts
+                      and self._ahead is None
                       and not self.waiting and self.running
                       and self._prefilling == 0)
             if steady:

@@ -1255,9 +1255,16 @@ def build_unpack(layout):
     and a bitcast where a field is no int32, nothing else: what it traces
     and lowers at every start is a few milliseconds (the engine's key is NOT
     split here: threefry inside a jitted program lowers in 0.2-0.8 s on the
-    chip's host at every start, PERF.md section 6, PR 34)."""
+    chip's host at every start, PERF.md section 6, PR 34).
 
-    def unpack_step_inputs(buf):
+    A decode step that is dispatched behind one still under way hands it,
+    beside the buffer, what that program returned (``out``, ``_with_stats``:
+    its tokens, an MoE model's stats behind them; a second shape of the one
+    jitted function): ``token_ids`` are then ``out``'s first rows, on the
+    device, where the buffer's hold nothing (0 in the rows that are not in
+    the step, as a host that had fetched them would write)."""
+
+    def unpack_step_inputs(buf, out=None):
         fields = {}
         for name, at, shape, dtype in layout.fields:
             x = jax.lax.slice(buf, (at,), (at + math.prod(shape),)
@@ -1265,8 +1272,14 @@ def build_unpack(layout):
             if dtype != "int32":
                 x = jax.lax.bitcast_convert_type(x, jnp.dtype(dtype))
             fields[name] = x
+        if out is not None:
+            ids = fields["token_ids"]
+            fields["token_ids"] = jnp.where(
+                fields["context_lens"] > 0,
+                jax.lax.slice(out, (0,), ids.shape), ids)
         return fields
 
+    # (``out`` is the array the next fetch reads: not donated either)
     # lint: allow(jit-no-donate) — its one argument is the host's NumPy buffer
     return _memo(("unpack", layout), lambda: jax.jit(unpack_step_inputs))
 

@@ -539,6 +539,8 @@ class DecodeStateTable:
         self.hist_len = np.zeros(max_seqs, np.int32)
         self.row_of: Dict[int, int] = {}
         self.seq_at: Dict[int, SequenceDescriptor] = {}
+        # ``seq_at``'s uids as an array (0: the row is free; uids start at 1)
+        self.uid = np.zeros(max_seqs, np.int64)
         self._free = list(range(max_seqs - 1, -1, -1))
 
     def admit(self, seq: SequenceDescriptor) -> int:
@@ -549,6 +551,7 @@ class DecodeStateTable:
             row = self._free.pop()
         self.row_of[seq.uid] = row
         self.seq_at[row] = seq
+        self.uid[row] = seq.uid
         self.active[row] = True
         bt = self.block_tables[row]
         bt[:] = 0
@@ -592,6 +595,7 @@ class DecodeStateTable:
         self.flush_tokens(seq)
         row = self.row_of.pop(seq.uid)
         del self.seq_at[row]
+        self.uid[row] = 0
         self.active[row] = False
         self.ctx[row] = 0
         self.next_tok[row] = 0

@@ -19,12 +19,19 @@ wait a stream.  ``staging`` (ISSUE 38): the share of the decode steps that
 ran on the copy the step before staged (``staged`` = ``"used"``) or made
 their own (``"fresh"``), and of the stagings (``engine/stage``, a row of the
 table like any span) the share thrown away unused, by the kind of step that
-found them.  And ``starved``:
+found them; beside them (ISSUE 50) the share that found their program under
+way (``ahead`` 1: ``staged`` = ``"ahead"``), the share that dispatched their
+successor before their own fetch (``ahead_next`` 1) and the tokens computed
+ahead and dropped (``ahead_dropped``, summed, and the steps that dropped
+any).  And ``starved``:
 the three sums ``device_starved_pct`` is made of (``pre_ms``, ``post_ms``,
 the turns that ended in a step) beside the time with nothing to run
 (``broker/idle``), in seconds, over the window and over the traced part of
 it, where the device's own idle share (``serve_device_idle_pct``) stands
-beside them and what is left over is a step's launch and the fetch's tail.
+beside them and what is left over is a step's launch and the fetch's tail;
+``post_behind_s`` is the part of ``post_s`` that ran beside a program (the
+``post_ms`` of the steps with ``ahead_next`` 1), which the device did not
+wait through.
 
     chiprun -- python scripts/host_path_by_span.py --workload chat-decode-sat \
         --seed 3400000001 [--root .bench_checkout/parent]
@@ -126,7 +133,9 @@ def staging(spans) -> dict:
     the decode steps, the share of them that say ``staged`` = ``"used"`` and
     ``"fresh"``, the ``engine/stage`` spans, and the share of those that a
     later step dropped (``stage_discarded``), by that step's kind, with
-    their bytes.  Empty for a program from before ``staged``."""
+    their bytes; and the shares that found their program under way and that
+    dispatched their successor ahead, with the tokens dropped (a program from
+    before ``ahead`` reads 0).  Empty for a program from before ``staged``."""
     steps = [s["attrs"] for s in spans if s["name"] == "engine/step"]
     use = [a["staged"] for a in steps if "staged" in a]
     if not use:
@@ -136,6 +145,13 @@ def staging(spans) -> dict:
     out = {"decode_steps": len(use),
            "used_pct": 100.0 * use.count("used") / len(use),
            "fresh_pct": 100.0 * use.count("fresh") / len(use),
+           "ahead_pct": 100.0 * sum(a.get("ahead", 0) for a in steps)
+           / len(use),
+           "ahead_next_pct": 100.0 * sum(a.get("ahead_next", 0)
+                                         for a in steps) / len(use),
+           "ahead_dropped": sum(a.get("ahead_dropped", 0) for a in steps),
+           "ahead_dropped_steps": sum(a.get("ahead_dropped", 0) > 0
+                                      for a in steps),
            "stagings": stagings, "discarded": len(dropped),
            "discarded_bytes": sum(a["stage_bytes"] for a in dropped)}
     if stagings:
@@ -152,15 +168,16 @@ def starved(spans, t0: float, t1: float) -> dict:
         return max(0.0, min(b, t1) - max(a, t0))
 
     out = {"seconds": t1 - t0, "steps": 0, "pre_s": 0.0, "post_s": 0.0,
-           "turn_s": 0.0, "nothing_to_run_s": 0.0}
+           "post_behind_s": 0.0, "turn_s": 0.0, "nothing_to_run_s": 0.0}
     for s in spans:
         a = s["attrs"]
         if s["name"] == "engine/step" and "pre_ms" in a:
             out["steps"] += t0 <= s["t_end"] < t1
             out["pre_s"] += inside(s["t_start"],
                                    s["t_start"] + a["pre_ms"] / 1e3)
-            out["post_s"] += inside(s["t_end"] - a["post_ms"] / 1e3,
-                                    s["t_end"])
+            post = inside(s["t_end"] - a["post_ms"] / 1e3, s["t_end"])
+            out["post_s"] += post
+            out["post_behind_s"] += post * a.get("ahead_next", 0)
         elif s["name"] == "broker/turn" and a.get("next") == "step":
             out["turn_s"] += inside(s["t_start"], s["t_end"])
         elif s["name"] == "broker/idle":

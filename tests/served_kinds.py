@@ -18,6 +18,19 @@ TURNED_ON = {
 }
 
 
+#: one tiny model a served kind, the KV kind's three shapes apart (dense,
+#: routed experts, a window pool beside the global one): preset, and what its
+#: engine is built with beyond the sizes a test file shares
+TINY_KINDS = {
+    "kv-dense": ("tiny", {}),
+    "kv-moe": ("tiny-olmoe", {}),
+    "kv-two-pools": ("tiny-mellum2", {}),
+    "state": ("tiny-nemotron3", {}),
+    "latent": ("tiny-glm52", dict(num_blocks=65)),
+    "eva": ("tiny-evabyte", {}),
+}
+
+
 def refusal_cases(kind, model_cfg, v2) -> list:
     """(V2Config overrides, the field the refusal names) for every row of the
     refusal table that ``kind`` holds for this model, every field of the row
@@ -30,6 +43,10 @@ def refusal_cases(kind, model_cfg, v2) -> list:
 _STEP = {"kind", "step", "running", "waiting", "prefilling", "emitted",
          "tokens", "budget", "h2d_copies", "h2d_bytes", "pre_ms", "device_ms",
          "post_ms", "pre_cpu_ms", "post_cpu_ms"}
+#: a decode step: whose copy its program ran on and, since ISSUE 50, whether
+#: it found that program under way, whether it dispatched its successor
+#: before its own fetch, and the rows whose token it dropped
+_DECODE = {"staged", "ahead", "ahead_next", "ahead_dropped"}
 #: a step that found a staged copy it could not use
 _SOMETIMES = {"stage_discarded", "stage_bytes"}
 #: by what the model is, beyond the above: on every step, on mixed steps only
@@ -61,7 +78,7 @@ def assert_step_attrs(steps, *what) -> None:
     assert {"mixed", "decode"} <= {a["kind"] for a in steps}
     for a in steps:
         mixed = a["kind"] == "mixed"
-        want = _STEP | ({"attn_q_slots"} if mixed else {"staged"})
+        want = _STEP | ({"attn_q_slots"} if mixed else _DECODE)
         for name in what:
             always, on_mixed = STEP_ATTRS[name]
             want |= always | (on_mixed if mixed else set())

@@ -176,9 +176,33 @@ def test_every_device_step_carries_its_split(devices, tiny_model, over,
     assert {s.attrs["kind"] for s in steps[1:]} == kinds
     spans = global_tracer.spans()
     short = []
+    before = None
     for s in steps[1:]:
         a = s.attrs
         assert all(k in a for k in SPLIT + ("device_ms",))
+        # the children of the step's own program carry its ``step``, and so
+        # do those of a program dispatched ahead (ISSUE 50), which lie inside
+        # the step before: its pack and its call
+        kids = {k.name: k for k in spans if k.name != "engine/step"
+                and k.attrs.get("step") == a["step"]}
+        if a.get("ahead"):
+            # the call found its program under way: ``device_ms`` opens at
+            # its entry, and the program was called inside the step before,
+            # ahead of that step's own fetch
+            assert a["pre_ms"] == 0.0 and a["staged"] == "ahead"
+            assert before.attrs["ahead_next"] == 1
+            assert kids["engine/dispatch"].parent_id == before.span_id
+            assert kids["engine/h2d"].parent_id == before.span_id
+            assert kids["engine/dispatch"].t_end <= next(
+                k.t_start for k in spans if k.name == "engine/wait"
+                and k.parent_id == before.span_id)
+            assert a["device_ms"] == pytest.approx(
+                (kids["engine/wait"].t_end - s.t_start) * 1e3)
+            kids["engine/dispatch"] = s  # the split's first edge
+        else:
+            assert a.get("ahead", 0) == 0
+            assert all(k.parent_id == s.span_id for k in kids.values())
+        before = s
         # the three parts are the span, to within the clock reads (a loaded
         # machine can take the core away between two of them: the median)
         whole = a["pre_ms"] + a["device_ms"] + a["post_ms"]
@@ -190,17 +214,20 @@ def test_every_device_step_carries_its_split(devices, tiny_model, over,
         # ``engine/dispatch`` opens, ``post_ms`` opens where ``engine/wait``
         # closes; the step reads the second clock itself, at those points,
         # and no span of it asks for one
-        kids = {k.name: k for k in spans if k.parent_id == s.span_id}
         assert "cpu_ms" not in a
         assert not any("cpu_ms" in k.attrs for k in kids.values())
         assert a["pre_ms"] == pytest.approx(
             (kids["engine/dispatch"].t_start - s.t_start) * 1e3)
-        assert a["pre_ms"] >= kids["engine/h2d"].duration_s * 1e3
+        if not a.get("ahead"):
+            assert a["pre_ms"] >= kids["engine/h2d"].duration_s * 1e3
         assert a["post_ms"] >= kids["engine/finish"].duration_s * 1e3
         assert a["device_ms"] == pytest.approx(
             (kids["engine/wait"].t_end
              - kids["engine/dispatch"].t_start) * 1e3)
     assert statistics.median(short) < 0.2
+    if "decode" in kinds:  # the run reaches both ways a decode step begins
+        assert {a.attrs["ahead"] for a in steps[1:]
+                if a.attrs["kind"] == "decode"} == {0, 1}
 
 
 @pytest.mark.parametrize("enabled, reads_a_step", [(False, 0), (True, 4)],
@@ -209,7 +236,9 @@ def test_a_device_step_reads_the_thread_clock_four_times_or_never(
         devices, tiny_model, monkeypatch, enabled, reads_a_step):
     """The split's four ends (entry, the program's call, the fetch's return,
     the step's end) and nothing else; with tracing off no clock is read and
-    nothing is recorded."""
+    nothing is recorded.  A step that dispatches its successor ahead reads
+    that program's call as well, and the step that finds it under way reads
+    none: four a step all the same."""
     global_tracer.clear()
     monkeypatch.setattr(global_tracer, "enabled", enabled)
     eng = _engine(tiny_model)
@@ -225,11 +254,14 @@ def test_a_device_step_reads_the_thread_clock_four_times_or_never(
     assert len(calls) == reads_a_step * len(steps)
     if not enabled:
         assert global_tracer.spans() == []
+        assert eng.ahead_steps == 1  # (the mechanism needs no tracing)
     else:
         assert len(steps) == 3 and all("pre_cpu_ms" in s.attrs for s in steps)
+        assert [s.attrs.get("ahead") for s in steps] == [None, 0, 1]
 
 
-# what happens between two steps → what the NEXT step says of the staging
+# what happens between a step that staged and the next → what the NEXT step
+# says of the staging
 _BETWEEN = {
     "nothing": (lambda eng, uids: None, "decode", "used", False),
     "put": (lambda eng, uids: eng.put([5, 6, 7, 8], 3), "mixed", None, True),
@@ -255,19 +287,23 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
     on any step that found staged fields it could not use, ``h2d_copies`` 1
     either way; and the calls of the unpack program lie where they should:
     a ``"used"`` step makes none before its program is called (its
-    ``pre_ms`` holds no trip into the runtime), its one is in
-    ``engine/stage``, for the step after it."""
+    ``pre_ms`` holds no trip into the runtime), its one is for the step
+    after it: in ``engine/stage``, last, or, where the step dispatches that
+    step ahead (ISSUE 50: every decode step here, none of whose rows is at
+    its budget), in that step's ``engine/h2d``, with the predecessor's
+    tokens beside the buffer, before this step's own fetch."""
     from deepspeed_tpu.inference.v2 import engine as engine_mod
 
     act, kind, staged, discarded = _BETWEEN[between]
-    calls, real = [], engine_mod.build_unpack
+    calls, behind, real = [], [], engine_mod.build_unpack
 
     def build_unpack(layout):
         program = real(layout)
 
-        def call(buf):
+        def call(buf, out=None):
             calls.append(time.monotonic())
-            return program(buf)
+            behind.append(int(out is not None))
+            return program(buf, out)
 
         return call
 
@@ -277,10 +313,12 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
     pinned = 0.5 if between == "temperature-all-pinned" else None
     uids = [eng.put(list(range(1, 1 + n)), 12, temperature=pinned)
             for n in ((5, 6, 2, 3) if pinned else (5, 9))]
-    for _ in range(3):  # mixed, then decode steps, all rows decoding
-        eng.step(temperature=eng.step_temperature)
+    # the mixed step that ends every prompt: all rows decoding, none ahead
+    eng.step(temperature=eng.step_temperature)
     assert eng._staged is not None and eng._prefilling == 0
+    assert eng._ahead is None
     act(eng, uids)
+    del calls[:], behind[:]
     global_tracer.clear()
     eng.step(temperature=eng.step_temperature)
     (st,) = global_tracer.spans(name="engine/step")
@@ -291,8 +329,11 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
         assert (a["stage_discarded"], a["stage_bytes"]) == (
             1, eng._decode_layout.size * 4)
     assert a["h2d_copies"] == 1
+    # the step's own children, and those of the step it dispatched ahead
     kids = {k.name: k for k in global_tracer.spans()
-            if k.parent_id == st.span_id}
+            if k.parent_id == st.span_id and k.attrs["step"] == a["step"]}
+    ahead = {k.name: k for k in global_tracer.spans()
+             if k.parent_id == st.span_id and k.attrs["step"] != a["step"]}
     inside = {name: sum(k.t_start <= t <= k.t_end for t in calls)
               for name, k in kids.items()}
     in_step = sum(st.t_start <= t <= st.t_end for t in calls)
@@ -300,10 +341,25 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
         st.t_start <= t <= kids["engine/dispatch"].t_start for t in calls)
     assert before_the_program == (0 if staged == "used" else 1)
     assert inside["engine/h2d"] == before_the_program
-    # every one of these steps ends steady: it stages the next one's
-    assert list(kids)[-1] == "engine/stage" and inside["engine/stage"] == 1
-    assert in_step == before_the_program + 1
-    assert eng._staged is not None
+    assert in_step == before_the_program + 1 == len(calls)
+    if kind == "decode":
+        # it dispatches the next step behind its own program: that step's
+        # copy is made with this program's tokens beside it, and nothing is
+        # staged
+        assert (a["ahead"], a["ahead_next"], a["ahead_dropped"]) == (0, 1, 0)
+        assert sorted(ahead) == ["engine/dispatch", "engine/h2d"]
+        assert {k.attrs["step"] for k in ahead.values()} == {a["step"] + 1}
+        assert ahead["engine/h2d"].t_start <= calls[-1] <= \
+            ahead["engine/h2d"].t_end <= kids["engine/wait"].t_start
+        assert behind == [0] * before_the_program + [1]
+        assert "engine/stage" not in kids and eng._staged is None
+        assert eng._ahead is not None
+    else:
+        # the mixed step ends steady: it stages the next one's, last
+        assert "ahead" not in a and not ahead and eng._ahead is None
+        assert list(kids)[-1] == "engine/stage"
+        assert inside["engine/stage"] == 1 and behind == [0, 0]
+        assert eng._staged is not None
 
 
 def test_nothing_is_staged_where_the_next_step_is_no_decode_step(
@@ -332,9 +388,11 @@ def test_nothing_is_staged_where_the_next_step_is_no_decode_step(
     steps = global_tracer.spans(name="engine/step")
     stages = [any(k.name == "engine/stage" and k.parent_id == s.span_id
                   for k in global_tracer.spans()) for s in steps]
-    assert stages == [False, False, True, True, False, True, False]
+    # (the first decode step dispatches the second ahead and stages nothing;
+    # the second, whose row is at its budget, is the last)
+    assert stages == [False, False, True, False, False, True, False]
     assert [s.attrs.get("staged") for s in steps] == [
-        None, None, None, "used", "used", None, None]
+        None, None, None, "used", "ahead", None, None]
     last = steps[-1].attrs
     assert "device_ms" not in last and "h2d_copies" not in last
     assert (last["stage_discarded"], last["stage_bytes"]) == (
@@ -583,10 +641,32 @@ def test_the_by_span_script_prints_both_clocks_and_the_three_sums():
              stage_discarded=1, stage_bytes=8832)] + [
         span("engine/stage", 106.02 + i / 10, 106.021 + i / 10, kind="decode")
         for i in range(6)]
+    # (a program from before ``ahead`` reads 0 in its four)
     assert script.staging(spans + staged) == {
         "decode_steps": 4, "used_pct": 75.0, "fresh_pct": 25.0,
+        "ahead_pct": 0.0, "ahead_next_pct": 0.0, "ahead_dropped": 0,
+        "ahead_dropped_steps": 0,
         "stagings": 6, "discarded": 3, "discarded_bytes": 26496,
         "discarded_pct": 50.0, "discarded_by_decode": 1,
         "discarded_by_mixed": 2}
+    # ISSUE 50: the decode steps that found their program under way, those
+    # that dispatched their successor before their own fetch, the tokens
+    # dropped; and the part of ``post_s`` that ran beside a program
+    ahead = [
+        step("decode", 107.0, 1.0, 0.9, 1.5, 1.2, staged="used", ahead=0,
+             ahead_next=1, ahead_dropped=0),
+        step("decode", 107.1, 0.0, 0.0, 1.5, 1.2, staged="ahead", ahead=1,
+             ahead_next=1, ahead_dropped=0),
+        step("decode", 107.2, 0.0, 0.0, 2.5, 1.2, staged="ahead", ahead=1,
+             ahead_next=1, ahead_dropped=3),
+        step("decode", 107.3, 0.0, 0.0, 1.5, 1.2, staged="ahead", ahead=1,
+             ahead_next=0, ahead_dropped=1)]
+    shares = script.staging(ahead)
+    assert (shares["used_pct"], shares["ahead_pct"],
+            shares["ahead_next_pct"]) == (25.0, 75.0, 75.0)
+    assert (shares["ahead_dropped"], shares["ahead_dropped_steps"]) == (4, 2)
+    behind = script.starved(ahead, 107.0, 108.0)
+    assert (behind["post_s"], behind["post_behind_s"]) == (0.007, 0.0055)
+    assert whole["post_behind_s"] == 0.0
     assert script.by_span(spans + staged)["decode"]["engine/stage"] == [
         1.0, 1.0]

@@ -726,7 +726,11 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     ``engine/stage`` behind them where the step ends steady and nowhere
     else; the step itself says how long the device was (presumed) busy, how
     full its batch was, what was copied to the device for its program and,
-    a decode step, whether the step before had staged that copy."""
+    a decode step, whether the step before had staged that copy.  A decode
+    step that dispatches its successor ahead (ISSUE 50) holds that step's
+    pack and call, under that step's number, before its own fetch, and
+    stages nothing; the step that finds the program under way holds neither
+    of its own."""
     from deepspeed_tpu.observability.trace import tracer
 
     cfg, params = tiny_model
@@ -756,29 +760,42 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
                               and False in ends_steady.values())
     for st in steps:
         kids = [s for s in spans if s.parent_id == st.span_id]
-        staged_next = ends_steady[st.attrs["step"]]
-        assert [k.name for k in kids] == _CHILDREN[kind] + (
-            [_STAGE] if staged_next else [])
+        found, ahead_next = (st.attrs.get("ahead", 0),
+                             st.attrs.get("ahead_next", 0))
+        staged_next = ends_steady[st.attrs["step"]] and not ahead_next
+        own, nxt = _CHILDREN[kind], _DECODE_PHASES[:2] * ahead_next
+        want = (own[:-2] * (not found) + nxt + own[-2:]
+                + ([_STAGE] if staged_next else []))
+        assert [k.name for k in kids] == want
+        first_of_next = len(own[:-2]) * (not found)
         edges = [st.t_start]
-        for k in kids:
+        for i, k in enumerate(kids):
             assert k.attrs["kind"] == kind
-            assert k.attrs["step"] == st.attrs["step"]
+            assert k.attrs["step"] == st.attrs["step"] + (
+                first_of_next <= i < first_of_next + len(nxt))
             edges += [k.t_start, k.t_end]
         edges.append(st.t_end)
         assert edges == sorted(edges)  # inside the step, one after another
+        if found:  # its pack and call lie in the step before, its split at 0
+            kids = [s for s in spans if s.name in _DECODE_PHASES[:2]
+                    and s.attrs["step"] == st.attrs["step"]] + kids
+            assert [k.name for k in kids[:2]] == _DECODE_PHASES[:2]
+            assert st.attrs["pre_ms"] == 0.0
+            kids[1] = st  # where ``device_ms`` opens: the step's entry
         assert 0.0 <= st.attrs["device_ms"] <= st.duration_s * 1e3
         assert st.attrs["budget"] == 16
         assert 0 < st.attrs["tokens"] <= 16
-        by_name = {k.name: k for k in kids}
+        by_name = {k.name: k for k in kids
+                   if k.attrs["step"] == st.attrs["step"]}
         assert st.attrs["device_ms"] == pytest.approx(
             (by_name["engine/wait"].t_end
-             - by_name["engine/dispatch"].t_start) * 1e3)
+             - by_name.get("engine/dispatch", st).t_start) * 1e3)
         # the staging lies in the step's ``post_ms``, behind the fetch
         if staged_next:
             assert st.attrs["post_ms"] >= (
                 st.t_end - by_name[_STAGE].t_start) * 1e3 - 1e-6
         for k in kids:  # a child carries what a reader joins on, no more
-            assert set(k.attrs) == {"kind", "step"}
+            assert k is st or set(k.attrs) == {"kind", "step"}
         # the copies that fed the step's program, on the step itself (a
         # speculative step keeps its own arguments and counts none)
         layout = {"decode": eng._decode_layout,
@@ -793,9 +810,14 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
         assert "stage_discarded" not in st.attrs
         if kind == "decode":
             assert st.attrs["staged"] == (
+                "ahead" if found else
                 "used" if ends_steady.get(st.attrs["step"] - 1) else "fresh")
+            assert st.attrs["ahead_dropped"] == 0
         else:
             assert "staged" not in st.attrs
+    if kind == "decode":  # both ways a decode step begins, and both it ends
+        assert {(s.attrs["ahead"], s.attrs["ahead_next"]) for s in steps} == {
+            (0, 1), (1, 1), (1, 0)}
     if kind == "mixed":  # the long prompt fills whole chunks of the budget
         assert max(s.attrs["tokens"] for s in steps) == 16
 
@@ -967,8 +989,11 @@ def _parents_argument_path(eng):
     as the reference: an array a field (``jnp.asarray`` each; the decode
     step's straight off the SoA table as ``_table_inputs`` and
     ``_row_temps`` read it) and the engine's key split eagerly on the host,
-    unpacked in Python."""
-    def to_device(layout, buf):
+    unpacked in Python; and, like the parent, one step in flight: no step is
+    dispatched before its predecessor's tokens are on the host."""
+    eng._may_go_ahead = lambda rng: False
+
+    def to_device(layout, buf, out=None):
         names = [f[0] for f in layout.fields]
         if "seeds" in names:  # the decode step: off the table, not the buffer
             t = eng.table
@@ -1006,17 +1031,25 @@ def _parents_argument_path(eng):
 # and go, greedy rows sit beside sampled ones (pinned and inherited
 # temperatures)
 _ARRIVALS = {
-    0: [(30, 12, None, 0, 0), (5, 22, 0.8, 7, 1)],
-    6: [(11, 9, 0.0, 0, 0)],
-    13: [(40, 8, 1.3, 3, 1), (3, 18, None, 9, 0)],
-    30: [(7, 14, 0.5, 1, 0)],
-    44: [(26, 9, None, 2, 1), (2, 15, 0.9, 5, 0)],
+    0: ((30, 12, None, 0, 0), (5, 22, 0.8, 7, 1)),
+    6: ((11, 9, 0.0, 0, 0),),
+    13: ((40, 8, 1.3, 3, 1), (3, 18, None, 9, 0)),
+    30: ((7, 14, 0.5, 1, 0),),
+    44: ((26, 9, None, 2, 1), (2, 15, 0.9, 5, 0)),
 }
 
 
-def _drive(eng, adapters, watch=None):
+def _drive(eng, adapters, watch=None, temps=None, late=()):
     """Run ``_ARRIVALS`` to the end → ([(kind, {uid: tokens})] a step, the
-    step keys in order).  ``watch(eng)`` wraps what it wants to observe."""
+    step keys in order, the step-level temperature each step ran under).
+    ``watch(eng)`` wraps what it wants to observe.  A step that was
+    dispatched ahead (ISSUE 50) ran under the temperature of the call that
+    dispatched it, the call before, and a request put while such a step was
+    under way was admitted by the step after it: ``temps`` hands a second
+    engine, which keeps one step in flight, the temperatures the first one's
+    steps ran under, and ``late`` the arrivals to put a step later, so that
+    both admit every request in the same step (a sampled row draws under
+    its step's key)."""
     from deepspeed_tpu.inference.v2 import engine as engine_mod
 
     keys, steps = [], []
@@ -1035,42 +1068,54 @@ def _drive(eng, adapters, watch=None):
     if watch:
         watch(eng)
     rs = np.random.default_rng(2)
+    ran_under, found_one = [], []
     try:
         n = 0
-        while n <= max(_ARRIVALS) or eng.running or eng.waiting:
-            for length, new, temp, seed, slot in _ARRIVALS.get(n, ()):
+        while n <= max(_ARRIVALS) + 1 or eng.running or eng.waiting:
+            arrive = (_ARRIVALS.get(n, ()) if n not in late else ()) + (
+                _ARRIVALS[n - 1] if n - 1 in late else ())
+            if arrive and eng._ahead is not None:
+                found_one.append(n)
+            for length, new, temp, seed, slot in arrive:
                 eng.put(rs.integers(1, 200, length).tolist(),
                         max_new_tokens=new, temperature=temp, seed=seed,
                         adapter_slot=slot if adapters else 0)
             # the step-level temperature, which rows without one inherit:
             # three steps each, so that a decode step can run on what the
             # step before staged, and the next change throws a staging away
-            eng.step_temperature = 0.7 if n // 3 % 2 else 0.0
+            under_way = eng._ahead is not None
+            before = getattr(eng, "step_temperature", None)
+            eng.step_temperature = (temps[n] if temps is not None
+                                    else 0.7 if n // 3 % 2 else 0.0)
+            ran_under.append(before if under_way else eng.step_temperature)
             steps.append(eng.step(temperature=eng.step_temperature))
             n += 1
     finally:
         engine_mod.sample_rows = sample
-    return steps, keys
+    return steps, keys, ran_under, found_one
 
 
 @pytest.fixture(scope="module", params=list(_LAYOUTS))
 def one_copy_run(request, devices):
     """The run of ``_ARRIVALS`` on an engine of each layout, watched, and the
     same run on its twin that brings the inputs over the parent's way."""
+    from deepspeed_tpu.inference.v2 import programs as programs_mod
     from deepspeed_tpu.inference.v2.programs import build_unpack
     from deepspeed_tpu.observability.trace import tracer
 
     name = request.param
     build = _one_copy_engine(name)
-    seen = {"copies": [], "staged": [], "explicit": [], "bufs": []}
+    seen = {"copies": [], "staged": [], "ahead": [], "explicit": [],
+            "bufs": []}
 
     def watch(eng):
         # from a step's start to the call of its program: implicit copies
         # (a NumPy array handed to a jitted program) are refused, explicit
         # ones (jax.device_put, jnp.asarray, jnp.array) counted, and the one
         # copy function allowed its one; the copy a step makes for the NEXT
-        # decode step once its own work is done (``_stage_next``) is counted
-        # apart
+        # decode step, be it once its own work is done (``_stage_next``) or
+        # with that step's program, ahead of its own fetch (``out``: the
+        # tokens it has not fetched yet), is counted apart
         to_device, impl, stage = (eng._to_device, eng._step_impl,
                                   eng._stage_next)
         programs = {"_fwd": eng._fwd, "_decode_fwd": eng._decode_fwd}
@@ -1082,11 +1127,12 @@ def one_copy_run(request, devices):
                 guard[0].__exit__(None, None, None)
                 guard[0] = None
 
-        def counted(layout, buf):
+        def counted(layout, buf, out=None):
             seen["bufs"].append((type(buf), buf.dtype, buf.nbytes))
-            seen["staged" if seen.get("staging") else "copies"][-1] += 1
+            seen["staged" if seen.get("staging") else
+                 "copies" if out is None else "ahead"][-1] += 1
             with jax.transfer_guard_host_to_device("allow"):
-                return to_device(layout, buf)
+                return to_device(layout, buf, out)
 
         def stage_next(*args):
             seen["staging"] = True
@@ -1098,6 +1144,7 @@ def one_copy_run(request, devices):
         def step_impl(*args):
             seen["copies"].append(0)
             seen["staged"].append(0)
+            seen["ahead"].append(0)
             seen["explicit"].append(0)
             guard[0] = jax.transfer_guard_host_to_device("disallow")
             guard[0].__enter__()
@@ -1135,19 +1182,26 @@ def one_copy_run(request, devices):
     thread = threading.current_thread().name
     try:
         eng = build()
-        steps, keys = _drive(eng, name == "adapters", watch)
+        # this run's own unpack programs: an engine of another model with the
+        # same layout (plain / state_slots) shares them by the memo, and its
+        # decode program's output has another shape
+        for layout in (eng._decode_layout, eng.builder.layout):
+            programs_mod._BUILD_CACHE.pop(("unpack", layout), None)
+        steps, keys, temps, late = _drive(eng, name == "adapters", watch)
     finally:
         for (mod, fn), real in explicit.items():
             setattr(mod, fn, real)
     spans = [s for s in tracer.spans()
              if s.thread == thread and s.name == "engine/step"]
-    ref_steps, ref_keys = _drive(_parents_argument_path(build()),
-                                 name == "adapters")
+    ref_steps, ref_keys, _, _ = _drive(_parents_argument_path(build()),
+                                       name == "adapters", temps=temps,
+                                       late=late)
     sizes = {step: build_unpack(layout)._cache_size()
              for step, layout in (("decode", eng._decode_layout),
                                   ("mixed", eng.builder.layout))}
 
     return dict(name=name, eng=eng, steps=steps, keys=keys, spans=spans,
+                late=late,
                 ref_steps=ref_steps, ref_keys=ref_keys, seen=seen,
                 unpack_programs=sizes)
 
@@ -1172,21 +1226,32 @@ def test_a_step_makes_one_host_to_device_copy(one_copy_run):
     for s in spans:
         assert s.attrs["h2d_copies"] == 1
         assert s.attrs["h2d_bytes"] == nbytes[s.attrs["kind"]]
-    # a step that runs on the staged copy makes none before its program
+    # a step that runs on the staged copy makes none before its program,
+    # nor does one that found its program under way (ISSUE 50): that one's
+    # copy was made in the step before, with its program, ahead of that
+    # step's own fetch
     used = [s.attrs.get("staged") == "used" for s in run["spans"]]
-    assert run["seen"]["copies"] == [int(on and not u)
-                                     for on, u in zip(ran, used)]
+    found = [s.attrs.get("staged") == "ahead" for s in run["spans"]]
+    assert run["seen"]["copies"] == [int(on and not u and not f)
+                                     for on, u, f in zip(ran, used, found)]
     assert used.count(True) >= 5 and not used[0]
+    assert found.count(True) >= 10 and not found[0]
     assert all(run["seen"]["staged"][i - 1] == 1
                for i, u in enumerate(used) if u)
+    assert run["seen"]["ahead"] == found[1:] + [False]
+    assert run["seen"]["ahead"] == [s.attrs.get("ahead_next", 0)
+                                    for s in run["spans"]]
     assert set(run["seen"]["staged"]) == {0, 1}
+    assert not any(a and st for a, st in zip(run["seen"]["ahead"],
+                                             run["seen"]["staged"]))
     assert run["seen"]["explicit"] == [0] * len(ran)
     # the buffers in the order they were copied: a step's own, then the one
-    # it staged
+    # of the step it dispatched ahead or the one it staged
     order = []
-    for s, own, staged in zip(run["spans"], run["seen"]["copies"],
-                              run["seen"]["staged"]):
-        order += [s.attrs["kind"]] * own + ["decode"] * staged
+    for s, own, ahead, staged in zip(run["spans"], run["seen"]["copies"],
+                                     run["seen"]["ahead"],
+                                     run["seen"]["staged"]):
+        order += [s.attrs["kind"]] * own + ["decode"] * (ahead + staged)
     assert len(order) == len(run["seen"]["bufs"])
     assert all(b == (np.ndarray, np.dtype(np.int32), nbytes[k])
                for b, k in zip(run["seen"]["bufs"], order))
@@ -1198,6 +1263,7 @@ def test_tokens_and_step_keys_are_the_parents(one_copy_run):
     over the parent's way, and the steps' keys are the same keys in the same
     order: the split moved to the device, the stream did not."""
     run = one_copy_run
+    assert run["late"]  # some arrival found a step dispatched ahead
     assert len(run["steps"]) == len(run["ref_steps"])
     assert run["steps"] == run["ref_steps"]
     # a key a step that ran the device
@@ -1216,9 +1282,11 @@ def test_tokens_and_step_keys_are_the_parents(one_copy_run):
 
 def test_the_unpack_programs_compile_once(one_copy_run):
     """Fifty steps and more with rows coming and going: one compiled unpack
-    program for the decode steps and one for the mixed steps."""
+    program for the decode steps and one for the mixed steps, and a second
+    shape of the decode steps' for those dispatched behind a program under
+    way (which takes that program's tokens beside the buffer)."""
     assert len(one_copy_run["keys"]) >= 50
-    assert one_copy_run["unpack_programs"] == {"decode": 1, "mixed": 1}
+    assert one_copy_run["unpack_programs"] == {"decode": 2, "mixed": 1}
 
 
 def test_a_callers_key_is_used_as_it_is(devices, tiny_model):

@@ -50,7 +50,11 @@ def _build(name):
 def _watch(eng, fed):
     """Hold what feeds every decode step's program to a fresh pack of the
     table at the moment of the call: a staged buffer that differs from it
-    must never be the one that is used."""
+    must never be the one that is used.  A step dispatched ahead (ISSUE 50:
+    every call of a ``step`` but the first of a ``step`` that found no
+    program under way, ``fed["own"]``) is called before its token ids reach
+    the host: they are kept in ``fed["ahead"]`` and held, once ``step`` has
+    returned, to what the table reads then (``_drive``)."""
     decode_fwd = eng._decode_fwd
     names = [f[0] for f in eng._decode_layout.fields]
 
@@ -67,11 +71,14 @@ def _watch(eng, fed):
         if adapter:
             got["row_adapter"] = adapter[1]
         assert sorted(got) == sorted(names)
+        if not fed["own"]:
+            fed["ahead"] = np.asarray(got.pop("token_ids"))
+        fed["own"] = False
         for name, arr in got.items():
             np.testing.assert_array_equal(
                 np.asarray(arr).view(np.int32), want[name].view(np.int32),
                 err_msg=name)
-        fed.append(1)
+        fed["n"] += 1
         return decode_fwd(params, caches, tok, pos, tables, ctx, temps, rng,
                           seeds, *adapter)
 
@@ -93,13 +100,13 @@ def _drive(build, seed, adapters, staging):
     eng = build()
     if staging is None:  # nothing is ever staged: the order before ISSUE 38
         eng._stage_next = lambda temperature, sub: None
-    fed = []
+    fed = {"n": 0, "own": True, "ahead": None}
     _watch(eng, fed)
     rs = np.random.default_rng(seed)
     served, live = [], []
     eng.step_temperature = 0.0
     n = 0
-    while n < _STEPS or eng.running or eng.waiting:
+    while n < _STEPS or eng.running or eng.waiting or eng._ahead is not None:
         draw = rs.random(4)
         if n < _STEPS and draw[0] < (0.9 if not live else 0.22) \
                 and len(live) < _V2["max_seqs"]:
@@ -115,7 +122,13 @@ def _drive(build, seed, adapters, staging):
             eng.step_temperature = [0.0, 0.7, 1.1][int(rs.integers(3))]
         if not staging:
             eng._staged = None
+        fed["own"], fed["ahead"] = eng._ahead is None, None
         out = eng.step(temperature=eng.step_temperature)
+        # a program went ahead exactly where one is under way now, and ran on
+        # the tokens this step has just recorded
+        assert (fed["ahead"] is None) == (eng._ahead is None)
+        if fed["ahead"] is not None:
+            np.testing.assert_array_equal(fed["ahead"], eng.table.next_tok)
         served.append(out)
         for uid, toks in out.items():
             if toks[-1] % 11 == 0 and uid in eng.running:  # a stop token
@@ -124,7 +137,7 @@ def _drive(build, seed, adapters, staging):
                 or any(s.uid == u for s in eng.waiting)]
         n += 1
     assert all(m.drained() for m in eng._managers)
-    return eng, served, len(fed)
+    return eng, served, fed["n"]
 
 
 @pytest.mark.parametrize("seed", [11, 12, 13])
@@ -139,8 +152,17 @@ def test_staged_serves_what_fresh_serves(devices, name, seed):
     decode = [a for a in steps if a["kind"] == "decode"]
     assert fed == fed_fresh == len(decode) >= 20
     use = [a["staged"] for a in decode]
-    # the interleaving reaches both paths, and both ways of losing a staging
-    assert use.count("used") >= 8 and use.count("fresh") >= 4
+    # the interleaving reaches all three paths, and both ways of losing a
+    # staging
+    assert (use.count("used") >= 7 and use.count("fresh") >= 2
+            and use.count("ahead") >= 30)
+    # (ISSUE 50) every program dispatched ahead is the next call's step, and
+    # the cancels and stop tokens between two calls dropped tokens of some
+    assert [a["ahead"] for a in decode[1:]] == [
+        a["ahead_next"] for a in decode[:-1]]
+    assert all((a["staged"] == "ahead") == a["ahead"] for a in decode)
+    assert sum(a["ahead_dropped"] for a in decode) >= 2
+    assert not any(a["ahead_dropped"] for a in decode if not a["ahead"])
     dropped = [a for a in steps if a.get("stage_discarded")]
     assert {a["kind"] for a in dropped} == {"decode", "mixed"}
     size = staging._decode_layout.size * 4
