@@ -153,6 +153,22 @@ class Sizes:
     # the tapped rows of all eight heads against the reference (median,
     # worst: the serving cell's bounds)
     eva_logit_tol: Tuple[float, float] = (0.12, 0.3)
+    # linear_latent_moe_server: the leading dense layer and one whole period
+    # of Kimi-Linear (K K A, then K K K A) at published widths, 32 of 256
+    # experts held, an eighth of the vocabulary; a prompt chunked three times
+    # off the chunk's edge, short rows beside it, a second batch through the
+    # same slots
+    linear_preset: str = "kimi-linear-48b"
+    linear_pattern: str = "KKAKKKA"
+    linear_held: int = 32
+    linear_vocab: int = 20480
+    linear_blocks: int = 8 * 32 + 1
+    linear_max_blocks_per_seq: int = 32
+    linear_requests: Tuple[Tuple[int, int], ...] = (
+        (1300, 12), (300, 16), (70, 10), (5, 8))
+    linear_second: Tuple[Tuple[int, int], ...] = ((600, 6), (40, 9))
+    linear_logit_tol: Tuple[float, float] = (0.15, 0.4)
+    linear_state_tol: Tuple[float, float] = (0.02, 0.2)
     # -- four chips: ZeRO-3 shards 14 B a parameter over four chips, beside
     # the caller's unsharded copy on chip 0
     zero3_layers: int = 8
@@ -1499,6 +1515,114 @@ def phase_zero3(sz: Sizes, seed: int) -> None:
 # ---------------------------------------------------------------------------
 
 
+def phase_linear_latent_moe_server(sz: Sizes, seed: int,
+                                   check_kernels: bool = True) -> None:
+    """A model of KDA layers beside latent attention without an indexer, a
+    leading dense layer and a SHARE of its routed experts (a cut of
+    Kimi-Linear-48B-A3B at published widths, int8 at group 128) through
+    ``InferenceEngineV2``: state slots of a delta-rule matrix state beside a
+    latent pool read whole; a prompt chunked three times with decode rows
+    riding in its mixed steps, then a second batch through the same slots and
+    blocks, then the first batch once more with the logits and the expert
+    choices of the engine's own step programs tapped; the KDA decode update
+    and both latent paths ran their kernels, none fallen back; every block
+    and slot free after each drain.  Against the plain reference
+    (``benchmark/reference/linear_latent_moe_decoder.py``) over the same
+    codes, as the serving cell compares: the tapped rows against the
+    reference HELD TO THE PROGRAM'S EXPERTS, every KDA layer's state of the
+    tapped sequences' slots against the reference's, and the router directly
+    (``serve_linear_latent_moe.check_logits``, ``check_router``)."""
+    from unittest import mock
+
+    from benchmark.drivers import serve_latent_moe
+    from benchmark.drivers import serve_linear_latent_moe as drv
+    from benchmark.reference import linear_latent_moe_decoder as reference
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-linear-latent-moe-int8"
+    n = len(sz.linear_pattern)
+    cfg = tfm.get_config(
+        sz.linear_preset, num_layers=n, vocab_size=sz.linear_vocab,
+        moe_experts_held=sz.linear_held, kda_pattern=tuple(sz.linear_pattern),
+        mlp_layer_types=("dense",) + ("sparse",) * (n - 1),
+        dtype="bfloat16", param_dtype="bfloat16")
+    log(phase, preset=sz.linear_preset, pattern=sz.linear_pattern,
+        held=cfg.experts_held, experts=cfg.num_experts, top=cfg.moe_top_k,
+        params_m=round(cfg.num_params() / 1e6, 1))
+    params = drv.make_params(cfg, seed, 8, 128)
+    tracer.clear()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.linear_blocks,
+        max_blocks_per_seq=sz.linear_max_blocks_per_seq))
+    rng = np.random.default_rng([seed, 51])
+    for batch in (sz.linear_requests, sz.linear_second):
+        prompts = [rng.integers(1, cfg.vocab_size, size=m).tolist()
+                   for m, _ in batch]
+        uids = [engine.put(p, max_new_tokens=m)
+                for p, (_, m) in zip(prompts, batch)]
+        whole = engine.generate_all(burst=1)
+        for p, u, (_, m) in zip(prompts, uids, batch):
+            if len(whole[u]) - len(p) != m:
+                raise AssertionError(f"{phase}: asked {m} tokens, got "
+                                     f"{len(whole[u]) - len(p)}")
+        engine.kv.check_consistency()
+        if not engine.drained():
+            raise AssertionError(
+                f"{phase}: {engine.free_blocks} of {engine.total_blocks} "
+                f"blocks and {engine.free_state_slots} of "
+                f"{engine.total_state_slots} slots free after the drain")
+    check = {"logit_prompts": [m for m, _ in sz.linear_requests[:3]],
+             "logit_tokens": 12, "logit_pad": 256,
+             "logit_tol_median": sz.linear_logit_tol[0],
+             "logit_tol": sz.linear_logit_tol[1],
+             "state_tol": sz.linear_state_tol[0],
+             "state_tol_deep": sz.linear_state_tol[1],
+             "state_low_bits_min": 0.5, "kda_tol": 1e-3, "agree_min": 0.5,
+             "router_tol": 1e-4}
+    tapped = drv.tap_logits(engine, cfg, seed, check)
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"
+             and "kda_tokens" in s.attrs]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    local = sum(a.get("moe_assignments_local") or 0 for a in steps)
+    made = sum(a["moe_assignments"] for a in steps)
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        kda_tokens=sum(a["kda_tokens"] for a in steps),
+        latent_keys_read=sum(a["latent_keys_read"] for a in steps),
+        local_share=round(local / max(made, 1), 4),
+        arrays={k: list(v.shape) for k, v in engine.caches.items()})
+    for name in ("kernel/grouped_mixed_gemm_tiles", "kernel/kda_decode_update",
+                 "kernel/kda_chunk_scan_tiles",
+                 "kernel/latent_attention_decode_full_tiles",
+                 "kernel/latent_attention_prefill_tiles"):
+        seen = {tuple(sorted(a.items())) for m, a in events if m == name}
+        if not seen:
+            raise AssertionError(f"{phase}: no {name} event")
+        for attrs in sorted(seen):
+            log(phase, event=name, **dict(attrs))
+    fell = [e for e in events if e[1].get("fallback")]
+    if fell and check_kernels:
+        raise AssertionError(f"{phase}: a kernel fell back to XLA: {fell}")
+    if check_kernels:
+        require_kernel(phase, "decode_step", engine._decode_fwd.lower(
+            *_decode_shapes(engine)).compile().as_text())
+    memory_line(phase, jax.local_devices()[0])
+    del engine
+    gc.collect()
+    model = drv.published_model(cfg)
+    got = drv.check_logits(params, model, tapped, check,
+                           lambda m: log(phase, check=m))
+    with mock.patch.object(serve_latent_moe, "reference", reference):
+        routed = serve_latent_moe.check_router(
+            params, model, cfg, tapped, check, lambda m: log(phase, check=m))
+    if not (got["ok"] and routed["ok"]):
+        raise AssertionError(f"{phase}: the step programs' logits, state or "
+                             f"router differ from the reference: {got} "
+                             f"{routed}")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
@@ -1548,6 +1672,8 @@ def main() -> int:
         phase_latent_moe_server(sz, args.seed)
         gc.collect()
         phase_eva_server(sz, args.seed)
+        gc.collect()
+        phase_linear_latent_moe_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -46,8 +46,13 @@ _QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate",
                          # einsums no GEMM kernel computes), ``w_kr`` (64
                          # columns) and ``w_iw`` (32 columns)
                          "sh_w_gate", "w_qa", "w_qb", "w_kva", "w_iq",
-                         "w_ik"})
-_QUANT_PARENTS = frozenset({"attn", "mlp", "moe", "mamba", "index"})
+                         "w_ik",
+                         # latent attention without query compression; a KDA
+                         # layer's q, k and v in one (models/kimi_linear.py;
+                         # its low-rank gate maps, ``w_beta`` and the conv
+                         # stay bf16)
+                         "w_q", "w_qkv"})
+_QUANT_PARENTS = frozenset({"attn", "mlp", "moe", "mamba", "index", "kda"})
 
 
 def pad_expert_width(w: jax.Array, key: str) -> jax.Array:

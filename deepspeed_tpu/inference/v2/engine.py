@@ -249,8 +249,9 @@ class InferenceEngineV2:
         # global layers keep a pool each: ``kv`` holds the global layers'
         # blocks and ``kv_win`` the window layers', which go back to their
         # pool as they fall behind the window (ragged.KVCacheManager); where
-        # every layer is windowed the one pool is.  ``ssm``: per-SEQUENCE
-        # state beside the paged K/V, a slot a sequence from the one manager.
+        # every layer is windowed the one pool is.  The kind's ``state``:
+        # per-SEQUENCE arrays beside the paged pool, a slot a sequence from
+        # the one manager.
         # A latent model's two pools share the ONE block table and allocator.
         # An EVA model's: ``kv`` holds the summaries' blocks, which grow by a
         # window's worth whenever one closes, and ``kv_win`` the current
@@ -258,7 +259,7 @@ class InferenceEngineV2:
         self.kind = kind_of(self.model_cfg)
         arrays = self.kind.arrays(self.model_cfg, self.cfg)
         two_pools = "k_win" in arrays
-        state_slots = self.cfg.max_seqs if "ssm" in arrays else 0
+        state_slots = self.cfg.max_seqs if self.kind.state else 0
         self._window = self.kind.window(self.model_cfg, self.cfg)  # 0: none
         # a window that TUMBLES (freed whole when it closes) leaves this many
         # entries in the main pool (its summaries), which then holds those
@@ -363,9 +364,9 @@ class InferenceEngineV2:
         self._ahead_flags = (0, 0, 0)
         self.caches = {name: jnp.zeros(shape, dtype)
                        for name, (shape, dtype) in arrays.items()}
-        # SSM state bytes a row reads and writes a step, all state layers
+        # state bytes a row reads and writes a step, all state layers
         self._state_row_bytes = (
-            2 * self.caches["ssm"].nbytes // (state_slots + 1)
+            2 * self.caches[self.kind.state[0]].nbytes // (state_slots + 1)
             if state_slots else 0)
         # what the kind counts of a step, and the step's under way
         self._count = getattr(self, self.kind.counters)
@@ -1312,6 +1313,50 @@ class InferenceEngineV2:
         if mixed:
             counts["attn_q_slots"] = int(
                 (-(-n[~one] // TILE_Q) * TILE_Q).sum() + one.sum())
+        return counts
+
+    def _count_linear_latent(self, start: "np.ndarray", n: "np.ndarray",
+                             mixed: bool) -> Dict[str, Any]:
+        """A step of a model of KDA layers beside latent attention.  Over
+        rows: the slots taken, the rows that began from zeros, the tokens
+        through the KDA layers and the state bytes read and written (all KDA
+        layers); summed over rows and latent layers: the keys its queries
+        have to READ (a row's whole context once, by the path that attends:
+        ``_single`` the rows of one token, ``_prefill`` the rows of two and
+        more) and the (query, key) pairs it multiplies; the blocks the
+        latent pool has out; the expert assignments the routed layers make
+        (the local ones come back with the step: ``_split_stats``); of a
+        mixed step what the chunked form walks (the rows of two tokens and
+        more, their tokens, their pieces of a chunk) and the query slots of
+        the prefill tiles."""
+        c = self.model_cfg
+        start, n = _live_rows(start, n)
+        La = self.caches["latent"].shape[0]
+        one = n == 1
+        read = start + n  # a row's keys up to its newest token
+        # a query at p sees p + 1 keys: n queries from start on
+        pairs = n * (start + 1) + n * (n - 1) // 2
+        counts = {
+            "state_slots_used": self.total_state_slots
+            - self.free_state_slots,
+            "state_rows_started": int((start == 0).sum()),
+            "kda_tokens": int(n.sum()),
+            "kda_state_bytes": len(n) * self._state_row_bytes,
+            "latent_keys_read": int(read.sum()) * La,
+            "latent_keys_single": int(read[one].sum()) * La,
+            "latent_keys_prefill": int(read[~one].sum()) * La,
+            "latent_query_keys": int(pairs.sum()) * La,
+            "blocks_used_latent": self.total_blocks - self.free_blocks,
+            "moe_assignments": int(n.sum()) * c.moe_top_k * self._moe_layers}
+        if self._moe_share:
+            counts["moe_assignments_local"] = None  # behind the step's tokens
+        if mixed:
+            many = n[~one]
+            counts.update(
+                kda_scan_rows=len(many), kda_scan_tokens=int(many.sum()),
+                kda_scan_pieces=int((-(-many // c.kda_chunk_size)).sum()),
+                attn_q_slots=int((-(-many // TILE_Q) * TILE_Q).sum()
+                                 + one.sum()))
         return counts
 
     def _count_eva(self, start: "np.ndarray", n: "np.ndarray", mixed: bool

@@ -3,8 +3,8 @@
 What KIND of model is served is decided once (``kind_of`` → ``ServedKind``:
 what it caches, how its layers run, what a step's rows tell them, what it
 is refused, what its step counts).  ``serving_layers`` is the kind's body
-(``attention_layers``, ``hybrid_layers``, ``latent_layers``, ``eva_layers``)
-under three callers: the mixed step (``build_ragged_forward``: chunks of prefill and
+(``attention_layers``, ``hybrid_layers``, ``latent_layers``, ``eva_layers``,
+``linear_latent_layers``) under three callers: the mixed step (``build_ragged_forward``: chunks of prefill and
 decode tokens in one ragged batch), the decode step (``_decode_body``: one
 token a row) and the verify step of speculation (``spec.py:verify_body``:
 ``Q`` positions a row); its docstring is the contract of a caller.  The
@@ -273,8 +273,8 @@ def pools_of(caches) -> list:
     """``caches`` as one (K, V) pair a pool (a latent model: its one pool of
     latents and the indexer's keys, which share the block table; an EVA
     model: the summaries', then the window's), in the tables' order."""
-    if "latent" in caches:
-        return [(caches["latent"], caches["index"])]
+    if "latent" in caches:  # (a model without an indexer: the latents alone)
+        return [(caches["latent"], caches.get("index"))]
     if "k_sum" in caches:
         return [(caches["k_sum"], caches["v_sum"]),
                 (caches["k_win"], caches["v_win"])]
@@ -372,6 +372,13 @@ def _mamba_decode(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
     return ssm_hybrid.mamba_out(y[:R], z, p, cfg), ssm, conv
 
 
+def _by_slot(a, at, slots: int, fill=0):
+    """``a (R, ...)`` laid out a state slot: row ``r`` at ``at[r]`` (past
+    the end: dropped), ``fill`` elsewhere."""
+    return jnp.full((slots,) + a.shape[1:], fill, a.dtype
+                    ).at[at].set(a, mode="drop")
+
+
 def _mamba_mixed(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
     """A Mamba-2 layer on a mixed step's flat rows: the rows of two tokens
     and more through the chunked scan, each from its slot's state; the rows
@@ -386,9 +393,8 @@ def _mamba_mixed(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
         at = jnp.where(one, rows.slots, S1)  # past the end: dropped
         first = jnp.clip(rows.row_start, 0, x.shape[0] - 1)
 
-        def by_slot(a, fill=0):
-            return jnp.full((S1,) + a.shape[1:], fill, a.dtype
-                            ).at[at].set(a, mode="drop")
+        def by_slot(a):
+            return _by_slot(a, at, S1)
 
         y1, ssm = ssm_decode_update(
             ssm, layer, by_slot(x[first]), by_slot(dt[first]), A,
@@ -519,6 +525,22 @@ def hybrid_layers(params, caches, x, positions, write_at, attend,
     x = tfm._norm(x, params["final_norm"], "rmsnorm", model_cfg.norm_eps)
     return (x, {"k": k_cache, "v": v_cache, "ssm": ssm, "conv": conv},
             jnp.concatenate(moe_stats) if moe_stats else None)
+
+
+def _share_step_stats(moe_stats, taps=()):
+    """The step's int32 stats of a model whose routed layers hold a SHARE of
+    their experts, from ``walk_pattern``'s per-run ``(layers, 3 + ...)``:
+    held experts hit summed over the routed layers, the largest rows of one,
+    the assignments that were local; behind them what a tapped config asks
+    for (the rows' chosen experts, then ``taps``).  None without a routed
+    layer."""
+    if not moe_stats:
+        return None
+    per_layer = jnp.concatenate(moe_stats)
+    return jnp.concatenate(
+        [jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max(),
+                    per_layer[:, 2].sum()]),
+         per_layer[:, 3:].reshape(-1)] + [t.reshape(-1) for t in taps])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -671,15 +693,193 @@ def latent_layers(params, caches, x, positions, write_at, attend,
         (x, caches["latent"], caches["index"], sel))
     x, latent, index, _ = carry
     x = tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
-    stats = None
-    if moe_stats:
-        per_layer = jnp.concatenate(moe_stats)
-        stats = jnp.concatenate(
-            [jnp.stack([per_layer[:, 0].sum(), per_layer[:, 1].max(),
-                        per_layer[:, 2].sum()]),
-             per_layer[:, 3:].reshape(-1)]
-            + [t.reshape(-1) for t in taps])
-    return x, {"latent": latent, "index": index}, stats
+    return (x, {"latent": latent, "index": index},
+            _share_step_stats(moe_stats, taps))
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearLatentRows:
+    """What a model of KDA layers beside latent attention has to know of a
+    step's rows: what its state layers need (``StepRows``) and what its
+    latent layers need (``LatentRows``), of the same step."""
+    state: StepRows
+    latent: LatentRows
+
+
+def linear_latent_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
+    """What a model of KDA layers beside latent attention caches: ``latent
+    (L_A, blocks, block, W)``, a token's latent and unrotated shared key in
+    the latent layers alone (``latent_attention.pool_width``), which grows
+    with the context through the one block table; and beside it the
+    per-sequence state of the KDA layers: ``kda (L_K, slots + 1, H, d_k,
+    d_v)`` float32 and the short conv's kept inputs ``conv (L_K, slots + 1,
+    K - 1, 3 x H x d_k)`` in the activation dtype (q, k and v side by side,
+    columns on the lanes).  A slot a row of the engine's table, and one
+    scratch slot."""
+    from ...models import kimi_linear
+
+    c, dt = model_cfg, jnp.dtype(v2.dtype)
+    width = latent_attention.pool_width(c.kv_lora_rank, c.qk_rope_head_dim)
+    Lk, La = (kimi_linear.layers_of(c, k) for k in "KA")
+    H, dk = c.kda_num_heads, c.kda_head_dim
+    return {"latent": ((La, v2.num_blocks, v2.block_size, width), dt),
+            "kda": ((Lk, v2.max_seqs + 1, H, dk, dk), jnp.float32),
+            "conv": ((Lk, v2.max_seqs + 1, c.kda_conv_kernel - 1,
+                      3 * H * dk), dt)}
+
+
+def linear_latent_rows(tables, start, n, flat=None) -> LinearLatentRows:
+    """``LinearLatentRows`` of a step: ``state_rows``' arguments."""
+    return LinearLatentRows(state_rows(tables, start, n, flat),
+                            latent_rows(tables, start, n, flat))
+
+
+def _kda_decode(a_in, p, cfg, kda, conv, layer, rows: StepRows):
+    """A KDA layer on one token a slot: the conv over the slot's kept
+    columns and the new one, one delta-rule step, both states in place."""
+    from ...models import kimi_linear
+    from ...ops.pallas.kda import kda_decode_update
+
+    R = a_in.shape[0]
+    qkv = kimi_linear.kda_in_proj(a_in, p)
+    with jax.named_scope("kda_conv"):
+        held = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)[:R]
+        cols = jnp.where(rows.fresh[:, None, None], 0, held)
+        out = ssm_hybrid.conv_taps(
+            [cols[:, j] for j in range(cols.shape[1])] + [qkv], p)
+        new = jnp.concatenate([cols[:, 1:], qkv[:, None]], axis=1)
+        conv = conv.at[layer, :R].set(
+            jnp.where(rows.active[:, None, None], new, held))
+    inputs = kimi_linear.kda_inputs(out, a_in, p, cfg)
+
+    def pad(a):  # the scratch slot takes no step
+        return jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))
+
+    o, kda = kda_decode_update(kda, layer, *(pad(a) for a in inputs),
+                               pad(rows.active), pad(rows.fresh))
+    return kimi_linear.kda_out(o[:R], a_in, p, cfg), kda, conv
+
+
+def _kda_mixed(a_in, p, cfg, kda, conv, layer, rows: StepRows):
+    """A KDA layer on a mixed step's flat rows: the rows of two tokens and
+    more through the chunked form, each from its slot's state; the rows of
+    one token (the decode rows) in one dense pass over the slots."""
+    from ...models import kimi_linear
+    from ...ops.pallas.kda import kda_chunk_scan, kda_decode_update
+
+    S1, T = kda.shape[1], a_in.shape[0]
+    qkv = kimi_linear.kda_in_proj(a_in, p)
+    with jax.named_scope("kda_conv"):
+        kept = jnp.where(rows.fresh[:, None, None], 0,
+                         conv[layer, rows.slots])
+        qkv, kept = ssm_hybrid.conv_ragged(qkv, kept, p, rows.row,
+                                           rows.offset, rows.row_start,
+                                           rows.row_len)
+    inputs = kimi_linear.kda_inputs(qkv, a_in, p, cfg)
+    o, kda = kda_chunk_scan(kda, layer, *inputs, rows.row_start,
+                            rows.row_len, rows.slots, rows.fresh,
+                            rows.row_len >= 2, cfg.kda_chunk_size)
+    one = rows.row_len == 1
+    at = jnp.where(one, rows.slots, S1)  # past the end: dropped
+    first = jnp.clip(rows.row_start, 0, T - 1)
+    o1, kda = kda_decode_update(
+        kda, layer, *(_by_slot(a[first], at, S1) for a in inputs),
+        _by_slot(one, at, S1), _by_slot(rows.fresh, at, S1))
+    o = jnp.where((one[rows.row] & rows.valid)[:, None, None],
+                  o1[rows.slots[rows.row]], o)
+    with jax.named_scope("kda_conv"):
+        conv = conv.at[layer, rows.slots].set(kept)
+    return kimi_linear.kda_out(o, a_in, p, cfg), kda, conv
+
+
+def linear_latent_layers(params, caches, x, positions, write_at, attend,
+                         model_cfg: tfm.TransformerConfig, v2, adapters=None,
+                         slots=None, valid=None,
+                         rows: LinearLatentRows = None):
+    """``serving_layers`` for a model of KDA layers beside latent attention
+    (``models/kimi_linear.py``): a mixer and an FFN a layer, in pattern
+    order (``walk_pattern``; each kind's parameters its own stack).  It takes
+    no ``attend``: a latent layer's rows of one token read their WHOLE
+    context through the table (``latent_decode_attention_full``), its rows of
+    two and more the prefill kernel under a causal selection; nothing is
+    rotated.  The latent pool (the latent layers only) and both state arrays
+    ride the carry whole and are updated in place.
+
+    → (hidden state after the final norm, the arrays, int32 stats of the
+    step as ``latent_layers`` gives them)."""
+    from ...models import kimi_linear
+
+    cfg, la, ls = model_cfg, latent_attention, latent_sparse
+    if rows is None:
+        raise ValueError("a model of KDA layers beside latent attention is "
+                         "served by the mixed and decode steps only")
+    blk_ids, offsets = write_at
+    st, lr = rows.state, rows.latent
+    mixed = lr.q_start is not None
+    T = x.shape[0]
+    W = caches["latent"].shape[-1]
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    scale = ls.softmax_scale(cfg)
+    of = stacked_layers(params["layers"])
+    kda_layer = _kda_mixed if mixed else _kda_decode
+    # the rows of one token read ctx keys: their own, just written, the last
+    ctx = jnp.where(lr.single, lr.positions + 1, 0).astype(jnp.int32)
+    if mixed:
+        tiles = la.prefill_tiles(lr.chunk_len, T)
+        # THE SELECTION of a model without an indexer: every earlier key,
+        # made once a step for all the latent layers
+        S = lr.tables.shape[1] * v2.block_size
+        causal = jnp.arange(S)[None, :] <= positions[:, None]
+
+    def per_row(a):  # the single rows' tokens out of the flat batch
+        return a[jnp.clip(lr.q_start, 0, T - 1)] if mixed else a
+
+    def one_layer(letter, idx, carry):
+        x, latent, kda, conv = carry
+        if letter in "Kk":
+            lp = of("K", idx["K"])
+            a_in = tfm._norm(x, lp["ln1"], "rmsnorm", cfg.norm_eps)
+            out, kda, conv = kda_layer(a_in, lp["kda"], cfg, kda, conv,
+                                       idx["K"], st)
+        else:
+            lp = of("A", idx["A"])
+            p = lp["attn"]
+            a_in = tfm._norm(x, lp["ln1"], "rmsnorm", cfg.norm_eps)
+            _, q_nope, q_rope = ls.queries(a_in, p, cfg, None, positions)
+            entry = ls.cache_entry(a_in, p, cfg, None, positions, W)
+            with jax.named_scope("cache_write"):
+                latent = latent.at[idx["A"], blk_ids[0], offsets].set(
+                    entry.astype(latent.dtype))
+            q_lat = ls.absorb(q_nope, q_rope, p["w_kvb"], W)
+            o = la.latent_decode_attention_full(
+                per_row(q_lat), latent, idx["A"], lr.tables, ctx,
+                scale=scale, latent=rkv)
+            if mixed:
+                o = la.latent_prefill_attention(
+                    q_lat, latent, idx["A"], lr.tables, causal, tiles,
+                    lr.q_start, lr.chunk_start, scale=scale, latent=rkv
+                ).at[jnp.where(lr.single, lr.q_start, T)].set(o, mode="drop")
+            out = tfm._lin(ls.unabsorb(o, p["w_kvb"], dn, x.dtype), p, "wo",
+                           "bo")
+        x = x + out
+        stats = None
+        if letter.islower():
+            fp = of("D", idx["D"])
+            m_in = tfm._norm(x, fp["ln2"], "rmsnorm", cfg.norm_eps)
+            out = tfm._mlp_block(m_in[None], fp["mlp"], cfg)[0]
+        else:
+            fp = of("S", idx["S"])
+            m_in = tfm._norm(x, fp["ln2"], "rmsnorm", cfg.norm_eps)
+            out, stats = serving_moe_block(m_in, fp["moe"], cfg, valid=valid)
+        return (x + out, latent, kda, conv), (stats,)
+
+    carry, (moe_stats,) = walk_pattern(
+        kimi_linear.pattern(cfg), kimi_linear.stacks_of, one_layer,
+        (x, caches["latent"], caches["kda"], caches["conv"]))
+    x, latent, kda, conv = carry
+    x = tfm._norm(x, params["final_norm"], "rmsnorm", cfg.norm_eps)
+    return (x, {"latent": latent, "kda": kda, "conv": conv},
+            _share_step_stats(moe_stats))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -926,15 +1126,17 @@ def attention_layers(params, caches, x, positions, write_at, attend,
 class ServedKind:
     """One kind of served model and all that depends on which it is: the
     engines and the step programs ask IT, never the configuration's fields.
-    Four today: ``KV`` (attention + FFN over one or two paged K/V pools, a
+    Five today: ``KV`` (attention + FFN over one or two paged K/V pools, a
     window that slides), ``STATE`` (state slots beside paged K/V),
-    ``LATENT`` (a latent pool and an indexer's keys) and ``EVA`` (a window
+    ``LATENT`` (a latent pool and an indexer's keys), ``EVA`` (a window
     pool that tumbles and a pool of the closed windows' summaries, in every
-    layer)."""
+    layer) and ``LINEAR_LATENT`` (state slots of a delta-rule matrix state
+    beside a latent pool read whole, without an indexer)."""
     name: str  # as a refusal names the model
     #: ``(model_cfg, v2) -> {name: (shape, dtype)}``, every array of
     #: ``caches``: the engine allocates them as they come and reads its
-    #: managers' needs off them (``k_win``: a second pool, ``ssm``: slots)
+    #: managers' needs off them (``k_win``: a second pool; ``state`` below
+    #: names the ones that live in slots)
     arrays: Callable
     layers: Callable  # the body: ``serving_layers``' arguments and results
     #: ``(tables, start, n, flat=None) ->`` the body's ``rows`` argument
@@ -950,6 +1152,10 @@ class ServedKind:
     #: ``generate_all`` may decode several tokens in one program (a kind
     #: whose every step counts may not)
     bursts: bool = False
+    #: the arrays that hold per-SEQUENCE state, a slot a row of the engine's
+    #: table and one scratch slot (empty: the kind needs no slots); the first
+    #: is the one a step reads and writes whole (its counters' bytes)
+    state: tuple = ()
     #: ``(model_cfg, v2) ->`` the window whose blocks go back to their pool
     #: while the sequence runs (0: none is active)
     window: Callable = lambda c, v2: max(
@@ -963,6 +1169,8 @@ class ServedKind:
 def kind_of(model_cfg: tfm.TransformerConfig) -> ServedKind:
     """The kind of model ``model_cfg`` is: the one place under ``inference/``
     that reads the configuration to tell."""
+    if model_cfg.kda_pattern:
+        return LINEAR_LATENT
     if model_cfg.kv_lora_rank:
         return LATENT
     if model_cfg.eva_window:
@@ -1016,7 +1224,8 @@ STATE = ServedKind(
     refuses=lambda c, v2: tuple(REFUSED),
     because="a model that has state layers (mixer_pattern with 'M'): {does}, "
             "and a sequence's state at an earlier position is kept nowhere "
-            "(that would take a snapshot)")
+            "(that would take a snapshot)",
+    state=("ssm", "conv"))
 LATENT = ServedKind(
     name="a model with latent attention (kv_lora_rank > 0: a latent a "
          "token, a learned selection of keys)",
@@ -1040,6 +1249,30 @@ EVA = ServedKind(
             "unmade, and is no prefix's alone to reuse)",
     window=lambda c, v2: c.eva_window,
     closes=lambda c: c.eva_window // c.eva_chunk)
+
+
+def _routed_layers(model_cfg: tfm.TransformerConfig) -> int:
+    from ...models import kimi_linear
+
+    return kimi_linear.layers_of(model_cfg, "S")
+
+
+LINEAR_LATENT = ServedKind(
+    name="a model of KDA layers beside latent attention (kda_pattern: a "
+         "delta-rule matrix state a sequence in state slots, a latent a "
+         "token in a paged pool read whole)",
+    arrays=linear_latent_arrays, layers=linear_latent_layers,
+    step_rows=linear_latent_rows,
+    moe_layers=_routed_layers,
+    counters="_count_linear_latent",
+    refuses=lambda c, v2: tuple(REFUSED),
+    because="a model of KDA layers beside latent attention (kda_pattern), "
+            "which keeps a delta-rule matrix state a sequence in state "
+            "slots and a latent a token in a paged pool, and no K or V "
+            "heads: {does}, and a sequence's KDA state at an earlier "
+            "position is kept nowhere (that would take a snapshot), so no "
+            "block of the latent pool can start another sequence",
+    state=("kda", "conv"))
 
 
 def serving_layers(params, caches, x, positions, write_at, attend,

@@ -286,7 +286,10 @@ def rope_tables(cfg, max_len: int):
 
 
 def _rope_first(x, rope, positions, dims: int):
-    """RoPE in pairs on the first ``dims`` of ``x (..., heads, D)``."""
+    """RoPE in pairs on the first ``dims`` of ``x (..., heads, D)``
+    (``rope`` None: a model without positions, nothing is turned)."""
+    if rope is None:
+        return x
     return tfm.rope_at(x, rope[0][:, :dims // 2], rope[1][:, :dims // 2],
                        positions)
 

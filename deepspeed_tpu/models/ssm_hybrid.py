@@ -192,9 +192,9 @@ def mamba_in_proj(a, p):
 def conv_taps(taps, p):
     """``silu(sum_j cw[j] * taps[j] + cb)`` over the ``conv_kernel`` shifted
     copies ``taps`` of the conv's input (oldest first), in float32 →
-    the input's dtype."""
+    the input's dtype (a conv without a bias: ``p`` holds no ``conv_b``)."""
     w = p["conv_w"].astype(jnp.float32)
-    acc = p["conv_b"].astype(jnp.float32)
+    acc = p["conv_b"].astype(jnp.float32) if "conv_b" in p else 0.0
     for j, tap in enumerate(taps):
         acc = acc + w[j] * tap.astype(jnp.float32)
     return jax.nn.silu(acc).astype(taps[-1].dtype)

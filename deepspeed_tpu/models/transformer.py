@@ -207,6 +207,23 @@ class TransformerConfig:
     # summaries (``attn.eva_phi``, ``attn.eva_mu``)
     eva_window: int = 0
     eva_chunk: int = 0
+    # Kimi Delta Attention beside latent attention (models/kimi_linear.py),
+    # on when ``kda_pattern`` is set: the MIXER of each layer, "K" a KDA
+    # layer (``kda_num_heads`` heads with a ``kda_head_dim`` x
+    # ``kda_head_dim`` matrix state each, updated by a delta rule under a
+    # gate a channel; a depthwise causal conv of ``kda_conv_kernel`` taps
+    # over q, k and v; the gate and the output gate through low-rank maps of
+    # inner width ``kda_gate_rank``) or "A" a latent attention layer
+    # (``kv_lora_rank`` etc. above, no query compression, no indexer, no
+    # rotation); ``num_layers`` long.  Every layer has an FFN behind its
+    # mixer, by ``mlp_layer_types``.  ``kda_chunk_size``: how the scan over a
+    # prompt is blocked, not what it computes
+    kda_pattern: Tuple[str, ...] = ()
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
+    kda_chunk_size: int = 64
     # output heads (EvaByte: head i scores the byte i + 1 positions ahead):
     # ``lm_head.w`` is ``num_pred_heads x vocab_size`` columns wide, head 0's
     # first, and the next token is sampled from head 0's
@@ -257,7 +274,12 @@ class TransformerConfig:
         object.__setattr__(self, "indexer_types", tuple(self.indexer_types))
         object.__setattr__(self, "mlp_layer_types",
                            tuple(self.mlp_layer_types))
-        if self.kv_lora_rank:
+        object.__setattr__(self, "kda_pattern", tuple(self.kda_pattern))
+        if self.kda_pattern:
+            from .kimi_linear import check_config as check_kda
+
+            check_kda(self)
+        elif self.kv_lora_rank:
             from .latent_sparse import check_config
 
             check_config(self)
@@ -338,6 +360,10 @@ class TransformerConfig:
         return 6 * n_params + attn
 
     def num_params(self, include_embed: bool = True) -> int:
+        if self.kda_pattern:
+            from .kimi_linear import num_params
+
+            return num_params(self, include_embed)
         if self.kv_lora_rank:
             from .latent_sparse import num_params
 
@@ -480,6 +506,44 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         moe_intermediate_size=1408, moe_shared_size=2816,
         moe_aux_loss_coef=0.001, moe_seq_aux=True,
         moe_routing="dropless", attn_impl="flash"),
+    # moonshotai/Kimi-Linear-48B-A3B-Instruct as published (kimi_linear):
+    # 49.1 B, about 3 B active; three KDA layers (a delta-rule matrix state a
+    # head under a gate a channel, short convs of 4 taps) to one latent
+    # attention layer WITHOUT positions, query compression or an indexer; one
+    # leading dense layer, then 256 routed experts (sigmoid, top 8, one
+    # group) and one shared; intermediate_size is the DENSE width.  The
+    # published head_dim 72 (2304 / 32) names no array of either mixer
+    "kimi-linear-48b": dict(
+        vocab_size=163840, hidden_size=2304, intermediate_size=9216,
+        num_layers=27, num_heads=32, num_kv_heads=32, max_seq_len=1048576,
+        rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
+        position="none", kda_pattern=tuple("KKKA" * 6 + "KKA"),
+        kda_num_heads=32, kda_head_dim=128, kda_conv_kernel=4,
+        kda_gate_rank=128, kda_chunk_size=64,
+        kv_lora_rank=512, q_lora_rank=0, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        mlp_layer_types=("dense",) + ("sparse",) * 26,
+        num_experts=256, moe_top_k=8, moe_norm_topk=True,
+        moe_router="sigmoid", moe_routed_scaling=2.446,
+        moe_intermediate_size=1024, moe_shared_size=1024,
+        moe_routing="dropless", attn_impl="flash"),
+    # the same block at toy widths: a dense layer, then K K A K K K A K A
+    # over routed FFNs (a pattern that is no period repeated); 4 of 16
+    # experts held (the second share of four); a chunk of 8, so that a
+    # prompt of 20 walks three pieces
+    "tiny-kimi-linear": dict(
+        vocab_size=256, hidden_size=128, intermediate_size=256,
+        num_layers=10, num_heads=4, num_kv_heads=4, max_seq_len=512,
+        norm_eps=1e-5, tie_embeddings=False, position="none",
+        kda_pattern=tuple("KKKAKKKAKA"), kda_num_heads=4, kda_head_dim=16,
+        kda_conv_kernel=4, kda_gate_rank=16, kda_chunk_size=8,
+        kv_lora_rank=32, q_lora_rank=0, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=32,
+        mlp_layer_types=("dense",) + ("sparse",) * 9,
+        num_experts=16, moe_top_k=4, moe_norm_topk=True, moe_router="sigmoid",
+        moe_routed_scaling=2.446, moe_intermediate_size=128,
+        moe_shared_size=128, moe_experts_held=4, moe_first_expert=4,
+        moe_routing="dropless"),
     # the same block at toy widths: one dense layer and three routed ones,
     # 2 of 8 experts held (the second share of four), a query-key width (24 +
     # 8) that is not the value width (16), YaRN whose ramp lies inside 64
@@ -584,6 +648,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Create the parameter pytree. Per-layer weights are stacked on a leading
     ``layers`` axis so the forward pass can ``lax.scan`` over them (a model
     with ``mixer_pattern``: one stack a kind of layer, models/ssm_hybrid.py)."""
+    if cfg.kda_pattern:
+        from .kimi_linear import init_params as init_kda
+
+        return init_kda(rng, cfg)
     if cfg.kv_lora_rank:
         from .latent_sparse import init_params as init_latent
 
@@ -671,6 +739,10 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
 
     Pass ``params`` for HF-converted trees that carry linear biases
     (qwen2/opt/gpt-neox …): bias leaves get matching axes entries."""
+    if cfg.kda_pattern:
+        from .kimi_linear import param_axes as kda_axes
+
+        return kda_axes(cfg)
     if cfg.kv_lora_rank:
         from .latent_sparse import param_axes as latent_axes
 
@@ -1086,6 +1158,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     ``attn_fn``/``moe_fn`` are injection points for Pallas flash attention,
     Ulysses/ring sequence parallelism and expert-parallel MoE dispatch.
     """
+    if cfg.kda_pattern:
+        from .kimi_linear import NOT_TRAINED as KDA_NOT_TRAINED
+
+        raise NotImplementedError(KDA_NOT_TRAINED)
     if cfg.kv_lora_rank:
         from .latent_sparse import forward_hidden as latent_hidden
 

@@ -261,6 +261,215 @@ def latent_decode_attention(q_lat: jax.Array, pool: jax.Array,
         return o / jnp.where(l == 0.0, 1.0, l)
 
 
+# ---------------------------------------------------------------------------
+# one query a row over the row's WHOLE context (a model without an indexer)
+# ---------------------------------------------------------------------------
+
+#: keys a fetch of the full decode kernel holds at most (1.3 MB of bfloat16
+#: at a pool width of 640), and the fetches held in VMEM (the one multiplied
+#: and those under way behind it: ``paged_attention.pick_decode_tiles``)
+_FULL_FETCH_KEYS, _FULL_SLOTS = 1024, 3
+
+
+def _decode_full_xla(q_lat, pool, layer, tables, context_lens, *,
+                     scale: float, latent: int):
+    """``latent_decode_attention_full`` as XLA: a scan over the table's
+    columns with an online softmax (what the kernel gives way to where the
+    pool's shapes forbid its fetches, and what the tests hold it to)."""
+    R, H, _ = q_lat.shape
+    bs = pool.shape[2]
+
+    def column(carry, j):
+        acc, m, l = carry
+        g = pool[layer, tables[:, j]]  # (R, bs, W)
+        s = jnp.einsum("rhw,rkw->rhk", q_lat, g,
+                       preferred_element_type=jnp.float32) * scale
+        seen = (j * bs + jnp.arange(bs))[None, None, :] \
+            < context_lens[:, None, None]
+        s = jnp.where(seen, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("rhk,rkc->rhc", p.astype(g.dtype), g[..., :latent],
+                        preferred_element_type=jnp.float32)
+        return (acc * alpha + pv, m_new, l), None
+
+    (acc, _, l), _ = jax.lax.scan(
+        column, (jnp.zeros((R, H, latent), jnp.float32),
+                 jnp.full((R, H, 1), _NEG, jnp.float32),
+                 jnp.zeros((R, H, 1), jnp.float32)),
+        jnp.arange(tables.shape[1]))
+    return acc / jnp.where(l == 0.0, 1.0, l)
+
+
+def _decode_full_kernel(layer_ref, tables_ref, ctx_ref,  # scalar prefetch
+                        q_ref, pool_hbm,  # the queries, the pool in HBM
+                        o_ref,  # the output
+                        rows_ref, k_buf, copy_sems,  # scratch
+                        *, scale: float, latent: int):
+    """``paged_attention._decode_kernel`` at one key head ``W`` wide whose
+    values are the keys' first ``latent`` lanes: the rows walked as ONE list
+    of (row, fetch), a fetch up to ``kb`` consecutive blocks of a row's table
+    (one score slab), the DMA slots ``slots`` deep ACROSS rows."""
+    rows, H, W = q_ref.shape
+    slots, kb, BS, _ = k_buf.shape
+    n = kb * BS
+    layer = layer_ref[0]
+
+    # -- the rows, one column of ``rows_ref`` each: the blocks [0, end) the
+    # row's query (at ctx - 1) sees a key of, and the next row that has a
+    # context (``rows``: none; that column reads 0, rows)
+    def add_row(i, live):
+        s = rows - 1 - i
+        ctx = ctx_ref[s]
+        rows_ref[0, s] = jax.lax.div(ctx + BS - 1, BS)
+        rows_ref[1, s] = live
+        return jnp.where(ctx > 0, s, live)
+
+    rows_ref[0, rows] = 0
+    rows_ref[1, rows] = rows
+    live = jax.lax.fori_loop(0, rows, add_row, jnp.int32(rows))
+
+    def copy(slot, c, blk):
+        return pltpu.make_async_copy(pool_hbm.at[layer, blk],
+                                     k_buf.at[slot, c],
+                                     copy_sems.at[slot, c])
+
+    def fetch(g, s, j):
+        """Start the DMAs of the ``g``-th fetch, row ``s``'s blocks ``j`` to
+        ``j + kb`` that the row has (past the last row: none), and → the
+        fetch after it."""
+        slot = jax.lax.rem(g, slots)
+        end = rows_ref[0, s]
+
+        def one(c, _):
+            copy(slot, c, tables_ref[s, j + c]).start()
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(kb, end - j), one, 0)
+        more = j + kb < end
+        return jnp.where(more, s, rows_ref[1, s]), jnp.where(more, j + kb, 0)
+
+    def await_fetch(slot, held):
+        def one(c, _):
+            copy(slot, c, 0).wait()  # a wait reads the size alone
+            return 0
+
+        jax.lax.fori_loop(0, held, one, 0)
+
+    # a block the row lacks is not fetched: what its slot held before must be
+    # finite where p = 0 meets it
+    k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, n), 1)
+
+    ahead = jax.lax.fori_loop(
+        0, slots - 1, lambda g, at: fetch(g, *at), (live, jnp.int32(0)))
+
+    def row(s, carry):
+        ctx, end = ctx_ref[s], rows_ref[0, s]
+        q = q_ref[s].astype(k_buf.dtype)
+
+        def step(i, carry):
+            acc, m, l, g, *ahead = carry
+            j = i * kb
+            slot = jax.lax.rem(g, slots)
+            ahead = fetch(g + slots - 1, *ahead)
+            await_fetch(slot, jnp.minimum(kb, end - j))
+            keys = k_buf[slot].reshape(n, W)
+            scores = jax.lax.dot_general(
+                q, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(j * BS + col < ctx, scores, -jnp.inf)
+            # every fetch holds a key the row sees: m_new is finite
+            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(scores - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.dot(p.astype(keys.dtype), keys[:, :latent],
+                         preferred_element_type=jnp.float32)
+            return (acc * alpha + pv, m_new, l, g + 1, *ahead)
+
+        acc, _, l, *carry = jax.lax.fori_loop(
+            0, jax.lax.div(end + kb - 1, kb), step,
+            (jnp.zeros((H, latent), jnp.float32),
+             jnp.full((H, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32), *carry))
+        # a row without a context ran no step: zero
+        o_ref[s] = acc / jnp.where(l == 0.0, 1.0, l)
+        return tuple(carry)
+
+    jax.lax.fori_loop(0, rows, row, (jnp.int32(0), *ahead))
+
+
+@functools.partial(jax.jit, static_argnames=("kb", "scale", "latent",
+                                             "interpret"))
+def _decode_full_pallas(q_lat, pool, layer, tables, context_lens, *, kb: int,
+                        scale: float, latent: int, interpret: bool):
+    """The kernel's call, under a jit of its own: the step program's latent
+    layers trace and lower it once."""
+    R, H, W = q_lat.shape
+    bs = pool.shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((R, H, W), lambda i, *_: (0, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec((R, H, latent), lambda i, *_: (0, 0, 0)),
+        scratch_shapes=[
+            pltpu.SMEM((2, R + 1), jnp.int32),
+            pltpu.VMEM((_FULL_SLOTS, kb, bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((_FULL_SLOTS, kb)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_full_kernel, scale=scale, latent=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, H, latent), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name="latent_attention_decode_full",
+    )(_layer_operand(layer), tables, context_lens, q_lat, pool)
+
+
+def latent_decode_attention_full(q_lat: jax.Array, pool: jax.Array,
+                                 layer: jax.Array, tables: jax.Array,
+                                 context_lens: jax.Array, *, scale: float,
+                                 latent: int) -> jax.Array:
+    """One query a row over the row's WHOLE context, streamed through its
+    table: ``q_lat (R, H, W)`` absorbed; ``context_lens (R,)`` INCLUDES the
+    row's own token (its entry already written; 0: the row takes no step,
+    reads nothing and comes out zero) → ``(R, H, latent)`` float32, the
+    weighted sum of the keys' latents.  One ring event a traced call,
+    ``kernel/latent_attention_decode_full_tiles`` (``kb`` blocks a fetch,
+    ``slots`` fetches held; or ``fallback=1`` where the pool's shapes are no
+    whole lanes and sublane groups)."""
+    R, H, W = q_lat.shape
+    bs = pool.shape[2]
+    blocks = tables.shape[1]
+    kb = max(1, min(blocks, _FULL_FETCH_KEYS // bs))
+    fallback = not backend.interpret() and bool(
+        W % LANES or latent % LANES or bs % (32 // pool.dtype.itemsize))
+    tracer.add_event("kernel/latent_attention_decode_full_tiles", attrs={
+        "rows": R, "heads": H, "w": W, "block": bs, "s_max": blocks * bs,
+        **({"fallback": 1} if fallback else
+           {"kb": kb, "slots": _FULL_SLOTS, "form": "absorbed, pallas"})})
+    with jax.named_scope("latent_attention_decode_full"):
+        if fallback:
+            backend.warn_fallback(
+                "latent_decode_attention_full",
+                f"pool width {W} or latent {latent} is not a multiple of "
+                f"{LANES}, or block_size={bs} is not whole sublane groups "
+                f"(Mosaic DMA slice alignment)")
+            return _decode_full_xla(q_lat, pool, layer, tables, context_lens,
+                                    scale=scale, latent=latent)
+        return _decode_full_pallas(
+            q_lat, pool, layer, tables, context_lens.astype(jnp.int32),
+            kb=kb, scale=scale, latent=latent,
+            interpret=backend.interpret())
+
+
 def _latent_prefill_xla(q_lat: jax.Array, pool: jax.Array, layer: jax.Array,
                         tables: jax.Array, mask: jax.Array, tiles: Tiles,
                         q_start: jax.Array, chunk_start: jax.Array, *,

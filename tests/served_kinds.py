@@ -28,6 +28,7 @@ TINY_KINDS = {
     "state": ("tiny-nemotron3", {}),
     "latent": ("tiny-glm52", dict(num_blocks=65)),
     "eva": ("tiny-evabyte", {}),
+    "linear-latent": ("tiny-kimi-linear", dict(num_blocks=65)),
 }
 
 
@@ -68,6 +69,14 @@ STEP_ATTRS = {
     "eva": ({"eva_window_keys", "eva_summary_keys", "eva_keys_full",
              "eva_query_keys", "eva_windows_closed", "eva_chunks_written",
              "blocks_used_window", "blocks_used_summary"}, set()),
+    # new with the kind (PR 51): no parent carried them
+    "linear_latent": ({"state_slots_used", "state_rows_started", "kda_tokens",
+                       "kda_state_bytes", "latent_keys_read",
+                       "latent_keys_single", "latent_keys_prefill",
+                       "latent_query_keys", "blocks_used_latent",
+                       "moe_assignments", "moe_assignments_local"},
+                      {"kda_scan_rows", "kda_scan_tokens",
+                       "kda_scan_pieces"}),
 }
 
 

@@ -204,11 +204,13 @@ def test_pick_spread_covers_the_lengths():
     assert drv.pick_spread(finished[5:6], check, 1) == []
 
 
-def test_the_shares_add_up_to_the_whole_layer(tiny):
-    """The guide's share test: the routed parts that all four shares of four
-    experts give, with the shared expert counted once, add up to what the
-    layer that holds all sixteen gives."""
-    cfg, params, _ = tiny
+@pytest.mark.parametrize("preset", ["tiny-glm52", "tiny-kimi-linear"])
+def test_the_shares_add_up_to_the_whole_layer(preset):
+    """The guide's share test, a served model with a share of its experts a
+    case: the routed parts that all four shares of four experts give, with
+    the shared expert counted once, add up to what the layer that holds all
+    sixteen gives."""
+    cfg = tfm.get_config(preset, dtype="float32", param_dtype="float32")
     whole_cfg = dataclasses.replace(cfg, moe_experts_held=0,
                                     moe_first_expert=0)
     whole = tfm.init_params(jax.random.PRNGKey(5), whole_cfg)

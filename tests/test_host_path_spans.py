@@ -570,13 +570,14 @@ def test_new_entry_finds_its_file_and_its_cells(name):
     if name == "decode_host_wait_ms_mean":
         assert sorted(entry["workloads"]) == [
             "chat-decode-sat", "evabyte-doc-bytes-sat",  # PR 48's, decode too
+            "kimilinear-reason-sat",  # PR 51's: nine steps in ten decode
             "nemotron3-chat-wide-sat", "olmoe-decode-sat"]
         assert moved["name"] == "serve_out_tokens_per_s"
     else:
         assert sorted(entry["workloads"]) == sorted(moved["workloads"])
         # every serving cell: five when the entry was added, PR 40's sixth,
-        # PR 48's seventh
-        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 7
+        # PR 48's seventh, PR 51's eighth
+        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 8
     for cell in entry["workloads"]:
         assert cell in moved["workloads"]
         assert entry in run.metrics_of(spec, "per_layer", cell)

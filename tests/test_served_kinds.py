@@ -23,7 +23,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
     ("olmoe-1b-7b", programs.KV), ("mellum2-12b-a2.5b", programs.KV),
     ("nemotron3-nano-30b-a3b", programs.STATE), ("glm-5.2", programs.LATENT),
     ("deepseek-v2-lite", programs.LATENT), ("evabyte-6.5b", programs.EVA),
-    ("tiny-evabyte", programs.EVA)])
+    ("tiny-evabyte", programs.EVA),
+    ("kimi-linear-48b", programs.LINEAR_LATENT),
+    ("tiny-kimi-linear", programs.LINEAR_LATENT)])
 def test_kind_of_names_the_kind(preset, kind):
     cfg = tfm.get_config(preset)
     assert programs.kind_of(cfg) is kind
@@ -56,7 +58,12 @@ def _cell(name):
     # two pools in every layer: 16 summary blocks a row at 16,384 bytes, a
     # whole window of 32 blocks a row, a scratch block each
     ("evabyte-6.5b-w8", {"k_sum": (32, 65, 64, 32, 128),
-                         "k_win": (32, 129, 64, 32, 128)}, 0, 1 + 4 * 32)])
+                         "k_win": (32, 129, 64, 32, 128)}, 0, 1 + 4 * 32),
+    # 48 rows at 8,192 tokens of latent in the 7 latent layers, 49 slots of
+    # 2 MiB of float32 state in each of the 20 KDA layers
+    ("kimi-linear-48b-ep8-w8", {"latent": (7, 6145, 64, 640),
+                                "kda": (20, 49, 32, 128, 128),
+                                "conv": (20, 49, 3, 12288)}, 26, None)])
 def test_arrays_of_the_served_cells(cell, shapes, moe_layers,
                                     window_default):
     """What each served configuration caches at its cell's sizes, on shapes
@@ -65,11 +72,13 @@ def test_arrays_of_the_served_cells(cell, shapes, moe_layers,
     kind = programs.kind_of(cfg)
     arrays = kind.arrays(cfg, v2)
     for name in list(shapes):  # V beside K, the window layers' beside both
-        if name.startswith("k"):
+        if name == "k" or name.startswith("k_"):
             shapes["v" + name[1:]] = shapes[name]
     assert {n: shape for n, (shape, _) in arrays.items()} == shapes
-    assert {str(dt) for n, (_, dt) in arrays.items() if n != "ssm"} == \
-        {"bfloat16"}
+    assert {str(dt) for n, (_, dt) in arrays.items()
+            if n not in ("ssm", "kda")} == {"bfloat16"}
+    assert all(arrays[n][1] == jnp.float32 for n in ("ssm", "kda")
+               if n in arrays)
     assert kind.moe_layers(cfg) == moe_layers
     if "k_win" in shapes:  # the default: what the rows hold at most, and one
         v2.num_window_blocks = 0
@@ -97,7 +106,10 @@ def _count(jaxpr, c):
     ("tiny-glm52", "tiny-glm52", {}, programs.LATENT, "latent index"),
     # pinned by the PR that brought the kind (48): no parent had it
     ("tiny-evabyte", "tiny-evabyte", {}, programs.EVA,
-     "k_sum v_sum k_win v_win")])
+     "k_sum v_sum k_win v_win"),
+    # pinned by the PR that brought the kind (51): no parent had it
+    ("tiny-kimi-linear", "tiny-kimi-linear", {}, programs.LINEAR_LATENT,
+     "latent kda conv")])
 def test_step_programs_are_the_parents(name, preset, over, kind, cached):
     """The lock on the three bodies: the mixed and the decode step of one tiny
     model a body (and a shape of the first) count, primitive by primitive, the
@@ -117,8 +129,9 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
     assert {n: (a.shape, a.dtype) for n, a in e.caches.items()} == \
         kind.arrays(e.model_cfg, v2)
     assert e._moe_layers == kind.moe_layers(cfg)
-    assert (e.kv.slots is not None) == ("ssm" in e.caches) == \
+    assert (e.kv.slots is not None) == bool(kind.state) == \
         bool(e.total_state_slots)
+    assert set(kind.state) <= set(e.caches)
     assert (e.kv_win is not None) == ("k_win" in e.caches)
     T, S = 32, 4
 
@@ -132,13 +145,17 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
         "mixed": jax.make_jaxpr(e._fwd)(
             e.params, e.caches, i32(T), i32(T), i32(T), tables, i32(S),
             i32(S), i32(S), i32(S),
-            *((None, None, i32(S)) if "ssm" in e.caches else ())),
+            *((None, None, i32(S)) if kind.state else ())),
         "decode": jax.make_jaxpr(e._decode_fwd)(
             e.params, e.caches, i32(S), i32(S), tables, i32(S),
             jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))}
-    scopes = {programs.STATE: ("ssm_", "moe_shared"),
-              programs.LATENT: ("latent", "dsa_"),
-              programs.EVA: ("eva_",)}
+    scopes = {programs.STATE: ("ssm_",),
+              programs.LATENT: ("dsa_",),
+              programs.EVA: ("eva_",),
+              programs.LINEAR_LATENT: ("kda_",)}
+    # what two kinds share: a latent pool, a shared expert
+    shared = {"latent": (programs.LATENT, programs.LINEAR_LATENT),
+              "moe_shared": (programs.STATE, programs.LINEAR_LATENT)}
     for step, jaxpr in programs_.items():
         assert dict(_count(jaxpr.jaxpr, collections.Counter())) == \
             pinned[step], step
@@ -146,6 +163,8 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
         for other, names in scopes.items():
             if other is not kind:
                 assert not any(n in text for n in names), (step, names)
+        for n, kinds in shared.items():
+            assert kind in kinds or n not in text, (step, n)
 
 
 def test_another_kind_s_start_loads_nothing_of_eva():

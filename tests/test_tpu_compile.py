@@ -204,8 +204,10 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
               for name, (shape, dtype) in arrays.items()}
     rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
     tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
-    if "latent" in arrays:  # a latent pool and the indexer's
-        pool = (arrays["latent"][0], arrays["index"][0])
+    if "latent" in arrays:  # a latent pool and, where there is one, the
+        # indexer's
+        pool = tuple(arrays[name][0] for name in ("latent", "index")
+                     if name in arrays)
     elif "k_win" in arrays:  # the window layers' pool and table beside them
         # (an EVA model: its summaries' pool, then its window's)
         main = "k_sum" if "k_sum" in arrays else "k"
@@ -238,7 +240,7 @@ def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
             rows(jnp.int32),
             # a model with state layers: each row's state slot, behind the
             # two adapter arguments it never has
-            *([None, None, rows(jnp.int32)] if "ssm" in arrays else []))
+            *([None, None, rows(jnp.int32)] if kind_of(cfg).state else []))
     return lowered, pool, params
 
 
@@ -980,7 +982,7 @@ def test_nemotron3_step_programs_compile(one_chip, mosaic, program):
     mem = compiled.memory_analysis()
     state = 4 * 65 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
     assert mem.alias_size_in_bytes >= 2 * 2 * int(np.prod(pool)) + state
-    assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.7e9, mem.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
@@ -1235,6 +1237,118 @@ def test_evabyte_step_programs_compile(one_chip, mosaic, program):
     assert mem.alias_size_in_bytes >= sum(
         2 * 2 * int(np.prod(pool)) for pool in pools)
     assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+
+
+# -- Kimi-Linear: KDA state slots beside a latent pool read whole ------------
+
+
+@pytest.mark.parametrize("kernel", ["kda_decode", "latent_decode_full"])
+def test_kimilinear_kernels_compile(one_chip, mosaic, kernel):
+    """The two kernels the fifth kind brought, at the published widths and
+    the serving cell's sizes: the KDA decode update (49 slots of 32 heads of
+    128 x 128, 20 layers, the state in place) and the paged latent decode
+    over a row's whole context (48 rows, 32 absorbed heads at the pool's 640,
+    tables of 128 blocks of 64: 16 blocks a fetch, three fetches held)."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import kda, latent_attention
+
+    f32, H, D = jnp.float32, 32, 128
+    sds = functools.partial(_sds, sharding=one_chip)
+    tracer.clear()
+    if kernel == "kda_decode":
+        S1 = 49
+        a_slot = sds((S1, H, D), f32)
+        _compile(
+            kda.kda_decode_update, sds((20, S1, H, D, D), f32),
+            sds((), jnp.int32), a_slot, a_slot, a_slot, a_slot,
+            sds((S1, H), f32), sds((S1,), bool), sds((S1,), bool),
+            kernels=["kda_decode_update"])
+        event = "kernel/kda_decode_update"
+    else:
+        _compile(
+            functools.partial(latent_attention.latent_decode_attention_full,
+                              scale=192 ** -0.5, latent=512),
+            sds((48, H, 640), jnp.bfloat16),
+            sds((7, 6145, 64, 640), jnp.bfloat16), sds((), jnp.int32),
+            sds((48, 128), jnp.int32), sds((48,), jnp.int32),
+            kernels=["latent_attention_decode_full"])
+        event = "kernel/latent_attention_decode_full_tiles"
+    (attrs,) = [s.attrs for s in tracer.spans() if s.name == event]
+    assert "fallback" not in attrs and "xla" not in attrs, attrs
+    if kernel == "latent_decode_full":
+        assert (attrs["kb"], attrs["slots"]) == (16, 3)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_kimilinear_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of Kimi-Linear-48B-A3B at FULL DEPTH (27 layers,
+    every width as published, W8A16 at group 128, the chip's 32 of 256
+    experts, an eighth of the vocabulary; the serving cell's engine sizes: 48
+    rows, 6,145 blocks, tables of 128) compile for the described chip.  Every
+    GEMM runs its kernel and so do the KDA decode update and both latent
+    paths (no ``kernel/*`` event with ``fallback``), the scopes of both
+    mixers are in the lowered names beside the ``moe_*`` ones, the latent
+    pool and both state arrays are updated in place, and arguments and temp
+    together stay under the 14.5 GB line (the configuration file's
+    ``as_run``)."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg = dataclasses.replace(
+        tfm.get_config("kimi-linear-48b", vocab_size=20480,
+                       moe_experts_held=32),
+        dtype="bfloat16", param_dtype="bfloat16")
+    tracer.clear()
+    lowered, pools, params = _lower_step_program(
+        program, cfg, functools.partial(_sds, sharding=one_chip), group=128,
+        max_seqs=48, num_blocks=6145, max_blocks_per_seq=128)
+    assert pools == ((7, 6145, 64, 640),)
+    moe = params["layers"]["S"]["moe"]
+    assert moe["w_in"].codes.shape == (26, 32, 2304, 1024)
+    assert moe["router"].shape == (26, 2304, 256)  # all experts are scored
+    kda = params["layers"]["K"]["kda"]
+    assert kda["w_qkv"].codes.shape == (20, 2304, 12288)
+    assert kda["w_f_up"].dtype == jnp.bfloat16 and \
+        kda["A_log"].dtype == jnp.float32
+    assert params["layers"]["A"]["attn"]["w_q"].codes.shape == (7, 2304, 6144)
+    assert params["layers"]["A"]["attn"]["w_kvb"].dtype == jnp.bfloat16
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    assert not [e for e in events if "fallback" in e[1]], events
+    names = {name for name, _ in events}
+    assert {"kernel/kda_decode_update",
+            "kernel/latent_attention_decode_full_tiles"} <= names
+    mixed = program == "mixed_step"
+    assert ("kernel/kda_chunk_scan_tiles" in names) == mixed
+    assert ("kernel/latent_attention_prefill_tiles" in names) == mixed
+    text = lowered.as_text(debug_info=True)
+    scopes = ["grouped_mixed_gemm", "mixed_gemm", "moe_route", "moe_dispatch",
+              "moe_experts", "moe_combine", "moe_shared", "kda_in_proj",
+              "kda_conv", "kda_gate_in", "kda_decode_update", "kda_gate_out",
+              "kda_out_proj", "latent_attention_decode_full", "latent_q_proj",
+              "latent_kv_proj", "latent_absorb_q", "latent_absorb_o"]
+    if mixed:
+        scopes += ["latent_attention_prefill", "kda_chunk_scan"]
+    for name in scopes:
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
+    for pool in pools:
+        assert _pool_passes(compiled_text, pool) == []
+    for kernel in ("kda_decode_update", "latent_attention_decode_full"):
+        assert re.search(rf"%{kernel}[.\d]* = .*custom_call_target="
+                         r'"tpu_custom_call"', compiled_text), kernel
+    mem = compiled.memory_analysis()
+    state = 20 * 49 * 32 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= 2 * int(np.prod(pools[0])) + state
+    print(f"kimi-linear-48b-ep8-w8 {program}: arguments "
+          f"{mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}, "
+          f"alias {mem.alias_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 0.7e9, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
 
 
