@@ -13,13 +13,20 @@ Four stages, each under a named scope a device trace can find:
                   the config's rule (``moe_norm_topk``: renormalised to sum 1,
                   or the raw probabilities); or sigmoid scores with a
                   correction bias for the choice (``moe_router``);
-``moe_dispatch``  tokens scattered once into the tile-aligned grouped layout
-                  (``ops/pallas/grouped_matmul.tile_aligned_layout``), whose
-                  ``tile_m`` follows the step's assignments (:func:`moe_tile_m`);
+``moe_dispatch``  the tile-aligned grouped layout planned
+                  (``ops/pallas/grouped_matmul.tile_aligned_layout``; its
+                  ``tile_m`` follows the step's assignments, :func:`moe_tile_m`)
+                  and inverted without a scatter (``layout_sources``: each
+                  row's token), then the tokens GATHERED into it under those
+                  indices (``ops/pallas/moe_rows.gather_rows``: a mixed
+                  step's on the MXU, and only the tiles that hold rows; a
+                  decode step's few assignments scattered, as they were);
 ``moe_experts``   the expert FFN as three grouped GEMMs: bf16
                   (``grouped_matmul``) or int8 codes dequantized in the kernel
                   (``grouped_mixed_gemm``), by the weight's type;
-``moe_combine``   weighted expert outputs gathered back and summed per token;
+``moe_combine``   weighted expert outputs gathered back (XLA's gather: its
+                  source is a layout's worth of rows) and summed per token
+                  in float32;
 ``moe_shared``    a shared expert (``sh_w_in`` / ``sh_w_out``), where the model
                   has one: every row through ``mixed_gemm``, added after.
 
@@ -34,14 +41,17 @@ no kernel wrote are read through ``jnp.where`` only, forward and backward.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas.grouped_matmul import grouped_matmul, tile_aligned_layout
+from ..ops.pallas.grouped_matmul import (grouped_matmul, layout_sources,
+                                         tile_aligned_layout)
 from ..ops.pallas.grouped_mixed_gemm import grouped_mixed_gemm
 from ..ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
+from ..ops.pallas.moe_rows import gather_rows
 
 _MIN_TILE_M, _MAX_TILE_M = 16, 512
 
@@ -148,6 +158,16 @@ def _expert_gemm(a, w, tile_group, pad_sizes, used_tiles, tile_m):
                           tile_m=tile_m, used_tiles=used_tiles)
 
 
+def _valid_counts(group: jax.Array, valid: jax.Array, groups: int
+                  ) -> jax.Array:
+    """The assignments ``valid`` marks, by group (``jnp.bincount`` with
+    weights, as a compare and a sum: the TPU walks a scatter-add of T
+    indices one after another, 36 us at T = 4,096)."""
+    hit = group[:, None] == jnp.arange(groups, dtype=group.dtype)[None, :]
+    return jnp.sum(hit & valid.astype(bool)[:, None], axis=0,
+                   dtype=jnp.int32)
+
+
 def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
                routing: Optional[Routing] = None,
                valid: Optional[jax.Array] = None
@@ -178,12 +198,13 @@ def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
             expert_flat, E, T, tile_m)
         counts = jnp.bincount(expert_flat, length=E)
         used_tiles = jnp.sum(-(-counts // tile_m)).astype(jnp.int32)
-        xs = jnp.zeros((M_pad, H), dt).at[positions].set(
-            jnp.repeat(x2, k, axis=0))
+        xs = gather_rows(
+            x2, positions.reshape(N, k), used_tiles, rows=M_pad,
+            tile_m=tile_m, sources=functools.partial(
+                layout_sources, expert_flat, counts, tile_group, pad_sizes,
+                tile_m))
         if valid is not None:
-            counts = jnp.bincount(
-                expert_flat, weights=jnp.repeat(valid, k).astype(jnp.int32),
-                length=E)
+            counts = _valid_counts(expert_flat, jnp.repeat(valid, k), E)
         stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]
                           ).astype(jnp.int32)
         if getattr(cfg, "moe_tap_choices", False):  # tooling only
@@ -247,13 +268,11 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
         positions, tile_group, pad_sizes, M_pad = tile_aligned_layout(
             group, held + 1, T, tile_m)
         tile_group = jnp.minimum(tile_group, held - 1)  # a block that exists
-        counts = jnp.bincount(group, length=held + 1)[:held]
+        counts = local_counts = jnp.bincount(group, length=held + 1)[:held]
         used_tiles = jnp.sum(-(-counts // tile_m)).astype(jnp.int32)
         at = jnp.where(local, positions, M_pad)  # elsewhere: written nowhere
         if valid is not None:
-            counts = jnp.bincount(
-                group, weights=jnp.repeat(valid, k).astype(jnp.int32),
-                length=held + 1)[:held]
+            counts = _valid_counts(group, jnp.repeat(valid, k), held)
         stats = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
                            jnp.sum(counts)]).astype(jnp.int32)
         if getattr(cfg, "moe_tap_choices", False):  # tooling only
@@ -266,8 +285,13 @@ def _routed_ffn_share(x2, p, cfg, routing, valid):
                                 E), stats
 
     with jax.named_scope("moe_dispatch"):
-        xs = jnp.zeros((M_pad, H), dt).at[at].set(
-            jnp.repeat(x2, k, axis=0), mode="drop")
+        # the group that lives elsewhere has no row in ``src``, and
+        # ``used_tiles`` ends the walk behind the local rows
+        xs = gather_rows(
+            x2, jnp.where(local, positions, -1).reshape(N, k), used_tiles,
+            rows=M_pad, tile_m=tile_m, sources=functools.partial(
+                layout_sources, group, local_counts, tile_group,
+                pad_sizes[:held], tile_m))
 
     with jax.named_scope("moe_experts"):
         def gmm(a, key):

@@ -319,3 +319,34 @@ def tile_aligned_layout(expert_flat: jax.Array, num_experts: int, T: int,
         jnp.asarray([M_pad], padded.dtype) - jnp.sum(padded[:-1])[None],
     ]).astype(jnp.int32)
     return positions.astype(jnp.int32), tile_group, pad_sizes, M_pad
+
+
+def layout_sources(expert_flat: jax.Array, counts: jax.Array,
+                   tile_group: jax.Array, pad_sizes: jax.Array,
+                   tile_m: int) -> jax.Array:
+    """The inverse of ``tile_aligned_layout``'s ``positions``: ``src
+    (M_pad,)`` int32, the assignment that lies at each row of the layout, -1
+    where none does.  Made WITHOUT a scatter (the TPU walks a scatter of T
+    indices one after another, as it walks a scatter of rows) and without a
+    gather of single indices (walked alike: 87 us for 12,288 of them): a
+    stable ``argsort`` of the assignments' groups is expert order, and a
+    tile's rows are ``tile_m`` CONSECUTIVE entries of it, from the group's
+    unpadded start plus the rank of the tile's first row (its offset from
+    the group's padded start), valid while the rank is under the group's
+    count: one slice a tile.  The groups are those of ``counts`` and
+    ``pad_sizes`` (``tile_group`` names no other): an assignment of a later
+    group (a share's rows that live elsewhere) has no row here."""
+    T = expert_flat.shape[0]
+    order = jnp.pad(jnp.argsort(expert_flat, stable=True), (0, tile_m))
+
+    def of_tile(by_group):
+        return by_group.at[tile_group].get(mode="promise_in_bounds")
+
+    tile_start = jnp.arange(tile_group.shape[0], dtype=jnp.int32) * tile_m
+    rank0 = tile_start - of_tile(jnp.cumsum(pad_sizes) - pad_sizes)
+    first = jnp.clip(of_tile(jnp.cumsum(counts) - counts) + rank0, 0, T)
+    rows = jax.vmap(lambda at: jax.lax.dynamic_slice(order, (at,), (tile_m,))
+                    )(first)
+    rank = rank0[:, None] + jnp.arange(tile_m, dtype=jnp.int32)[None, :]
+    src = jnp.where(rank < of_tile(counts)[:, None], rows, -1)
+    return src.reshape(-1).astype(jnp.int32)

@@ -639,6 +639,42 @@ def test_grouped_matmul_compiles(one_chip, mosaic, rows, experts, tile_m, k,
         kernels=["grouped_matmul"])
 
 
+@pytest.mark.parametrize("rows,tile_m,h,tokens,top_k", [
+    (12288, 128, 2304, 512, 8), (1280, 16, 2048, 32, 8),
+    (2432, 16, 2688, 64, 6), (6272, 128, 6144, 512, 8),
+    (912, 16, 2304, 48, 8)],
+    ids=["mellum2-mixed", "olmoe-decode", "nemotron3-decode",
+         "glm52-mixed-share", "kimilinear-decode-share"])
+def test_moe_rows_compiles(one_chip, mosaic, rows, tile_m, h, tokens, top_k):
+    """The routed experts' gather kernel at the five served layouts (a
+    one-hot of the block's sources, transposed into the MXU against the
+    step's tokens).  ``gather_rows`` announces a mixed step's as ``pallas``
+    and scatters a decode step's few assignments as the parent did."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import moe_rows
+
+    block = moe_rows.block_rows(rows, tile_m, tokens, h, jnp.bfloat16)
+    assert block
+    # ``src``: a token a row for the kernel, an assignment a row for
+    # ``gather_rows`` (only the types matter to a compile)
+    args = (_sds((tokens, h), jnp.bfloat16, one_chip),
+            _sds((rows,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+    _compile(lambda x, src, used: moe_rows._rows_pallas(
+        x, src, used, tile_m=tile_m, rows=block, interpret=False),
+        *args, kernels=["moe_rows"])
+    tracer.clear()
+    text = jax.jit(lambda x, src, used, inverse: moe_rows.gather_rows(
+        x, inverse, used, rows=rows, tile_m=tile_m, sources=lambda: src)
+    ).lower(*args, _sds((tokens, top_k), jnp.int32, one_chip)
+            ).compile().as_text()
+    (event,) = [s.attrs for s in tracer.spans() if s.name == "kernel/moe_rows"]
+    mixed = tokens * top_k >= moe_rows._MIN_LIVE
+    assert event == {"rows": rows, "h": h, "tile_m": tile_m, "tokens": tokens,
+                     "live": tokens * top_k,
+                     "pallas" if mixed else "scatter": 1}
+    assert ("tpu_custom_call" in text) == mixed
+
+
 @pytest.mark.parametrize("n", [HIDDEN * MLP, 1_000_003],
                          ids=["mlp-weight", "ragged"])
 def test_fused_adamw_compiles(one_chip, mosaic, n):
@@ -897,6 +933,27 @@ def test_mellum2_step_programs_compile(one_chip, mosaic, program):
     calls = re.findall(r"%(grouped_mixed_gemm[.\d]*) = [^\n]*custom-call\(",
                        compiled_text)
     assert len(calls) == 12, calls
+    # the rows reach the grouped layout through the gather kernel, which the
+    # compiled text keeps under ``moe_dispatch`` (what
+    # ``moe_dispatch_busy_pct`` reads), and no scatter of the layout's rows
+    # (the parent's 0.42 ms a call) is left
+    from benchmark import kernel_time
+
+    rows_events = {(a["rows"], "pallas" in a, "scatter" in a)
+                   for name, a in events if name == "kernel/moe_rows"}
+    calls = re.findall(r"%(moe_rows[.\d]*) = [^\n]*custom-call\(",
+                       compiled_text)
+    if program == "decode_step":  # 256 assignments: the parent's scatter
+        assert rows_events == {(1280, False, True)} and not calls
+    else:
+        assert rows_events == {(12288, True, False)}
+        scope_of = kernel_time.scopes_of_text(
+            compiled_text, ("moe_route", "moe_dispatch", "moe_experts",
+                            "moe_combine"))
+        assert len(calls) == 4 and {scope_of.get(c) for c in calls} == {
+            "moe_dispatch"}, [(c, scope_of.get(c)) for c in calls]
+        assert not re.search(r"= bf16\[12288,2304\]\S* scatter\(",
+                             compiled_text)
     for pool in pools:
         assert _pool_passes(compiled_text, pool) == []
     assert _weight_passes(compiled_text, params) == []
