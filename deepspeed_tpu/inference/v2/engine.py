@@ -17,6 +17,7 @@ prefill steps.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
@@ -72,6 +73,19 @@ def _thread_cpu() -> Optional[float]:
     """The calling thread's CPU clock, for the step's split; None with
     tracing off, which reads no clock.  A system call (trace.py)."""
     return time.thread_time() if tracer.enabled else None
+
+
+# The ``thread`` an ``engine/program`` span is recorded under, behind the
+# name of the thread that steps the engine (two replicas in one process each
+# have their own): a track of the chrome export beside that thread's.  Two,
+# by the step's parity: a program overlaps its predecessor and its successor
+# and no other.
+_QUEUE_TRACKS = ("/device-queue/0", "/device-queue/1")
+# ``unqueued_ms`` and its parts, in the order the engine thread passes their
+# ends; and what a program called behind another says of them
+_UNQUEUED = ("unqueued_ms", "unqueued_post_ms", "unqueued_turn_ms",
+             "unqueued_pre_ms")
+_NOTHING_UNQUEUED = dict.fromkeys(_UNQUEUED, 0.0)
 
 
 def _host_split(sp, cpu_entry, sp_dispatch, cpu_called, sp_wait, cpu_fetched
@@ -362,6 +376,18 @@ class InferenceEngineV2:
         # the rows whose token it dropped)
         self._ahead: Optional[_Underway] = None
         self._ahead_flags = (0, 0, 0)
+        # The device's queue as the engine saw it, for ``engine/program``
+        # (``_program_called``), all of it read off spans and none of it kept
+        # with tracing off: the step programs called and not yet fetched, by
+        # their step (two at most); the ``engine/step`` span under way; the
+        # latest fetch, as its ``engine/wait`` span and the ``engine/step``
+        # span it lay in, whose ``t_end`` say where the fetch returned and
+        # where its step did (None where the engine cannot say: before the
+        # first fetch, behind a failed step and behind ``_burst_decode``,
+        # whose program no span stands for)
+        self._calls: Dict[int, Tuple[float, Dict[str, Any]]] = {}
+        self._step_sp: Any = None
+        self._fetched: Optional[Tuple[Any, Any]] = None
         self.caches = {name: jnp.zeros(shape, dtype)
                        for name, (shape, dtype) in arrays.items()}
         # state bytes a row reads and writes a step, all state layers
@@ -907,7 +933,11 @@ class InferenceEngineV2:
 
     def close(self) -> None:
         """Release paging resources (promote-ahead thread, spill writer).
-        Safe to call more than once; a pagerless engine is a no-op."""
+        Safe to call more than once; a pagerless engine is a no-op.  A
+        program still under way, which no call will fetch, leaves the ring as
+        an ``engine/program`` marked ``error`` that ends here."""
+        if self._calls:
+            self._programs_dropped()
         if self.pager is not None:
             self.pager.close()
 
@@ -1566,6 +1596,85 @@ class InferenceEngineV2:
                 and self._spec_fwd is None and bool(t.active.any())
                 and not (t.gen + 1 >= t.budget)[t.active].any())
 
+    def _program_called(self, sp_dispatch, sub: Dict[str, Any],
+                        behind: Optional[_Underway] = None) -> None:
+        """The program of step ``sub["step"]`` is being called, inside its
+        ``engine/dispatch`` span (None with tracing off: nothing is kept and
+        nothing asked).  What only the engine knows, and only now, is kept by
+        the step for the program's ``engine/program`` span, which
+        ``_program_fetched`` records: whether the device still held a program
+        of this engine (``behind``: the predecessor, called and unfetched) and,
+        if so, whether that one had finished already (``late``: one
+        non-blocking ``is_ready``; the device ran dry inside ``step`` before
+        this call reached it); if not, since when it held none
+        (``unqueued_ms``: from the latest fetch's return to this call) and
+        whose time that was, in three parts that add up to it, every end a
+        ``t_start`` or ``t_end`` some span holds already: ``unqueued_post_ms``
+        (the fetch's return to its step's: ``engine/finish``, ``stage``, the
+        span's close), ``unqueued_turn_ms`` (that step's return to this
+        step's entry: the caller's) and ``unqueued_pre_ms`` (this step's entry
+        to the call: ``engine/schedule``, ``build``, ``h2d``).  Where the
+        engine does not know the latest fetch (``_fetched``) the four are left
+        out."""
+        if sp_dispatch is None:
+            return
+        called = sp_dispatch.t_start
+        if behind is not None:
+            attrs = {**sub, "behind": 1, "late": int(behind.out.is_ready()),
+                     **_NOTHING_UNQUEUED}
+        else:
+            attrs = {**sub, "behind": 0}
+            if self._fetched is not None and self._step_sp is not None:
+                sp_wait, sp_step = self._fetched  # (of a step that returned)
+                fetched, returned = sp_wait.t_end, sp_step.t_end
+                entry = self._step_sp.t_start
+                attrs.update(zip(_UNQUEUED, (
+                    (called - fetched) * 1e3, (returned - fetched) * 1e3,
+                    (entry - returned) * 1e3, (called - entry) * 1e3)))
+        self._calls[sub["step"]] = (called, attrs)
+
+    def _program_fetched(self, sub: Dict[str, Any], sp_wait) -> None:
+        """The tokens of the program of step ``sub["step"]`` have reached the
+        host, inside the ``engine/wait`` span just closed: ONE retroactive
+        ``engine/program`` span from the ``t_start`` of its
+        ``engine/dispatch`` to the ``t_end`` of this span, with what
+        ``_program_called`` kept and ``fetch_wait_ms``, this span's length
+        (about 0: the device had finished before the host asked, and the host
+        sets the pace; a step's length: the device does); its parent is the
+        step whose tokens it made, this one.  Ring-only, like
+        every retroactive span: two programs in flight overlap without
+        nesting, so in the chrome export they lie on two tracks of their own
+        (``<the stepping thread>/device-queue/0`` and ``/1``, by the step's
+        parity), beside that thread's."""
+        call = self._calls.pop(sub["step"], None)
+        if sp_wait is None or self._step_sp is None:  # (tracing off, or on
+            self._fetched = None                      # since this step began)
+            return
+        self._fetched = (sp_wait, self._step_sp)
+        if call is not None:
+            t_start, attrs = call
+            attrs["fetch_wait_ms"] = (sp_wait.t_end - sp_wait.t_start) * 1e3
+            tracer.add_span("engine/program", t_start, sp_wait.t_end,
+                            parent_id=self._step_sp.span_id, attrs=attrs,
+                            thread=sp_wait.thread
+                            + _QUEUE_TRACKS[sub["step"] % 2])
+
+    def _programs_dropped(self, sp=None) -> None:
+        """Every program called and not fetched leaves the ring as an
+        ``engine/program`` marked ``error``, so that the ring never holds a
+        call without an end: it ends where the failed ``engine/step`` ``sp``
+        did, or now (``close``: no call will fetch it).  What is queued
+        behind a failed step the engine no longer knows."""
+        t_end = time.monotonic() if sp is None else sp.t_end
+        stepped_by = threading.current_thread().name
+        for step, (t_start, attrs) in sorted(self._calls.items()):
+            tracer.add_span("engine/program", t_start, t_end,
+                            parent_id=getattr(sp, "span_id", None),
+                            attrs={**attrs, "error": True},
+                            thread=stepped_by + _QUEUE_TRACKS[step % 2])
+        self._calls.clear()
+        self._fetched = None
+
     def _call_decode(self, temperature: float, rng: Optional[jax.Array],
                      sub: Dict[str, Any], behind: Optional[_Underway] = None
                      ) -> Tuple[_Underway, Any, Optional[float]]:
@@ -1600,6 +1709,7 @@ class InferenceEngineV2:
         h2d, self._h2d = self._h2d, held
         cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        self._program_called(sp_dispatch, sub, behind)
         out, self.caches = self._decode_fwd(self.params, self.caches, *args)
         self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp_dispatch)
@@ -1651,6 +1761,7 @@ class InferenceEngineV2:
         sampled = self._split_stats(np.asarray(run.out))
         tracer.end(sp_wait)
         cpu_fetched = _thread_cpu()
+        self._program_fetched(sub, sp_wait)
         sp = tracer.begin("engine/finish", **sub)
         rows = run.rows[t.uid[run.rows] == run.uids]  # still whose they were
         dropped = len(run.rows) - len(rows)
@@ -1701,6 +1812,7 @@ class InferenceEngineV2:
         hidden_np = None
         cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        self._program_called(sp_dispatch, sub)
         if self_draft:
             emitted, alen, new_hidden, self.caches = self._spec_fwd(
                 self.params, self.spec_heads, self.caches, next_tok, ctx,
@@ -1719,6 +1831,7 @@ class InferenceEngineV2:
         alen = np.asarray(alen)
         tracer.end(sp_wait)
         cpu_fetched = _thread_cpu()
+        self._program_fetched(sub, sp_wait)
         sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
         k = self.cfg.spec_k
@@ -1780,7 +1893,23 @@ class InferenceEngineV2:
         ``engine/h2d``, before ``device_ms`` opens, and that is still the
         right start: it is a dozen slices of one small buffer, and once it
         is done the device waits for the step's program like before it, so
-        the wait the host causes ends where ``engine/dispatch`` opens."""
+        the wait the host causes ends where ``engine/dispatch`` opens.
+
+        ``device_ms`` / ``pre_ms`` / ``post_ms`` describe a STEP, not a
+        program, and misread a step whose program was under way: a decode
+        program is called in one step, behind that step's own, and fetched in
+        the next (``_decode_step_fast``), so such a step opens ``device_ms``
+        at its entry and its successor's pack and call lie inside it.  The
+        unit the device is occupied by is a program call, and
+        ``engine/program`` stands for one (``_program_called``,
+        ``_program_fetched``): one retroactive span a call of a step program,
+        from the ``t_start`` of its ``engine/dispatch`` to the ``t_end`` of
+        its ``engine/wait``, with ``kind`` and ``step`` of the step whose
+        tokens it makes, ``behind``, ``late``, ``unqueued_ms`` and its three
+        parts, and ``fetch_wait_ms``; a program nobody fetched (a failed
+        step, ``close``) ends as one marked ``error``.  Read it, not the
+        split, for when the device had nothing queued and whose time that
+        was."""
         # a decode program under way is this call's step, whatever the table
         # has come to since (``_decode_step_fast``)
         steady = self._ahead is not None or (
@@ -1801,11 +1930,14 @@ class InferenceEngineV2:
         t0 = time.monotonic()
         sp = tracer.begin("engine/step", running=running, waiting=waiting,
                           prefilling=self._prefilling, **sub)
+        self._step_sp = sp
         cpu_entry = _thread_cpu()
         try:
             out, tokens, dispatched = self._step_impl(temperature, rng, sub)
         except Exception:
             tracer.end(sp, error=True)
+            if self._calls:  # called in this step or the one before
+                self._programs_dropped(sp)
             raise
         emitted = sum(len(v) for v in out.values())
         attrs = {"emitted": emitted, "tokens": tokens,
@@ -1895,6 +2027,7 @@ class InferenceEngineV2:
         tracer.end(sp)
         cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        self._program_called(sp_dispatch, sub)
         logits, hidden, self.caches, *rest = self._fwd(
             self.params, self.caches, *batch_args, *ad_args)
         moe_stats = rest[0] if rest else None  # an MoE model's fourth
@@ -1927,6 +2060,7 @@ class InferenceEngineV2:
                      if self.cfg.spec_mode == "self_draft" else None)
         tracer.end(sp_wait)
         cpu_fetched = _thread_cpu()
+        self._program_fetched(sub, sp_wait)
 
         sp = tracer.begin("engine/finish", **sub)
         out: Dict[int, List[int]] = {}
@@ -1966,6 +2100,7 @@ class InferenceEngineV2:
             self._multi_decode[k] = build_multi_decode_forward(
                 self.model_cfg, self.cfg, k)
         t = self.table
+        self._fetched = None  # no span stands for this program
         toks, self.caches = self._multi_decode[k](
             self.params, self.caches, *self._table_inputs(),
             self._step_rng(rng), jnp.asarray(self._row_temps(temperature)),

@@ -220,16 +220,21 @@ class Tracer:
     def add_span(self, name: str, t_start: float, t_end: float,
                  trace_id: Optional[str] = None,
                  parent_id: Optional[int] = None,
-                 attrs: Optional[Dict[str, Any]] = None) -> Optional[Span]:
+                 attrs: Optional[Dict[str, Any]] = None,
+                 thread: Optional[str] = None) -> Optional[Span]:
         """Record a retroactive (already-completed) span from known
         timestamps — how the broker emits request-phase spans whose
-        endpoints were observed on different threads."""
+        endpoints were observed on different threads.  ``thread``: the track
+        of the chrome export it lies on, where that is not the recording
+        thread's: intervals that overlap without nesting (the engine's
+        ``engine/program``: two programs in flight) do not render among a
+        thread's nested spans."""
         if not self.enabled:
             return None
         sp = Span(name=name, trace_id=trace_id, span_id=next(self._ids),
                   parent_id=parent_id, t_start=t_start, t_end=t_end,
                   attrs=dict(attrs or {}),
-                  thread=threading.current_thread().name)
+                  thread=thread or threading.current_thread().name)
         with self._lock:
             sp.seq = next(self._seq)
             self._ring.append(sp)

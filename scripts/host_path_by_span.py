@@ -23,15 +23,20 @@ found them; beside them (ISSUE 50) the share that found their program under
 way (``ahead`` 1: ``staged`` = ``"ahead"``), the share that dispatched their
 successor before their own fetch (``ahead_next`` 1) and the tokens computed
 ahead and dropped (``ahead_dropped``, summed, and the steps that dropped
-any).  And ``starved``:
-the three sums ``device_starved_pct`` is made of (``pre_ms``, ``post_ms``,
-the turns that ended in a step) beside the time with nothing to run
-(``broker/idle``), in seconds, over the window and over the traced part of
-it, where the device's own idle share (``serve_device_idle_pct``) stands
-beside them and what is left over is a step's launch and the fetch's tail;
-``post_behind_s`` is the part of ``post_s`` that ran beside a program (the
-``post_ms`` of the steps with ``ahead_next`` 1), which the device did not
-wait through.
+any).  And ``starved``
+(ISSUE 53): the account of the device's queue that
+``benchmark/program_queue.py`` makes of the ``engine/program`` spans, one
+implementation with the readers ``device_unqueued_pct`` and
+``unqueued_{post,turn,pre}_pct``: the share of the interval in which there
+was work and the device held no program of it, its three parts, the time with
+nothing to run (``broker/idle``), ``long_gap_turn_pct``, the turn part of
+the gaps of 10 ms or more, which the device trace's reduction names
+"nothing to run" whatever the loop was doing, and the gaps by length
+(``gaps_by_length``: count, and per cent of the interval); over the window and over the
+traced part of it, where the device's own idle share
+(``serve_device_idle_pct``) stands beside them and what is left over
+(``residual_pct``) is a step's launch, the fetch's tail and the programs that
+came late.  A program from before ``engine/program`` gives ``{}``.
 
     chiprun -- python scripts/host_path_by_span.py --workload chat-decode-sat \
         --seed 3400000001 [--root .bench_checkout/parent]
@@ -161,32 +166,44 @@ def staging(spans) -> dict:
     return {k: round(v, 4) for k, v in out.items()}
 
 
-def starved(spans, t0: float, t1: float) -> dict:
-    """What ``device_starved_pct`` sums, and the time with nothing to run,
-    as far as each lies inside ``[t0, t1)``, in seconds."""
-    def inside(a, b):
-        return max(0.0, min(b, t1) - max(a, t0))
+def _program_queue():
+    """``benchmark/program_queue.py`` of THIS checkout, by its path: with
+    ``--root`` the package ``benchmark`` is another checkout's, which may be
+    from before the file (it imports nothing of the package)."""
+    spec = importlib.util.spec_from_file_location(
+        "program_queue", os.path.join(HERE, "benchmark", "program_queue.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
-    out = {"seconds": t1 - t0, "steps": 0, "pre_s": 0.0, "post_s": 0.0,
-           "post_behind_s": 0.0, "turn_s": 0.0, "nothing_to_run_s": 0.0}
-    for s in spans:
-        a = s["attrs"]
-        if s["name"] == "engine/step" and "pre_ms" in a:
-            out["steps"] += t0 <= s["t_end"] < t1
-            out["pre_s"] += inside(s["t_start"],
-                                   s["t_start"] + a["pre_ms"] / 1e3)
-            post = inside(s["t_end"] - a["post_ms"] / 1e3, s["t_end"])
-            out["post_s"] += post
-            out["post_behind_s"] += post * a.get("ahead_next", 0)
-        elif s["name"] == "broker/turn" and a.get("next") == "step":
-            out["turn_s"] += inside(s["t_start"], s["t_end"])
-        elif s["name"] == "broker/idle":
-            out["nothing_to_run_s"] += inside(s["t_start"], s["t_end"])
-    out["starved_pct"] = 100.0 * (out["pre_s"] + out["post_s"]
-                                  + out["turn_s"]) / out["seconds"]
-    out["nothing_to_run_pct"] = 100.0 * out["nothing_to_run_s"] / \
-        out["seconds"]
-    return {k: round(v, 4) for k, v in out.items()}
+
+def starved(spans, t0: float, t1: float) -> dict:
+    """The device's queue over ``[t0, t1)`` as ``program_queue.unqueued``
+    accounts for it, in per cent of the interval; ``{}`` where no
+    ``engine/program`` span touches it."""
+    q = _program_queue().unqueued(spans, t0, t1)
+    if q is None:
+        return {}
+    pct = 100.0 / q["seconds"]
+    parts = q["post_s"] + q["turn_s"] + q["pre_s"]
+    # the gaps before the programs called inside the interval, by length
+    gaps = [s["attrs"] for s in spans if s["name"] == "engine/program"
+            and t0 <= s["t_start"] < t1 and s["attrs"].get("unqueued_ms")]
+    long_turn = sum(a["unqueued_turn_ms"] for a in gaps
+                    if a["unqueued_ms"] >= 10.0) / 1e3
+    by_length = {}
+    for lo, hi in ((0, 2), (2, 5), (5, 10), (10, 50), (50, float("inf"))):
+        ms = [a["unqueued_ms"] for a in gaps if lo <= a["unqueued_ms"] < hi]
+        by_length[f"{lo}-{hi} ms"] = [len(ms), round(pct * sum(ms) / 1e3, 4)]
+    out = {"seconds": q["seconds"], "accounted_s": q["accounted_s"],
+           "programs": q["programs"], "unqueued_pct": pct * q["unqueued_s"],
+           "post_pct": pct * q["post_s"], "turn_pct": pct * q["turn_s"],
+           "pre_pct": pct * q["pre_s"],
+           "nobodys_pct": pct * (q["unqueued_s"] - parts),
+           "nothing_to_run_pct": pct * q["nothing_to_run_s"],
+           "long_gap_turn_pct": pct * long_turn}
+    return {**{k: round(v, 4) for k, v in out.items()},
+            "gaps_by_length": by_length}  # [count, per cent of the interval]
 
 
 def main() -> int:
@@ -238,13 +255,15 @@ def main() -> int:
     window = seen["window"]
     in_trace = starved(seen["spans"], *traced)
     idle = result["metrics"].get("serve_device_idle_pct", {}).get("value")
-    if idle is not None and in_trace["steps"]:
-        # what neither host clock sees: the launch and the fetch's tail
+    if idle is not None and in_trace:
+        # what the program's clock cannot see: the launch, the fetch's tail
+        # and the programs that came late
         in_trace["device_idle_pct"] = round(idle, 4)
-        left = idle - in_trace["starved_pct"] - in_trace["nothing_to_run_pct"]
+        left = idle - in_trace["unqueued_pct"] - in_trace["nothing_to_run_pct"]
         in_trace["residual_pct"] = round(left, 4)
-        in_trace["residual_ms_a_step"] = round(
-            left / 100.0 * in_trace["seconds"] / in_trace["steps"] * 1e3, 4)
+        in_trace["residual_ms_a_program"] = round(
+            left / 100.0 * in_trace["seconds"] / in_trace["programs"] * 1e3,
+            4)
     line = {"workload": opts.workload, "seed": opts.seed, "root": opts.root,
             "device": result["device"]["kind"],
             "by_span_ms": by_span(seen["spans"]),

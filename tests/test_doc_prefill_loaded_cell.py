@@ -5,8 +5,8 @@ whole (the schedule ``doc-prefill-loaded`` sends, every name
 spans, what ``correct`` sees of an altered token and of the control),
 imported as ``tests/test_mellum2_cell.py`` imports its rehearsal.  One test
 is replaced: the count of the cell's per-layer metrics, which that file
-fixes at PR 36's sixteen and PR 37 raised by six (a file under
-``benchmark/`` is a ``benchmark`` PR's to edit); and a second, for the same
+fixes at PR 36's sixteen, PR 37 raised by six and PR 53 by four (a file
+under ``benchmark/`` is a ``benchmark`` PR's to edit); and a second, for the same
 reason: ``test_every_listed_name_is_found`` fixes the benchmark at seven
 cells, PR 40 added the eighth and PR 42 the ninth."""
 
@@ -23,15 +23,19 @@ from test_doc_prefill_loaded import \
 WAITED_FOR = ("mixed_host_wait_ms_mean", "loop_turn_wait_ms_mean",
               "device_starved_pct", "step_interval_p90_ms",
               "attn_q_fill_pct", "step_h2d_copies_max")
+# PR 53's account of the device's queue, the four that every serving cell has
+UNQUEUED = ("device_unqueued_pct", "unqueued_post_pct", "unqueued_pre_pct",
+            "unqueued_turn_pct")
 
 
 def test_the_new_cell_reports_what_the_retired_one_reported(spec):  # noqa: F811
     assert {m["name"] for m in run.metrics_of(spec, "end_to_end", CELL)} \
         == {"ttft_p90_ms", "itl_p90_ms", "setup_s"}
     per_layer = [m["name"] for m in run.metrics_of(spec, "per_layer", CELL)]
-    # the retired cell's fifteen, PR 36's one, and PR 37's six behind them
-    assert len(per_layer) == 22 and per_layer[15] == "mixed_gap_share_pct"
-    assert tuple(per_layer[16:]) == WAITED_FOR
+    # the retired cell's fifteen, PR 36's one, PR 37's six behind them and
+    # PR 53's four behind those
+    assert len(per_layer) == 26 and per_layer[15] == "mixed_gap_share_pct"
+    assert tuple(per_layer[16:]) == WAITED_FOR + UNQUEUED
     alone = [m["name"] for m in spec["per_layer"]
              if m.get("workloads") == [CELL]]
     assert len(alone) == 7

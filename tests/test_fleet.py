@@ -483,8 +483,15 @@ def test_fleet_hang_detected_by_missed_heartbeats(fleet_pool, ref_fn,
     assert victim.inject_fault({"serving.worker.hang": "hang"})
     got += list(it)
     assert got == ref_fn([7, 1, 3], 16)
+    # the wedge fires at the worker's next heartbeat tick and the supervisor
+    # needs ``heartbeat_timeout_s`` of silence: a worker that had streamed its
+    # last tokens by then left the fleet looking whole, and healing returned
+    # before anything was detected
+    wait_until(lambda: fleet_pool.metrics.fleet["heartbeat_misses"] > misses0,
+               timeout=60.0, msg="missed heartbeats counted")
     _fleet_heal(fleet_pool)
-    assert victim.generation > gen0
+    wait_until(lambda: victim.generation > gen0, timeout=180.0,
+               msg="the hung worker's slot respawned")
     assert fleet_pool.metrics.fleet["heartbeat_misses"] > misses0
 
 
