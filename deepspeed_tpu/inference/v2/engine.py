@@ -55,18 +55,42 @@ _StepResult = Tuple[Dict[int, List[int]], int,
 
 @dataclasses.dataclass
 class _Underway:
-    """A decode program that has been called and whose tokens no call has
-    fetched yet: what it returned (``out``: the tokens on the device, an MoE
-    model's stats behind them), the rows it ran and whose they were then
-    (``uids``: a row retired since has lost its own), and what its
-    ``engine/step`` span will say of it: the kind's counters, its copies and
-    bytes."""
+    """A step program that has been called and whose tokens no call has
+    fetched yet, of either kind.  ``out``: what it returned, the tokens on the
+    device with an MoE model's stats behind them (a mixed program's: what its
+    sampler returned, None until that is enqueued, ``InferenceEngineV2.
+    _sample``); ``counts`` / ``h2d`` / ``tokens``: what its ``engine/step``
+    span will say of it (the kind's counters, its copies and bytes, the tokens
+    in its batch).  A decode program ran ``rows`` of the table, a mixed one
+    its ``picks`` (``sampler``: what its sampler is still to be called with,
+    the logits, the temperatures and seeds by pick, an MoE model's stats;
+    ``hidden``: what the self-draft heads propose from, fetched with the
+    tokens).
 
-    out: jax.Array
-    rows: "np.ndarray"
-    uids: "np.ndarray"
+    ``_advance`` then says who gets a token from it, an entry a sequence:
+    ``rows`` / ``uids`` (its row of the table, and whose that was then: a row
+    retired since has lost its own), ``src`` (where in ``out`` its token lies:
+    the row itself, or its place among the picks), ``pos`` (the token's
+    position in the sequence), ``at`` (where the table's history keeps a
+    place for it; past its end: the descriptor does) and ``ends`` (the
+    sequence is over with it); and ``dropped``: how many were retired
+    between its call and now, whose token nobody gets."""
+
+    kind: str
+    out: Optional[jax.Array]
     counts: Dict[str, Any]
     h2d: Tuple[int, int]
+    tokens: int = 0
+    rows: Any = None
+    uids: Any = None
+    picks: Any = ()
+    sampler: Any = None
+    hidden: Any = None
+    src: Any = None
+    pos: Any = None
+    at: Any = None
+    ends: Any = None
+    dropped: int = 0
 
 
 def _thread_cpu() -> Optional[float]:
@@ -356,26 +380,35 @@ class InferenceEngineV2:
         self._staged: Optional[Tuple[Any, Dict[str, jax.Array], Any]] = None
         self._stage_use: Optional[str] = None
         self._stage_dropped = 0
-        # Decode-ahead: the decode program that the call before dispatched
-        # BEHIND its own, before it fetched its own tokens, and that the next
-        # call of ``step`` takes for its step (``_decode_step_fast``).  Its
-        # token ids never saw the host: they are the array its predecessor
-        # returned.  While it is set the table reads what it read on the
-        # parent between the same two calls (the step before is whole: counted,
-        # recorded, trimmed), and everything that touches a pool from the host
-        # does so through ``self.caches``, which this program returned: the
-        # device runs it behind the program, so a block that a ``cancel``
-        # frees under it, a demoted or exported block (hashed content ends
-        # before the slot the program writes), a promoted or imported one (a
-        # later owner's writes are dispatched later) need no wait.  Nor do
-        # ``close`` (the pager's alone) and ``swap_params`` (a drained
-        # engine's, and the program holds the weights it was called with):
-        # what is left of the program is dropped by the next ``step``.
+        # Two steps in flight: the step program, of either kind, that the call
+        # before called BEHIND its own, before it fetched its own tokens, and
+        # that the next call of ``step`` takes for its step (``_step_impl``).
+        # The token ids its decode rows read never saw the host: they are in
+        # the array its predecessor returned, and its buffer says where
+        # (``_promise``).  While it is set the descriptors and the table read
+        # what they read on an engine that never goes ahead between the same
+        # two calls (the step before is whole: counted, recorded, trimmed,
+        # what it ended finished), and everything that touches a pool from
+        # the host does so through ``self.caches``, which this program
+        # returned: the device runs it behind the program, so a block that a
+        # ``cancel`` frees under it, a demoted or exported block (hashed
+        # content ends before the slots the program writes), a promoted or
+        # imported one (a later owner's writes are dispatched later) need no
+        # wait.  Nor do ``close`` (the pager's alone) and ``swap_params`` (a
+        # drained engine's, and the program holds the weights it was called
+        # with): what is left of the program is dropped by the next ``step``.
         # ``_ahead_flags``: of the step under way, for its span (whether it
-        # found its program under way, whether it dispatched its successor,
-        # the rows whose token it dropped)
+        # found its program under way, whether it called its successor, the
+        # rows whose token it dropped; None: it ran no such program).
+        # ``_out``: what the latest step program returned, fetched or not: the
+        # unpack program takes it beside every buffer, so that it has one
+        # shape whoever is behind whom (``_to_device``).  ``_kept_out``: the
+        # head of the waiting queue that the latest ``_schedule`` could not
+        # admit (its uid; 0: none)
         self._ahead: Optional[_Underway] = None
-        self._ahead_flags = (0, 0, 0)
+        self._ahead_flags: Optional[Tuple[int, int, int]] = None
+        self._out: Optional[jax.Array] = None
+        self._kept_out = 0
         # The device's queue as the engine saw it, for ``engine/program``
         # (``_program_called``), all of it read off spans and none of it kept
         # with tracing off: the step programs called and not yet fetched, by
@@ -436,6 +469,7 @@ class InferenceEngineV2:
         # of those, the ones that found their program under way, and the
         # tokens computed ahead for rows that were retired before the fetch
         self.ahead_steps = 0
+        self.mixed_ahead_steps = 0  # mixed steps that found theirs under way
         self.ahead_dropped = 0
         self.burst_steps = 0  # telemetry: multi-token burst programs run
         self._uid = 0
@@ -1065,10 +1099,14 @@ class InferenceEngineV2:
         """Dynamic SplitFuse: decode tokens first, then prefill chunks."""
         budget = self.cfg.max_tokens_per_step
         picks: List[Tuple[SequenceDescriptor, int]] = []
-        # running sequences: 1 decode token each (or remaining prefill)
+        # running sequences: 1 decode token each (or remaining prefill); one
+        # that ends with the token under way (``_advance`` took its row out
+        # of the steps to come) holds its place until that token's fetch
         for seq in list(self.running.values()):
             if len(picks) >= self.cfg.max_seqs or budget <= 0:
                 break
+            if not self.table.active[self.table.row_of[seq.uid]]:
+                continue
             n = min(seq.cur_len - seq.seen_tokens, budget) or 1
             # (a window that tumbles ends a chunk at its edge)
             n = min(n, budget, self._managers[-1].chunk_cap(seq.seen_tokens))
@@ -1080,7 +1118,10 @@ class InferenceEngineV2:
         # request's ENTIRE block budget (prompt + max_new_tokens) up front so
         # an admitted sequence can never stall mid-decode — without this the
         # pool can be exhausted by half-admitted requests and livelock.
-        while self.waiting and budget > 0 and len(picks) < self.cfg.max_seqs:
+        # (a row of the table each: one that ended with the token under way
+        # has not given its own back yet)
+        while (self.waiting and budget > 0 and len(picks) < self.cfg.max_seqs
+               and len(self.running) < self.cfg.max_seqs):
             seq = self.waiting[0]
             # draft mode can't take prefix hits: skipped prefill would leave
             # the DRAFT cache without KV for the shared tokens (the tree only
@@ -1110,6 +1151,7 @@ class InferenceEngineV2:
             self._prefilling += 1
             picks.append((seq, n))
             budget -= n
+        self._kept_out = self.waiting[0].uid if self.waiting else 0
         return picks
 
     def _reserve(self, seq: SequenceDescriptor, total_tokens: int,
@@ -1431,10 +1473,12 @@ class InferenceEngineV2:
 
     def _decode_fields(self, temperature: float) -> Dict[str, "np.ndarray"]:
         """The decode step's host inputs off the SoA table, by the names of
-        its layout's fields (padded static shapes; inactive rows carry ctx
-        0)."""
+        its layout's fields (padded static shapes; inactive rows carry
+        zeros, a free row's and a row's that ended with the token under
+        way)."""
         t = self.table
-        fields = {"token_ids": t.next_tok, "position_ids": t.ctx,
+        fields = {"token_ids": t.next_tok * t.active,
+                  "position_ids": t.ctx * t.active,
                   "context_lens": (t.ctx + 1) * t.active,
                   "temps": self._row_temps(temperature), "seeds": t.seed,
                   "block_tables": t.block_tables}
@@ -1457,11 +1501,16 @@ class InferenceEngineV2:
         """The step's one host-to-device copy: ``buf`` goes to the program
         that takes it apart on the device as it is, and the call makes the
         copy (one trip into the runtime, not two) → its fields as device
-        arrays.  ``out``: what the decode program before returned, where the
-        step is dispatched behind it: the same program takes its
-        ``token_ids`` from there, on the device."""
+        arrays.  ``out``: what the step program before returned, where this
+        step is called behind it: the unpack program takes from there, on the
+        device, the token ids the buffer only points at (``_promise``).  A
+        buffer that points at nothing is handed the latest program's output
+        all the same (``_out``), fetched long ago or not: the unpack program
+        then has ONE shape a layout from an engine's second step on, whatever
+        the traffic later makes of who is called behind whom."""
         copies, nbytes = self._h2d or (0, 0)
         self._h2d = (copies + 1, nbytes + buf.nbytes)
+        out = self._out if out is None else out
         unpack = build_unpack(layout)
         return unpack(buf) if out is None else unpack(buf, out)
 
@@ -1476,7 +1525,7 @@ class InferenceEngineV2:
         """At the end of a step, once its bookkeeping is done: where the next
         step will be a decode step unless the caller changes the table first
         (nothing waits, nothing prefills, no speculation), make that step's
-        one copy NOW, as ``_decode_step_fast`` would at its head: open the
+        one copy NOW, as ``_call_decode`` would at its head: open the
         windowed pool's next blocks, pack the buffer, hand it to the unpack
         program.  ``step`` then returns to a broker that wakes a streaming
         thread a row, and the next step's first trip into the runtime is its
@@ -1555,46 +1604,59 @@ class InferenceEngineV2:
         if self._step_key is None:
             self._rng, self._step_key = jax.random.split(self._rng)
 
-    def _record_rows(self, rows: "np.ndarray", sel: "np.ndarray") -> None:
-        """``sel``: (k, ns) new tokens of ``rows``, as they arrive: into the
-        rows' history, the last of them the next input."""
+    def _advance_rows(self, sel: "np.ndarray") -> "np.ndarray":
+        """A burst's bookkeeping, vectorized.  ``sel``: (k, ns) new tokens
+        for the active rows, into the rows' history, the last of them the
+        next input; retires sequences whose budget is exhausted; returns the
+        active row indices."""
         t = self.table
+        rows = np.nonzero(t.active)[0]
         k = sel.shape[0]
         t.hist[rows[:, None],
                t.hist_len[rows][:, None] + np.arange(k)[None, :]] = sel.T
         t.hist_len[rows] += k
         t.next_tok[rows] = sel[-1]
-
-    def _advance_rows(self, sel: "np.ndarray") -> "np.ndarray":
-        """Vectorized post-decode bookkeeping. ``sel``: (k, ns) new tokens
-        for the active rows; retires sequences whose budget is exhausted;
-        returns the active row indices."""
-        t = self.table
-        rows = np.nonzero(t.active)[0]
-        k = sel.shape[0]
-        self._record_rows(rows, sel)
         t.ctx[rows] += k
         t.gen[rows] += k
         for r in rows[t.gen[rows] >= t.budget[rows]]:
             self._finish(t.seq_at[int(r)])
         return rows
 
-    def _may_go_ahead(self, rng: Optional[jax.Array]) -> bool:
-        """Whether the decode step BEHIND the one whose program was just
-        called may be dispatched before that one's tokens are fetched, by
-        what the engine sees now: the caller leaves the keys to the engine
-        (a key handed to ``step`` is one step's, and the next call's is not
-        known yet), nothing waits, nothing prefills, no speculation, and no
-        active row reaches its budget with the token under way: then a row
-        frees, its caller admits a request, and the next step is a mixed
-        step.  Such a step needs of its predecessor only the token ids, which
-        never leave the device; positions, context lengths, tables,
-        temperatures and seeds the host knows now (a row's whole budget of
-        blocks was set aside at admission)."""
-        t = self.table
-        return (rng is None and not self.waiting and not self._prefilling
-                and self._spec_fwd is None and bool(t.active.any())
-                and not (t.gen + 1 >= t.budget)[t.active].any())
+    def _next_kind(self) -> Optional[str]:
+        """The kind of the step the engine would run now, by what the
+        descriptors and the table say; None: there is nothing to run."""
+        if self.waiting or self._prefilling:
+            return "mixed"
+        if not self.table.active.any():
+            return None
+        return "spec" if self._spec_fwd is not None else "decode"
+
+    def _may_go_ahead(self, rng: Optional[jax.Array], behind: _Underway,
+                      kind: Optional[str]) -> bool:
+        """Whether the step of ``kind`` BEHIND the one whose program is under
+        way (``behind``, advanced already) may be called before that one's
+        tokens are fetched, by what the engine sees now.  Whose step it is:
+        the caller leaves the keys to the engine (a key handed to ``step`` is
+        one step's, and the next call's is not known yet), no speculation,
+        and there is a step to run.  Whom it may keep waiting: a request put
+        between the two calls finds the step called and joins the one after,
+        so a step goes ahead only where an arrival could not have joined it
+        anyway: every sequence slot is taken (the rows that end with the
+        token under way hold theirs until its fetch) or the waiting queue's
+        head is one the latest ``_schedule`` had to leave there; and, as ever
+        since two steps were in flight, a decode step behind a decode step
+        with nothing waiting, which costs an arrival one decode step.  Such a
+        step needs of its predecessor only the token ids, which never leave
+        the device (``_promise``); chunk tokens, positions, context lengths,
+        tables, slots, temperatures and seeds the host knows now (a row's
+        whole budget of blocks was set aside at admission)."""
+        if rng is not None or self._spec_fwd is not None or kind is None:
+            return False
+        if behind.kind == kind == "decode":
+            return True
+        return (len(self.running) + len(self.waiting) >= self.cfg.max_seqs
+                or bool(self.waiting)
+                and self.waiting[0].uid == self._kept_out)
 
     def _program_called(self, sp_dispatch, sub: Dict[str, Any],
                         behind: Optional[_Underway] = None) -> None:
@@ -1675,20 +1737,51 @@ class InferenceEngineV2:
         self._calls.clear()
         self._fetched = None
 
-    def _call_decode(self, temperature: float, rng: Optional[jax.Array],
-                     sub: Dict[str, Any], behind: Optional[_Underway] = None
-                     ) -> Tuple[_Underway, Any, Optional[float]]:
-        """Pack and call the decode program of the step the table describes
-        (``table.ctx`` / ``active`` / ``seq_at`` are that step's when
-        ``_decode_fwd`` is called: the benchmark's taps read them there) →
-        the program under way, and where its step's split opens: its
-        ``engine/dispatch`` span and the thread's CPU clock there.
-        ``behind``: the program before it, still under way: its tokens are
-        this one's ``token_ids``, as the array it returned, and the staged
-        buffer is not asked for.  The engine's ``_h2d`` is left as it was:
-        the copy made here is the returned program's."""
-        t = self.table
+    # -- a step in two halves: CALL (``_call``: schedule, build, pack, the
+    # one copy, the program; a mixed program's sampler with ``_sample``) and
+    # FETCH (``_fetch``: wait for the tokens, record them, finish what
+    # ended), with ``_advance`` between them: what the step's token makes of
+    # the descriptors and the table that is known without the token.  Used
+    # the same way whether the successor is called between the halves or not
+    # (``_step_impl``)
+
+    @staticmethod
+    def _promise(src):
+        """What stands for a token that is still on the device, wherever its
+        id would stand (a descriptor's ``tokens``, the table's ``hist`` and
+        ``next_tok``, and so a step's buffer): where in the output of the
+        program under way it lies, as a negative id (``-1``: its first
+        entry).  The unpack program of the step called behind that program
+        reads it there (``programs.build_unpack``); ``_fetch`` writes the id
+        in its place.  It lives inside one call of ``step``: between two
+        calls every token the host holds is an id."""
+        return -1 - src
+
+    def _call(self, kind: str, temperature: float, rng: Optional[jax.Array],
+              sub: Dict[str, Any], behind: Optional[_Underway] = None
+              ) -> Optional[Tuple[_Underway, Any, Optional[float]]]:
+        """Call the program of the step of ``kind`` that the descriptors and
+        the table describe → the program under way, and where its step's
+        split opens: its ``engine/dispatch`` span and the thread's CPU clock
+        there; None where a mixed step finds nothing to run.  ``behind``: the
+        program before it, still under way and advanced: the token ids it
+        owes are read from what it returned, on the device.  The engine's
+        ``_h2d`` is left as it was: the copy made here is the returned
+        program's."""
         held, self._h2d = self._h2d, None
+        try:
+            call = self._call_decode if kind == "decode" else self._call_mixed
+            return call(temperature, rng, sub, behind)
+        finally:
+            self._h2d = held
+
+    def _call_decode(self, temperature: float, rng: Optional[jax.Array],
+                     sub: Dict[str, Any], behind: Optional[_Underway]):
+        """``_call`` of a decode step: its inputs ARE the table's arrays
+        (``table.ctx`` / ``active`` / ``seq_at`` are that step's own when
+        ``_decode_fwd`` is called: the benchmark's taps read them there).
+        Called behind nothing it asks for the staged buffer first."""
+        t = self.table
         sp = tracer.begin("engine/h2d", **sub)
         if self._growing:
             self._window_open_blocks()
@@ -1706,77 +1799,187 @@ class InferenceEngineV2:
         if self.adapter_stack is not None:
             args += (self.adapter_stack, f["row_adapter"])
         tracer.end(sp)
-        h2d, self._h2d = self._h2d, held
         cpu_called = _thread_cpu()
         sp_dispatch = tracer.begin("engine/dispatch", **sub)
         self._program_called(sp_dispatch, sub, behind)
         out, self.caches = self._decode_fwd(self.params, self.caches, *args)
+        self._out = out
         self._split_ahead()  # the next step's key, behind this program
         tracer.end(sp_dispatch)
         rows = np.nonzero(t.active)[0]
-        return (_Underway(out, rows, t.uid[rows], counts, h2d), sp_dispatch,
+        return (_Underway("decode", out, counts, self._h2d, len(rows),
+                          rows=rows, uids=t.uid[rows]), sp_dispatch,
                 cpu_called)
 
-    def _decode_step_fast(self, temperature: float, rng: Optional[jax.Array],
-                          sub: Dict[str, Any]) -> _StepResult:
-        """Steady-state decode: inputs ARE the table arrays; bookkeeping is
-        vectorized; Python touches only sequences that just completed.
+    def _call_mixed(self, temperature: float, rng: Optional[jax.Array],
+                    sub: Dict[str, Any], behind: Optional[_Underway]):
+        """``_call`` of a mixed step: Dynamic SplitFuse over the descriptors
+        (at the call of ``_fwd`` the picks' descriptors read as that step's
+        own: ``seen_tokens`` where its chunk begins, ``cur_len`` with the
+        place of a token still under way).  The sampler is not enqueued here
+        (``_sample``): ``rng`` is its step's, asked for there."""
+        sp = tracer.begin("engine/schedule", **sub)
+        self._flush_table()
+        picks = self._schedule()
+        tracer.end(sp)
+        if not picks:
+            if self.table.active.any():
+                raise RuntimeError(
+                    "scheduler made no progress with running sequences — "
+                    "KV reservation invariant violated (bug)")
+            return None
+        if self._spec_fwd is not None:
+            self.spec_fallback += 1  # prefill/mixed step: no speculation
+        sp = tracer.begin("engine/build", **sub)
+        batch = self.builder.build(picks)
+        tracer.end(sp)
+        sp = tracer.begin("engine/h2d", **sub)
+        f = self._to_device(self.builder.layout, batch.packed,
+                            None if behind is None else behind.out)
+        counts = self._count(batch.chunk_start, batch.chunk_len, True)
+        batch_args = (
+            f["token_ids"], f["position_ids"], f["seq_index"],
+            self._tables(f), f["context_lens"], f["logits_rows"],
+            f["chunk_start"], f["chunk_len"])
+        if batch.state_slots is not None:  # a model with state layers:
+            # behind the two adapter arguments, which such a model never has
+            batch_args += (None, None, f["state_slots"])
+        ad_args = ()
+        if self.adapter_stack is not None:
+            # the batch's rows are in picks order (seq_index indexes into
+            # the pick rows, not the SoA table): the builder's slot vector
+            ad_args = (self.adapter_stack, f["row_adapter"])
+        tracer.end(sp)
+        cpu_called = _thread_cpu()
+        sp_dispatch = tracer.begin("engine/dispatch", **sub)
+        self._program_called(sp_dispatch, sub, behind)
+        logits, hidden, self.caches, *rest = self._fwd(
+            self.params, self.caches, *batch_args, *ad_args)
+        if self.cfg.spec_mode == "draft":
+            # mirror every target KV write into the draft cache (same block
+            # tables, its own pool array) so the draft scan can decode from
+            # position ctx without ever re-prefilling
+            _, _, self._draft_caches, *_ = self._draft_fwd(
+                self.draft_params, self._draft_caches, *batch_args)
+        tracer.end(sp_dispatch)
+        # per-row selection mirrors the jitted decode path: pick rows carry
+        # their request's pinned temperature/seed (else the temperature of
+        # the call that called the program), padding rows stay greedy
+        temps = np.zeros(self.cfg.max_seqs, np.float32)
+        seeds = np.zeros(self.cfg.max_seqs, np.int32)
+        for row, (seq, _) in enumerate(picks):
+            temps[row] = (temperature if seq.temperature is None
+                          else seq.temperature)
+            seeds[row] = np.int32(np.uint32(seq.seed & 0xFFFFFFFF))
+        return (_Underway(
+            "mixed", None, counts, self._h2d, sum(n for _, n in picks),
+            picks=picks, hidden=hidden,
+            # (an MoE model's step stats ride fourth)
+            sampler=(logits, temps, seeds, rest[0] if rest else None)),
+            sp_dispatch, cpu_called)
 
-        Two steps in flight: the step's program is the one the call before
-        dispatched ahead, or is called here; then, where ``_may_go_ahead``,
-        the NEXT step's program is called behind it, and only then are this
-        step's tokens fetched, recorded and returned.  The fetch's tail, the
-        bookkeeping, the caller's turn and the next entry then run beside a
-        program.  For the step dispatched ahead the table's counters move
-        before its call (``ctx`` / ``gen``: what this step's token makes of
-        them, known without the token) and the window's blocks are trimmed
-        and opened for it; ``hist`` / ``next_tok`` are filled when the tokens
-        arrive.  What happens to the table between two calls (``put``,
-        ``cancel``, a stop token) finds the program under way already: it is
-        fetched whole by the next call, the tokens of rows retired since are
-        dropped and counted, and a request put since waits one step."""
-        self.fast_steps += 1
+    def _sample(self, run: _Underway, rng: Optional[jax.Array],
+                sub: Dict[str, Any]) -> None:
+        """Enqueue a mixed program's eager sampler behind it (a dozen small
+        programs, 15 ms and more of the engine thread): right after the call
+        where the step was called at its own turn, and at the entry of the
+        step that takes it where it was called behind another, so that the
+        predecessor's fetch does not stand behind it and its own successor's
+        unpack program still finds ``out``."""
+        sp = tracer.begin("engine/sample", **sub)
+        (logits, temps, seeds, moe_stats), run.sampler = run.sampler, None
+        run.out = self._out = _with_stats(
+            sample_rows(tfm.next_token_logits(logits, self.model_cfg),
+                        jnp.asarray(temps), self._step_rng(rng),
+                        jnp.asarray(seeds)), moe_stats)
+        self._split_ahead()  # the next step's key, behind this program
+        tracer.end(sp)
+
+    def _advance(self, run: _Underway) -> None:
+        """What the token ``run`` computes makes of the descriptors and the
+        table that is known without it, and who gets one (``_Underway``): a
+        decode row's ``ctx`` / ``gen``, a pick's ``seen_tokens``, a prefill
+        that ended with the step (``in_decode``, ``_prefilling``), the
+        window's trim; the token's place is kept by a ``_promise``.  A
+        sequence that ends with the token leaves the steps to come here (its
+        row goes inactive) and is finished at the fetch, with the token: no
+        block and no slot is given back early.  A sequence retired since the
+        call (a ``cancel``, a stop token) is passed over."""
         t = self.table
-        run, self._ahead = self._ahead, None
-        found = run is not None
-        if found:  # its split opens at the call's entry (``step``)
-            sp_dispatch = cpu_called = None
-            self.ahead_steps += 1
-            self._stage_use = "ahead"
-        else:
-            run, sp_dispatch, cpu_called = self._call_decode(temperature, rng,
-                                                             sub)
-        self._h2d, self._step_counts = run.h2d, run.counts
-        ahead = self._may_go_ahead(rng)
-        if ahead:
-            # this step's token counted before it is known; no row ends here
-            t.ctx[t.active] += 1
-            t.gen[t.active] += 1
+        if run.kind == "decode":
+            rows = run.rows[t.uid[run.rows] == run.uids]
+            run.dropped = len(run.rows) - len(rows)
+            t.ctx[rows] += 1
+            t.gen[rows] += 1
+            run.rows, run.uids, run.src = rows, t.uid[rows], rows
+            run.pos, run.at = t.ctx[rows].copy(), t.hist_len[rows].copy()
+            t.hist[rows, run.at] = t.next_tok[rows] = self._promise(rows)
+            t.hist_len[rows] += 1
+            run.ends = t.gen[rows] >= t.budget[rows]
+            run.tokens = len(rows)
+            t.active[rows[run.ends]] = False
             if self._windowed is not None:
                 self._window_trim_rows()
-            self._ahead, _, _ = self._call_decode(
-                temperature, None, {"kind": "decode", "step": self.steps + 1},
-                behind=run)
+        else:
+            rows, src, ends = [], [], []
+            for i, (seq, n) in enumerate(run.picks):
+                last = seq.seen_tokens + n >= seq.cur_len  # gets a token
+                if seq.done:
+                    run.dropped += last
+                    continue
+                seq.seen_tokens += n
+                if self._windowed is not None:  # what fell behind the window
+                    self._windowed.trim(seq, seq.seen_tokens)
+                if last:
+                    seq.tokens.append(self._promise(i))
+                    seq.generated += 1
+                    if not seq.in_decode:
+                        seq.in_decode = True
+                        self._prefilling -= 1
+                    rows.append(t.row_of[seq.uid])
+                    src.append(i)
+                    ends.append(seq.generated >= seq.max_new_tokens)
+                t.sync(seq)
+            run.rows = np.array(rows, np.int64)
+            run.src, run.ends = np.array(src, np.int64), np.array(ends, bool)
+            run.uids, run.pos = t.uid[run.rows], t.ctx[run.rows].copy()
+            run.at = np.full(len(rows), t.hist.shape[1])  # the descriptors
+            t.active[run.rows[run.ends]] = False
+        self.ahead_dropped += run.dropped
+
+    def _fetch(self, run: _Underway, sub: Dict[str, Any]
+               ) -> Tuple[Dict[int, List[int]], Any, Optional[float]]:
+        """The tokens of ``run`` (advanced) reach the host → the tokens by
+        uid, the ``engine/wait`` span and the thread's CPU clock where it
+        closed.  Each token goes where its promise stands: the table's
+        ``next_tok`` and its history, or the descriptor where a mixed step
+        has flushed that since; then the sequences that end with it are
+        finished."""
+        t = self.table
         sp_wait = tracer.begin("engine/wait", **sub)
         sampled = self._split_stats(np.asarray(run.out))
+        hidden = (np.asarray(run.hidden) if run.hidden is not None
+                  and self.cfg.spec_mode == "self_draft" else None)
         tracer.end(sp_wait)
         cpu_fetched = _thread_cpu()
         self._program_fetched(sub, sp_wait)
         sp = tracer.begin("engine/finish", **sub)
-        rows = run.rows[t.uid[run.rows] == run.uids]  # still whose they were
-        dropped = len(run.rows) - len(rows)
-        self.ahead_dropped += dropped
-        self._ahead_flags = (int(found), int(ahead), dropped)
-        sel = sampled[rows].astype(np.int32)[None, :]  # (1, ns)
-        out = {t.seq_at[int(r)].uid: [int(s)] for r, s in zip(rows, sel[0])}
-        if ahead:
-            self._record_rows(rows, sel)
-        else:  # (the active rows are ``rows``: none was admitted since)
-            self._advance_rows(sel)
-            if self._windowed is not None:
-                self._window_trim_rows()
+        rows, at, src, ends = run.rows, run.at, run.src, run.ends
+        toks = sampled[src].astype(np.int32)
+        t.next_tok[rows] = toks
+        held = t.hist_len[rows] > at  # (a flush leaves no history)
+        t.hist[rows[held], at[held]] = toks[held]
+        for r, p, tok in zip(rows[~held], run.pos[~held], toks[~held]):
+            t.seq_at[int(r)].tokens[int(p)] = int(tok)
+        out = {int(u): [int(tok)] for u, tok in zip(run.uids, toks)}
+        if hidden is not None:
+            # hidden at the position whose lm head produced the token — the
+            # state the self-draft heads will propose from
+            self._spec_hidden[rows[~ends]] = hidden[src[~ends]]
+        for r in rows[ends]:
+            self._finish(t.seq_at[int(r)])
         tracer.end(sp)
-        return out, len(rows), (sp_dispatch, cpu_called, sp_wait, cpu_fetched)
+        return out, sp_wait, cpu_fetched
 
     def _split_stats(self, fetched: "np.ndarray") -> "np.ndarray":
         """The tokens of a step's one fetch; an MoE model's two stats behind
@@ -1896,10 +2099,17 @@ class InferenceEngineV2:
         the wait the host causes ends where ``engine/dispatch`` opens.
 
         ``device_ms`` / ``pre_ms`` / ``post_ms`` describe a STEP, not a
-        program, and misread a step whose program was under way: a decode
-        program is called in one step, behind that step's own, and fetched in
-        the next (``_decode_step_fast``), so such a step opens ``device_ms``
-        at its entry and its successor's pack and call lie inside it.  The
+        program, and misread a step whose program was under way: a program of
+        either kind is called in one step, behind that step's own, and fetched
+        in the next (``_step_impl``), so such a step opens ``device_ms`` at
+        its entry and its successor's schedule, pack and call lie inside it
+        (a mixed step's opens with its own ``engine/sample``; its
+        ``engine/schedule``, ``build``, ``h2d`` and ``dispatch`` lie in the
+        step before, under its own ``kind`` and ``step``).  Every step that
+        ran such a program says ``ahead`` (it found its program under way),
+        ``ahead_next`` (it called its successor before its own fetch) and
+        ``ahead_dropped`` (the rows retired between the two calls, whose token
+        nobody gets); ``kind`` is the fetched program's.  The
         unit the device is occupied by is a program call, and
         ``engine/program`` stands for one (``_program_called``,
         ``_program_fetched``): one retroactive span a call of a step program,
@@ -1910,12 +2120,11 @@ class InferenceEngineV2:
         step, ``close``) ends as one marked ``error``.  Read it, not the
         split, for when the device had nothing queued and whose time that
         was."""
-        # a decode program under way is this call's step, whatever the table
-        # has come to since (``_decode_step_fast``)
-        steady = self._ahead is not None or (
-            not self.waiting and self.running and self._prefilling == 0)
-        kind = (("spec" if self._spec_fwd is not None else "decode")
-                if steady else "mixed")
+        # a program under way is this call's step, whatever the engine has
+        # come to since (``_step_impl``); with nothing to run it is a mixed
+        # step that schedules nothing
+        kind = (self._ahead.kind if self._ahead is not None
+                else self._next_kind() or "mixed")
         running, waiting = self.num_running, len(self.waiting)
         prop0, acc0 = self.spec_proposed, self.spec_accepted
         self.steps += 1
@@ -1925,6 +2134,7 @@ class InferenceEngineV2:
         self._h2d = None
         self._stage_use = None
         self._stage_dropped = 0
+        self._ahead_flags = None
         if kind != "decode":  # what was staged for a decode step: dropped
             self._take_staged()
         t0 = time.monotonic()
@@ -1952,6 +2162,7 @@ class InferenceEngineV2:
             attrs["h2d_copies"], attrs["h2d_bytes"] = self._h2d
         if self._stage_use is not None:  # a decode step: whose copy it ran on
             attrs["staged"] = self._stage_use
+        if self._ahead_flags is not None:  # it ran a program that may go ahead
             (attrs["ahead"], attrs["ahead_next"],
              attrs["ahead_dropped"]) = self._ahead_flags
         if self._stage_dropped:  # staged for this step and not what it needs
@@ -1986,107 +2197,53 @@ class InferenceEngineV2:
 
     def _step_impl(self, temperature: float, rng: Optional[jax.Array],
                    sub: Dict[str, Any]) -> _StepResult:
-        """The step body.  ``sub`` is what each of its spans carries."""
-        if sub["kind"] != "mixed":
-            # steady state: every running sequence is decoding — SoA path
-            if self._spec_fwd is not None:
-                return self._spec_decode_step(temperature, rng, sub)
-            return self._decode_step_fast(temperature, rng, sub)
-        sp = tracer.begin("engine/schedule", **sub)
-        self._flush_table()
-        picks = self._schedule()
-        tokens = sum(n for _, n in picks)
-        tracer.end(sp)
-        if not picks:
-            if self.running:
-                raise RuntimeError(
-                    "scheduler made no progress with running sequences — "
-                    "KV reservation invariant violated (bug)")
-            return {}, 0, None
-        if self._spec_fwd is not None:
-            self.spec_fallback += 1  # prefill/mixed step: no speculation
-        sp = tracer.begin("engine/build", **sub)
-        batch = self.builder.build(picks)
-        tracer.end(sp)
-        sp = tracer.begin("engine/h2d", **sub)
-        f = self._to_device(self.builder.layout, batch.packed)
-        self._step_counts = self._count(batch.chunk_start, batch.chunk_len,
-                                        True)
-        batch_args = (
-            f["token_ids"], f["position_ids"], f["seq_index"],
-            self._tables(f), f["context_lens"], f["logits_rows"],
-            f["chunk_start"], f["chunk_len"])
-        if batch.state_slots is not None:  # a model with state layers:
-            # behind the two adapter arguments, which such a model never has
-            batch_args += (None, None, f["state_slots"])
-        ad_args = ()
-        if self.adapter_stack is not None:
-            # the batch's rows are in picks order (seq_index indexes into
-            # the pick rows, not the SoA table): the builder's slot vector
-            ad_args = (self.adapter_stack, f["row_adapter"])
-        tracer.end(sp)
-        cpu_called = _thread_cpu()
-        sp_dispatch = tracer.begin("engine/dispatch", **sub)
-        self._program_called(sp_dispatch, sub)
-        logits, hidden, self.caches, *rest = self._fwd(
-            self.params, self.caches, *batch_args, *ad_args)
-        moe_stats = rest[0] if rest else None  # an MoE model's fourth
-        if self.cfg.spec_mode == "draft":
-            # mirror every target KV write into the draft cache (same block
-            # tables, its own pool array) so the draft scan can decode from
-            # position ctx without ever re-prefilling
-            _, _, self._draft_caches, *_ = self._draft_fwd(
-                self.draft_params, self._draft_caches, *batch_args)
-        tracer.end(sp_dispatch)
-        # per-row selection mirrors the jitted decode path: pick rows carry
-        # their request's pinned temperature/seed, padding rows stay greedy
-        # (its inputs are made after the dispatch, while the device works)
-        sp = tracer.begin("engine/sample", **sub)
-        temps = np.zeros(self.cfg.max_seqs, np.float32)
-        seeds = np.zeros(self.cfg.max_seqs, np.int32)
-        for row, (seq, _) in enumerate(picks):
-            temps[row] = (temperature if seq.temperature is None
-                          else seq.temperature)
-            seeds[row] = np.int32(np.uint32(seq.seed & 0xFFFFFFFF))
-        sampled = _with_stats(
-            sample_rows(tfm.next_token_logits(logits, self.model_cfg),
-                        jnp.asarray(temps), self._step_rng(rng),
-                        jnp.asarray(seeds)), moe_stats)
-        self._split_ahead()  # the next step's key, behind this program
-        tracer.end(sp)
-        sp_wait = tracer.begin("engine/wait", **sub)
-        sampled = self._split_stats(np.asarray(sampled))
-        hidden_np = (np.asarray(hidden)
-                     if self.cfg.spec_mode == "self_draft" else None)
-        tracer.end(sp_wait)
-        cpu_fetched = _thread_cpu()
-        self._program_fetched(sub, sp_wait)
+        """The step body.  ``sub`` is what each of its spans carries.
 
-        sp = tracer.begin("engine/finish", **sub)
-        out: Dict[int, List[int]] = {}
-        for row, (seq, n) in enumerate(picks):
-            seq.seen_tokens += n
-            if self._windowed is not None:  # what fell behind the window
-                self._windowed.trim(seq, seq.seen_tokens)
-            if seq.seen_tokens >= seq.cur_len:  # produced a next token
-                tok = int(sampled[row])
-                seq.tokens.append(tok)
-                seq.generated += 1
-                out[seq.uid] = [tok]
-                if not seq.in_decode:
-                    seq.in_decode = True
-                    self._prefilling -= 1
-                if seq.generated >= seq.max_new_tokens:
-                    self._finish(seq)
-                elif hidden_np is not None:
-                    # hidden at the position whose lm head produced `tok` —
-                    # the state the self-draft heads will propose from
-                    self._spec_hidden[self.table.row_of[seq.uid]] = \
-                        hidden_np[row]
-            if seq.uid in self.table.row_of:
-                self.table.sync(seq)
-        tracer.end(sp)
-        return out, tokens, (sp_dispatch, cpu_called, sp_wait, cpu_fetched)
+        Two steps in flight, of either kind: the step's program is the one
+        the call before called ahead, or is called here; it is advanced;
+        then, where ``_may_go_ahead``, the NEXT step's program is called
+        behind it, whatever its kind, and only then are this step's tokens
+        fetched, recorded and returned.  The fetch's tail, the bookkeeping,
+        the caller's turn and the next entry then run beside a program.  What
+        happens to the engine between two calls (``put``, ``cancel``, a stop
+        token) finds the program under way already: it is fetched whole by
+        the next call, the tokens of rows retired since are dropped and
+        counted, and a request put since joins the step after.  A steady-state
+        decode step stays vectorized: its inputs ARE the table's arrays, and
+        Python touches only the sequences that just completed."""
+        kind = sub["kind"]
+        if kind == "spec":
+            return self._spec_decode_step(temperature, rng, sub)
+        run, self._ahead = self._ahead, None
+        found = run is not None
+        if found:  # its split opens at the call's entry (``step``)
+            sp_dispatch = cpu_called = None
+        else:
+            called = self._call(kind, temperature, rng, sub)
+            if called is None:
+                return {}, 0, None
+            run, sp_dispatch, cpu_called = called
+        if kind == "decode":  # steady state: the SoA path
+            self.fast_steps += 1
+            self.ahead_steps += found
+            if found:
+                self._stage_use = "ahead"
+        else:
+            self.mixed_ahead_steps += found
+        self._h2d, self._step_counts = run.h2d, run.counts
+        if run.out is None:
+            self._sample(run, rng, sub)
+        self._advance(run)
+        nxt = self._next_kind()
+        called = self._call(
+            nxt, temperature, None, {"kind": nxt, "step": self.steps + 1},
+            behind=run) if self._may_go_ahead(rng, run, nxt) else None
+        if called is not None:
+            self._ahead = called[0]
+        out, sp_wait, cpu_fetched = self._fetch(run, sub)
+        self._ahead_flags = (int(found), int(called is not None), run.dropped)
+        return out, run.tokens, (sp_dispatch, cpu_called, sp_wait,
+                                 cpu_fetched)
 
     def _burst_decode(self, k: int, temperature: float = 0.0,
                       rng: Optional[jax.Array] = None) -> None:

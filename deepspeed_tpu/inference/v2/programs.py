@@ -1490,12 +1490,17 @@ def build_unpack(layout):
     split here: threefry inside a jitted program lowers in 0.2-0.8 s on the
     chip's host at every start, PERF.md section 6, PR 34).
 
-    A decode step that is dispatched behind one still under way hands it,
-    beside the buffer, what that program returned (``out``, ``_with_stats``:
-    its tokens, an MoE model's stats behind them; a second shape of the one
-    jitted function): ``token_ids`` are then ``out``'s first rows, on the
-    device, where the buffer's hold nothing (0 in the rows that are not in
-    the step, as a host that had fetched them would write)."""
+    A step that is called behind a program still under way hands it, beside
+    the buffer, what that program returned (``out``, ``_with_stats``: its
+    tokens, an MoE model's stats behind them; a second shape of the one
+    jitted function): where the buffer's ``token_ids`` hold a NEGATIVE id the
+    token is still on the device, and ``-1 - id`` says where in ``out`` (the
+    engine's ``_promise``: a decode program's output lies in the table's row
+    order, a mixed program's in its batch's pick order, and the step behind
+    reads its decode rows' ids in ITS order).  The other ids (a prompt's
+    chunk, a token the host has fetched, 0 in the rows that are not in the
+    step) pass as they are, so a buffer that points at nothing may be handed
+    any ``out``."""
 
     def unpack_step_inputs(buf, out=None):
         fields = {}
@@ -1508,8 +1513,7 @@ def build_unpack(layout):
         if out is not None:
             ids = fields["token_ids"]
             fields["token_ids"] = jnp.where(
-                fields["context_lens"] > 0,
-                jax.lax.slice(out, (0,), ids.shape), ids)
+                ids < 0, jnp.take(out, -1 - ids, mode="clip"), ids)
         return fields
 
     # (``out`` is the array the next fetch reads: not donated either)

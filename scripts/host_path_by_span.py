@@ -23,8 +23,9 @@ found them; beside them (ISSUE 50) the share that found their program under
 way (``ahead`` 1: ``staged`` = ``"ahead"``), the share that dispatched their
 successor before their own fetch (``ahead_next`` 1) and the tokens computed
 ahead and dropped (``ahead_dropped``, summed, and the steps that dropped
-any).  And ``starved``
-(ISSUE 53): the account of the device's queue that
+any).  ``behind`` (ISSUE 54): by kind of step, the share of the programs that
+was called behind another, and of those the share that came ``late``.  And
+``starved`` (ISSUE 53): the account of the device's queue that
 ``benchmark/program_queue.py`` makes of the ``engine/program`` spans, one
 implementation with the readers ``device_unqueued_pct`` and
 ``unqueued_{post,turn,pre}_pct``: the share of the interval in which there
@@ -142,11 +143,12 @@ def staging(spans) -> dict:
     dispatched their successor ahead, with the tokens dropped (a program from
     before ``ahead`` reads 0).  Empty for a program from before ``staged``."""
     steps = [s["attrs"] for s in spans if s["name"] == "engine/step"]
-    use = [a["staged"] for a in steps if "staged" in a]
+    dropped = [a for a in steps if a.get("stage_discarded")]
+    steps = [a for a in steps if "staged" in a]  # (a mixed step says ``ahead``
+    use = [a["staged"] for a in steps]           # too since ISSUE 54: below)
     if not use:
         return {}
     stagings = sum(s["name"] == "engine/stage" for s in spans)
-    dropped = [a for a in steps if a.get("stage_discarded")]
     out = {"decode_steps": len(use),
            "used_pct": 100.0 * use.count("used") / len(use),
            "fresh_pct": 100.0 * use.count("fresh") / len(use),
@@ -175,6 +177,31 @@ def _program_queue():
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def behind(spans) -> dict:
+    """Two steps in flight, by kind of step (ISSUE 54): the programs fetched
+    (``engine/program``), the share of them that was called BEHIND another,
+    before that one's tokens were fetched (``behind`` 1), and of those the
+    share whose predecessor had finished by then (``late`` 1: the device ran
+    dry inside ``step``); beside them the steps that called their successor
+    ahead (``ahead_next``) and the tokens dropped.  ``{}`` for a program from
+    before the span."""
+    q = _program_queue()
+    out = {}
+    for kind in sorted({a["kind"] for a in q.programs(spans)}):
+        steps = [s["attrs"] for s in spans if s["name"] == "engine/step"
+                 and s["attrs"]["kind"] == kind and "ahead" in s["attrs"]]
+        out[kind] = {
+            "programs": len(q.programs(spans, kind=kind)),
+            "behind_pct": q.share_pct(spans, {"kind": kind}, {"behind": 1}),
+            "late_pct": q.share_pct(spans, {"kind": kind, "behind": 1},
+                                    {"late": 1}),
+            "ahead_next_pct": 100.0 * sum(a["ahead_next"] for a in steps)
+            / len(steps) if steps else None,
+            "ahead_dropped": sum(a["ahead_dropped"] for a in steps)}
+    return {kind: {k: v if v is None else round(v, 4) for k, v in d.items()}
+            for kind, d in out.items()}
 
 
 def starved(spans, t0: float, t1: float) -> dict:
@@ -269,6 +296,7 @@ def main() -> int:
             "by_span_ms": by_span(seen["spans"]),
             "burst": burst(seen["spans"]),
             "staging": staging(seen["spans"]),
+            "behind": behind(seen["spans"]),
             "starved": {"window": starved(seen["spans"], window["t_open"],
                                           window["t_close"]),
                         "traced": in_trace},

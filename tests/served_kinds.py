@@ -44,10 +44,12 @@ def refusal_cases(kind, model_cfg, v2) -> list:
 _STEP = {"kind", "step", "running", "waiting", "prefilling", "emitted",
          "tokens", "budget", "h2d_copies", "h2d_bytes", "pre_ms", "device_ms",
          "post_ms", "pre_cpu_ms", "post_cpu_ms"}
-#: a decode step: whose copy its program ran on and, since ISSUE 50, whether
-#: it found that program under way, whether it dispatched its successor
-#: before its own fetch, and the rows whose token it dropped
-_DECODE = {"staged", "ahead", "ahead_next", "ahead_dropped"}
+#: a decode step: whose copy its program ran on
+_DECODE = {"staged"}
+#: a decode step since ISSUE 50, a mixed step since ISSUE 54: whether it found
+#: its program under way, whether it called its successor before its own
+#: fetch, and the rows whose token it dropped
+_AHEAD = {"ahead", "ahead_next", "ahead_dropped"}
 #: a step that found a staged copy it could not use
 _SOMETIMES = {"stage_discarded", "stage_bytes"}
 #: by what the model is, beyond the above: on every step, on mixed steps only
@@ -87,7 +89,7 @@ def assert_step_attrs(steps, *what) -> None:
     assert {"mixed", "decode"} <= {a["kind"] for a in steps}
     for a in steps:
         mixed = a["kind"] == "mixed"
-        want = _STEP | ({"attn_q_slots"} if mixed else _DECODE)
+        want = _STEP | _AHEAD | ({"attn_q_slots"} if mixed else _DECODE)
         for name in what:
             always, on_mixed = STEP_ATTRS[name]
             want |= always | (on_mixed if mixed else set())

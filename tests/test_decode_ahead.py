@@ -1,17 +1,21 @@
-"""Two decode steps in flight (ISSUE 50, ``engine._decode_step_fast``): a
-decode step whose successor needs nothing of it but the token ids has that
-successor dispatched BEFORE its own tokens are fetched, the ids handed over on
-the device.  Held here, one tiny model a served kind: the tokens are those of
-an engine that never goes ahead (and of the float32 reference); what happens
-to the table between two calls drops exactly the tokens it should and leaks
-nothing; a request put between two calls waits one step; no step goes ahead
-where the engine can see that the next is no pure decode step; the table is
-the called step's own when its program is called; and every way of leaving or
-changing an engine copes with a program under way.
+"""Two steps in flight (ISSUE 50: a decode step behind a decode step; ISSUE
+54: a step of either kind behind either kind, ``engine._step_impl``): a step
+whose successor needs nothing of it but the token ids has that successor
+called BEFORE its own tokens are fetched, the ids handed over on the device
+through a row map (``engine._promise``).  Held here, one tiny model a served
+kind: the tokens are those of an engine that never goes ahead (and of the
+float32 reference); what happens to the engine between two calls drops exactly
+the tokens it should and leaks nothing; a request put between two calls joins
+the step after; a step goes ahead only where an arrival could not have joined
+it anyway (or, as since ISSUE 50, a decode step behind a decode step); the
+table and the picks' descriptors are the called step's own when its program
+is called; and every way of leaving or changing an engine copes with a
+program under way.
 
-To drive an engine that never goes ahead: ``eng._may_go_ahead = lambda rng:
+To drive an engine that never goes ahead: ``eng._may_go_ahead = lambda *a:
 False`` (the gate is one method; there is no option).  Every case that claims
-identity asserts ``ahead_steps > 0`` beside it."""
+identity asserts ``ahead_steps > 0`` (decode steps) or ``mixed_ahead_steps >
+0`` beside it."""
 
 import os
 import sys
@@ -50,7 +54,7 @@ def _engine(built, never=False, **over):
     eng = InferenceEngineV2(cfg, params, V2Config(
         **{**{f: getattr(v2, f) for f in _V2}, **over}))
     if never:
-        eng._may_go_ahead = lambda rng: False
+        eng._may_go_ahead = lambda *a: False
     return eng
 
 
@@ -96,12 +100,19 @@ def test_serves_what_an_engine_that_never_goes_ahead_serves(devices, built,
     want, want_steps = _run(never, temperature=0.7 if sampled else 0.0)
     assert served == want and steps == want_steps
     assert [len(served[u]) for u in uids] == [b for _, b in _REQUESTS]
-    assert never.ahead_steps == 0 and eng.ahead_dropped == 0
-    # most decode steps found their program under way; the one behind a
-    # mixed step and the one behind a row's last never do
+    assert never.ahead_steps == never.mixed_ahead_steps == 0
+    assert eng.ahead_dropped == 0
+    # four requests over four rows: every step but the first found its
+    # program under way while all four ran, the mixed steps too; with a row
+    # free a decode step behind a decode step still does, and the last
+    # phase's first does not
     decode = [a for a in spans if a["kind"] == "decode"]
+    mixed = [a for a in spans if a["kind"] == "mixed"]
     assert eng.ahead_steps == sum(a["ahead"] for a in decode) >= 25
-    assert eng.ahead_steps >= 0.7 * len(decode)
+    assert eng.ahead_steps >= 0.9 * len(decode)
+    assert eng.mixed_ahead_steps == sum(a["ahead"] for a in mixed) \
+        == len(mixed) - 1 >= 2
+    assert all(a["ahead"] for a in spans[1:] if a["running"] == 4)
     assert eng.fast_steps == never.fast_steps == len(decode)
     assert eng.drained() and never.drained()
     for a in decode:  # a program a step, a copy a program
@@ -214,7 +225,7 @@ def test_a_request_put_between_two_calls_waits_one_step(devices, built):
     assert list(steps[3]) == [first] and sorted(steps[4]) == [first, late]
     kinds = [(s.attrs["kind"], s.attrs.get("ahead"), s.attrs.get("ahead_next"))
              for s in tracer.spans(name="engine/step")]
-    assert kinds[3:5] == [("decode", 1, 0), ("mixed", None, None)]
+    assert kinds[3:5] == [("decode", 1, 0), ("mixed", 0, 0)]
     # an engine that never goes ahead admits it a step earlier and serves
     # both the same tokens
     never = _engine(built, never=True)
@@ -226,55 +237,97 @@ def test_a_request_put_between_two_calls_waits_one_step(devices, built):
     assert eng.drained()
 
 
-# -- (d) where no step goes ahead -------------------------------------------
+# -- (d) when a step goes ahead ---------------------------------------------
 
 
-def test_no_step_goes_ahead_of_a_row_at_its_budget(devices, built):
-    """The step that hands a row its last token dispatches nothing behind
-    itself (the row frees, and its caller may admit a request), whatever the
-    other rows have left; the decode step after it starts from the staged
-    buffer."""
+def _step_kinds():
+    return [(a["kind"], a["running"], a["ahead"], a["ahead_next"],
+             a.get("staged"))
+            for a in (s.attrs for s in tracer.spans(name="engine/step"))]
+
+
+def test_with_every_slot_taken_a_step_goes_ahead_of_a_budget_and_a_prefill(
+        devices, built):
+    """Four requests over four rows: an arrival could join no step, so every
+    step calls its successor before its own fetch, whatever both are: a mixed
+    step behind a mixed step (a prompt of two chunks prefills), a decode step
+    behind the mixed step that ends the prefill, and the step behind the one
+    that hands a row its last token (the row holds its slot until that
+    token's fetch).  Once a slot is free only a decode step behind a decode
+    step goes ahead, as since ISSUE 50, a row at its budget or not."""
+    tracer.clear()
+    eng = _engine(built)
+    for n, budget in ((5, 4), (6, 9), (30, 6), (7, 9)):
+        eng.put(_prompt(n), budget)
+    _run(eng)
+    assert _step_kinds() == [
+        ("mixed", 0, 0, 1, None),  # (the fourth joins the step called here)
+        ("mixed", 4, 1, 1, None),  # two prompts prefill
+        ("mixed", 4, 1, 1, None),  # the last chunk
+        ("decode", 4, 1, 1, "ahead"),  # the first row's fourth token
+        ("decode", 3, 1, 1, "ahead"), ("decode", 3, 1, 1, "ahead"),
+        ("decode", 3, 1, 1, "ahead"),  # the third row's sixth
+        ("decode", 2, 1, 1, "ahead"),
+        ("decode", 2, 1, 1, "ahead"),  # the second row's ninth
+        ("decode", 1, 1, 1, "ahead"), ("decode", 1, 1, 0, "ahead")]
+    programs = [(s.attrs["kind"], s.attrs["step"], s.attrs["behind"])
+                for s in tracer.spans(name="engine/program")]
+    assert programs == [("mixed", 1, 0), ("mixed", 2, 1), ("mixed", 3, 1)] + [
+        ("decode", n, 1) for n in range(4, 12)]
+    assert (eng.mixed_ahead_steps, eng.ahead_steps) == (2, 8)
+    assert eng.drained()
+
+
+def test_with_a_free_slot_and_nothing_waiting_a_mixed_step_does_not_go_ahead(
+        devices, built):
+    """Two requests over four rows: a request arriving now could join the
+    next step, so neither the mixed step nor the decode step behind it is
+    called ahead (that one starts from the staged buffer); a decode step
+    behind a decode step is, the one behind a row's last token too."""
     tracer.clear()
     eng = _engine(built)
     eng.put(_prompt(5), 4)
     eng.put(_prompt(6), 9)
     _run(eng)
-    spans = [s.attrs for s in tracer.spans(name="engine/step")]
-    got = [(a["kind"], a["running"], a.get("ahead"), a.get("ahead_next"),
-            a.get("staged")) for a in spans]
-    assert got == [
-        ("mixed", 0, None, None, None),
+    assert _step_kinds() == [
+        ("mixed", 0, 0, 0, None),
         ("decode", 2, 0, 1, "used"), ("decode", 2, 1, 1, "ahead"),
-        ("decode", 2, 1, 0, "ahead"),  # the first row's fourth token
-        ("decode", 1, 0, 1, "used"), ("decode", 1, 1, 1, "ahead"),
+        ("decode", 2, 1, 1, "ahead"),  # the first row's fourth token
+        ("decode", 1, 1, 1, "ahead"), ("decode", 1, 1, 1, "ahead"),
         ("decode", 1, 1, 1, "ahead"), ("decode", 1, 1, 1, "ahead"),
         ("decode", 1, 1, 0, "ahead")]
-    assert eng.ahead_steps == 6 and eng.drained()
+    assert (eng.mixed_ahead_steps, eng.ahead_steps) == (0, 7)
+    assert eng.drained()
 
 
-def test_no_step_goes_ahead_while_something_waits_or_prefills(devices, built):
-    """A request that cannot be admitted yet waits (every step is a mixed
-    step), a prompt of several chunks prefills: no decode program is
-    dispatched ahead until both are over; a caller that hands ``step`` its
+def test_a_request_that_capacity_keeps_out_lets_the_steps_go_ahead(devices,
+                                                                   built):
+    """Five requests over four rows: the fifth waits in the engine (every
+    step is a mixed step) and an arrival would wait behind it, so the steps
+    go ahead; the four end in one step, whose successor would be the fifth
+    alone, which can take no slot before their fetch: nothing is called ahead
+    there, and nothing was released early.  A caller that hands ``step`` its
     keys never goes ahead."""
+    tracer.clear()
     eng = _engine(built)
-    uids = [eng.put(_prompt(4 + i), 6) for i in range(5)]  # four rows
-    seen = []
-    while eng.waiting or eng._prefilling:
-        eng.step()
-        seen.append(eng._ahead)
-    assert seen and not any(seen) and eng.ahead_steps == 0
-    assert uids[4] in eng.running  # admitted behind the first to finish
+    uids = [eng.put(_prompt(4 + i), 6) for i in range(5)]
     served, _ = _run(eng)
-    assert eng.ahead_steps > 0 and eng.drained()
+    got = [(kind, running, ahead, nxt) for kind, running, ahead, nxt, _
+           in _step_kinds()]
+    assert got[:7] == [("mixed", 0, 0, 1)] + [("mixed", 4, 1, 1)] * 4 + [
+        ("mixed", 4, 1, 0), ("mixed", 0, 0, 0)]
+    assert [len(served[u]) for u in uids] == [6] * 5
+    assert eng.mixed_ahead_steps == 5 and eng.drained()
     keyed = _engine(built)
-    keyed.put(_prompt(5), 8)
+    for i in range(4):
+        keyed.put(_prompt(5 + i), 8)
     key = jax.random.PRNGKey(3)
     while keyed.running or keyed.waiting:
         key, sub = jax.random.split(key)
         keyed.step(rng=sub)
         assert keyed._ahead is None
-    assert keyed.fast_steps == 7 and keyed.ahead_steps == 0
+    assert keyed.fast_steps == 7
+    assert keyed.ahead_steps == keyed.mixed_ahead_steps == 0
 
 
 def test_no_step_goes_ahead_under_speculation(devices):
@@ -330,47 +383,55 @@ def test_the_table_is_the_called_steps_own(devices, built):
 # -- (f) leaving or changing an engine with a program under way -------------
 
 
-def _under_way(built, **over):
+def _under_way(built, kind="decode", **over):
+    """An engine with a program of ``kind`` under way (four rows of four:
+    the mixed steps go ahead too)."""
     eng = _engine(built, **over)
     uids = _put_all(eng, False)
-    while eng._ahead is None:
+    while eng._ahead is None or eng._ahead.kind != kind:
         eng.step()
     return eng, uids
 
 
-def test_generate_all_takes_over_a_program_under_way(devices, built):
-    eng, uids = _under_way(built)
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_generate_all_takes_over_a_program_under_way(devices, built, kind):
+    eng, uids = _under_way(built, kind)
     got = eng.generate_all()
     want_eng = _engine(built, never=True)
     want_uids = _put_all(want_eng, False)
     want = want_eng.generate_all()
     assert [got[u] for u in uids] == [want[u] for u in want_uids]
-    assert eng.ahead_steps > 0 and eng._ahead is None and eng.drained()
+    assert eng.mixed_ahead_steps + eng.ahead_steps > 0
+    assert eng._ahead is None and eng.drained()
     with pytest.raises(RuntimeError, match="under way"):
-        busy, _ = _under_way(built)
+        busy, _ = _under_way(built, kind)
         busy._burst_decode(2)
 
 
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
 def test_close_and_swap_params_leave_a_program_under_way_alone(devices,
-                                                               built):
+                                                               built, kind):
     """``close`` is the pager's alone, and ``swap_params`` is a drained
     engine's (its rows were cancelled): neither waits for the program, which
     holds the weights it was called with; the next ``step`` fetches it,
     drops its tokens and counts them, and serves what is put since."""
-    eng, uids = _under_way(built)
+    eng, uids = _under_way(built, kind)
     eng.close()
     assert eng._ahead is not None
     served, _ = _run(eng)  # (closing is no end)
     assert sorted(served) == uids and eng.drained()
     assert eng.ahead_dropped == 0
-    eng, uids = _under_way(built)
-    live = len(eng.running)
+    eng, uids = _under_way(built, kind)
+    # the rows that get a token from the program under way: all of a decode
+    # program's, of a mixed program's those whose prompt ends in it
+    owed = sum(seq.seen_tokens + n >= seq.cur_len
+               for seq, n in eng._ahead.picks) or len(eng.running)
     for uid in uids:
         eng.cancel(uid)
     assert eng.drained() and eng._ahead is not None
     eng.swap_params(eng.params)
     uid = eng.put(_prompt(6), 4)
-    assert eng.step() == {} and eng.ahead_dropped == live
+    assert eng.step() == {} and eng.ahead_dropped == owed
     assert len(_run(eng)[0][uid]) == 4 and eng.drained()
 
 
@@ -402,7 +463,7 @@ def test_the_prefix_cache_and_the_pager_read_behind_a_program_under_way(
     again = [eng.put(p, 6) for p in prompts] + [eng.put(_prompt(40, 9), 6)]
     served, _ = _run(eng)
     cold = InferenceEngineV2(cfg, params, V2Config(**_V2))
-    cold._may_go_ahead = lambda rng: False
+    cold._may_go_ahead = lambda *a: False
     cold_uids = [cold.put(p, 6) for p in prompts] + [cold.put(_prompt(40, 9),
                                                               6)]
     want, _ = _run(cold)

@@ -189,7 +189,8 @@ def test_every_device_step_carries_its_split(devices, tiny_model, over,
             # the call found its program under way: ``device_ms`` opens at
             # its entry, and the program was called inside the step before,
             # ahead of that step's own fetch
-            assert a["pre_ms"] == 0.0 and a["staged"] == "ahead"
+            assert a["pre_ms"] == 0.0 and a.get("staged") == (
+                "ahead" if a["kind"] == "decode" else None)
             assert before.attrs["ahead_next"] == 1
             assert kids["engine/dispatch"].parent_id == before.span_id
             assert kids["engine/h2d"].parent_id == before.span_id
@@ -257,7 +258,7 @@ def test_a_device_step_reads_the_thread_clock_four_times_or_never(
         assert eng.ahead_steps == 1  # (the mechanism needs no tracing)
     else:
         assert len(steps) == 3 and all("pre_cpu_ms" in s.attrs for s in steps)
-        assert [s.attrs.get("ahead") for s in steps] == [None, 0, 1]
+        assert [s.attrs.get("ahead") for s in steps] == [0, 0, 1]
 
 
 # what happens between a step that staged and the next → what the NEXT step
@@ -281,7 +282,7 @@ _BETWEEN = {
 
 @pytest.mark.parametrize("between", sorted(_BETWEEN))
 def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
-                                                 monkeypatch, between):
+                                                 between):
     """``staged`` on a decode step (``"used"``: the copy the step before
     staged; ``"fresh"``: made here), ``stage_discarded`` / ``stage_bytes``
     on any step that found staged fields it could not use, ``h2d_copies`` 1
@@ -292,29 +293,26 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
     step ahead (ISSUE 50: every decode step here, none of whose rows is at
     its budget), in that step's ``engine/h2d``, with the predecessor's
     tokens beside the buffer, before this step's own fetch."""
-    from deepspeed_tpu.inference.v2 import engine as engine_mod
-
     act, kind, staged, discarded = _BETWEEN[between]
-    calls, behind, real = [], [], engine_mod.build_unpack
-
-    def build_unpack(layout):
-        program = real(layout)
-
-        def call(buf, out=None):
-            calls.append(time.monotonic())
-            behind.append(int(out is not None))
-            return program(buf, out)
-
-        return call
-
-    monkeypatch.setattr(engine_mod, "build_unpack", build_unpack)
+    calls, behind = [], []
     eng = _engine(tiny_model)
+    to_device = eng._to_device  # the one place that calls the unpack program
+
+    def counted(layout, buf, out=None):
+        calls.append(time.monotonic())
+        behind.append(int(out is not None))
+        return to_device(layout, buf, out)
+
+    eng._to_device = counted
     eng.step_temperature = 0.0
     pinned = 0.5 if between == "temperature-all-pinned" else None
     uids = [eng.put(list(range(1, 1 + n)), 12, temperature=pinned)
             for n in ((5, 6, 2, 3) if pinned else (5, 9))]
     # the mixed step that ends every prompt: all rows decoding, none ahead
-    eng.step(temperature=eng.step_temperature)
+    # (with every slot taken it would call the decode step ahead, ISSUE 54,
+    # and stage nothing: a caller's key keeps that step to itself)
+    eng.step(temperature=eng.step_temperature,
+             rng=jax.random.PRNGKey(7) if pinned else None)
     assert eng._staged is not None and eng._prefilling == 0
     assert eng._ahead is None
     act(eng, uids)
@@ -356,7 +354,8 @@ def test_a_decode_step_says_whose_copy_it_ran_on(devices, tiny_model,
         assert eng._ahead is not None
     else:
         # the mixed step ends steady: it stages the next one's, last
-        assert "ahead" not in a and not ahead and eng._ahead is None
+        assert (a["ahead"], a["ahead_next"]) == (0, 0)
+        assert not ahead and eng._ahead is None
         assert list(kids)[-1] == "engine/stage"
         assert inside["engine/stage"] == 1 and behind == [0, 0]
         assert eng._staged is not None
@@ -692,3 +691,23 @@ def test_the_by_span_script_prints_both_clocks_and_the_three_sums():
     assert (shares["ahead_dropped"], shares["ahead_dropped_steps"]) == (4, 2)
     assert script.by_span(spans + staged)["decode"]["engine/stage"] == [
         1.0, 1.0]
+    # ISSUE 54: by kind of step, the programs called behind another and of
+    # those the late ones (a mixed step says ``ahead`` too, and the decode
+    # steps' shares above do not count it)
+    assert script.behind(spans) == {}  # from before ``engine/program``
+    mixed = [
+        step("mixed", 108.0, 0.0, 0.0, 1.5, 1.2, ahead=1, ahead_next=1,
+             ahead_dropped=2),
+        step("mixed", 108.1, 2.0, 0.9, 1.5, 1.2, ahead=0, ahead_next=1,
+             ahead_dropped=0),
+        span("engine/program", 108.0, 108.1, kind="mixed", behind=1, late=1),
+        span("engine/program", 108.1, 108.2, kind="mixed", behind=1, late=0),
+        span("engine/program", 108.2, 108.3, kind="mixed", behind=0),
+        span("engine/program", 108.3, 108.4, kind="mixed", behind=1,
+             error=True)]  # (nobody fetched it: in no share)
+    assert script.behind(queue + ahead + mixed) == {
+        "decode": {"programs": 4, "behind_pct": 25.0, "late_pct": 0.0,
+                   "ahead_next_pct": 75.0, "ahead_dropped": 4},
+        "mixed": {"programs": 3, "behind_pct": 66.6667, "late_pct": 50.0,
+                  "ahead_next_pct": 100.0, "ahead_dropped": 2}}
+    assert script.staging(ahead + mixed) == shares

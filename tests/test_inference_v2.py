@@ -703,15 +703,15 @@ def test_step_programs_import_without_the_engine(module):
 # the step seen from inside: engine/step's children (ISSUE 24)
 # ---------------------------------------------------------------------------
 
-_DECODE_PHASES = ["engine/h2d", "engine/dispatch", "engine/wait",
-                  "engine/finish"]
-_CHILDREN = {
-    "decode": _DECODE_PHASES,
-    "spec": _DECODE_PHASES,
+# a step's two halves: the call of its program (a mixed program's sampler
+# is enqueued behind it) and the fetch of its tokens
+_CALL = {
+    "decode": ["engine/h2d", "engine/dispatch"],
+    "spec": ["engine/h2d", "engine/dispatch"],
     "mixed": ["engine/schedule", "engine/build", "engine/h2d",
-              "engine/dispatch", "engine/sample", "engine/wait",
-              "engine/finish"],
+              "engine/dispatch"],
 }
+_FETCH = ["engine/wait", "engine/finish"]
 # the last child of a step that ends steady: the NEXT decode step's copy
 # (ISSUE 38); a speculative engine stages nothing
 _STAGE = "engine/stage"
@@ -726,11 +726,11 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     ``engine/stage`` behind them where the step ends steady and nowhere
     else; the step itself says how long the device was (presumed) busy, how
     full its batch was, what was copied to the device for its program and,
-    a decode step, whether the step before had staged that copy.  A decode
-    step that dispatches its successor ahead (ISSUE 50) holds that step's
-    pack and call, under that step's number, before its own fetch, and
-    stages nothing; the step that finds the program under way holds neither
-    of its own."""
+    a decode step, whether the step before had staged that copy.  A step
+    that calls its successor ahead (ISSUE 50; of either kind, ISSUE 54)
+    holds that step's call, under that step's kind and number, before its
+    own fetch, and stages nothing; the step that finds the program under way
+    holds no call of its own, and a mixed one opens with its sampler."""
     from deepspeed_tpu.observability.trace import tracer
 
     cfg, params = tiny_model
@@ -758,30 +758,34 @@ def test_step_children_nest_in_order(devices, tiny_model, kind, over):
     assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
     assert kind == "spec" or (True in ends_steady.values()
                               and False in ends_steady.values())
+    kind_of_step = {s.attrs["step"]: s.attrs["kind"] for s in spans
+                    if s.name == "engine/step"}
+    assert kind == "spec" or any(s.attrs["ahead"] for s in steps)
     for st in steps:
         kids = [s for s in spans if s.parent_id == st.span_id]
         found, ahead_next = (st.attrs.get("ahead", 0),
                              st.attrs.get("ahead_next", 0))
         staged_next = ends_steady[st.attrs["step"]] and not ahead_next
-        own, nxt = _CHILDREN[kind], _DECODE_PHASES[:2] * ahead_next
-        want = (own[:-2] * (not found) + nxt + own[-2:]
-                + ([_STAGE] if staged_next else []))
+        own = _CALL[kind] * (not found) + ["engine/sample"] * (kind == "mixed")
+        next_kind = kind_of_step.get(st.attrs["step"] + 1)
+        nxt = _CALL[next_kind] * ahead_next if ahead_next else []
+        want = own + nxt + _FETCH + ([_STAGE] if staged_next else [])
         assert [k.name for k in kids] == want
-        first_of_next = len(own[:-2]) * (not found)
         edges = [st.t_start]
         for i, k in enumerate(kids):
-            assert k.attrs["kind"] == kind
-            assert k.attrs["step"] == st.attrs["step"] + (
-                first_of_next <= i < first_of_next + len(nxt))
+            of_next = len(own) <= i < len(own) + len(nxt)
+            assert k.attrs["kind"] == (next_kind if of_next else kind)
+            assert k.attrs["step"] == st.attrs["step"] + of_next
             edges += [k.t_start, k.t_end]
         edges.append(st.t_end)
         assert edges == sorted(edges)  # inside the step, one after another
-        if found:  # its pack and call lie in the step before, its split at 0
-            kids = [s for s in spans if s.name in _DECODE_PHASES[:2]
+        if found:  # its call lies in the step before, its split at 0
+            kids = [s for s in spans if s.name in _CALL[kind]
                     and s.attrs["step"] == st.attrs["step"]] + kids
-            assert [k.name for k in kids[:2]] == _DECODE_PHASES[:2]
+            assert [k.name for k in kids[:len(_CALL[kind])]] == _CALL[kind]
             assert st.attrs["pre_ms"] == 0.0
-            kids[1] = st  # where ``device_ms`` opens: the step's entry
+            # where ``device_ms`` opens: the step's entry
+            kids[len(_CALL[kind]) - 1] = st
         assert 0.0 <= st.attrs["device_ms"] <= st.duration_s * 1e3
         assert st.attrs["budget"] == 16
         assert 0 < st.attrs["tokens"] <= 16
@@ -991,26 +995,28 @@ def _parents_argument_path(eng):
     ``_row_temps`` read it) and the engine's key split eagerly on the host,
     unpacked in Python; and, like the parent, one step in flight: no step is
     dispatched before its predecessor's tokens are on the host."""
-    eng._may_go_ahead = lambda rng: False
+    eng._may_go_ahead = lambda *a: False
 
     def to_device(layout, buf, out=None):
         names = [f[0] for f in layout.fields]
         if "seeds" in names:  # the decode step: off the table, not the buffer
+            # (copies: on the CPU ``jnp.asarray`` may alias the table's own
+            # arrays, which the step advances before the program has run)
             t = eng.table
-            tok, ctx, tables, ctx_in = eng._table_inputs()
-            fields = {"token_ids": tok, "position_ids": ctx,
-                      "context_lens": ctx_in,
+            fields = {"token_ids": jnp.asarray(t.next_tok.copy()),
+                      "position_ids": jnp.asarray(t.ctx.copy()),
+                      "context_lens": jnp.asarray(
+                          ((t.ctx + 1) * t.active).astype(np.int32)),
                       "temps": jnp.asarray(np.where(
                           t.temp >= 0.0, t.temp,
                           np.float32(eng.step_temperature))
                           .astype(np.float32)),
-                      "seeds": jnp.asarray(t.seed)}
-            if isinstance(tables, tuple):
-                fields["block_tables"], fields["win_tables"] = tables
-            else:
-                fields["block_tables"] = tables
+                      "seeds": jnp.asarray(t.seed.copy()),
+                      "block_tables": jnp.asarray(t.block_tables.copy())}
+            if t.win_tables is not None:
+                fields["win_tables"] = jnp.asarray(t.win_tables.copy())
             if "row_adapter" in names:
-                fields["row_adapter"] = jnp.asarray(t.adapter)
+                fields["row_adapter"] = jnp.asarray(t.adapter.copy())
         else:
             fields = {n: jnp.asarray(v.copy())
                       for n, v in layout.views(buf).items()}
@@ -1160,6 +1166,18 @@ def one_copy_run(request, devices):
                 return fn(*args)
             return call
 
+        def sample(*args):
+            # a mixed program's sampler: behind its call, or, where the
+            # program was called ahead (ISSUE 54), at the head of the step
+            # that takes it; its two arguments are no step's inputs
+            was, seen["open"] = seen.get("open"), False
+            try:
+                with jax.transfer_guard_host_to_device("allow"):
+                    return sampler(*args)
+            finally:
+                seen["open"] = was
+
+        sampler, eng._sample = eng._sample, sample
         eng._to_device, eng._step_impl = counted, step_impl
         eng._stage_next = stage_next
         for attr, fn in programs.items():
@@ -1231,7 +1249,10 @@ def test_a_step_makes_one_host_to_device_copy(one_copy_run):
     # copy was made in the step before, with its program, ahead of that
     # step's own fetch
     used = [s.attrs.get("staged") == "used" for s in run["spans"]]
-    found = [s.attrs.get("staged") == "ahead" for s in run["spans"]]
+    found = [s.attrs.get("ahead") == 1 for s in run["spans"]]
+    assert [s.attrs.get("staged") == "ahead" for s in run["spans"]] == [
+        f and s.attrs["kind"] == "decode" for f, s in zip(found,
+                                                          run["spans"])]
     assert run["seen"]["copies"] == [int(on and not u and not f)
                                      for on, u, f in zip(ran, used, found)]
     assert used.count(True) >= 5 and not used[0]
@@ -1246,12 +1267,14 @@ def test_a_step_makes_one_host_to_device_copy(one_copy_run):
                                              run["seen"]["staged"]))
     assert run["seen"]["explicit"] == [0] * len(ran)
     # the buffers in the order they were copied: a step's own, then the one
-    # of the step it dispatched ahead or the one it staged
+    # of the step it called ahead (of that step's kind) or the one it staged
     order = []
-    for s, own, ahead, staged in zip(run["spans"], run["seen"]["copies"],
-                                     run["seen"]["ahead"],
-                                     run["seen"]["staged"]):
-        order += [s.attrs["kind"]] * own + ["decode"] * (ahead + staged)
+    kinds_after = [s.attrs["kind"] for s in run["spans"][1:]] + [None]
+    for s, nxt, own, ahead, staged in zip(
+            run["spans"], kinds_after, run["seen"]["copies"],
+            run["seen"]["ahead"], run["seen"]["staged"]):
+        order += ([s.attrs["kind"]] * own + [nxt] * ahead
+                  + ["decode"] * staged)
     assert len(order) == len(run["seen"]["bufs"])
     assert all(b == (np.ndarray, np.dtype(np.int32), nbytes[k])
                for b, k in zip(run["seen"]["bufs"], order))
@@ -1281,12 +1304,14 @@ def test_tokens_and_step_keys_are_the_parents(one_copy_run):
 
 
 def test_the_unpack_programs_compile_once(one_copy_run):
-    """Fifty steps and more with rows coming and going: one compiled unpack
-    program for the decode steps and one for the mixed steps, and a second
-    shape of the decode steps' for those dispatched behind a program under
-    way (which takes that program's tokens beside the buffer)."""
+    """Fifty steps and more with rows coming and going, steps of either kind
+    called behind either: one compiled unpack program for the decode steps
+    and one for the mixed steps, each handed the latest program's tokens
+    beside the buffer whether the buffer points into them or not; and the
+    shape without them of an engine's very first step, which has none.  No
+    shape waits for the traffic to bring its case."""
     assert len(one_copy_run["keys"]) >= 50
-    assert one_copy_run["unpack_programs"] == {"decode": 2, "mixed": 1}
+    assert one_copy_run["unpack_programs"] == {"decode": 1, "mixed": 2}
 
 
 def test_a_callers_key_is_used_as_it_is(devices, tiny_model):

@@ -574,10 +574,11 @@ def test_request_timeline_reconstructs_ttft(devices, tiny_model):
 def test_engine_steps_recorded_with_batch_attrs(devices, tiny_model):
     eng = _engine(tiny_model)
     eng.put([1, 2, 3], max_new_tokens=3)
-    before = len(global_recorder.snapshot()["steps"])
+    since = time.monotonic()  # (the ring is bounded: no index holds)
     while eng.running or eng.waiting:
         eng.step()
-    steps = global_recorder.snapshot()["steps"][before:]
+    steps = [s for s in global_recorder.snapshot()["steps"]
+             if s["t_start"] >= since]
     assert steps
     assert steps[0]["kind"] == "mixed"  # first step prefills
     for s in steps:

@@ -50,11 +50,11 @@ def _build(name):
 def _watch(eng, fed):
     """Hold what feeds every decode step's program to a fresh pack of the
     table at the moment of the call: a staged buffer that differs from it
-    must never be the one that is used.  A step dispatched ahead (ISSUE 50:
-    every call of a ``step`` but the first of a ``step`` that found no
-    program under way, ``fed["own"]``) is called before its token ids reach
-    the host: they are kept in ``fed["ahead"]`` and held, once ``step`` has
-    returned, to what the table reads then (``_drive``)."""
+    must never be the one that is used.  A step called ahead (ISSUE 50; its
+    buffer holds promises where the token ids would stand) is called before
+    its token ids reach the host: what the unpack program made of them is
+    kept in ``fed["ahead"]`` and held, once ``step`` has returned, to what
+    the table reads then (``_drive``)."""
     decode_fwd = eng._decode_fwd
     names = [f[0] for f in eng._decode_layout.fields]
 
@@ -71,9 +71,8 @@ def _watch(eng, fed):
         if adapter:
             got["row_adapter"] = adapter[1]
         assert sorted(got) == sorted(names)
-        if not fed["own"]:
+        if (want["token_ids"] < 0).any():
             fed["ahead"] = np.asarray(got.pop("token_ids"))
-        fed["own"] = False
         for name, arr in got.items():
             np.testing.assert_array_equal(
                 np.asarray(arr).view(np.int32), want[name].view(np.int32),
@@ -100,7 +99,7 @@ def _drive(build, seed, adapters, staging):
     eng = build()
     if staging is None:  # nothing is ever staged: the order before ISSUE 38
         eng._stage_next = lambda temperature, sub: None
-    fed = {"n": 0, "own": True, "ahead": None}
+    fed = {"n": 0, "ahead": None}
     _watch(eng, fed)
     rs = np.random.default_rng(seed)
     served, live = [], []
@@ -122,11 +121,13 @@ def _drive(build, seed, adapters, staging):
             eng.step_temperature = [0.0, 0.7, 1.1][int(rs.integers(3))]
         if not staging:
             eng._staged = None
-        fed["own"], fed["ahead"] = eng._ahead is None, None
+        fed["ahead"] = None
         out = eng.step(temperature=eng.step_temperature)
-        # a program went ahead exactly where one is under way now, and ran on
-        # the tokens this step has just recorded
-        assert (fed["ahead"] is None) == (eng._ahead is None)
+        # a decode program went ahead exactly where one is under way now (a
+        # mixed one is not watched here: ``tests/test_steps_in_flight.py``),
+        # and ran on the tokens this step has just recorded
+        assert (fed["ahead"] is None) == (eng._ahead is None
+                                          or eng._ahead.kind != "decode")
         if fed["ahead"] is not None:
             np.testing.assert_array_equal(fed["ahead"], eng.table.next_tok)
         served.append(out)
@@ -154,17 +155,19 @@ def test_staged_serves_what_fresh_serves(devices, name, seed):
     use = [a["staged"] for a in decode]
     # the interleaving reaches all three paths, and both ways of losing a
     # staging
-    assert (use.count("used") >= 7 and use.count("fresh") >= 2
-            and use.count("ahead") >= 30)
-    # (ISSUE 50) every program dispatched ahead is the next call's step, and
-    # the cancels and stop tokens between two calls dropped tokens of some
-    assert [a["ahead"] for a in decode[1:]] == [
-        a["ahead_next"] for a in decode[:-1]]
+    assert (use.count("used") >= 7 and use.count("fresh") >= 1
+            and use.count("ahead") >= 25)
+    # (ISSUE 50, 54) every program called ahead, of either kind, is the next
+    # call's step, and the cancels and stop tokens between two calls dropped
+    # tokens of some
+    ran = [a for a in steps if "ahead" in a]
+    assert [a["ahead"] for a in ran[1:]] == [
+        a["ahead_next"] for a in ran[:-1]]
     assert all((a["staged"] == "ahead") == a["ahead"] for a in decode)
-    assert sum(a["ahead_dropped"] for a in decode) >= 2
-    assert not any(a["ahead_dropped"] for a in decode if not a["ahead"])
+    assert sum(a["ahead_dropped"] for a in ran) >= 2
+    assert not any(a["ahead_dropped"] for a in ran if not a["ahead"])
     dropped = [a for a in steps if a.get("stage_discarded")]
-    assert {a["kind"] for a in dropped} == {"decode", "mixed"}
+    assert {"decode"} <= {a["kind"] for a in dropped} <= {"decode", "mixed"}
     size = staging._decode_layout.size * 4
     assert all(a["stage_bytes"] == size for a in dropped)
     assert all("staged" not in a for a in steps if a["kind"] != "decode")

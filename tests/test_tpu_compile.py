@@ -728,6 +728,34 @@ def test_step_programs_carry_their_names(one_chip, mosaic, program):
         _assert_attention_walks_the_tokens(compiled)
 
 
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_unpack_program_compiles_with_its_row_map(one_chip, step):
+    """The program that takes a step's one buffer apart, at Mellum2's sizes
+    (32 rows, two tables of 132 blocks, 512 tokens a step), handed the
+    predecessor's output beside the buffer (ISSUE 54): where a token id is
+    negative it is read out of that output by the row map, one small gather,
+    and nothing of the buffer is copied but its fields; the shape without an
+    output (an engine's first step) compiles to slices alone."""
+    from deepspeed_tpu.inference.v2 import programs, ragged
+
+    layout = (ragged.decode_layout(32, 132, two_pools=True)
+              if step == "decode"
+              else ragged.mixed_layout(512, 32, 132, two_pools=True))
+    # (the memo's program, traced for the described chip)
+    unpack = programs.build_unpack(layout)
+    buf = _sds((layout.size,), jnp.int32, one_chip)
+    out = _sds((32 + 2,), jnp.int32, one_chip)  # an MoE model's two stats
+    alone = unpack.lower(buf).compile().as_text()
+    mapped = unpack.lower(buf, out).compile().as_text()
+    assert "gather" not in alone and "select" not in alone
+    assert len(re.findall(r" gather\(", mapped)) <= 1
+    fields = jax.eval_shape(unpack, buf, out)
+    assert sorted(fields) == sorted(f[0] for f in layout.fields)
+    assert fields["token_ids"].shape == ((32,) if step == "decode"
+                                         else (512,))
+    assert fields["token_ids"].dtype == jnp.int32
+
+
 def _assert_attention_walks_the_tokens(compiled):
     """The mixed step's prefill attention reads the step's queries as the
     layer made them (ISSUE 32): the kernel's name once in the layer body,
