@@ -37,6 +37,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from deepspeed_tpu.models import mixed_ffn  # noqa: E402
 from deepspeed_tpu.models import transformer as tfm  # noqa: E402
 
 
@@ -140,6 +141,21 @@ class Sizes:
     latent_train_micro: int = 2
     latent_train_seq: int = 8192
     latent_train_steps: int = 2
+    # -- window and full attention layers mixed TRAINED on one chip of
+    # sixteen: published layers 1-5 of Trinity-Mini (a dense layer, then
+    # window, FULL, window, window over routed FFNs), 8 of 128 experts, an
+    # eighth of the vocabulary: 0.5041 B parameters, 7.1 GB at 14 B a
+    # parameter (16 experts, 0.7055 B, do not load beside the step's temp:
+    # PERF.md section 4); one row of 16,384 (the benchmark cell's
+    # configuration and batch)
+    swa_train_preset: str = "trinity-mini"
+    swa_train_kinds: Tuple[str, ...] = ("sliding", "sliding", "full",
+                                        "sliding", "sliding")
+    swa_train_held: int = 8
+    swa_train_vocab: int = 25024
+    swa_train_micro: int = 1
+    swa_train_seq: int = 16384
+    swa_train_steps: int = 2
     # -- EVA attention: four layers of EvaByte-6.5B at its published widths
     # (0.81 B parameters in int8), both pools sized as the cell sizes them a
     # row (a whole window of 32 blocks, a summary block a 1,024 tokens);
@@ -255,7 +271,8 @@ def build_trainer(sz: Sizes, seed: int, num_layers: int,
 
     spec = ModelSpec(loss_fn=loss_fn, params=params,
                      param_axes=tfm.param_axes(cfg),
-                     flops_per_token=cfg.flops_per_token())
+                     flops_per_token=cfg.flops_per_token(),
+                     **mixed_ffn.spec_rules(params, cfg))
     config = {
         "train_micro_batch_size_per_gpu": micro or sz.train_micro,
         "optimizer": {"type": "AdamW", "params": {"lr": sz.lr}},
@@ -381,6 +398,81 @@ def phase_latent_moe_trainer(sz: Sizes, seed: int,
             output_gb=round(ma.output_size_in_bytes / 1e9, 3),
             alias_gb=round(ma.alias_size_in_bytes / 1e9, 3))
         require_kernel("kernels", "train_step", compiled.as_text())
+
+
+def phase_swa_moe_trainer(sz: Sizes, seed: int,
+                          check_kernels: bool = True) -> None:
+    """Window and full attention layers in one stack TRAINED through the same
+    entry points: the flash kernel's band path beside its full path (32 query
+    heads over 4 K/V heads of 128), the gate on the attention's output, a
+    share of sigmoid-routed experts with its backward, and the router's bias
+    moved by the model's rule inside the one train program.  Fails on a
+    kernel that gave way to its reference, on a banded or a plain flash
+    kernel missing from the compiled step, and on a bias that did not move."""
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "swa_moe_trainer"
+    kinds = sz.swa_train_kinds
+    log(phase, preset=sz.swa_train_preset, kinds=kinds,
+        held=sz.swa_train_held, vocab=sz.swa_train_vocab,
+        why="one chip of sixteen that share each layer: the dense layer "
+            "and one whole period of routed ones at 14 B a parameter")
+    tracer.clear()
+    cfg, params, engine, batch = build_trainer(
+        sz, seed, len(kinds), {}, preset=sz.swa_train_preset, overrides=dict(
+            vocab_size=sz.swa_train_vocab, layer_types=kinds,
+            moe_experts_held=sz.swa_train_held,
+            mlp_layer_types=("dense",) + ("sparse",) * (len(kinds) - 1)),
+        micro=sz.swa_train_micro, seq=sz.swa_train_seq)
+    bias = np.asarray(params["layers"]["S"]["moe"]["router_bias"])
+    del params
+    gc.collect()
+    log(phase, params_m=round(cfg.num_params() / 1e6, 1),
+        micro_batch=sz.swa_train_micro, seq=sz.swa_train_seq,
+        attn=cfg.attn_impl, window=cfg.sliding_window)
+    placed = engine.place_batch(batch)
+    _, step_s = timed_steps(phase, engine, placed, 1, sz.swa_train_steps)
+    out = engine.train_batch(placed)
+    counts = np.asarray(out["moe_expert_counts"])
+    moved = np.abs(np.asarray(engine.state.params["layers"]["S"]["moe"][
+        "router_bias"]) - bias).max()
+    log(phase, tokens_per_second=round(
+        engine.train_batch_size * sz.swa_train_seq / step_s, 1),
+        busiest_expert_over_mean=round(float(
+            (counts.max(-1) / counts.mean(-1)).mean()), 3),
+        bias_moved_by=round(float(moved), 5),
+        **{k: round(float(out[k]), 4) for k in (
+            "loss", "moe_local_rows", "moe_rows_max", "moe_experts_hit",
+            "grad_norm")})
+    memory_line(phase, jax.devices()[0])
+    events = [s for s in tracer.spans() if s.name in (
+        "kernel/flash_attention_tiles", "kernel/grouped_matmul_tiles")]
+    log(phase, kernel_events=sorted({
+        json.dumps({"name": s.name, **s.attrs}, sort_keys=True)
+        for s in events}))
+    if not 0 < moved <= 2 * cfg.moe_bias_update_rate * (
+            2 + sz.swa_train_steps):
+        raise AssertionError(f"{phase}: the routers' biases moved by "
+                             f"{moved}, the rule's step is "
+                             f"{cfg.moe_bias_update_rate}")
+    if check_kernels:
+        bad = [s.attrs for s in events if s.attrs.get("fallback")]
+        if bad or not events:
+            raise AssertionError(f"{phase}: kernel fallbacks {bad} "
+                                 f"(events {len(events)})")
+        compiled = engine._train_step.lower(engine.state,
+                                            placed.placed).compile()
+        ma = compiled.memory_analysis()
+        log(phase, arguments_gb=round(ma.argument_size_in_bytes / 1e9, 3),
+            temp_gb=round(ma.temp_size_in_bytes / 1e9, 3),
+            output_gb=round(ma.output_size_in_bytes / 1e9, 3),
+            alias_gb=round(ma.alias_size_in_bytes / 1e9, 3))
+        text = compiled.as_text()
+        require_kernel("kernels", "train_step", text)
+        for name in ("flash_attention_fwd_band", "flash_attention_bwd_dq",
+                     "flash_attention_bwd_dkv_band", "grouped_matmul"):
+            if name not in text:
+                raise AssertionError(f"{phase}: no {name} in the train step")
 
 
 # ---------------------------------------------------------------------------
@@ -1658,6 +1750,8 @@ def main() -> int:
         phase_trainer(sz, args.seed)
         gc.collect()
         phase_latent_moe_trainer(sz, args.seed)
+        gc.collect()
+        phase_swa_moe_trainer(sz, args.seed)
         gc.collect()
         phase_server(sz, args.seed, quantize_bits=0)
         gc.collect()

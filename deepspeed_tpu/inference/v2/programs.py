@@ -1173,6 +1173,11 @@ def kind_of(model_cfg: tfm.TransformerConfig) -> ServedKind:
         return LINEAR_LATENT
     if model_cfg.kv_lora_rank:
         return LATENT
+    if model_cfg.attn_gate or model_cfg.post_branch_norm \
+            or model_cfg.mlp_layer_types:
+        from ...models.mixed_ffn import NOT_SERVED
+
+        raise NotImplementedError(NOT_SERVED)
     if model_cfg.eva_window:
         return EVA
     return STATE if model_cfg.mixer_pattern else KV

@@ -121,6 +121,20 @@ class TransformerConfig:
     # RMSNorm of q and k over the whole projection, before the split into
     # heads and before RoPE (OLMoE); v has none
     qk_norm: bool = False
+    # RMSNorm of q and k over each HEAD's ``head_dim``, one learned scale of
+    # ``head_dim`` that the heads share, after the split into heads and
+    # before RoPE (afmoe); not with ``qk_norm``
+    qk_norm_per_head: bool = False
+    # the attention's output times a sigmoid of a projection of its own
+    # (``attn.wg``, as wide as the heads) of the layer's normed input, before
+    # ``wo`` (afmoe)
+    attn_gate: bool = False
+    # the kinds of layer (of ``layer_types``) that carry NO position: q and k
+    # go unrotated there (afmoe: RoPE on "sliding" layers, none on "full")
+    nope_layer_kinds: Tuple[str, ...] = ()
+    # a norm AFTER each branch (``ln1_post`` / ``ln2_post``), before the
+    # residual add, beside the one before it (afmoe)
+    post_branch_norm: bool = False
     # PR-MoE (reference deepspeed/moe/layer.py:17 use_residual): a dense
     # "shared expert" MLP runs beside the MoE and a learned 2-way softmax
     # coefficient mixes the two outputs per token
@@ -176,9 +190,11 @@ class TransformerConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_types: Tuple[str, ...] = ()
-    # the FFN of each layer of a latent model, "dense" (``intermediate_size``)
-    # or "sparse" (routed experts of ``moe_intermediate_size`` and the shared
-    # expert), ``num_layers`` long; the parameters are stacked by kind
+    # the FFN of each layer, "dense" (``intermediate_size``) or "sparse"
+    # (routed experts of ``moe_intermediate_size`` and the shared expert),
+    # ``num_layers`` long; the parameters are stacked by kind (a latent
+    # model: models/latent_sparse.py; grouped-query attention:
+    # models/mixed_ffn.py)
     mlp_layer_types: Tuple[str, ...] = ()
     moe_intermediate_size: int = 0  # 0: an expert is ``intermediate_size`` wide
     # THE CHIP'S SHARE of the experts: the router keeps ``num_experts``
@@ -195,6 +211,13 @@ class TransformerConfig:
     # form over the whole batch (moe/dropless.balance_loss)
     moe_aux_loss_coef: float = 0.0
     moe_seq_aux: bool = False
+    # the step by which a RULE, not a gradient, moves a sigmoid router's
+    # correction bias after every optimizer step (0: the bias is a trained
+    # leaf like any other): ``b <- b + d - mean(d)`` with ``d = rate x
+    # sign(mean(c) - c)`` over the step's assignments ``c`` to each expert
+    # (DeepSeek-V3's balancing without a loss, centred; afmoe's
+    # ``load_balance_coeff``; models/mixed_ffn.py)
+    moe_bias_update_rate: float = 0.0
     # test and benchmark tooling (benchmark/selection_tap.py): a step program
     # BUILT for a config with this set carries the indexer's scores and picks
     # of its "full" layers out.  A served model's config leaves it False
@@ -275,6 +298,8 @@ class TransformerConfig:
         object.__setattr__(self, "mlp_layer_types",
                            tuple(self.mlp_layer_types))
         object.__setattr__(self, "kda_pattern", tuple(self.kda_pattern))
+        object.__setattr__(self, "nope_layer_kinds",
+                           tuple(self.nope_layer_kinds))
         if self.kda_pattern:
             from .kimi_linear import check_config as check_kda
 
@@ -283,6 +308,13 @@ class TransformerConfig:
             from .latent_sparse import check_config
 
             check_config(self)
+        elif self.mlp_layer_types:
+            from .mixed_ffn import check_config as check_mixed
+
+            check_mixed(self)
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError("qk_norm is over the whole projection, "
+                             "qk_norm_per_head over a head: one or the other")
         if self.eva_window:
             from .eva import check_config as check_eva
 
@@ -368,6 +400,10 @@ class TransformerConfig:
             from .latent_sparse import num_params
 
             return num_params(self, include_embed)
+        if self.mlp_layer_types:
+            from .mixed_ffn import num_params
+
+            return num_params(self, include_embed)
         if self.mixer_pattern:
             from .ssm_hybrid import num_params
 
@@ -378,6 +414,12 @@ class TransformerConfig:
         per_layer = h * qh + 2 * h * kvh + qh * h  # q, k, v, o
         if self.qk_norm:
             per_layer += qh + kvh
+        if self.qk_norm_per_head:
+            per_layer += 2 * self.head_dim
+        if self.attn_gate:
+            per_layer += h * qh
+        if self.post_branch_norm:
+            per_layer += 2 * h
         n_mlp = 3 * h * f if self.is_gated_mlp else 2 * h * f
         if self.num_experts > 0:
             n_mlp = n_mlp * self.num_experts + h * self.num_experts  # experts + router
@@ -527,6 +569,46 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         moe_router="sigmoid", moe_routed_scaling=2.446,
         moe_intermediate_size=1024, moe_shared_size=1024,
         moe_routing="dropless", attn_impl="flash"),
+    # arcee-ai/Trinity-Mini as published (afmoe): 26.1 B, about 3 B active;
+    # three window layers (2,048, RoPE) to one full layer WITHOUT positions;
+    # the attention's output gated by a sigmoid of a projection of its own, q
+    # and k normed a head, a norm before AND after each branch, the embedding
+    # times sqrt(hidden) (``mup_enabled``); two leading dense layers, then
+    # 128 routed experts (sigmoid, top 8, renormalised, x 2.826) and one
+    # shared; the router's bias moved by a rule (``load_balance_coeff``), no
+    # balance loss.  intermediate_size is the DENSE width
+    "trinity-mini": dict(
+        vocab_size=200192, hidden_size=2048, intermediate_size=6144,
+        num_layers=32, num_heads=32, num_kv_heads=4, head_dim_override=128,
+        max_seq_len=131072, rope_theta=10000.0, norm_eps=1e-5,
+        tie_embeddings=False, embed_scale_by_sqrt_dim=True,
+        sliding_window=2048,
+        layer_types=("sliding", "sliding", "sliding", "full"),
+        nope_layer_kinds=("full",), attn_gate=True, qk_norm_per_head=True,
+        post_branch_norm=True,
+        mlp_layer_types=("dense",) * 2 + ("sparse",) * 30,
+        num_experts=128, moe_top_k=8, moe_norm_topk=True,
+        moe_router="sigmoid", moe_routed_scaling=2.826,
+        moe_intermediate_size=1024, moe_shared_size=1024,
+        moe_bias_update_rate=0.001, moe_routing="dropless",
+        attn_impl="flash"),
+    # the same block at toy widths: one dense layer and four routed ones
+    # (sliding | sliding, full, sliding, sliding), a window of 8, 4 of 16
+    # experts held (the second share of four)
+    "tiny-trinity": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=160, num_layers=5,
+        num_heads=4, num_kv_heads=2, head_dim_override=16, max_seq_len=256,
+        rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
+        embed_scale_by_sqrt_dim=True, sliding_window=8,
+        layer_types=("sliding", "sliding", "full", "sliding", "sliding"),
+        nope_layer_kinds=("full",), attn_gate=True, qk_norm_per_head=True,
+        post_branch_norm=True,
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        num_experts=16, moe_top_k=4, moe_norm_topk=True,
+        moe_router="sigmoid", moe_routed_scaling=2.826,
+        moe_intermediate_size=48, moe_shared_size=48, moe_experts_held=4,
+        moe_first_expert=4, moe_bias_update_rate=0.001,
+        moe_routing="dropless", attn_impl="flash"),
     # the same block at toy widths: a dense layer, then K K A K K K A K A
     # over routed FFNs (a pattern that is no period repeated); 4 of 16
     # experts held (the second share of four); a chunk of 8, so that a
@@ -644,6 +726,30 @@ def _dense_init(key, shape, in_axis_size, dtype):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
+def init_attention_extras(key, cfg: TransformerConfig, L: int, pd
+                          ) -> Dict[str, Any]:
+    """What ``attn_gate`` and ``qk_norm_per_head`` add to a stack of ``L``
+    layers' ``attn`` dict (nothing for a model with neither)."""
+    h, hd = cfg.hidden_size, cfg.head_dim
+    extra: Dict[str, Any] = {}
+    if cfg.qk_norm_per_head:
+        extra["q_norm"] = {"scale": jnp.ones((L, hd), pd)}
+        extra["k_norm"] = {"scale": jnp.ones((L, hd), pd)}
+    if cfg.attn_gate:
+        extra["wg"] = _dense_init(key, (L, h, cfg.num_heads * hd), h, pd)
+    return extra
+
+
+def attention_extras_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    extra: Dict[str, Any] = {}
+    if cfg.qk_norm_per_head:
+        extra["q_norm"] = {"scale": ("layers", None)}
+        extra["k_norm"] = {"scale": ("layers", None)}
+    if cfg.attn_gate:
+        extra["wg"] = ("layers", "embed", "heads")
+    return extra
+
+
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Create the parameter pytree. Per-layer weights are stacked on a leading
     ``layers`` axis so the forward pass can ``lax.scan`` over them (a model
@@ -656,6 +762,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         from .latent_sparse import init_params as init_latent
 
         return init_latent(rng, cfg)
+    if cfg.mlp_layer_types:
+        from .mixed_ffn import init_params as init_mixed
+
+        return init_mixed(rng, cfg)
     if cfg.mixer_pattern:
         from .ssm_hybrid import init_params as init_hybrid
 
@@ -683,6 +793,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.qk_norm:
         layer["attn"]["q_norm"] = {"scale": jnp.ones((L, nh * hd), pd)}
         layer["attn"]["k_norm"] = {"scale": jnp.ones((L, nkv * hd), pd)}
+    layer["attn"].update(init_attention_extras(keys[13], cfg, L, pd))
+    if cfg.post_branch_norm:
+        layer["ln1_post"] = {"scale": norm_init((L, h), pd)}
+        layer["ln2_post"] = {"scale": norm_init((L, h), pd)}
     if cfg.eva_window:
         from .eva import init_vectors
 
@@ -747,6 +861,10 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
         from .latent_sparse import param_axes as latent_axes
 
         return latent_axes(cfg)
+    if cfg.mlp_layer_types:
+        from .mixed_ffn import param_axes as mixed_axes
+
+        return mixed_axes(cfg)
     if cfg.mixer_pattern:
         from .ssm_hybrid import param_axes as hybrid_axes
 
@@ -767,6 +885,10 @@ def param_axes(cfg: TransformerConfig, params: Optional[Dict[str, Any]] = None
     if cfg.qk_norm:
         layer["attn"]["q_norm"] = {"scale": ("layers", "heads")}
         layer["attn"]["k_norm"] = {"scale": ("layers", "kv_heads")}
+    layer["attn"].update(attention_extras_axes(cfg))
+    if cfg.post_branch_norm:
+        layer["ln1_post"] = dict(ln)
+        layer["ln2_post"] = dict(ln)
     if cfg.num_experts > 0:
         moe = {
             "router": ("layers", "embed", None),
@@ -1091,7 +1213,11 @@ def _attention_block(x, p, cfg: TransformerConfig, cos, sin, attn_fn: AttentionF
         k = qk_norm(_lin(x, p, "wk", "bk"), p, "k_norm", cfg
                     ).reshape(B, S, nkv, hd)
         v = _lin(x, p, "wv", "bv").reshape(B, S, nkv, hd)
-        if cfg.position == "rope":
+        if cfg.qk_norm_per_head:  # over each head's own width
+            with jax.named_scope("qk_norm"):
+                q = _norm(q, p["q_norm"], "rmsnorm", cfg.norm_eps)
+                k = _norm(k, p["k_norm"], "rmsnorm", cfg.norm_eps)
+        if cfg.position == "rope" and cos is not None:  # None: no position
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         if cfg.position == "alibi":
@@ -1101,7 +1227,12 @@ def _attention_block(x, p, cfg: TransformerConfig, cos, sin, attn_fn: AttentionF
                         bias=alibi_bias(nh, S)[None])
         else:
             o = attn_fn(q, k, v, causal=True)
-        return _lin(o.reshape(B, S, nh * hd), p, "wo", "bo")
+        o = o.reshape(B, S, nh * hd)
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                o = o * jax.nn.sigmoid(_lin(x, p, "wg", "bg").astype(
+                    jnp.float32)).astype(dt)
+        return _lin(o, p, "wo", "bo")
 
 
 def apply_activation(x, kind: str):
@@ -1149,6 +1280,25 @@ def _remat_policy(name: str):
     return pols[name]
 
 
+def attention_of_kind(cfg: TransformerConfig, kind: str, seq_len: int,
+                      attn_fn: Optional[AttentionFn] = None):
+    """→ (the attention function, (cos, sin)) of a layer of ``kind``
+    ("sliding" | "full"): ``attn_fn`` or the config's implementation, under
+    the kind's window; the kind's RoPE table, ``(None, None)`` for a model or
+    a kind without rotation."""
+    fn = attn_fn
+    if fn is None:
+        fn = resolve_attention(cfg.attn_impl)
+        if cfg.window_of(kind) > 0:
+            if cfg.attn_impl != "flash":
+                raise ValueError("sliding_window requires attn_impl='flash'")
+            fn = partial(fn, window=cfg.window_of(kind))
+    rope = (rope_table_of(seq_len, cfg.rot_dim, cfg.rope_of(kind))
+            if cfg.position == "rope" and kind not in cfg.nope_layer_kinds
+            else (None, None))
+    return fn, rope
+
+
 def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                    cfg: TransformerConfig,
                    attn_fn: Optional[AttentionFn] = None,
@@ -1166,6 +1316,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         from .latent_sparse import forward_hidden as latent_hidden
 
         return latent_hidden(params, tokens, cfg, attn_fn)
+    if cfg.mlp_layer_types:
+        from .mixed_ffn import forward_train as mixed_train
+
+        return mixed_train(params, tokens, cfg, attn_fn)[0]
     if cfg.mixer_pattern:
         from .ssm_hybrid import forward_hidden as hybrid_hidden
 
@@ -1183,19 +1337,8 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     # one (attention function, RoPE table) a kind of layer in the period
     period = cfg.layer_period
     B, S = tokens.shape
-    attn_fns, ropes = [], []
-    for kind in period:
-        fn = attn_fn
-        if fn is None:
-            fn = resolve_attention(cfg.attn_impl)
-            if cfg.window_of(kind) > 0:
-                if cfg.attn_impl != "flash":
-                    raise ValueError(
-                        "sliding_window requires attn_impl='flash'")
-                fn = partial(fn, window=cfg.window_of(kind))
-        attn_fns.append(fn)
-        ropes.append(rope_table_of(S, cfg.rot_dim, cfg.rope_of(kind))
-                     if cfg.position == "rope" else (None, None))
+    attn_fns, ropes = zip(*(attention_of_kind(cfg, kind, S, attn_fn)
+                            for kind in period))
 
     with jax.named_scope("embed"):
         x = embed_tokens(params, tokens, cfg)
@@ -1214,6 +1357,9 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         a_in = _norm(h, layer_params["ln1"], cfg.norm, cfg.norm_eps)
         attn_out = _attention_block(a_in, layer_params["attn"], cfg, cos, sin,
                                     attn_fn)
+        if cfg.post_branch_norm:
+            attn_out = _norm(attn_out, layer_params["ln1_post"], cfg.norm,
+                             cfg.norm_eps)
         if cfg.parallel_residual:
             # falcon/gpt-neox/phi-2: both branches read the SAME input h
             m_in = _norm(h, layer_params["ln2"], cfg.norm, cfg.norm_eps)
@@ -1229,6 +1375,9 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                 mlp_out = moe_fn(m_in, layer_params["moe"], cfg)
         else:
             mlp_out = _mlp_block(m_in, layer_params["mlp"], cfg)
+        if cfg.post_branch_norm:
+            mlp_out = _norm(mlp_out, layer_params["ln2_post"], cfg.norm,
+                            cfg.norm_eps)
         if cfg.parallel_residual:
             h = h + checkpoint_name(attn_out, "attn_out") \
                 + checkpoint_name(mlp_out, "mlp_out")

@@ -168,6 +168,15 @@ def _valid_counts(group: jax.Array, valid: jax.Array, groups: int
                    dtype=jnp.int32)
 
 
+def expert_counts(experts: jax.Array, num_experts: int) -> jax.Array:
+    """The assignments ``experts (N, k)`` makes to EACH of ``num_experts``
+    experts, int32 ``(num_experts,)``, held here or not: what a rule that
+    balances the router's load is fed (``models/mixed_ffn.py``)."""
+    with jax.named_scope("moe_route"):
+        flat = experts.reshape(-1)
+        return _valid_counts(flat, jnp.ones_like(flat), num_experts)
+
+
 def routed_ffn(x2: jax.Array, p: Dict[str, Any], cfg, *,
                routing: Optional[Routing] = None,
                valid: Optional[jax.Array] = None

@@ -251,6 +251,16 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         lse_ref[0, 0] = jnp.where(l == 0.0, -jnp.inf, lse)
 
 
+def _kernel_name(which: str, window: int, kv_len: int) -> str:
+    """A kernel's name in the compiled program and the trace:
+    ``flash_attention_<which>``, with ``_band`` behind it where the band CUTS
+    something (0 < window < the keys' length), so that a trace tells a window
+    layer's calls from a full layer's in one program.  A window that cuts
+    nothing keeps the plain name."""
+    return f"flash_attention_{which}" + ("_band" if 0 < window < kv_len
+                                         else "")
+
+
 def _pallas_call(name, kernel, grid, in_specs, out_specs, out_shape,
                  scratch_shapes, mask_tab, inputs):
     """Dispatch with or without the scalar-prefetched block-mask table;
@@ -320,7 +330,7 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
     ]
     inputs += [q, k, v]
     out, lse = _pallas_call(
-        "flash_attention_fwd",
+        _kernel_name("fwd", window, Skv),
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window,
                           has_mask=mask_tab is not None, has_seg=has_seg,
@@ -499,7 +509,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     ]
     inputs += [q, k, v, g, lse, delta]
     dk, dv = _pallas_call(
-        "flash_attention_bwd_dkv",
+        _kernel_name("bwd_dkv", window, Skv),
         functools.partial(_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq,
                           window=window, has_mask=mask_tab is not None,
@@ -547,7 +557,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     ]
     inputs += [q, k, v, g, lse, delta]
     dq = _pallas_call(
-        "flash_attention_bwd_dq",
+        _kernel_name("bwd_dq", window, Skv),
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window,
                           has_mask=mask_tab is not None, has_seg=has_seg),

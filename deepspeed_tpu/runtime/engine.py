@@ -75,10 +75,15 @@ class LazyMetrics(collections.abc.Mapping):
         if self._dev is not None:
             host = jax.device_get(self._dev)
             self._dev = None
-            self._host = {k: float(v) for k, v in host.items()}
+            # a metric that is no scalar (a rule's input: every expert's
+            # count) stays the array it is, and out of the span
+            self._host = {k: float(v) if np.ndim(v) == 0 else v
+                          for k, v in host.items()}
             if self._t_dispatch is not None and tracer.enabled:
                 tracer.add_span("train/step", self._t_dispatch,
-                                time.monotonic(), attrs=dict(self._host))
+                                time.monotonic(), attrs={
+                                    k: v for k, v in self._host.items()
+                                    if isinstance(v, float)})
         return self._host
 
     def __getitem__(self, k):
@@ -113,6 +118,17 @@ class ModelSpec:
     # optional extra aux-loss fn (e.g. MoE router losses already inside loss_fn)
     eval_fn: Optional[LossFn] = None
     flops_per_token: Optional[float] = None
+    # Leaves that a RULE of the model's moves, and no gradient (a router's
+    # balancing bias): ``rule_moved`` is a pytree of bools shaped like
+    # ``params``, True on such a leaf, and ``apply_rules(params, metrics) ->
+    # params`` moves them from the step's metrics (what ``loss_fn`` returned,
+    # a mean over the micro-batches; a metric may be an array).  The engine
+    # keeps those leaves out of differentiation, the optimizer's moments,
+    # clipping and the gradient norm, and calls the rule after the
+    # optimizer's update inside the one compiled train step.  ZeRO stages 0
+    # to 2 on the device; not with offload, PEFT or 1-bit compression yet
+    rule_moved: Any = None
+    apply_rules: Optional[Callable[[Any, Dict[str, jax.Array]], Any]] = None
 
 
 @jax.tree_util.register_pytree_node_class
@@ -221,6 +237,25 @@ class TrainingEngine:
                     "peft.lora + zero_quantized_weights is not supported "
                     "(the frozen base is already stored quantized; qwZ "
                     "would re-quantize the stage-3 gathers of int codes)")
+        if model.rule_moved is not None:
+            # rule-moved leaves ride the mask that keeps PEFT's frozen base
+            # out of gradients and optimizer state; the rule itself runs in
+            # ``step_fn``
+            off_o = config.zero_optimization.offload_optimizer
+            off_p = config.zero_optimization.offload_param
+            if self.peft_enabled or config.zero_optimization.stage >= 3 \
+                    or (off_o is not None and off_o.device_str != "none") \
+                    or (off_p is not None and off_p.device_str != "none") \
+                    or config.zenflow.enabled \
+                    or config.gradient_compression.enabled \
+                    or config.zero_optimization.zero_quantized_gradients:
+                raise ConfigError(
+                    "a model with rule-moved leaves (ModelSpec.rule_moved) "
+                    "trains at ZeRO stages 0 to 2 on the device: not with "
+                    "peft.lora, stage 3, offload, zenflow or compressed "
+                    "gradients yet")
+            self._trainable_mask = jax.tree.map(lambda moved: not moved,
+                                                model.rule_moved)
 
         # ---- sharding rules ------------------------------------------
         stage = config.zero_optimization.stage
@@ -237,7 +272,7 @@ class TrainingEngine:
         # trainable template (frozen leaves → None, absent on flatten) is the
         # shape source for everything gradient-adjacent, and the opt/grad
         # sharding tree is masked to match
-        if self.peft_enabled:
+        if self._trainable_mask is not None:
             self._trainable_template = trainable_subtree(
                 model.params, self._trainable_mask)
             self.opt_param_shardings = trainable_subtree(
@@ -378,6 +413,9 @@ class TrainingEngine:
             stage <= 2 and not self.offload_enabled
             and not self.param_offload_enabled
             and topo.dp_world_size > 1  # nothing to reduce across on 1 rank
+            # the explicit reduction stacks the metrics as scalars; a rule's
+            # metrics are arrays, so such a model reduces under GSPMD
+            and model.rule_moved is None
             and all(topo.size(ax) == 1 for ax in ("tp", "sp", "ep", "pp")))
         self._bucket_plan = None   # exact path (scatter buckets at stage ≥2)
         self._wire_plan = None     # compressed paths (flat buckets only)
@@ -544,8 +582,9 @@ class TrainingEngine:
         """Sharding tree for the optimizer state: param-like leaves get the
         *optimizer* rules (ZeRO-1/2 shard them over dp even when params are
         replicated); scalar counters replicate.  Under PEFT the state covers
-        adapter leaves only (frozen base leaves are absent, not zero-sized)."""
-        if self.peft_enabled:
+        adapter leaves only (frozen base leaves are absent, not zero-sized),
+        and never a rule-moved leaf."""
+        if self._trainable_mask is not None:
             from ..linear.optimized_linear import trainable_subtree
 
             params_sharded = trainable_subtree(params_sharded,
@@ -632,7 +671,7 @@ class TrainingEngine:
             opt_shardings = self._opt_state_shardings(params)
             self.opt_shardings = opt_shardings
             init_params = params
-            if self.peft_enabled:
+            if self._trainable_mask is not None:
                 from ..linear.optimized_linear import trainable_subtree
 
                 init_params = trainable_subtree(params, self._trainable_mask)
@@ -781,15 +820,18 @@ class TrainingEngine:
         # PEFT: differentiate w.r.t. the trainable subtree only — frozen
         # (possibly quantized) base leaves enter the forward as constants, so
         # no gradient, cotangent buffer, or reduction ever exists for them
-        peft = self.peft_enabled
+        # ... and w.r.t. no leaf that a rule moves (ModelSpec.rule_moved):
+        # one mask serves both
         tmask = self._trainable_mask
-        if peft:
+        masked = tmask is not None
+        apply_rules = self.model.apply_rules
+        if masked:
             from ..linear.optimized_linear import (merge_trainable,
                                                    trainable_subtree)
 
         def microbatch_grads(params, mb, rng, ls_state):
             def scaled_loss(p):
-                if peft:
+                if masked:
                     p = merge_trainable(p, params, tmask)
                 if qwz:
                     # ZeRO++ qwZ: stage-3 gathers ship int8 codes + scales
@@ -799,7 +841,7 @@ class TrainingEngine:
                 loss, metrics = loss_fn(p, mb, rng)
                 return scale_loss(loss, ls_state) if fp16 else loss, metrics
 
-            diff_params = trainable_subtree(params, tmask) if peft else params
+            diff_params = trainable_subtree(params, tmask) if masked else params
             (loss, metrics), grads = jax.value_and_grad(
                 scaled_loss, has_aux=True)(diff_params)
             return loss, metrics, grads
@@ -833,10 +875,10 @@ class TrainingEngine:
             _, metrics_shape = jax.eval_shape(
                 lambda p, b: loss_fn(p, b, step_rng), state.params, one_mb)
             zero_metrics = jax.tree.map(
-                lambda s: jnp.zeros((), jnp.float32), metrics_shape)
+                lambda s: jnp.zeros(s.shape, jnp.float32), metrics_shape)
 
             def accumulate(params, batch):
-                grad_tmpl = trainable_subtree(params, tmask) if peft else params
+                grad_tmpl = trainable_subtree(params, tmask) if masked else params
                 zg = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                   grad_tmpl)
 
@@ -882,7 +924,7 @@ class TrainingEngine:
                 rep = jax.tree.map(lambda _: P(), state.params)
                 grad_rep = (jax.tree.map(
                     lambda _: P(), trainable_subtree(state.params, tmask))
-                    if peft else rep)
+                    if masked else rep)
                 gspec = grad_specs if grad_specs is not None else grad_rep
                 mspec = jax.tree.map(lambda _: P(), zero_metrics)
                 nspec = (P(),) if norm_out else ()
@@ -1081,7 +1123,7 @@ class TrainingEngine:
             # --- optimizer update (skipped on overflow) ----------------
             def do_update(operand):
                 params, opt_state, grads = operand
-                upd_params = (trainable_subtree(params, tmask) if peft
+                upd_params = (trainable_subtree(params, tmask) if masked
                               else params)
                 updates, new_opt = optimizer.update(grads, opt_state,
                                                     upd_params)
@@ -1089,7 +1131,9 @@ class TrainingEngine:
                     updates = jax.tree.map(lambda u: u * lr_scale, updates)
                 new_trainable = optax.apply_updates(upd_params, updates)
                 new_params = (merge_trainable(new_trainable, params, tmask)
-                              if peft else new_trainable)
+                              if masked else new_trainable)
+                if apply_rules is not None:  # a skipped step skips it too
+                    new_params = apply_rules(new_params, metrics)
                 return new_params, new_opt
 
             def skip_update(operand):
@@ -1119,10 +1163,10 @@ class TrainingEngine:
             # GSPMD sees already-replicated values and inserts nothing.
             if self._gather_plan is not None:
                 gathered = self._coalesced_gather_fn(
-                    trainable_subtree(new_params, tmask) if peft
+                    trainable_subtree(new_params, tmask) if masked
                     else new_params)
                 new_params = (merge_trainable(gathered, new_params, tmask)
-                              if peft else gathered)
+                              if masked else gathered)
 
             # Pin the new state to its canonical shardings: prevents GSPMD
             # placement drift across steps (e.g. stage-1 params must come back
@@ -1594,7 +1638,8 @@ class TrainingEngine:
 
     def _write_monitor(self, metrics: Dict[str, float]) -> None:
         if self.monitor.enabled:
-            events = [(f"Train/{k}", v, self.global_steps) for k, v in metrics.items()]
+            events = [(f"Train/{k}", v, self.global_steps)
+                      for k, v in metrics.items() if np.ndim(v) == 0]
             self.monitor.write_events(events)
 
     # -- state accessors (reference: engine property surface) -----------

@@ -131,6 +131,12 @@ def tiled_loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array], cfg,
         from ..models.latent_sparse import forward_train
 
         x, extras = forward_train(params, tokens, cfg, attn_fn=attn_fn)
+    elif cfg.mlp_layer_types and not cfg.kv_lora_rank:
+        # dense layers ahead of routed ones: the routed layers' counters and
+        # every expert's assignments come out beside the hidden states
+        from ..models.mixed_ffn import forward_train as mixed_train
+
+        x, extras = mixed_train(params, tokens, cfg, attn_fn=attn_fn)
     else:
         x = tfm.forward_hidden(params, tokens, cfg, attn_fn=attn_fn)
     if cfg.tie_embeddings:
@@ -151,5 +157,7 @@ def tiled_loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array], cfg,
         metrics.update(extras, ce_loss=loss)
         with jax.named_scope("moe_aux"):
             loss = loss + cfg.moe_aux_loss_coef * extras["moe_aux_loss"]
+    else:  # counters alone: no balance loss is trained
+        metrics.update(extras)
     metrics["loss"] = loss
     return loss, metrics

@@ -470,6 +470,31 @@ def test_flash_attention_at_unequal_widths_compiles(one_chip, mosaic, grad,
                == (192, 128, block, block) for e in events)
 
 
+@pytest.mark.parametrize("window", [2048, 0], ids=["band", "full"])
+def test_flash_attention_band_at_16k_compiles(one_chip, mosaic, window):
+    """Trinity-Mini's two kinds of layer at the trained length: one row of
+    16,384, 32 query heads over 4 K/V heads of 128, a band of 2,048 keys or
+    the whole triangle, forward and backward.  The banded calls carry
+    ``_band`` behind their names (a trace tells them from the full layer's),
+    the full layer's keep the plain ones; blocks stay 1,024 (the window is
+    past the cap, so it is not shrunk)."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = _sds((1, 16384, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+    fn = jax.grad(lambda q_, k_, v_: flash_attention(
+        q_, k_, v_, causal=True, window=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    tracer.clear()
+    suffix = "_band" if window else ""
+    text = _compile(fn, q, kv, kv,
+                    kernels=[k + suffix for k in _FLASH_KERNELS])
+    assert ("flash_attention_fwd_band" in text) == bool(window)
+    events = _flash_events({"fwd", "dkdv", "dq"}, jnp.bfloat16)
+    assert all((e["block_q"], e["block_k"]) == (1024, 1024) for e in events)
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
                          ids=["gate-up", "down"])
 def test_grouped_matmul_backward_compiles(one_chip, mosaic, k, n):
