@@ -1401,7 +1401,8 @@ def phase_latent_moe_server(sz: Sizes, seed: int, check_kernels: bool = True
         local_share=round(local / max(made, 1), 4),
         pools={k: list(v.shape) for k, v in engine.caches.items()})
     for name in ("kernel/grouped_mixed_gemm_tiles",
-                 "kernel/latent_attention_prefill_tiles"):
+                 "kernel/latent_attention_prefill_tiles",
+                 "kernel/latent_attention_decode_tiles"):
         seen = {tuple(sorted(a.items())) for m, a in events if m == name}
         if not seen:
             raise AssertionError(f"{phase}: no {name} event")

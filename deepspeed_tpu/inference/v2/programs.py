@@ -599,8 +599,10 @@ def latent_layers(params, caches, x, positions, write_at, attend,
     leading layers unrolled, then the periods of four.  Both
     pools ride the carry whole, and so does THE SELECTION: the keys a "full"
     layer picked for every query of the step, which the "shared" layers
-    behind it attend over (a mask ``(T, S)`` for the prefill rows' queries,
-    ``index_topk`` positions a row for the rows of one token).
+    behind it attend over (a mask ``(T, S)`` for the prefill rows' queries;
+    for the rows of one token a mask ``(R, S)`` that the paged decode kernel
+    reads their context under, or ``index_topk`` positions a row where the
+    static shapes say gather: ``latent_attention.decode_gathers``).
 
     → (hidden state after the final norm, the pools, int32 stats of the
     step: held experts hit summed over the routed layers, the largest rows of
@@ -622,6 +624,10 @@ def latent_layers(params, caches, x, positions, write_at, attend,
     scale = ls.softmax_scale(cfg)
     of = stacked_layers(params["layers"])
     tiles = la.prefill_tiles(rows.chunk_len, T) if mixed else None
+    gathers = la.decode_gathers(blocks, caches["latent"], rkv, K)
+    pick_rows = la.select_rows if gathers else la.select_rows_mask
+    # the rows of one token read ctx keys: their own, just written, the last
+    ctx = jnp.where(rows.single, rows.positions + 1, 0).astype(jnp.int32)
 
     def per_row(a):  # the single rows' tokens out of the flat batch
         return a[jnp.clip(rows.q_start, 0, T - 1)] if mixed else a
@@ -644,7 +650,7 @@ def latent_layers(params, caches, x, positions, write_at, attend,
                 index = index.at[idx["I"], blk_ids[0], offsets].set(
                     ki.astype(index.dtype))
             qi, w = ls.index_queries(c_q, a_in, ip, cfg, rope, positions)
-            r_idx, r_ok = la.select_rows(
+            r_sel = pick_rows(
                 per_row(qi), per_row(w), index, idx["I"], rows.tables,
                 rows.positions, rows.single, K)
             mask = sel[0]
@@ -652,18 +658,23 @@ def latent_layers(params, caches, x, positions, write_at, attend,
                 mask = la.select_tiles(qi, w, index, idx["I"], rows.tables,
                                        tiles, rows.q_start, rows.chunk_start,
                                        K)
-            sel = (mask, r_idx, r_ok)
+            sel = (mask, r_sel)
             if cfg.dsa_tap:  # tooling only: every query's pick, packed
-                picks = la.rows_as_mask(r_idx, r_ok, S)
+                picks = la.rows_as_mask(*r_sel, S) if gathers else r_sel
                 if mixed:
                     picks = mask.at[jnp.where(rows.single, rows.q_start, T)
                                     ].set(picks, mode="drop")
                 tap = la.pack_mask(picks)
-        mask, r_idx, r_ok = sel
+        mask, r_sel = sel
         q_lat = ls.absorb(q_nope, q_rope, p["w_kvb"], W)
-        o = la.latent_decode_attention(
-            per_row(q_lat), latent, idx["A"], rows.tables, r_idx, r_ok,
-            scale=scale, latent=rkv)
+        if gathers:
+            o = la.latent_decode_attention(
+                per_row(q_lat), latent, idx["A"], rows.tables, *r_sel,
+                scale=scale, latent=rkv)
+        else:
+            o = la.latent_decode_attention_masked(
+                per_row(q_lat), latent, idx["A"], rows.tables, r_sel, ctx,
+                scale=scale, latent=rkv, k=K)
         if mixed:
             o = la.latent_prefill_attention(
                 q_lat, latent, idx["A"], rows.tables, mask, tiles,
@@ -687,7 +698,8 @@ def latent_layers(params, caches, x, positions, write_at, attend,
                 "S": letter in "Ss"}
 
     sel = (jnp.zeros((T, S) if mixed else (1, 1), bool),
-           jnp.zeros((R, K), jnp.int32), jnp.zeros((R, K), bool))
+           (jnp.zeros((R, K), jnp.int32), jnp.zeros((R, K), bool)) if gathers
+           else jnp.zeros((R, S), bool))
     carry, (moe_stats, taps) = walk_pattern(
         ls.pattern(cfg), stack_of, one_layer,
         (x, caches["latent"], caches["index"], sel))

@@ -25,11 +25,26 @@ Four named scopes, which a device trace is reduced by:
                       on a v5e, where ``lax.top_k`` takes 7.1 ms), and the
                       selection is the mask of the scores above it and of
                       as many at it, lowest positions first, as make k; for
-                      one query a row ``lax.top_k`` (the same keys, the same
-                      ties), whose indices the decode path gathers by.
-``latent_attention_decode``   one query a row: the row's selected keys are
-                      gathered from the pool (``index_topk`` x W values) and
-                      attended over.
+                      one query a row the same mask (``select_rows_mask``)
+                      for the masked decode path, or a row ``lax.top_k``
+                      (the same keys, the same ties: ``select_rows``), whose
+                      indices the gathered decode path reads by.
+``latent_attention_decode``   one query a row over the row's selected keys.
+                      MASKED, since PR 56: the row's context is streamed
+                      through its table by the paged decode kernel below
+                      (``_decode_full_kernel``, here named as this scope)
+                      and the keys the row did not pick are masked out of
+                      the scores; a row that is no row of one token in this
+                      step is neither fetched nor multiplied.  Three and a
+                      half times the gather's bytes at nine times its rate:
+                      12 live rows of 4k-17k take 0.21 ms a layer on a v5e
+                      where the gather of 16 x 2,048 rows of 640 takes 0.58
+                      (0.8 inside the step program; PERF.md section 5).
+                      GATHERED (``index_topk`` x W values a row, whatever
+                      the context) where the static shapes say so,
+                      ``decode_gathers``: under a table past
+                      ``_MASKED_UP_TO`` times the picks, and on a pool the
+                      kernel's DMAs cannot slice.
 ``latent_attention_prefill``  the rows of two tokens and more, cut into
                       tiles of ``TILE_Q`` queries of one row: a tile reads
                       its row's keys up to its last query's position, in
@@ -41,8 +56,8 @@ Four named scopes, which a device trace is reduced by:
                       are fetched once for many queries, not once a query.
                       Past about 32k of context the gathered form would win.
 
-The indexer, the top-k and the decode rows are XLA under these scopes.  The
-prefill path is ONE Pallas kernel (``_prefill_kernel``, named as its scope:
+The indexer and the top-k are XLA under these scopes.  The prefill path is
+ONE Pallas kernel (``_prefill_kernel``, named as its scope:
 what ``paged_attention._prefill_kernel`` is for K/V heads, at one KV head, a
 group of all the heads, keys ``W`` wide and values the same rows' first
 ``kv_lora_rank`` lanes).  The pool stays in HBM; the tiles, the block table
@@ -179,14 +194,13 @@ def topk_mask(scores: jax.Array, k: int) -> jax.Array:
         return pick & (scores > -jnp.inf)
 
 
-def select_rows(q: jax.Array, w: jax.Array, index_pool: jax.Array,
+def _row_scores(q: jax.Array, w: jax.Array, index_pool: jax.Array,
                 layer: jax.Array, tables: jax.Array, positions: jax.Array,
-                active: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+                active: jax.Array) -> jax.Array:
     """One query a row: ``q (R, J, D)``, ``w (R, J)``, the row's keys read
     through ``tables (R, blocks)`` from ``index_pool`` at ``layer``; the query
-    of row ``r`` sits at ``positions[r]`` and sees keys ``<=`` it.  → ``(idx
-    (R, k) int32`` positions in the row's sequence, ``ok (R, k)`` which of
-    them are real picks: all ``k`` once the row has ``k`` keys)."""
+    of row ``r`` sits at ``positions[r]`` and sees keys ``<=`` it.  → ``(R,
+    S)`` float32 scores, ``-inf`` where the row sees no key."""
     R, blocks = tables.shape
     bs, D = index_pool.shape[2], index_pool.shape[3]
     keys = index_pool[layer, tables].reshape(R, blocks * bs, D)
@@ -194,8 +208,30 @@ def select_rows(q: jax.Array, w: jax.Array, index_pool: jax.Array,
     seen = (jnp.arange(blocks * bs)[None] <= positions[:, None]) \
         & active[:, None]
     with jax.named_scope("dsa_topk"):
-        vals, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+        return jnp.where(seen, scores, -jnp.inf)
+
+
+def select_rows(q: jax.Array, w: jax.Array, index_pool: jax.Array,
+                layer: jax.Array, tables: jax.Array, positions: jax.Array,
+                active: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """The picks of one query a row (``_row_scores``'s arguments) as the
+    gather reads them → ``(idx (R, k) int32`` positions in the row's
+    sequence, ``ok (R, k)`` which of them are real picks: all ``k`` once the
+    row has ``k`` keys)."""
+    scores = _row_scores(q, w, index_pool, layer, tables, positions, active)
+    with jax.named_scope("dsa_topk"):
+        vals, idx = jax.lax.top_k(scores, k)
     return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+def select_rows_mask(q: jax.Array, w: jax.Array, index_pool: jax.Array,
+                     layer: jax.Array, tables: jax.Array,
+                     positions: jax.Array, active: jax.Array, k: int
+                     ) -> jax.Array:
+    """``select_rows``'s picks as the masked decode kernel reads them → bool
+    ``(R, S)``: the same set, key for key (``topk_mask``)."""
+    return topk_mask(
+        _row_scores(q, w, index_pool, layer, tables, positions, active), k)
 
 
 def select_tiles(q: jax.Array, w: jax.Array, index_pool: jax.Array,
@@ -242,11 +278,18 @@ def latent_decode_attention(q_lat: jax.Array, pool: jax.Array,
                             layer: jax.Array, tables: jax.Array,
                             idx: jax.Array, ok: jax.Array, *, scale: float,
                             latent: int) -> jax.Array:
-    """One query a row over the row's selected keys: ``q_lat (R, H, W)``
-    absorbed, ``idx (R, k)`` the keys' positions in the row's sequence (``ok``:
-    which are picks) → ``(R, H, latent)`` float32, the weighted sum of the
-    keys' latents (a row without a pick: zeros)."""
+    """One query a row over the row's selected keys, GATHERED: ``q_lat (R, H,
+    W)`` absorbed, ``idx (R, k)`` the keys' positions in the row's sequence
+    (``ok``: which are picks) → ``(R, H, latent)`` float32, the weighted sum
+    of the keys' latents (a row without a pick: zeros).  What
+    ``latent_decode_attention_masked`` gives way to (``decode_gathers``) and
+    what the tests hold it to; one ring event a traced call,
+    ``kernel/latent_attention_decode_tiles``, with the reason."""
     bs = pool.shape[2]
+    why = decode_gathers(tables.shape[1], pool, latent, idx.shape[1])
+    tracer.add_event("kernel/latent_attention_decode_tiles", attrs={
+        **_decode_event(q_lat, tables, bs, idx.shape[1]),
+        "form": "gathered, xla", **({why: 1} if why else {})})
     with jax.named_scope("latent_attention_decode"):
         blk = jnp.take_along_axis(tables, idx // bs, axis=1)
         g = pool[layer, blk, idx % bs]  # (R, k, W)
@@ -269,6 +312,20 @@ def latent_decode_attention(q_lat: jax.Array, pool: jax.Array,
 #: at a pool width of 640), and the fetches held in VMEM (the one multiplied
 #: and those under way behind it: ``paged_attention.pick_decode_tiles``)
 _FULL_FETCH_KEYS, _FULL_SLOTS = 1024, 3
+
+
+def _fetch_blocks(blocks: int, bs: int) -> int:
+    """Blocks a fetch of the decode kernel holds."""
+    return max(1, min(blocks, _FULL_FETCH_KEYS // bs))
+
+
+def _no_block_fetch(pool: jax.Array, latent: int) -> bool:
+    """Whether Mosaic's DMAs cannot slice this pool a block at a time: the
+    pool's rows and their value part in whole lanes, a block in whole sublane
+    groups."""
+    bs, W = pool.shape[2:]
+    return not backend.interpret() and bool(
+        W % LANES or latent % LANES or bs % (32 // pool.dtype.itemsize))
 
 
 def _decode_full_xla(q_lat, pool, layer, tables, context_lens, *,
@@ -305,13 +362,21 @@ def _decode_full_xla(q_lat, pool, layer, tables, context_lens, *,
 
 def _decode_full_kernel(layer_ref, tables_ref, ctx_ref,  # scalar prefetch
                         q_ref, pool_hbm,  # the queries, the pool in HBM
-                        o_ref,  # the output
-                        rows_ref, k_buf, copy_sems,  # scratch
-                        *, scale: float, latent: int):
+                        *rest,  # [the selection,] the output, the scratch
+                        scale: float, latent: int, masked: bool = False):
     """``paged_attention._decode_kernel`` at one key head ``W`` wide whose
     values are the keys' first ``latent`` lanes: the rows walked as ONE list
     of (row, fetch), a fetch up to ``kb`` consecutive blocks of a row's table
-    (one score slab), the DMA slots ``slots`` deep ACROSS rows."""
+    (one score slab), the DMA slots ``slots`` deep ACROSS rows.
+
+    ``masked``: the rows PICKED their keys, and the selection rides in behind
+    the pool as what it does to a score (``(rows, fetches, keys a fetch)``
+    float32: 0 for a picked key, ``_NEG`` for any other, the keys past the
+    context among them).  A fetch may then hold no key of its row's, so the
+    running maximum starts finite and the weights are taken against a bound
+    above ``_NEG`` (``_prefill_kernel``'s two)."""
+    bias_ref = rest[0] if masked else None
+    o_ref, rows_ref, k_buf, copy_sems = rest[masked:]
     rows, H, W = q_ref.shape
     slots, kb, BS, _ = k_buf.shape
     n = kb * BS
@@ -380,11 +445,18 @@ def _decode_full_kernel(layer_ref, tables_ref, ctx_ref,  # scalar prefetch
             scores = jax.lax.dot_general(
                 q, keys, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(j * BS + col < ctx, scores, -jnp.inf)
-            # every fetch holds a key the row sees: m_new is finite
+            if masked:
+                # a key the row did not pick lands on ``_NEG`` exactly
+                scores = scores + bias_ref[s, pl.ds(i, 1), :]
+            else:
+                scores = jnp.where(j * BS + col < ctx, scores, -jnp.inf)
+            # unmasked, every fetch holds a key the row sees and m_new is
+            # finite; a fetch without a PICK leaves m_new where it was, and
+            # against a bound above ``_NEG`` every weight of it is zero
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
-            p = jnp.exp(scores - m_new)
+            p = jnp.exp(scores - (jnp.maximum(m_new, 0.1 * _NEG) if masked
+                                  else m_new))
             l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
             pv = jnp.dot(p.astype(keys.dtype), keys[:, :latent],
                          preferred_element_type=jnp.float32)
@@ -393,28 +465,32 @@ def _decode_full_kernel(layer_ref, tables_ref, ctx_ref,  # scalar prefetch
         acc, _, l, *carry = jax.lax.fori_loop(
             0, jax.lax.div(end + kb - 1, kb), step,
             (jnp.zeros((H, latent), jnp.float32),
-             jnp.full((H, 1), -jnp.inf, jnp.float32),
+             jnp.full((H, 1), _NEG if masked else -jnp.inf, jnp.float32),
              jnp.zeros((H, 1), jnp.float32), *carry))
-        # a row without a context ran no step: zero
+        # a row without a context ran no step, one without a pick summed
+        # nothing: zero
         o_ref[s] = acc / jnp.where(l == 0.0, 1.0, l)
         return tuple(carry)
 
     jax.lax.fori_loop(0, rows, row, (jnp.int32(0), *ahead))
 
 
-@functools.partial(jax.jit, static_argnames=("kb", "scale", "latent",
-                                             "interpret"))
-def _decode_full_pallas(q_lat, pool, layer, tables, context_lens, *, kb: int,
-                        scale: float, latent: int, interpret: bool):
-    """The kernel's call, under a jit of its own: the step program's latent
-    layers trace and lower it once."""
+def _decode_call(q_lat, pool, layer, tables, context_lens, bias=None, *,
+                 kb: int, scale: float, latent: int, interpret: bool):
+    """The paged decode kernel's call: over a row's whole context, or
+    (``bias``: ``_decode_full_kernel``'s selection) over the keys it picked,
+    under the name of its scope."""
     R, H, W = q_lat.shape
     bs = pool.shape[2]
+    masked = bias is not None
+    selection = [bias] if masked else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(1,),
         in_specs=[pl.BlockSpec((R, H, W), lambda i, *_: (0, 0, 0)),
-                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                  *(pl.BlockSpec(b.shape, lambda i, *_: (0, 0, 0))
+                    for b in selection)],
         out_specs=pl.BlockSpec((R, H, latent), lambda i, *_: (0, 0, 0)),
         scratch_shapes=[
             pltpu.SMEM((2, R + 1), jnp.int32),
@@ -423,14 +499,47 @@ def _decode_full_pallas(q_lat, pool, layer, tables, context_lens, *, kb: int,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_decode_full_kernel, scale=scale, latent=latent),
+        functools.partial(_decode_full_kernel, scale=scale, latent=latent,
+                          masked=masked),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, H, latent), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-        name="latent_attention_decode_full",
-    )(_layer_operand(layer), tables, context_lens, q_lat, pool)
+        name="latent_attention_decode" if masked
+        else "latent_attention_decode_full",
+    )(_layer_operand(layer), tables, context_lens, q_lat, pool, *selection)
+
+
+@functools.partial(jax.jit, static_argnames=("kb", "scale", "latent",
+                                             "interpret"))
+def _decode_full_pallas(q_lat, pool, layer, tables, context_lens, *, kb: int,
+                        scale: float, latent: int, interpret: bool):
+    """The kernel's call, under a jit of its own: the step program's latent
+    layers trace and lower it once."""
+    return _decode_call(q_lat, pool, layer, tables, context_lens, kb=kb,
+                        scale=scale, latent=latent, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("kb", "scale", "latent",
+                                             "interpret"))
+def _decode_masked_pallas(q_lat, pool, layer, tables, mask, context_lens, *,
+                          kb: int, scale: float, latent: int,
+                          interpret: bool):
+    """The kernel's call over the keys ``mask (R, S)`` picks, under a jit of
+    its own; the selection is turned into the kernel's operand here, so that
+    the scope's time is the path's whole cost: a fetch's piece of a row in
+    whole lanes, the keys a row cannot see and the slots past the table
+    among the unpicked."""
+    R, S = mask.shape
+    n = kb * pool.shape[2]
+    fetches = -(-S // n)
+    seen = jnp.arange(S)[None, :] < context_lens[:, None]
+    bias = jnp.where(jnp.pad(mask & seen, ((0, 0), (0, fetches * n - S))),
+                     0.0, _NEG).astype(jnp.float32).reshape(R, fetches, n)
+    return _decode_call(q_lat, pool, layer, tables, context_lens, bias,
+                        kb=kb, scale=scale, latent=latent,
+                        interpret=interpret)
 
 
 def latent_decode_attention_full(q_lat: jax.Array, pool: jax.Array,
@@ -448,9 +557,8 @@ def latent_decode_attention_full(q_lat: jax.Array, pool: jax.Array,
     R, H, W = q_lat.shape
     bs = pool.shape[2]
     blocks = tables.shape[1]
-    kb = max(1, min(blocks, _FULL_FETCH_KEYS // bs))
-    fallback = not backend.interpret() and bool(
-        W % LANES or latent % LANES or bs % (32 // pool.dtype.itemsize))
+    kb = _fetch_blocks(blocks, bs)
+    fallback = _no_block_fetch(pool, latent)
     tracer.add_event("kernel/latent_attention_decode_full_tiles", attrs={
         "rows": R, "heads": H, "w": W, "block": bs, "s_max": blocks * bs,
         **({"fallback": 1} if fallback else
@@ -468,6 +576,67 @@ def latent_decode_attention_full(q_lat: jax.Array, pool: jax.Array,
             q_lat, pool, layer, tables, context_lens.astype(jnp.int32),
             kb=kb, scale=scale, latent=latent,
             interpret=backend.interpret())
+
+
+# ---------------------------------------------------------------------------
+# the same kernel under a pick's mask (the rows of one token of a model WITH
+# an indexer)
+# ---------------------------------------------------------------------------
+
+#: the keys of a row's table over the keys it picks, up to which the rows of
+#: one token read their picks through the kernel: it streams a row's whole
+#: context (1.8-2.0 us a fetch of 1,024 keys on a v5e) where the gather moves
+#: ``index_topk`` rows whatever the context (0.58 ms for 16 x 2,048).  Timed
+#: alone at 2,048 picks the two cross at 27k keys a table when every row
+#: stands at its table's end and at about 43k when the rows are spread over
+#: a quarter of it to the whole (PERF.md section 5, PR 56)
+_MASKED_UP_TO = 16
+
+
+def decode_gathers(blocks: int, pool: jax.Array, latent: int, k: int) -> str:
+    """Why the rows of one token of a model that picks ``k`` keys GATHER them
+    (``latent_decode_attention``), by the static shapes alone, or ``""``
+    where they read them through the kernel under the pick's mask
+    (``latent_decode_attention_masked``): ``"fallback"`` on a pool the kernel
+    cannot fetch (or a fetch's piece of the selection in no whole lanes),
+    ``"past_crossing"`` under a table so wide that the ``k`` gathered rows
+    are the fewer bytes by more than the kernel's rate makes up."""
+    bs = pool.shape[2]
+    if _no_block_fetch(pool, latent) or (
+            not backend.interpret() and _fetch_blocks(blocks, bs) * bs % LANES):
+        return "fallback"
+    return "past_crossing" if blocks * bs > _MASKED_UP_TO * k else ""
+
+
+def _decode_event(q_lat, tables, bs: int, k: int) -> dict:
+    R, H, W = q_lat.shape
+    return {"rows": R, "heads": H, "w": W, "block": bs,
+            "s_max": tables.shape[1] * bs, "k": k}
+
+
+def latent_decode_attention_masked(q_lat: jax.Array, pool: jax.Array,
+                                   layer: jax.Array, tables: jax.Array,
+                                   mask: jax.Array, context_lens: jax.Array,
+                                   *, scale: float, latent: int, k: int
+                                   ) -> jax.Array:
+    """``latent_decode_attention`` through the paged decode kernel: a row's
+    context is streamed through its table as ``latent_decode_attention_full``
+    streams it (``context_lens (R,)`` as there: 0 for a row that takes no
+    step) and the keys outside ``mask (R, S)``, the row's ``k`` picks
+    (``select_rows_mask``), are masked out of the scores → ``(R, H,
+    latent)`` float32.  One ring event a traced call,
+    ``kernel/latent_attention_decode_tiles`` (``kb`` blocks a fetch, ``slots``
+    fetches held)."""
+    bs = pool.shape[2]
+    kb = _fetch_blocks(tables.shape[1], bs)
+    tracer.add_event("kernel/latent_attention_decode_tiles", attrs={
+        **_decode_event(q_lat, tables, bs, k), "form": "masked, pallas",
+        "kb": kb, "slots": _FULL_SLOTS})
+    with jax.named_scope("latent_attention_decode"):
+        return _decode_masked_pallas(
+            q_lat, pool, layer, tables, mask,
+            context_lens.astype(jnp.int32), kb=kb, scale=scale,
+            latent=latent, interpret=backend.interpret())
 
 
 def _latent_prefill_xla(q_lat: jax.Array, pool: jax.Array, layer: jax.Array,

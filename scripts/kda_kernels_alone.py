@@ -18,7 +18,14 @@ token at a time; the XLA twin of the latent kernel).
   (carried across two mixed steps) and with keys that are nine tenths one
   direction, against the recurrence;
 * ``latent``: 48 rows' one query each over contexts of 1k-8k and of 1k-3k
-  (one row without a context), the kernel against its XLA twin.
+  (one row without a context), the kernel against its XLA twin; then
+  GLM-5.2's rows of one token (``glm52-ctx8k-sat``: 16 rows of which 12
+  live, 64 heads, 2,048 picks drawn uniformly and in runs of 64), nine
+  layers in one program: the kernel under the pick's mask against the gather
+  under tables of 8k, 17k (the cell's), 32k and 64k keys, contexts of a
+  quarter of the table up to all of it and, for the crossing, all at the
+  table's end; and what turns a row's scores into its picks, as positions
+  (``lax.top_k``) and as the kernel's mask (``topk_mask``).
 
 A measurement of the chip: without a TPU whose kind ``benchmark/peaks.json``
 names it stops before the first run.  The lines go to the output and to
@@ -26,6 +33,7 @@ names it stops before the first run.  The lines go to the output and to
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -38,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import kda_flops
+from benchmark import dsa_flops, kda_flops
 from deepspeed_tpu.ops.pallas import kda
 from deepspeed_tpu.ops.pallas import latent_attention as la
 
@@ -195,6 +203,113 @@ def case_latent(peaks):
             "diff": float(jnp.abs(got - want).max()),
             "largest": float(jnp.abs(want).max()),
             "no_context_is_zero": float(jnp.abs(got[5]).max()) == 0.0})
+    return lines + glm52_lines(peaks)
+
+
+def glm52_lines(peaks, layers=9, widths=(128, 272, 512, 1024)):
+    """GLM-5.2's rows of one token, ``layers`` layers in one program (a call
+    is 0.1-0.2 ms of the host here, a layer's kernel 0.2-0.5 ms of the
+    device) under tables of ``widths`` blocks."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "glm-5.2-ep16-w8.json")) as f:
+        model = json.load(f)
+    R, live, Hq, W, BS = 16, 12, 64, 640, 64
+    nb = R * max(widths) + 1
+    key = jax.random.PRNGKey(4)
+    pool = jax.random.normal(key, (LAYERS, nb, BS, W), jnp.bfloat16)
+    rng = np.random.default_rng(1)
+    # a layer its own queries: XLA folds calls alike into one
+    q = jax.random.normal(jax.random.fold_in(key, 1), (layers, R, Hq, W),
+                          jnp.bfloat16)
+    kw = dict(scale=256 ** -0.5, latent=model["kv_lora_rank"])
+
+    # the layer's index rides in as the layer scan hands it over, traced: the
+    # gather reads a pool of many layers at a STATIC index three times faster
+    # (0.19 against 0.58 ms a layer, PERF.md section 5), and no step program
+    # has one
+    layer_ids = jnp.arange(layers, dtype=jnp.int32) % LAYERS
+
+    def stack(attend):
+        def run(layer_ids, q, pool, tables, *picks):
+            return sum(attend(q[i], pool, layer_ids[i], tables, *picks, **kw)
+                       for i in range(layers))
+        return functools.partial(jax.jit(run), layer_ids)
+
+    gather = stack(la.latent_decode_attention)
+    lines = []
+    for blocks in widths:
+        S = blocks * BS
+        k = min(model["index_topk"], S)
+        kernel = stack(functools.partial(la.latent_decode_attention_masked,
+                                         k=k))
+        tables = jnp.asarray(
+            rng.permutation(nb - 1)[:R * blocks].reshape(R, blocks),
+            jnp.int32)
+        for contexts in ("quarter-to-all", "all"):
+            ctx = np.full(R, S) if contexts == "all" \
+                else rng.integers(S // 4, S + 1, size=R)
+            ctx[rng.permutation(R)[:R - live]] = 0
+            for drawn in ("uniform", "runs-of-64"):
+                mask = np.zeros((R, S), bool)
+                for r in np.flatnonzero(ctx):
+                    if drawn == "uniform" or ctx[r] <= k:
+                        at = rng.permutation(ctx[r])[:k]
+                    else:
+                        starts = rng.permutation(ctx[r] // 64)[:k // 64] * 64
+                        at = (starts[:, None] + np.arange(64)).reshape(-1)
+                    mask[r, at] = True
+                picked = mask.sum(axis=1)
+                idx = np.argsort(~mask, axis=1, kind="stable")[:, :k]
+                ok = np.arange(k)[None] < picked[:, None]
+                args = (q, pool, tables)
+                got, ms = timed(kernel, *args, jnp.asarray(mask),
+                                jnp.asarray(ctx, jnp.int32))
+                want, ms_xla = timed(gather, *args,
+                                     jnp.asarray(idx, jnp.int32),
+                                     jnp.asarray(ok))
+                fetched = float((-(-ctx // BS) * BS).sum())
+                lines.append({
+                    "case": "latent-glm52", "s_max": S, "contexts": contexts,
+                    "picks": drawn, "layers": layers,
+                    "engages": la.decode_gathers(blocks, pool, kw["latent"],
+                                                 k) or "masked, pallas",
+                    "keys_fetched": fetched, "keys_picked": float(picked.sum()),
+                    "ms_a_layer": ms / layers,
+                    "gather_ms_a_layer": ms_xla / layers,
+                    "hbm_ms_as_fetched": fetched * W * 2
+                    / peaks["hbm_bytes_per_s"] * 1e3,
+                    "hbm_ms_as_picked": dsa_flops.attention_bytes(
+                        model, float(picked.sum()))
+                    / peaks["hbm_bytes_per_s"] * 1e3,
+                    "mxu_ms_as_multiplied": 2.0 * fetched * Hq
+                    * (W + kw["latent"]) / peaks["bf16_flops_per_s"] * 1e3,
+                    "mxu_ms_as_picked": dsa_flops.attention_flops(
+                        model, float(picked.sum()))
+                    / peaks["bf16_flops_per_s"] * 1e3,
+                    "diff": float(jnp.abs(got - want).max()),
+                    "largest": float(jnp.abs(want).max()),
+                    "idle_rows_zero": float(
+                        jnp.abs(got[np.flatnonzero(ctx == 0)]).max()) == 0.0})
+        # a picking layer's scores into the rows' picks, both ways
+        scores = jnp.where(
+            jnp.arange(S)[None] < jnp.asarray(ctx)[:, None],
+            jax.random.normal(jax.random.fold_in(key, blocks), (R, S)),
+            -jnp.inf)
+        as_idx = jax.jit(lambda x: jax.lax.top_k(x, k))
+        as_mask = jax.jit(lambda x: la.topk_mask(x, k))
+
+        def scattered(x):
+            vals, idx = jax.lax.top_k(x, k)
+            return la.rows_as_mask(idx, vals > -jnp.inf, S)
+
+        back = jax.jit(scattered)
+        _, ms_idx = timed(as_idx, scores)
+        m, ms_mask = timed(as_mask, scores)
+        m2, ms_back = timed(back, scores)
+        lines.append({"case": "latent-glm52-pick", "s_max": S,
+                      "top_k_ms": ms_idx, "topk_mask_ms": ms_mask,
+                      "top_k_then_scatter_ms": ms_back,
+                      "same_set": bool((m == m2).all())})
     return lines
 
 

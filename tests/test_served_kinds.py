@@ -103,6 +103,8 @@ def _count(jaxpr, c):
     ("tiny-mellum2", "tiny-mellum2", {}, programs.KV, "k v k_win v_win"),
     ("tiny-nemotron3", "tiny-nemotron3", {}, programs.STATE,
      "k v ssm conv"),
+    # re-pinned by PR 56: its rows of one token read their picks through the
+    # paged decode kernel (interpret mode here), not through a gather
     ("tiny-glm52", "tiny-glm52", {}, programs.LATENT, "latent index"),
     # pinned by the PR that brought the kind (48): no parent had it
     ("tiny-evabyte", "tiny-evabyte", {}, programs.EVA,

@@ -85,6 +85,20 @@ def _compile(fn, *args, kernels) -> str:
     return text
 
 
+def _pallas_calls(jaxpr) -> list:
+    """The ``pallas_call`` equations of a jaxpr, those inside its calls too."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            found.append(e)
+        for v in e.params.values():
+            inner = getattr(v, "jaxpr", v)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
+
+
 def _pool_passes(compiled_text: str, pool) -> list:
     """The instructions of a compiled program that copy, slice or update a
     slice with a result the shape of a K/V pool ``(L, blocks, block, KV, D)``
@@ -1104,9 +1118,12 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     blocks, tables of 272) compile for the described chip.  Every GEMM runs
     its kernel (no ``kernel/*_tiles`` event with ``fallback``: the grouped
     GEMM walks K = 6144 in six tiles of 1024, all 2048 columns a tile), the
-    prefill path leaves its ring event, the lowered program names the latent
-    attention's and the indexer's scopes beside the ``moe_*`` ones, and both
-    pools are updated in place."""
+    prefill path leaves its ring event, the rows of one token read their
+    picks through the paged decode kernel under the pick's mask (PR 56: its
+    ring event, its custom call, and no gather of 16 x 2,048 rows of 640
+    left), the lowered program names the latent attention's and the
+    indexer's scopes beside the ``moe_*`` ones, and both pools are updated in
+    place."""
     import dataclasses
 
     from deepspeed_tpu.models import transformer as tfm
@@ -1142,6 +1159,13 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     for a in tiled:  # the kernel engaged: items of 64 queries, 1,024 keys
         assert (a["form"], a["sq"], a["qb"], a["kb"], a["key_chunk"]) == (
             "absorbed, masked, pallas", 64, 8, 16, 1024), a
+    rows = [a for name, a in events
+            if name == "kernel/latent_attention_decode_tiles"]
+    assert rows, "no kernel/latent_attention_decode_tiles event"
+    for a in rows:  # 16 blocks a fetch of the tables' 272, three fetches held
+        assert a == {"rows": 16, "heads": 64, "w": 640, "block": 64,
+                     "s_max": 272 * 64, "k": 2048, "form": "masked, pallas",
+                     "kb": 16, "slots": 3}, a
     text = lowered.as_text(debug_info=True)
     scopes = ["grouped_mixed_gemm", "mixed_gemm", "moe_route", "moe_dispatch",
               "moe_experts", "moe_combine", "moe_shared", "dsa_index_scores",
@@ -1162,6 +1186,10 @@ def test_glm52_step_programs_compile(one_chip, mosaic, program):
     assert bool(re.search(
         r"%latent_attention_prefill[.\d]* = .*custom_call_target="
         r'"tpu_custom_call"', compiled_text)) == (program == "mixed_step")
+    # so is the decode path, and the gather it replaced is gone
+    assert re.search(r"%latent_attention_decode[.\d]* = .*custom_call_target="
+                     r'"tpu_custom_call"', compiled_text)
+    assert "[16,2048,640]" not in compiled_text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p)) for p in pools)
     assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
@@ -1359,7 +1387,9 @@ def test_kimilinear_kernels_compile(one_chip, mosaic, kernel):
     the serving cell's sizes: the KDA decode update (49 slots of 32 heads of
     128 x 128, 20 layers, the state in place) and the paged latent decode
     over a row's whole context (48 rows, 32 absorbed heads at the pool's 640,
-    tables of 128 blocks of 64: 16 blocks a fetch, three fetches held)."""
+    tables of 128 blocks of 64: 16 blocks a fetch, three fetches held; its
+    operands and scratch as they were before the body learned to take a
+    selection, PR 56: GLM-5.2's operand rides in for GLM-5.2 alone)."""
     from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas import kda, latent_attention
 
@@ -1376,18 +1406,25 @@ def test_kimilinear_kernels_compile(one_chip, mosaic, kernel):
             kernels=["kda_decode_update"])
         event = "kernel/kda_decode_update"
     else:
-        _compile(
-            functools.partial(latent_attention.latent_decode_attention_full,
-                              scale=192 ** -0.5, latent=512),
-            sds((48, H, 640), jnp.bfloat16),
-            sds((7, 6145, 64, 640), jnp.bfloat16), sds((), jnp.int32),
-            sds((48, 128), jnp.int32), sds((48,), jnp.int32),
-            kernels=["latent_attention_decode_full"])
+        full = functools.partial(latent_attention.latent_decode_attention_full,
+                                 scale=192 ** -0.5, latent=512)
+        args = (sds((48, H, 640), jnp.bfloat16),
+                sds((7, 6145, 64, 640), jnp.bfloat16), sds((), jnp.int32),
+                sds((48, 128), jnp.int32), sds((48,), jnp.int32))
+        _compile(full, *args, kernels=["latent_attention_decode_full"])
         event = "kernel/latent_attention_decode_full_tiles"
     (attrs,) = [s.attrs for s in tracer.spans() if s.name == event]
     assert "fallback" not in attrs and "xla" not in attrs, attrs
     if kernel == "latent_decode_full":
         assert (attrs["kb"], attrs["slots"]) == (16, 3)
+        (call,) = _pallas_calls(jax.make_jaxpr(full)(*args).jaxpr)
+        assert call.params["name"] == "latent_attention_decode_full"
+        assert [str(v.aval) for v in call.params["jaxpr"].invars] == [
+            "Ref<smem>{int32[1]}", "Ref<smem>{int32[48,128]}",
+            "Ref<smem>{int32[48]}", "Ref{bfloat16[48,32,640]}",
+            "Ref<any>{bfloat16[7,6145,64,640]}", "Ref{float32[48,32,512]}",
+            "Ref<smem>{int32[2,49]}", "Ref<vmem>{bfloat16[3,16,64,640]}",
+            "Ref<semaphore_mem>{dma_sem[3,16]}"]
 
 
 @pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
