@@ -112,6 +112,22 @@ class Sizes:
     # the tapped sequences' slots of the engine's state array against the
     # reference pass's final states, of the largest element (the cell's bound)
     ssm_state_tol: float = 0.15
+    # -- a Mamba-1 mixer or attention, and a dense FFN behind each: S F S F *
+    # F S F at AI21-Jamba2-3B's widths (d_inner 5120, 16 states a channel,
+    # 20 query heads on ONE K/V head, FFN 8192, the tied 65,536-row head) is
+    # 0.6 B parameters in bfloat16; a prompt crosses the step budget three
+    # times, a second batch reuses the slots
+    selective_preset: str = "jamba2-3b"
+    selective_pattern: str = "SFSF*FSF"
+    selective_max_blocks_per_seq: int = 24
+    selective_requests: Tuple[Tuple[int, int], ...] = (
+        (1300, 8), (300, 12), (40, 10), (9, 14))
+    selective_second: Tuple[Tuple[int, int], ...] = (
+        (70, 8), (600, 6), (5, 12))
+    # the tapped rows against the reference (median, worst) and the slots'
+    # states against its final states, of the largest element
+    selective_logit_tol: Tuple[float, float] = (0.1, 0.3)
+    selective_state_tol: float = 0.05
     # -- latent attention, a learned selection of keys, a share of the experts:
     # the dense layer that picks and one period (shared shared shared full)
     # at GLM-5.2's widths with 16 of 256 experts and an eighth of the
@@ -1716,6 +1732,197 @@ def phase_linear_latent_moe_server(sz: Sizes, seed: int,
                              f"{routed}")
 
 
+def check_selective_updates(phase: str, cfg) -> None:
+    """The two Mamba-1 state updates alone, on the device at the model's
+    widths (``selective_scan`` on rows of 1, 127, 128, 129 and 300 tokens in
+    one call, each from the state of its slot, then
+    ``selective_decode_update``), against the recurrence one token at a time:
+    states and ``y`` within 1e-4 of their largest element (float32 on both
+    sides: what differs is the order of a sum of 16)."""
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+
+    di, N = cfg.mamba_d_inner, cfg.mamba_state_size
+    lens = np.array([1, 127, 128, 129, 300, 0], np.int32)
+    T, S1 = int(lens.sum()) + 11, 7
+    k = jax.random.split(jax.random.PRNGKey(11), 8)
+    x = jax.random.normal(k[0], (T, di)).astype(jnp.bfloat16)
+    delta = jax.nn.softplus(jax.random.normal(k[1], (T, di)) - 4.0)
+    A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32)[:, None],
+                          (N, di))
+    B, C = (jax.random.normal(k[i], (T, N)) for i in (2, 3))
+    D = jax.random.uniform(k[4], (di,), jnp.float32, 0.5, 1.5)
+    ssm0 = jax.random.normal(k[5], (2, S1, N, di))
+    slots = jnp.asarray([4, 0, 5, 2, 1, 6], jnp.int32)
+    fresh = jnp.asarray([False, False, True, False, False, False])
+    starts = jnp.asarray(np.cumsum(lens) - lens, jnp.int32)
+    y, new = jax.jit(ss.selective_scan)(
+        ssm0, jnp.int32(1), x, delta, A, B, C, D, starts, jnp.asarray(lens),
+        slots, fresh, jnp.asarray(lens >= 2))
+    walk = jax.jit(ss.selective_recurrence)
+    worst = 0.0
+    for r in range(1, 5):
+        a, n, slot = int(starts[r]), int(lens[r]), int(slots[r])
+        first = jnp.zeros((N, di)) if bool(fresh[r]) else ssm0[1, slot]
+        want, state = walk(x[a:a + n], delta[a:a + n], A, B[a:a + n],
+                           C[a:a + n], D, first)
+        worst = max(worst,
+                    float(jnp.abs(y[a:a + n] - want).max()
+                          / jnp.abs(want).max()),
+                    float(jnp.abs(new[1, slot] - state).max()
+                          / jnp.abs(state).max()))
+    kept = bool((new[0] == ssm0[0]).all()
+                and (new[1, [3, 4, 6]] == ssm0[1, [3, 4, 6]]).all())
+    active = jnp.ones((S1,), bool).at[3].set(False)
+    y1, dec = jax.jit(ss.selective_decode_update)(
+        ssm0, jnp.int32(0), x[:S1], delta[:S1], A, B[:S1], C[:S1], D, active,
+        jnp.zeros((S1,), bool).at[2].set(True))
+    for r in range(S1):
+        first = jnp.zeros((N, di)) if r == 2 else ssm0[0, r]
+        want, state = walk(x[r:r + 1], delta[r:r + 1], A, B[r:r + 1],
+                           C[r:r + 1], D, first)
+        if r == 3:
+            kept &= bool((dec[0, r] == ssm0[0, r]).all())
+            continue
+        worst = max(worst,
+                    float(jnp.abs(y1[r] - want[0]).max()
+                          / jnp.abs(want).max()),
+                    float(jnp.abs(dec[0, r] - state).max()
+                          / jnp.abs(state).max()))
+    log(phase, state_updates_rel=float(f"{worst:.3g}"), untouched_kept=kept,
+        d_inner=di, n=N)
+    if not (worst <= 1e-4 and kept):
+        raise AssertionError(f"{phase}: the state updates differ from the "
+                             f"recurrence by {worst:.3g} (kept {kept})")
+
+
+def phase_selective_server(sz: Sizes, seed: int, check_kernels: bool = True
+                           ) -> None:
+    """A model whose layers are a Mamba-1 mixer (or attention without
+    positions) AND a dense FFN (a cut of AI21-Jamba2-3B's pattern at its
+    published widths, bfloat16 weights, the tied head) through
+    ``InferenceEngineV2``: per-sequence state in slots beside the paged K/V
+    of ONE K/V head, a prompt chunked three times with decode rows riding in
+    its mixed steps, then a second batch through the SAME slots, then the
+    first batch once more with the logits of the engine's own step programs
+    tapped; both state updates and both paged attention kernels leave their
+    events without ``fallback``; every block and every slot free after each
+    drain.  Against ``benchmark/reference/selective_ssm_decoder.py``: the
+    tapped rows (median, worst) and the slots' states, as the serving cell
+    compares them; the two state updates alone against the recurrence."""
+    from benchmark.drivers.serve_selective import (draw_small_tensors,
+                                                   published_model,
+                                                   sequence_errors)
+    from benchmark.drivers.serve_ssm_moe import low_bits_share
+    from benchmark.logit_tap_donated import DonatedLogitTap
+    from deepspeed_tpu.inference.v2.engine import InferenceEngineV2, V2Config
+    from deepspeed_tpu.observability.trace import tracer
+
+    phase = "server-selective-bf16"
+    cfg = tfm.get_config(
+        sz.selective_preset, num_layers=len(sz.selective_pattern),
+        mixer_pattern=sz.selective_pattern, dtype="bfloat16",
+        param_dtype="bfloat16")
+    log(phase, preset=sz.selective_preset, pattern=sz.selective_pattern,
+        d_inner=cfg.mamba_d_inner, heads=cfg.num_heads, kv_heads=cfg.kv_heads,
+        params_m=round(cfg.num_params() / 1e6, 1))
+    params = jax.jit(lambda k: draw_small_tensors(
+        tfm.init_params(k, cfg), k))(jax.random.PRNGKey(seed))
+    tracer.clear()
+    engine = InferenceEngineV2(cfg, params, V2Config(
+        max_tokens_per_step=sz.max_tokens_per_step, max_seqs=sz.max_seqs,
+        block_size=sz.block_size, num_blocks=sz.num_blocks,
+        max_blocks_per_seq=sz.selective_max_blocks_per_seq))
+    rng = np.random.default_rng([seed, 58])
+    served, tapped = 0, []
+    for batch, tap_it in ((sz.selective_requests, False),
+                          (sz.selective_second, False),
+                          (sz.selective_requests, True)):
+        prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+                   for n, _ in batch]
+        tap = DonatedLogitTap(engine) if tap_it else None
+        slot_of, take = {}, engine.kv.slots.take
+        engine.kv.slots.take = lambda uid: slot_of.setdefault(uid, take(uid))
+        try:
+            uids = [engine.put(p, max_new_tokens=n)
+                    for p, (_, n) in zip(prompts, batch)]
+            whole = engine.generate_all(burst=1) if tap_it \
+                else engine.generate_all()
+        finally:
+            engine.kv.slots.take = take
+            if tap is not None:
+                tap.remove()
+        for p, u, (_, n) in zip(prompts, uids, batch):
+            if len(whole[u]) - len(p) != n:
+                raise AssertionError(f"{phase}: asked {n} tokens, got "
+                                     f"{len(whole[u]) - len(p)}")
+            served += 1
+            if tap is not None:
+                tapped.append({
+                    "prompt": len(p), "tokens": whole[u][:-1],
+                    "rows": tap.logits[u], "long": False,
+                    "state": np.asarray(
+                        engine.caches["ssm"][:, slot_of[u]], np.float32)})
+        engine.kv.check_consistency()
+        if not engine.drained():
+            raise AssertionError(
+                f"{phase}: {engine.free_state_slots} of "
+                f"{engine.total_state_slots} state slots and "
+                f"{engine.free_blocks} of {engine.total_blocks} blocks free "
+                f"after the drain")
+    steps = [s.attrs for s in tracer.spans() if s.name == "engine/step"
+             and "ssm_tokens" in s.attrs]
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    started = sum(a["state_rows_started"] for a in steps)
+    log(phase, steps=len(steps), kinds=sorted({a["kind"] for a in steps}),
+        rows_started=started, ssm_tokens=sum(a["ssm_tokens"] for a in steps),
+        slots=engine.total_state_slots, kernel_events=len(events))
+    for name in ("kernel/selective_decode_update", "kernel/selective_scan",
+                 "kernel/paged_attention_decode_tiles"):
+        seen = {tuple(sorted(a.items())) for n, a in events if n == name}
+        if not seen:
+            raise AssertionError(f"{phase}: no {name} event")
+        for attrs in sorted(seen):
+            log(phase, event=name, **dict(attrs))
+    check_prefill_tiles(phase, [
+        a for name, a in events
+        if name == "kernel/paged_attention_prefill_tiles"])
+    check_step_copies(phase)
+    fallen = [e for e in events if "fallback" in e[1]]
+    if check_kernels and fallen:
+        raise AssertionError(f"{phase}: kernels fallen back: {fallen}")
+    if started != served:
+        raise AssertionError(f"{phase}: {started} rows started from zeros, "
+                             f"{served} sequences were served")
+    model = published_model(cfg)
+    memory_line(phase, jax.local_devices()[0])
+    del engine
+    gc.collect()
+    if check_kernels:
+        check_selective_updates(phase, cfg)
+    rows, states = [], []
+    for t in tapped:
+        errs, state = sequence_errors(params, model, t, pad=256)
+        rows.append(errs)
+        states.append(state)
+    rows, states = np.concatenate(rows), np.concatenate(states)
+    low_bits = low_bits_share(np.stack([t["state"] for t in tapped]))
+    median, worst = float(np.median(rows)), float(rows.max())
+    log(phase, tapped_rows=len(rows), median_row=round(median, 4),
+        worst_row=round(worst, 4), allowed=sz.selective_logit_tol,
+        slot_state_rel=float(f"{states.max():.3g}"),
+        state_allowed=sz.selective_state_tol, state_low_bits=low_bits)
+    if not (median <= sz.selective_logit_tol[0]
+            and worst <= sz.selective_logit_tol[1]
+            and states.max() <= sz.selective_state_tol and low_bits > 0.5):
+        raise AssertionError(
+            f"{phase}: the step programs' logits or the slots' states "
+            f"differ from the reference: median row {median:.4f}, worst "
+            f"{worst:.4f} (allowed {sz.selective_logit_tol}), state "
+            f"{states.max():.4f} (allowed {sz.selective_state_tol}), low "
+            f"bits {low_bits}")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
@@ -1769,6 +1976,8 @@ def main() -> int:
         phase_eva_server(sz, args.seed)
         gc.collect()
         phase_linear_latent_moe_server(sz, args.seed)
+        gc.collect()
+        phase_selective_server(sz, args.seed)
     log("done", total_seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

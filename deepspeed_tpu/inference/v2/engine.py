@@ -30,6 +30,7 @@ from ...models import transformer as tfm
 from ...observability.recorder import recorder
 from ...observability.trace import tracer
 from ...ops.pallas.latent_attention import TILE_Q
+from ...ops.pallas.selective_scan import scan_pieces
 # ``_decode_body`` and ``_memo`` are not used here: ``benchmark/logit_tap.py``
 # imports them from this module
 from .programs import (REFUSED, _decode_body, _memo,  # noqa: F401
@@ -1336,12 +1337,22 @@ class InferenceEngineV2:
             state_rows_started=int((start == 0).sum()),
             ssm_tokens=int(n.sum()),
             ssm_state_bytes=len(n) * self._state_row_bytes)
-        if mixed:
+        if mixed and self.model_cfg.layers_of("S"):
+            # the selective scan cuts the BATCH into blocks, and a row into
+            # the segments its tokens make with them
+            q_start = np.cumsum(n) - n
+            many = n >= 2
+            counts.update(
+                ssm_scan_rows=int(many.sum()),
+                ssm_scan_tokens=int(n[many].sum()),
+                ssm_scan_pieces=int(scan_pieces(q_start, n)[many].sum()))
+        elif mixed:
             many = n[n >= 2]
             chunk = self.model_cfg.mamba_chunk_size
             counts.update(
                 ssm_scan_rows=len(many), ssm_scan_tokens=int(many.sum()),
                 ssm_scan_pieces=int((-(-many // chunk)).sum()))
+        counts["kv_blocks_used"] = self.total_blocks - self.free_blocks
         return counts
 
     def _count_latent(self, start: "np.ndarray", n: "np.ndarray", mixed: bool

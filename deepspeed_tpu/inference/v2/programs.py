@@ -26,11 +26,12 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ...models import latent_sparse, ssm_hybrid
+from ...models import latent_sparse, selective_ssm, ssm_hybrid
 from ...models import transformer as tfm
 from ...moe.dropless import serving_moe_block
 from ...ops.pallas import latent_attention
 from ...ops.pallas.mixed_gemm import LayerOf, QuantizedWeight
+from ...ops.pallas.selective_scan import selective_decode_update
 from ...ops.pallas.ssm import ssm_decode_update
 from ...ops.pallas.paged_attention import (PrefillTiles,
                                            paged_decode_attention,
@@ -318,14 +319,24 @@ def state_arrays(model_cfg: tfm.TransformerConfig, v2) -> dict:
     layers: ``ssm (L_M, slots + 1, H, P, N)`` float32 and the conv's kept
     inputs ``conv (L_M, slots + 1, K - 1, C)`` in the activation dtype
     (columns on the lanes: a last dimension of K - 1 = 3 would be padded to
-    128 in HBM).  A slot a row of the engine's table, and one scratch slot."""
+    128 in HBM).  A slot a row of the engine's table, and one scratch slot.
+    A model of Mamba-1 layers keeps ``ssm (L_S, slots + 1, N, d_inner)``
+    (one decay a (channel, state) pair: the channels on the lanes) and the
+    conv's inputs over ``x`` alone, ``conv (L_S, slots + 1, K - 1,
+    d_inner)``."""
     c, dt = model_cfg, jnp.dtype(v2.dtype)
-    L, kv_layers = c.layers_of("M"), c.layers_of("*")
+    L, kv_layers = c.layers_of("M") or c.layers_of("S"), c.layers_of("*")
     if not (L and kv_layers):
         raise NotImplementedError(
-            "a mixer_pattern model is served with at least one Mamba-2 "
+            "a mixer_pattern model is served with at least one Mamba "
             "and one attention layer")
     pool = _kv_pool(c, v2, kv_layers, v2.num_blocks)
+    if c.layers_of("S"):  # Mamba-1: no heads, the channels on the lanes
+        return {"k": pool, "v": pool,
+                "ssm": ((L, v2.max_seqs + 1, c.mamba_state_size,
+                         c.mamba_d_inner), jnp.float32),
+                "conv": ((L, v2.max_seqs + 1, c.mamba_conv_kernel - 1,
+                          c.mamba_d_inner), dt)}
     return {"k": pool, "v": pool,
             "ssm": ((L, v2.max_seqs + 1, c.mamba_num_heads, c.mamba_head_dim,
                      c.mamba_state_size), jnp.float32),
@@ -347,29 +358,85 @@ def state_rows(tables, start, n, flat=None) -> StepRows:
         row_len=n, slots=slots, valid=valid)
 
 
+def _conv_one_token(xbc, p, conv, layer, rows: StepRows):
+    """The causal conv on one token a slot ``xbc (R, C)``: over the slot's
+    kept columns (zeros for a slot that starts) and the new one, the kept
+    columns moved on in place for the slots that take the step → (the conv's
+    output, conv)."""
+    R = xbc.shape[0]
+    held = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)[:R]
+    cols = jnp.where(rows.fresh[:, None, None], 0, held)
+    out = ssm_hybrid.conv_taps(
+        [cols[:, j] for j in range(cols.shape[1])] + [xbc], p)
+    new = jnp.concatenate([cols[:, 1:], xbc[:, None]], axis=1)
+    return out, conv.at[layer, :R].set(
+        jnp.where(rows.active[:, None, None], new, held))
+
+
+def _pad_scratch(a):
+    """A row more, for the scratch slot, which takes no step."""
+    return jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))
+
+
 def _mamba_decode(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
     """A Mamba-2 layer on one token a slot: the conv over the slot's kept
     columns and the new one, one recurrence step, both states in place."""
     R = a_in.shape[0]
     z, xbc, dt = ssm_hybrid.mamba_in_proj(a_in, p)
     with jax.named_scope("ssm_conv"):
-        held = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)[:R]
-        cols = jnp.where(rows.fresh[:, None, None], 0, held)
-        out = ssm_hybrid.conv_taps(
-            [cols[:, j] for j in range(cols.shape[1])] + [xbc], p)
-        new = jnp.concatenate([cols[:, 1:], xbc[:, None]], axis=1)
-        conv = conv.at[layer, :R].set(
-            jnp.where(rows.active[:, None, None], new, held))
+        out, conv = _conv_one_token(xbc, p, conv, layer, rows)
     x, B, C, dt, A, D = ssm_hybrid.ssm_inputs(out, dt, p, cfg)
-
-    def pad(a):  # the scratch slot takes no step
-        return jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))
-
+    pad = _pad_scratch
     with jax.named_scope("ssm_scan"):
         y, ssm = ssm_decode_update(ssm, layer, pad(x), pad(dt), A, pad(B),
                                    pad(C), D, pad(rows.active),
                                    pad(rows.fresh))
     return ssm_hybrid.mamba_out(y[:R], z, p, cfg), ssm, conv
+
+
+def _selective_decode(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
+    """A Mamba-1 mixer on one token a slot: ``_mamba_decode`` with the other
+    recurrence (the conv over ``x`` alone, dt, B and C from its output)."""
+    R = a_in.shape[0]
+    x, z = selective_ssm.in_proj(a_in, p, cfg)
+    with jax.named_scope("sel_conv"):
+        out, conv = _conv_one_token(x, p, conv, layer, rows)
+    delta, A, B, C, D = selective_ssm.scan_inputs(out, p, cfg)
+    pad = _pad_scratch
+    with jax.named_scope("sel_scan"):
+        y, ssm = selective_decode_update(
+            ssm, layer, pad(out), pad(delta), A, pad(B), pad(C), D,
+            pad(rows.active), pad(rows.fresh))
+    return selective_ssm.gate_out(y[:R], z, p), ssm, conv
+
+
+def _selective_mixed(a_in, p, cfg, ssm, conv, layer, rows: StepRows):
+    """A Mamba-1 mixer on a mixed step's flat rows: the rows of two tokens
+    and more through ``selective_scan``, each from its slot's state; the
+    rows of one token in one dense pass over the slots, as ``_mamba_mixed``
+    does."""
+    S1 = ssm.shape[1]
+    many = rows.row_len >= 2
+    (z, x, delta, A, B, C, D), y, ssm, kept = selective_ssm.selective_rows(
+        a_in, p, cfg, ssm, conv, layer, rows.row, rows.offset, rows.row_start,
+        rows.row_len, rows.slots, rows.fresh, many)
+    with jax.named_scope("sel_scan"):
+        one = rows.row_len == 1
+        at = jnp.where(one, rows.slots, S1)  # past the end: dropped
+        first = jnp.clip(rows.row_start, 0, x.shape[0] - 1)
+
+        def by_slot(a):
+            return _by_slot(a, at, S1)
+
+        y1, ssm = selective_decode_update(
+            ssm, layer, by_slot(x[first]), by_slot(delta[first]), A,
+            by_slot(B[first]), by_slot(C[first]), D, by_slot(one),
+            by_slot(rows.fresh))
+        y = jnp.where((one[rows.row] & rows.valid)[:, None],
+                      y1[rows.slots[rows.row]], y)
+    with jax.named_scope("sel_conv"):
+        conv = conv.at[layer, rows.slots].set(kept)
+    return selective_ssm.gate_out(y, z, p), ssm, conv
 
 
 def _by_slot(a, at, slots: int, fill=0):
@@ -491,6 +558,7 @@ def hybrid_layers(params, caches, x, positions, write_at, attend,
     nh, nkv, hd = model_cfg.num_heads, model_cfg.kv_heads, model_cfg.head_dim
     of = stacked_layers(params["layers"])
     mamba = _mamba_decode if rows.row is None else _mamba_mixed
+    selective = _selective_decode if rows.row is None else _selective_mixed
 
     def one_layer(kind, idx, carry):
         x, k_cache, v_cache, ssm, conv = carry
@@ -501,6 +569,11 @@ def hybrid_layers(params, caches, x, positions, write_at, attend,
         if kind == "M":
             out, ssm, conv = mamba(a_in, lp["mamba"], model_cfg, ssm, conv,
                                    idx, rows)
+        elif kind == "S":
+            out, ssm, conv = selective(a_in, lp["mamba"], model_cfg, ssm,
+                                       conv, idx, rows)
+        elif kind == "F":
+            out = selective_ssm.ffn(a_in, lp["mlp"], model_cfg)
         elif kind == "E":
             out, stats = serving_moe_block(a_in, lp["moe"], model_cfg,
                                            valid=valid)
@@ -1239,7 +1312,8 @@ STATE = ServedKind(
     arrays=state_arrays, layers=hybrid_layers, step_rows=state_rows,
     moe_layers=lambda c: c.layers_of("E"), counters="_count_state",
     refuses=lambda c, v2: tuple(REFUSED),
-    because="a model that has state layers (mixer_pattern with 'M'): {does}, "
+    because="a model that has state layers (mixer_pattern with 'M' or 'S'): "
+            "{does}, "
             "and a sequence's state at an earlier position is kept nowhere "
             "(that would take a snapshot)",
     state=("ssm", "conv"))

@@ -20,6 +20,12 @@ the Mamba layer's mathematics is written once:
     ``ops/pallas/ssm.py``                                      ``ssm_scan``
     y = GroupRMSNorm(y * silu(z)), groups of d_inner / G       ``ssm_gate_norm``
     out = y W_out                                              ``ssm_out_proj``
+
+Which recurrence: this file holds Mamba-2's layer (one decay a head).  A
+pattern may instead hold ``"S"``, a Mamba-1 mixer (one decay a (channel,
+state) pair), and ``"F"``, a dense gated FFN as a sub-layer of its own
+(jamba); their pieces are ``models/selective_ssm.py``'s, and a model holds
+``"S"`` or ``"M"``, not both.
 """
 
 from __future__ import annotations
@@ -31,9 +37,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.ssm import ssd_chunk_scan
+from . import selective_ssm
 from . import transformer as tfm
 
-KINDS = ("M", "E", "*")
+KINDS = ("M", "E", "*", "S", "F")
 
 
 def layer_plan(cfg) -> Tuple[Tuple[str, int], ...]:
@@ -76,7 +83,7 @@ def init_params(rng: jax.Array, cfg) -> Dict[str, Any]:
     hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.kv_heads
     H, di, cd = cfg.mamba_num_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
     kc, fs = cfg.mamba_conv_kernel, cfg.moe_shared_size
-    Lm, Le, La = (cfg.layers_of(k) for k in KINDS)
+    Lm, Le, La = (cfg.layers_of(k) for k in "ME*")
     keys = iter(jax.random.split(rng, 24))
     dense = tfm._dense_init
 
@@ -119,12 +126,20 @@ def init_params(rng: jax.Array, cfg) -> Dict[str, Any]:
     if fs:
         layers["E"]["moe"]["sh_w_in"] = dense(next(keys), (Le, h, fs), h, pd)
         layers["E"]["moe"]["sh_w_out"] = dense(next(keys), (Le, fs, h), fs, pd)
-    return {
+    if selective_ssm.has_sublayers(cfg):
+        # a pattern of sub-layers holds the stacks it names and no other
+        layers = {k: v for k, v in {**layers, **selective_ssm.init_layers(
+            jax.random.fold_in(rng, 0x5E1), cfg)}.items()
+            if k in cfg.mixer_pattern}
+    params = {
         "embed": {"tokens": dense(next(keys), (cfg.vocab_size, h), h, pd)},
         "layers": layers,
         "final_norm": {"scale": jnp.ones((h,), pd)},
-        "lm_head": {"w": dense(next(keys), (h, cfg.vocab_size), h, pd)},
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {
+            "w": dense(next(keys), (h, cfg.vocab_size), h, pd)}
+    return params
 
 
 def param_axes(cfg) -> Dict[str, Any]:
@@ -136,28 +151,32 @@ def param_axes(cfg) -> Dict[str, Any]:
     if cfg.moe_shared_size:
         moe["sh_w_in"] = ("layers", "embed", "mlp")
         moe["sh_w_out"] = ("layers", "mlp", "embed")
-    return {
-        "embed": {"tokens": ("vocab", "embed")},
-        "layers": {
-            "M": {"norm": dict(ln), "mamba": {
-                "w_z": ("layers", "embed", "mlp"),
-                "w_xbc": ("layers", "embed", "mlp"),
-                "w_dt": ("layers", "embed", None),
-                "conv_w": ("layers", None, "mlp"),
-                "conv_b": ("layers", "mlp"),
-                "dt_bias": ("layers", None), "A_log": ("layers", None),
-                "D": ("layers", None), "norm_w": ("layers", "mlp"),
-                "w_out": ("layers", "mlp", "embed")}},
-            "E": {"norm": dict(ln), "moe": moe},
-            "*": {"norm": dict(ln), "attn": {
-                "wq": ("layers", "embed", "heads"),
-                "wk": ("layers", "embed", "kv_heads"),
-                "wv": ("layers", "embed", "kv_heads"),
-                "wo": ("layers", "heads", "embed")}},
-        },
-        "final_norm": {"scale": ("embed",)},
-        "lm_head": {"w": ("embed", "vocab")},
+    layers = {
+        "M": {"norm": dict(ln), "mamba": {
+            "w_z": ("layers", "embed", "mlp"),
+            "w_xbc": ("layers", "embed", "mlp"),
+            "w_dt": ("layers", "embed", None),
+            "conv_w": ("layers", None, "mlp"),
+            "conv_b": ("layers", "mlp"),
+            "dt_bias": ("layers", None), "A_log": ("layers", None),
+            "D": ("layers", None), "norm_w": ("layers", "mlp"),
+            "w_out": ("layers", "mlp", "embed")}},
+        "E": {"norm": dict(ln), "moe": moe},
+        "*": {"norm": dict(ln), "attn": {
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv_heads"),
+            "wv": ("layers", "embed", "kv_heads"),
+            "wo": ("layers", "heads", "embed")}},
     }
+    if selective_ssm.has_sublayers(cfg):
+        layers = {k: v for k, v in {**layers,
+                                    **selective_ssm.layer_axes()}.items()
+                  if k in cfg.mixer_pattern}
+    axes = {"embed": {"tokens": ("vocab", "embed")}, "layers": layers,
+            "final_norm": {"scale": ("embed",)}}
+    if not cfg.tie_embeddings:
+        axes["lm_head"] = {"w": ("embed", "vocab")}
+    return axes
 
 
 def num_params(cfg, include_embed: bool = True) -> int:
@@ -170,9 +189,11 @@ def num_params(cfg, include_embed: bool = True) -> int:
         "E": h + h * E + E + 2 * E * h * f + 2 * h * cfg.moe_shared_size,
         "*": h + h * qh + 2 * h * kvh + qh * h,
     }
+    if selective_ssm.has_sublayers(cfg):
+        per.update(selective_ssm.params_per_layer(cfg))
     total = sum(per[k] for k in cfg.mixer_pattern) + h
     if include_embed:
-        total += 2 * cfg.vocab_size * h
+        total += (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * h
     return total
 
 
@@ -316,6 +337,18 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array, cfg,
                 jnp.int32(0), row, offset, row_start, row_len, rows, every,
                 every)
             out = mamba_out(y, z, lp["mamba"], cfg).reshape(x.shape)
+        elif kind == "S":
+            di = cfg.mamba_d_inner
+            ssm = jnp.zeros((1, Bn + 1, N, di), jnp.float32)
+            conv = jnp.zeros((1, Bn + 1, cfg.mamba_conv_kernel - 1, di),
+                             a_in.dtype)
+            (z, *_), y, _, _ = selective_ssm.selective_rows(
+                a_in.reshape(T, -1), lp["mamba"], cfg, ssm, conv,
+                jnp.int32(0), row, offset, row_start, row_len, rows, every,
+                every)
+            out = selective_ssm.gate_out(y, z, lp["mamba"]).reshape(x.shape)
+        elif kind == "F":
+            out = selective_ssm.ffn(a_in, lp["mlp"], cfg)
         elif kind == "E":
             out, _ = serving_moe_block(a_in, lp["moe"], cfg)
         else:

@@ -158,7 +158,11 @@ class TransformerConfig:
     # ``hybrid_override_pattern``): "M" a Mamba-2 layer, "E" an MoE layer,
     # "*" an attention layer; ``num_layers`` long, and NOT one period
     # repeated.  Empty: every layer is attention + FFN.  The parameters are
-    # stacked by kind (models/ssm_hybrid.py)
+    # stacked by kind (models/ssm_hybrid.py).  "S" a Mamba-1 (selective
+    # scan) mixer and "F" a dense gated FFN, each a sub-layer ``x +
+    # f(norm(x))`` of its own (jamba's layer is a mixer AND an FFN: two
+    # letters, and ``num_layers`` counts the letters); a model holds "S" or
+    # "M", not both (models/selective_ssm.py)
     mixer_pattern: Tuple[str, ...] = ()
     # Mamba-2 sizes: d_inner = heads x head_dim, B and C in ``groups`` groups
     # of ``state`` each, a depthwise causal conv of ``conv_kernel`` taps over
@@ -169,6 +173,11 @@ class TransformerConfig:
     mamba_state_size: int = 0
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
+    # Mamba-1 (an "S" layer): d_inner = ``mamba_expand`` x hidden, one decay
+    # a (channel, state) pair, dt through a bottleneck of ``mamba_dt_rank``;
+    # ``mamba_state_size`` and ``mamba_conv_kernel`` as above
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
     # Latent attention (MLA; models/latent_sparse.py), on when kv_lora_rank >
     # 0: queries through a rank-``q_lora_rank`` bottleneck, keys and values
     # from ONE latent of ``kv_lora_rank`` values a token and one shared
@@ -284,9 +293,14 @@ class TransformerConfig:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "mixer_pattern", tuple(self.mixer_pattern))
         if self.mixer_pattern:
-            if set(self.mixer_pattern) - {"M", "E", "*"}:
+            if set(self.mixer_pattern) - {"M", "E", "*", "S", "F"}:
                 raise ValueError(f"mixer_pattern holds kinds other than 'M', "
-                                 f"'E' and '*': {self.mixer_pattern}")
+                                 f"'E', '*', 'S' and 'F': "
+                                 f"{self.mixer_pattern}")
+            if "S" in self.mixer_pattern:
+                from .selective_ssm import check_config as check_selective
+
+                check_selective(self)
             if len(self.mixer_pattern) != self.num_layers:
                 raise ValueError(
                     f"mixer_pattern names {len(self.mixer_pattern)} layers, "
@@ -357,7 +371,8 @@ class TransformerConfig:
             RopeParams(theta=self.rope_theta)
 
     def layers_of(self, kind: str) -> int:
-        """Layers of mixer kind ``kind`` ("M", "E", "*") in the pattern."""
+        """Layers of mixer kind ``kind`` ("M", "E", "*", "S", "F") in the
+        pattern."""
         return sum(k == kind for k in self.mixer_pattern)
 
     @property
@@ -372,6 +387,8 @@ class TransformerConfig:
 
     @property
     def mamba_d_inner(self) -> int:
+        if self.mamba_expand:  # Mamba-1: no heads
+            return self.mamba_expand * self.hidden_size
         return self.mamba_num_heads * self.mamba_head_dim
 
     @property
@@ -504,6 +521,19 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         num_experts=128, moe_top_k=6, moe_norm_topk=True,
         moe_router="sigmoid", moe_routed_scaling=2.5, moe_shared_size=3712,
         moe_routing="dropless", attn_impl="flash"),
+    # ai21labs/AI21-Jamba2-3B as published (jamba): 3.03 B; 28 layers of a
+    # mixer AND a dense SwiGLU FFN, written as 56 sub-layers: "S" a Mamba-1
+    # mixer, "*" attention (published layers 7 and 21: attn_layer_period 14,
+    # offset 7) without positions, "F" the FFN behind every mixer; tied head
+    "jamba2-3b": dict(
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+        num_layers=56, num_heads=20, num_kv_heads=1, head_dim_override=128,
+        max_seq_len=262144, norm_eps=1e-6, tie_embeddings=True,
+        position="none", activation="silu", gated_mlp=True,
+        mixer_pattern=tuple("".join(
+            ("*" if i % 14 == 7 else "S") + "F" for i in range(28))),
+        mamba_expand=2, mamba_state_size=16, mamba_dt_rank=160,
+        mamba_conv_kernel=4, attn_impl="flash"),
     # zai-org/GLM-5.2 as published (glm_moe_dsa): 744 B, about 40 B active;
     # latent attention (MLA), a learned indexer that picks 2,048 keys a query
     # on every fourth layer and shares the pick with the three behind it,
@@ -682,6 +712,17 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         mamba_state_size=32, mamba_conv_kernel=4, mamba_chunk_size=16,
         num_experts=8, moe_top_k=3, moe_norm_topk=True, moe_router="sigmoid",
         moe_routed_scaling=2.5, moe_shared_size=128, moe_routing="dropless"),
+    # jamba's two sub-layers at toy widths: 8 published layers (period 4,
+    # offset 2), 1 K/V head under 4 query heads, tied head
+    "tiny-jamba2": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=16,
+        num_heads=4, num_kv_heads=1, head_dim_override=16, max_seq_len=512,
+        norm_eps=1e-6, tie_embeddings=True, position="none",
+        activation="silu", gated_mlp=True,
+        mixer_pattern=tuple("".join(
+            ("*" if i % 4 == 2 else "S") + "F" for i in range(8))),
+        mamba_expand=2, mamba_state_size=16, mamba_dt_rank=8,
+        mamba_conv_kernel=4),
     "tiny-moe": dict(vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
                      num_heads=4, max_seq_len=128, num_experts=4, moe_top_k=2),
     # OLMoE's block at toy widths (tests, the benchmark's rehearsal)

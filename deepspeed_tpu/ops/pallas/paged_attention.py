@@ -544,7 +544,10 @@ def _prefill_kernel(layer_ref, tables_ref, q_start_ref, chunk_start_ref,
                     *, tiles: PrefillTiles, group: int, spans: int,
                     window: int = 0):
     span, H, D = q_ref.shape
-    _, kb, BS, KV, _ = k_buf.shape
+    if k_buf.ndim == 4:  # ONE K/V head: a block's tokens are its rows
+        (_, kb, BS, _), KV = k_buf.shape, 1
+    else:
+        _, kb, BS, KV, _ = k_buf.shape
     S = chunk_len_ref.shape[0]
     kbs = kb * BS
     small, big = tiles.small, tiles.big
@@ -661,8 +664,14 @@ def _prefill_kernel(layer_ref, tables_ref, q_start_ref, chunk_start_ref,
 
             await_fetch(slot)
             # (keys, KV, D) → (KV, keys, D): each KV head's keys together
-            kt_ref[...] = jnp.swapaxes(k_buf[slot].reshape(kbs, KV, D), 0, 1)
-            vt_ref[...] = jnp.swapaxes(v_buf[slot].reshape(kbs, KV, D), 0, 1)
+            if KV == 1:
+                kt_ref[...] = k_buf[slot].reshape(1, kbs, D)
+                vt_ref[...] = v_buf[slot].reshape(1, kbs, D)
+            else:
+                kt_ref[...] = jnp.swapaxes(
+                    k_buf[slot].reshape(kbs, KV, D), 0, 1)
+                vt_ref[...] = jnp.swapaxes(
+                    v_buf[slot].reshape(kbs, KV, D), 0, 1)
             pos = (first + i * kb) * BS + col
             keep = held & (pos <= q_abs)
             if window:
@@ -794,6 +803,15 @@ def _prefill_pallas(q, k_cache, v_cache, layer, block_tables, q_start,
     rows = group * big
     if T % span:  # whole spans: the tokens added are no row's
         q = jnp.pad(q, ((0, spans * span - T), (0, 0), (0, 0)))
+    block = (BS, KV, D)
+    if KV == 1:
+        # one K/V head (20 query heads on it: AI21-Jamba2): Mosaic's DMA
+        # cannot slice a pool whose second-minor dimension is 1 (its tile
+        # pads it to 2), so a block's tokens are viewed as its rows; the
+        # view is a bitcast, as the decode kernel's
+        block = (BS, D)
+        k_cache, v_cache = (x.reshape(x.shape[0], NB, *block)
+                            for x in (k_cache, v_cache))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(spans,),
@@ -807,8 +825,8 @@ def _prefill_pallas(q, k_cache, v_cache, layer, block_tables, q_start,
             # a row adds a tile of the largest size per ``big`` tokens it
             # holds of the span, and one more
             pltpu.SMEM((6, S + span // big), jnp.int32),
-            pltpu.VMEM((2, tiles.kb, BS, KV, D), k_cache.dtype),
-            pltpu.VMEM((2, tiles.kb, BS, KV, D), v_cache.dtype),
+            pltpu.VMEM((2, tiles.kb, *block), k_cache.dtype),
+            pltpu.VMEM((2, tiles.kb, *block), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2, tiles.kb)),
             pltpu.VMEM((KV, kbs, D), k_cache.dtype),
             pltpu.VMEM((KV, kbs, D), v_cache.dtype),

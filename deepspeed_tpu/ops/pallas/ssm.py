@@ -1,4 +1,6 @@
-"""Mamba-2 (SSD) state updates of a served model: the recurrence
+"""Mamba-2 (SSD) state updates of a served model (which recurrence: ONE
+DECAY A HEAD; Mamba-1's, one decay a (channel, state) pair, is
+``ops/pallas/selective_scan.py``): the recurrence
 
     S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (outer) B_t[g(h)]
     y_t[h] = S_t[h] C_t[g(h)] + D[h] x_t[h]

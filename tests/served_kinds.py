@@ -59,8 +59,10 @@ STEP_ATTRS = {
     "window": ({"kv_blocks_read", "kv_blocks_full", "kv_query_keys",
                 "window_blocks_freed", "blocks_used_global",
                 "blocks_used_window"}, set()),
+    # ``kv_blocks_used``: new with PR 58 (the attention layers' pool beside
+    # the state slots), on every step of the kind
     "state": ({"state_slots_used", "state_rows_started", "ssm_tokens",
-               "ssm_state_bytes"},
+               "ssm_state_bytes", "kv_blocks_used"},
               {"ssm_scan_rows", "ssm_scan_tokens", "ssm_scan_pieces"}),
     "latent": ({"dsa_keys_visible", "dsa_keys_selected",
                 "dsa_selected_single", "dsa_selected_prefill",

@@ -76,7 +76,7 @@ def test_the_cell_is_in_the_benchmark():
              if w["name"] == "evabyte-doc-bytes-sat"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("evabyte-6.5b-w8", "doc-bytes-sat", 1)
-    assert len(spec["workloads"]) == 12  # PR 51: the eleventh; PR 55
+    assert len(spec["workloads"]) == 13  # PR 51: the eleventh; PR 55, 58
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
     with open(os.path.join(ROOT, "benchmark", "traffic",
                            "doc-bytes-sat.json")) as f:

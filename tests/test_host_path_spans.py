@@ -575,8 +575,8 @@ def test_new_entry_finds_its_file_and_its_cells(name):
     else:
         assert sorted(entry["workloads"]) == sorted(moved["workloads"])
         # every serving cell: five when the entry was added, PR 40's sixth,
-        # PR 48's seventh, PR 51's eighth
-        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 8
+        # PR 48's seventh, PR 51's eighth, PR 58's ninth
+        assert moved["name"] == "itl_p90_ms" and len(entry["workloads"]) == 9
     for cell in entry["workloads"]:
         assert cell in moved["workloads"]
         assert entry in run.metrics_of(spec, "per_layer", cell)

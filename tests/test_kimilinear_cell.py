@@ -85,8 +85,9 @@ def test_the_cell_is_in_the_benchmark():
              if w["name"] == "kimilinear-reason-sat"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("kimi-linear-48b-ep8-w8", "reason-sat", 1)
-    # PR 55 added the twelfth cell and the eleventh configuration
-    assert len(spec["workloads"]) == 12 and len(spec["configs"]) == 11
+    # PR 55 added the twelfth cell and the eleventh configuration, PR 58
+    # the thirteenth and the twelfth
+    assert len(spec["workloads"]) == 13 and len(spec["configs"]) == 12
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
     entry, = [c for c in spec["configs"]
               if c["name"] == "kimi-linear-48b-ep8-w8"]

@@ -245,3 +245,34 @@ def test_q_slots_round_a_row_up_to_its_tiles():
     # tokens 0-9 | 10-299: 246 in the first span, 44 in the second | 300
     np.testing.assert_array_equal(
         spans.slots([10, 290, 1]), [128, 128 + 128 + 128, 8])
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_twenty_heads_on_one_kv_head(monkeypatch, impl, dtype):
+    """20 query heads on ONE K/V head (AI21-Jamba2's attention mixers: a
+    group that is no multiple of the sublane tile of 8, where every other
+    model has 1, 4, 8 or 16): the kernel neither pads nor falls back; with
+    one K/V head it views a block's tokens as its rows (on the chip the DMA
+    cannot slice a second-minor dimension of 1) and multiplies a slab of 20
+    x the tile's queries.  Decode rows, a chunk over several tiles and short
+    chunks in one call, against the dense formulation."""
+    import sys
+
+    from deepspeed_tpu.observability.trace import tracer
+
+    monkeypatch.setattr(sys.modules[__name__], "H", 20)
+    c = _case("30-short-rows-beside-a-chunk", 1, dtype)
+    assert c["q"].shape == (T, 20, D) and c["k"].shape[3] == 1
+    tracer.clear()
+    err = np.abs(_run(c, impl, 0) - _dense(c, 0))
+    if dtype is np.float32:
+        assert err.max() < 2e-5
+    else:  # the bounds of ``test_flat_prefill_bfloat16_operands``
+        assert err.max() < 2e-2 and err.mean() < 2e-3, (err.max(),
+                                                         err.mean())
+    if impl == "pallas":
+        (event,) = [s.attrs for s in tracer.spans()
+                    if s.name == "kernel/paged_attention_prefill_tiles"][-1:]
+        assert "fallback" not in event and (event["heads"], event["kv"]) \
+            == (20, 1)

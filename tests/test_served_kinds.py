@@ -25,7 +25,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
     ("deepseek-v2-lite", programs.LATENT), ("evabyte-6.5b", programs.EVA),
     ("tiny-evabyte", programs.EVA),
     ("kimi-linear-48b", programs.LINEAR_LATENT),
-    ("tiny-kimi-linear", programs.LINEAR_LATENT)])
+    ("tiny-kimi-linear", programs.LINEAR_LATENT),
+    ("jamba2-3b", programs.STATE), ("tiny-jamba2", programs.STATE)])
 def test_kind_of_names_the_kind(preset, kind):
     cfg = tfm.get_config(preset)
     assert programs.kind_of(cfg) is kind
@@ -63,7 +64,12 @@ def _cell(name):
     # 2 MiB of float32 state in each of the 20 KDA layers
     ("kimi-linear-48b-ep8-w8", {"latent": (7, 6145, 64, 640),
                                 "kda": (20, 49, 32, 128, 128),
-                                "conv": (20, 49, 3, 12288)}, 26, None)])
+                                "conv": (20, 49, 3, 12288)}, 26, None),
+    # the other recurrence of the STATE kind: no heads, the channels on the
+    # lanes; 32 rows at 33,280 tokens of ONE K/V head in 2 layers
+    ("jamba2-3b-bf16", {"k": (2, 16640, 64, 1, 128),
+                        "ssm": (26, 33, 16, 5120),
+                        "conv": (26, 33, 3, 5120)}, 0, None)])
 def test_arrays_of_the_served_cells(cell, shapes, moe_layers,
                                     window_default):
     """What each served configuration caches at its cell's sizes, on shapes
@@ -111,7 +117,10 @@ def _count(jaxpr, c):
      "k_sum v_sum k_win v_win"),
     # pinned by the PR that brought the kind (51): no parent had it
     ("tiny-kimi-linear", "tiny-kimi-linear", {}, programs.LINEAR_LATENT,
-     "latent kda conv")])
+     "latent kda conv"),
+    # pinned by the PR that brought the sub-layers (58): the STATE kind's
+    # other recurrence, beside ``tiny-nemotron3`` which stays the parent's
+    ("tiny-jamba2", "tiny-jamba2", {}, programs.STATE, "k v ssm conv")])
 def test_step_programs_are_the_parents(name, preset, over, kind, cached):
     """The lock on the three bodies: the mixed and the decode step of one tiny
     model a body (and a shape of the first) count, primitive by primitive, the
@@ -151,7 +160,7 @@ def test_step_programs_are_the_parents(name, preset, over, kind, cached):
         "decode": jax.make_jaxpr(e._decode_fwd)(
             e.params, e.caches, i32(S), i32(S), tables, i32(S),
             jnp.zeros(S, jnp.float32), jax.random.PRNGKey(0), i32(S))}
-    scopes = {programs.STATE: ("ssm_",),
+    scopes = {programs.STATE: ("ssm_", "sel_"),
               programs.LATENT: ("dsa_",),
               programs.EVA: ("eva_",),
               programs.LINEAR_LATENT: ("kda_",)}

@@ -1508,3 +1508,127 @@ def test_mesh_follows_the_torus(topo):
     assert t.mesh.devices.shape == (1, 1, 2, 1, 1, 2)
     assert {d.id for d in np.ravel(t.mesh.devices)} == \
         {d.id for d in topo.devices}
+
+
+@pytest.mark.parametrize("kernel", ["selective_scan",
+                                    "selective_decode_update"])
+def test_jamba2_kernels_compile(one_chip, mosaic, kernel):
+    """The two Mamba-1 state updates at AI21-Jamba2-3B's published widths
+    and the serving cell's sizes (d_inner 5120 on the lanes, 16 states a
+    channel, 33 slots, 26 layers' states in one array aliased in place; a
+    step of 512 tokens over 32 rows): both are Mosaic kernels under their own
+    names, and the scan's temp holds no ``(T, d_inner, N)`` array (168 MB)."""
+    from deepspeed_tpu.observability.trace import tracer
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+
+    f32, i32 = jnp.float32, jnp.int32
+    N, di, S1, L, T, R = 16, 5120, 33, 26, 512, 32
+    sds = functools.partial(_sds, sharding=one_chip)
+    state = sds((L, S1, N, di), f32)
+    consts = (sds((N, di), f32),)
+    tracer.clear()
+    if kernel == "selective_scan":
+        rows = (sds((R,), i32),) * 3 + (sds((R,), bool),) * 2
+        args = (state, sds((), i32), sds((T, di), jnp.bfloat16),
+                sds((T, di), f32), *consts, sds((T, N), f32),
+                sds((T, N), f32), sds((di,), f32), *rows)
+        fn = ss.selective_scan
+    else:
+        args = (state, sds((), i32), sds((S1, di), jnp.bfloat16),
+                sds((S1, di), f32), *consts, sds((S1, N), f32),
+                sds((S1, N), f32), sds((di,), f32), sds((S1,), bool),
+                sds((S1,), bool))
+        fn = ss.selective_decode_update
+    _compile(fn, *args, kernels=[kernel])
+    (attrs,) = [s.attrs for s in tracer.spans()
+                if s.name == f"kernel/{kernel}"]
+    assert "fallback" not in attrs and "xla" not in attrs, attrs
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * S1 * N * di * 4
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["decode_step", "mixed_step"])
+def test_jamba2_step_programs_compile(one_chip, mosaic, program):
+    """The two step programs of AI21-Jamba2-3B WHOLE (28 layers as 56
+    sub-layers, every width as published, bfloat16 weights, the tied head
+    over all 65,536 rows; the serving cell's engine sizes: 32 rows, 16,640
+    blocks, tables of 520) compile for the described chip: both Mamba-1
+    kernels and both paged attention kernels (20 query heads on ONE K/V
+    head) run as kernels (no ``kernel/*`` event with ``fallback``), the
+    ``sel_*`` and ``dense_ffn`` scopes are in the lowered names, the K/V pool
+    and both state arrays are updated in place, and arguments and temp stay
+    under the 14.5 GB line (the configuration file's ``as_run``)."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.v2 import engine as v2e
+    from deepspeed_tpu.inference.v2.programs import kind_of
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.observability.trace import tracer
+
+    cfg = dataclasses.replace(tfm.get_config("jamba2-3b"), dtype="bfloat16",
+                              param_dtype="bfloat16")
+    v2 = v2e.V2Config(max_tokens_per_step=512, max_seqs=32, block_size=64,
+                      num_blocks=16640, max_blocks_per_seq=520,
+                      dtype="bfloat16")
+    sds = functools.partial(_sds, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda key: tfm.init_params(key, cfg),
+                       jax.random.PRNGKey(0)))
+    assert "lm_head" not in params  # tied
+    arrays = kind_of(cfg).arrays(cfg, v2)
+    assert arrays["k"][0] == (2, 16640, 64, 1, 128)
+    assert arrays["ssm"] == ((26, 33, 16, 5120), jnp.float32)
+    assert arrays["conv"][0] == (26, 33, 3, 5120)
+    caches = {name: sds(shape, dtype)
+              for name, (shape, dtype) in arrays.items()}
+    rows = lambda dtype: sds((v2.max_seqs,), dtype)  # noqa: E731
+    tables = sds((v2.max_seqs, v2.max_blocks_per_seq), jnp.int32)
+    tracer.clear()
+    if program == "decode_step":
+        lowered = v2e.build_decode_forward(cfg, v2).lower(
+            params, caches, rows(jnp.int32), rows(jnp.int32), tables,
+            rows(jnp.int32), rows(jnp.float32), sds((2,), jnp.uint32),
+            rows(jnp.int32))
+    else:
+        tokens = lambda: sds((v2.max_tokens_per_step,), jnp.int32)  # noqa: E731
+        lowered = v2e.build_ragged_forward(cfg, v2).lower(
+            params, caches, tokens(), tokens(), tokens(), tables,
+            rows(jnp.int32), rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.int32), None, None, rows(jnp.int32))
+    events = [(s.name, s.attrs) for s in tracer.spans()
+              if s.name.startswith("kernel/")]
+    assert not [e for e in events if "fallback" in e[1]], events
+    names = {name for name, _ in events}
+    mixed = program == "mixed_step"
+    assert "kernel/selective_decode_update" in names
+    assert ("kernel/selective_scan" in names) == mixed
+    assert ("kernel/paged_attention_prefill_tiles" in names) == mixed
+    text = lowered.as_text(debug_info=True)
+    scopes = ["sel_in_proj", "sel_conv", "sel_x_proj", "sel_scan",
+              "sel_gate", "sel_out_proj", "dense_ffn",
+              "selective_decode_update"]
+    if mixed:
+        scopes += ["selective_scan", "prefill_attention"]
+    for name in scopes:
+        assert re.search(rf'[/"]{name}/', text), \
+            f"{name} is not in the lowered program's operation names"
+    compiled = lowered.compile()
+    compiled_text = compiled.as_text()
+    assert _pool_passes(compiled_text, arrays["k"][0]) == []
+    kernels = ["selective_decode_update"] + (
+        ["selective_scan", "paged_attention_prefill"] if mixed
+        else ["paged_attention_decode"])
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\d]* = .*custom_call_target="
+                         r'"tpu_custom_call"', compiled_text), kernel
+    mem = compiled.memory_analysis()
+    state = 26 * 33 * 16 * 5120 * 4
+    assert mem.alias_size_in_bytes >= \
+        2 * 2 * int(np.prod(arrays["k"][0])) + state
+    print(f"jamba2-3b-bf16 {program}: arguments "
+          f"{mem.argument_size_in_bytes}, temp {mem.temp_size_in_bytes}, "
+          f"alias {mem.alias_size_in_bytes}")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
