@@ -227,23 +227,55 @@ def conv_ragged(xbc, kept, p, row, offset, row_start, row_len):
     before a row's first token are the row's kept columns ``kept (R, K - 1,
     C)`` (the conv's last ``K - 1`` inputs of the sequence, oldest first;
     zeros for a row that starts one).  → ``(conv output (T, C), the kept
-    columns after the step (R, K - 1, C))``."""
-    T = xbc.shape[0]
-    K1 = kept.shape[1]
-    t = jnp.arange(T)
-    taps = []
-    for s in range(K1, 0, -1):  # the input s tokens back
-        own = xbc[jnp.maximum(t - s, 0)]
-        old = kept[row, jnp.clip(K1 - s + offset, 0, K1 - 1)]
-        taps.append(jnp.where((offset >= s)[:, None], own, old))
-    out = conv_taps(taps + [xbc], p)
-    # column i of the kept state is the input K1 - i tokens before the end
-    back = K1 - jnp.arange(K1)[None, :]  # (1, K1)
-    j = row_len[:, None] - back  # its offset in this step's row, or < 0
-    own = xbc[jnp.clip(row_start[:, None] + j, 0, T - 1)]
-    old = kept[jnp.arange(kept.shape[0])[:, None],
-               jnp.clip(jnp.arange(K1)[None, :] + row_len[:, None], 0, K1 - 1)]
-    return out, jnp.where((j >= 0)[..., None], own, old)
+    columns after the step (R, K - 1, C))``.
+
+    The step's tokens are read in ONE pass: the input ``s`` tokens back is
+    ``xbc`` shifted by ``s`` rows (static slices, which fuse into
+    ``conv_taps``) for every token but a row's first ``K - 1``, which reach
+    into the kept columns.  Those ``R (K - 1)`` edge outputs are computed
+    beside the pass, from the same values in the same order, and laid over
+    it.  Rows are moved by one-hot products and not by gathers, which walk
+    their rows one at a time (six of all ``T`` rows were twice the scan
+    kernel's time): one non-zero term a sum selects exactly in bfloat16
+    (float32 takes ``HIGHEST``, or the chip's dot would round).  The few rows
+    a column are held as ``(R, C)`` arrays, stacked column-major: with ``K -
+    1`` on the sublanes every operation on them pads and relays."""
+    T, C = xbc.shape
+    R, K1 = kept.shape[:2]
+    exact = None if xbc.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+    def move(hot, rows):
+        return jnp.dot(hot.astype(xbc.dtype), rows, precision=exact,
+                       preferred_element_type=jnp.float32).astype(xbc.dtype)
+
+    out = conv_taps([jnp.pad(xbc[:max(T - s, 0)], ((min(s, T), 0), (0, 0)))
+                     for s in range(K1, 0, -1)] + [xbc], p)
+    # each row's first K1 tokens, then the tokens its new kept columns are:
+    # column i is the input K1 - i before the row's end, its back[i]-th token
+    # this step where that is not negative
+    i = jnp.arange(K1)[:, None]
+    back = row_len[None, :] - K1 + i  # (K1, R)
+    at = jnp.concatenate([row_start[None, :] + i,
+                          row_start[None, :] + back]).reshape(-1, 1)
+    own = move(at == jnp.arange(T)[None, :], xbc)  # (2 K1 R, C)
+    first = [own[j * R:(j + 1) * R] for j in range(K1)]
+    last = [own[(K1 + j) * R:(K1 + j + 1) * R] for j in range(K1)]
+    old = [kept[:, j] for j in range(K1)]
+    ext = old + first  # the j-th token's input s back is column K1 + j - s
+    edge = jnp.concatenate([
+        conv_taps([ext[K1 + j - s] for s in range(K1, -1, -1)], p)
+        for j in range(K1)])  # (K1 R, C), row j R + r
+    at_edge = (offset >= 0) & (offset < K1)
+    pick = at_edge[:, None] & ((offset * R + row)[:, None]
+                               == jnp.arange(K1 * R)[None, :])
+    out = jnp.where(at_edge[:, None], move(pick, edge), out)
+    cols = []
+    for j in range(K1):  # a row of n < K1 - j tokens: the old column j + n
+        col = old[K1 - 1]
+        for n in range(K1 - 2 - j, -1, -1):
+            col = jnp.where((row_len == n)[:, None], old[j + n], col)
+        cols.append(jnp.where((back[j] >= 0)[:, None], last[j], col))
+    return out, jnp.stack(cols, axis=1)
 
 
 def ssm_inputs(xbc, dt, p, cfg):

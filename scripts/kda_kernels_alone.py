@@ -25,7 +25,10 @@ token at a time; the XLA twin of the latent kernel).
   under tables of 8k, 17k (the cell's), 32k and 64k keys, contexts of a
   quarter of the table up to all of it and, for the crossing, all at the
   table's end; and what turns a row's scores into its picks, as positions
-  (``lax.top_k``) and as the kernel's mask (``topk_mask``).
+  (``lax.top_k``) and as the kernel's mask (``topk_mask``);
+* ``conv`` (on request): the mixed step's ragged causal conv alone, at
+  ``kda_conv``'s shape among the three cells' (``ssm_hybrid.conv_ragged``;
+  the case is ``scripts/selective_kernels_alone.py``'s).
 
 A measurement of the chip: without a TPU whose kind ``benchmark/peaks.json``
 names it stops before the first run.  The lines go to the output and to
@@ -313,12 +316,22 @@ def glm52_lines(peaks, layers=9, widths=(128, 272, 512, 1024)):
     return lines
 
 
-CASES = {"decode": case_decode, "chunk": case_chunk, "latent": case_latent}
+def case_conv(peaks):
+    """The mixed step's ragged conv alone at the three cells' shapes (Kimi's
+    ``kda_conv`` is the second): ``selective_kernels_alone.py``'s case."""
+    from scripts.selective_kernels_alone import case_conv as conv
+
+    return conv(peaks)
+
+
+CASES = {"decode": case_decode, "chunk": case_chunk, "latent": case_latent,
+         "conv": case_conv}
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--cases", nargs="*", default=list(CASES))
+    ap.add_argument("--cases", nargs="*", choices=list(CASES),
+                    default=["decode", "chunk", "latent"])
     args = ap.parse_args()
     dev = jax.devices()[0]
     with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:

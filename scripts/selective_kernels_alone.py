@@ -6,13 +6,20 @@ of the same mathematics and beside the HBM time of what the rows have to move
 and ``sel_decode_roofline_pct``), and the largest difference from the oracle
 (the recurrence a token at a time) on the same operands.
 
-    chiprun -- python scripts/selective_kernels_alone.py [--cases scan decode]
+    chiprun -- python scripts/selective_kernels_alone.py [--cases scan decode conv]
 
 * ``scan``: a step of 512 tokens holding one long row (499 tokens from its
   slot's state behind 13 rows of one token, which the scan leaves alone),
   then a fresh row of 300 beside a row of 199 carried from the first call's
   state (rows whose lengths are no multiple of the block);
-* ``decode``: 16 rows of one token over the 33 slots, one of them fresh.
+* ``decode``: 16 rows of one token over the 33 slots, one of them fresh;
+* ``conv``: the mixed step's ragged causal conv alone
+  (``ssm_hybrid.conv_ragged``, not a kernel: XLA's one pass over the step's
+  tokens) at the three cells' ``(T, C, K, R)``, a row of 499 behind 13 rows of
+  one token, beside the form it had before PR 59 (two ``(T, C)`` gathers a
+  tap, kept in ``tests/served_kinds.py``): microseconds a call of each,
+  whether the two agree bit for bit on the step's real tokens and on the kept
+  columns, and float32 operands once (the one-hot product's ``HIGHEST``).
 
 A measurement of the chip: without a TPU whose kind ``benchmark/peaks.json``
 names it stops before the first run.  The lines go to the output and to
@@ -190,10 +197,74 @@ def case_decode(peaks):
              "other_layer_kept": bool((new[4] == state[4]).all())}]
 
 
+#: the mixed step's conv in the three cells that run it: (cell, T, C, K, R)
+CONV_SHAPES = (("jamba2-doc-long-sat", 512, 5120, 4, 32),
+               ("kimilinear-reason-sat", 512, 12288, 4, 48),
+               ("nemotron3-chat-wide-sat", 512, 6144, 4, 64))
+
+
+def conv_us(fn, x, kept, p, meta, repeats=5):
+    """Microseconds a call of ``fn`` (a ``conv_ragged``) timed as a step
+    program calls it: ONE program of 26 calls, each on the one before's
+    output (nothing to hoist), the host's dispatch paid once."""
+    def every_layer(x, kept):
+        def one(_, carry):
+            return fn(*carry, p, *meta)
+
+        return jax.lax.fori_loop(0, LAYERS, one, (x, kept))
+
+    program = jax.jit(every_layer)
+    jax.block_until_ready(program(x, kept))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = program(x, kept)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / (repeats * LAYERS) * 1e6
+
+
+def case_conv(peaks):
+    sys.path.insert(0, os.path.join(ROOT, "tests"))
+    from served_kinds import conv_ragged_gather
+
+    from deepspeed_tpu.models.ssm_hybrid import conv_ragged
+
+    lines = []
+    shapes = [s + (jnp.bfloat16,) for s in CONV_SHAPES]
+    for cell, t, c, k, r, dtype in shapes + [CONV_SHAPES[0] + (jnp.float32,)]:
+        key = jax.random.PRNGKey(c)
+        x = jax.random.normal(key, (t, c)).astype(dtype)
+        kept = jax.random.normal(jax.random.fold_in(key, 1),
+                                 (r, k - 1, c)).astype(dtype)
+        p = {"conv_w": jax.random.normal(jax.random.fold_in(key, 2), (k, c)),
+             "conv_b": jax.random.normal(jax.random.fold_in(key, 3), (c,))}
+        n = np.zeros(r, np.int32)
+        n[:14] = [1] * 13 + [499]  # 512 tokens, none of them padding
+        start = np.cumsum(n) - n
+        row = np.minimum(np.searchsorted(np.cumsum(n), np.arange(t), "right"),
+                         r - 1)
+        meta = tuple(jnp.asarray(a, jnp.int32) for a in
+                     (row, np.arange(t) - start[row], start, n))
+        got, got_kept = jax.jit(conv_ragged)(x, kept, p, *meta)
+        want, want_kept = jax.jit(conv_ragged_gather)(x, kept, p, *meta)
+        once = t * c * x.dtype.itemsize * 2 / peaks["hbm_bytes_per_s"]
+        lines.append({
+            "case": "conv", "cell": cell, "shape": [t, c, k, r],
+            "dtype": jnp.dtype(dtype).name,
+            "us": conv_us(conv_ragged, x, kept, p, meta),
+            "gather_us": conv_us(conv_ragged_gather, x, kept, p, meta),
+            "read_write_once_us": once * 1e6,
+            "out_identical": bool((got == want).all()),
+            "kept_identical": bool((got_kept == want_kept).all())})
+    return lines
+
+
+CASES = {"scan": case_scan, "decode": case_decode, "conv": case_conv}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cases", nargs="+", default=["scan", "decode"],
-                    choices=["scan", "decode"])
+                    choices=list(CASES))
     args = ap.parse_args()
     d = jax.devices()[0]
     with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
@@ -205,7 +276,7 @@ def main() -> int:
     peaks = table[d.device_kind]
     lines = [{"device": d.device_kind, "platform": d.platform}]
     for case in args.cases:
-        lines += {"scan": case_scan, "decode": case_decode}[case](peaks)
+        lines += CASES[case](peaks)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out",
                            "selective_kernels_alone.jsonl"), "w") as f:

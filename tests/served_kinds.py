@@ -32,6 +32,33 @@ TINY_KINDS = {
 }
 
 
+def conv_ragged_gather(xbc, kept, p, row, offset, row_start, row_len):
+    """``ssm_hybrid.conv_ragged`` as it stood before PR 59, the reference of
+    its tests and of ``scripts/selective_kernels_alone.py --cases conv``: the
+    input ``s`` tokens back as two gathers of all ``T`` rows a tap (the
+    step's own tokens; the rows' kept columns) and a ``where`` between
+    them."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.ssm_hybrid import conv_taps
+
+    T = xbc.shape[0]
+    K1 = kept.shape[1]
+    t = jnp.arange(T)
+    taps = []
+    for s in range(K1, 0, -1):  # the input s tokens back
+        own = xbc[jnp.maximum(t - s, 0)]
+        old = kept[row, jnp.clip(K1 - s + offset, 0, K1 - 1)]
+        taps.append(jnp.where((offset >= s)[:, None], own, old))
+    out = conv_taps(taps + [xbc], p)
+    back = K1 - jnp.arange(K1)[None, :]  # (1, K1)
+    j = row_len[:, None] - back  # its offset in this step's row, or < 0
+    own = xbc[jnp.clip(row_start[:, None] + j, 0, T - 1)]
+    old = kept[jnp.arange(kept.shape[0])[:, None],
+               jnp.clip(jnp.arange(K1)[None, :] + row_len[:, None], 0, K1 - 1)]
+    return out, jnp.where((j >= 0)[..., None], own, old)
+
+
 def refusal_cases(kind, model_cfg, v2) -> list:
     """(V2Config overrides, the field the refusal names) for every row of the
     refusal table that ``kind`` holds for this model, every field of the row

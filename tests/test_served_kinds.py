@@ -107,6 +107,10 @@ def _count(jaxpr, c):
      programs.KV, "k v"),
     ("tiny-olmoe", "tiny-olmoe", {}, programs.KV, "k v"),
     ("tiny-mellum2", "tiny-mellum2", {}, programs.KV, "k v k_win v_win"),
+    # "mixed" re-pinned by PR 59 here, in ``tiny-kimi-linear`` and in
+    # ``tiny-jamba2``: the mixers' ragged conv (``ssm_hybrid.conv_ragged``)
+    # reads the step's tokens through shifted slices and moves its rows by
+    # one-hot products, no gather; every "decode" entry is the parent's
     ("tiny-nemotron3", "tiny-nemotron3", {}, programs.STATE,
      "k v ssm conv"),
     # re-pinned by PR 56: its rows of one token read their picks through the

@@ -189,6 +189,20 @@ def _whole_scale_stack_copies(compiled_text: str, params) -> list:
                   if dims in stacks)
 
 
+def _scope_gathers(compiled_text: str, scope: str) -> list:
+    """The result shapes of the ``gather`` instructions of a compiled
+    program (inside its fusions too) that ``scope`` holds, as ``[dims]``.  A
+    mixer's ragged conv (``ssm_hybrid.conv_ragged``) gathers its rows' first
+    and last ``K - 1`` tokens and the kept columns, ``R (K - 1)`` rows each;
+    before PR 59 it gathered all ``T`` tokens twice a tap, six passes of
+    ``(512, 5120)`` a layer that took longer than the scan kernel."""
+    return [re.search(r"\[[\d,]*\]", result).group(0)
+            for result, rest in re.findall(
+                r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) gather\((.*)$",
+                compiled_text, re.M)
+            if re.search(rf'op_name="[^"]*/{scope}/', rest)]
+
+
 def _lower_step_program(program: str, cfg, sds, group: int = 256, **sizes):
     """``decode_step``, ``mixed_step`` or the self-draft ``spec_step`` (four
     proposals a row) of a W8A16 model of ``cfg``, lowered on shapes alone at
@@ -1489,6 +1503,9 @@ def test_kimilinear_step_programs_compile(one_chip, mosaic, program):
     for kernel in ("kda_decode_update", "latent_attention_decode_full"):
         assert re.search(rf"%{kernel}[.\d]* = .*custom_call_target="
                          r'"tpu_custom_call"', compiled_text), kernel
+    if mixed:  # the conv reads the step's tokens through shifted slices
+        gathers = _scope_gathers(compiled_text, "kda_conv")
+        assert gathers and "[512,12288]" not in gathers, gathers
     mem = compiled.memory_analysis()
     state = 20 * 49 * 32 * 128 * 128 * 4
     assert mem.alias_size_in_bytes >= 2 * int(np.prod(pools[0])) + state
@@ -1624,6 +1641,9 @@ def test_jamba2_step_programs_compile(one_chip, mosaic, program):
     for kernel in kernels:
         assert re.search(rf"%{kernel}[.\d]* = .*custom_call_target="
                          r'"tpu_custom_call"', compiled_text), kernel
+    if mixed:  # the conv reads the step's tokens through shifted slices
+        gathers = _scope_gathers(compiled_text, "sel_conv")
+        assert gathers and "[512,5120]" not in gathers, gathers
     mem = compiled.memory_analysis()
     state = 26 * 33 * 16 * 5120 * 4
     assert mem.alias_size_in_bytes >= \
