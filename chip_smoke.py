@@ -287,7 +287,6 @@ def build_trainer(sz: Sizes, seed: int, num_layers: int,
 
     spec = ModelSpec(loss_fn=loss_fn, params=params,
                      param_axes=tfm.param_axes(cfg),
-                     flops_per_token=cfg.flops_per_token(),
                      **mixed_ffn.spec_rules(params, cfg))
     config = {
         "train_micro_batch_size_per_gpu": micro or sz.train_micro,

@@ -157,8 +157,7 @@ class Trainer:
             return tfm.loss_fn(p, batch, cfg)
 
         return ModelSpec(loss_fn=loss_fn, params=params,
-                         param_axes=tfm.param_axes(cfg),
-                         flops_per_token=cfg.flops_per_token())
+                         param_axes=tfm.param_axes(cfg))
 
     def _build_config(self) -> Dict[str, Any]:
         ds = _get(self.args, "deepspeed") or _get(self.args, "hf_deepspeed_config")

@@ -402,12 +402,6 @@ class TransformerConfig:
         """Rotated head dims (partial rotary rounds down to even)."""
         return int(self.head_dim * self.partial_rotary_factor) // 2 * 2
 
-    def flops_per_token(self) -> float:
-        """Dense fwd+bwd FLOPs/token ≈ 6N + attention term (PaLM appendix B)."""
-        n_params = self.num_params(include_embed=False)
-        attn = 12 * self.num_layers * self.hidden_size * self.max_seq_len
-        return 6 * n_params + attn
-
     def num_params(self, include_embed: bool = True) -> int:
         if self.kda_pattern:
             from .kimi_linear import num_params

@@ -20,10 +20,8 @@ Exceeds the reference DeepSpeed, which ships a monitor fan-out
   ``$DSTPU_FLIGHT_DIR`` on crash or injected fault;
 * :mod:`.prometheus` — text-exposition builder (HELP/TYPE, histograms,
   labels) plus a strict format parser used as the test oracle;
-* :mod:`.replay` — workload capture at the broker, seeded heavy-tail
-  synthesis, open-loop trace replay against a replica pool, and the
-  declarative ``slo.toml`` regression gate
-  (``serving/bench.py --mode replay``).
+* :mod:`.replay` — the workload-trace schema and its capture at the
+  broker (``benchmark/loadgen.py`` is the load generator).
 
 Server surfaces (``serving/server.py``): ``GET /debug/requests`` (recent
 timelines), ``GET /debug/trace`` (Perfetto JSON), ``GET /debug/profile``
@@ -39,16 +37,13 @@ Tracing never enters a jitted computation, so the analysis budgets
 from .prometheus import (DEFAULT_MS_BUCKETS, ExpositionBuilder,
                          ExpositionError, Histogram, parse_exposition)
 from .recorder import FlightRecorder, load_dump, recorder
-from .replay import (SLOError, SLOViolation, WorkloadCapture, WorkloadError,
-                     WorkloadRequest, check_slo, load_slos, load_workload,
-                     replay_workload, save_workload, synthesize_workload)
+from .replay import (WorkloadCapture, WorkloadError, WorkloadRequest,
+                     load_workload, save_workload)
 from .trace import Span, Tracer, tracer
 
 __all__ = [
     "DEFAULT_MS_BUCKETS", "ExpositionBuilder", "ExpositionError",
-    "FlightRecorder", "Histogram", "SLOError", "SLOViolation", "Span",
-    "Tracer", "WorkloadCapture", "WorkloadError", "WorkloadRequest",
-    "check_slo", "load_dump", "load_slos",
-    "load_workload", "parse_exposition", "recorder", "replay_workload",
-    "save_workload", "synthesize_workload", "tracer",
+    "FlightRecorder", "Histogram", "Span", "Tracer", "WorkloadCapture",
+    "WorkloadError", "WorkloadRequest", "load_dump", "load_workload",
+    "parse_exposition", "recorder", "save_workload", "tracer",
 ]

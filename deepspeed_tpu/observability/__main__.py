@@ -8,8 +8,8 @@ dump as a human-readable timeline summary, or inspect a workload trace.
 
 Flight dumps show per-request phase timelines (queue → prefill → decode)
 with duration bars, an engine-step summary grouped by step kind, and the
-infra-event log.  The ``workload`` subcommand summarizes a captured or
-synthesized workload-trace JSONL (``observability/replay.py`` schema):
+infra-event log.  The ``workload`` subcommand summarizes a captured
+workload-trace JSONL (``observability/replay.py`` schema):
 arrival process, prompt/budget distributions, prefix sharing, cancels.
 For interactive digging, load the server's ``GET /debug/trace`` output in
 Perfetto (https://ui.perfetto.dev) instead.

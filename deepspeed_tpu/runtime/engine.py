@@ -117,7 +117,6 @@ class ModelSpec:
     param_axes: Any = None
     # optional extra aux-loss fn (e.g. MoE router losses already inside loss_fn)
     eval_fn: Optional[LossFn] = None
-    flops_per_token: Optional[float] = None
     # Leaves that a RULE of the model's moves, and no gradient (a router's
     # balancing bias): ``rule_moved`` is a pytree of bools shaped like
     # ``params``, True on such a leaf, and ``apply_rules(params, metrics) ->

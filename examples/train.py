@@ -63,8 +63,7 @@ def main() -> int:
             return tfm.loss_fn(p_, b, cfg)
 
     spec = ModelSpec(loss_fn=loss_fn, params=params,
-                     param_axes=tfm.param_axes(cfg),
-                     flops_per_token=cfg.flops_per_token())
+                     param_axes=tfm.param_axes(cfg))
     engine, _, _, _ = deepspeed_tpu.initialize(model=spec, config=raw)
 
     rng = np.random.default_rng(0)

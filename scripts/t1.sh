@@ -123,9 +123,8 @@ then
     exit 2
 fi
 
-# replay suite: imports the workload capture/synthesis/replay harness
-# (observability/replay.py), the packaged slo.toml gate, and the
-# bench --mode replay plumbing over both transports
+# replay suite: imports the workload schema and the broker's capture
+# (observability/replay.py) and the fleet's trace stitching
 if ! timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python -m pytest tests/test_replay.py -q --collect-only \
     -p no:cacheprovider -p no:xdist -p no:randomly >> /tmp/_t1_collect.log 2>&1

@@ -16,8 +16,7 @@ def tiny_lm_spec(preset: str = "tiny", seed: int = 0, **overrides) -> ModelSpec:
         return tfm.loss_fn(p, batch, cfg)
 
     return ModelSpec(loss_fn=loss_fn, params=params,
-                     param_axes=tfm.param_axes(cfg),
-                     flops_per_token=cfg.flops_per_token())
+                     param_axes=tfm.param_axes(cfg))
 
 
 def copy_task_batch(rng: np.random.Generator, batch_size: int, seq_len: int,

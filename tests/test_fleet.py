@@ -601,12 +601,13 @@ def test_fleet_graceful_degradation_capacity_signal(fleet_pool):
 
 def test_fleet_chaos_soak_and_clean_drain(fleet_pool, ref_fn, flight_dir):
     """The chaos gate: concurrent streams while a worker is hard-killed
-    and another has its heartbeat wedged; every stream must deliver the
-    exact greedy reference, the fleet must heal, and the final drain must
-    leave zero worker processes."""
+    and another has its heartbeat wedged, four of them arriving staggered
+    after the kill, while its slot respawns; every stream must deliver the
+    exact greedy reference, the fleet must heal with no block left pinned,
+    and the final drain must leave zero worker processes."""
     _fleet_heal(fleet_pool)
     dumps0 = len(os.listdir(flight_dir))
-    prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
+    prompts = [[i + 1, i + 2, i + 3] for i in range(10)]
     results, errors = {}, []
 
     def run(i):
@@ -618,11 +619,13 @@ def test_fleet_chaos_soak_and_clean_drain(fleet_pool, ref_fn, flight_dir):
 
     threads = [threading.Thread(target=run, args=(i,))
                for i in range(len(prompts))]
-    for t in threads:
+    for t in threads[:6]:
         t.start()
     time.sleep(0.4)  # let streams get going mid-decode
     fleet_pool.replicas[0].inject_fault({"serving.worker.hardkill": "exit"})
-    time.sleep(0.6)
+    for t in threads[6:]:
+        time.sleep(0.15)
+        t.start()
     fleet_pool.replicas[1].inject_fault({"serving.worker.hang": "hang"})
     for t in threads:
         t.join(timeout=300)
@@ -632,6 +635,11 @@ def test_fleet_chaos_soak_and_clean_drain(fleet_pool, ref_fn, flight_dir):
         assert results[i] == ref_fn(prompt, 16), f"stream {i} diverged"
     _fleet_heal(fleet_pool)
     assert len(os.listdir(flight_dir)) > dumps0
+    wait_until(lambda: all(t.num_running() == 0
+                           for t in fleet_pool.replicas if t.healthy()),
+               timeout=60.0, msg="fleet idle")
+    assert all(t.prefix_stats().get("pinned_blocks", 0) == 0
+               for t in fleet_pool.replicas if t.healthy())
     # drain: every worker process (all generations) must be gone, and the
     # parent must shed the transport fds (sockets + stdout pipes) it held
     pids = _worker_pids(fleet_pool)

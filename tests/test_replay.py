@@ -1,29 +1,23 @@
-"""Trace-driven replay harness + fleet-wide trace stitching (ISSUE 13).
+"""Workload traces + fleet-wide trace stitching (ISSUE 13, cut by ISSUE 60).
 
-Covers both tentpole halves and their acceptance criteria:
-
-* workload schema: seeded synthesis determinism, JSONL round-trip with
-  hard schema errors, broker-side live capture (arrivals, prompts,
-  budgets, cancels);
-* SLO gate: packaged ``slo.toml`` loads, unknown keys and vacuous gates
-  are hard errors, violations render as named-key diffs;
-* replay driver: a fast (seconds) seeded in-process replay smoke that is
-  deterministic (same seed → identical token streams and arrival
-  schedule), matches the uncached-forward greedy reference, leaks no KV
-  blocks, and passes the packaged SLO table — the tier-1 regression gate;
+* workload schema: JSONL round-trip with hard schema errors, the
+  inspector CLI, broker-side live capture (arrivals, prompts, budgets,
+  cancels), and the fields a load generator's traffic file needs surviving
+  capture -> file -> load;
 * cross-process stitching: under the subprocess transport, worker-side
   ``engine/step`` spans and request spans arrive over the heartbeat
   channel and appear in the front's ``/debug/trace`` under the worker's
   own pid track; a mid-stream worker kill yields ONE request timeline
   (same trace id) spanning two worker pids;
-* strict Perfetto schema validity of ``/debug/trace`` in both transports;
-* chaos replay: a worker hardkill mid-replay completes with degradation
-  reported, token-identical streams vs the greedy reference, and zero
-  leaked processes/blocks.
+* strict Perfetto schema validity of ``/debug/trace`` in both transports.
+
+What a replay under chaos held of the fleet (a hard-killed worker loses no
+token) is in ``tests/test_fleet.py``.
 """
 
 import http.client
 import json
+import random
 import threading
 import time
 
@@ -64,7 +58,7 @@ def tiny_model():
 @pytest.fixture(scope="module")
 def ref_fn(tiny_model):
     """Greedy continuation via the plain uncached forward — the oracle
-    every replay (including chaos failover replays) must match."""
+    every stream (a failed-over one included) must match."""
     cfg, params = tiny_model
     cache = {}
 
@@ -83,37 +77,29 @@ def ref_fn(tiny_model):
 
 
 # ---------------------------------------------------------------------------
-# workload schema: synthesis + JSONL round-trip
+# workload schema: JSONL round-trip
 # ---------------------------------------------------------------------------
 
 
-def test_synthesis_is_seed_deterministic():
-    m1, w1 = rp.synthesize_workload(seed=7, num_requests=32,
-                                    cancel_fraction=0.1)
-    m2, w2 = rp.synthesize_workload(seed=7, num_requests=32,
-                                    cancel_fraction=0.1)
-    assert w1 == w2 and m1 == m2
-    _, w3 = rp.synthesize_workload(seed=8, num_requests=32)
-    assert [r.prompt for r in w1] != [r.prompt for r in w3]
-    # arrival schedule starts at 0 and is nondecreasing (Gamma gaps)
-    offs = [r.offset_s for r in w1]
-    assert offs[0] == 0.0 and offs == sorted(offs)
-    # bounded-Zipf template reuse: the hot template prefix is shared
-    prefixes = {}
-    for r in w1:
-        prefixes.setdefault(tuple(r.prompt[:12]), 0)
-        prefixes[tuple(r.prompt[:12])] += 1
-    assert max(prefixes.values()) > 1, "no prefix sharing synthesized"
-    assert len(prefixes) <= 4  # num_templates
-    # suffixes are unique per request within a template
-    assert len({tuple(r.prompt) for r in w1}) == len(w1)
-    assert all(1 <= (r.max_new_tokens or 0) <= 8 for r in w1)
-    assert any(r.cancel_after_s is not None for r in w1)
+def _workload(seed, n):
+    """``n`` requests over four shared 12-token templates with unique
+    suffixes, seeded gaps and budgets, every fourth cancelled."""
+    rng = random.Random(seed)
+    templates = [[rng.randrange(1, 250) for _ in range(12)]
+                 for _ in range(4)]
+    t, out = 0.0, []
+    for i in range(n):
+        k = rng.randrange(4)
+        out.append(rp.WorkloadRequest(
+            offset_s=t, prompt=templates[k] + [250 + i, rng.randrange(1, 250)],
+            max_new_tokens=rng.randrange(1, 9), template=k,
+            cancel_after_s=0.05 * (i + 1) if i % 4 == 0 else None))
+        t += rng.expovariate(8.0)
+    return {"source": "handmade", "seed": seed, "requests": n}, out
 
 
 def test_workload_jsonl_roundtrip(tmp_path):
-    meta, wl = rp.synthesize_workload(seed=3, num_requests=16,
-                                      cancel_fraction=0.2)
+    meta, wl = _workload(seed=3, n=16)
     path = str(tmp_path / "wl.jsonl")
     rp.save_workload(path, wl, meta)
     meta2, back = rp.load_workload(path)
@@ -148,72 +134,15 @@ def test_workload_schema_is_strict(tmp_path):
 
 
 def test_workload_inspector_cli(tmp_path, capsys):
-    meta, wl = rp.synthesize_workload(seed=1, num_requests=12,
-                                      cancel_fraction=0.25)
+    meta, wl = _workload(seed=1, n=12)
     path = str(tmp_path / "wl.jsonl")
     rp.save_workload(path, wl, meta)
     assert obs_main(["workload", path]) == 0
     out = capsys.readouterr().out
     assert "requests: 12" in out
     assert "prefix sharing" in out
-    assert "source=synthetic" in out
-
-
-# ---------------------------------------------------------------------------
-# SLO gate (contract modeled on analysis/budgets.py)
-# ---------------------------------------------------------------------------
-
-
-def test_packaged_slo_file_is_valid():
-    slos = rp.load_slos()
-    assert "synthetic-smoke" in slos and "chaos-smoke" in slos
-
-
-def test_slo_unknown_key_is_hard_error(tmp_path):
-    p = tmp_path / "slo.toml"
-    p.write_text('[workloads."x"]\nmax_ttft_ms_p95 = 1.0\n'
-                 'max_ttft_p95_ms = 2.0\n')  # transposed suffix: a typo
-    with pytest.raises(rp.SLOError, match="max_ttft_p95_ms"):
-        rp.load_slos(str(p))
-    p.write_text('[workloads."x"]\nmax_failed = "zero"\n')
-    with pytest.raises(rp.SLOError, match="must be a number"):
-        rp.load_slos(str(p))
-    p.write_text("# no tables\n")
-    with pytest.raises(rp.SLOError, match="workloads"):
-        rp.load_slos(str(p))
-
-
-def test_slo_never_passes_vacuously():
-    # gating a metric the summary doesn't have (or that is None because no
-    # samples arrived) must raise, never silently pass
-    with pytest.raises(rp.SLOError, match="vacuously"):
-        rp.check_slo({}, {"max_ttft_ms_p95": 5.0}, "w")
-    with pytest.raises(rp.SLOError, match="vacuously"):
-        rp.check_slo({"ttft_ms_p95": None}, {"max_ttft_ms_p95": 5.0}, "w")
-
-
-def test_slo_violations_are_named_key_diffs():
-    summary = {"ttft_ms_p95": 80.0, "goodput_rps": 1.5, "failed": 0}
-    slo = {"max_ttft_ms_p95": 50.0, "min_goodput_rps": 2.0,
-           "max_failed": 0, "description": "d"}
-    vs = rp.check_slo(summary, slo, "prod")
-    assert {v.check for v in vs} == {"ttft_ms_p95", "goodput_rps"}
-    ttft = next(v for v in vs if v.check == "ttft_ms_p95")
-    assert str(ttft) == "[prod] ttft_ms_p95: actual 80.0 violates SLO 50.0"
-    assert ttft.to_dict() == {"workload": "prod", "check": "ttft_ms_p95",
-                              "limit": 50.0, "actual": 80.0}
-    assert rp.check_slo({"failed": 0}, {"max_failed": 0}, "w") == []
-
-
-def test_chaos_schedule_grammar():
-    evs = rp.parse_chaos(
-        "0.5:0:serving.worker.hardkill=exit, 1.5:1:serving.step=delay:0.2")
-    assert [(e.at_s, e.replica) for e in evs] == [(0.5, 0), (1.5, 1)]
-    assert evs[0].spec == {"serving.worker.hardkill": "exit"}
-    assert evs[1].spec == {"serving.step": "delay:0.2"}
-    assert rp.parse_chaos(None) == [] and rp.parse_chaos("") == []
-    with pytest.raises(rp.WorkloadError, match="malformed chaos"):
-        rp.parse_chaos("nonsense")
+    assert "source=handmade" in out
+    assert "cancels: 3" in out
 
 
 # ---------------------------------------------------------------------------
@@ -263,46 +192,34 @@ def test_capture_records_live_traffic(inproc_pool):
     assert meta["source"] == "capture" and meta["requests"] == 6
 
 
-# ---------------------------------------------------------------------------
-# in-process replay smoke: deterministic + SLO-gated (tier-1, fast)
-# ---------------------------------------------------------------------------
-
-
-def test_replay_smoke_deterministic_and_slo_gated(inproc_pool, ref_fn):
-    meta, wl = rp.synthesize_workload(seed=11, num_requests=6,
-                                      mean_rate_rps=24.0)
-    # warm the compile caches so the smoke stays fast and TTFT measures
-    # serving, not first-touch XLA
-    inproc_pool.submit([1, 2, 3], max_new_tokens=2).result(timeout=300)
-
-    out1 = rp.replay_workload(inproc_pool, wl, time_scale=0.5)
-    out2 = rp.replay_workload(inproc_pool, wl, time_scale=0.5)
-    s = out1["summary"]
-    assert s["requests"] == 6 and s["completed"] == 6
-    assert s["failed"] == 0 and s["rejected"] == 0
-    assert s["goodput_rps"] > 0 and s["tokens_per_s"] > 0
-    assert s["ttft_ms_p50"] is not None and s["tpot_ms_p50"] is not None
-    assert s["queue_depth_max"] is not None
-    # determinism: same workload → identical token streams, both runs
-    toks1 = [r["tokens"] for r in out1["requests"]]
-    toks2 = [r["tokens"] for r in out2["requests"]]
-    assert toks1 == toks2
-    # and both match the uncached greedy reference
-    srt = sorted(wl, key=lambda r: r.offset_s)
-    for req, got in zip(srt, out1["requests"]):
-        assert got["tokens"] == ref_fn(req.prompt, req.max_new_tokens)
-    # zero leaked blocks once idle
-    wait_until(lambda: inproc_pool.replicas[0].num_running() == 0,
-               timeout=60, msg="pool idle")
-    assert inproc_pool.replicas[0].prefix_stats().get("pinned_blocks",
-                                                      0) == 0
-    # the packaged gate passes on a healthy run...
-    slos = rp.load_slos()
-    assert rp.check_slo(s, slos["synthetic-smoke"], "synthetic-smoke") == []
-    # ...and a regression (here: a synthetic failure count) is a named diff
-    bad = dict(s, failed=2, completed_fraction=0.5)
-    vs = rp.check_slo(bad, slos["synthetic-smoke"], "synthetic-smoke")
-    assert {v.check for v in vs} == {"failed", "completed_fraction"}
+def test_captured_trace_holds_what_a_traffic_file_needs(inproc_pool,
+                                                        tmp_path):
+    """capture -> save_workload -> load_workload keeps, for every request,
+    what ``benchmark/loadgen.py`` would need to send it again: when it was
+    due, how long its prompt was, its budget, its stop ids, its temperature
+    and its tenant."""
+    sent = [([3, 1, 4, 1, 5, 9], dict(max_new_tokens=3, stop_token_ids=[249],
+                                      temperature=0.7, tenant="acme")),
+            ([2, 7], dict(max_new_tokens=5, tenant="zenith")),
+            ([6] * 11, dict(max_new_tokens=2, temperature=0.0))]
+    with rp.WorkloadCapture() as cap:
+        for prompt, kw in sent:
+            inproc_pool.submit(prompt, **kw).result(timeout=120)
+            time.sleep(0.01)
+    path = str(tmp_path / "captured.jsonl")
+    rp.save_workload(path, cap.to_workload(), cap.meta())
+    meta, back = rp.load_workload(path)
+    assert meta["source"] == "capture" and meta["requests"] == len(sent)
+    offsets = [r.offset_s for r in back]
+    assert offsets[0] == 0.0 and offsets == sorted(offsets)
+    assert len(set(offsets)) == len(sent)
+    for (prompt, kw), r in zip(sent, back):
+        assert r.prompt == prompt
+        assert r.max_new_tokens == kw["max_new_tokens"]
+        assert r.stop_token_ids == tuple(kw.get("stop_token_ids", ()))
+        assert r.temperature == kw.get("temperature")
+        assert r.tenant == kw.get("tenant", "default")
+        assert r.cancel_after_s is None and r.rid
 
 
 # ---------------------------------------------------------------------------
@@ -378,7 +295,7 @@ def test_debug_trace_schema_inprocess(inproc_pool):
 
 
 # ---------------------------------------------------------------------------
-# subprocess fleet: stitching, one-timeline failover, chaos replay
+# subprocess fleet: stitching, one-timeline failover
 # ---------------------------------------------------------------------------
 
 
@@ -476,37 +393,3 @@ def test_fleet_kill_is_one_timeline_across_workers(fleet_pool, ref_fn):
     rids = {s.attrs.get("rid") for s in spans if s.attrs.get("rid")}
     assert len(rids) >= 2  # two placements, one trace
     _fleet_heal(fleet_pool)
-
-
-def test_chaos_replay_degrades_without_losing_tokens(fleet_pool, ref_fn):
-    _fleet_heal(fleet_pool)
-    meta, wl = rp.synthesize_workload(seed=5, num_requests=10,
-                                      mean_rate_rps=8.0)
-    # warm both replicas' compile caches before the measured window
-    warm = [fleet_pool.submit([1, 2, 3], max_new_tokens=2)
-            for _ in range(2)]
-    for h in warm:
-        h.result(timeout=300)
-    chaos = [rp.ChaosEvent(at_s=0.3, replica=0,
-                           spec={"serving.worker.hardkill": "exit"})]
-    out = rp.replay_workload(fleet_pool, wl, chaos=chaos,
-                             token_timeout_s=300.0)
-    s = out["summary"]
-    # degradation is reported, not hidden: the run completes, goodput and
-    # wall are measured through the kill + failover window
-    assert s["completed"] == 10 and s["failed"] == 0 and s["rejected"] == 0
-    assert s["goodput_rps"] > 0 and s["wall_s"] > 0
-    # token-identical streams vs the fault-free greedy reference: failover
-    # replays the prefix and skips delivered tokens
-    srt = sorted(wl, key=lambda r: r.offset_s)
-    for req, got in zip(srt, out["requests"]):
-        assert got["tokens"] == ref_fn(req.prompt, req.max_new_tokens)
-    assert rp.check_slo(s, rp.load_slos()["chaos-smoke"],
-                        "chaos-smoke") == []
-    # the killed worker respawned; no pinned blocks remain anywhere
-    _fleet_heal(fleet_pool)
-    wait_until(lambda: all(t.num_running() == 0
-                           for t in fleet_pool.replicas if t.healthy()),
-               timeout=60, msg="fleet idle")
-    assert all(t.prefix_stats().get("pinned_blocks", 0) == 0
-               for t in fleet_pool.replicas if t.healthy())

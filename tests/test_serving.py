@@ -553,25 +553,3 @@ def test_metrics_flow_to_monitor_csv(devices, tiny_model, tmp_path):
         assert expected in names, (expected, names)
     rows = (csv_dir / "serving_ttft_ms_p50.csv").read_text().splitlines()
     assert len(rows) >= 2  # header + at least one sample
-
-
-# ---------------------------------------------------------------------------
-# soak (slow): sustained offered load through the subprocess server
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_serving_soak_offered_load(tmp_path):
-    from deepspeed_tpu.serving.bench import run_sweep
-
-    result = run_sweep([4.0, 16.0], duration_s=6.0, max_tokens=6,
-                       prompt_len=4, replicas=2, max_queue=8,
-                       env={"JAX_PLATFORMS": "cpu"})
-    assert result["graceful_shutdown_rc"] == 0
-    for point in result["sweep"]:
-        assert point["failed"] == 0, point
-        assert point["completed"] > 0
-        # conservation: every offered request is accounted for
-        assert point["completed"] + point["rejected_429"] + point["failed"] \
-            == point["requests"]
-    assert result["sweep"][0]["tokens_per_s"] > 0
