@@ -8,12 +8,26 @@ one kernel computes softmax(QKᵀ)V with online (streaming) softmax so the S×S
 score matrix never materializes in HBM — O(S) memory instead of O(S²).
 
 Design (classic FlashAttention-2 schedule on the MXU):
-* grid = (batch, heads, q_blocks, kv_blocks); TPU executes the innermost
-  (kv) dimension sequentially, so the running max/denominator/accumulator
-  live in VMEM scratch across kv steps;
-* causal masking skips fully-masked kv blocks via predication, and a
-  skipped step's index maps name the block at the band's edge (the one its
-  neighbour names), so the pipeline fetches nothing for it;
+* grid = (batch, heads, q_blocks, steps); TPU executes the innermost
+  dimension sequentially, so the running max/denominator/accumulator live in
+  VMEM scratch across a q block's steps.  A step is one tile of the q block's
+  WALK over its kv blocks: the walk ends at the last kv block the band keeps
+  for the row and is as long as the band's widest row (``_band_tiles``, from
+  static shapes: 3 steps under a window of 2,048 at blocks of 1,024, every
+  kv block where nothing cuts), so a window layer has no dead step but the
+  few of its first rows.  A row's dead steps come FIRST (the causal
+  triangle of a full layer keeps its own: a row's kv blocks above the
+  diagonal) and their index maps name the row's first live block, so nothing
+  is fetched for them, the first live step finds its blocks there, and the
+  next row's blocks are fetched behind the row's last tile;
+* the band's element mask is built only in a tile the band's edge cuts, and
+  only from the bound that cuts it (``_tile_cuts``: scalar compares on the
+  grid indices choose between bodies of one function, one for each pair
+  (cut by the causal bound, cut by the window's far bound) that the static
+  shapes can give: a full layer has two, Trinity's window layers three:
+  interior, diagonal, far edge).  A tile that lies wholly inside the band,
+  and every tile of a call without a band, runs without a band mask.
+  Segment ids, where given, are masked in every body;
 * GQA: kv block index maps ``h → h * kv_heads // heads`` so grouped heads
   read the same K/V without materializing repeats;
 * segment ids (packed sequences) are masked in-kernel: q ids ride along
@@ -22,8 +36,11 @@ Design (classic FlashAttention-2 schedule on the MXU):
 * arbitrary block-sparse masks: a scalar-prefetched (nq, nk) table gates
   each tile, so fully-masked tiles cost nothing (the reference's
   `sparse_attention` layouts — fixed/bigbird/longformer — compile to this);
-* backward = two kernels (dkdv: grid over kv blocks; dq: grid over q blocks)
-  using the saved logsumexp, in the standard recompute formulation;
+* backward = two kernels (dkdv: grid over kv blocks, each walking the q
+  blocks of its column of the band once a query head of the group; dq: the
+  forward's grid) using the saved logsumexp, in the standard recompute
+  formulation; a masked ``p`` is selected to 0 AFTER ``exp(s - lse)``, so the
+  scores themselves are not selected on;
 * precision: every dot multiplies its operands in the dtype the caller handed
   in and sums in float32.  q, k, v and dO go to the MXU as they are read; the
   probabilities ``p`` and ``ds`` are computed in float32 and rounded to the
@@ -48,6 +65,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -72,15 +90,25 @@ def aligned_divisor(n: int, cap: int, align: int = NUM_SUBLANES):
     return None
 
 
-def _band_mask(s_shape, q_start, k_start, causal: bool, window: int):
-    """Causal/sliding-window keep-mask for one (bq, bk) tile.  ``window > 0``
-    keeps keys in (query-window, query] — the band implies the causal upper
-    bound even when ``causal=False``."""
+def _band_mask(s_shape, q_start, k_start, by_causal: bool, by_window: bool,
+               window: int):
+    """Causal/sliding-window keep-mask for one (bq, bk) tile, built from the
+    bounds that cut the tile (``_tile_cuts``): ``by_causal`` keeps keys up to
+    the query, ``by_window`` keys in (query-window, ...; a bound that cuts
+    nothing of the tile would compare all true.  None when neither cuts.
+    ``window > 0`` implies the causal upper bound even when
+    ``causal=False``."""
+    if not (by_causal or by_window):
+        return None
     rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 0)
     cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1)
-    if window > 0:
-        return (cols > rows - window) & (cols <= rows)
-    return rows >= cols
+    keep = None
+    if by_causal:
+        keep = cols <= rows
+    if by_window:
+        inside = cols > rows - window
+        keep = inside if keep is None else inside & keep
+    return keep
 
 
 def _tile_in_band(q_start, k_start, block_q: int, block_k: int,
@@ -94,29 +122,100 @@ def _tile_in_band(q_start, k_start, block_q: int, block_k: int,
     return ok
 
 
-def _kv_block_in_band(iq, ik, block_q: int, block_k: int, causal: bool,
-                      window: int):
-    """``ik`` held to the kv blocks that ``_tile_in_band`` keeps for q block
-    ``iq``.  A step outside the band computes nothing; with its block index
-    held at the band's edge it names the block the neighbouring step names,
-    and the pipeline fetches nothing for it."""
+def _tile_cuts(q_start, k_start, block_q: int, block_k: int, causal: bool,
+               window: int):
+    """Which of the band's two bounds cut the tile (mask some element of
+    it) → (by_causal, by_window): its last key is later than its first
+    query; under a window, its first key is outside the window of its last
+    query.  A live tile that neither cuts lies wholly inside the band."""
+    by_causal = by_window = False
     if causal or window > 0:
-        ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+        by_causal = k_start + block_k - 1 > q_start
     if window > 0:
-        ik = jnp.maximum(ik, jnp.maximum(iq * block_q - window + 1, 0)
-                         // block_k)
-    return ik
+        by_window = k_start < q_start + block_q - window
+    return by_causal, by_window
 
 
-def _q_block_in_band(iq, ik, block_q: int, block_k: int, causal: bool,
-                     window: int):
-    """``iq`` held to the q blocks that ``_tile_in_band`` keeps for kv block
-    ``ik``: ``_kv_block_in_band`` for the kernel that walks the q blocks."""
-    if window > 0:
-        iq = jnp.minimum(iq, (ik * block_k + block_k + window - 2) // block_q)
+def _at_least(x, lo: int):
+    """max(x, lo): of plain ints while a call is traced, of a grid index in
+    an index map or a kernel."""
+    return max(x, lo) if isinstance(x, int) else jnp.maximum(x, lo)
+
+
+def _at_most(x, hi):
+    return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
+
+
+def _kv_block_in_band(iq, step, steps: int, block_q: int, block_k: int,
+                      nk: int, causal: bool, window: int):
+    """Step ``step`` of q block ``iq``'s walk of ``steps`` steps over its kv
+    blocks → ``(ik, held)``.  The walk ENDS at the last kv block
+    ``_tile_in_band`` keeps for the row, so ``ik`` counts up to it; ``held``
+    is ``ik`` held to the row's live blocks inside ``[0, nk)``, which is what
+    the index maps name.  A row that keeps fewer blocks than the widest (the
+    causal triangle's, a window's first) has its dead steps FIRST: they
+    compute nothing, name the row's first live block, which the first live
+    step then finds fetched, and the walk's last step is a live tile, behind
+    whose compute the next row's blocks arrive."""
+    first, last = 0, nk - 1
     if causal or window > 0:
-        iq = jnp.maximum(iq, ik * block_k // block_q)
-    return iq
+        last = _at_most((iq * block_q + block_q - 1) // block_k, last)
+    if window > 0:
+        first = _at_least(iq * block_q - window + 1, 0) // block_k
+    ik = last - (steps - 1) + step
+    return ik, _at_most(_at_least(ik, first), nk - 1)
+
+
+def _q_block_in_band(ik, step, steps: int, block_q: int, block_k: int,
+                     nq: int, causal: bool, window: int):
+    """``_kv_block_in_band`` for the kernel that walks the q blocks of kv
+    block ``ik``'s column: → ``(iq, held)``."""
+    first, last = 0, nq - 1
+    if causal or window > 0:
+        first = ik * block_k // block_q
+    if window > 0:
+        last = _at_most((ik * block_k + block_k + window - 2) // block_q,
+                        last)
+    iq = last - (steps - 1) + step
+    return iq, _at_most(_at_least(iq, first), nq - 1)
+
+
+def _band_tiles(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
+                window: int) -> dict:
+    """What the static shapes say of a call's tiles: ``kv_steps`` / ``q_steps``
+    (the most tiles ``_tile_in_band`` keeps in a row / a column: the length
+    of the forward's and dQ's / of the dK/dV kernel's walk), ``live_tiles``
+    and, of those, ``edge_tiles`` (the ones ``_tile_cuts`` says build a
+    mask), each a head; ``bodies``: the (by_causal, by_window) pairs that
+    occur among the live tiles, one body of a kernel each."""
+    q0 = np.arange(nq)[:, None] * block_q
+    k0 = np.arange(nk)[None, :] * block_k
+    live, by_causal, by_window = (
+        np.broadcast_to(x, (nq, nk)) for x in (
+            _tile_in_band(q0, k0, block_q, block_k, causal, window),
+            *_tile_cuts(q0, k0, block_q, block_k, causal, window)))
+    return {"kv_steps": max(int(live.sum(axis=1).max()), 1),
+            "q_steps": max(int(live.sum(axis=0).max()), 1),
+            "live_tiles": int(live.sum()),
+            "edge_tiles": int((live & (by_causal | by_window)).sum()),
+            "bodies": tuple(sorted({(bool(a), bool(b)) for a, b in zip(
+                by_causal[live], by_window[live])}))}
+
+
+def _for_live_tile(live, cuts, bodies, tile) -> None:
+    """Run ``tile(by_causal, by_window)`` where the step's tile is live, in
+    the body that builds the compares of the bounds that cut it and no other:
+    none where the whole tile lies inside the band (and wherever the call
+    has no band).  ``bodies`` are the pairs the call's shapes can give."""
+    def says(cut, want: bool):  # a grid index's compare, or a plain bool
+        if isinstance(cut, bool):
+            return cut == want
+        return cut if want else jnp.logical_not(cut)
+
+    by_causal, by_window = cuts
+    for a, b in bodies:
+        pl.when(live & says(by_causal, a) & says(by_window, b))(
+            functools.partial(tile, a, b))
 
 
 def _seg_mask(q_seg_tile, k_seg_tile, block_k: int):
@@ -162,24 +261,33 @@ def _unpack(refs, has_mask: bool, has_seg: bool, n_io: int,
     return mask_tab, q_seg, k_seg, b1, b2, io
 
 
-def _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start, k_start,
-                   sm_scale, causal, window, block_k, has_seg):
-    """QKᵀ·scale with the combined element keep-mask (band ∧ segments)
-    applied. Returns (s, keep); ``keep`` is None when nothing masks at the
-    element level. Shared by the forward and both backward kernels so mask
+def _scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start, k_start, sm_scale,
+            window, block_k, has_seg, by_causal: bool, by_window: bool):
+    """QKᵀ·scale of a tile and its element keep-mask (band ∧ segments) →
+    ``(s, keep)``.  ``by_causal`` / ``by_window`` say which of the band's
+    bounds cut the tile (``_tile_cuts``): an interior tile builds no band
+    mask.  ``keep`` is None when nothing masks
+    at the element level; ``s`` comes back as computed, the caller selects
+    (the forward on ``s`` for its maximum and on ``p``, the backward kernels
+    on ``p`` alone).  Shared by the forward and both backward kernels so mask
     semantics can never desynchronize between passes."""
     s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
-    keep = None
-    if causal or window > 0:
-        keep = _band_mask(s.shape, q_start, k_start, causal, window)
+    keep = _band_mask(s.shape, q_start, k_start, by_causal, by_window,
+                      window)
     if has_seg:
         sm = _seg_mask(q_seg_ref[0], k_seg_ref[0, :1], block_k)
         keep = sm if keep is None else keep & sm
-    if keep is not None:
-        s = jnp.where(keep, s, DEFAULT_MASK_VALUE)
     return s, keep
+
+
+def _masked_p(s, lse, keep):
+    """The backward kernels' probabilities: ``exp(s - lse)`` with the masked
+    elements selected to 0 (whatever ``exp`` made of them: a row whose
+    ``lse`` is -inf has no kept element)."""
+    p = jnp.exp(s - lse)  # (bq, bk)
+    return p if keep is None else jnp.where(keep, p, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -188,15 +296,20 @@ def _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start, k_start,
 
 
 def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
-                block_k: int, window: int, has_mask: bool, has_seg: bool,
-                has_b1: bool = False, has_b2: bool = False):
+                block_k: int, nk: int, kv_steps: int, window: int,
+                bodies: tuple,
+                has_mask: bool, has_seg: bool, has_b1: bool = False,
+                has_b2: bool = False):
+    # grid: (B, H, nq, steps) — the innermost dim walks the q block's live kv
+    # blocks (``_kv_block_in_band``)
     mask_tab, q_seg_ref, k_seg_ref, b1_ref, b2_ref, io = _unpack(
         refs, has_mask, has_seg, 8, has_b1, has_b2)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = io
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
+    ik, held = _kv_block_in_band(iq, step, kv_steps, block_q, block_k, nk,
+                                 causal, window)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -205,24 +318,24 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
     q_start = iq * block_q
     k_start = ik * block_k
 
-    should_run = _tile_in_band(q_start, k_start, block_q, block_k, causal,
-                               window)
+    live = _tile_in_band(q_start, k_start, block_q, block_k, causal, window)
+    if causal or window > 0:
+        live = live & (ik >= 0) & (ik < nk)
     if has_mask:
-        should_run = should_run & (mask_tab[iq, ik] != 0)
+        live = live & (mask_tab[iq, held] != 0)
 
-    @pl.when(should_run)
-    def _compute():
+    def tile(by_causal: bool, by_window: bool):
         v = v_ref[0, 0]  # (bk, d)
-        s, keep = _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
-                                 k_start, sm_scale, causal, window, block_k,
-                                 has_seg)  # (bq, bk)
+        s, keep = _scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
+                          k_start, sm_scale, window, block_k, has_seg,
+                          by_causal, by_window)  # (bq, bk)
         # additive attention biases (evoformer pair/mask biases): a per-key
         # row bias broadcast over queries and a full (bq, bk) tile
         if has_b1:
             s = s + b1_ref[0, :1].astype(jnp.float32)  # (1, bk) → rows
         if has_b2:
             s = s + b2_ref[0, 0].astype(jnp.float32)  # (bq, bk)
-        if (has_b1 or has_b2) and keep is not None:
+        if keep is not None:
             s = jnp.where(keep, s, DEFAULT_MASK_VALUE)
 
         m_prev = m_ref[:]  # (bq, 1)
@@ -242,7 +355,10 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         m_ref[:] = m_new
         l_ref[:] = l_new
 
-    @pl.when(ik == nk - 1)
+    _for_live_tile(live, _tile_cuts(q_start, k_start, block_q, block_k,
+                                    causal, window), bodies, tile)
+
+    @pl.when(step == kv_steps - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -296,35 +412,43 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
     has_b1 = bias_kv is not None
     has_b2 = bias_qk is not None
 
-    grid = (B, H, nq, nk)
-    def kv_at(iq, ik):  # the kv block of a step, held inside the band
-        return _kv_block_in_band(iq, ik, block_q, block_k, causal, window)
+    tiles = _band_tiles(nq, nk, block_q, block_k, causal, window)
+    grid = (B, H, nq, tiles["kv_steps"])
 
-    def kv_rows(b, h, iq, ik, *_):  # a block of this head's kv rows
-        return (b, h // group, kv_at(iq, ik), 0)
+    def kv_at(iq, step):  # the kv block a step of the walk names
+        return _kv_block_in_band(iq, step, tiles["kv_steps"], block_q,
+                                 block_k, nk, causal, window)[1]
+
+    def kv_rows(b, h, iq, step, *_):  # a block of this head's kv rows
+        return (b, h // group, kv_at(iq, step), 0)
+
+    def own_q_rows(b, h, iq, step, *_):  # the q block's rows of this head
+        return (b, h, iq, 0)
 
     in_specs = []
     inputs = []
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, block_q, NUM_LANES),
-                         lambda b, h, iq, ik, *_: (b, iq, 0)),
+                         lambda b, h, iq, step, *_: (b, iq, 0)),
             pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda b, h, iq, ik, *_: (b, 0, kv_at(iq, ik))),
+                         lambda b, h, iq, step, *_: (b, 0, kv_at(iq, step))),
         ]
         inputs += [q_seg, k_seg]
     if has_b1:  # per-key bias, (B, NUM_SUBLANES, Skv) lane layout
-        in_specs += [pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                                  lambda b, h, iq, ik, *_: (b, 0, ik))]
+        in_specs += [pl.BlockSpec(
+            (1, NUM_SUBLANES, block_k),
+            lambda b, h, iq, step, *_: (b, 0, kv_at(iq, step)))]
         inputs += [bias_kv]
     if has_b2:  # full (q, k) bias, batch-broadcast (e.g. pair bias over MSA)
         b2_rep = B // bias_qk.shape[0]
         in_specs += [pl.BlockSpec(
             (1, 1, block_q, block_k),
-            lambda b, h, iq, ik, *_: (b // b2_rep, h, iq, ik))]
+            lambda b, h, iq, step, *_: (b // b2_rep, h, iq,
+                                     kv_at(iq, step)))]
         inputs += [bias_qk]
     in_specs += [
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, D), own_q_rows),
         pl.BlockSpec((1, 1, block_k, D), kv_rows),
         pl.BlockSpec((1, 1, block_k, Dv), kv_rows),
     ]
@@ -332,13 +456,15 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
     out, lse = _pallas_call(
         _kernel_name("fwd", window, Skv),
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, window=window,
+                          block_q=block_q, block_k=block_k, nk=nk,
+                          kv_steps=tiles["kv_steps"], window=window,
+                          bodies=tiles["bodies"],
                           has_mask=mask_tab is not None, has_seg=has_seg,
                           has_b1=has_b1, has_b2=has_b2),
         grid, in_specs,
         [
-            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), own_q_rows),
+            pl.BlockSpec((1, 1, block_q, 1), own_q_rows),
         ],
         [
             jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
@@ -359,17 +485,19 @@ def _flash_fwd(q, k, v, q_seg, k_seg, mask_tab, sm_scale, causal, block_q,
 
 
 def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, nq: int,
-                     window: int, has_mask: bool, has_seg: bool):
-    # grid: (B, KV, nk, group*nq) — the innermost dim walks every q block of
-    # every query head in this kv head's group, accumulating straight into
+                     q_steps: int, window: int, bodies: tuple,
+                     has_mask: bool, has_seg: bool):
+    # grid: (B, KV, nk, group*q_steps) — the innermost dim walks, for every
+    # query head in this kv head's group, the q blocks of the kv block's
+    # column of the band (``_q_block_in_band``), accumulating straight into
     # the per-KV-head dk/dv (no (B, H, S, D) f32 intermediate).
     mask_tab, q_seg_ref, k_seg_ref, _, _, io = _unpack(
         refs, has_mask, has_seg, 10)
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
      dk_ref, dv_ref, dk_acc, dv_acc) = io
     ik, iqg = pl.program_id(2), pl.program_id(3)
-    niqg = pl.num_programs(3)
-    iq = iqg % nq
+    iq, held = _q_block_in_band(ik, iqg % q_steps, q_steps, block_q, block_k,
+                                nq, causal, window)
 
     @pl.when(iqg == 0)
     def _init():
@@ -378,25 +506,23 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, nq: int,
 
     q_start = iq * block_q
     k_start = ik * block_k
-    should_run = _tile_in_band(q_start, k_start, block_q, block_k, causal,
-                               window)
+    live = _tile_in_band(q_start, k_start, block_q, block_k, causal, window)
+    if causal or window > 0:
+        live = live & (iq >= 0) & (iq < nq)
     if has_mask:
-        should_run = should_run & (mask_tab[iq, ik] != 0)
+        live = live & (mask_tab[held, ik] != 0)
 
-    @pl.when(should_run)
-    def _compute():
+    def tile(by_causal: bool, by_window: bool):
         q = q_ref[0, 0]  # (bq, d)
         v = v_ref[0, 0]
         do = do_ref[0, 0]  # (bq, d)
         lse = lse_ref[0, 0]  # (bq, 1)
         delta = delta_ref[0, 0]  # (bq, 1)
 
-        s, keep = _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
-                                 k_start, sm_scale, causal, window, block_k,
-                                 has_seg)
-        p = jnp.exp(s - lse)  # (bq, bk)
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
+        s, keep = _scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
+                          k_start, sm_scale, window, block_k, has_seg,
+                          by_causal, by_window)
+        p = _masked_p(s, lse, keep)  # (bq, bk)
 
         dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
                                          (((0,), (0,)), ((), ())),
@@ -408,45 +534,49 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, nq: int,
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    @pl.when(iqg == niqg - 1)
+    _for_live_tile(live, _tile_cuts(q_start, k_start, block_q, block_k,
+                                    causal, window), bodies, tile)
+
+    @pl.when(iqg == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, window: int,
+def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, nk: int,
+                   kv_steps: int, window: int, bodies: tuple,
                    has_mask: bool, has_seg: bool):
+    # grid: the forward's, (B, H, nq, steps)
     mask_tab, q_seg_ref, k_seg_ref, _, _, io = _unpack(
         refs, has_mask, has_seg, 8)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = io
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
+    ik, held = _kv_block_in_band(iq, step, kv_steps, block_q, block_k, nk,
+                                 causal, window)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = iq * block_q
     k_start = ik * block_k
-    should_run = _tile_in_band(q_start, k_start, block_q, block_k, causal,
-                               window)
+    live = _tile_in_band(q_start, k_start, block_q, block_k, causal, window)
+    if causal or window > 0:
+        live = live & (ik >= 0) & (ik < nk)
     if has_mask:
-        should_run = should_run & (mask_tab[iq, ik] != 0)
+        live = live & (mask_tab[iq, held] != 0)
 
-    @pl.when(should_run)
-    def _compute():
+    def tile(by_causal: bool, by_window: bool):
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
         lse = lse_ref[0, 0]  # (bq, 1)
         delta = delta_ref[0, 0]  # (bq, 1)
 
-        s, keep = _masked_scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
-                                 k_start, sm_scale, causal, window, block_k,
-                                 has_seg)
-        p = jnp.exp(s - lse)
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
+        s, keep = _scores(q_ref, k_ref, q_seg_ref, k_seg_ref, q_start,
+                          k_start, sm_scale, window, block_k, has_seg,
+                          by_causal, by_window)
+        p = _masked_p(s, lse, keep)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * sm_scale
@@ -454,9 +584,32 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, window: int,
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    @pl.when(ik == nk - 1)
+    _for_live_tile(live, _tile_cuts(q_start, k_start, block_q, block_k,
+                                    causal, window), bodies, tile)
+
+    @pl.when(step == kv_steps - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _tiles_event(q, v, block_q: int, block_k: int, which: str = "",
+                 tiles: Optional[dict] = None) -> None:
+    """The ``kernel/flash_attention_tiles`` ring event of one pass of a
+    traced call: the widths, the blocks, the dots' dtype, and what the static
+    shapes say of the walk (``steps`` a q block, for the dK/dV kernel a kv
+    block and a query head; ``live_tiles`` and ``edge_tiles`` a head); with
+    no ``tiles``, the event of a call that fell back."""
+    attrs = {"d_qk": q.shape[-1], "d_v": v.shape[-1], "block_q": block_q,
+             "block_k": block_k, "operand_dtype": jnp.dtype(q.dtype).name}
+    if tiles is None:
+        attrs["fallback"] = 1
+    else:
+        attrs.update({
+            "pass": which,
+            "steps": tiles["q_steps" if which == "dkdv" else "kv_steps"],
+            "live_tiles": tiles["live_tiles"],
+            "edge_tiles": tiles["edge_tiles"]})
+    tracer.add_event("kernel/flash_attention_tiles", attrs=attrs)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
@@ -469,23 +622,24 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     nq = pl.cdiv(S, block_q)
     nk = pl.cdiv(Skv, block_k)
     has_seg = q_seg is not None
+    tiles = _band_tiles(nq, nk, block_q, block_k, causal, window)
+    q_steps = tiles["q_steps"]
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)  # (B, H, S, 1)
     for which in ("dkdv", "dq"):  # once a traced backward, as the forward's
-        tracer.add_event("kernel/flash_attention_tiles", attrs={
-            "d_qk": D, "d_v": Dv, "block_q": block_q, "block_k": block_k,
-            "operand_dtype": jnp.dtype(q.dtype).name, "pass": which})
+        _tiles_event(q, v, block_q, block_k, which, tiles)
 
-    # dk, dv: one pass per kv block; the innermost grid dim walks all
-    # (group, q-block) pairs so GQA groups accumulate directly into the
-    # (B, KV, Skv, D) result — no (B, H, Skv, D) f32 intermediate.
-    def q_at(ik, iqg):  # the q block of a step, held inside the band
-        return _q_block_in_band(iqg % nq, ik, block_q, block_k, causal,
-                                window)
+    # dk, dv: one pass per kv block; the innermost grid dim walks the q
+    # blocks of the kv block's column once a query head of the group, so GQA
+    # groups accumulate directly into the (B, KV, Skv, D) result — no
+    # (B, H, Skv, D) f32 intermediate.
+    def q_at(ik, iqg):  # the q block a step of the walk names
+        return _q_block_in_band(ik, iqg % q_steps, q_steps, block_q,
+                                block_k, nq, causal, window)[1]
 
     def q_rows(b, kv, ik, iqg, *_):  # a block of this head's q rows
-        return (b, kv * group + iqg // nq, q_at(ik, iqg), 0)
+        return (b, kv * group + iqg // q_steps, q_at(ik, iqg), 0)
 
     in_specs = []
     inputs = []
@@ -512,9 +666,10 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
         _kernel_name("bwd_dkv", window, Skv),
         functools.partial(_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq,
-                          window=window, has_mask=mask_tab is not None,
-                          has_seg=has_seg),
-        (B, KV, nk, group * nq), in_specs,
+                          q_steps=q_steps, window=window,
+                          bodies=tiles["bodies"],
+                          has_mask=mask_tab is not None, has_seg=has_seg),
+        (B, KV, nk, group * q_steps), in_specs,
         [
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, kv, ik, iqg, *_: (b, kv, ik, 0)),
@@ -531,39 +686,44 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
         ],
         mask_tab, inputs)
 
-    def kv_at(iq, ik):  # the kv block of a step, held inside the band
-        return _kv_block_in_band(iq, ik, block_q, block_k, causal, window)
+    def kv_at(iq, step):  # the kv block a step of the walk names
+        return _kv_block_in_band(iq, step, tiles["kv_steps"], block_q,
+                                 block_k, nk, causal, window)[1]
 
-    def kv_rows(b, h, iq, ik, *_):  # a block of this head's kv rows
-        return (b, h // group, kv_at(iq, ik), 0)
+    def kv_rows(b, h, iq, step, *_):  # a block of this head's kv rows
+        return (b, h // group, kv_at(iq, step), 0)
+
+    def own_q_rows(b, h, iq, step, *_):  # the q block's rows of this head
+        return (b, h, iq, 0)
 
     in_specs = []
     inputs = []
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, block_q, NUM_LANES),
-                         lambda b, h, iq, ik, *_: (b, iq, 0)),
+                         lambda b, h, iq, step, *_: (b, iq, 0)),
             pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda b, h, iq, ik, *_: (b, 0, kv_at(iq, ik))),
+                         lambda b, h, iq, step, *_: (b, 0, kv_at(iq, step))),
         ]
         inputs += [q_seg, k_seg]
     in_specs += [
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, D), own_q_rows),
         pl.BlockSpec((1, 1, block_k, D), kv_rows),
         pl.BlockSpec((1, 1, block_k, Dv), kv_rows),
-        pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, Dv), own_q_rows),
+        pl.BlockSpec((1, 1, block_q, 1), own_q_rows),
+        pl.BlockSpec((1, 1, block_q, 1), own_q_rows),
     ]
     inputs += [q, k, v, g, lse, delta]
     dq = _pallas_call(
         _kernel_name("bwd_dq", window, Skv),
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, window=window,
+                          block_q=block_q, block_k=block_k, nk=nk,
+                          kv_steps=tiles["kv_steps"], window=window,
+                          bodies=tiles["bodies"],
                           has_mask=mask_tab is not None, has_seg=has_seg),
-        (B, H, nq, nk), in_specs,
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, h, iq, ik, *_: (b, h, iq, 0)),
+        (B, H, nq, tiles["kv_steps"]), in_specs,
+        pl.BlockSpec((1, 1, block_q, D), own_q_rows),
         jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         [pltpu.VMEM((block_q, D), jnp.float32)],
         mask_tab, inputs)
@@ -699,11 +859,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"block_mask shape {block_mask.shape} != grid ({nq}, {nk}) "
                 f"for S={S}, block_q={block_q}, block_k={block_k}")
     # chosen once per shape, while the caller's program is traced
-    event = {"d_qk": D, "d_v": v.shape[3], "block_q": block_q,
-             "block_k": block_k, "operand_dtype": jnp.dtype(q.dtype).name}
     if not usable:
-        tracer.add_event("kernel/flash_attention_tiles",
-                         attrs={**event, "fallback": 1})
+        _tiles_event(q, v, block_q, block_k)
         backend.warn_fallback(
             "flash_attention",
             f"S={S}, Skv={k.shape[1]}, H={H}, KV={KV} do not tile into "
@@ -728,8 +885,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    tracer.add_event("kernel/flash_attention_tiles",
-                     attrs={**event, "pass": "fwd"})
+    _tiles_event(q, v, block_q, block_k, "fwd", _band_tiles(
+        S // block_q, k.shape[1] // block_k, block_q, block_k, causal,
+        window))
     out = _flash_attention_bhsd(qt, kt, vt, q_seg3, k_seg3, mask_tab,
                                 sm_scale, causal, block_q, block_k, window)
     return out.transpose(0, 2, 1, 3)

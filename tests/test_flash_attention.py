@@ -134,8 +134,9 @@ def test_sliding_window_model_config(devices):
 # name → (query-key width, value width, heads, KV heads): the dense models'
 # 128 / 128 under GQA, and latent attention's 192 / 128
 WIDTHS = {"128-128": (128, 128, 4, 2), "192-128": (192, 128, 2, 2)}
-# dots a kernel holds: q kᵀ and p v; q kᵀ, pᵀ dO, dO vᵀ and dsᵀ q; q kᵀ,
-# dO vᵀ and ds k
+# dots a body of a kernel holds: q kᵀ and p v; q kᵀ, pᵀ dO, dO vᵀ and dsᵀ q;
+# q kᵀ, dO vᵀ and ds k.  A causal kernel holds two bodies of one function:
+# the tile on the diagonal and the interior tile (ISSUE 61)
 KERNEL_DOTS = {"flash_attention_fwd": 2, "flash_attention_bwd_dkv": 4,
                "flash_attention_bwd_dq": 3}
 # name → flash_attention's keywords and whether segment ids ride along
@@ -208,7 +209,7 @@ def test_dots_multiply_what_they_are_given(devices, width, dtype, kernel):
     one (with float32 inputs the casts of p and ds are no conversion)."""
     call = _traced_kernels(width, dtype)[kernel]
     dots = _dots(call.params["jaxpr"], [])
-    assert len(dots) == KERNEL_DOTS[kernel]
+    assert len(dots) == 2 * KERNEL_DOTS[kernel]
     for dot, makers in dots:
         assert [str(x.aval.dtype) for x in dot.invars] == [dtype, dtype]
         assert dot.outvars[0].aval.dtype == jnp.float32
@@ -356,42 +357,229 @@ def test_blocks_past_one_lane_tile_follow_the_operand_width(devices, dtype,
     assert event["operand_dtype"] == dtype and "fallback" not in event
 
 
+BLOCK_SHAPES = [(64, 64), (128, 32), (32, 128)]
+
+
+def _kept_tiles(seq, block_q, block_k, causal, window):
+    from deepspeed_tpu.ops.pallas.flash_attention import _tile_in_band
+
+    return np.array([[bool(_tile_in_band(iq * block_q, ik * block_k, block_q,
+                                         block_k, causal, window))
+                      for ik in range(seq // block_k)]
+                     for iq in range(seq // block_q)])
+
+
 @pytest.mark.parametrize("window", [0, 40, 64, 200])
-@pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 32), (32, 128)])
+@pytest.mark.parametrize("block_q,block_k", BLOCK_SHAPES)
 def test_a_step_outside_the_band_names_the_bands_edge(block_q, block_k,
                                                       window):
-    """The index maps hold a skipped step's block at the nearest block its
-    row (the dK/dV kernel: its column) of tiles keeps, so consecutive skipped
-    steps name one block and nothing is fetched for them; a step inside the
-    band names its own block."""
+    """The walk of a row of tiles (the dK/dV kernel: of a column) is as long
+    as the widest row, ends at the row's last live tile and names every tile
+    ``_tile_in_band`` keeps exactly once, in order; the dead steps of a
+    shorter row come FIRST, stand for a block that is dead (outside the band
+    or outside ``[0, n)``) and name the row's first live block, the one the
+    first live step names, so nothing is fetched for them; no step names a
+    block outside ``[0, n)``."""
     from deepspeed_tpu.ops.pallas.flash_attention import (
-        _kv_block_in_band, _q_block_in_band, _tile_in_band)
+        _band_tiles, _kv_block_in_band, _q_block_in_band)
 
     seq = 512
     nq, nk = seq // block_q, seq // block_k
-    kept = np.array([[bool(_tile_in_band(iq * block_q, ik * block_k, block_q,
-                                         block_k, True, window))
-                      for ik in range(nk)] for iq in range(nq)])
+    kept = _kept_tiles(seq, block_q, block_k, True, window)
     assert kept.any(axis=1).all() and kept.any(axis=0).all()
-    for iq in range(nq):
-        inside = np.flatnonzero(kept[iq])
-        for ik in range(nk):
-            held = int(_kv_block_in_band(iq, ik, block_q, block_k, True,
-                                         window))
-            assert held == int(np.clip(ik, inside[0], inside[-1]))
-    for ik in range(nk):
-        inside = np.flatnonzero(kept[:, ik])
-        for iq in range(nq):
-            held = int(_q_block_in_band(iq, ik, block_q, block_k, True,
-                                        window))
-            assert held == int(np.clip(iq, inside[0], inside[-1]))
+    tiles = _band_tiles(nq, nk, block_q, block_k, True, window)
+    assert tiles["kv_steps"] == kept.sum(axis=1).max()
+    assert tiles["q_steps"] == kept.sum(axis=0).max()
+    assert tiles["live_tiles"] == kept.sum()
+    walks = [(kept, nk, tiles["kv_steps"], _kv_block_in_band),
+             (kept.T, nq, tiles["q_steps"], _q_block_in_band)]
+    for rows, n, steps, block_in_band in walks:
+        for i, row in enumerate(rows):
+            inside = np.flatnonzero(row)
+            walked = [tuple(int(x) for x in block_in_band(
+                i, step, steps, block_q, block_k, n, True, window))
+                for step in range(steps)]
+            true, held = (list(x) for x in zip(*walked))
+            assert true == list(range(inside[-1] - steps + 1, inside[-1] + 1))
+            assert held == [max(j, inside[0]) for j in true]
+            live = [j for j in true if 0 <= j < n and row[j]]
+            assert live == list(inside) and 0 <= min(held) <= max(held) < n
+            assert row[true[-1]]  # the walk's last step is a live tile
 
 
 def test_without_a_band_every_step_names_its_own_block():
-    from deepspeed_tpu.ops.pallas.flash_attention import (_kv_block_in_band,
-                                                          _q_block_in_band)
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _band_tiles, _kv_block_in_band, _q_block_in_band)
 
+    assert _band_tiles(4, 8, 64, 32, False, 0) == {
+        "kv_steps": 8, "q_steps": 4, "live_tiles": 32, "edge_tiles": 0,
+        "bodies": ((False, False),)}
     for i in range(4):
         for j in range(4):
-            assert _kv_block_in_band(i, j, 64, 32, False, 0) == j
-            assert _q_block_in_band(i, j, 64, 32, False, 0) == i
+            assert _kv_block_in_band(i, j, 8, 64, 32, 8, False, 0) == (j, j)
+            assert _q_block_in_band(j, i, 4, 64, 32, 4, False, 0) == (i, i)
+
+
+@pytest.mark.parametrize("window", [0, 40, 64, 200])
+@pytest.mark.parametrize("block_q,block_k", BLOCK_SHAPES)
+def test_a_bound_cuts_a_tile_where_its_compare_masks_something(block_q,
+                                                               block_k,
+                                                               window):
+    """``_tile_cuts`` against the mask itself: a bound cuts a tile exactly
+    where its compare is not all true, so a tile is interior (no mask built)
+    exactly where the whole band mask is all true, the mask built from the
+    bounds that cut equals the whole mask, ``edge_tiles`` counts the live
+    tiles that build one and ``bodies`` holds the pairs that occur."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        _band_mask, _band_tiles, _tile_cuts)
+
+    seq = 512
+    nq, nk = seq // block_q, seq // block_k
+    kept = _kept_tiles(seq, block_q, block_k, True, window)
+    edge, bodies = 0, set()
+    for iq in range(nq):
+        for ik in range(nk):
+            at = ((block_q, block_k), iq * block_q, ik * block_k)
+            by_causal, by_window = (bool(x) for x in _tile_cuts(
+                iq * block_q, ik * block_k, block_q, block_k, True, window))
+            causal_mask = np.asarray(_band_mask(*at, True, False, window))
+            assert by_causal == (not causal_mask.all())
+            whole = causal_mask
+            if window:
+                window_mask = np.asarray(_band_mask(*at, False, True, window))
+                assert by_window == (not window_mask.all())
+                whole = causal_mask & window_mask
+            assert kept[iq, ik] == bool(whole.any())
+            built = _band_mask(*at, by_causal, by_window, window)
+            assert (built is None) == bool(whole.all())
+            if built is not None:
+                assert np.array_equal(np.asarray(built), whole)
+            if kept[iq, ik]:
+                edge += int(by_causal or by_window)
+                bodies.add((by_causal, by_window))
+    tiles = _band_tiles(nq, nk, block_q, block_k, True, window)
+    assert tiles["edge_tiles"] == edge
+    assert tiles["bodies"] == tuple(sorted(bodies))
+    # a window past one block of queries and one of keys has interior tiles,
+    # and no tile that both bounds cut
+    wide = window == 0 or window >= block_q + block_k - 1
+    assert ((False, False) in bodies) == wide
+    assert not (wide and (True, True) in bodies)
+
+
+def test_trinitys_walk_at_blocks_of_1024():
+    """Trinity-Mini's two kinds of layer at 16,384 tokens: a window of 2,048
+    walks 3 steps a row with 45 live tiles a head, 30 of them cut by the
+    band's edge (16 on the diagonal, 14 at the window's far edge, none by
+    both: three bodies); the full layer walks all 16 with 136 live, the 16
+    on the diagonal cut (two bodies)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _band_tiles
+
+    interior, diagonal, far = (False, False), (True, False), (False, True)
+    assert _band_tiles(16, 16, 1024, 1024, True, 2048) == {
+        "kv_steps": 3, "q_steps": 3, "live_tiles": 45, "edge_tiles": 30,
+        "bodies": (interior, far, diagonal)}
+    assert _band_tiles(16, 16, 1024, 1024, True, 0) == {
+        "kv_steps": 16, "q_steps": 16, "live_tiles": 136, "edge_tiles": 16,
+        "bodies": (interior, diagonal)}
+    assert _band_tiles(32, 32, 512, 512, True, 2048) == {
+        "kv_steps": 5, "q_steps": 5, "live_tiles": 150, "edge_tiles": 60,
+        "bodies": (interior, far, diagonal)}
+    # ``train-1chip``: a window of 4,096 over 2,048 tokens cuts nothing
+    assert _band_tiles(2, 2, 1024, 1024, True, 4096) == {
+        "kv_steps": 2, "q_steps": 2, "live_tiles": 3, "edge_tiles": 2,
+        "bodies": (interior, diagonal)}
+
+
+def _outputs(q, k, v, w, seg, window, block):
+    """(out, lse, dq, dk, dv) of the three kernels at blocks of ``block``.
+    The scale is a power of two: the CPU's compiler contracts ``q kᵀ * scale
+    - m`` into one fused multiply-add only where no select stands between
+    the two, and with an exact product both forms give the same bits."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    q_seg = k_seg = None
+    if seg is not None:
+        q_seg = jax.lax.broadcast_in_dim(seg, (1, SEQ, 128), (0, 1))
+        k_seg = jax.lax.broadcast_in_dim(seg, (1, 8, SEQ), (0, 2))
+    args = (q_seg, k_seg, None, 0.125, True, block, block, window)
+    out, lse = fa._flash_fwd(t(q), t(k), t(v), *args)
+    grads = jax.grad(lambda *a: (fa._flash_attention_bhsd(*a, *args).astype(
+        jnp.float32) * t(w).astype(jnp.float32)).sum(), argnums=(0, 1, 2))(
+            t(q), t(k), t(v))
+    return (out, lse) + grads
+
+
+@pytest.mark.parametrize("with_seg", [False, True], ids=["", "segments"])
+@pytest.mark.parametrize("window", [0, 40, 64, 200])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_interior_tiles_give_the_bits_of_masked_ones(devices, monkeypatch,
+                                                     width, window, with_seg):
+    """Forward, ``lse`` and the three gradients with every tile masked by
+    the bounds that cut it, and by no other, equal bit for bit a run in which
+    every live tile builds the whole band mask (``_tile_cuts`` patched to say
+    both bounds cut everywhere: one body): blocks of 16 at 256 tokens, so
+    that every window has interior tiles and tiles one bound cuts."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    q, k, v, w = _inputs(width, jnp.bfloat16)
+    seg = (jnp.arange(SEQ)[None] // 100).astype(jnp.int32) if with_seg \
+        else None
+    tiles = fa._band_tiles(SEQ // 16, SEQ // 16, 16, 16, True, window)
+    assert 0 < tiles["edge_tiles"] < tiles["live_tiles"]
+    assert len(tiles["bodies"]) == (3 if window else 2)
+    got = _outputs(q, k, v, w, seg, window, 16)
+    monkeypatch.setattr(fa, "_tile_cuts", lambda *a: (True, window > 0))
+    assert fa._band_tiles(SEQ // 16, SEQ // 16, 16, 16, True, window)[
+        "bodies"] == ((True, window > 0),)
+    want = _outputs(q, k, v, w, seg, window, 16)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)), name
+
+
+@pytest.mark.parametrize("window", [0, 72])
+def test_a_block_mask_gates_the_walks_tiles(devices, window):
+    """A block mask under the short walk: the table is read at the block a
+    step names, forward and gradients against the XLA reference."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _reference_attention
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), 1, 256, 4, 32, KV=2)
+    mask = np.tril(np.random.default_rng(0).integers(0, 2, (8, 8))) | np.eye(
+        8, dtype=np.int64)
+    kernel = lambda *a: flash_attention(*a, causal=True, window=window,
+                                        block_q=32, block_k=32,
+                                        block_mask=mask)
+    ref = lambda *a: _reference_attention(
+        *a, causal=True, window=window, segment_ids=None, block_mask=mask,
+        block_q=32, block_k=32)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: (kernel(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: (ref(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
+                                   rtol=5e-4, err_msg=f"d{name}")
+
+
+def test_the_evoformers_biased_forward_walks_every_block(devices):
+    """The evoformer's call (two additive biases, not causal, several kv
+    blocks a row) against its own XLA formulation: without a band the walk
+    is every kv block and the biases' index maps follow it."""
+    from deepspeed_tpu.ops.evoformer import evoformer_attention
+
+    B, N, L, H, D = 1, 2, 1024, 2, 16  # blocks of 512: two a row
+    keys = jax.random.split(jax.random.PRNGKey(9), 5)
+    q, k, v = (jax.random.normal(key, (B, N, L, H, D)) for key in keys[:3])
+    b1 = jax.random.normal(keys[3], (B, N, 1, 1, L))
+    b2 = jax.random.normal(keys[4], (B, 1, H, L, L))
+    out = evoformer_attention(q, k, v, [b1, b2])
+    s = jnp.einsum("bnqhd,bnkhd->bnhqk", q, k, precision="highest") \
+        * D ** -0.5 + b1 + b2
+    ref = jnp.einsum("bnhqk,bnkhd->bnqhd", jax.nn.softmax(s, axis=-1), v,
+                     precision="highest")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)

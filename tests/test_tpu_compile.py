@@ -505,7 +505,10 @@ def test_flash_attention_band_at_16k_compiles(one_chip, mosaic, window):
     the whole triangle, forward and backward.  The banded calls carry
     ``_band`` behind their names (a trace tells them from the full layer's),
     the full layer's keep the plain ones; blocks stay 1,024 (the window is
-    past the cap, so it is not shrunk)."""
+    past the cap, so it is not shrunk).  The banded kernels' inner grid
+    dimension is the band's width, 3 steps a row of tiles, the full layer's
+    all 16; 45 / 136 tiles a head are live and 30 / 16 of them build the
+    band's mask (ISSUE 61)."""
     from deepspeed_tpu.observability.trace import tracer
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
@@ -521,6 +524,9 @@ def test_flash_attention_band_at_16k_compiles(one_chip, mosaic, window):
     assert ("flash_attention_fwd_band" in text) == bool(window)
     events = _flash_events({"fwd", "dkdv", "dq"}, jnp.bfloat16)
     assert all((e["block_q"], e["block_k"]) == (1024, 1024) for e in events)
+    walk = (3, 45, 30) if window else (16, 136, 16)
+    assert all((e["steps"], e["live_tiles"], e["edge_tiles"]) == walk
+               for e in events)
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],

@@ -396,10 +396,13 @@ def dense_step_text(deepspeed_tpu, tfm, ModelSpec) -> str:
                                      m.group(1).split(","))), text)
 
 
-#: sha256 of ``dense_step_text`` at commit a5eecec (PR 54: this function run
-#: over that commit's ``deepspeed_tpu``), and on this tree
+#: sha256 of ``dense_step_text`` on this tree.  Until PR 61 it was the text of
+#: commit a5eecec (PR 54: this function run over that commit's
+#: ``deepspeed_tpu``), 266ab6d0...; PR 61 changed the three flash kernels'
+#: grids and bodies on purpose (the walk over the band's tiles, a body for each
+#: bound that cuts a tile) and nothing else of the step
 DENSE_STEP_SHA256 = (
-    "266ab6d0980b82eb37f6fb86e5bba6dd971e82f79a1fbbe595da0d3858360850")
+    "eedd028e3fe12969946502898a04245396f177d1fe4ee35e6075eb71692d4373")
 
 
 def test_a_model_without_such_a_leaf_traces_what_it_traced(devices):
